@@ -9,7 +9,7 @@ a record drifts:
 
 * **Every record** is a single JSON object with ``metric`` (str),
   ``value`` (finite number >= 0), ``unit`` (non-empty str) and
-  ``backend`` in {tpu, cpu-fallback, cpu}.
+  ``backend`` in {tpu, cpu}.
 * **tpu-backend records** must carry a numeric ``decode_mfu`` in
   (0, 1] — the scoreboard's roofline axis.
 * **schema_version >= 2 records** (everything bench.py writes since
@@ -63,7 +63,7 @@ import math
 import os
 import sys
 
-BACKENDS = {"tpu", "cpu-fallback", "cpu"}
+BACKENDS = {"tpu", "cpu"}
 REQUIRED = ("metric", "value", "unit", "backend")
 
 

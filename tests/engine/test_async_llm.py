@@ -95,7 +95,6 @@ def test_async_cancellation_aborts(checkpoint):
 
 
 def test_async_mp_core(checkpoint, monkeypatch):
-    monkeypatch.setenv("VDT_PLATFORM", "cpu")
     monkeypatch.setenv("VDT_RPC_TIMEOUT", "300")
     path, hf = checkpoint
     engine = make_async_engine(path, multiprocess_engine_core=True)

@@ -33,7 +33,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address=f"127.0.0.1:{port}",
                            num_processes=2, process_id=rank)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -89,9 +88,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     f"--xla_force_host_platform_device_count={dev_per_host}")
 os.environ["VDT_PALLAS_INTERPRET"] = "1"
-os.environ["VDT_PLATFORM"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 from vllm_distributed_tpu.config import (CacheConfig, EngineConfig,
                                          LoadConfig, ModelConfig,
@@ -146,9 +143,7 @@ rank = int(sys.argv[1]); port = sys.argv[2]; baddr = sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 os.environ["VDT_PALLAS_INTERPRET"] = "1"
-os.environ["VDT_PLATFORM"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 from vllm_distributed_tpu.config import (CacheConfig, EngineConfig,
                                          LoadConfig, ModelConfig,

@@ -225,6 +225,12 @@ def test_peak_tables_and_aliases():
     assert peak_hbm_per_chip("") == HOST_PEAK_HBM
 
 
+@pytest.mark.parametrize("lookup", [peak_flops_per_chip, peak_hbm_per_chip])
+def test_unknown_accelerator_kind_raises(lookup):
+    with pytest.raises(ValueError, match="no published peaks"):
+        lookup("TPU v9 mega")
+
+
 def test_mesh_scales_peaks():
     cm = CostModel.from_arch(_arch(), kv_row_bytes=512.0,
                              num_chips=4, device_kind="TPU v4")

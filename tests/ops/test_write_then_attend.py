@@ -2,7 +2,10 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
+from vllm_distributed_tpu.config import ParallelConfig
 from vllm_distributed_tpu.models.common import AttentionBatch
+from vllm_distributed_tpu.parallel.mesh import build_mesh, global_mesh
 from vllm_distributed_tpu.ops.attention import (write_kv_cache,
                                                 paged_attention,
                                                 naive_ragged_attention)
@@ -46,7 +49,17 @@ def make(ps=4, n=5, L=2, N=16, KVH=2, QH=4, D=16, max_q=8, T=24,
     return k_all, v_all, k_new, v_new, q, batch, n
 
 
-def test_combo_jit(monkeypatch):
+@pytest.fixture()
+def one_device_mesh():
+    """The ops read the process-global mesh at trace time, and a worker
+    leaves its own there (init_device): whatever engine test ran earlier
+    in this pytest worker would decide whether these direct calls get
+    wrapped in a shard_map over ITS model axis. Pin a one-device mesh."""
+    with global_mesh(build_mesh(ParallelConfig(), jax.devices()[:1])):
+        yield
+
+
+def test_combo_jit(monkeypatch, one_device_mesh):
     monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
     k_all, v_all, k_new, v_new, q, batch, n = make()
     layer = jnp.asarray([1], jnp.int32)
@@ -71,7 +84,7 @@ def test_combo_jit(monkeypatch):
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
-def test_combo_scan(monkeypatch):
+def test_combo_scan(monkeypatch, one_device_mesh):
     monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
     k_all, v_all, k_new, v_new, q, batch, n = make()
 

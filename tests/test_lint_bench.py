@@ -112,7 +112,7 @@ def test_v1_cpu_record_is_grandfathered(tmp_path):
     # Pre-plane records carry no schema_version and no mfu on CPU.
     _write(tmp_path, "BENCH_old.json", {
         "metric": "decode_throughput", "value": 385.0,
-        "unit": "tok/s", "backend": "cpu-fallback"})
+        "unit": "tok/s", "backend": "cpu"})
     res = _run("--dir", str(tmp_path))
     assert res.returncode == 0, res.stderr
 

@@ -46,15 +46,16 @@ class ShmRingOverrun(ShmRingError):
 
 
 def _compile_lib() -> str:
-    """Build the .so from the C++ source once, keyed by source mtime."""
+    """Build the .so from the C++ source once, keyed by a hash of the
+    source (a copied checkout keeps neither mtimes nor the right to
+    trust a stale build). Needs ``g++`` on the machine."""
+    import hashlib
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    so = os.path.join(_BUILD_DIR, "shm_ring.so")
-    stamp = os.path.join(_BUILD_DIR, "shm_ring.stamp")
-    src_mtime = str(os.path.getmtime(_SRC))
-    if os.path.exists(so) and os.path.exists(stamp):
-        with open(stamp) as f:
-            if f.read() == src_mtime:
-                return so
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_BUILD_DIR, f"shm_ring.{digest}.so")
+    if os.path.exists(so):
+        return so
     tmp = so + f".tmp.{os.getpid()}"
     # -lrt: shm_open/shm_unlink live in librt on glibc < 2.34 (a no-op
     # link on newer glibc where they merged into libc). Without it the
@@ -65,9 +66,6 @@ def _compile_lib() -> str:
     logger.info("building shm ring: %s", " ".join(cmd))
     subprocess.run(cmd, check=True, capture_output=True)
     os.replace(tmp, so)  # atomic vs concurrent builders
-    with open(stamp + ".tmp", "w") as f:
-        f.write(src_mtime)
-    os.replace(stamp + ".tmp", stamp)
     return so
 
 

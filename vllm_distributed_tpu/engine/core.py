@@ -582,13 +582,12 @@ class EngineCore:
         core.py:297; TPU variant tpu_worker.py:246-256 — here
         jax.profiler, viewable in TensorBoard/XProf).
 
-        Hardened for transient-tunnel use: each capture gets its own
-        auto-named directory under VDT_PROFILER_DIR (captures never
+        Hardened against a client that goes away: each capture gets its
+        own auto-named directory under VDT_PROFILER_DIR (captures never
         overwrite each other), a second concurrent start is rejected,
         and every capture carries a VDT_PROFILE_MAX_S deadline the step
         loop enforces — so one RPC pair always yields a self-contained
-        xplane dump even if the client (or the tunnel) dies before the
-        stop lands. Fault point ``perf.capture_stall`` simulates that
+        xplane dump even if the client dies before the stop lands. Fault point ``perf.capture_stall`` simulates that
         wedged client: the stop RPC fails and the deadline is what ends
         the capture, counted in vdt:fault_injections_total."""
         import os

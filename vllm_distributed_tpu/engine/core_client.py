@@ -191,6 +191,11 @@ class SyncMPClient(EngineCoreClient):
 
     reference: core_client.py SyncMPClient + MPClient (ready handshake,
     output queue, engine-dead sentinel, startup timeout).
+
+    On an accelerator host the spawned core claims every chip of the
+    host, so the parent must not have touched JAX (a chip belongs to
+    one process at a time), and only one such core fits a host
+    (engine/dp_client.py refuses subprocess DP replicas there).
     """
 
     def __init__(self, config: EngineConfig) -> None:

@@ -60,6 +60,26 @@ def make_replica_config(config: EngineConfig, rank: int) -> EngineConfig:
     return rc
 
 
+def _require_cpu_for_subprocess_replicas() -> None:
+    """A chip belongs to one process at a time, and a spawned engine
+    core initializes JAX for itself and claims every chip of the host
+    (worker.init_device slices its replica's devices out of
+    ``jax.devices()`` afterwards). Two such cores cannot share a
+    multi-chip host, so subprocess DP replicas run only where the
+    platform is pinned to the CPU; on an accelerator host use the
+    in-process replicas (the default), which take disjoint device
+    slices inside the one process that holds the chips."""
+    import os
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] != "cpu":
+        raise RuntimeError(
+            "subprocess engine-core replicas (multiprocess_engine_core / "
+            "VDT_ENABLE_MP_ENGINE with data_parallel_size > 1) cannot "
+            "share an accelerator host: every spawned core claims all of "
+            "the host's chips, and a chip belongs to one process at a "
+            "time. Run the replicas in-process (the default), or set "
+            "JAX_PLATFORMS=cpu")
+
+
 class DPEngineClient(EngineCoreClient):
     """Balancing front-end over data_parallel_size engine replicas."""
 
@@ -73,6 +93,8 @@ class DPEngineClient(EngineCoreClient):
             force_mp = (config.parallel_config.multiprocess_engine_core
                         or envs.VDT_ENABLE_MP_ENGINE)
         self.is_mp = bool(force_mp)
+        if self.is_mp:
+            _require_cpu_for_subprocess_replicas()
         # Disaggregated serving tier (engine/disagg.py): when VDT_DISAGG
         # is set, the fleet splits into a prefill pool and a decode pool
         # — the plan is computed BEFORE replica construction so each

@@ -1105,7 +1105,7 @@ async def start_profile(request: web.Request) -> web.Response:
     Hardened (ISSUE 14): the engine core auto-names each capture's
     trace dir, bounds it with a VDT_PROFILE_MAX_S force-stop deadline,
     and rejects a second concurrent capture — surfaced as 409 here so
-    a retrying tunnel script can tell "busy" from "broken"."""
+    a retrying client can tell "busy" from "broken"."""
     try:
         dirs = _profile_dirs(
             await request.app[ENGINE_KEY].profile("start"))
@@ -1119,7 +1119,7 @@ async def start_profile(request: web.Request) -> web.Response:
 
 async def stop_profile(request: web.Request) -> web.Response:
     """End the capture. The response bundles the trace dirs WITH the
-    current performance-attribution snapshot, so one transient-tunnel
+    current performance-attribution snapshot, so one
     RPC pair yields an xplane trace plus the self-describing analytic
     table that explains it."""
     try:

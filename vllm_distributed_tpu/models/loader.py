@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from vllm_distributed_tpu.config import EngineConfig
 from vllm_distributed_tpu.logger import init_logger
@@ -360,17 +361,15 @@ def get_model(config: EngineConfig, mesh,
             # weight so the tree matches param_specs() again.
             if isinstance(params.get("layers"), dict):
                 params["layers"].pop("wqkv", None)
-    elif load_format == "dummy" or (
-            load_format == "auto" and not os.path.isdir(model_path)
-            and not (model_path.endswith(".gguf")
-                     and os.path.isfile(model_path))):
-        if load_format != "dummy":
-            logger.warning(
-                "%s is not a local directory; using dummy weights "
-                "(set load_format='safetensors' with a local path for "
-                "real weights)", model_path)
+    elif load_format == "dummy":
         rng = jax.random.PRNGKey(config.model_config.seed)
-        params = model.init_params(rng)
+        params = _init_dummy_params(model, rng, mesh if shard else None)
+    elif not (os.path.isdir(model_path) or
+              (model_path.endswith(".gguf") and os.path.isfile(model_path))):
+        raise ValueError(
+            f"{model_path!r} is not a local checkpoint directory (or "
+            f".gguf file); random weights are served only when asked for "
+            f"by name (load_format='dummy')")
     else:
         tensors = load_hf_state_dict(model_path)
         from vllm_distributed_tpu.models.gptq import maybe_dequantize_gptq
@@ -403,6 +402,30 @@ def get_model(config: EngineConfig, mesh,
 
     params = place_tree(params, specs)
     return model, params
+
+
+def _init_dummy_params(model, rng, mesh) -> dict:
+    """Seeded random weights, generated by ONE jitted program in the
+    target dtype and — when ``mesh`` is given — directly into the target
+    shardings. XLA fuses each tensor's generate/scale/cast chain into
+    its output, so no tensor ever exists unsharded, in f32, or on a
+    device that does not own it: the HBM profile that follows reads
+    weights + workspace and nothing else, and a TP mesh never funnels
+    the whole model through its first device. Threefry is partitionable,
+    so the values do not depend on the mesh."""
+    if mesh is None:
+        return jax.jit(model.init_params)(rng)
+    abstract = jax.eval_shape(model.init_params, rng)
+    specs = model.param_specs()
+
+    def shardings(node, spec):
+        if isinstance(node, dict):
+            return {k: shardings(v, spec.get(k) if spec else None)
+                    for k, v in node.items()}
+        return NamedSharding(mesh, spec if spec is not None else P())
+
+    return jax.jit(model.init_params,
+                   out_shardings=shardings(abstract, specs))(rng)
 
 
 def block_fusion_ineligible_reason(arch, model_cls,

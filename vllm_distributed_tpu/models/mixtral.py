@@ -166,16 +166,17 @@ class MixtralForCausalLM(LlamaForCausalLM):
             if not have_map:
                 return arr  # still in logical order
             cur_first = np.asarray(layers["expert_map"])[:, :, 0]  # [L, E]
-            return np.stack([np.asarray(arr)[l][cur_first[l]]
-                             for l in range(L)])
+            return jnp.stack([arr[l][cur_first[l]] for l in range(L)])
 
         for name in self._EXPERT_WEIGHTS:
             for key in (name, name + "_scale"):
                 if key not in layers:
                     continue
                 logical = logical_index(layers[key])
-                layers[key] = jnp.asarray(
-                    np.stack([logical[l][p2l[l]] for l in range(L)]))
+                # jnp, not np: the weights may be traced (the dummy
+                # loader jits init_params); the index tables are host.
+                layers[key] = jnp.stack(
+                    [logical[l][p2l[l]] for l in range(L)])
         r_cap = self._replica_cap
         emap = np.zeros((L, E, r_cap), np.int32)
         for l in range(L):

@@ -284,7 +284,7 @@ def latent_attention(q_absorbed, q_pe, c_all, batch, *, sm_scale,
     ragged latent attention (the MLA analogue of ops/attention.
     paged_attention). Dispatches to the Pallas latent kernel
     (ops/pallas_mla.py) on the pallas backend; the XLA scan is the
-    correctness reference and CPU fallback. Token parallelism is
+    correctness reference and the CPU path. Token parallelism is
     rejected upstream by the loader."""
     from vllm_distributed_tpu.ops.attention import \
         resolve_attention_backend

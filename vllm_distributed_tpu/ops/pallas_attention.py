@@ -593,11 +593,11 @@ def _decode_call(q, k_pages, v_pages, seq_info, num_seqs, block_tables,
         num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # q
-            pl.BlockSpec(memory_space=pltpu.ANY),  # k_pages
-            pl.BlockSpec(memory_space=pltpu.ANY),  # v_pages
+            pl.BlockSpec(memory_space=pl.ANY),  # q
+            pl.BlockSpec(memory_space=pl.ANY),  # k_pages
+            pl.BlockSpec(memory_space=pl.ANY),  # v_pages
         ],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         scratch_shapes=[
             pltpu.VMEM((sb, num_q_heads, head_dim), q.dtype),
             pltpu.VMEM((2, sb, num_kv_heads, blk, head_dim),
@@ -699,13 +699,13 @@ def ragged_paged_attention_pallas(
         pltpu.VMEM((bq, num_q_heads, head_dim), q.dtype),
     ]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
-    out_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]
+    out_specs = [pl.BlockSpec(memory_space=pl.ANY)]
     if emit_state:
         scratch.append(
             pltpu.VMEM((bq, num_q_heads, head_dim), jnp.float32))
         out_shape.append(
             jax.ShapeDtypeStruct(q.shape, jnp.float32))
-        out_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     scratch += [
         pltpu.SemaphoreType.DMA(()),
         pltpu.SemaphoreType.DMA((2, 2, ppb)),  # [slot, k/v, page]
@@ -718,9 +718,9 @@ def ragged_paged_attention_pallas(
         num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # q
-            pl.BlockSpec(memory_space=pltpu.ANY),  # k_pages
-            pl.BlockSpec(memory_space=pltpu.ANY),  # v_pages
+            pl.BlockSpec(memory_space=pl.ANY),  # q
+            pl.BlockSpec(memory_space=pl.ANY),  # k_pages
+            pl.BlockSpec(memory_space=pl.ANY),  # v_pages
         ],
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -1198,12 +1198,12 @@ def _mega_call(q, k_pages, v_pages, desc, seq_info, decode_list, kv_runs,
         fuse_write=fuse_write, window=window, logit_cap=logit_cap,
         has_alibi=has_alibi, has_sinks=has_sinks)
 
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]  # q
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]  # q
     operands = [q]
     if fuse_write:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
         operands += [k_new_hl, v_new_hl]
-    in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
     operands += [k_pages, v_pages]
     # Head-feature sidecar (ALiBi slopes / sink logits): whole-array
     # VMEM block, read as a value by the attention bodies.
@@ -1211,20 +1211,20 @@ def _mega_call(q, k_pages, v_pages, desc, seq_info, decode_list, kv_runs,
     operands += [feat]
 
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
-    out_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]
+    out_specs = [pl.BlockSpec(memory_space=pl.ANY)]
     aliases = {}
     if fuse_write:
         out_shape += [
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ]
-        out_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
         # Flat operand indices: 6 scalar-prefetch args, then q, k_new,
         # v_new, k_pages (9), v_pages (10) alias outputs 1 and 2.
         aliases = {9: 1, 10: 2}
     if emit_state:
         out_shape.append(jax.ShapeDtypeStruct(q.shape, jnp.float32))
-        out_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
 
     scratch = [
         pltpu.VMEM((stage_rows, num_q_heads, head_dim), q.dtype),

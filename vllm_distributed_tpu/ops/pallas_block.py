@@ -585,7 +585,7 @@ def fused_block_decode_pallas(
         page_size=PS, group=group, tq=tq, to=to, ti=ti, window=window,
         logit_cap=logit_cap, has_alibi=has_alibi, has_sinks=has_sinks)
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     vmem_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,

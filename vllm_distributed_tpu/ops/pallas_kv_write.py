@@ -176,14 +176,14 @@ def write_kv_pages_pallas(
         num_scalar_prefetch=3,
         grid=(G, ),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # k_new
-            pl.BlockSpec(memory_space=pltpu.ANY),  # v_new
-            pl.BlockSpec(memory_space=pltpu.ANY),  # k_all
-            pl.BlockSpec(memory_space=pltpu.ANY),  # v_all
+            pl.BlockSpec(memory_space=pl.ANY),  # k_new
+            pl.BlockSpec(memory_space=pl.ANY),  # v_new
+            pl.BlockSpec(memory_space=pl.ANY),  # k_all
+            pl.BlockSpec(memory_space=pl.ANY),  # v_all
         ],
         out_specs=(
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ),
         scratch_shapes=[
             pltpu.VMEM((KVH, PS, D), k_all.dtype),

@@ -196,10 +196,10 @@ def ragged_latent_attention_pallas(
         num_scalar_prefetch=4,
         grid=(R, num_q_tiles),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # q
-            pl.BlockSpec(memory_space=pltpu.ANY),  # c_pages
+            pl.BlockSpec(memory_space=pl.ANY),  # q
+            pl.BlockSpec(memory_space=pl.ANY),  # c_pages
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((bq, N, kdim_pad), q.dtype),
             pltpu.VMEM((blk, Cs), c_pages.dtype),

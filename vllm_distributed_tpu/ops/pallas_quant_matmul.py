@@ -91,7 +91,8 @@ def _gkernel(x_vmem, gs_vmem, gm_vmem, w_hbm, out_vmem, w_vmem, sems,
         pltpu.make_async_copy(
             w_hbm.at[pl.ds(0, bk), pl.ds(0, bn)], w_vmem.at[slot],
             sems.at[slot]).wait()
-        w_blk = w_vmem[slot].astype(jnp.float32)  # [bk, bn]
+        # Mosaic has no unsigned -> float cast: go through int32.
+        w_blk = w_vmem[slot].astype(jnp.int32).astype(jnp.float32)
         gs = gs_vmem[pl.ds(k * ng, ng), :]  # [ng, bn]
         gm = gm_vmem[pl.ds(k * ng, ng), :]
         wf = (w_blk.reshape(ng, g, bn) * gs[:, None, :] +
@@ -137,7 +138,7 @@ def quant_matmul_grouped(x: jax.Array,  # [T, K]
                 pl.BlockSpec((T, K), lambda n: (0, 0)),
                 pl.BlockSpec((G, bn), lambda n: (0, n)),
                 pl.BlockSpec((G, bn), lambda n: (0, n)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((T, bn), lambda n: (0, n)),
             scratch_shapes=[
@@ -177,7 +178,7 @@ def quant_matmul(x: jax.Array,  # [T, K] activations (bf16/f32)
             in_specs=[
                 pl.BlockSpec((T, K), lambda n: (0, 0)),  # x in VMEM
                 pl.BlockSpec((1, N), lambda n: (0, 0)),  # scales
-                pl.BlockSpec(memory_space=pltpu.ANY),  # packed weights
+                pl.BlockSpec(memory_space=pl.ANY),  # packed weights
             ],
             out_specs=pl.BlockSpec((T, bn), lambda n: (0, n)),
             scratch_shapes=[

@@ -22,18 +22,7 @@ from vllm_distributed_tpu.logger import init_logger
 
 logger = init_logger(__name__)
 
-# Version-portable shard_map: jax >= 0.5 exposes it at the top level
-# (kwarg ``check_vma``); older installs (0.4.x) keep it under
-# jax.experimental with the kwarg spelled ``check_rep``.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax 0.4.x installs
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_04(f, **kwargs)
+shard_map = jax.shard_map
 
 _GLOBAL_MESH: Optional[Mesh] = None
 

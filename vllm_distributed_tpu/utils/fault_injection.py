@@ -86,7 +86,7 @@ but never fired by production code):
   stop RPC fails (the stop is "lost") and only the VDT_PROFILE_MAX_S
   capture-window deadline, enforced by the step loop and stats polls,
   force-stops the trace. The drill proves a profiler client that dies
-  (or a tunnel that drops) mid-capture can never wedge serving, with
+  mid-capture can never wedge serving, with
   the fire counted in ``vdt:fault_injections_total``.
 * ``kv_tier.spill_corrupt`` — a tier-2 spill page file is corrupted
   after its CRC is computed, so promotion detects the mismatch and

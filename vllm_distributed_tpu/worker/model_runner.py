@@ -377,21 +377,27 @@ class TPUModelRunner:
         return [(self.params["layers"], (0, self.model.cfg.num_layers))]
 
     def _make_sharded_caches(self, num_pages: int) -> dict:
+        """The stacked caches, created directly in their shardings: a
+        jitted zeros program with ``out_shardings`` — built unsharded
+        and then placed, the whole pool would first have to fit on the
+        mesh's first device."""
         from jax.sharding import NamedSharding
+        depth = None
+        if self._eagle is not None:
+            # EAGLE's draft KV layers stack onto the target's cache
+            # (same pages/block tables; see spec_decode/eagle.py).
+            depth = (self.model.cfg.num_layers +
+                     self._eagle.num_layers)
+
+        def make():
+            return self.model.make_kv_caches(num_pages, self.page_size,
+                                             num_layers=depth)
+
         with self.mesh:
-            depth = None
-            if self._eagle is not None:
-                # EAGLE's draft KV layers stack onto the target's cache
-                # (same pages/block tables; see spec_decode/eagle.py).
-                depth = (self.model.cfg.num_layers +
-                         self._eagle.num_layers)
-            caches = self.model.make_kv_caches(num_pages, self.page_size,
-                                               num_layers=depth)
-            specs = self.model.kv_cache_specs()
-            return jax.tree.map(
-                lambda x, s: jax.device_put(
-                    x, NamedSharding(self.mesh, s)), caches, specs,
-                is_leaf=lambda x: isinstance(x, jax.Array))
+            shardings = jax.tree.map(
+                lambda _, s: NamedSharding(self.mesh, s),
+                jax.eval_shape(make), self.model.kv_cache_specs())
+            return jax.jit(make, out_shardings=shardings)()
 
     def initialize_kv_cache(self, num_pages: int) -> None:
         assert self.model is not None
@@ -696,6 +702,17 @@ class TPUModelRunner:
         # The model owns its cache layout (MLA stores one latent row per
         # token instead of per-head K/V).
         bytes_ = self.model.kv_cache_page_bytes(self.page_size)
+        if self._use_unified():
+            # The model prices a K/V page across the whole mesh; the
+            # worker budgets ONE device's HBM, and a device holds only
+            # its shard of every page array (kv heads over the model
+            # axis, pages over the token axis). MLA models price their
+            # latent page per rank themselves.
+            spec = self.model.kv_cache_specs()["k"]
+            for entry in spec:
+                for axis in ((entry, ) if isinstance(entry, str)
+                             else entry or ()):
+                    bytes_ //= self.mesh.shape[axis]
         if self._eagle is not None:
             L = self.model.cfg.num_layers
             bytes_ = bytes_ * (L + self._eagle.num_layers) // L
@@ -2638,16 +2655,19 @@ class TPUModelRunner:
             return 0
         n = 0
         for P_ in self.token_buckets:
-            sel = self._gather_sample_rows(
-                jnp.zeros((P_, self.model.cfg.hidden_size),
-                          self.model.cfg.dtype),
-                jnp.arange(P_, dtype=jnp.int32), mesh=mesh)
             with self._compile_watch(("plp", P_)):
-                tgt, _, _ = self._plp_fn(
-                    self.params, sel, jnp.zeros((P_, ), jnp.int32))
+                tgt, _, _ = self._plp_fn(self.params,
+                                         *self._plp_dummy_args(P_, mesh))
             jax.block_until_ready(tgt)
             n += 1
         return n
+
+    def _plp_dummy_args(self, P_: int, mesh) -> tuple:
+        sel = self._gather_sample_rows(
+            jnp.zeros((P_, self.model.cfg.hidden_size),
+                      self.model.cfg.dtype),
+            jnp.arange(P_, dtype=jnp.int32), mesh=mesh)
+        return sel, jnp.zeros((P_, ), jnp.int32)
 
     def _precompile_samplers(self, mesh) -> int:
         """Warm the plain + extended sampler graphs for every row bucket
@@ -2657,17 +2677,7 @@ class TPUModelRunner:
         S1 = self.spec_k + 1
         for R in self.req_buckets:
             rows = R * S1  # sampler sees S+1 rows/request with spec
-            md = SamplingMetadata(
-                temperature=jnp.zeros((rows, ), jnp.float32),
-                top_k=jnp.zeros((rows, ), jnp.int32),
-                top_p=jnp.ones((rows, ), jnp.float32),
-                min_p=jnp.zeros((rows, ), jnp.float32),
-                seeds=jnp.zeros((rows, ), jnp.int64),
-            )
-            hidden_sel = self._gather_sample_rows(
-                jnp.zeros((rows, self.model.cfg.hidden_size),
-                          self.model.cfg.dtype),
-                jnp.arange(rows, dtype=jnp.int32), mesh=mesh)
+            hidden_sel, md, ext, mask = self._sampler_dummy_args(rows, mesh)
             with self._compile_watch(("sample", rows)):
                 tokens, _ = self._sample_fn(self.params, hidden_sel, md)
             jax.block_until_ready(tokens)
@@ -2693,19 +2703,6 @@ class TPUModelRunner:
                                   jnp.float32), md)
                 jax.block_until_ready(verify[0])
                 n += 1
-            ext = ExtendedSamplingMetadata(
-                hist_tokens=jnp.zeros((rows, self.max_model_len),
-                                      jnp.int32),
-                prompt_len=jnp.zeros((rows, ), jnp.int32),
-                total_len=jnp.zeros((rows, ), jnp.int32),
-                presence_penalty=jnp.zeros((rows, ), jnp.float32),
-                frequency_penalty=jnp.zeros((rows, ), jnp.float32),
-                repetition_penalty=jnp.ones((rows, ), jnp.float32),
-                bias_ids=jnp.zeros((rows, self._BIAS_BUF), jnp.int32),
-                bias_vals=jnp.zeros((rows, self._BIAS_BUF), jnp.float32),
-                base_fill=jnp.zeros((rows, ), jnp.float32),
-            )
-            mask = jnp.ones((rows, self.model.cfg.vocab_size), jnp.bool_)
             for want_topk in (False, True):
                 for vocab_mask in (None, mask):
                     with self._compile_watch(("sampleX", rows, want_topk,
@@ -2716,6 +2713,35 @@ class TPUModelRunner:
                     jax.block_until_ready(tokens)
                     n += 1
         return n
+
+    def _sampler_dummy_args(self, rows: int, mesh) -> tuple:
+        """(hidden_sel, sampling md, extended md, vocab mask) for one
+        sampler row bucket."""
+        md = SamplingMetadata(
+            temperature=jnp.zeros((rows, ), jnp.float32),
+            top_k=jnp.zeros((rows, ), jnp.int32),
+            top_p=jnp.ones((rows, ), jnp.float32),
+            min_p=jnp.zeros((rows, ), jnp.float32),
+            seeds=jnp.zeros((rows, ), jnp.int64),
+        )
+        hidden_sel = self._gather_sample_rows(
+            jnp.zeros((rows, self.model.cfg.hidden_size),
+                      self.model.cfg.dtype),
+            jnp.arange(rows, dtype=jnp.int32), mesh=mesh)
+        ext = ExtendedSamplingMetadata(
+            hist_tokens=jnp.zeros((rows, self.max_model_len),
+                                  jnp.int32),
+            prompt_len=jnp.zeros((rows, ), jnp.int32),
+            total_len=jnp.zeros((rows, ), jnp.int32),
+            presence_penalty=jnp.zeros((rows, ), jnp.float32),
+            frequency_penalty=jnp.zeros((rows, ), jnp.float32),
+            repetition_penalty=jnp.ones((rows, ), jnp.float32),
+            bias_ids=jnp.zeros((rows, self._BIAS_BUF), jnp.int32),
+            bias_vals=jnp.zeros((rows, self._BIAS_BUF), jnp.float32),
+            base_fill=jnp.zeros((rows, ), jnp.float32),
+        )
+        mask = jnp.ones((rows, self.model.cfg.vocab_size), jnp.bool_)
+        return hidden_sel, md, ext, mask
 
     def _precompile_multi_step(self, n_steps: int, R: int) -> None:
         md = SamplingMetadata(
@@ -2806,34 +2832,41 @@ class TPUModelRunner:
             })
         return stats
 
-    def profile_memory_bytes(self) -> int:
-        """Bytes of HBM available for KV pages, from a MEASURED peak: run
-        the largest-shape forward against a small scratch cache and read
-        the device's peak allocation (weights + real activation/workspace
-        footprint), mirroring the reference's profile run
-        (gpu_worker.py:200, tpu_worker.py:163). Returns 0 when the
-        platform exposes no memory stats (CPU tests)."""
-        try:
-            dev = next(iter(self.mesh.devices.flat))
-            stats = dev.memory_stats() or {}
-            limit = int(stats.get("bytes_limit", 0))
-        except Exception:  # pragma: no cover - platform specific
-            return 0
-        if not limit:
-            return 0
-        util = self.config.cache_config.gpu_memory_utilization
-        try:
-            peak = self._profile_peak_bytes(dev)
-        except Exception as e:  # pragma: no cover - defensive
-            logger.warning("profile run failed (%s); using current usage",
-                           e)
-            peak = int(stats.get("peak_bytes_in_use",
-                                 stats.get("bytes_in_use", 0)))
-        return max(int(limit * util) - peak, 0)
+    def profile_memory_bytes(self) -> Optional[int]:
+        """Bytes of HBM available for KV pages: the device's limit times
+        ``gpu_memory_utilization``, less what is in use once the
+        largest-shape forward has run against a small scratch cache
+        (the weights — measured), less the largest workspace any one
+        serving program needs while it runs (asked of the compiler).
+        Mirrors the reference's profile run (gpu_worker.py:200,
+        tpu_worker.py:163). Returns None when the platform exposes no
+        memory stats (the CPU backend); on a device that reports
+        ``bytes_limit`` a failing profile run raises.
 
-    def _profile_peak_bytes(self, dev) -> int:
-        """Execute the largest forward shape with a 16-page scratch cache
-        and return the device peak bytes."""
+        Deliberately not ``peak_bytes_in_use``: that is a peak over the
+        process's life, so it charges a second engine in one process
+        (sleep/wake, tests, a restart supervisor) for the first one's
+        pool, and it never includes program scratch anyway."""
+        # This process's devices: memory stats exist only for those.
+        devices = self.mesh.local_devices
+        limit = int((devices[0].memory_stats() or {}).get("bytes_limit", 0))
+        if not limit:
+            return None
+        util = self.config.cache_config.gpu_memory_utilization
+        forwards = self._profile_run()
+        in_use = max(int((d.memory_stats() or {}).get("bytes_in_use", 0))
+                     for d in devices)
+        workspace = self._program_workspace_bytes(forwards)
+        logger.info("profiled HBM: %.2f GiB in use (weights) + %.2f GiB "
+                    "program workspace", in_use / 2**30,
+                    workspace / 2**30)
+        return int(limit * util) - in_use - workspace
+
+    def _profile_run(self) -> list:
+        """Execute the largest forward shape with a 16-page scratch
+        cache — the largest program must load and run before the pool is
+        carved out of what it leaves — and return it lowered, for the
+        compiler's account of its workspace."""
         assert self.model is not None
         scratch = self._make_sharded_caches(16)
         if self._forward_fn is None:
@@ -2841,13 +2874,47 @@ class TPUModelRunner:
         T, max_q, G = max(self.forward_shapes())
         token_ids, batch = self._dummy_step_inputs(T, max_q, G)
         with self.mesh:
+            lowered = self._forward_fn.lower(self.params, scratch,
+                                             token_ids, batch)
+            # The caches are donated: only the returned ones are live.
             scratch, hidden = self._forward_fn(self.params, scratch,
                                                token_ids, batch)
             jax.block_until_ready(hidden)
-        del scratch, hidden
-        stats = dev.memory_stats() or {}
-        peak = int(stats.get("peak_bytes_in_use",
-                             stats.get("bytes_in_use", 0)))
-        logger.info("profiled peak HBM (weights + workspace): %.2f GiB",
-                    peak / 2**30)
-        return peak
+        return [lowered]
+
+    def _program_workspace_bytes(self, programs: list) -> int:
+        """Largest workspace any one of the serving programs — the
+        lowered forward ``programs`` plus the largest sampler and
+        prompt-logprob graphs — needs while it runs: its temp buffers plus the outputs it does not alias
+        onto donated inputs. The TPU runtime takes a program's temp
+        buffers from the bottom of HBM when the program loads, and
+        ``memory_stats()`` never counts them — so a pool sized from
+        measured usage alone grows into them. The compiler knows the
+        size; ask it for the largest member of each family (the
+        prompt-logprob graph at the largest token bucket holds
+        [T, vocab] f32 logits: ~2 GB at a 128k vocabulary). Programs
+        run one at a time, so the maximum is what must stay free."""
+        from vllm_distributed_tpu.parallel.mesh import global_mesh
+        programs = list(programs)
+        mesh = self._head_mesh()
+        with global_mesh(mesh), mesh:
+            S1 = self.spec_k + 1
+            hidden_sel, md, ext, mask = self._sampler_dummy_args(
+                self.req_buckets[-1] * S1, mesh)
+            programs.append(self._sample_ext_fn.lower(
+                self.params, hidden_sel, md, ext, True, mask))
+            if self.pool_role != "decode":
+                programs.append(self._plp_fn.lower(
+                    self.params,
+                    *self._plp_dummy_args(self.token_buckets[-1], mesh)))
+
+            def need(program) -> int:
+                ma = program.compile().memory_analysis()
+                return int(ma.temp_size_in_bytes + ma.output_size_in_bytes
+                           - ma.alias_size_in_bytes)
+
+            return max(need(p) for p in programs)
+
+    def _head_mesh(self):
+        """The mesh the sampler / prompt-logprob graphs run on."""
+        return self.mesh

@@ -214,7 +214,7 @@ class PPModelRunner(TPUModelRunner):
                     self.kv_caches[p], hidden = self._stage_fn(
                         self.stage_params[p], self.kv_caches[p], hidden,
                         batch, first_layer=self._stage_first_layer(p))
-        sml = self.stage_meshes[-1]
+        sml = self._head_mesh()
         with global_mesh(sml), sml:
             return self._launch_sample(hidden, logits_indices,
                                        sampling_md, ext_md, want_topk,
@@ -248,7 +248,7 @@ class PPModelRunner(TPUModelRunner):
                             first_layer=self._stage_first_layer(p))
                 n += 1
             jax.block_until_ready(hidden)
-        sml = self.stage_meshes[-1]
+        sml = self._head_mesh()
         with global_mesh(sml), sml:
             self._precompile_samplers(sml)
             self._precompile_plp(sml)
@@ -270,10 +270,11 @@ class PPModelRunner(TPUModelRunner):
             max_layers = cdiv(L, self.pp)
         return max(full * max_layers // L, 1)
 
-    def _profile_peak_bytes(self, dev) -> int:
-        """Largest-shape pipeline pass against per-stage scratch caches;
-        peak taken as the max over one device per stage. (The base
-        class's limit/util/fallback logic wraps this.)"""
+    def _profile_run(self) -> list:
+        """Largest-shape pipeline pass against per-stage scratch caches.
+        (The base class's limit/util logic wraps this.) Returns no
+        lowered program: the stage programs' compiler-reported
+        workspace is not charged yet (ROADMAP D3)."""
         scratch = self._stage_caches(16)
         if self._forward_fn is None:
             self._build_step_fn()
@@ -291,13 +292,7 @@ class PPModelRunner(TPUModelRunner):
                     self.stage_params[p], scratch[p], hidden, batch,
                     first_layer=self._stage_first_layer(p))
         jax.block_until_ready(hidden)
-        del scratch, hidden
-        peak = 0
-        for sm in self.stage_meshes:
-            d = next(iter(sm.devices.flat))
-            s = d.memory_stats() or {}
-            peak = max(peak,
-                       int(s.get("peak_bytes_in_use",
-                                 s.get("bytes_in_use", 0))))
-        logger.info("profiled PP peak HBM: %.2f GiB", peak / 2**30)
-        return peak
+        return []
+
+    def _head_mesh(self):
+        return self.stage_meshes[-1]
