@@ -1428,8 +1428,6 @@ def _block_fusion_leg(config, prompts, sp, record) -> None:
                                              LoadConfig, SchedulerConfig)
     from vllm_distributed_tpu.engine.llm_engine import LLMEngine
     from vllm_distributed_tpu.sampling_params import SamplingParams
-    import jax as _jax
-    on_tpu = _jax.default_backend() == "tpu"
     keys = ("VDT_BLOCK_FUSION", "VDT_ATTENTION_BACKEND",
             "VDT_PALLAS_INTERPRET")
     saved = {k: os.environ.get(k) for k in keys}
@@ -1444,8 +1442,9 @@ def _block_fusion_leg(config, prompts, sp, record) -> None:
     import dataclasses as _dc
     model_cfg = _dc.replace(config.model_config, dtype="float32")
     try:
-        if not on_tpu:
-            # The fused path only dispatches on the Pallas backend.
+        if TINY:
+            # The fused path only dispatches on the Pallas backend; the
+            # CPU smoke runs it in interpret mode.
             os.environ["VDT_ATTENTION_BACKEND"] = "pallas"
             os.environ["VDT_PALLAS_INTERPRET"] = "1"
         for leg, flag in (("block_fusion_off", "0"),
@@ -2154,6 +2153,15 @@ def main() -> None:
     from vllm_distributed_tpu.engine.llm_engine import LLMEngine
     from vllm_distributed_tpu.sampling_params import SamplingParams
 
+    import jax
+    backend = jax.devices()[0].platform
+    if not TINY and backend != "tpu":
+        # The full-size bench measures the chip. The CPU runs only the
+        # smoke size, and only when asked for by name.
+        raise RuntimeError(
+            f"bench.py found platform {backend!r}, not a TPU; the CPU "
+            "smoke is VDT_BENCH_TINY=1")
+
     # Llama-3.2-1B architecture with dummy weights (no checkpoint on the
     # bench host; compute cost is identical to real weights).
     hf_dims = (dict(
@@ -2212,7 +2220,6 @@ def main() -> None:
 
     # Instrument the multi-step decode burst so the record separates
     # on-device time from host/scheduler overhead.
-    import jax
     runner = _find_runner(engine)
     device_decode = {"s": 0.0, "bursts": 0}
     if runner is not None and hasattr(runner, "_multi_step_fn"):
@@ -2274,8 +2281,7 @@ def main() -> None:
     jax.block_until_ready(out)
     sampler_ms = (time.perf_counter() - t0) / 20 * 1e3
 
-    backend = jax.devices()[0].platform
-    is_tpu = backend not in ("cpu", )
+    is_tpu = not TINY
     params = _model_params(hf_dims)
     # Decode MFU/MBU from the engine's analytic cost model (ISSUE 14):
     # FLOPs credit attention at the run's average context (the old

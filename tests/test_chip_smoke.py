@@ -1,8 +1,11 @@
 """chip_smoke.py's contract, as far as a machine without the chip can
 hold it to: the rehearsal runs the whole script green on the CPU and can
-only ever print the `cpu` line; no failure ends in exit code 0; and the
-compile cache is placed from outside."""
+only ever print the `cpu` line; no failure ends in exit code 0 (nor does
+bench.py's without a chip); its checks on the compile cache and on
+greedy ties refuse what the chip runs of PR 21 showed to be faults; and
+the compile cache is placed from outside."""
 
+import argparse
 import json
 import os
 import shutil
@@ -60,6 +63,81 @@ def test_failure_exits_nonzero_and_prints_ok_false(args):
     last = json.loads(lines[-1])
     assert last["ok"] is False
     assert '"ok": true' not in res.stdout
+
+
+def test_bench_without_a_chip_fails():
+    """bench.py runs on the CPU only when VDT_BENCH_TINY=1 asks for it."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("VDT_BENCH_TINY", None)
+    res = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
+                         cwd=REPO, env=env, capture_output=True, text=True,
+                         timeout=300)
+    assert res.returncode != 0
+    assert "VDT_BENCH_TINY=1" in res.stderr
+    assert '"metric"' not in res.stdout
+
+
+def _startup(hits, misses, precompile_s, graphs=40):
+    return {"cache_hits": hits, "cache_misses": misses,
+            "precompile_s": precompile_s, "precompile_graphs": graphs}
+
+
+@pytest.mark.parametrize("cold, warm, ok", [
+    # Chip call 5 of PR 21: the pool's page count jittered between the
+    # two starts, so the second compiled every forward graph again.
+    pytest.param(_startup(40, 18, 82.5), _startup(40, 18, 76.1), False,
+                 id="second-start-compiled-again"),
+    pytest.param(_startup(40, 18, 78.7), _startup(58, 0, 12.1), True,
+                 id="forwards-cold-then-warm"),
+    pytest.param(_startup(0, 58, 343.0), _startup(58, 0, 12.1), True,
+                 id="from-empty"),
+    # Every program found, but one with a Mosaic kernel inside loads in
+    # 6.5 s in a new process against 0.7 s later (chip call 14).
+    pytest.param(_startup(58, 0, 61.9), _startup(58, 0, 12.9), True,
+                 id="machine-came-with-the-cache-filled"),
+    pytest.param(_startup(40, 18, 78.7), _startup(58, 0, 70.0), False,
+                 id="cache-hit-but-no-faster"),
+    pytest.param(_startup(0, 0, 78.7), _startup(0, 0, 12.1), False,
+                 id="cache-never-asked"),
+])
+def test_compile_cache_check(capsys, cold, warm, ok):
+    import chip_smoke
+    if ok:
+        chip_smoke.Smoke.check_compile_cache(cold, warm)
+    else:
+        with pytest.raises(chip_smoke.CheckFailed):
+            chip_smoke.Smoke.check_compile_cache(cold, warm)
+    # The figures are printed before they are judged.
+    assert '"misses"' in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("other_id, other_lp, ok", [
+    pytest.param(7, None, True, id="same-ids"),
+    pytest.param(9, -2.05, True, id="near-tie"),
+    pytest.param(9, -2.12, False, id="flip-away-from-a-tie"),
+    pytest.param(9, None, False, id="token-the-first-run-never-ranked"),
+])
+def test_greedy_runs_may_part_only_at_a_near_tie(other_id, other_lp, ok):
+    import chip_smoke
+    smoke = chip_smoke.Smoke(argparse.Namespace(
+        cpu_rehearsal=False, chips=1, seed=0, inject_failure=None))
+    top = {7: -2.0, 8: -2.5}
+    if other_lp is not None:
+        top[9] = other_lp
+    a = {"ids": [3, 7, 5], "lps": [-1.0, -2.0, -1.5],
+         "top": [{3: -1.0}, top, {5: -1.5}]}
+    facts = smoke.tie_facts(a, {"ids": [3, other_id, 5]})
+    if other_id == 7:
+        assert facts == {"greedy_tokens_agreeing": 3, "of": 3}
+    else:
+        assert facts["greedy_tokens_agreeing"] == 1
+        assert facts["first_divergence"]["token"] == 1
+        assert facts["tie_tolerance"] == 0.08  # well under the bf16 `tol`
+    if ok:
+        smoke.check_ties("test", facts)
+    else:
+        with pytest.raises(chip_smoke.CheckFailed):
+            smoke.check_ties("test", facts)
 
 
 def test_script_alone_fails(tmp_path):

@@ -376,14 +376,14 @@ class TPUModelRunner:
         one per stage under pipeline parallelism."""
         return [(self.params["layers"], (0, self.model.cfg.num_layers))]
 
-    def _make_sharded_caches(self, num_pages: int) -> dict:
-        """The stacked caches, created directly in their shardings: a
-        jitted zeros program with ``out_shardings`` — built unsharded
-        and then placed, the whole pool would first have to fit on the
-        mesh's first device."""
+    def _cache_layout(self, num_pages: int, mesh=None,
+                      depth: Optional[int] = None) -> tuple:
+        """(make, shapes, shardings) of the stacked caches on ``mesh``
+        (default: the whole mesh); ``depth`` overrides the layer count
+        for a pipeline stage's slice."""
         from jax.sharding import NamedSharding
-        depth = None
-        if self._eagle is not None:
+        mesh = self.mesh if mesh is None else mesh
+        if depth is None and self._eagle is not None:
             # EAGLE's draft KV layers stack onto the target's cache
             # (same pages/block tables; see spec_decode/eagle.py).
             depth = (self.model.cfg.num_layers +
@@ -393,10 +393,19 @@ class TPUModelRunner:
             return self.model.make_kv_caches(num_pages, self.page_size,
                                              num_layers=depth)
 
-        with self.mesh:
-            shardings = jax.tree.map(
-                lambda _, s: NamedSharding(self.mesh, s),
-                jax.eval_shape(make), self.model.kv_cache_specs())
+        shapes = jax.eval_shape(make)
+        shardings = jax.tree.map(lambda _, s: NamedSharding(mesh, s),
+                                 shapes, self.model.kv_cache_specs())
+        return make, shapes, shardings
+
+    def _make_sharded_caches(self, num_pages: int, mesh=None,
+                             depth: Optional[int] = None) -> dict:
+        """The stacked caches, created directly in their shardings: a
+        jitted zeros program with ``out_shardings`` — built unsharded
+        and then placed, the whole pool would first have to fit on the
+        mesh's first device."""
+        make, _, shardings = self._cache_layout(num_pages, mesh, depth)
+        with self.mesh if mesh is None else mesh:
             return jax.jit(make, out_shardings=shardings)()
 
     def initialize_kv_cache(self, num_pages: int) -> None:
@@ -699,24 +708,26 @@ class TPUModelRunner:
         logger.info("awake: weights restored, KV cache reset")
 
     def kv_cache_bytes_per_page(self) -> int:
-        # The model owns its cache layout (MLA stores one latent row per
-        # token instead of per-head K/V).
-        bytes_ = self.model.kv_cache_page_bytes(self.page_size)
-        if self._use_unified():
-            # The model prices a K/V page across the whole mesh; the
-            # worker budgets ONE device's HBM, and a device holds only
-            # its shard of every page array (kv heads over the model
-            # axis, pages over the token axis). MLA models price their
-            # latent page per rank themselves.
-            spec = self.model.kv_cache_specs()["k"]
-            for entry in spec:
-                for axis in ((entry, ) if isinstance(entry, str)
-                             else entry or ()):
-                    bytes_ //= self.mesh.shape[axis]
-        if self._eagle is not None:
-            L = self.model.cfg.num_layers
-            bytes_ = bytes_ * (L + self._eagle.num_layers) // L
-        return bytes_
+        """ONE device's HBM bytes per pool page — the worker budgets one
+        device's memory — read off the layout the runner allocates
+        (``_cache_layout``), whatever the model: how much a device's
+        shards of the cache arrays grow per page added to the pool. A
+        device holds only its shard of every page array (kv heads or
+        latent lanes over the model axis, pages over the token axis);
+        arrays that do not grow with the pool (SSM state rows) cost
+        nothing per page."""
+        import math
+
+        def device_bytes(num_pages: int) -> int:
+            _, shapes, shardings = self._cache_layout(num_pages)
+            return sum(
+                math.prod(sh.shard_shape(x.shape)) * x.dtype.itemsize
+                for x, sh in zip(jax.tree.leaves(shapes),
+                                 jax.tree.leaves(shardings)))
+
+        # Any product of mesh axes divides a step of mesh.size pages.
+        n = self.mesh.size
+        return (device_bytes(2 * n) - device_bytes(n)) // n
 
     def model_fixed_cache_bytes(self) -> int:
         """Per-request fixed state bytes (SSM rows); 0 for paged-KV-only
@@ -2594,13 +2605,8 @@ class TPUModelRunner:
             n += self._precompile_samplers(self.mesh)
             n += self._precompile_plp(self.mesh)
             n += self._precompile_state_cache()
-            n_steps = self.config.scheduler_config.num_scheduler_steps
-            # The scheduler forces multi-step to 1 for stateful models
-            # with the state cache on (fused bursts would cross
-            # snapshot boundaries mid-burst): don't warm burst graphs
-            # that can never dispatch.
-            if self._state_cache_active():
-                n_steps = 1
+            # Burst graphs that can never dispatch are not warmed.
+            n_steps = self._multi_step_steps()
             if n_steps > 1:
                 for R in self.req_buckets:
                     self._precompile_multi_step(n_steps, R)
@@ -2691,16 +2697,10 @@ class TPUModelRunner:
                 jax.block_until_ready(nm)
                 n += 1
             if self.spec_k:
-                from vllm_distributed_tpu.spec_decode.draft_model import \
-                    SUPPORT_K
                 with self._compile_watch(("specv", rows)):
                     verify = self._spec_verify_fn(
                         self.params, hidden_sel,
-                        jnp.full((R, self.spec_k), -1, jnp.int32),
-                        jnp.zeros((R, self.spec_k, SUPPORT_K),
-                                  jnp.int32),
-                        jnp.zeros((R, self.spec_k, SUPPORT_K),
-                                  jnp.float32), md)
+                        *self._spec_verify_dummy_args(R), md)
                 jax.block_until_ready(verify[0])
                 n += 1
             for want_topk in (False, True):
@@ -2713,6 +2713,13 @@ class TPUModelRunner:
                     jax.block_until_ready(tokens)
                     n += 1
         return n
+
+    def _spec_verify_dummy_args(self, R: int) -> tuple:
+        """(drafts, q_ids, q_probs) of the verification graph."""
+        from vllm_distributed_tpu.spec_decode.draft_model import SUPPORT_K
+        return (jnp.full((R, self.spec_k), -1, jnp.int32),
+                jnp.zeros((R, self.spec_k, SUPPORT_K), jnp.int32),
+                jnp.zeros((R, self.spec_k, SUPPORT_K), jnp.float32))
 
     def _sampler_dummy_args(self, rows: int, mesh) -> tuple:
         """(hidden_sel, sampling md, extended md, vocab mask) for one
@@ -2743,7 +2750,17 @@ class TPUModelRunner:
         mask = jnp.ones((rows, self.model.cfg.vocab_size), jnp.bool_)
         return hidden_sel, md, ext, mask
 
-    def _precompile_multi_step(self, n_steps: int, R: int) -> None:
+    def _multi_step_steps(self) -> int:
+        """Steps of the fused decode burst this engine can dispatch (1 =
+        never). The scheduler forces multi-step to 1 for stateful models
+        with the state cache on (fused bursts would cross snapshot
+        boundaries mid-burst)."""
+        if self._state_cache_active():
+            return 1
+        return self.config.scheduler_config.num_scheduler_steps
+
+    def _multi_step_dummy_args(self, n_steps: int, R: int) -> tuple:
+        """The burst graph's arguments after (params, kv_caches)."""
         md = SamplingMetadata(
             temperature=jnp.zeros((R, ), jnp.float32),
             top_k=jnp.zeros((R, ), jnp.int32),
@@ -2751,14 +2768,16 @@ class TPUModelRunner:
             min_p=jnp.zeros((R, ), jnp.float32),
             seeds=jnp.zeros((R, ), jnp.int64),
         )
-        with self._compile_watch(("multi", n_steps, R)):
-            self.kv_caches, toks, _ = self._multi_step_fn(
-                self.params, self.kv_caches, jnp.zeros((R, ), jnp.int32),
-                jnp.zeros((R, ), jnp.int32),
+        return (jnp.zeros((R, ), jnp.int32), jnp.zeros((R, ), jnp.int32),
                 jnp.zeros((R, self.max_pages_per_req), jnp.int32), md,
                 jnp.zeros((n_steps, R), jnp.int64),
-                jnp.zeros((1, ), jnp.int32),
-                jnp.zeros((R, ), jnp.int32))
+                jnp.zeros((1, ), jnp.int32), jnp.zeros((R, ), jnp.int32))
+
+    def _precompile_multi_step(self, n_steps: int, R: int) -> None:
+        with self._compile_watch(("multi", n_steps, R)):
+            self.kv_caches, toks, _ = self._multi_step_fn(
+                self.params, self.kv_caches,
+                *self._multi_step_dummy_args(n_steps, R))
         jax.block_until_ready(toks)
 
     def get_stats(self) -> dict[str, float]:
@@ -2865,8 +2884,10 @@ class TPUModelRunner:
     def _profile_run(self) -> list:
         """Execute the largest forward shape with a 16-page scratch
         cache — the largest program must load and run before the pool is
-        carved out of what it leaves — and return it lowered, for the
-        compiler's account of its workspace."""
+        carved out of what it leaves — and return, lowered, every
+        serving program that takes the caches (that forward, and the
+        largest fused decode burst where one can dispatch), for the
+        compiler's account of their workspace."""
         assert self.model is not None
         scratch = self._make_sharded_caches(16)
         if self._forward_fn is None:
@@ -2874,46 +2895,58 @@ class TPUModelRunner:
         T, max_q, G = max(self.forward_shapes())
         token_ids, batch = self._dummy_step_inputs(T, max_q, G)
         with self.mesh:
-            lowered = self._forward_fn.lower(self.params, scratch,
-                                             token_ids, batch)
+            lowered = [self._forward_fn.lower(self.params, scratch,
+                                              token_ids, batch)]
+            n_steps = self._multi_step_steps()
+            if n_steps > 1:
+                lowered.append(self._multi_step_fn.lower(
+                    self.params, scratch, *self._multi_step_dummy_args(
+                        n_steps, self.req_buckets[-1])))
             # The caches are donated: only the returned ones are live.
             scratch, hidden = self._forward_fn(self.params, scratch,
                                                token_ids, batch)
             jax.block_until_ready(hidden)
-        return [lowered]
+        return lowered
 
     def _program_workspace_bytes(self, programs: list) -> int:
         """Largest workspace any one of the serving programs — the
-        lowered forward ``programs`` plus the largest sampler and
-        prompt-logprob graphs — needs while it runs: its temp buffers plus the outputs it does not alias
-        onto donated inputs. The TPU runtime takes a program's temp
-        buffers from the bottom of HBM when the program loads, and
+        lowered cache-taking ``programs`` plus ``_head_programs`` —
+        needs while it runs: its temp buffers plus the outputs it does
+        not alias onto donated inputs. The TPU runtime takes a program's
+        temp buffers from the bottom of HBM when the program loads, and
         ``memory_stats()`` never counts them — so a pool sized from
         measured usage alone grows into them. The compiler knows the
-        size; ask it for the largest member of each family (the
-        prompt-logprob graph at the largest token bucket holds
-        [T, vocab] f32 logits: ~2 GB at a 128k vocabulary). Programs
+        size; ask it for the largest member of each family. Programs
         run one at a time, so the maximum is what must stay free."""
+
+        def need(program) -> int:
+            ma = program.compile().memory_analysis()
+            return int(ma.temp_size_in_bytes + ma.output_size_in_bytes
+                       - ma.alias_size_in_bytes)
+
+        return max(need(p) for p in programs + self._head_programs())
+
+    def _head_programs(self) -> list:
+        """The largest sampler, verification and prompt-logprob graphs,
+        lowered (the prompt-logprob graph at the largest token bucket
+        holds [T, vocab] f32 logits: ~2 GB at a 128k vocabulary)."""
         from vllm_distributed_tpu.parallel.mesh import global_mesh
-        programs = list(programs)
         mesh = self._head_mesh()
         with global_mesh(mesh), mesh:
-            S1 = self.spec_k + 1
+            R = self.req_buckets[-1]
             hidden_sel, md, ext, mask = self._sampler_dummy_args(
-                self.req_buckets[-1] * S1, mesh)
-            programs.append(self._sample_ext_fn.lower(
-                self.params, hidden_sel, md, ext, True, mask))
+                R * (self.spec_k + 1), mesh)
+            programs = [self._sample_ext_fn.lower(
+                self.params, hidden_sel, md, ext, True, mask)]
+            if self.spec_k:
+                programs.append(self._spec_verify_fn.lower(
+                    self.params, hidden_sel,
+                    *self._spec_verify_dummy_args(R), md))
             if self.pool_role != "decode":
                 programs.append(self._plp_fn.lower(
                     self.params,
                     *self._plp_dummy_args(self.token_buckets[-1], mesh)))
-
-            def need(program) -> int:
-                ma = program.compile().memory_analysis()
-                return int(ma.temp_size_in_bytes + ma.output_size_in_bytes
-                           - ma.alias_size_in_bytes)
-
-            return max(need(p) for p in programs)
+        return programs
 
     def _head_mesh(self):
         """The mesh the sampler / prompt-logprob graphs run on."""

@@ -99,19 +99,9 @@ class PPModelRunner(TPUModelRunner):
 
     # ------------------------------------------------------------------
     def _stage_caches(self, num_pages: int) -> list[dict]:
-        specs = self.model.kv_cache_specs()
-        out = []
-        for p, (s, e) in enumerate(self.layer_ranges):
-            sm = self.stage_meshes[p]
-            with sm:
-                caches = self.model.make_kv_caches(
-                    num_pages, self.page_size, num_layers=e - s)
-                out.append(
-                    jax.tree.map(
-                        lambda x, sp: jax.device_put(
-                            x, NamedSharding(sm, sp)), caches, specs,
-                        is_leaf=lambda x: isinstance(x, jax.Array)))
-        return out
+        return [
+            self._make_sharded_caches(num_pages, self.stage_meshes[p], e - s)
+            for p, (s, e) in enumerate(self.layer_ranges)]
 
     def initialize_kv_cache(self, num_pages: int) -> None:
         assert self.model is not None
@@ -271,10 +261,10 @@ class PPModelRunner(TPUModelRunner):
         return max(full * max_layers // L, 1)
 
     def _profile_run(self) -> list:
-        """Largest-shape pipeline pass against per-stage scratch caches.
-        (The base class's limit/util logic wraps this.) Returns no
-        lowered program: the stage programs' compiler-reported
-        workspace is not charged yet (ROADMAP D3)."""
+        """Largest-shape pipeline pass against per-stage scratch caches;
+        returns every stage's program lowered. (The base class's
+        limit/util logic wraps this and charges the largest program's
+        compiler-reported workspace against every device.)"""
         scratch = self._stage_caches(16)
         if self._forward_fn is None:
             self._build_step_fn()
@@ -284,15 +274,19 @@ class PPModelRunner(TPUModelRunner):
         with global_mesh(sm0), sm0:
             hidden = self._embed_fn(self.embed_params, token_ids,
                                  batch.positions)
+        lowered = []
         for p in range(self.pp):
             sm = self.stage_meshes[p]
             hidden = self._hop(hidden, sm)
             with global_mesh(sm), sm:
-                scratch[p], hidden = self._stage_fn(
-                    self.stage_params[p], scratch[p], hidden, batch,
-                    first_layer=self._stage_first_layer(p))
+                args = (self.stage_params[p], scratch[p], hidden, batch)
+                first = self._stage_first_layer(p)
+                lowered.append(self._stage_fn.lower(*args,
+                                                    first_layer=first))
+                scratch[p], hidden = self._stage_fn(*args,
+                                                    first_layer=first)
         jax.block_until_ready(hidden)
-        return []
+        return lowered
 
     def _head_mesh(self):
         return self.stage_meshes[-1]
