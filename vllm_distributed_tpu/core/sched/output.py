@@ -67,6 +67,10 @@ class SchedulerOutput:
     # req_id -> tokens to run this step (new prompt chunk or 1 + spec len).
     num_scheduled_tokens: dict[str, int] = field(default_factory=dict)
     total_num_scheduled_tokens: int = 0
+    # The grant's composition: tokens of requests still in their prompt,
+    # and the number of requests past it (for the step's spans).
+    num_prefill_tokens: int = 0
+    num_decode_reqs: int = 0
     # req_id -> speculative draft tokens being verified this step.
     scheduled_spec_decode_tokens: dict[str, list[int]] = \
         field(default_factory=dict)

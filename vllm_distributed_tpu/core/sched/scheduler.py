@@ -28,6 +28,9 @@ from vllm_distributed_tpu.core.sched.output import (CachedRequestData,
                                                     TokenParallelAllocation)
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.metrics import events as ev
+from vllm_distributed_tpu.metrics.stats import (QUEUE_LAG_BUCKETS,
+                                                STEP_TOKEN_BUCKETS,
+                                                Histogram)
 from vllm_distributed_tpu.request import Request, RequestStatus
 
 logger = init_logger(__name__)
@@ -358,9 +361,14 @@ class Scheduler:
         # Cached like events_enabled; off means zero stamping work and
         # byte-identical event details.
         self.trace_enabled = ev.trace_plane_enabled()
-        # Batch composition of the most recent non-empty step (gauges).
+        # Batch composition of the most recent non-empty step (gauges),
+        # and of every non-empty step (vdt:step_tokens{kind}).
         self.last_step_prefill_tokens = 0
         self.last_step_decode_tokens = 0
+        self.step_tokens = {kind: Histogram(STEP_TOKEN_BUCKETS)
+                            for kind in ("prefill", "decode")}
+        # add_request to the first step that grants the request tokens.
+        self.queue_wait = Histogram(QUEUE_LAG_BUCKETS)
 
     def _record_event(self, request: Request, event: str,
                       detail: Optional[dict] = None, *,
@@ -395,6 +403,7 @@ class Scheduler:
         assert request.request_id not in self.requests
         self.requests[request.request_id] = request
         request.status = RequestStatus.WAITING
+        request.queued_at = time.monotonic()
         self._record_event(request, ev.QUEUED,
                            {"prompt_tokens": request.num_prompt_tokens,
                             "priority": request.priority})
@@ -609,6 +618,7 @@ class Scheduler:
         # Batch composition (prefill vs decode tokens) of this step.
         prefill_tokens = 0
         decode_tokens = 0
+        decode_reqs = 0
         # SSM state-cache directives accumulated this step (plus any
         # deferred from empty outputs).
         state_saves: list = []
@@ -762,6 +772,7 @@ class Scheduler:
                      "granted": num_new_tokens})
             else:
                 decode_tokens += num_new_tokens
+                decode_reqs += 1
             if request.spec_token_ids:
                 # Trim drafts to the granted token count (1 committed token
                 # + at most num_new_tokens-1 drafts); publishing untrimmed
@@ -1079,6 +1090,14 @@ class Scheduler:
                     # Whole prompt already computed (e.g. remote-KV
                     # pull landed everything): this grant is decode.
                     decode_tokens += num_new_tokens
+                    decode_reqs += 1
+                if request.queued_at is not None:
+                    # Once per request: a preempted request that is
+                    # granted again has waited for pages, not in the
+                    # queue.
+                    self.queue_wait.observe(time.monotonic()
+                                            - request.queued_at)
+                    request.queued_at = None
 
                 all_block_ids = self.kv_cache_manager.get_block_ids(
                     request.request_id)
@@ -1112,6 +1131,8 @@ class Scheduler:
         if num_scheduled_tokens:
             self.last_step_prefill_tokens = prefill_tokens
             self.last_step_decode_tokens = decode_tokens
+            self.step_tokens["prefill"].observe(prefill_tokens)
+            self.step_tokens["decode"].observe(decode_tokens)
         tknp_alloc = None
         if self.tknp_size > 1:
             req_to_rank = {
@@ -1138,6 +1159,8 @@ class Scheduler:
             scheduled_cached_reqs=cached_reqs,
             num_scheduled_tokens=num_scheduled_tokens,
             total_num_scheduled_tokens=total,
+            num_prefill_tokens=prefill_tokens,
+            num_decode_reqs=decode_reqs,
             scheduled_spec_decode_tokens=scheduled_spec_tokens,
             finished_req_ids=self.finished_req_ids,
             multi_step=multi_step if num_scheduled_tokens else 1,
@@ -1806,6 +1829,9 @@ class Scheduler:
             "kv_pull_failures": self.kv_pull_failures,
             "last_step_prefill_tokens": self.last_step_prefill_tokens,
             "last_step_decode_tokens": self.last_step_decode_tokens,
+            "step_tokens": {kind: h.to_dict()
+                            for kind, h in self.step_tokens.items()},
+            "request_queue_seconds": self.queue_wait.to_dict(),
             **self.kv_cache_manager.make_prefix_cache_stats(),
         }
         if self.state_cache is not None:

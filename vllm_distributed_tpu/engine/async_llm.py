@@ -25,6 +25,7 @@ from vllm_distributed_tpu.engine.output_processor import OutputProcessor
 from vllm_distributed_tpu.engine.processor import Processor
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.metrics import events as ev
+from vllm_distributed_tpu.metrics.stats import span
 from vllm_distributed_tpu.outputs import RequestOutput
 from vllm_distributed_tpu.sampling_params import SamplingParams
 
@@ -311,7 +312,13 @@ class AsyncLLM:
         return self.core.recv_outputs(timeout_ms=int(timeout_s * 1000))
 
     def _process_batch(self, core_outputs) -> None:
-        processed = self.output_processor.process_outputs(core_outputs)
+        stats = self.output_processor.stats
+        # A batch of the in-process transport says which step it is of
+        # and when the engine core handed it over (core_proc.StepOutputs).
+        with span("vdt.process_outputs", stats.process_outputs,
+                  step=getattr(core_outputs, "step", 0)):
+            processed = self.output_processor.process_outputs(
+                core_outputs)
         self._abort_in_core(processed.reqs_to_abort)
         # Journal reaping keys off the RAW core outputs plus front-end
         # finishes (stop strings): even a request whose front-end state
@@ -330,6 +337,9 @@ class AsyncLLM:
             q.put_nowait(ro)
             if ro.finished:
                 self.request_queues.pop(ro.request_id, None)
+        handed_over = getattr(core_outputs, "handed_over", None)
+        if handed_over is not None:
+            stats.output_lag.observe(time.monotonic() - handed_over)
 
     def _fail_request(self, request_id: str, err: Exception) -> None:
         """Terminal error for ONE request (replay rejection) while the

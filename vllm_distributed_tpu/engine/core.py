@@ -27,7 +27,6 @@ keep the host from blocking and the scheduler's grant state coherent.
 import os
 import time
 from collections import deque
-from contextlib import nullcontext
 from typing import Optional
 
 from vllm_distributed_tpu.config import EngineConfig
@@ -36,21 +35,12 @@ from vllm_distributed_tpu.core.sched.scheduler import (EngineCoreOutput,
 from vllm_distributed_tpu.executor import Executor
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.metrics import events as ev
-from vllm_distributed_tpu.metrics.stats import (HOST_GAP_BUCKETS,
-                                                STEP_PHASE_BUCKETS,
-                                                Histogram)
+from vllm_distributed_tpu.metrics.stats import StepPhases
 from vllm_distributed_tpu.request import (EngineCoreRequest, Request,
                                           RequestStatus)
 from vllm_distributed_tpu.utils import fault_injection
 
 logger = init_logger(__name__)
-
-# Step phases the engine core times directly. prepare_inputs is timed
-# inside the model runner (it happens under dispatch) and merged into
-# the same family by get_stats. The sync (no batch queue) path folds
-# dispatch+wait into "wait" — the device wait dominates it.
-STEP_PHASES = ("schedule", "dispatch", "wait", "update")
-
 
 class EngineCore:
 
@@ -143,26 +133,20 @@ class EngineCore:
         # to drive this toward zero).
         self.steps_dispatched = 0
         self.steps_overlapped = 0
-        self.step_host_gap = Histogram(HOST_GAP_BUCKETS)
         self._last_wait_done: Optional[float] = None
-        # Step-phase profiler: where each engine iteration's wall time
-        # goes (schedule / dispatch / device wait / update); rendered as
-        # vdt:step_phase_seconds{phase=...} next to the host-gap
-        # histogram. Always on — a perf_counter pair and one bisect per
-        # phase per step.
-        self.step_phases = {p: Histogram(STEP_PHASE_BUCKETS)
-                            for p in STEP_PHASES}
+        # Step phases and spans (metrics/stats.py StepPhases): where the
+        # time of this thread goes, as vdt:step_phase_seconds{phase} and
+        # its companions on /metrics and as vdt.* spans in a capture of
+        # the profile RPC. Always on. The model runner times its own
+        # part of the step into the same object.
+        self.phases = StepPhases(sync=self.batch_queue is None)
+        runner = getattr(getattr(self.executor, "worker", None),
+                         "model_runner", None)
+        if hasattr(runner, "phases"):
+            runner.phases = self.phases
         # Engine-level lifecycle events (batch dispatch/retire); the
         # scheduler keeps its own recorder for request transitions.
         self.events = ev.EventRecorder()
-        # Opt-in TPU timeline annotation: wraps every dispatch in a
-        # jax.profiler.StepTraceAnnotation so a trace captured via the
-        # profile RPC shows per-step boundaries (trace dump dir:
-        # VDT_PROFILER_DIR). Cached — the envs registry re-reads
-        # os.environ per access.
-        from vllm_distributed_tpu import envs
-        self._profile_steps = envs.VDT_PROFILE_STEPS
-        self._step_seq = 0
         # Hardened on-demand profiler capture (the profile RPC):
         # exactly one capture at a time, auto-named trace dirs, and a
         # monotonic deadline (VDT_PROFILE_MAX_S) after which the step
@@ -203,6 +187,8 @@ class EngineCore:
 
     # ------------------------------------------------------------------
     def add_request(self, request: EngineCoreRequest) -> None:
+        if self.phases.idle:
+            self.phases.resume()
         if request.sampling_params.structured is not None:
             self._register_structured(request)
         self.scheduler.add_request(Request.from_engine_core_request(request))
@@ -268,24 +254,6 @@ class EngineCore:
         self.scheduler.finish_requests(request_ids,
                                        RequestStatus.FINISHED_ABORTED)
 
-    def _observe_phase(self, phase: str, start: float) -> float:
-        """Record one step-phase duration; returns the new timestamp so
-        call sites chain them without extra clock reads."""
-        now = time.perf_counter()
-        self.step_phases[phase].observe(now - start)
-        return now
-
-    def _step_annotation(self):
-        """jax.profiler.StepTraceAnnotation around a dispatch when
-        VDT_PROFILE_STEPS is set (TPU timeline capture); no-op
-        otherwise."""
-        if not self._profile_steps:
-            return nullcontext()
-        import jax
-        self._step_seq += 1
-        return jax.profiler.StepTraceAnnotation("vdt_step",
-                                                step_num=self._step_seq)
-
     # Bounded retries for a force-stop whose stop_trace itself fails
     # (full disk mid-export): retry once per window, then declare the
     # jax profiler state unknown and release the capture lane.
@@ -334,24 +302,30 @@ class EngineCore:
         """One scheduling iteration (reference: core.py:223)."""
         if self._profile_dir is not None:
             self._maybe_expire_profile()
-        if self.batch_queue is not None:
-            return self.step_with_batch_queue()
         self.last_step_scheduled = False
-        if not (self.scheduler.has_requests()
+        if not (self.has_unfinished_requests()
                 or self.scheduler.has_kv_transfer_work()):
             return []
-        t = time.perf_counter()
-        scheduler_output = self.scheduler.schedule()
-        t = self._observe_phase("schedule", t)
+        ph = self.phases
+        with ph.begin_step():
+            outputs = (self._step_sync() if self.batch_queue is None
+                       else self.step_with_batch_queue())
+        ph.end_step(more_work=self.has_unfinished_requests()
+                    or self.scheduler.has_kv_transfer_work())
+        return outputs
+
+    def _step_sync(self) -> list[EngineCoreOutput]:
+        """The synchronous step: its phases (and the runner's, which
+        execute_model runs through) partition it, see StepPhases."""
+        ph = self.phases
+        with ph.span("vdt.schedule", "schedule"):
+            scheduler_output = self.scheduler.schedule()
         self.last_step_scheduled = \
             scheduler_output.total_num_scheduled_tokens > 0
-        with self._step_annotation():
-            runner_output = self.executor.execute_model(scheduler_output)
-        t = self._observe_phase("wait", t)
-        outputs = self.scheduler.update_from_output(scheduler_output,
-                                                    runner_output)
-        self._observe_phase("update", t)
-        return outputs
+        runner_output = self.executor.execute_model(scheduler_output)
+        with ph.span("vdt.update_from_output", "update"):
+            return self.scheduler.update_from_output(scheduler_output,
+                                                     runner_output)
 
     def step_with_batch_queue(self) -> list[EngineCoreOutput]:
         """One iteration of the batch queue (PP microbatches or the
@@ -359,26 +333,24 @@ class EngineCore:
         fresh batch whenever there is room and schedulable work;
         otherwise retire the oldest. Each call does at most one of the
         two, so dispatches outnumber waits until the pipeline fills."""
-        self.last_step_scheduled = False
+        ph = self.phases
         if (len(self.batch_queue) < self.batch_queue_size
                 and self.scheduler.has_schedulable_requests()):
-            t = time.perf_counter()
-            scheduler_output = self.scheduler.schedule()
-            t = self._observe_phase("schedule", t)
+            with ph.span("vdt.schedule", "schedule"):
+                scheduler_output = self.scheduler.schedule()
             if scheduler_output.total_num_scheduled_tokens > 0:
                 self.scheduler.mark_in_flight(
                     scheduler_output.num_scheduled_tokens)
-                now = time.perf_counter()
-                if self._last_wait_done is not None:
-                    self.step_host_gap.observe(now - self._last_wait_done)
-                    self._last_wait_done = None
                 self.steps_dispatched += 1
                 if self.batch_queue:
                     self.steps_overlapped += 1
-                with self._step_annotation():
+                with ph.span("vdt.dispatch", "dispatch") as dispatch:
+                    if self._last_wait_done is not None:
+                        ph.host_gap.observe(dispatch.start
+                                            - self._last_wait_done)
+                        self._last_wait_done = None
                     handle = self.executor.execute_model_async(
                         scheduler_output)
-                self._observe_phase("dispatch", now)
                 if self.events.enabled:
                     self.events.record("", ev.BATCH_DISPATCH, {
                         "reqs": len(
@@ -428,9 +400,9 @@ class EngineCore:
                 fault_injection.maybe_delay("step.reconcile_stall")
             else:
                 fault_injection.fire_or_raise("step.reconcile_stall")
-        t = time.perf_counter()
-        runner_output = self.executor.wait_model(handle)
-        self._last_wait_done = t = self._observe_phase("wait", t)
+        with ph.span("vdt.wait", "wait") as wait:
+            runner_output = self.executor.wait_model(handle)
+        self._last_wait_done = wait.end
         if self.events.enabled:
             self.events.record("", ev.BATCH_RETIRE, {
                 "reqs": len(scheduler_output.num_scheduled_tokens),
@@ -438,10 +410,9 @@ class EngineCore:
             })
         self.scheduler.unmark_in_flight(
             scheduler_output.num_scheduled_tokens)
-        outputs = self.scheduler.update_from_output(scheduler_output,
-                                                    runner_output)
-        self._observe_phase("update", t)
-        return outputs
+        with ph.span("vdt.update_from_output", "update"):
+            return self.scheduler.update_from_output(scheduler_output,
+                                                     runner_output)
 
     def has_unfinished_requests(self) -> bool:
         # A non-empty batch queue counts as work even when every live
@@ -475,16 +446,7 @@ class EngineCore:
         stats["steps_overlapped"] = self.steps_overlapped
         stats["decode_overlap_frac"] = (
             self.steps_overlapped / max(self.steps_dispatched, 1))
-        stats["step_host_gap_seconds"] = self.step_host_gap.to_dict()
-        # Step-phase profiler family. The runner times prepare_inputs
-        # itself (it happens under dispatch); fold it into the family so
-        # /metrics renders one labeled histogram.
-        phases = {name: h.to_dict()
-                  for name, h in self.step_phases.items()}
-        prep = stats.pop("prepare_inputs_seconds", None)
-        if isinstance(prep, dict):
-            phases["prepare_inputs"] = prep
-        stats["step_phase_seconds"] = phases
+        stats.update(self.phases.to_dict())
         # Transport telemetry: per-connector KV-transfer bytes/latency/
         # inflight and shm-ring wait/lag, recorded by everything built
         # inside this core's construction window. Multi-host follower
@@ -604,7 +566,12 @@ class EngineCore:
             trace_dir = os.path.join(
                 envs.VDT_PROFILER_DIR,
                 f"trace-{os.getpid()}-{self._profile_seq:03d}")
-            jax.profiler.start_trace(trace_dir)
+            # The program's own spans (StepPhases) name the host side
+            # of a capture; JAX's Python tracer would add a million call
+            # events a second and slow the host it is measuring.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
             self._profile_dir = trace_dir
             self._profile_deadline = (time.monotonic() +
                                       envs.VDT_PROFILE_MAX_S)

@@ -212,6 +212,19 @@ def _busy_loop(core: EngineCore, inp: zmq.Socket, out: zmq.Socket) -> None:
 # ---------------------------------------------------------------------------
 
 
+class StepOutputs(list):
+    """One step's outputs as the in-process transport hands them to the
+    front end: the list, the step's number (the ``step`` of its spans)
+    and ``time.monotonic()`` of the hand-over."""
+
+    __slots__ = ("step", "handed_over")
+
+    def __init__(self, outputs, step: int) -> None:
+        super().__init__(outputs)
+        self.step = step
+        self.handed_over = time.monotonic()
+
+
 class BackgroundEngineCore:
     """EngineCore driven by a daemon thread with queue transport.
 
@@ -300,7 +313,8 @@ class BackgroundEngineCore:
                     pass
                 outputs = self.core.step()
                 if outputs:
-                    self.output_queue.put(outputs)
+                    self.output_queue.put(
+                        StepOutputs(outputs, self.core.phases.step))
                 elif (busy and not self.core.last_step_scheduled
                       and not self.core.has_inflight_batches()):
                     # Host-only poll step (async KV transfer in

@@ -1036,12 +1036,14 @@ class DPEngineClient(EngineCoreClient):
                 agg[k] = agg[k] / len(per)
         # Histogram-shaped entries merge element-wise so DP /metrics
         # renders the fleet histogram instead of silently dropping it.
+        from vllm_distributed_tpu.metrics.prometheus import (
+            HISTOGRAMS, LABELED_HISTOGRAMS)
         from vllm_distributed_tpu.metrics.stats import \
             merge_histogram_dicts
-        merged_gap = merge_histogram_dicts(
-            [s.get("step_host_gap_seconds") for s in per])
-        if merged_gap is not None:
-            agg["step_host_gap_seconds"] = merged_gap
+        for key in HISTOGRAMS:
+            merged = merge_histogram_dicts([s.get(key) for s in per])
+            if merged is not None:
+                agg[key] = merged
         # Attention kernel dispatch counts: {kernel: steps}, summed per
         # kernel label across replicas (a dict, so the flat numeric-sum
         # loop above skipped it).
@@ -1116,17 +1118,18 @@ class DPEngineClient(EngineCoreClient):
             agg["perf_peaks"] = {
                 k: max(float(p.get(k, 0.0)) for p in peak_maps)
                 for k in {k for p in peak_maps for k in p}}
-        # Step-phase family: {phase -> histogram dict}, merged per phase.
-        phase_maps = [s["step_phase_seconds"] for s in per
-                      if isinstance(s.get("step_phase_seconds"), dict)]
-        if phase_maps:
-            merged_phases = {}
-            for phase in sorted({p for m in phase_maps for p in m}):
-                h = merge_histogram_dicts(
-                    [m.get(phase) for m in phase_maps])
+        # Labeled histogram families (step phases, step tokens):
+        # {label value -> histogram dict}, merged per label value.
+        for key in LABELED_HISTOGRAMS:
+            maps = [s[key] for s in per if isinstance(s.get(key), dict)]
+            if not maps:
+                continue
+            merged_map = {}
+            for value in sorted({v for m in maps for v in m}):
+                h = merge_histogram_dicts([m.get(value) for m in maps])
                 if h is not None:
-                    merged_phases[phase] = h
-            agg["step_phase_seconds"] = merged_phases
+                    merged_map[value] = h
+            agg[key] = merged_map
         # Telemetry plane: per-worker maps union (labels are
         # fleet-unique, so no counter is ever summed twice), transport
         # snapshots merge per connector/side label, block-pool stats

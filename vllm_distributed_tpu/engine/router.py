@@ -332,7 +332,9 @@ class ReplicaRouter:
         self._stats[replica] = stats
         self._stats_at[replica] = time.monotonic()
         phases = stats.get("step_phase_seconds")
-        h = phases.get("wait") if isinstance(phases, dict) else None
+        # device_wait: the one blocking phase that both the synchronous
+        # and the batch-queue step observe ("wait" is the latter's alone).
+        h = phases.get("device_wait") if isinstance(phases, dict) else None
         if isinstance(h, dict) and h.get("count"):
             s, c = float(h.get("sum", 0.0)), int(h["count"])
             ps, pc = self._wait_prev[replica]

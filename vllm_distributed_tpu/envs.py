@@ -118,13 +118,6 @@ environment_variables: dict[str, Callable[[], Any]] = {
     # bound the overhead). Read ONCE per component at construction.
     "VDT_REQUEST_TIMELINE":
     lambda: os.getenv("VDT_REQUEST_TIMELINE", "1") == "1",
-    # Step-phase TPU timeline capture: "1" wraps every engine-core
-    # dispatch in jax.profiler.StepTraceAnnotation so a trace captured
-    # via the profile RPC (dump dir: VDT_PROFILER_DIR) shows per-step
-    # boundaries on the device timeline. Opt-in: the annotation costs a
-    # TraceMe on the hot path.
-    "VDT_PROFILE_STEPS":
-    lambda: os.getenv("VDT_PROFILE_STEPS", "0") == "1",
     # Cascade (shared-prefix) attention on the XLA path: "1" enables the
     # detection + split; opt-in because it adds a second compiled
     # forward variant per shape bucket.

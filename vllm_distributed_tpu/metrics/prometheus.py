@@ -139,11 +139,41 @@ _COUNTERS = {
 # {"buckets": [...], "counts": [...], "sum": s, "count": n} dicts
 # (counts has one extra +Inf slot), rendered here in full exposition
 # shape.
-_HISTOGRAMS = {
+HISTOGRAMS = {
     "step_host_gap_seconds": (
         "vdt:step_host_gap_seconds",
-        "Host gap between a step's wait_model return and the next "
-        "dispatch (device idle time the async scheduler hides)"),
+        "Host gap between the return of a step's fetch and the launch "
+        "of the next step's forward (batch queue: wait_model return to "
+        "next dispatch): the device's idle time per step as the host "
+        "clock sees it"),
+    "step_period_seconds": (
+        "vdt:step_period_seconds",
+        "Step start to next step start while the engine has work "
+        "(synchronous path); the step phases sum to it"),
+    "step_host_stall_seconds": (
+        "vdt:step_host_stall_seconds",
+        "Per step, wall time outside the device wait less the "
+        "engine-core thread's own CPU time: time it wanted to run and "
+        "did not (GIL held elsewhere, core taken, launch blocked)"),
+    "request_queue_seconds": (
+        "vdt:request_queue_seconds",
+        "add_request to the first step that grants the request tokens"),
+    "step_kv_tokens_read": (
+        "vdt:step_kv_tokens_read",
+        "Per step, context lengths (computed + scheduled tokens) summed "
+        "over the scheduled requests: K/V tokens the attention reads"),
+}
+
+# Stats entries that map a label value to a histogram dict:
+# key -> (family, HELP, label name).
+LABELED_HISTOGRAMS = {
+    "step_phase_seconds": (
+        "vdt:step_phase_seconds",
+        "Wall seconds per engine-core step phase", "phase"),
+    "step_tokens": (
+        "vdt:step_tokens",
+        "Tokens granted per non-empty step, by kind (prefill = requests "
+        "still in their prompt, decode = past it)", "kind"),
 }
 
 
@@ -153,6 +183,7 @@ _HISTOGRAMS = {
 # table, so an undocumented label set fails tier-1).
 LABELED_METRICS = {
     "vdt:step_phase_seconds": ("phase", ),
+    "vdt:step_tokens": ("kind", ),
     "vdt:fault_injections_total": ("point", ),
     # Telemetry plane: per-worker device/compilation series.
     "vdt:recompiles_total": ("worker", ),
@@ -787,23 +818,21 @@ def _render_histogram(name: str, help_text: str, h: dict) -> list[str]:
                                   h.get("count", 0))
 
 
-def _render_step_phases(phases: dict) -> list[str]:
-    """One labeled histogram family for the engine step-phase profiler:
-    vdt:step_phase_seconds{phase="schedule"|"prepare_inputs"|"dispatch"
-    |"wait"|"update"}. HELP/TYPE once, then the per-phase series —
-    bucket/+Inf shape comes from the shared exposition helper."""
+def _render_labeled_histograms(name: str, help_text: str, label: str,
+                               hists: dict) -> list[str]:
+    """One labeled histogram family (vdt:step_phase_seconds{phase},
+    vdt:step_tokens{kind}): HELP/TYPE once, then one series per label
+    value — bucket/+Inf shape comes from the shared exposition helper."""
     from vllm_distributed_tpu.metrics.stats import render_histogram_lines
-    name = "vdt:step_phase_seconds"
-    lines = [f"# HELP {name} Wall seconds per engine-core step phase",
-             f"# TYPE {name} histogram"]
-    for phase in sorted(phases):
-        h = phases[phase]
+    lines = [f"# HELP {name} {help_text}", f"# TYPE {name} histogram"]
+    for value in sorted(hists):
+        h = hists[value]
         if not isinstance(h, dict):
             continue
         lines += render_histogram_lines(
             name, "", h.get("buckets", ()), h.get("counts", ()),
             h.get("sum", 0.0), h.get("count", 0),
-            label=f'phase="{phase}"', header=False)
+            label=f'{label}="{value}"', header=False)
     return lines
 
 
@@ -819,13 +848,15 @@ def render_metrics(stats: dict) -> str:
             lines += [f"# HELP {name} {help_text}",
                       f"# TYPE {name} counter",
                       f"{name} {float(stats[key])}"]
-    for key, (name, help_text) in _HISTOGRAMS.items():
+    for key, (name, help_text) in HISTOGRAMS.items():
         value = stats.get(key)
         if isinstance(value, dict):
             lines += _render_histogram(name, help_text, value)
-    step_phases = stats.get("step_phase_seconds")
-    if isinstance(step_phases, dict) and step_phases:
-        lines += _render_step_phases(step_phases)
+    for key, (name, help_text, label) in LABELED_HISTOGRAMS.items():
+        value = stats.get(key)
+        if isinstance(value, dict) and value:
+            lines += _render_labeled_histograms(name, help_text, label,
+                                                value)
     # Attention kernel dispatch counts ({kernel: steps} from the runner,
     # summed per kernel across DP replicas).
     calls = stats.get("attn_kernel_calls")

@@ -14,6 +14,8 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Optional
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from vllm_distributed_tpu.logger import init_logger
 
 logger = init_logger(__name__)
@@ -37,6 +39,13 @@ HOST_GAP_BUCKETS = (0.0002, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.02,
 STEP_PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
                       0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                       5.0, 10.0)
+# Waits read by quantile (a request's wait for its first grant, a step's
+# outputs on their way to the request queues): 0.25 ms to 2 s, at most a
+# factor 2 apart below 100 ms so that a median is resolved.
+QUEUE_LAG_BUCKETS = (0.00025, 0.0005, 0.001, 0.002, 0.004, 0.008, 0.016,
+                     0.032, 0.064, 0.1, 0.25, 0.5, 1.0, 2.0)
+# Tokens of one step (granted, or attended to by the step's queries).
+STEP_TOKEN_BUCKETS = tuple(2**i for i in range(21))
 
 
 def render_histogram_lines(name: str, help_text: str, buckets, counts,
@@ -91,6 +100,169 @@ class Histogram:
         dict is byte-identical to render() on the live object."""
         return {"buckets": list(self.buckets), "counts": list(self.counts),
                 "sum": self.total, "count": self.count}
+
+
+class span:
+    """Times a block into ``hist`` (``time.perf_counter()``) and opens a
+    ``jax.profiler.TraceAnnotation`` named ``name`` around it, so that the
+    same block shows in a profiler capture, on the device trace's clock,
+    with ``attrs`` as the event's stats. The annotation costs about half
+    a microsecond while no capture runs, so it is never switched off.
+    ``start``, ``end`` and ``seconds`` are readable once the block has
+    been left."""
+
+    __slots__ = ("hist", "start", "end", "_annotation")
+
+    def __init__(self, name: str, hist: Optional[Histogram] = None,
+                 **attrs) -> None:
+        self.hist = hist
+        self._annotation = TraceAnnotation(name, **attrs)
+
+    def __enter__(self) -> "span":
+        self._annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.perf_counter()
+        if self.hist is not None:
+            self.hist.observe(self.end - self.start)
+        self._annotation.__exit__(*exc)
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
+class _PhaseSpan(span):
+    """A span of a ``StepPhases`` timeline. Inside a synchronous step it
+    starts where the timeline's last phase ended, so that the phases
+    leave no hole between them."""
+
+    __slots__ = ("_phases", )
+
+    def __init__(self, phases: "StepPhases", name: str,
+                 hist: Optional[Histogram], attrs: dict) -> None:
+        super().__init__(name, hist, step=phases.step, **attrs)
+        self._phases = phases
+
+    def __enter__(self) -> "span":
+        super().__enter__()
+        phases = self._phases
+        if self.hist is not None and phases.sync and not phases.idle:
+            self.start = phases.mark
+        return self
+
+    def __exit__(self, *exc) -> None:
+        super().__exit__(*exc)
+        if self.hist is not None:
+            self._phases.mark = self.end
+
+
+class StepPhases:
+    """Where the time of the engine-core thread goes, step by step: one
+    object per engine core, shared with its model runner.
+
+    On the synchronous path (``sync``, no batch queue) the phases ``loop,
+    schedule, update_states, prepare_inputs, launch, device_wait, finish,
+    update`` partition the thread's time between two step starts: each
+    starts at ``mark``, the end of the one before it, so they neither
+    overlap nor leave holes, and their sums add up to the sum of
+    ``period``. On the batch-queue path every phase times its own block,
+    and ``dispatch`` and ``wait`` contain the runner's phases.
+
+    ``host_gap`` estimates the device's idle time per step on the host's
+    clock: from the return of a step's fetch to the launch of the next
+    step's forward. ``host_stall`` is the time this thread wanted to run
+    and did not: the wall time from one fetch's return to the next
+    fetch's start less the thread's own CPU time over it. Near zero, the
+    host time of a step is this thread's own work; several milliseconds,
+    another thread held the GIL, the core was taken, or a launch blocked
+    inside the runtime."""
+
+    PHASES = ("loop", "schedule", "update_states", "prepare_inputs",
+              "launch", "device_wait", "finish", "update", "dispatch",
+              "wait")
+
+    def __init__(self, sync: bool = True) -> None:
+        self.hists = {p: Histogram(STEP_PHASE_BUCKETS)
+                      for p in self.PHASES}
+        self.period = Histogram(STEP_PHASE_BUCKETS)
+        self.host_gap = Histogram(HOST_GAP_BUCKETS)
+        self.host_stall = Histogram(HOST_GAP_BUCKETS)
+        self.sync = sync
+        self.step = 0      # number of the current (or last) vdt_step
+        self.idle = True   # the last step left no work behind
+        self.mark = 0.0    # end of the last phase of this busy period
+        self.step_start: Optional[float] = None
+        self._fetch_done: Optional[float] = None
+        self._cpu_at_fetch_done = 0.0
+        self._loop_span: Optional[span] = None
+
+    def span(self, name: str, phase: Optional[str] = None,
+             **attrs) -> span:
+        """A span of the current step; with ``phase``, one that observes
+        that phase's histogram as well."""
+        return _PhaseSpan(self, name,
+                          self.hists[phase] if phase else None, attrs)
+
+    def resume(self) -> None:
+        """Work arrived at an idle engine: the wait for it belongs to no
+        phase, period, gap or stall."""
+        self.idle = False
+        self.mark = time.perf_counter()
+        self.step_start = self._fetch_done = None
+
+    def begin_step(self) -> StepTraceAnnotation:
+        """At the start of a step that has work: close ``loop`` and the
+        period. Returns the step's parent annotation, ``vdt_step``."""
+        if self._loop_span is not None:
+            self._loop_span.__exit__(None, None, None)
+            self._loop_span = None
+        if self.idle:
+            self.resume()
+        if self.sync:
+            now = time.perf_counter()
+            self.hists["loop"].observe(now - self.mark)
+            self.mark = now
+            if self.step_start is not None:
+                self.period.observe(now - self.step_start)
+            self.step_start = now
+        self.step += 1
+        return StepTraceAnnotation("vdt_step", step_num=self.step)
+
+    def end_step(self, more_work: bool) -> None:
+        """What follows a step until the next one is ``loop``, unless the
+        engine goes idle."""
+        self.idle = not more_work
+        if more_work:
+            self._loop_span = span("vdt.loop", step=self.step)
+            self._loop_span.__enter__()
+
+    def launch_issued(self) -> None:
+        if self.sync and self._fetch_done is not None:
+            self.host_gap.observe(time.perf_counter() - self._fetch_done)
+
+    def fetch_begins(self, now: float) -> None:
+        if self.sync and self._fetch_done is not None:
+            cpu = time.thread_time() - self._cpu_at_fetch_done
+            self.host_stall.observe(
+                max(0.0, now - self._fetch_done - cpu))
+
+    def fetch_done(self, now: float) -> None:
+        if self.sync:
+            self._fetch_done = now
+            self._cpu_at_fetch_done = time.thread_time()
+
+    def to_dict(self) -> dict:
+        """The stats-feed entries of this timeline."""
+        return {
+            "step_phase_seconds": {p: h.to_dict()
+                                   for p, h in self.hists.items()},
+            "step_period_seconds": self.period.to_dict(),
+            "step_host_gap_seconds": self.host_gap.to_dict(),
+            "step_host_stall_seconds": self.host_stall.to_dict(),
+        }
 
 
 def merge_histogram_dicts(hists: list[dict]) -> Optional[dict]:
@@ -197,6 +369,14 @@ class FrontendStats:
     ttft: Histogram = field(default_factory=lambda: Histogram(TTFT_BUCKETS))
     itl: Histogram = field(default_factory=lambda: Histogram(ITL_BUCKETS))
     e2e: Histogram = field(default_factory=lambda: Histogram(E2E_BUCKETS))
+    # The front end's own share of a step (AsyncLLM._process_batch): the
+    # time in OutputProcessor.process_outputs, and from the engine core
+    # handing a step's outputs over to their RequestOutputs being on the
+    # request queues (in-process transport; one monotonic clock).
+    process_outputs: Histogram = field(
+        default_factory=lambda: Histogram(STEP_PHASE_BUCKETS))
+    output_lag: Histogram = field(
+        default_factory=lambda: Histogram(QUEUE_LAG_BUCKETS))
     num_prompt_tokens: int = 0
     num_generation_tokens: int = 0
     num_finished: int = 0
@@ -340,6 +520,14 @@ class FrontendStats:
         lines += self.e2e.render(
             "vdt:e2e_request_latency_seconds",
             "Request arrival to completion latency")
+        lines += self.process_outputs.render(
+            "vdt:frontend_process_outputs_seconds",
+            "Front-end time per step in OutputProcessor.process_outputs "
+            "(detokenizing, stop strings, latency stats)")
+        lines += self.output_lag.render(
+            "vdt:frontend_output_lag_seconds",
+            "Engine core handing a step's outputs over to their "
+            "RequestOutputs being on the request queues")
         for name, help_text, value in (
             ("vdt:prompt_tokens_total",
              "Cumulative prompt tokens of finished requests",

@@ -213,8 +213,13 @@ class MixtralForCausalLM(LlamaForCausalLM):
         form — only selected (token, expert) pairs hit the MXU.
 
         VDT_MOE_BACKEND=dense restores the all-expert einsum baseline
-        (also used by the FLOP-reduction regression test)."""
-        top_idx, top_vals = self._route(lp, x)
+        (also used by the FLOP-reduction regression test).
+
+        The ``moe_*`` named scopes only label the operations (they show
+        in a device trace's op metadata): router, dispatch (sort and row
+        gather), the three grouped GEMMs, combine."""
+        with jax.named_scope("moe_router"):
+            top_idx, top_vals = self._route(lp, x)
         return self.moe_dispatch(lp, x, top_idx, top_vals)
 
     def _route(self, lp: dict, x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -251,29 +256,32 @@ class MixtralForCausalLM(LlamaForCausalLM):
         # Flatten assignments and sort by expert id: each expert's rows
         # become contiguous, exactly what ragged_dot's group_sizes
         # describe (reference: moe_align_block_size kernels, csrc/moe/).
-        flat_e = top_idx.astype(jnp.int32).reshape(-1)        # [T*k]
-        flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
-        flat_w = top_vals.reshape(-1)
-        Pn = self.num_physical
-        if Pn > E:
-            # EPLB indirection: each assignment picks one of its logical
-            # expert's physical replicas, spread round-robin by token
-            # row (reference: eplb_state.py replica selection).
-            choice = flat_t % lp["expert_replicas"][flat_e]
-            flat_e = lp["expert_map"][flat_e, choice]
-        order = jnp.argsort(flat_e, stable=True)
-        se, st = flat_e[order], flat_t[order]
-        sw = flat_w[order]
-        xs = x[st]                                            # [T*k, H]
+        with jax.named_scope("moe_dispatch"):
+            flat_e = top_idx.astype(jnp.int32).reshape(-1)    # [T*k]
+            flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+            flat_w = top_vals.reshape(-1)
+            Pn = self.num_physical
+            if Pn > E:
+                # EPLB indirection: each assignment picks one of its
+                # logical expert's physical replicas, spread round-robin
+                # by token row (reference: eplb_state.py replica
+                # selection).
+                choice = flat_t % lp["expert_replicas"][flat_e]
+                flat_e = lp["expert_map"][flat_e, choice]
+            order = jnp.argsort(flat_e, stable=True)
+            se, st = flat_e[order], flat_t[order]
+            sw = flat_w[order]
+            xs = x[st]                                        # [T*k, H]
 
         if c.expert_parallel:
             y = self._moe_ep_ragged(lp, xs, se, sw)
         else:
-            group_sizes = jnp.bincount(se, length=Pn)
-            y = self._expert_ffn(lp, xs, group_sizes)
-            y = y * sw[:, None].astype(y.dtype)
+            y = self._expert_ffn(lp, xs, jnp.bincount(se, length=Pn))
         # Un-sort + combine the k expert outputs per token.
-        out = jax.ops.segment_sum(y, st, num_segments=T)
+        with jax.named_scope("moe_combine"):
+            if not c.expert_parallel:   # the EP path weights its own rows
+                y = y * sw[:, None].astype(y.dtype)
+            out = jax.ops.segment_sum(y, st, num_segments=T)
         return out.astype(x.dtype)
 
     def _expert_ffn(self, lp: dict, xs: jax.Array,
@@ -281,11 +289,14 @@ class MixtralForCausalLM(LlamaForCausalLM):
         """SwiGLU over expert-sorted rows: three grouped GEMMs. Rows
         beyond sum(group_sizes) come back zero (ragged_dot semantics) —
         the EP path exploits that for its padding."""
-        g = jax.nn.silu(
-            jax.lax.ragged_dot(xs, self._w(lp, "w_gate"), group_sizes))
-        u = jax.lax.ragged_dot(xs, self._w(lp, "w_up"), group_sizes)
-        return jax.lax.ragged_dot(g * u, self._w(lp, "w_down"),
-                                  group_sizes)
+        with jax.named_scope("moe_gate_gemm"):
+            g = jax.nn.silu(jax.lax.ragged_dot(
+                xs, self._w(lp, "w_gate"), group_sizes))
+        with jax.named_scope("moe_up_gemm"):
+            u = jax.lax.ragged_dot(xs, self._w(lp, "w_up"), group_sizes)
+        with jax.named_scope("moe_down_gemm"):
+            return jax.lax.ragged_dot(g * u, self._w(lp, "w_down"),
+                                      group_sizes)
 
     def _a2a_applicable(self, T: int) -> bool:
         """The all-to-all dispatch needs the token bucket divisible by

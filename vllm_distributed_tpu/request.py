@@ -156,6 +156,9 @@ class Request:
             self.mm_hash = mm_content_hash(mm_inputs)
 
         self.status = RequestStatus.WAITING
+        # time.monotonic() of Scheduler.add_request until the request's
+        # first grant (vdt:request_queue_seconds), None afterwards.
+        self.queued_at: Optional[float] = None
         self.stop_reason: Optional[int | str] = None
 
         # Lifecycle timeline (metrics/events.py): (monotonic_ts, event,

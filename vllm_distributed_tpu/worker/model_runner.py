@@ -30,7 +30,8 @@ from vllm_distributed_tpu.core.sched.output import (ModelRunnerOutput,
                                                     SchedulerOutput)
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.metrics.stats import (STEP_PHASE_BUCKETS,
-                                                Histogram)
+                                                STEP_TOKEN_BUCKETS,
+                                                Histogram, StepPhases)
 from vllm_distributed_tpu.models.common import (AttentionBatch,
                                                 TknpAttentionBatch)
 from vllm_distributed_tpu.ops.attention import resolve_attention_backend
@@ -171,10 +172,15 @@ class TPUModelRunner:
         # token ladder itself is already capped by its pool config.
         self.pool_role = config.kv_transfer_config.pool_role
 
-        # Step-phase profiler share: host-side input prep per dispatch
-        # (merged into vdt:step_phase_seconds{phase="prepare_inputs"} by
-        # the engine core's get_stats).
-        self.prepare_inputs_hist = Histogram(STEP_PHASE_BUCKETS)
+        # The runner's part of a step (update_states, prepare_inputs,
+        # launch, device_wait, finish) as phases and spans. The engine
+        # core puts its own StepPhases here, so that one timeline holds
+        # the whole step; a runner driven without a core keeps this one.
+        self.phases = StepPhases()
+        # K/V tokens the step's queries attend to (context lengths
+        # summed over the scheduled requests): what the attention kernel
+        # must read, in tokens.
+        self.kv_tokens_read_hist = Histogram(STEP_TOKEN_BUCKETS)
         # Device/compilation telemetry (metrics/telemetry.py): the
         # blocking device-fetch wait per step and the recompile counter
         # behind vdt:recompiles_total — a steady-state recompile is the
@@ -1255,6 +1261,9 @@ class TPUModelRunner:
             if kv_runs:
                 kv_runs_arr[:len(kv_runs)] = kv_runs
             n_kv_runs = len(kv_runs)
+        # seq_info rows are (q start, q len, kv len, batch row): kv len
+        # is the context (computed + scheduled) a request's queries see.
+        self.kv_tokens_read_hist.observe(int(seq_info[:num_runs, 2].sum()))
 
         # Mega-kernel partition descriptor: kv-write rows first (the
         # fused write+attend pass needs them to precede every attention
@@ -1637,14 +1646,25 @@ class TPUModelRunner:
         waiting on the oldest (reference: core.py:242
         step_with_batch_queue); requests in a dispatched batch are
         excluded from scheduling until their batch retires."""
-        self._update_states(scheduler_output)
-        # State restores BEFORE the forward (the scan's re-entry carry);
-        # zero-token outputs never carry them (scheduler invariant: the
-        # zero-token path does no device work).
-        self._apply_state_restores(scheduler_output)
-        # KV-tier demotion gather + promotion scatter, also pre-forward
-        # (and, like state ops, never on zero-token outputs).
-        tier_pending = self._apply_kv_tier_pre(scheduler_output)
+        with self.phases.span(
+                "vdt.dispatch_model",
+                tokens=scheduler_output.total_num_scheduled_tokens,
+                prefill_tokens=scheduler_output.num_prefill_tokens,
+                decode_reqs=scheduler_output.num_decode_reqs):
+            return self._dispatch_model(scheduler_output)
+
+    def _dispatch_model(self, scheduler_output: SchedulerOutput) -> dict:
+        ph = self.phases
+        with ph.span("vdt._update_states", "update_states"):
+            self._update_states(scheduler_output)
+            # State restores BEFORE the forward (the scan's re-entry
+            # carry); zero-token outputs never carry them (scheduler
+            # invariant: the zero-token path does no device work).
+            self._apply_state_restores(scheduler_output)
+            # KV-tier demotion gather + promotion scatter, also
+            # pre-forward (and, like state ops, never on zero-token
+            # outputs).
+            tier_pending = self._apply_kv_tier_pre(scheduler_output)
         if scheduler_output.total_num_scheduled_tokens == 0:
             # Nothing to run, but async KV transfers may need servicing:
             # hand queued peer reads / completed pulls to the connector
@@ -1666,116 +1686,126 @@ class TPUModelRunner:
                 pad_to_bucket(len(scheduler_output.num_scheduled_tokens),
                               self.req_buckets),
                 n_steps=scheduler_output.multi_step)
-            t_burst = time.perf_counter() if pending is not None else 0.0
-            out = self._execute_multi_step(scheduler_output)
+            with ph.span("vdt.device_wait", "device_wait") as burst:
+                out = self._execute_multi_step(scheduler_output)
             if pending is not None:
-                self._perf_commit(pending,
-                                  time.perf_counter() - t_burst)
+                self._perf_commit(pending, burst.seconds)
             self._apply_kv_tier_post(tier_pending)
             return {"ready": out}
 
-        t_prep = time.perf_counter()
-        (token_ids, batch, logits_indices, sampling_md, sampling_req_ids,
-         fwd_shape, R, spec_pack, ext_md, want_topk, vocab_mask,
-         plp, chain) = self._prepare_inputs(scheduler_output)
-        prep_s = time.perf_counter() - t_prep
-        self.prepare_inputs_hist.observe(prep_s)
-        attn_label = self._attn_kernel_label(batch)
-        self._count_attn_dispatch(attn_label)
-        self._count_block_fusion(batch)
-        perf = self._perf_charge(scheduler_output, attn_label,
-                                 fwd_shape[0])
-        drafts_arr, q_ids, q_probs, spec_truncate = spec_pack
-        if chain is not None:
-            # Async run-ahead rows: substitute the previous dispatch's
-            # on-device samples for the host-unknown input tokens. JAX
-            # program order serializes this gather after the previous
-            # step's _chain_record scatter, so the value is always the
-            # real sampled token by the time the forward reads it.
-            with self.mesh:
-                token_ids = self._chain_apply(
-                    token_ids, chain[0], self._ensure_last_sampled(),
-                    chain[1])
+        with ph.span("vdt._prepare_inputs", "prepare_inputs") as prep:
+            (token_ids, batch, logits_indices, sampling_md,
+             sampling_req_ids, fwd_shape, R, spec_pack, ext_md, want_topk,
+             vocab_mask, plp, chain) = self._prepare_inputs(
+                 scheduler_output)
+        prep_s = prep.seconds
+        # Launch: from the prepared inputs to the handle (forward and
+        # sampler launches, the async chain, state saves).
+        with ph.span("vdt.launch", "launch"):
+            attn_label = self._attn_kernel_label(batch)
+            self._count_attn_dispatch(attn_label)
+            self._count_block_fusion(batch)
+            perf = self._perf_charge(scheduler_output, attn_label,
+                                     fwd_shape[0])
+            drafts_arr, q_ids, q_probs, spec_truncate = spec_pack
+            if chain is not None:
+                # Async run-ahead rows: substitute the previous dispatch's
+                # on-device samples for the host-unknown input tokens. JAX
+                # program order serializes this gather after the previous
+                # step's _chain_record scatter, so the value is always the
+                # real sampled token by the time the forward reads it.
+                with self.mesh:
+                    token_ids = self._chain_apply(
+                        token_ids, chain[0], self._ensure_last_sampled(),
+                        chain[1])
 
-        kv_meta = scheduler_output.kv_connector_metadata
-        if self.kv_connector is not None and kv_meta is not None:
-            # External KV lands in the paged cache BEFORE the forward
-            # (reference: maybe_setup_kv_connector/start_load_kv).
-            self.kv_connector.start_load_kv(kv_meta, self)
+            kv_meta = scheduler_output.kv_connector_metadata
+            if self.kv_connector is not None and kv_meta is not None:
+                # External KV lands in the paged cache BEFORE the forward
+                # (reference: maybe_setup_kv_connector/start_load_kv).
+                self.kv_connector.start_load_kv(kv_meta, self)
 
-        # Rejection-sampling verification handles every spec batch
-        # except extended/structured ones (those rows never carry
-        # drafts; the plain expanded sampler + host prefix match stays
-        # exact for them).
-        spec_q = None
-        if (self.spec_k and ext_md is None and vocab_mask is None):
-            spec_q = (jnp.asarray(drafts_arr), jnp.asarray(q_ids),
-                      jnp.asarray(q_probs), spec_truncate)
-        dev = self._launch_device_step(token_ids, batch, logits_indices,
-                                       sampling_md, fwd_shape, ext_md,
-                                       want_topk, vocab_mask, plp=plp,
-                                       spec_q=spec_q)
-        if self._async_chain and spec_q is None:
-            # Record this step's samples for the next dispatch's chain
-            # (device-to-device; no host sync). Padding rows scatter out
-            # of range and drop.
-            rows_pad = np.full((R, ), self.max_num_reqs, np.int32)
-            rows_pad[:len(sampling_req_ids)] = [
-                self.input_batch.req_id_to_index[r]
-                for r in sampling_req_ids]
-            with self.mesh:
-                self._last_sampled_dev = self._chain_record(
-                    self._ensure_last_sampled(), jnp.asarray(rows_pad),
-                    dev[0])
-        # State snapshots AFTER the forward dispatch: program order on
-        # the (donated) cache arrays makes the copy read post-step rows.
-        self._apply_state_saves(scheduler_output)
-        # Demotion host fetch AFTER the forward dispatch: the copies
-        # were started pre-forward, so they complete while the device
-        # runs the step.
-        self._apply_kv_tier_post(tier_pending)
-        return {"so": scheduler_output, "dev": dev, "kv_meta": kv_meta,
-                "sampling_req_ids": sampling_req_ids,
-                "drafts_arr": drafts_arr, "R": R,
-                "specv": spec_q is not None,
-                "plp_meta": plp[2] if plp else None,
-                "perf": perf, "perf_prep_s": prep_s}
+            # Rejection-sampling verification handles every spec batch
+            # except extended/structured ones (those rows never carry
+            # drafts; the plain expanded sampler + host prefix match stays
+            # exact for them).
+            spec_q = None
+            if (self.spec_k and ext_md is None and vocab_mask is None):
+                spec_q = (jnp.asarray(drafts_arr), jnp.asarray(q_ids),
+                          jnp.asarray(q_probs), spec_truncate)
+            ph.launch_issued()
+            dev = self._launch_device_step(token_ids, batch, logits_indices,
+                                           sampling_md, fwd_shape, ext_md,
+                                           want_topk, vocab_mask, plp=plp,
+                                           spec_q=spec_q)
+            if self._async_chain and spec_q is None:
+                # Record this step's samples for the next dispatch's chain
+                # (device-to-device; no host sync). Padding rows scatter out
+                # of range and drop.
+                rows_pad = np.full((R, ), self.max_num_reqs, np.int32)
+                rows_pad[:len(sampling_req_ids)] = [
+                    self.input_batch.req_id_to_index[r]
+                    for r in sampling_req_ids]
+                with self.mesh:
+                    self._last_sampled_dev = self._chain_record(
+                        self._ensure_last_sampled(), jnp.asarray(rows_pad),
+                        dev[0])
+            # State snapshots AFTER the forward dispatch: program order on
+            # the (donated) cache arrays makes the copy read post-step rows.
+            self._apply_state_saves(scheduler_output)
+            # Demotion host fetch AFTER the forward dispatch: the copies
+            # were started pre-forward, so they complete while the device
+            # runs the step.
+            self._apply_kv_tier_post(tier_pending)
+            return {"so": scheduler_output, "dev": dev, "kv_meta": kv_meta,
+                    "sampling_req_ids": sampling_req_ids,
+                    "drafts_arr": drafts_arr, "R": R,
+                    "specv": spec_q is not None,
+                    "plp_meta": plp[2] if plp else None,
+                    "perf": perf, "perf_prep_s": prep_s}
 
     def wait_model(self, handle: dict) -> ModelRunnerOutput:
         """Blocking half: fetch the sampled tokens, fold them into the
         persistent batch, build the runner output."""
         if "ready" in handle:
             return handle["ready"]
-        scheduler_output = handle["so"]
-        kv_meta = handle["kv_meta"]
-        sampling_req_ids = handle["sampling_req_ids"]
-        drafts_arr = handle["drafts_arr"]
-        R = handle["R"]
-
         # Device-vs-host attribution: this fetch is where the host
         # blocks on the device (everything since dispatch ran async), so
         # its duration IS the step's device wait as seen by this worker.
         # The perf-attribution plane rides the same timing pair to
         # charge the dispatch's analytic FLOPs/bytes against it.
+        ph = self.phases
+        with ph.span("vdt.device_wait", "device_wait") as wait:
+            ph.fetch_begins(wait.start)
+            if handle.get("specv"):
+                verify = tuple(np.asarray(jax.device_get(x))
+                               for x in handle["dev"][0])
+                sample = (None, None, None)
+            else:
+                verify = None
+                sample = self._fetch_sample(handle["dev"])
+        ph.fetch_done(wait.end)
+        wait_s = wait.seconds
+        if self._device_telemetry:
+            self.device_wait_hist.observe(wait_s)
         perf = handle.get("perf")
-        timing = self._device_telemetry or perf is not None
-        t_wait = time.perf_counter() if timing else 0.0
-        if handle.get("specv"):
-            verify = handle["dev"][0]
+        if perf is not None:
+            self._perf_commit(perf, wait_s, handle.get("perf_prep_s", 0.0))
+        with ph.span("vdt.finish", "finish"):
+            return self._finish_step(handle, verify, sample)
+
+    def _finish_step(self, handle: dict, verify,
+                     sample) -> ModelRunnerOutput:
+        """The rest of wait_model once the samples are on the host."""
+        scheduler_output = handle["so"]
+        kv_meta = handle["kv_meta"]
+        sampling_req_ids = handle["sampling_req_ids"]
+        drafts_arr = handle["drafts_arr"]
+        R = handle["R"]
+        tokens_np, logprobs_np, topk_np = sample
+        if verify is not None:
             (accept_np, residual_np, bonus_np, lp_cand_np,
-             lp_bonus_np) = (np.asarray(jax.device_get(x))
-                             for x in verify)
-            tokens_np = logprobs_np = topk_np = None
-        else:
-            tokens_np, logprobs_np, topk_np = self._fetch_sample(
-                handle["dev"])
-        if timing:
-            wait_s = time.perf_counter() - t_wait
-            if self._device_telemetry:
-                self.device_wait_hist.observe(wait_s)
-            if perf is not None:
-                self._perf_commit(perf, wait_s,
-                                  handle.get("perf_prep_s", 0.0))
+             lp_bonus_np) = verify
 
         # Embedding requests: the pooled hidden state of the sampled row
         # is the result; no token is emitted (reference: pooling path of
@@ -2036,53 +2066,54 @@ class TPUModelRunner:
         Returns device arrays (tokens, logprobs, (topv, topi) | None);
         with ``spec_q`` the first slot instead carries the rejection
         verifier's output tuple."""
-        n_rows = logits_indices.shape[0]  # R or R*(S+1) with spec
-        topk_dev = None
-        plp_dev = None
-        if plp is not None:
-            rows, targets, _meta = plp
-            sel = self._gather_sample_rows(hidden, rows, mesh=mesh)
-            with self._compile_watch(("plp", rows.shape[0])):
-                plp_dev = self._plp_fn(self.params, sel, targets)
-        hidden_sel = self._gather_sample_rows(hidden, logits_indices,
-                                              mesh=mesh)
-        if self._numerics_fn is not None:
-            # Dispatch-only like the sampler; the tap harvests the
-            # PREVIOUS step's reduction, so this never blocks the step.
-            # Strided (the reduction re-derives logits — an extra
-            # lm-head pass — so tapping every step would be a ~2x
-            # logits cost). Fused multi-step bursts bypass
-            # _launch_sample and are not tapped (documented sentinel
-            # limitation).
-            self._numerics_countdown -= 1
-            if self._numerics_countdown <= 0:
-                from vllm_distributed_tpu.correctness_plane import \
-                    NUMERICS_TAP_STRIDE
-                self._numerics_countdown = NUMERICS_TAP_STRIDE
-                with self._compile_watch(("numerics", n_rows)):
-                    self._numerics.dispatch(
-                        self._numerics_fn(self.params, hidden_sel))
-        if spec_q is not None:
-            drafts_d, q_ids_d, q_probs_d, truncate = spec_q
-            with self._compile_watch(("specv", n_rows, truncate)):
-                verify = self._spec_verify_fn(
-                    self.params, hidden_sel, drafts_d, q_ids_d,
-                    q_probs_d, sampling_md, truncate=truncate)
-            return verify, None, None, hidden_sel, plp_dev
-        if ext_md is not None:
-            with self._compile_watch(("sampleX", n_rows, want_topk,
-                                      vocab_mask is not None)):
-                tokens, logprobs, topv, topi = self._sample_ext_fn(
-                    self.params, hidden_sel, sampling_md, ext_md,
-                    want_topk, vocab_mask)
-            if want_topk:
-                topk_dev = (topv, topi)
-        else:
-            with self._compile_watch(("sample", n_rows)):
-                tokens, logprobs = self._sample_fn(
-                    self.params, hidden_sel, sampling_md)
-        # hidden_sel rides along for pooling requests (fetched lazily).
-        return tokens, logprobs, topk_dev, hidden_sel, plp_dev
+        with self.phases.span("vdt._launch_sample"):
+            n_rows = logits_indices.shape[0]  # R or R*(S+1) with spec
+            topk_dev = None
+            plp_dev = None
+            if plp is not None:
+                rows, targets, _meta = plp
+                sel = self._gather_sample_rows(hidden, rows, mesh=mesh)
+                with self._compile_watch(("plp", rows.shape[0])):
+                    plp_dev = self._plp_fn(self.params, sel, targets)
+            hidden_sel = self._gather_sample_rows(hidden, logits_indices,
+                                                  mesh=mesh)
+            if self._numerics_fn is not None:
+                # Dispatch-only like the sampler; the tap harvests the
+                # PREVIOUS step's reduction, so this never blocks the step.
+                # Strided (the reduction re-derives logits — an extra
+                # lm-head pass — so tapping every step would be a ~2x
+                # logits cost). Fused multi-step bursts bypass
+                # _launch_sample and are not tapped (documented sentinel
+                # limitation).
+                self._numerics_countdown -= 1
+                if self._numerics_countdown <= 0:
+                    from vllm_distributed_tpu.correctness_plane import \
+                        NUMERICS_TAP_STRIDE
+                    self._numerics_countdown = NUMERICS_TAP_STRIDE
+                    with self._compile_watch(("numerics", n_rows)):
+                        self._numerics.dispatch(
+                            self._numerics_fn(self.params, hidden_sel))
+            if spec_q is not None:
+                drafts_d, q_ids_d, q_probs_d, truncate = spec_q
+                with self._compile_watch(("specv", n_rows, truncate)):
+                    verify = self._spec_verify_fn(
+                        self.params, hidden_sel, drafts_d, q_ids_d,
+                        q_probs_d, sampling_md, truncate=truncate)
+                return verify, None, None, hidden_sel, plp_dev
+            if ext_md is not None:
+                with self._compile_watch(("sampleX", n_rows, want_topk,
+                                          vocab_mask is not None)):
+                    tokens, logprobs, topv, topi = self._sample_ext_fn(
+                        self.params, hidden_sel, sampling_md, ext_md,
+                        want_topk, vocab_mask)
+                if want_topk:
+                    topk_dev = (topv, topi)
+            else:
+                with self._compile_watch(("sample", n_rows)):
+                    tokens, logprobs = self._sample_fn(
+                        self.params, hidden_sel, sampling_md)
+            # hidden_sel rides along for pooling requests (fetched lazily).
+            return tokens, logprobs, topk_dev, hidden_sel, plp_dev
 
     @staticmethod
     def _fetch_sample(dev):
@@ -2782,11 +2813,12 @@ class TPUModelRunner:
 
     def get_stats(self) -> dict[str, float]:
         """Runner-side stats (spec-decode acceptance; reference:
-        v1/metrics/stats.py SpecDecodingStats) plus the input-prep share
-        of the step-phase profiler and the device/compilation telemetry
-        (recompiles, device wait, HBM high-water mark)."""
+        v1/metrics/stats.py SpecDecodingStats) plus the K/V tokens read
+        per step and the device/compilation telemetry (recompiles,
+        device wait, HBM high-water mark). The runner's step phases are
+        reported by the engine core, which shares ``self.phases``."""
         stats: dict = {
-            "prepare_inputs_seconds": self.prepare_inputs_hist.to_dict(),
+            "step_kv_tokens_read": self.kv_tokens_read_hist.to_dict(),
             "num_recompiles": self.num_recompiles,
             # Kernel-dispatch + lattice observability (vdt:attn_kernel_
             # calls_total{kernel} / vdt:precompile_graphs_total).
