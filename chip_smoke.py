@@ -727,11 +727,11 @@ class Smoke:
         in_use = [(d.memory_stats() or {}).get("bytes_in_use", 0)
                   for d in devs]
         T, max_q, G = max(runner.forward_shapes())
-        tok, batch = runner._dummy_step_inputs(T, max_q, G)
+        inputs = runner._dummy_step_inputs(T, max_q, G)
         from vllm_distributed_tpu.parallel.mesh import global_mesh
         with runner.mesh, global_mesh(runner.mesh):
             text = runner._forward_fn.lower(
-                runner.params, runner.kv_caches, tok, batch).compile(
+                runner.params, runner.kv_caches, inputs).compile(
                 ).as_text()
         facts = {
             "weight_bytes_per_device": weights,

@@ -105,3 +105,55 @@ def test_no_recompile_multi_step(checkpoint, monkeypatch):
         if not engine.has_unfinished_requests():
             break
     assert not engine.has_unfinished_requests()
+
+
+@pytest.mark.parametrize("pp", [1, 2])
+def test_warmed_programs_equal_the_served_ones(checkpoint, monkeypatch,
+                                               pp):
+    """The packed signature (worker/step_inputs.py StepInputs, the
+    [5, R] sampling buffer) is the same at warm-up and in serving. A jit
+    traces its body once per distinct signature (pytree, shapes, dtypes,
+    weak types, statics), so a dummy input that differs from a served
+    step's in ANY of them shows as a new trace of the model's methods;
+    the runner's own _compile_watch keys only on the shape bucket.
+    Mixed traffic with plain, extended and prompt-logprob sampling must
+    trace nothing, and the warmed count is the lattice's: one forward a
+    token bucket (embed + one a stage under PP), five samplers a request
+    bucket, one prompt-logprob graph a token bucket."""
+    monkeypatch.setenv("VDT_PRECOMPILE", "1")
+    monkeypatch.setenv("VDT_ASSERT_NO_RECOMPILE", "1")
+    path, _ = checkpoint
+    engine = make_engine(path, max_num_batched_tokens=16, max_num_seqs=4,
+                         pipeline_parallel_size=pp)
+    r = _runner(engine)
+    shapes = len(r.forward_shapes())
+    heads = 5 * len(r.req_buckets) + len(r.token_buckets)
+    if pp == 1:
+        assert r.precompile_graphs == shapes + heads
+        assert len(r._compiled_shapes) == shapes + heads
+    else:
+        assert r.precompile_graphs == (1 + pp) * shapes
+        assert len(r._compiled_shapes) == (1 + pp) * shapes + heads
+    traced = []
+    for name in ("forward", "embed", "run_layers", "compute_logits"):
+        def counted(*a, _name=name, _fn=getattr(r.model, name), **k):
+            traced.append(_name)
+            return _fn(*a, **k)
+        monkeypatch.setattr(r.model, name, counted)
+    rng = np.random.default_rng(5)
+    extras = [dict(), dict(repetition_penalty=1.2), dict(logprobs=2),
+              dict(prompt_logprobs=1), dict(temperature=0.8, seed=7)]
+    for i, n in enumerate((3, 11, 23, 2, 7)):
+        params = dict(temperature=0.0, max_tokens=4 + i % 3,
+                      ignore_eos=True)
+        params.update(extras[i])
+        engine.add_request(
+            f"w{i}", [int(x) for x in rng.integers(2, 127, size=n)],
+            SamplingParams(**params))
+    for _ in range(200):
+        engine.step()
+        if not engine.has_unfinished_requests():
+            break
+    assert not engine.has_unfinished_requests()
+    assert traced == []
+    assert r.num_recompiles == 0

@@ -162,6 +162,11 @@ HISTOGRAMS = {
         "vdt:step_kv_tokens_read",
         "Per step, context lengths (computed + scheduled tokens) summed "
         "over the scheduled requests: K/V tokens the attention reads"),
+    "step_host_transfers": (
+        "vdt:step_host_transfers",
+        "Per step, host arrays the model runner's _prepare_inputs and "
+        "launch handed to the device explicitly (3 on the default path: "
+        "packed forward inputs, packed sampling inputs, sampled rows)"),
 }
 
 # Stats entries that map a label value to a histogram dict:

@@ -8,6 +8,8 @@ lowers to the same compiled graph — adding a request never recompiles.
 from dataclasses import dataclass
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 
 @jax.tree_util.register_dataclass
@@ -21,9 +23,35 @@ class SamplingMetadata:
     top_p: jax.Array
     # [R] float32; 0.0 disables min-p.
     min_p: jax.Array
-    # [R] int64 per-step fold-in values: derived from (user seed, step) for
-    # seeded requests or (engine rng, step) otherwise, built on the host.
+    # [R] int32 per-step fold-in values: derived from (user seed, step) for
+    # seeded requests or (engine rng, step) otherwise, built on the host
+    # in int64 and wrapped to the 32 bits the device holds.
     seeds: jax.Array
+
+
+def pack_sampling(temperature, top_k, top_p, min_p, seeds) -> np.ndarray:
+    """The five [R] host arrays as one [5, R] int32 buffer, one transfer
+    a step. Floats ride as their bit patterns (bit-exact); the int64
+    seeds wrap to the int32 the programs have always received (JAX
+    without x64 made the same cast on the way to the device)."""
+    return np.stack([
+        np.asarray(temperature, np.float32).view(np.int32),
+        np.asarray(top_k, np.int32),
+        np.asarray(top_p, np.float32).view(np.int32),
+        np.asarray(min_p, np.float32).view(np.int32),
+        np.asarray(seeds).astype(np.int32),
+    ])
+
+
+def unpack_sampling(packed: jax.Array) -> SamplingMetadata:
+    """Inverse of ``pack_sampling``, inside the jitted sampler."""
+
+    def f32(row):
+        return jax.lax.bitcast_convert_type(row, jnp.float32)
+
+    return SamplingMetadata(temperature=f32(packed[0]), top_k=packed[1],
+                            top_p=f32(packed[2]), min_p=f32(packed[3]),
+                            seeds=packed[4])
 
 
 @jax.tree_util.register_dataclass

@@ -31,16 +31,20 @@ from vllm_distributed_tpu.core.sched.output import (ModelRunnerOutput,
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.metrics.stats import (STEP_PHASE_BUCKETS,
                                                 STEP_TOKEN_BUCKETS,
+                                                STEP_TRANSFER_BUCKETS,
                                                 Histogram, StepPhases)
 from vllm_distributed_tpu.models.common import (AttentionBatch,
                                                 TknpAttentionBatch)
 from vllm_distributed_tpu.ops.attention import resolve_attention_backend
 from vllm_distributed_tpu.sample.metadata import (ExtendedSamplingMetadata,
-                                                  SamplingMetadata)
+                                                  SamplingMetadata,
+                                                  pack_sampling,
+                                                  unpack_sampling)
 from vllm_distributed_tpu.sample.sampler import (MAX_LOGPROBS, sample_tokens,
                                                  sample_tokens_extended)
 from vllm_distributed_tpu.utils import cdiv, make_buckets, pad_to_bucket
 from vllm_distributed_tpu.worker.input_batch import InputBatch
+from vllm_distributed_tpu.worker.step_inputs import StepInputs, StepLayout
 
 logger = init_logger(__name__)
 
@@ -181,6 +185,14 @@ class TPUModelRunner:
         # summed over the scheduled requests): what the attention kernel
         # must read, in tokens.
         self.kv_tokens_read_hist = Histogram(STEP_TOKEN_BUCKETS)
+        # Host arrays that _prepare_inputs and the launch handed to the
+        # device for the step through _h2d (explicit hand-overs only:
+        # not the scalars an eager op puts, not the multi-step burst):
+        # three on the default path (the packed forward buffer, the
+        # packed sampling buffer, the sampled-row indices), more with an
+        # optional field.
+        self.host_transfers_hist = Histogram(STEP_TRANSFER_BUCKETS)
+        self._step_transfers = 0
         # Device/compilation telemetry (metrics/telemetry.py): the
         # blocking device-fetch wait per step and the recompile counter
         # behind vdt:recompiles_total — a steady-state recompile is the
@@ -282,6 +294,8 @@ class TPUModelRunner:
         # descriptor builder and the kernel.
         self._unified: Optional[bool] = None
         self._tile_params_memo: Optional[tuple[int, int]] = None
+        # (T, G) -> StepLayout of the packed step buffer.
+        self._step_layouts: dict[tuple[int, int], StepLayout] = {}
         self._xla_route_memo: Optional[bool] = None
         # Kernel-dispatch observability: one count per step per kernel
         # family (fused_block|unified|decode|general|cascade|naive)
@@ -751,7 +765,8 @@ class TPUModelRunner:
         model = self.model
         eagle = self._eagle
 
-        def forward(params, kv_caches, token_ids, batch: AttentionBatch):
+        def forward(params, kv_caches, inputs: StepInputs):
+            token_ids, batch = inputs.unpack()
             hidden, kv_caches = model.forward(params, kv_caches, token_ids,
                                               batch)
             if eagle is not None:
@@ -763,17 +778,21 @@ class TPUModelRunner:
                                           token_ids, hidden, batch)
             return kv_caches, hidden
 
-        def sample(params, hidden_sel, sampling_md: SamplingMetadata):
+        # The samplers take the [5, R] packed sampling buffer
+        # (sample/metadata.py pack_sampling) and unpack it first.
+        def sample(params, hidden_sel, sampling_packed):
             logits = model.compute_logits(params, hidden_sel)
-            tokens, logprobs = sample_tokens(logits, sampling_md)
+            tokens, logprobs = sample_tokens(
+                logits, unpack_sampling(sampling_packed))
             return tokens, logprobs
 
-        def sample_ext(params, hidden_sel, sampling_md: SamplingMetadata,
+        def sample_ext(params, hidden_sel, sampling_packed,
                        ext: ExtendedSamplingMetadata, want_topk: bool,
                        vocab_mask=None):
             logits = model.compute_logits(params, hidden_sel)
-            return sample_tokens_extended(logits, sampling_md, ext,
-                                          want_topk, vocab_mask)
+            return sample_tokens_extended(
+                logits, unpack_sampling(sampling_packed), ext, want_topk,
+                vocab_mask)
 
         def prompt_lp(params, sel, targets):
             """Score prompt positions: log-softmax over the LM head at
@@ -792,8 +811,7 @@ class TPUModelRunner:
             return tgt, topv, topi
 
         def spec_verify(params, hidden_sel, drafts, q_ids, q_probs,
-                        sampling_md: SamplingMetadata,
-                        truncate: bool = False):
+                        sampling_packed, truncate: bool = False):
             """Logits + true rejection-sampling verification in one
             graph (reference: v1/sample/rejection_sampler.py:23); keyed
             by the R bucket like the plain sampler."""
@@ -802,6 +820,7 @@ class TPUModelRunner:
             from vllm_distributed_tpu.sample.sampler import \
                 spec_verify_rejection
             logits = model.compute_logits(params, hidden_sel)
+            sampling_md = unpack_sampling(sampling_packed)
             R = drafts.shape[0]
             S1 = hidden_sel.shape[0] // R
             # The dispatch path builds [R*S1]-expanded metadata (the
@@ -979,18 +998,18 @@ class TPUModelRunner:
                 and resolve_attention_backend() == "pallas")
         return self._block_fusion_memo
 
-    def _count_block_fusion(self, batch=None, reason: str = None) -> None:
+    def _count_block_fusion(self, inputs: StepInputs = None,
+                            reason: str = None) -> None:
         """Per-step fused-dispatch accounting, only while fusion is
         enabled+eligible so the families stay silent otherwise."""
         if not self._block_fusion_active():
             return
-        if reason is None and batch is not None:
-            if getattr(batch, "block_fused", False):
+        if reason is None and inputs is not None:
+            if inputs.rest.block_fused:
                 self.block_fusion_calls += 1
                 return
-            reason = ("cascade"
-                      if getattr(batch, "cascade_shared_ids", None)
-                      is not None else "mixed_wave")
+            reason = ("cascade" if inputs.rest.cascade_shared_ids is not None
+                      else "mixed_wave")
         self.block_fusion_fallbacks[reason] = (
             self.block_fusion_fallbacks.get(reason, 0) + 1)
 
@@ -1081,24 +1100,49 @@ class TPUModelRunner:
                                                jnp.int32)
         return self._last_sampled_dev
 
+    def _h2d(self, x) -> jax.Array:
+        """One explicit host-to-device hand-over of the step, counted
+        into vdt:step_host_transfers."""
+        self._step_transfers += 1
+        return jnp.asarray(x)
+
+    def _step_layout(self, T: int, G: int) -> StepLayout:
+        """The packed buffer's layout at forward shape (T, *, G)."""
+        layout = self._step_layouts.get((T, G))
+        if layout is None:
+            P_desc = 0
+            if self._use_unified():
+                from vllm_distributed_tpu.ops.pallas_attention import (
+                    Q_TILE_PAD, num_partition_programs)
+                bq, sb = self._tile_params()
+                P_desc = num_partition_programs(
+                    T - Q_TILE_PAD, self.max_num_reqs, bq=bq, sb=sb,
+                    num_kv_writes=G)
+            layout = self._step_layouts[(T, G)] = StepLayout(
+                T, G, self.max_num_reqs, self.max_pages_per_req, P_desc)
+        return layout
+
     def _prepare_inputs(self, scheduler_output: SchedulerOutput):
-        """Flatten the scheduled requests into padded per-token arrays."""
+        """Flatten the scheduled requests into padded per-token arrays:
+        views of ONE packed int32 buffer (worker/step_inputs.py), shipped
+        with one transfer."""
         ib = self.input_batch
         num_sched = scheduler_output.num_scheduled_tokens
         total_tokens = scheduler_output.total_num_scheduled_tokens
+        self._step_transfers = 0
         # Static shape bucket; token arrays carry one extra q tile of
         # padding so a sequence's final tile may spill past its q_len
         # (see ops/pallas_attention.py).
         T, max_q, G = self._batch_shape(total_tokens,
                                         max(num_sched.values()))
-
-        token_ids = np.zeros((T, ), np.int32)
-        positions = np.zeros((T, ), np.int32)
-        req_idx = np.zeros((T, ), np.int32)
-        slot_mapping = np.full((T, ), -1, np.int32)
+        layout = self._step_layout(T, G)
+        buf = layout.new_buffer()
+        f = layout.views(buf)
+        token_ids, positions = f["token_ids"], f["positions"]
+        req_idx, slot_mapping = f["req_idx"], f["slot_mapping"]
+        seq_info, kv_runs_arr = f["seq_info"], f["kv_runs"]
         mrope_np = (np.zeros((T, 3), np.int32) if self._mrope_on
                     else None)
-        seq_info = np.zeros((self.max_num_reqs, 4), np.int32)
         kv_runs: list[tuple[int, int, int, int]] = []
         ps = self.page_size
 
@@ -1152,7 +1196,6 @@ class TPUModelRunner:
             seq_info[:N] = np.stack(
                 [idx, np.ones(N, np.int32), starts + 1, rows_np], axis=1)
             num_runs = N
-            kv_runs_arr = np.zeros((G, 4), np.int32)
             kv_runs_arr[:N] = np.stack(
                 [pages, offs, idx - offs + ps,
                  np.ones(N, np.int32)], axis=1)
@@ -1257,7 +1300,6 @@ class TPUModelRunner:
             t += n
 
         if fast is None:
-            kv_runs_arr = np.zeros((G, 4), np.int32)
             if kv_runs:
                 kv_runs_arr[:len(kv_runs)] = kv_runs
             n_kv_runs = len(kv_runs)
@@ -1269,23 +1311,18 @@ class TPUModelRunner:
         # fused write+attend pass needs them to precede every attention
         # program), then prefill q-tiles and SB decode groups. The fast
         # decode path feeds its row vector directly (no q_len scan).
-        attn_desc = decode_list_arr = None
         bq = sb = 0
-        if self._use_unified():
-            from vllm_distributed_tpu.ops.pallas_attention import (
-                Q_TILE_PAD, build_partition_descriptor,
-                num_partition_programs)
+        P_desc = layout.desc_programs
+        if P_desc:
+            from vllm_distributed_tpu.ops.pallas_attention import \
+                build_partition_descriptor
             bq, sb = self._tile_params()
-            P_desc = num_partition_programs(
-                T - Q_TILE_PAD, self.max_num_reqs, bq=bq, sb=sb,
-                num_kv_writes=G)
-            desc_np, dl_np = build_partition_descriptor(
-                seq_info, num_runs, bq=bq, sb=sb,
-                num_programs=P_desc, num_kv_writes=n_kv_runs,
-                decode_rows=(np.arange(num_runs, dtype=np.int32)
-                             if fast is not None else None))
-            attn_desc = jnp.asarray(desc_np)
-            decode_list_arr = jnp.asarray(dl_np)
+            f["attn_desc"][:], f["decode_list"][:] = \
+                build_partition_descriptor(
+                    seq_info, num_runs, bq=bq, sb=sb,
+                    num_programs=P_desc, num_kv_writes=n_kv_runs,
+                    decode_rows=(np.arange(num_runs, dtype=np.int32)
+                                 if fast is not None else None))
             if K > 1:
                 tk_desc = np.zeros((K, P_desc, 3), np.int32)
                 tk_dl = np.zeros((K, self.max_num_reqs), np.int32)
@@ -1351,13 +1388,10 @@ class TPUModelRunner:
         seeds_e = expand(seeds)
         if self.spec_k:
             seeds_e = seeds_e + 7919 * np.tile(np.arange(S1), R)
-        sampling_md = SamplingMetadata(
-            temperature=jnp.asarray(expand(ib.temperature[rows])),
-            top_k=jnp.asarray(expand(ib.top_k[rows])),
-            top_p=jnp.asarray(expand(ib.top_p[rows])),
-            min_p=jnp.asarray(expand(ib.min_p[rows])),
-            seeds=jnp.asarray(seeds_e),
-        )
+        # Shipped by _launch_sample, once the forward is on the device.
+        sampling_packed = pack_sampling(
+            expand(ib.temperature[rows]), expand(ib.top_k[rows]),
+            expand(ib.top_p[rows]), expand(ib.min_p[rows]), seeds_e)
         ext_md = None
         want_topk = False
         if any(ib.extended_active(r) for r in sampling_rows):
@@ -1391,20 +1425,18 @@ class TPUModelRunner:
                 # across the group masks real samples correctly and the
                 # discarded padding positions don't matter.
                 mask_np = np.repeat(mask_np, S1, axis=0)
-            vocab_mask = jnp.asarray(mask_np)
+            vocab_mask = self._h2d(mask_np)
         tknp = None
         if K > 1:
             tknp = TknpAttentionBatch(
-                slot_mapping=jnp.asarray(tk_slot),
-                block_tables=jnp.asarray(tk_bt),
-                seq_info=jnp.asarray(tk_seq_info),
-                num_seqs=jnp.asarray(tk_num_seqs),
-                kv_runs=jnp.asarray(tk_kv_runs),
-                num_kv_runs=jnp.asarray(tk_num_kv_runs),
-                desc=(jnp.asarray(tk_desc) if attn_desc is not None
-                      else None),
-                decode_list=(jnp.asarray(tk_dl)
-                             if attn_desc is not None else None),
+                slot_mapping=self._h2d(tk_slot),
+                block_tables=self._h2d(tk_bt),
+                seq_info=self._h2d(tk_seq_info),
+                num_seqs=self._h2d(tk_num_seqs),
+                kv_runs=self._h2d(tk_kv_runs),
+                num_kv_runs=self._h2d(tk_num_kv_runs),
+                desc=self._h2d(tk_desc) if P_desc else None,
+                decode_list=self._h2d(tk_dl) if P_desc else None,
             )
         cascade_ids = self._detect_cascade(scheduler_output)
         lora_ctx = None
@@ -1417,11 +1449,11 @@ class TPUModelRunner:
             order = np.argsort(slots, kind="stable")
             S = self.config.lora_config.max_loras + 1
             lora_ctx = LoraBatch(
-                order=jnp.asarray(order.astype(np.int32)),
-                inv=jnp.asarray(np.argsort(order).astype(np.int32)),
-                group_sizes=jnp.asarray(
+                order=self._h2d(order.astype(np.int32)),
+                inv=self._h2d(np.argsort(order).astype(np.int32)),
+                group_sizes=self._h2d(
                     np.bincount(slots, minlength=S)[:S].astype(np.int32)),
-                scaling=jnp.asarray(
+                scaling=self._h2d(
                     self.lora_manager.scaling[slots[order]]),
             )
         # Multimodal: placeholder positions scheduled this step take
@@ -1460,31 +1492,26 @@ class TPUModelRunner:
                         mk[ti] = True
                         break
             if mk.any():
-                mm_embeds = jnp.asarray(ov)
-                mm_mask = jnp.asarray(mk)
+                mm_embeds = self._h2d(ov)
+                mm_mask = self._h2d(mk)
             # else: pure-decode step of an image request — no placeholder
             # positions scheduled; take the text-only graph (no [T, H]
             # upload, no mm-variant compile).
 
-        batch = AttentionBatch(
-            req_idx=jnp.asarray(req_idx),
-            positions=jnp.asarray(positions),
-            slot_mapping=jnp.asarray(slot_mapping),
-            block_tables=jnp.asarray(ib.block_table),
-            seq_lens=jnp.asarray(ib.num_computed),
-            seq_info=jnp.asarray(seq_info),
-            num_seqs=jnp.asarray([num_runs], np.int32),
-            kv_runs=jnp.asarray(kv_runs_arr),
-            num_kv_runs=jnp.asarray([n_kv_runs], np.int32),
+        f["block_tables"][:] = ib.block_table
+        f["seq_lens"][:] = ib.num_computed
+        f["num_seqs"][0] = num_runs
+        f["num_kv_runs"][0] = n_kv_runs
+        inputs = StepInputs.of(
+            self._h2d(buf),
+            layout,
             tknp=tknp,
             lora=lora_ctx,
             cascade_shared_ids=cascade_ids,
             mm_embeds=mm_embeds,
             mm_mask=mm_mask,
-            mrope_positions=(jnp.asarray(mrope_np)
+            mrope_positions=(self._h2d(mrope_np)
                              if mrope_np is not None else None),
-            attn_desc=attn_desc,
-            decode_list=decode_list_arr,
             max_q=max_q,
             attn_bq=bq,
             attn_sb=sb,
@@ -1503,7 +1530,7 @@ class TPUModelRunner:
             tgt_np = np.zeros((Pb, ), np.int32)
             rows_np[:len(plp_rows)] = plp_rows
             tgt_np[:len(plp_targets)] = plp_targets
-            plp = (jnp.asarray(rows_np), jnp.asarray(tgt_np), plp_meta)
+            plp = (self._h2d(rows_np), self._h2d(tgt_np), plp_meta)
         # Verifier truncation only when some batch row needs it (static
         # jit arg: the default-sampling serving case keeps the cheaper
         # untruncated verify graph; padding rows sit at the no-op
@@ -1513,16 +1540,17 @@ class TPUModelRunner:
             or (ib.min_p[rows] > 0.0).any())
         chain = None
         if chain_pos:
-            # Padded to the request bucket; pad positions point one past
-            # the token array so _chain_apply drops them.
+            # Padded to the request bucket. The token ids lead the packed
+            # buffer, so a flat position indexes it as it is; pad
+            # positions point past the buffer's end so _chain_apply
+            # drops them.
             C = pad_to_bucket(len(chain_pos), self.req_buckets)
-            cp = np.full((C, ), T, np.int32)
+            cp = np.full((C, ), layout.size, np.int32)
             cr = np.zeros((C, ), np.int32)
             cp[:len(chain_pos)] = chain_pos
             cr[:len(chain_rows)] = chain_rows
-            chain = (jnp.asarray(cp), jnp.asarray(cr))
-        return (jnp.asarray(token_ids), batch,
-                jnp.asarray(logits_indices), sampling_md,
+            chain = (self._h2d(cp), self._h2d(cr))
+        return (inputs, logits_indices, sampling_packed,
                 sampling_req_ids, (T, max_q, G), R,
                 (drafts_arr, q_ids, q_probs, spec_truncate), ext_md,
                 want_topk, vocab_mask, plp, chain)
@@ -1578,15 +1606,15 @@ class TPUModelRunner:
             vals = np.zeros((R, L), np.int32)
             vals[:len(full_rows)] = ib.token_ids[full_rows]
             self._hist_dev = self._hist_apply_full(self._hist_dev,
-                                              jnp.asarray(fr),
-                                              jnp.asarray(vals))
+                                              self._h2d(fr),
+                                              self._h2d(vals))
         if nd:
             self._hist_dev = self._hist_apply_delta(
-                self._hist_dev, jnp.asarray(d_rows),
-                jnp.asarray(d_start), jnp.asarray(d_toks),
-                jnp.asarray(d_len))
+                self._hist_dev, self._h2d(d_rows),
+                self._h2d(d_start), self._h2d(d_toks),
+                self._h2d(d_len))
         rows_pad = np.asarray(expand(rows), np.int32)
-        return self._hist_dev[jnp.asarray(rows_pad)]
+        return self._hist_dev[self._h2d(rows_pad)]
 
     def _build_extended_md(self, rows: np.ndarray,
                            expand) -> ExtendedSamplingMetadata:
@@ -1622,16 +1650,16 @@ class TPUModelRunner:
                 bias_vals[i, j] = v
         return ExtendedSamplingMetadata(
             hist_tokens=self._hist_rows_device(rows, expand),
-            prompt_len=jnp.asarray(expand(ib.prompt_len[rows])),
-            total_len=jnp.asarray(expand(ib.num_tokens[rows])),
-            presence_penalty=jnp.asarray(expand(ib.presence_penalty[rows])),
-            frequency_penalty=jnp.asarray(expand(
+            prompt_len=self._h2d(expand(ib.prompt_len[rows])),
+            total_len=self._h2d(expand(ib.num_tokens[rows])),
+            presence_penalty=self._h2d(expand(ib.presence_penalty[rows])),
+            frequency_penalty=self._h2d(expand(
                 ib.frequency_penalty[rows])),
-            repetition_penalty=jnp.asarray(expand(
+            repetition_penalty=self._h2d(expand(
                 ib.repetition_penalty[rows])),
-            bias_ids=jnp.asarray(expand(bias_ids)),
-            bias_vals=jnp.asarray(expand(bias_vals)),
-            base_fill=jnp.asarray(expand(base_fill)),
+            bias_ids=self._h2d(expand(bias_ids)),
+            bias_vals=self._h2d(expand(bias_vals)),
+            base_fill=self._h2d(expand(base_fill)),
         )
 
     # ------------------------------------------------------------------
@@ -1694,17 +1722,16 @@ class TPUModelRunner:
             return {"ready": out}
 
         with ph.span("vdt._prepare_inputs", "prepare_inputs") as prep:
-            (token_ids, batch, logits_indices, sampling_md,
-             sampling_req_ids, fwd_shape, R, spec_pack, ext_md, want_topk,
-             vocab_mask, plp, chain) = self._prepare_inputs(
-                 scheduler_output)
+            (inputs, logits_indices, sampling_packed, sampling_req_ids,
+             fwd_shape, R, spec_pack, ext_md, want_topk, vocab_mask, plp,
+             chain) = self._prepare_inputs(scheduler_output)
         prep_s = prep.seconds
         # Launch: from the prepared inputs to the handle (forward and
         # sampler launches, the async chain, state saves).
         with ph.span("vdt.launch", "launch"):
-            attn_label = self._attn_kernel_label(batch)
+            attn_label = self._attn_kernel_label(inputs)
             self._count_attn_dispatch(attn_label)
-            self._count_block_fusion(batch)
+            self._count_block_fusion(inputs)
             perf = self._perf_charge(scheduler_output, attn_label,
                                      fwd_shape[0])
             drafts_arr, q_ids, q_probs, spec_truncate = spec_pack
@@ -1715,9 +1742,9 @@ class TPUModelRunner:
                 # step's _chain_record scatter, so the value is always the
                 # real sampled token by the time the forward reads it.
                 with self.mesh:
-                    token_ids = self._chain_apply(
-                        token_ids, chain[0], self._ensure_last_sampled(),
-                        chain[1])
+                    inputs.packed = self._chain_apply(
+                        inputs.packed, chain[0],
+                        self._ensure_last_sampled(), chain[1])
 
             kv_meta = scheduler_output.kv_connector_metadata
             if self.kv_connector is not None and kv_meta is not None:
@@ -1731,13 +1758,13 @@ class TPUModelRunner:
             # exact for them).
             spec_q = None
             if (self.spec_k and ext_md is None and vocab_mask is None):
-                spec_q = (jnp.asarray(drafts_arr), jnp.asarray(q_ids),
-                          jnp.asarray(q_probs), spec_truncate)
+                spec_q = (self._h2d(drafts_arr), self._h2d(q_ids),
+                          self._h2d(q_probs), spec_truncate)
             ph.launch_issued()
-            dev = self._launch_device_step(token_ids, batch, logits_indices,
-                                           sampling_md, fwd_shape, ext_md,
-                                           want_topk, vocab_mask, plp=plp,
-                                           spec_q=spec_q)
+            dev = self._launch_device_step(inputs, logits_indices,
+                                           sampling_packed, fwd_shape,
+                                           ext_md, want_topk, vocab_mask,
+                                           plp=plp, spec_q=spec_q)
             if self._async_chain and spec_q is None:
                 # Record this step's samples for the next dispatch's chain
                 # (device-to-device; no host sync). Padding rows scatter out
@@ -1748,7 +1775,7 @@ class TPUModelRunner:
                     for r in sampling_req_ids]
                 with self.mesh:
                     self._last_sampled_dev = self._chain_record(
-                        self._ensure_last_sampled(), jnp.asarray(rows_pad),
+                        self._ensure_last_sampled(), self._h2d(rows_pad),
                         dev[0])
             # State snapshots AFTER the forward dispatch: program order on
             # the (donated) cache arrays makes the copy read post-step rows.
@@ -1757,6 +1784,7 @@ class TPUModelRunner:
             # were started pre-forward, so they complete while the device
             # runs the step.
             self._apply_kv_tier_post(tier_pending)
+            self.host_transfers_hist.observe(self._step_transfers)
             return {"so": scheduler_output, "dev": dev, "kv_meta": kv_meta,
                     "sampling_req_ids": sampling_req_ids,
                     "drafts_arr": drafts_arr, "R": R,
@@ -2017,7 +2045,7 @@ class TPUModelRunner:
             if not np.array_equal(ib.block_table[r, :S], first):
                 return None
         self.cascade_steps += 1
-        return jnp.asarray(first)
+        return self._h2d(first)
 
     def _poll_kv_connector(self, scheduler_output: SchedulerOutput,
                            out: ModelRunnerOutput) -> None:
@@ -2038,8 +2066,8 @@ class TPUModelRunner:
             out.finished_recving = recving
             out.failed_recving = failed
 
-    def _launch_device_step(self, token_ids, batch, logits_indices,
-                            sampling_md, fwd_shape, ext_md, want_topk,
+    def _launch_device_step(self, inputs: StepInputs, logits_indices,
+                            sampling_packed, fwd_shape, ext_md, want_topk,
                             vocab_mask=None, plp=None, spec_q=None):
         """Enqueue one step's device work WITHOUT blocking: JAX dispatch
         is asynchronous, so the host returns as soon as the programs are
@@ -2048,26 +2076,31 @@ class TPUModelRunner:
         the oldest, reference core.py:242 step_with_batch_queue); the
         pipeline-parallel runner overrides only the forward half."""
         with self.mesh:
-            cascade = batch.cascade_shared_ids is not None
-            fused = bool(getattr(batch, "block_fused", False))
+            cascade = inputs.rest.cascade_shared_ids is not None
             with self._compile_watch(("fwd", ) + fwd_shape +
-                                     (cascade, fused)):
+                                     (cascade, inputs.rest.block_fused)):
                 self.kv_caches, hidden = self._forward_fn(
-                    self.params, self.kv_caches, token_ids, batch)
-            return self._launch_sample(hidden, logits_indices, sampling_md,
-                                       ext_md, want_topk, self.mesh,
-                                       vocab_mask, plp=plp, spec_q=spec_q)
+                    self.params, self.kv_caches, inputs)
+            return self._launch_sample(hidden, logits_indices,
+                                       sampling_packed, ext_md, want_topk,
+                                       self.mesh, vocab_mask, plp=plp,
+                                       spec_q=spec_q)
 
-    def _launch_sample(self, hidden, logits_indices, sampling_md, ext_md,
-                       want_topk, mesh, vocab_mask=None, plp=None,
+    def _launch_sample(self, hidden, logits_indices, sampling_packed,
+                       ext_md, want_topk, mesh, vocab_mask=None, plp=None,
                        spec_q=None):
         """Row gather + (extended) sampling on ``mesh``, dispatch only;
         shared by the single-program and pipeline-parallel step paths.
-        Returns device arrays (tokens, logprobs, (topv, topi) | None);
-        with ``spec_q`` the first slot instead carries the rejection
-        verifier's output tuple."""
+        ``logits_indices`` and ``sampling_packed`` arrive as host arrays
+        and are shipped here, behind the forward's launch: the device is
+        already at work while they travel. Returns device arrays
+        (tokens, logprobs, (topv, topi) | None); with ``spec_q`` the
+        first slot instead carries the rejection verifier's output
+        tuple."""
         with self.phases.span("vdt._launch_sample"):
             n_rows = logits_indices.shape[0]  # R or R*(S+1) with spec
+            logits_indices = self._h2d(logits_indices)
+            sampling_packed = self._h2d(sampling_packed)
             topk_dev = None
             plp_dev = None
             if plp is not None:
@@ -2098,20 +2131,20 @@ class TPUModelRunner:
                 with self._compile_watch(("specv", n_rows, truncate)):
                     verify = self._spec_verify_fn(
                         self.params, hidden_sel, drafts_d, q_ids_d,
-                        q_probs_d, sampling_md, truncate=truncate)
+                        q_probs_d, sampling_packed, truncate=truncate)
                 return verify, None, None, hidden_sel, plp_dev
             if ext_md is not None:
                 with self._compile_watch(("sampleX", n_rows, want_topk,
                                           vocab_mask is not None)):
                     tokens, logprobs, topv, topi = self._sample_ext_fn(
-                        self.params, hidden_sel, sampling_md, ext_md,
+                        self.params, hidden_sel, sampling_packed, ext_md,
                         want_topk, vocab_mask)
                 if want_topk:
                     topk_dev = (topv, topi)
             else:
                 with self._compile_watch(("sample", n_rows)):
                     tokens, logprobs = self._sample_fn(
-                        self.params, hidden_sel, sampling_md)
+                        self.params, hidden_sel, sampling_packed)
             # hidden_sel rides along for pooling requests (fetched lazily).
             return tokens, logprobs, topk_dev, hidden_sel, plp_dev
 
@@ -2329,7 +2362,7 @@ class TPUModelRunner:
             or getattr(cfg, "alibi", False)
             or getattr(cfg, "attn_sinks", False))
 
-    def _attn_kernel_label(self, batch) -> str:
+    def _attn_kernel_label(self, inputs: StepInputs) -> str:
         """Which attention kernel family this step's batch dispatches to
         (mirrors the ops/attention.py routing, including the feature
         gates that force the XLA path): the vdt:attn_kernel_calls_total
@@ -2339,16 +2372,16 @@ class TPUModelRunner:
         if (resolve_attention_backend() != "pallas"
                 or self._model_routes_xla()):
             return "naive"
-        if getattr(batch, "block_fused", False):
+        if inputs.rest.block_fused:
             return "fused_block"
-        if getattr(batch, "cascade_shared_ids", None) is not None:
+        if inputs.rest.cascade_shared_ids is not None:
             return ("naive" if self._model_has_attn_features()
                     else "cascade")
-        if getattr(batch, "attn_desc", None) is not None:
+        if inputs.layout.desc_programs:
             return "unified"
         if self._model_has_attn_features():
             return "naive"  # descriptor-less legacy path keeps XLA
-        return "decode" if batch.max_q == 1 else "general"
+        return "decode" if inputs.rest.max_q == 1 else "general"
 
     def _count_attn_dispatch(self, label: str) -> None:
         self.attn_kernel_calls[label] = (
@@ -2496,23 +2529,16 @@ class TPUModelRunner:
         return jax.device_put(sel, NamedSharding(mesh or self.mesh,
                                                  PartitionSpec()))
 
-    def _dummy_step_inputs(self, T: int, max_q: int, G: int):
-        """Inert inputs for one forward at shape (T, max_q, G): padding
-        slots (-1) and zero run/seq counts make every write a no-op (an
-        all-noop partition descriptor likewise runs zero programs)."""
+    def _dummy_step_inputs(self, T: int, max_q: int,
+                           G: int) -> StepInputs:
+        """Inert inputs for one forward at shape (T, max_q, G), in the
+        packed signature a served step has: padding slots (-1) and zero
+        run/seq counts make every write a no-op (an all-noop partition
+        descriptor likewise runs zero programs)."""
         K = self.tknp_size
-        attn_desc = decode_list = None
-        bq = sb = 0
-        P_desc = 0
-        if self._use_unified():
-            from vllm_distributed_tpu.ops.pallas_attention import (
-                Q_TILE_PAD, num_partition_programs)
-            bq, sb = self._tile_params()
-            P_desc = num_partition_programs(
-                T - Q_TILE_PAD, self.max_num_reqs, bq=bq, sb=sb,
-                num_kv_writes=G)
-            attn_desc = jnp.zeros((P_desc, 3), jnp.int32)
-            decode_list = jnp.zeros((self.max_num_reqs, ), jnp.int32)
+        layout = self._step_layout(T, G)
+        P_desc = layout.desc_programs
+        bq, sb = self._tile_params() if P_desc else (0, 0)
         tknp = None
         if K > 1:
             tknp = TknpAttentionBatch(
@@ -2525,33 +2551,22 @@ class TPUModelRunner:
                 kv_runs=jnp.zeros((K, G, 4), jnp.int32),
                 num_kv_runs=jnp.zeros((K, 1), jnp.int32),
                 desc=(jnp.zeros((K, P_desc, 3), jnp.int32)
-                      if attn_desc is not None else None),
+                      if P_desc else None),
                 decode_list=(jnp.zeros((K, self.max_num_reqs),
                                        jnp.int32)
-                             if attn_desc is not None else None),
+                             if P_desc else None),
             )
-        batch = AttentionBatch(
-            req_idx=jnp.zeros((T, ), jnp.int32),
-            positions=jnp.zeros((T, ), jnp.int32),
-            slot_mapping=jnp.full((T, ), -1, jnp.int32),
-            block_tables=jnp.zeros(
-                (self.max_num_reqs, self.max_pages_per_req), jnp.int32),
-            seq_lens=jnp.zeros((self.max_num_reqs, ), jnp.int32),
-            seq_info=jnp.zeros((self.max_num_reqs, 4), jnp.int32),
-            num_seqs=jnp.zeros((1, ), jnp.int32),
-            kv_runs=jnp.zeros((G, 4), jnp.int32),
-            num_kv_runs=jnp.zeros((1, ), jnp.int32),
+        return StepInputs.of(
+            jnp.asarray(layout.new_buffer()),
+            layout,
             tknp=tknp,
             lora=self._dummy_lora_batch(T),
             mrope_positions=(jnp.zeros((T, 3), jnp.int32)
                              if self._mrope_on else None),
-            attn_desc=attn_desc,
-            decode_list=decode_list,
             max_q=max_q,
             attn_bq=bq,
             attn_sb=sb,
         )
-        return jnp.zeros((T, ), jnp.int32), batch
 
     def _dummy_lora_batch(self, T: int):
         """Inert LoRA routing for warm-up (all tokens in slot 0): the
@@ -2599,11 +2614,11 @@ class TPUModelRunner:
                                           self.token_buckets)
                             if self._block_fusion_active() else -1)
             for T, max_q, G in sorted(self.forward_shapes()):
-                token_ids, batch = self._dummy_step_inputs(T, max_q, G)
+                inputs = self._dummy_step_inputs(T, max_q, G)
                 with self._compile_watch(("fwd", T, max_q, G, False,
                                           False)):
                     self.kv_caches, hidden = self._forward_fn(
-                        self.params, self.kv_caches, token_ids, batch)
+                        self.params, self.kv_caches, inputs)
                 jax.block_until_ready(hidden)
                 n += 1
                 import dataclasses as _dc
@@ -2611,26 +2626,25 @@ class TPUModelRunner:
                 from vllm_distributed_tpu import envs as _envs
                 from vllm_distributed_tpu.ops.pallas_attention import \
                     Q_TILE_PAD
-                if (max_q == 1 and batch.attn_desc is not None
+                if (max_q == 1 and inputs.layout.desc_programs
                         and 0 <= T - Q_TILE_PAD <= fusion_t_max):
-                    fbatch = _dc.replace(batch, block_fused=True)
                     with self._compile_watch(("fwd", T, max_q, G, False,
                                               True)):
                         self.kv_caches, hidden = self._forward_fn(
-                            self.params, self.kv_caches, token_ids,
-                            fbatch)
+                            self.params, self.kv_caches,
+                            _dc.replace(inputs, rest=_dc.replace(
+                                inputs.rest, block_fused=True)))
                     jax.block_until_ready(hidden)
                     n += 1
                 if _envs.VDT_CASCADE_ATTENTION:
                     S = _envs.VDT_CASCADE_SHARED_PAGES
-                    cbatch = _dc.replace(
-                        batch,
+                    cinputs = _dc.replace(
+                        inputs,
                         cascade_shared_ids=jnp.zeros((S, ), jnp.int32))
                     with self._compile_watch(("fwd", T, max_q, G, True,
                                               False)):
                         self.kv_caches, hidden = self._forward_fn(
-                            self.params, self.kv_caches, token_ids,
-                            cbatch)
+                            self.params, self.kv_caches, cinputs)
                     jax.block_until_ready(hidden)
                     n += 1
             n += self._precompile_samplers(self.mesh)
@@ -2753,15 +2767,11 @@ class TPUModelRunner:
                 jnp.zeros((R, self.spec_k, SUPPORT_K), jnp.float32))
 
     def _sampler_dummy_args(self, rows: int, mesh) -> tuple:
-        """(hidden_sel, sampling md, extended md, vocab mask) for one
-        sampler row bucket."""
-        md = SamplingMetadata(
-            temperature=jnp.zeros((rows, ), jnp.float32),
-            top_k=jnp.zeros((rows, ), jnp.int32),
-            top_p=jnp.ones((rows, ), jnp.float32),
-            min_p=jnp.zeros((rows, ), jnp.float32),
-            seeds=jnp.zeros((rows, ), jnp.int64),
-        )
+        """(hidden_sel, packed sampling md, extended md, vocab mask) for
+        one sampler row bucket."""
+        zeros = np.zeros((rows, ), np.float32)
+        md = jnp.asarray(pack_sampling(zeros, zeros, zeros + 1, zeros,
+                                       zeros))
         hidden_sel = self._gather_sample_rows(
             jnp.zeros((rows, self.model.cfg.hidden_size),
                       self.model.cfg.dtype),
@@ -2819,6 +2829,7 @@ class TPUModelRunner:
         reported by the engine core, which shares ``self.phases``."""
         stats: dict = {
             "step_kv_tokens_read": self.kv_tokens_read_hist.to_dict(),
+            "step_host_transfers": self.host_transfers_hist.to_dict(),
             "num_recompiles": self.num_recompiles,
             # Kernel-dispatch + lattice observability (vdt:attn_kernel_
             # calls_total{kernel} / vdt:precompile_graphs_total).
@@ -2925,10 +2936,10 @@ class TPUModelRunner:
         if self._forward_fn is None:
             self._build_step_fn()
         T, max_q, G = max(self.forward_shapes())
-        token_ids, batch = self._dummy_step_inputs(T, max_q, G)
+        inputs = self._dummy_step_inputs(T, max_q, G)
         with self.mesh:
             lowered = [self._forward_fn.lower(self.params, scratch,
-                                              token_ids, batch)]
+                                              inputs)]
             n_steps = self._multi_step_steps()
             if n_steps > 1:
                 lowered.append(self._multi_step_fn.lower(
@@ -2936,7 +2947,7 @@ class TPUModelRunner:
                         n_steps, self.req_buckets[-1])))
             # The caches are donated: only the returned ones are live.
             scratch, hidden = self._forward_fn(self.params, scratch,
-                                               token_ids, batch)
+                                               inputs)
             jax.block_until_ready(hidden)
         return lowered
 

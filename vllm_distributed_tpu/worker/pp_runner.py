@@ -114,15 +114,21 @@ class PPModelRunner(TPUModelRunner):
     def _build_step_fn(self) -> None:
         model = self.model
 
-        def embed(params, token_ids, positions=None):
-            h = model.embed(params, token_ids, positions)
+        # Embed and every stage take the packed step inputs that
+        # _prepare_inputs returns and unpack them first, like the
+        # single-program forward: one buffer hops to each stage's
+        # sub-mesh instead of a dozen arrays.
+        def embed(params, packed, layout):
+            f = layout.views(packed)
+            h = model.embed(params, f["token_ids"], f["positions"])
             # Replicate INSIDE the jit (GSPMD all-gather over the stage
             # mesh, where collectives are legal) so the inter-stage hop
             # only moves locally-complete values — multi-controller
             # device_put cannot gather across hosts (see _hop).
             return jax.lax.with_sharding_constraint(h, PartitionSpec())
 
-        def stage(layer_params, kv_caches, hidden, batch, first_layer=0):
+        def stage(layer_params, kv_caches, hidden, inputs, first_layer=0):
+            _, batch = inputs.unpack()
             hidden, kv_caches = model.run_layers(layer_params, kv_caches,
                                                  hidden, batch,
                                                  first_layer=first_layer)
@@ -130,7 +136,7 @@ class PPModelRunner(TPUModelRunner):
                 hidden, PartitionSpec())
             return kv_caches, hidden
 
-        self._embed_fn = jax.jit(embed)
+        self._embed_fn = jax.jit(embed, static_argnames=("layout", ))
         self._stage_fn = jax.jit(stage, donate_argnums=(1, ),
                                  static_argnames=("first_layer", ))
         # Base sampler jits (compute_logits + sampling) work unchanged —
@@ -182,14 +188,14 @@ class PPModelRunner(TPUModelRunner):
         return jax.make_array_from_callback(
             hidden.shape, target, lambda idx: local[idx])
 
-    def _launch_device_step(self, token_ids, batch, logits_indices,
-                            sampling_md, fwd_shape, ext_md, want_topk,
+    def _launch_device_step(self, inputs, logits_indices,
+                            sampling_packed, fwd_shape, ext_md, want_topk,
                             vocab_mask=None, plp=None, spec_q=None):
         sm0 = self.stage_meshes[0]
         with global_mesh(sm0), sm0:
             with self._compile_watch(("embed", fwd_shape[0])):
-                hidden = self._embed_fn(self.embed_params, token_ids,
-                                 batch.positions)
+                hidden = self._embed_fn(self.embed_params, inputs.packed,
+                                        layout=inputs.layout)
         for p in range(self.pp):
             sm = self.stage_meshes[p]
             # Activation handoff: ICI/DCN copy to the next stage's
@@ -203,11 +209,11 @@ class PPModelRunner(TPUModelRunner):
                 with self._compile_watch(("stage", p) + fwd_shape):
                     self.kv_caches[p], hidden = self._stage_fn(
                         self.stage_params[p], self.kv_caches[p], hidden,
-                        batch, first_layer=self._stage_first_layer(p))
+                        inputs, first_layer=self._stage_first_layer(p))
         sml = self._head_mesh()
         with global_mesh(sml), sml:
             return self._launch_sample(hidden, logits_indices,
-                                       sampling_md, ext_md, want_topk,
+                                       sampling_packed, ext_md, want_topk,
                                        sml, vocab_mask, plp=plp,
                                        spec_q=spec_q)
 
@@ -220,12 +226,13 @@ class PPModelRunner(TPUModelRunner):
         start = time.perf_counter()
         n = 0
         for T, max_q, G in sorted(self.forward_shapes()):
-            token_ids, batch = self._dummy_step_inputs(T, max_q, G)
+            inputs = self._dummy_step_inputs(T, max_q, G)
             sm0 = self.stage_meshes[0]
             with global_mesh(sm0), sm0:
                 with self._compile_watch(("embed", T)):
-                    hidden = self._embed_fn(self.embed_params, token_ids,
-                                 batch.positions)
+                    hidden = self._embed_fn(self.embed_params,
+                                            inputs.packed,
+                                            layout=inputs.layout)
             n += 1
             for p in range(self.pp):
                 sm = self.stage_meshes[p]
@@ -234,7 +241,7 @@ class PPModelRunner(TPUModelRunner):
                     with self._compile_watch(("stage", p, T, max_q, G)):
                         self.kv_caches[p], hidden = self._stage_fn(
                             self.stage_params[p], self.kv_caches[p],
-                            hidden, batch,
+                            hidden, inputs,
                             first_layer=self._stage_first_layer(p))
                 n += 1
             jax.block_until_ready(hidden)
@@ -269,17 +276,17 @@ class PPModelRunner(TPUModelRunner):
         if self._forward_fn is None:
             self._build_step_fn()
         T, max_q, G = max(self.forward_shapes())
-        token_ids, batch = self._dummy_step_inputs(T, max_q, G)
+        inputs = self._dummy_step_inputs(T, max_q, G)
         sm0 = self.stage_meshes[0]
         with global_mesh(sm0), sm0:
-            hidden = self._embed_fn(self.embed_params, token_ids,
-                                 batch.positions)
+            hidden = self._embed_fn(self.embed_params, inputs.packed,
+                                    layout=inputs.layout)
         lowered = []
         for p in range(self.pp):
             sm = self.stage_meshes[p]
             hidden = self._hop(hidden, sm)
             with global_mesh(sm), sm:
-                args = (self.stage_params[p], scratch[p], hidden, batch)
+                args = (self.stage_params[p], scratch[p], hidden, inputs)
                 first = self._stage_first_layer(p)
                 lowered.append(self._stage_fn.lower(*args,
                                                     first_layer=first))

@@ -1,0 +1,111 @@
+"""One host-to-device transfer per step for the forward's integer inputs.
+
+The model runner writes a step's token ids and every integer field of the
+``AttentionBatch`` into ONE int32 host buffer and ships it with one
+transfer; the jitted programs slice it at static offsets and build the
+``AttentionBatch`` as their first act (``StepInputs.unpack``), so
+``AttentionBatch`` stays the model's interface. The layout is a pure
+function of shapes the forward program is compiled for anyway
+(``StepLayout``), so the packed signature keys exactly the programs the
+per-array signature keyed. Optional fields (token parallelism, LoRA,
+multimodal rows, M-RoPE ids, cascade pages) ride beside the buffer as
+arrays of their own: their steps are rare.
+"""
+
+import dataclasses
+from dataclasses import dataclass
+from functools import cached_property
+
+import jax
+import numpy as np
+
+from vllm_distributed_tpu.models.common import AttentionBatch
+
+
+@dataclass(frozen=True)
+class StepLayout:
+    """Where each field lies in the packed buffer: ``T`` padded tokens,
+    ``G`` KV-write runs, ``max_reqs`` batch rows of ``pages_per_req``
+    pages, ``desc_programs`` rows of the mega-kernel's partition
+    descriptor (0: no descriptor, the legacy dispatch)."""
+
+    T: int
+    G: int
+    max_reqs: int
+    pages_per_req: int
+    desc_programs: int
+
+    @cached_property
+    def fields(self) -> tuple[tuple[str, int, int, tuple[int, ...]], ...]:
+        """(name, start, stop, shape) in buffer order."""
+        T, G, R = self.T, self.G, self.max_reqs
+        shapes = [("token_ids", (T, )), ("req_idx", (T, )),
+                  ("positions", (T, )), ("slot_mapping", (T, )),
+                  ("block_tables", (R, self.pages_per_req)),
+                  ("seq_lens", (R, )), ("seq_info", (R, 4)),
+                  ("num_seqs", (1, )), ("kv_runs", (G, 4)),
+                  ("num_kv_runs", (1, ))]
+        if self.desc_programs:
+            shapes += [("attn_desc", (self.desc_programs, 3)),
+                       ("decode_list", (R, ))]
+        out, start = [], 0
+        for name, shape in shapes:
+            stop = start + int(np.prod(shape))
+            out.append((name, start, stop, shape))
+            start = stop
+        return tuple(out)
+
+    @property
+    def size(self) -> int:
+        return self.fields[-1][2]
+
+    @cached_property
+    def _blank(self) -> np.ndarray:
+        buf = np.zeros((self.size, ), np.int32)
+        self.views(buf)["slot_mapping"][:] = -1   # padding: no KV write
+        return buf
+
+    def new_buffer(self) -> np.ndarray:
+        """An inert step: zero counts, padding slots. A fresh buffer a
+        step, never one reused: a dispatched step may still be reading
+        its buffer (batches in flight; the CPU backend aliases host
+        memory instead of copying it)."""
+        return self._blank.copy()
+
+    def views(self, buf) -> dict:
+        """The fields as reshaped slices of ``buf``: writable views of a
+        host buffer, static slices of a (traced) device one."""
+        return {name: buf[start:stop].reshape(shape)
+                for name, start, stop, shape in self.fields}
+
+
+@dataclass
+class StepInputs:
+    """What ``_prepare_inputs`` hands the forward programs."""
+
+    # [layout.size] int32: token ids and the AttentionBatch's integer
+    # fields (StepLayout.fields).
+    packed: jax.Array
+    # The AttentionBatch less those fields (they are None here): its
+    # optional arrays (None on the default path) and its statics.
+    rest: AttentionBatch
+    # Static: where each field lies in ``packed``.
+    layout: StepLayout
+
+    @classmethod
+    def of(cls, packed, layout: StepLayout, **rest) -> "StepInputs":
+        """``rest``: AttentionBatch's optional fields and statics."""
+        packed_fields = [name for name, *_ in layout.fields[1:]]
+        return cls(packed, AttentionBatch(**dict.fromkeys(packed_fields),
+                                          **rest), layout)
+
+    def unpack(self) -> tuple[jax.Array, AttentionBatch]:
+        """(token_ids, AttentionBatch); inside a jitted program the
+        slices are static and cost nothing."""
+        f = self.layout.views(self.packed)
+        token_ids = f.pop("token_ids")
+        return token_ids, dataclasses.replace(self.rest, **f)
+
+
+jax.tree_util.register_dataclass(
+    StepInputs, data_fields=["packed", "rest"], meta_fields=["layout"])
