@@ -368,6 +368,27 @@ def compare_with_reference(cell: dict, args, scoring: list) -> dict:
     return out
 
 
+def compared_numbers(comparison: dict, in_window: dict) -> list[str]:
+    """Each number that decided ``correct`` beside its limit, one line
+    each: the result line carries them and the run's standard error ends
+    with them."""
+    tol = comparison.get("tolerance")
+    rows = [(f"compiled_in_window.{k}", v, "<=", 0)
+            for k, v in in_window.items()]
+    if tol is None:
+        rows.append(("reference.error", str(comparison.get("error"))[:200],
+                     "==", None))
+    else:
+        for key in ("max", "mean"):
+            rows.append((f"reference.{key}_abs_logprob_dev",
+                         comparison[f"{key}_abs_logprob_dev"], "<=",
+                         tol[key]))
+            rows += [(f"control.{name}.{key}", c[key], ">=", 3 * tol[key])
+                     for name, c in comparison["controls"].items()]
+    return [f"compared {name} {value} limit {op} {limit}"
+            for name, value, op, limit in rows]
+
+
 # ---------------------------------------------------------------------
 def device_facts(chips: int, require_platform: str = "tpu") -> dict:
     import jax
@@ -378,8 +399,7 @@ def device_facts(chips: int, require_platform: str = "tpu") -> dict:
         raise SystemExit(
             f"benchmarks.run: need {chips} {require_platform} device(s), "
             f"found {dev}")
-    if dev["platform"] == "tpu" and dev["kind"] not in load_json(
-            HERE, "peaks.json"):
+    if dev["platform"] == "tpu" and layers.load_peaks(dev["kind"]) is None:
         raise SystemExit(f"benchmarks.run: no peaks for device kind "
                          f"{dev['kind']!r} in benchmarks/peaks.json")
     return dev
@@ -405,7 +425,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     trace_dir = os.path.join(WORK, cell["config"], "trace")
     if trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
-        os.environ["VDT_PROFILE_STEPS"] = "1"
         os.environ["VDT_PROFILER_DIR"] = trace_dir
     device = device_facts(cell["chips"], require_platform)
     run = Run()
@@ -458,11 +477,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         comparison = compare_with_reference(cell, args, scoring)
     emit("reference", **comparison)
     correct = bool(comparison["ok"]) and not any(in_window.values())
+    compared = compared_numbers(comparison, in_window)
 
     if trace:
-        ctx = layers.Context(before=before, after=after, gauges=[
-            metrics.parse_prometheus(text) for _, text in res["gauges"]],
-            client=e2e["metrics"], trace_dir=trace_dir)
+        gauges = [(t, metrics.parse_prometheus(text))
+                  for t, text in res["gauges"]]
+        ctx = layers.Context(
+            before=before, after=after, gauges=[g for _, g in gauges],
+            client=e2e["metrics"], trace_dir=trace_dir,
+            config=cell["config_file"], device_kind=device["kind"],
+            capture=layers.capture_scrapes(gauges, marks))
         wanted = cell_metrics(manifest, workload, "per_layer")
         values = layers.evaluate_all(wanted, ctx)
         device.update(ctx.device_times())
@@ -477,7 +501,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "failed": e2e["failed"],
             "metrics": {k: {"value": v, "unit": units[k]}
                         for k, v in values.items() if v is not None},
-            "device": device}
+            "device": device, "compared": compared}
     if breakdown:
         line["breakdown"] = breakdown
     return line
@@ -537,6 +561,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     print(json.dumps(line), flush=True)
+    print("\n".join(line["compared"]), file=sys.stderr, flush=True)
     return 0
 
 

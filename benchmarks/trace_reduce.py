@@ -8,9 +8,9 @@ chip is a plane ``/device:TPU:<n>``; its line ``XLA Modules`` has one
 event per launched program (``jit_<fn>(<fingerprint>)``), its line
 ``XLA Ops`` one event per operation inside them (fusions, custom calls
 of the Pallas kernels, copies), and ``Steps`` the step markers. Host
-threads are lines of the plane ``/host:CPU``; the program's
-``StepTraceAnnotation("vdt_step")`` shows there as events named
-``vdt_step``. Every plane is on one clock.
+threads are lines of the plane ``/host:CPU``, all named ``python3``; the
+program's ``StepTraceAnnotation("vdt_step")`` and its ``vdt.*`` phases
+show on the engine-core thread's line. Every plane is on one clock.
 """
 
 import glob
@@ -32,14 +32,21 @@ def find_trace(trace_dir: str) -> str | None:
 
 
 def load(path: str) -> dict:
-    """{plane name: {line name: [(event name, start_s, dur_s)]}}."""
+    """{plane name: {line name: [(event name, start_s, dur_s)]}}. A host
+    thread is a line of its own: the threads of one process all carry
+    the process's name (``python3``), so a repeated name of the host
+    plane gets ``#2``, ``#3``. Repeated names of other planes merge."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     planes: dict = {}
     for plane in data.planes:
         lines = planes.setdefault(plane.name, {})
         for line in plane.lines:
-            evs = lines.setdefault(line.name, [])
+            name, n = line.name, 1
+            while plane.name == HOST_PLANE and name in lines:
+                n += 1
+                name = f"{line.name}#{n}"
+            evs = lines.setdefault(name, [])
             for ev in line.events:
                 evs.append((short_name(ev.name), ev.start_ns * 1e-9,
                             ev.duration_ns * 1e-9))
@@ -158,9 +165,9 @@ def top_ops(events, n: int = 10, t0=None, t1=None) -> list:
 def idle_gaps(events, host_events, t0: float, t1: float,
               n: int = 10) -> list:
     """The longest device-idle gaps in [t0, t1], each named by what the
-    host was in at its middle: ``in:<host event>`` (the innermost host
-    event that spans it) or ``between_steps``; summed by name,
-    [name, seconds], longest first."""
+    host thread whose events are given was in at its middle:
+    ``in:<host event>`` (the innermost event that spans it) or
+    ``between_steps``; summed by name, [name, seconds], longest first."""
     busy = merge(intervals(events, t0, t1))
     gaps, prev = [], t0
     for a, b in busy + [(t1, t1)]:

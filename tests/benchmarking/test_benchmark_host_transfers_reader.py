@@ -1,10 +1,10 @@
 """The reader file that ISSUE 26 adds, ``runner.host_transfers_per_step``,
 through ``layers.evaluate_all`` on ``/metrics`` snapshots written by the
 program's own renderer, as ``test_benchmark_step_readers`` does for ISSUE
-25's thirteen. ``BENCHMARK.json`` has no entry for it yet: that file's
-``test_the_manifest_lists_the_thirteen_new_readers_last`` holds the
-thirteen to the END of ``per_layer``, and a PR that may only add to the
-benchmark can neither edit that test nor put an entry in the middle."""
+25's thirteen."""
+
+import json
+import os
 
 import pytest
 
@@ -47,10 +47,21 @@ def test_reader_file_reads_the_window():
 
 
 def test_the_reader_names_a_family_the_program_exports():
-    """The file is ready for the manifest entry that a ``benchmark`` PR
-    adds (``PERF.md`` section 7 says why this PR could not): it has the
-    shape of its neighbours, and the family it names is one that
-    ``/metrics`` renders."""
+    """The file has the shape of its neighbours, the family it names is
+    one that ``/metrics`` renders, and the manifest's entry (ISSUE 27)
+    reports it in the four cells of its day (a later cell adds its name
+    to the list where it wants the count, and need not)."""
+    with open(os.path.join(os.path.dirname(__file__), "..", "..",
+                           "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    (entry, ) = [m for m in manifest["per_layer"] if m["name"] == NAME]
+    assert entry == {"name": NAME, "unit": "count", "better": "lower",
+                     "source": "program_counter", "layer": "model runner",
+                     "moves": "tpot_p50_ms",
+                     "workloads": entry["workloads"]}
+    assert {"olmo2-1b.chat", "olmoe-1b-7b.chat", "olmo2-1b.decode-heavy",
+            "olmoe-1b-7b.decode-heavy"} <= set(entry["workloads"]) <= {
+                w["name"] for w in manifest["workloads"]}
     spec = layers.load_spec(NAME)
     assert set(spec) == {"source", "what"}
     assert spec["source"] == {"kind": "prometheus",

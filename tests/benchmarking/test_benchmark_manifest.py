@@ -1,11 +1,15 @@
 """BENCHMARK.json against the contract's limits and the files it names;
 the tests' own tiny manifest shows that a cell, a configuration and a mix
 are new files and entries only, and that the layer-metric readers serve
-cells they have never heard of."""
+cells they have never heard of; a third manifest, built here from a copy
+of BENCHMARK.json with one of everything APPENDED, is the standing proof
+that an addition edits no file and no entry that is there."""
 
+import importlib
 import json
 import os
 import re
+import shutil
 import subprocess
 import sys
 
@@ -17,19 +21,76 @@ REPO = os.path.normpath(os.path.join(os.path.dirname(__file__), "..", ".."))
 DATA = os.path.join(os.path.dirname(__file__), "data")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-MANIFESTS = [(os.path.join(REPO, "BENCHMARK.json"), run.HERE),
-             (os.path.join(DATA, "manifest.json"), DATA)]
+# (manifest, the directory of cells, configurations and mixes, the
+# directory the manifest's `file` paths start from); "appended" is built
+# by `_append_one_of_everything` in a temporary directory.
+MANIFESTS = {
+    "BENCHMARK.json": (os.path.join(REPO, "BENCHMARK.json"), run.HERE, REPO),
+    "tests-tiny": (os.path.join(DATA, "manifest.json"), DATA, REPO),
+    "appended": None}
+ADDED = {"config": "added-model", "traffic": "added-mix",
+         "cell": "added-model.added-mix", "metric": "added.counter"}
 
 
-@pytest.fixture(params=MANIFESTS, ids=["BENCHMARK.json", "tests-tiny"])
-def manifest(request):
-    path, root = request.param
+def _append_one_of_everything(tmp: str) -> tuple[str, str, str]:
+    """What a later PR does, and all it does: new files beside the old
+    ones and entries at the END of the manifest's lists."""
+    root = os.path.join(tmp, "benchmarks")
+    for d in ("cells", "configs", "traffic", "layer_metrics"):
+        shutil.copytree(os.path.join(run.HERE, d), os.path.join(root, d))
+    before = {d: sorted(os.listdir(os.path.join(root, d)))
+              for d in os.listdir(root)}
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        m = json.load(f)
+
+    def write(kind, name, obj):
+        with open(os.path.join(root, kind, f"{name}.json"), "x") as f:
+            json.dump(obj, f)
+
+    config = dict(run.load_json(run.HERE, "configs", "olmo2-1b.json"),
+                  num_hidden_layers=4, reduced=["num_hidden_layers"],
+                  source="test only: a configuration that a later PR adds")
+    write("configs", ADDED["config"], config)
+    write("traffic", ADDED["traffic"], dict(
+        run.load_json(run.HERE, "traffic", "decode-heavy.json"),
+        output_len={"dist": "uniform", "min": 16, "max": 32}))
+    write("cells", ADDED["cell"], {
+        "config": ADDED["config"], "traffic": ADDED["traffic"], "chips": 1,
+        "load": {"clients": 4}, "why": "test"})
+    write("layer_metrics", ADDED["metric"], {
+        "source": {"kind": "prometheus", "metric": "vdt:added_total",
+                   "reduce": "delta"}, "what": "test"})
+    m["configs"].append({
+        "name": ADDED["config"], "source": config["source"],
+        "file": f"benchmarks/configs/{ADDED['config']}.json",
+        "reduced": config["reduced"], "why": "test"})
+    m["workloads"].append({
+        "name": ADDED["cell"], "config": ADDED["config"],
+        "traffic": ADDED["traffic"], "chips": 1, "why": "test"})
+    m["per_layer"].append({
+        "name": ADDED["metric"], "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "added layer",
+        "moves": "tpot_p50_ms", "workloads": [ADDED["cell"]]})
+    path = os.path.join(tmp, "BENCHMARK.json")
+    with open(path, "w") as f:
+        json.dump(m, f)
+    # Nothing that was there was touched: one new file in each directory.
+    for d, names in before.items():
+        now = sorted(os.listdir(os.path.join(root, d)))
+        assert len(now) == len(names) + 1 and set(names) < set(now)
+    return path, root, tmp
+
+
+@pytest.fixture(params=list(MANIFESTS))
+def manifest(request, tmp_path):
+    path, root, files_from = MANIFESTS[request.param] or \
+        _append_one_of_everything(str(tmp_path))
     with open(path) as f:
-        return json.load(f), root
+        return json.load(f), root, files_from
 
 
 def test_keys_names_units_and_limits(manifest):
-    m, _ = manifest
+    m, root, _ = manifest
     assert set(m) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
     assert 1 <= m["run_seconds"] <= 51 and isinstance(m["run_seconds"], int)
@@ -55,6 +116,10 @@ def test_keys_names_units_and_limits(manifest):
     for x in m["per_layer"]:
         assert set(x) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
+        # BENCHMARK.json names the cells of every per-layer metric, so a
+        # new cell inherits none (the tests' tiny manifest keeps the
+        # entries without a list that the contract also allows).
+        assert "workloads" in x or root == DATA, x["name"]
     assert "setup_s" in [x["name"] for x in m["end_to_end"]]
     for w in m["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
@@ -65,7 +130,7 @@ def test_keys_names_units_and_limits(manifest):
 
 
 def test_every_named_file_exists_and_parses(manifest):
-    m, root = manifest
+    m, root, files_from = manifest
     configs = {c["name"]: c for c in m["configs"]}
     used = set()
     for w in m["workloads"]:
@@ -85,19 +150,25 @@ def test_every_named_file_exists_and_parses(manifest):
         assert traffic.describe(plan)["requests"] > 0
     assert used == set(configs)
     for c in m["configs"]:
-        assert os.path.exists(os.path.join(REPO, c["file"]))
+        assert os.path.exists(os.path.join(files_from, c["file"]))
+    readers = root if os.path.isdir(os.path.join(root, "layer_metrics")) \
+        else run.HERE
     for x in m["per_layer"]:
         # A reader file says how to read and nothing else: unit, layer,
         # `moves` and the cells stand once, in the manifest, so a PR that
-        # adds a cell edits no file that is there. (Both manifests here
-        # read the SAME files under benchmarks/layer_metrics/.)
-        spec = layers.load_spec(x["name"])
+        # adds a cell edits no file that is there. (The tests' tiny
+        # manifest reads the SAME files under benchmarks/layer_metrics/.)
+        spec = layers.load_spec(x["name"], readers)
         assert set(spec) == {"source", "what"}, x["name"]
         assert spec["source"]["kind"] in layers.READERS
+        if spec["source"].get("reduce") == "roofline_share":
+            work = importlib.import_module(
+                f"benchmarks.rooflines.{spec['source']['work']}").work
+            assert callable(work) and work.__doc__
 
 
 def test_moves_is_reported_wherever_the_layer_metric_is(manifest):
-    m, _ = manifest
+    m, _, _ = manifest
     cells = [w["name"] for w in m["workloads"]]
     e2e = {x["name"]: x.get("workloads", cells) for x in m["end_to_end"]}
     for x in m["per_layer"]:

@@ -6,6 +6,7 @@ no harness code. (The command line itself refuses the CPU; the tests call
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -43,7 +44,11 @@ def test_run_cell_end_to_end_on_cpu(cell, trace, wants, tmp_path):
     lines = [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
     last = json.loads(lines[-1])
     assert set(last) <= {"correct", "attempted", "failed", "metrics",
-                         "device", "breakdown"}
+                         "device", "breakdown", "compared"}
+    # Each number that decided `correct` stands beside its limit.
+    assert len(last["compared"]) >= 5 and all(
+        re.match(r"^compared \S+ \S+ limit (<=|>=) \S+$", c)
+        for c in last["compared"]), last["compared"]
     assert last["correct"] is True, lines[-3:]
     assert last["attempted"] > 0 and last["failed"] == 0
     assert wants <= set(last["metrics"]), last["metrics"]

@@ -47,6 +47,31 @@ def test_idle_gaps_are_named_by_what_the_host_was_in():
     assert list(inner) == ["in:$model_runner.py:1078 _prepare_inputs"]
 
 
+def test_a_gap_is_named_by_the_engine_core_thread_s_innermost_span():
+    """Two host threads: the API thread's short `vdt.process_outputs`
+    covers the gap's middle too, and is not what the chip waits for."""
+    engine = [("vdt_step", 3.5, 2.0), ("vdt.dispatch_model", 3.6, 1.8),
+              ("vdt.launch", 4.4, 0.9), ("vdt.loop", 7.0, 2.5),
+              ("vdt_step", 9.5, 0.4), ("PjitFunction(add)", 4.9, 0.2)]
+    api = [("vdt.process_outputs", 4.8, 0.4),
+           ("vdt.process_outputs", 8.4, 0.2)]
+    ctx = layers.Context([], [], [], {}, "/nonexistent")
+    ctx._planes = {"/device:TPU:0": {"XLA Ops": OPS},
+                   "/host:CPU": {"python3": api, "python3#2": engine}}
+    # Gap 4-6: middle 5.0, in `vdt.launch` (JAX's own event there is no
+    # span of the program's and names nothing); the span ends at 7.0.
+    assert dict(ctx.breakdown()["idle_gaps"]) == {
+        "in:vdt.launch": pytest.approx(2.0)}
+    assert ctx.steps() == 2
+    gaps = dict(tr.idle_gaps(OPS, ctx.engine_thread(), 0.0, 10.0))
+    assert gaps == {"in:vdt.launch": pytest.approx(2.0),
+                    "in:vdt.loop": pytest.approx(3.0)}
+    for name in ("vdt.launch", "vdt.device_wait", "vdt.finish", "vdt.loop",
+                 "vdt._update_states", "vdt._prepare_inputs",
+                 "vdt.process_outputs", "vdt_step"):
+        assert tr.matching([(name, 0, 1)], layers.HOST_SPANS), name
+
+
 def test_plane_selection_and_event_names():
     planes = {"/device:TPU:0": {"XLA Ops": OPS}, "/device:TPU:1": {},
               "/host:CPU": {"python3": HOST}, "#Chip0 Misc": {},
@@ -87,9 +112,18 @@ def test_load_reads_a_trace_recorded_from_a_cpu_jit(tmp_path):
     f = jax.jit(lambda x: (x @ x).sum())
     x = jnp.ones((64, 64))
     f(x).block_until_ready()
+    import threading
+
+    def api_thread():
+        with jax.profiler.TraceAnnotation("vdt.process_outputs"):
+            f(x).block_until_ready()
+
     jax.profiler.start_trace(str(tmp_path))
     with jax.profiler.StepTraceAnnotation("vdt_step", step_num=0):
         f(x).block_until_ready()
+    other = threading.Thread(target=api_thread)
+    other.start()
+    other.join(60)
     jax.profiler.stop_trace()
     path = tr.find_trace(str(tmp_path))
     assert path and path.endswith(".xplane.pb")
@@ -98,6 +132,12 @@ def test_load_reads_a_trace_recorded_from_a_cpu_jit(tmp_path):
     steps = [e for line in planes[tr.HOST_PLANE].values()
              for e in tr.matching(line, r"^vdt_step")]
     assert steps and all(d > 0 for _, _, d in steps)
+    # Two threads of one process: two lines of one name, kept apart.
+    mine = [name for name, line in planes[tr.HOST_PLANE].items()
+            if tr.matching(line, r"^vdt")]
+    assert len(mine) == 2 and len({n.partition("#")[0] for n in mine}) == 1
+    ctx = layers.Context([], [], [], {}, str(tmp_path))
+    assert [e[0] for e in ctx.engine_thread()] == ["vdt_step"]
     # The CPU has no device plane: every trace reader then reads nothing.
     assert tr.device_planes(planes) == []
     ctx = layers.Context([], [], [], {}, str(tmp_path))
