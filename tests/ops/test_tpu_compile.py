@@ -256,6 +256,33 @@ def test_mla_latent_attention_compiles(compile_for_chip, monkeypatch,
                      sds((1, )), sds((R, PAGES_PER_REQ)), sds((1, )))
 
 
+@pytest.mark.parametrize("max_q,T", [(1, 65), (2048, 2048 + 128)])
+def test_mla_latent_attention_compiles_at_published_widths(
+        compile_for_chip, monkeypatch, max_q, T):
+    """GigaChat3 / DeepSeek-V3 widths as `gigachat3-702b-ep16` serves
+    them: 64 heads, 576 -> 640 stored lanes, page 16, block tables of
+    512 pages (max_model_len 8192), a decode batch and a 2048-token
+    chunk; and the custom call carries the kernel's own name."""
+    from vllm_distributed_tpu.ops import mla
+    from vllm_distributed_tpu.ops.pallas_mla import (
+        KERNEL_NAME, ragged_latent_attention_pallas)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lkv, rope, heads = 512, 64, 64
+    cs = mla.latent_storage_dim(lkv, rope)
+    assert cs == 640
+
+    def fn(q, c, si, ns, bt, layer):
+        return ragged_latent_attention_pallas(
+            q, c, si, ns, bt, layer, sm_scale=0.1, max_q=max_q,
+            kv_lora_rank=lkv, rope_dim=rope, interpret=False)
+
+    compiled = compile_for_chip(
+        fn, sds((T, heads, cs), jnp.bfloat16),
+        sds((6, 26000, PS, cs), jnp.bfloat16), sds((R, 4)), sds((1, )),
+        sds((R, 512)), sds((1, )))
+    assert f"%{KERNEL_NAME}" in compiled.as_text()
+
+
 @pytest.mark.xfail(
     strict=True,
     reason="Mosaic failed to compile TPU kernel: infer-vector-layout: "

@@ -198,6 +198,10 @@ LABELED_METRICS = {
     # TPLA latent-pool geometry (ops/mla.py; MLA models only).
     "vdt:tpla_latent_shards": ("worker", ),
     "vdt:mla_latent_page_bytes": ("worker", ),
+    "vdt:mla_latent_token_bytes": ("worker", ),
+    # One chip's share of an expert layer (models/deepseek.py).
+    "vdt:moe_experts_held": ("worker", ),
+    "vdt:moe_experts_published": ("worker", ),
     # Performance-attribution plane (metrics/costmodel.py): per-worker
     # utilization ratios, analytic HBM traffic by kind, and the
     # per-phase roofline placement.
@@ -463,6 +467,15 @@ def _render_worker_telemetry(workers: dict) -> list[str]:
         ("mla_latent_page_bytes", "vdt:mla_latent_page_bytes", "gauge",
          "Per-rank HBM bytes one MLA latent page costs (1/TP of the "
          "replicated row under TPLA, plus the rope sidecar)"),
+        ("mla_latent_token_bytes", "vdt:mla_latent_token_bytes", "gauge",
+         "HBM bytes of one token's latent rows over every layer: what "
+         "a token read by vdt:step_kv_tokens_read costs an MLA model"),
+        ("moe_experts_held", "vdt:moe_experts_held", "gauge",
+         "Routed experts whose weights this worker holds under an "
+         "expert share (config.json expert_share)"),
+        ("moe_experts_published", "vdt:moe_experts_published", "gauge",
+         "Routed experts the router scores under an expert share: the "
+         "published count of the deployment this worker is one chip of"),
         # Performance-attribution plane: this worker's analytic FLOPs
         # / bytes over its measured device time against its mesh peak.
         ("mfu", "vdt:mfu", "gauge",

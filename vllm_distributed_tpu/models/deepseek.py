@@ -23,8 +23,33 @@ de-interleaved rope is score-equivalent to the V2 complex form because
 the same permutation hits q and k; see models/common.py
 apply_rope_pairwise).
 
-Not wired in this round (rejected at load with clear errors): token
-parallelism, LoRA, quantization, and EPLB redundancy for this family.
+**One chip's share of an expert layer** — ``expert_share`` in
+``config.json`` (``{"published_experts": 256, "chips": 16, "index": 0}``)
+says that ``n_routed_experts`` counts the experts HELD here, share
+``index`` of a deployment in which ``chips`` chips divide each layer's
+``published_experts``. The router and its correction bias keep the
+published width; group selection and top-k run over all of it; picks
+that land on absent experts contribute nothing; the shared expert is
+computed whole. That partial sum is the layer's output: what
+``_moe_ep_ragged``'s ``rank_fn`` computes on one rank, without its
+``psum``. Nothing stands in for the absent chips.
+
+**Expert weights are per-layer leaves** — ``w_gate`` / ``w_up`` /
+``w_down`` are tuples of ``[E, H, Im]`` arrays, one a MoE layer, and
+the MoE segment of ``run_layers`` is unrolled: ``jax.lax.ragged_dot``
+then reads its layer's buffer in place. A slice of a layer-stacked
+operand, static or dynamic, is materialised as a copy of the whole
+slab for it (PERF.md, findings of PR 28). Everything else stays
+stacked; the dense segment stays a scan.
+
+The multi-token-prediction module (``num_nextn_predict_layers``) is a
+draft head that does not enter the next-token logits; its weights are
+not loaded (as transformers' own DeepseekV3 drops them).
+
+Not wired (rejected at load with clear errors): token parallelism,
+LoRA, quantization and EPLB redundancy for this family; and with an
+expert share: ``enable_expert_parallel`` (the share IS one rank of it),
+TPLA and pipeline parallelism (models/loader.py).
 """
 
 from typing import Optional
@@ -87,6 +112,24 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
         arch.n_group = getattr(hf, "n_group", 1) or 1
         arch.topk_group = getattr(hf, "topk_group", 1) or 1
         arch.norm_topk_prob = bool(getattr(hf, "norm_topk_prob", False))
+        # Router width: the published expert count under an expert share
+        # (module docstring), else the experts there are.
+        arch.router_experts = arch.num_experts
+        arch.experts_held = None
+        share = getattr(hf, "expert_share", None)
+        if share:
+            published, chips, index = (int(share[k]) for k in (
+                "published_experts", "chips", "index"))
+            if (published != arch.num_experts * chips
+                    or not 0 <= index < chips):
+                raise ValueError(
+                    f"expert_share {dict(share)} does not describe "
+                    f"n_routed_experts={arch.num_experts} held experts: "
+                    "published_experts must be n_routed_experts x chips "
+                    "and index one of the chips")
+            arch.router_experts = published
+            arch.experts_held = (index * arch.num_experts,
+                                 arch.num_experts)
         if getattr(hf, "moe_layer_freq", 1) not in (None, 1):
             raise ValueError("DeepSeek moe_layer_freq != 1 layouts are "
                              "not supported")
@@ -166,14 +209,16 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
             layer["router"] = P(None, None, None)
             if self.SCORING == "sigmoid":
                 layer["router_bias"] = P(None, None)
+            # Per-layer leaves [E, H, Im] (module docstring): one spec
+            # stands for every leaf of the tuple (a pytree prefix).
             if c.expert_parallel:
-                ffn = P(None, MODEL_AXIS, None, None)
+                ffn = P(MODEL_AXIS, None, None)
                 layer.update({"w_gate": ffn, "w_up": ffn, "w_down": ffn})
             else:
                 layer.update({
-                    "w_gate": P(None, None, None, MODEL_AXIS),
-                    "w_up": P(None, None, None, MODEL_AXIS),
-                    "w_down": P(None, None, MODEL_AXIS, None),
+                    "w_gate": P(None, None, MODEL_AXIS),
+                    "w_up": P(None, None, MODEL_AXIS),
+                    "w_down": P(None, MODEL_AXIS, None),
                 })
             if c.shared_expert_intermediate_size:
                 layer.update({
@@ -208,11 +253,13 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
         N = c.num_q_heads
         Pn, R, V = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
         Lkv = c.kv_lora_rank
-        keys = iter(jax.random.split(rng, 24))
+        nd, nm = self._n_dense, self._n_moe
+        keys = iter(jax.random.split(rng, 24 + 3 * nm))
 
         def norm(shape):
-            return (scale * jax.random.normal(next(keys), shape,
-                                              jnp.float32)).astype(c.dtype)
+            # Drawn in the serving dtype: a float32 draw of one layer's
+            # expert slab alone is 0.94 GB at the 702B widths.
+            return scale * jax.random.normal(next(keys), shape, c.dtype)
 
         layer: dict = {
             "input_ln": jnp.ones((L, H), c.dtype),
@@ -231,7 +278,6 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
             })
         else:
             layer["wq"] = norm((L, H, N * (Pn + R)))
-        nd, nm = self._n_dense, self._n_moe
         if nd:
             I = c.intermediate_size
             layer.update({
@@ -243,13 +289,21 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
             E = c.num_experts
             Im = c.moe_intermediate_size or c.intermediate_size
             layer.update({
-                "router": norm((nm, H, E)),
-                "w_gate": norm((nm, E, H, Im)),
-                "w_up": norm((nm, E, H, Im)),
-                "w_down": norm((nm, E, Im, H)),
+                "router": norm((nm, H, c.router_experts)),
+                "w_gate": tuple(norm((E, H, Im)) for _ in range(nm)),
+                "w_up": tuple(norm((E, H, Im)) for _ in range(nm)),
+                "w_down": tuple(norm((E, Im, H)) for _ in range(nm)),
             })
             if self.SCORING == "sigmoid":
-                layer["router_bias"] = jnp.zeros((nm, E), jnp.float32)
+                # Seeded, not zero: with a zero bias the choice and the
+                # weights read the same scores. Small, as a trained
+                # balancing bias is: sigmoid scores of a seeded router
+                # crowd near 1 at the top, and a bias of 0.1 there sends
+                # half the picks to the same hundred experts (105 of 256
+                # hit by 512 picks, against 190 at 0.02 and 215 at 0;
+                # PERF.md, findings of PR 28).
+                layer["router_bias"] = 0.25 * scale * jax.random.normal(
+                    next(keys), (nm, c.router_experts), jnp.float32)
             Is = c.shared_expert_intermediate_size
             if Is:
                 layer.update({
@@ -336,9 +390,10 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
                 for i in moe:
                     mats = [t(M.format(i) + f"experts.{e}.{proj}.weight")
                             for e in range(E)]
-                    per_layer.append(np.stack(
-                        [m.T if transpose else m for m in mats]))
-                return jnp.asarray(np.stack(per_layer), dtype=c.dtype)
+                    per_layer.append(jnp.asarray(np.stack(
+                        [m.T if transpose else m for m in mats]),
+                        dtype=c.dtype))
+                return tuple(per_layer)
 
             layer["w_gate"] = stack_experts("gate_proj")
             layer["w_up"] = stack_experts("up_proj")
@@ -427,7 +482,7 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
         c = self.cfg
         T = x.shape[0]
         k = c.num_experts_per_tok
-        E = c.num_experts
+        E = c.router_experts
         logits = (x.astype(jnp.float32)
                   @ lp["router"].astype(jnp.float32))  # [T, E]
         if self.SCORING == "sigmoid":
@@ -471,6 +526,40 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
         gmask = jnp.zeros((T, G), bool).at[
             jnp.arange(T)[:, None], gidx].set(True)
         return jnp.repeat(gmask, E // G, axis=-1)
+
+    def moe_dispatch(self, lp: dict, x: jax.Array, top_idx: jax.Array,
+                     top_vals: jax.Array) -> jax.Array:
+        """Without an expert share: the Mixtral dispatch. With one
+        (module docstring): the picks among the held experts
+        ``[lo, lo + n)`` sort to the front by local id, the rest into a
+        virtual group ``n`` that ``group_sizes`` leaves out:
+        ``_moe_ep_ragged``'s ``rank_fn`` on one rank, without the
+        ``psum``. ``ragged_dot`` does NOT zero the rows past its groups
+        on the TPU (they come back uninitialised, NaN among them, as
+        measured on a v5e in PR 28), so those rows are selected away,
+        not multiplied by a weight of 0."""
+        held = self.cfg.experts_held
+        if held is None:
+            return super().moe_dispatch(lp, x, top_idx, top_vals)
+        lo, n = held
+        T, k = top_idx.shape
+        with jax.named_scope("moe_dispatch"):
+            local = top_idx.astype(jnp.int32).reshape(-1) - lo
+            is_held = (local >= 0) & (local < n)
+            local = jnp.where(is_held, local, n)
+            order = jnp.argsort(local, stable=True)
+            st = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)[order]
+            sw = top_vals.reshape(-1)[order]
+            xs = x[st]
+            group_sizes = jnp.bincount(local, length=n + 1)[:n]
+            # Held picks sort first: rows [0, their count).
+            in_group = jnp.arange(T * k) < group_sizes.sum()
+        y = self._expert_ffn(lp, xs, group_sizes)
+        with jax.named_scope("moe_combine"):
+            y = jnp.where(in_group[:, None],
+                          y * sw[:, None].astype(y.dtype), 0)
+            out = jax.ops.segment_sum(y, st, num_segments=T)
+        return out.astype(x.dtype)
 
     def mlp_block(self, lp: dict, x: jax.Array,
                   lora_ctx=None) -> jax.Array:
@@ -587,36 +676,42 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
         nd_local = max(
             0, min(first_layer + num_layers, self._n_dense) - first_layer)
 
-        def seg_scan(carry, seg_start, seg_len, kind):
+        def layer_body(kind, car, a_lp, m_lp, layer_idx):
+            h, caches = car
+            h, caches = attn_block(a_lp, h, caches, layer_idx)
+            x2 = rms_norm(h, a_lp["post_ln"], c.rms_norm_eps)
+            if kind == "dense":
+                return h + LlamaForCausalLM.mlp_block(self, m_lp, x2), caches
+            return h + self.mlp_block(m_lp, x2), caches
+
+        def dense_scan(carry, seg_len):
             if seg_len == 0:
                 return carry
-            attn_lp = {k: layer_params[k][seg_start:seg_start + seg_len]
-                       for k in attn_keys}
+            attn_lp = {k: layer_params[k][:seg_len] for k in attn_keys}
             # Dense/MoE stacks are indexed in their OWN depth space and
             # slice_layer_params already rebased them per stage, so each
             # kind's stack starts at 0 locally.
             mlp_lp = {k: layer_params[k][:seg_len]
-                      for k in mlp_keys[kind]}
-            ids = jnp.arange(seg_start, seg_start + seg_len,
-                             dtype=jnp.int32)[:, None]
+                      for k in mlp_keys["dense"]}
+            ids = jnp.arange(seg_len, dtype=jnp.int32)[:, None]
 
             def body(car, xs):
-                h, caches = car
-                a_lp, m_lp, layer_idx = xs
-                h, caches = attn_block(a_lp, h, caches, layer_idx)
-                x2 = rms_norm(h, a_lp["post_ln"], c.rms_norm_eps)
-                if kind == "dense":
-                    mlp_out = LlamaForCausalLM.mlp_block(self, m_lp, x2)
-                else:
-                    mlp_out = self.mlp_block(m_lp, x2)
-                return (h + mlp_out, caches), None
+                return layer_body("dense", car, *xs), None
 
             carry, _ = jax.lax.scan(body, carry, (attn_lp, mlp_lp, ids))
             return carry
 
         carry = (hidden, dict(kv_caches))
-        carry = seg_scan(carry, 0, nd_local, "dense")
-        carry = seg_scan(carry, nd_local, num_layers - nd_local, "moe")
+        carry = dense_scan(carry, nd_local)
+        # The MoE segment is unrolled: each layer's expert weights are
+        # leaves of their own, which a scan cannot index and which
+        # ragged_dot then reads in place (module docstring).
+        for j in range(num_layers - nd_local):
+            i = nd_local + j
+            carry = layer_body(
+                "moe", carry, {k: layer_params[k][i] for k in attn_keys},
+                {k: layer_params[k][j] for k in mlp_keys["moe"]},
+                jnp.full((1, ), i, jnp.int32))
         hidden, caches = carry
         return hidden, caches
 

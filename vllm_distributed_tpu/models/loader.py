@@ -294,6 +294,23 @@ def get_model(config: EngineConfig, mesh,
                     "lanes replicated)", mla_tp,
                     arch.kv_lora_rank // mla_tp, arch.kv_lora_rank,
                     arch.qk_rope_head_dim)
+    if getattr(arch, "experts_held", None) is not None:
+        # One chip's share of an expert layer (models/deepseek.py): the
+        # program computes that share and nothing of the other chips'.
+        pc = config.parallel_config
+        for bad, what in (
+                (pc.enable_expert_parallel,
+                 "enable_expert_parallel (the share is one rank of an "
+                 "expert-parallel deployment already)"),
+                (getattr(arch, "tpla_shards", 1) > 1,
+                 "TPLA (set VDT_TPLA=0 or tensor_parallel_size=1)"),
+                (pc.pipeline_parallel_size > 1,
+                 "pipeline parallelism (the layers left out of the "
+                 "share's file are the further stages)")):
+            if bad:
+                raise ValueError(
+                    f"expert_share (config.json) cannot be combined "
+                    f"with {what}")
     # KV-head replication when TP exceeds the checkpoint's KV-head count
     # (reference: QKVParallelLinear kv replication, layers/linear.py):
     # repeat heads to the lcm so the kv-head dim divides the model axis.

@@ -287,8 +287,10 @@ class MixtralForCausalLM(LlamaForCausalLM):
     def _expert_ffn(self, lp: dict, xs: jax.Array,
                     group_sizes: jax.Array) -> jax.Array:
         """SwiGLU over expert-sorted rows: three grouped GEMMs. Rows
-        beyond sum(group_sizes) come back zero (ragged_dot semantics) —
-        the EP path exploits that for its padding."""
+        beyond sum(group_sizes) come back zero on the CPU and
+        UNINITIALISED on the TPU (measured on a v5e, PR 28: NaN among
+        them): a caller that leaves rows out of the groups selects
+        them away afterwards."""
         with jax.named_scope("moe_gate_gemm"):
             g = jax.nn.silu(jax.lax.ragged_dot(
                 xs, self._w(lp, "w_gate"), group_sizes))
@@ -456,13 +458,14 @@ class MixtralForCausalLM(LlamaForCausalLM):
             xs_l = xs_[part]
             local_ids = jnp.where(is_local[part], se_[part] - lo, E_local)
             # Foreign rows bucket into a virtual group E_local that is
-            # dropped from group_sizes; ragged_dot then zero-fills them.
+            # dropped from group_sizes; ragged_dot leaves their rows
+            # uninitialised on the TPU, so they are selected away.
             group_sizes = jnp.bincount(local_ids, length=E_local + 1)[:-1]
-            w = jnp.where(is_local[part], sw_[part], 0.0)
             g = jax.nn.silu(jax.lax.ragged_dot(xs_l, w_gate, group_sizes))
             u = jax.lax.ragged_dot(xs_l, w_up, group_sizes)
             y = jax.lax.ragged_dot(g * u, w_down, group_sizes)
-            y = y * w[:, None].astype(y.dtype)
+            y = jnp.where(is_local[part][:, None],
+                          y * sw_[part][:, None].astype(y.dtype), 0)
             y = y[jnp.argsort(part)]  # back to expert-sorted order
             from vllm_distributed_tpu.parallel import collectives
             return collectives.psum(y, MODEL_AXIS, path="ep")

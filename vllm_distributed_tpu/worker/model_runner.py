@@ -2860,6 +2860,19 @@ class TPUModelRunner:
                 getattr(self.model.cfg, "tpla_shards", 1) or 1)
             stats["mla_latent_page_bytes"] = int(
                 self.model.kv_cache_page_bytes(self.page_size))
+            # One token's latent row over every layer (7 680 B where an
+            # OLMo-2 token costs 128 KiB): what vdt:step_kv_tokens_read
+            # is multiplied by for this family.
+            stats["mla_latent_token_bytes"] = (
+                stats["mla_latent_page_bytes"] // self.page_size)
+            held = getattr(self.model.cfg, "experts_held", None)
+            if held is not None:
+                # One chip's share of an expert layer
+                # (models/deepseek.py): experts whose weights are here,
+                # of the experts the router scores.
+                stats["moe_experts_held"] = int(held[1])
+                stats["moe_experts_published"] = int(
+                    self.model.cfg.router_experts)
         cm = self._cost_model()
         if cm is not None and self._perf_dispatches:
             # Performance-attribution plane: analytic totals + the

@@ -270,7 +270,9 @@ class TPUWorker:
         # memory peaks (union merge, never summed).
         for key in ("device_wait_seconds", "device_memory_peak_bytes",
                     "device_memory_in_use_bytes", "tpla_latent_shards",
-                    "mla_latent_page_bytes", "mfu", "mbu"):
+                    "mla_latent_page_bytes", "mla_latent_token_bytes",
+                    "moe_experts_held", "moe_experts_published", "mfu",
+                    "mbu"):
             if key in stats:
                 per_worker[key] = stats.pop(key)
         if "num_recompiles" in stats:
