@@ -261,6 +261,11 @@ class GptOssForCausalLM(MixtralForCausalLM):
     tensors, per-expert biases and the clamped (up+1)*glu activation
     (reference: models/gpt_oss.py)."""
 
+    # The per-expert biases ride on the expert weights into the serving
+    # layout (models/mixtral.py).
+    _PER_LAYER = MixtralForCausalLM._PER_LAYER + ("b_gate", "b_up",
+                                                  "b_down")
+
     @classmethod
     def configure_arch(cls, arch: LlamaArchConfig, hf) -> None:
         arch.num_experts = hf.num_local_experts

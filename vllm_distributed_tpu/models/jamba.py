@@ -48,6 +48,9 @@ class JambaForCausalLM(MixtralForCausalLM):
 
     QUANT_TARGETS = ()
     LORA_TARGETS = ()
+    # The serving layout's per-layer buffers under this tree's names
+    # (models/mixtral.py); run_layers' sub() indexes them by position.
+    _PER_LAYER = ("e_w_gate", "e_w_up", "e_w_down")
     STATEFUL = True
     # Hybrid stack: state restores must re-enter coherently with the
     # attention layers' cached KV pages (core/state_cache.py requires

@@ -11,8 +11,21 @@ per shape.
 
 Weight layout mirrors HF checkpoint tensors transposed to right-multiply
 form (x @ W), stacked on a leading layer axis.
+
+**Two layouts of one tree.** The loader's tree (``get_model``, the
+state-dict mappers, quantization, EPLB, pipeline slicing) is the
+canonical one: every per-layer tensor stacked ``[L, ...]``. What the
+runner hands the jitted programs is ``serving_layout`` of it: the same
+tree for every dense model, and for the Mixtral family the expert
+weights as tuples of per-layer buffers (models/mixtral.py says why).
+``run_layers`` scans whichever it is given. Tuple leaves stay outside
+the scanned tree, as constants of the scan, and the one consumer that
+wants them, the MLP block, runs under a ``lax.switch`` on the layer
+index with one branch a layer, each reading its own layer's buffers
+(PERF.md, Findings PR 28 and PR 29).
 """
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -846,6 +859,19 @@ class LlamaForCausalLM:
         models whose stacks have per-kind depths override (deepseek)."""
         return {k: v[start:end] for k, v in layers.items()}
 
+    def serving_layout(self, layers: dict) -> dict:
+        """The layer tree as the jitted serving programs take it, made
+        from the canonical stacked one wherever the runner takes or
+        re-places weights. A dense model serves from the stacks as they
+        are; families whose kernels want a layer's weights as a buffer
+        of its own override (models/mixtral.py)."""
+        return layers
+
+    def canonical_layout(self, layers: dict) -> dict:
+        """Inverse of ``serving_layout``, on the host: the tree as the
+        loader returns it and a sharded-state checkpoint stores it."""
+        return layers
+
     def make_kv_caches(self, num_pages: int, page_size: int,
                        cache_dtype=None,
                        num_layers: Optional[int] = None) -> dict:
@@ -1173,10 +1199,12 @@ class LlamaForCausalLM:
         cache_layer_offset: int = 0,
     ) -> tuple[jax.Array, dict]:
         """Run a contiguous slice of decoder layers over the hidden
-        states. ``layer_params`` is a stacked [Ls, ...] subtree and
-        ``kv_caches`` that slice's own [Ls, ...] cache — under pipeline
-        parallelism each stage calls this with its local slice
-        (reference: the per-stage module list built by get_pp_indices,
+        states. ``layer_params`` is a stacked [Ls, ...] subtree (in a
+        serving layout some leaves are tuples of Ls buffers: they stay
+        out of the scan, below) and ``kv_caches`` that slice's own
+        [Ls, ...] cache — under pipeline parallelism each stage calls
+        this with its local slice (reference: the per-stage module list
+        built by get_pp_indices,
         distributed/utils.py:89). ``first_layer`` is the slice's global
         offset, selecting the right rows of mixed window layouts
         (static — PP keys its stage jit on it for patterned models).
@@ -1189,6 +1217,15 @@ class LlamaForCausalLM:
             sm_scale = c.sm_scale_override
         else:
             sm_scale = (c.query_pre_attn_scalar or c.head_dim) ** -0.5
+        # A tuple leaf is one buffer a layer (serving_layout): it is not
+        # scanned. A slice of a stack, a scan's dynamic one or an
+        # unrolled loop's static one, is copied whole for a custom-call
+        # consumer like ragged_dot (PERF.md, Findings PR 28), so those
+        # buffers reach mlp_block as they are (``mlp`` below).
+        per_layer = {k: v for k, v in layer_params.items()
+                     if isinstance(v, tuple)}
+        layer_params = {k: v for k, v in layer_params.items()
+                        if k not in per_layer}
         num_layers = jax.tree_util.tree_leaves(layer_params)[0].shape[0]
         rd = c.rotary_dim or c.head_dim
         if c.pos_embedding != "rope":
@@ -1314,6 +1351,22 @@ class LlamaForCausalLM:
                 window=window, logit_cap=c.attn_logit_softcap,
                 has_alibi=c.alibi, has_sinks=c.attn_sinks)
 
+        def mlp(lp, x, layer_idx):
+            """The MLP block of the layer at ``layer_idx`` (traced). Its
+            per-layer buffers are picked by a switch with one branch a
+            layer, so each branch's kernels read buffers that are
+            arguments of the program, in place."""
+            if not per_layer:
+                return self.mlp_block(lp, x, lora_ctx)
+            # One jitted block for all branches: traced once, called
+            # with each layer's buffers.
+            block = jax.jit(self.mlp_block)
+            return jax.lax.switch(
+                layer_idx[0] - cache_layer_offset,
+                [functools.partial(
+                    block, {**lp, **{k: v[i] for k, v in per_layer.items()}},
+                    lora_ctx=lora_ctx) for i in range(num_layers)], x)
+
         def layer_body(h, k_all, v_all, lp, layer_idx, window,
                        nope=False):
             if use_fused:
@@ -1378,13 +1431,13 @@ class LlamaForCausalLM:
                 # input state; one residual add.
                 x2 = (x if c.shared_block_ln else
                       self._norm(h, lp["post_ln"], lp.get("post_ln_b")))
-                mlp_out = self.mlp_block(lp, x2, lora_ctx)
+                mlp_out = mlp(lp, x2, layer_idx)
                 h = sp(h + rm * (attn_out + mlp_out))
                 return h, k_all, v_all
             h = sp(h + rm * attn_out)
             x2 = (self._norm(h, lp["post_ln"], lp.get("post_ln_b"))
                   if c.pre_norm else h)
-            mlp_out = self.mlp_block(lp, x2, lora_ctx)
+            mlp_out = mlp(lp, x2, layer_idx)
             if "post_ffw_ln" in lp:
                 mlp_out = self._norm(mlp_out, lp["post_ffw_ln"],
                                      lp.get("post_ffw_ln_b"))

@@ -14,16 +14,59 @@ numerics for parity tests.
 
 The attention/embedding/norm stack is inherited from the Llama decoder
 (Mixtral is architecturally Llama + MoE MLP).
+
+**Where the expert weights live.** The loader's tree holds them
+layer-stacked (``[L, E, H, I]``, the canonical layout every mapper,
+``quantize_params``, EPLB and the pipeline slicer keep). The serving
+programs take ``serving_layout`` of it: ``w_gate`` / ``w_up`` /
+``w_down`` (and what rides on them) as tuples of L per-layer buffers, so
+that every ``jax.lax.ragged_dot`` reads a buffer that is an argument of
+the jitted program. ``ragged_dot`` is a custom call that wants a whole
+operand: a slice of a stack, a scan's dynamic one or an unrolled loop's
+static one, is materialised for it as a copy of the slab. On a v5e that
+copy was the largest device operation of both OLMoE cells: 6.4 GB read
+and written a step, 19.6 ms of a 35.5 ms step, more than the GEMMs
+themselves (PERF.md, Findings PR 28 "Experts read in place" and PR 29).
+Everything else in the tree stays stacked and scanned;
+``LlamaForCausalLM.run_layers`` keeps the tuples out of its scan and
+reaches ``mlp_block`` through a switch with one branch a layer, each
+holding its own layer's buffers (a Python loop over the layers reads
+in place as well, but traces the whole layer body once a layer: on the
+chip the 36 serving programs of OLMoE then took 71 s to come up from a
+warm compile cache where they take 29 s, PERF.md Findings PR 29).
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from vllm_distributed_tpu.models.llama import (MODEL_AXIS,
                                                LlamaForCausalLM)
 from vllm_distributed_tpu.parallel.mesh import shard_map
+
+
+@functools.lru_cache(maxsize=None)
+def _take_layer(sharding):
+    return jax.jit(
+        lambda a, l: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
+        out_shardings=sharding)
+
+
+def _unstack(stack) -> tuple:
+    """``[L, ...]`` -> L buffers ``[...]``, each with the stack's
+    sharding less its layer axis. One program for all layers of a shape
+    (the index is an argument)."""
+    sharding = stack.sharding
+    if isinstance(sharding, NamedSharding):
+        sharding = NamedSharding(sharding.mesh, P(*sharding.spec[1:]))
+    else:
+        sharding = None     # one device: the result stays where it is
+    take = _take_layer(sharding)
+    return tuple(take(stack, np.int32(l)) for l in range(stack.shape[0]))
 
 
 class MixtralForCausalLM(LlamaForCausalLM):
@@ -34,6 +77,9 @@ class MixtralForCausalLM(LlamaForCausalLM):
     # MoE LoRA support).
     LORA_TARGETS = ("wq", "wk", "wv", "wo")
     _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+    # Per-layer buffers in the serving layout, with the quantization
+    # scales that ride on them (module docstring).
+    _PER_LAYER = _EXPERT_WEIGHTS
 
     @property
     def num_physical(self) -> int:
@@ -162,21 +208,25 @@ class MixtralForCausalLM(LlamaForCausalLM):
         p2l = placement.phys_to_logical  # [L, P]
         have_map = "expert_map" in layers
 
-        def logical_index(arr):
-            if not have_map:
-                return arr  # still in logical order
+        if have_map:
             cur_first = np.asarray(layers["expert_map"])[:, :, 0]  # [L, E]
-            return jnp.stack([arr[l][cur_first[l]] for l in range(L)])
 
         for name in self._EXPERT_WEIGHTS:
             for key in (name, name + "_scale"):
                 if key not in layers:
                     continue
-                logical = logical_index(layers[key])
-                # jnp, not np: the weights may be traced (the dummy
-                # loader jits init_params); the index tables are host.
-                layers[key] = jnp.stack(
-                    [logical[l][p2l[l]] for l in range(L)])
+                # One layer at a time, so a stack and a serving
+                # layout's tuple of per-layer buffers answer alike, and
+                # the result keeps the layout it came in. jnp, not np:
+                # the weights may be traced (the dummy loader jits
+                # init_params); the index tables are host.
+                arr = layers[key]
+                moved = []
+                for l in range(L):
+                    logical = arr[l][cur_first[l]] if have_map else arr[l]
+                    moved.append(logical[p2l[l]])
+                layers[key] = (tuple(moved) if isinstance(arr, tuple)
+                               else jnp.stack(moved))
         r_cap = self._replica_cap
         emap = np.zeros((L, E, r_cap), np.int32)
         for l in range(L):
@@ -196,6 +246,44 @@ class MixtralForCausalLM(LlamaForCausalLM):
         returned tree with its param shardings)."""
         self._scatter_placement(params["layers"], placement)
         return params
+
+    # ------------------------------------------------------------------
+    # Serving layout: expert weights as per-layer buffers
+    # ------------------------------------------------------------------
+    def _per_layer_keys(self, layers: dict) -> list:
+        return [name + rider for name in self._PER_LAYER
+                for rider in ("", "_scale", "_gscale", "_gmin")
+                if name + rider in layers]
+
+    def serving_layout(self, layers: dict) -> dict:
+        """Replace each layer-stacked expert tensor by a tuple of its
+        layers, each a device buffer of its own with the stack's
+        sharding less the layer axis (module docstring). In place and
+        ONE KEY AT A TIME, the stack dropped before the next is split:
+        a stack and its leaves coexist once, never all three (OLMoE at
+        8 layers: 2.15 GB beside 7.1 GB of weights, before the HBM
+        profile and the pool exist). One device copy of the expert
+        weights at load, outside every window."""
+        for key in self._per_layer_keys(layers):
+            if not isinstance(layers[key], tuple):  # else: per layer already
+                # pop: the leaves hold the only copy once they are made.
+                layers[key] = jax.block_until_ready(
+                    _unstack(layers.pop(key)))
+        return layers
+
+    def canonical_layout(self, layers: dict) -> dict:
+        """Stack, on the host, what ``serving_layout`` split: the tuples
+        whose canonical spec has the layer axis their leaves lack (a
+        family whose canonical leaves are per layer keeps them)."""
+        specs = self.param_specs()["layers"]
+        out = dict(layers)
+        for key in self._per_layer_keys(layers):
+            leaves = layers[key]
+            if (isinstance(leaves, tuple)
+                    and len(specs[key]) == leaves[0].ndim + 1):
+                out[key] = np.stack(
+                    [np.asarray(jax.device_get(x)) for x in leaves])
+        return out
 
     # ------------------------------------------------------------------
     def mlp_block(self, lp: dict, x: jax.Array,

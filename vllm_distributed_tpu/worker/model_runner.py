@@ -351,6 +351,7 @@ class TPUModelRunner:
         """Build the model and load weights per LoadConfig."""
         from vllm_distributed_tpu.models.loader import get_model
         self.model, self.params = get_model(self.config, self.mesh)
+        self._to_serving_layout()
         self._mrope_on = bool(
             getattr(self.model.cfg, "mrope_section", None))
         if getattr(self.model, "CROSS_ATTENTION", False):
@@ -383,6 +384,14 @@ class TPUModelRunner:
                 placed = place(host, specs)
             self.params["eagle"] = placed
             self._eagle.eparams = placed
+
+    def _to_serving_layout(self) -> None:
+        """The loader returns the canonical layer-stacked tree; the
+        jitted programs take the model's serving layout of it
+        (LlamaForCausalLM.serving_layout: the same tree for a dense
+        model). Called wherever weights are taken or re-placed."""
+        self.params["layers"] = self.model.serving_layout(
+            self.params["layers"])
 
     def _init_lora_manager(self) -> None:
         if self.config.lora_config.enable_lora:
@@ -640,7 +649,10 @@ class TPUModelRunner:
     def save_sharded_state(self, path: str) -> None:
         import orbax.checkpoint as ocp
         ckpt = ocp.StandardCheckpointer()
-        ckpt.save(os.path.abspath(path), self.params)
+        # On disk the tree is the canonical one the loader restores.
+        ckpt.save(os.path.abspath(path), dict(
+            self.params,
+            layers=self.model.canonical_layout(self.params["layers"])))
         ckpt.wait_until_finished()
         logger.info("saved sharded state to %s", path)
 
@@ -660,8 +672,12 @@ class TPUModelRunner:
         freed += sum(x.nbytes
                      for x in jax.tree_util.tree_leaves(self.kv_caches))
         if level == 1:
+            # Each leaf goes back where it was: the shardings follow the
+            # tree, whatever layout the model serves from.
             self._host_params = jax.tree.map(
                 lambda x: np.asarray(jax.device_get(x)), self.params)
+            self._host_shardings = jax.tree.map(
+                lambda x: x.sharding, self.params)
         else:
             self._host_params = None
         if self._hist_dev is not None:
@@ -693,25 +709,14 @@ class TPUModelRunner:
         """Restore weights + a fresh (empty) KV cache. Compiled step
         functions persist — shapes are unchanged, so no recompiles."""
         assert getattr(self, "_sleeping", False), "not sleeping"
-        from jax.sharding import NamedSharding
         if self._host_params is not None:
-            # Walk the saved tree generically — families carry extra
-            # top-level keys (embed_pos, embed_ln, encoder heads) and
-            # some drop final_ln (post-norm BART).
-            specs = self.model.param_specs()
-            if self._eagle is not None and "eagle" in self._host_params:
-                specs["eagle"] = self._eagle.param_specs()
-
-            def place(p, s):
-                if isinstance(p, dict):
-                    return {k: place(v, s[k]) for k, v in p.items()}
-                return jax.device_put(p, NamedSharding(self.mesh, s))
-
-            self.params = place(self._host_params, specs)
-            self._host_params = None
+            self.params = jax.device_put(self._host_params,
+                                         self._host_shardings)
+            self._host_params = self._host_shardings = None
         else:
             from vllm_distributed_tpu.models.loader import get_model
             self.model, self.params = get_model(self.config, self.mesh)
+            self._to_serving_layout()
             if self.lora_manager is not None:
                 # The reload came with fresh zero adapter buffers; the
                 # slot map must forget its names or old adapters would

@@ -62,11 +62,11 @@ class PPModelRunner(TPUModelRunner):
             sm = self.stage_meshes[p]
             sliced = self.model.slice_layer_params(
                 host_params["layers"], s, e)
-            self.stage_params.append({
+            self.stage_params.append(self.model.serving_layout({
                 k: jax.device_put(v, NamedSharding(sm,
                                                    specs["layers"][k]))
                 for k, v in sliced.items()
-            })
+            }))
         sm0, sml = self.stage_meshes[0], self.stage_meshes[-1]
         self.embed_params = {
             "embed": jax.device_put(host_params["embed"],
