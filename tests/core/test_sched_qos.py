@@ -8,7 +8,7 @@ The adversarial-flood drill here is the deterministic, scheduler-level
 form of the acceptance criterion: gaps are measured in SCHEDULER STEPS
 (each step is one decode iteration, so an interactive request's
 inter-grant step gap IS its TPOT in step units) instead of flaky wall
-clock — bench.py's QoS leg carries the wall-clock version."""
+clock."""
 
 import pytest
 

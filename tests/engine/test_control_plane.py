@@ -20,9 +20,12 @@ import time
 import pytest
 
 from tests.conftest import make_config
-from tests.engine.test_fleet import (FLEET_ENV, _Collector, _FleetStub,
-                                     _pressure, _pump, _req, _tick,
-                                     _tok, make_fleet)
+from tests.engine.test_fleet import (DIURNAL_ENV, DIURNAL_PHASES, FLEET_ENV,
+                                     _Collector, _FleetStub, _pressure,
+                                     _pump, _req, _tick, _tok,
+                                     diurnal_prompts, make_fleet,
+                                     run_diurnal_phase)
+from tests.engine.test_llm_engine import checkpoint, make_engine  # noqa: F401
 from vllm_distributed_tpu.engine import dp_client as dp_mod
 from vllm_distributed_tpu.engine.control_plane import HAFleetController
 from vllm_distributed_tpu.engine.dp_client import DPEngineClient
@@ -450,3 +453,84 @@ def test_goodput_floor_ignored_when_signals_off(monkeypatch):
     dp.observe_goodput({"gold": 0.2})
     _tick(dp, 2)
     assert dp.fleet.scale_ins == 1  # no veto: occupancy-only
+
+
+# ---------------------------------------------------------------------------
+# Real engines: the leader dies mid-scale-in, the standby finishes it
+# ---------------------------------------------------------------------------
+def test_real_engines_leader_killed_mid_scale_in_token_identical(
+        checkpoint, monkeypatch, tmp_path):
+    """The diurnal walk of ``test_fleet`` on two in-process replicas of
+    a real engine with the lease-fenced controller on and a second
+    front end standing by on the same coordinator and journal. The
+    leader is killed between the drain's journaled intent and its
+    completion; the standby takes the lease, replays the journal,
+    finishes the retire and scales out again at the second peak. Every
+    request's greedy tokens equal the static fleet's."""
+    path, _ = checkpoint
+    prompts = diurnal_prompts(17)
+    env = {**DIURNAL_ENV,
+           "VDT_FLEET_LEASE_TTL_S": "0.3",
+           "VDT_FLEET_JOURNAL_DIR": str(tmp_path),
+           # Below the trough's in-flight occupancy (2/16): scale-in
+           # only accumulates on the idle ticks driven below, so the
+           # kill lands between the drain's intent and its completion.
+           "VDT_FLEET_LOW_WATERMARK": "0.05"}
+    for key, val in env.items():
+        monkeypatch.setenv(key, val)
+    outputs = {}
+
+    monkeypatch.setenv("VDT_FLEET", "0")
+    monkeypatch.setenv("VDT_FLEET_CONTROLLER", "0")
+    engine = make_engine(path, data_parallel_size=2)
+    for ph, n in DIURNAL_PHASES:
+        run_diurnal_phase(engine, "off", ph, n, prompts,
+                          outputs.setdefault("off", {}))
+    engine.shutdown()
+
+    monkeypatch.setenv("VDT_FLEET", "1")
+    monkeypatch.setenv("VDT_FLEET_CONTROLLER", "1")
+    engine = make_engine(path, data_parallel_size=2)
+    dp = engine.engine_core
+    standby = None
+    try:
+        outs = outputs["on"] = {}
+        for ph, n in DIURNAL_PHASES[:2]:
+            run_diurnal_phase(engine, "on", ph, n, prompts, outs)
+        primary = dp.fleet
+        timeline = [primary.get_stats()["replicas"]]
+        # Idle ticks walk the trough's scale-in up to (not past) the
+        # drain's start: intent journaled, retire incomplete.
+        for _ in range(50):
+            dp._tick()
+            if primary._draining:
+                break
+        assert primary._draining, "trough scale-in never began a drain"
+        fi.inject("fleet.controller_die", max_fires=1)
+        try:
+            dp._tick()
+        finally:
+            fi.clear("fleet.controller_die")
+        standby = HAFleetController(dp, dp.config, holder="fe-standby")
+        deadline = time.monotonic() + 5.0
+        while not standby.is_leader and time.monotonic() < deadline:
+            standby.tick()
+            time.sleep(0.02)
+        assert standby.is_leader, "standby never acquired the lease"
+        for _ in range(20):
+            standby.tick()
+            if standby.get_stats()["replicas"] == 1:
+                break
+        timeline.append(standby.get_stats()["replicas"])
+        run_diurnal_phase(engine, "on", *DIURNAL_PHASES[2], prompts, outs,
+                          each_step=standby.tick)
+        st = standby.get_stats()
+        timeline.append(st["replicas"])
+    finally:
+        if standby is not None:
+            standby.close()
+        engine.shutdown()
+    assert timeline == [2, 1, 2]
+    assert st["leader_transitions"] == 2
+    assert st["journal_replays"] == 1
+    assert outputs["on"] == outputs["off"]

@@ -10,13 +10,17 @@ re-splits proven by pool-occupancy metrics, and the ``VDT_FLEET=0``
 inert default. The chaos soaks at the bottom run the 2->3->1 schedule
 under a seeded fault sequence with continuous traffic and a
 deterministic per-session token function, so zero-loss/zero-duplication
-and greedy-parity are exact assertions, not spot checks."""
+and greedy-parity are exact assertions, not spot checks. The last test
+walks 2 -> 1 -> 2 on two in-process replicas of a real engine and holds
+its tokens to the static fleet's."""
 
 import time
 
+import numpy as np
 import pytest
 
 from tests.conftest import make_config
+from tests.engine.test_llm_engine import checkpoint, make_engine  # noqa: F401
 from vllm_distributed_tpu.core.sched.scheduler import EngineCoreOutput
 from vllm_distributed_tpu.engine import dp_client as dp_mod
 from vllm_distributed_tpu.engine.core_client import (EngineCoreClient,
@@ -925,3 +929,86 @@ def test_quarantine_hint_without_signals_is_dropped(monkeypatch):
     _tick(dp, 3)
     assert dp.fleet.quarantines == 0
     assert 1 not in dp._down
+
+
+# ---------------------------------------------------------------------
+# Real engines: the diurnal walk on a two-replica in-process DP fleet
+# ---------------------------------------------------------------------
+DIURNAL_PHASES = (("peak1", 8), ("trough", 2), ("peak2", 8))
+# Peak occupancy on ONE replica is 1.0, on two it is 0.5; the trough
+# sits near 0.12: the watermarks bracket exactly the 2 -> 1 -> 2 walk.
+DIURNAL_ENV = {
+    "VDT_FLEET_TICK_S": "0",
+    "VDT_FLEET_EVAL_TICKS": "3",
+    "VDT_FLEET_STALE_S": "0",
+    # Zero drain grace: retirement mid-trough journal-migrates the live
+    # stragglers; token parity is what proves that path exact.
+    "VDT_FLEET_DRAIN_S": "0",
+    "VDT_FLEET_MIN_REPLICAS": "1",
+    "VDT_FLEET_MAX_REPLICAS": "2",
+    "VDT_FLEET_HIGH_WATERMARK": "0.7",
+    "VDT_FLEET_LOW_WATERMARK": "0.2",
+    "VDT_FLEET_ACTIONS": "20",
+}
+
+
+def diurnal_prompts(seed: int) -> dict:
+    rng = np.random.default_rng(seed)
+    return {(ph, s): [int(x) for x in rng.integers(2, 127, size=24)]
+            for ph, n in DIURNAL_PHASES for s in range(n)}
+
+
+def run_diurnal_phase(engine, leg, ph, n, prompts, outs, each_step=None):
+    sp = SamplingParams(temperature=0.0, max_tokens=12, ignore_eos=True)
+    for s in range(n):
+        engine.add_request(f"{leg}-{ph}-{s}", list(prompts[(ph, s)]), sp)
+    for _ in range(2000):
+        if not engine.has_unfinished_requests():
+            break
+        for o in engine.step():
+            if o.finished:
+                outs[o.request_id.split("-", 1)[1]] = list(
+                    o.outputs[0].token_ids)
+        if each_step is not None:
+            each_step()
+    assert not engine.has_unfinished_requests()
+
+
+def test_real_engines_walk_2_1_2_token_identical_to_static_fleet(
+        checkpoint, monkeypatch):
+    """A peak wave, a trough and a second peak on two in-process
+    replicas of a real engine: the controller retires one replica in
+    the trough (its live requests journal-migrated), brings it back
+    warm at the second peak, and every request's greedy tokens equal
+    the static two-replica fleet's (``VDT_FLEET=0``) on the same
+    traffic."""
+    path, _ = checkpoint
+    prompts = diurnal_prompts(16)
+    for key, val in DIURNAL_ENV.items():
+        monkeypatch.setenv(key, val)
+    outputs, timeline, stats = {}, [], None
+    for leg, flag in (("on", "1"), ("off", "0")):
+        monkeypatch.setenv("VDT_FLEET", flag)
+        engine = make_engine(path, data_parallel_size=2)
+        outs = outputs[leg] = {}
+        for ph, n in DIURNAL_PHASES:
+            run_diurnal_phase(engine, leg, ph, n, prompts, outs)
+            fleet = engine.engine_core.fleet
+            if fleet is not None:
+                timeline.append(fleet.get_stats()["replicas"])
+                # Idle ticks settle a drain in progress; none after the
+                # last phase, or the counters show a retire after it.
+                if ph != DIURNAL_PHASES[-1][0]:
+                    for _ in range(8):
+                        engine.engine_core._tick()
+        if flag == "1":
+            stats = engine.get_stats()
+        else:
+            assert engine.engine_core.fleet is None
+        engine.shutdown()
+    assert timeline == [2, 1, 2]
+    assert stats["fleet"]["scale_ins"] >= 1
+    assert stats["fleet"]["scale_outs"] >= 1
+    assert stats.get("replica_failovers", 0) == 0
+    assert len(outputs["off"]) == sum(n for _, n in DIURNAL_PHASES)
+    assert outputs["on"] == outputs["off"]

@@ -327,9 +327,8 @@ def test_large_pull_applies_in_chunks_without_stalling_a_step(
 
 
 def test_async_pull_under_pipeline_parallelism(checkpoint):
-    """Disaggregated prefill with pp=2 on both sides (BASELINE config
-    #5 shape): wire pages span all stages' layer slices; parity with a
-    plain pp=2 engine."""
+    """Disaggregated prefill with pp=2 on both sides: wire pages span
+    all stages' layer slices; parity with a plain pp=2 engine."""
     pp = dict(pipeline_parallel_size=2)
     baseline = [o.outputs[0].token_ids
                 for o in run(make_engine(checkpoint, **pp), PROMPTS,

@@ -7,10 +7,14 @@ plane's scoring rules in isolation — journal self-seeding, the
 vote/reference/logprob/timeout cause ladder, the median-based numerics
 drift detector, episode hygiene (forget_replica, clean-round resets) —
 and the render contract: per-replica labeled series that are NEVER
-numeric-summed across replicas."""
+numeric-summed across replicas. The last test runs the soak, the
+corruption drill and plane-on against plane-off on real engines."""
 
+import numpy as np
 import pytest
 
+from tests.engine.test_llm_engine import (checkpoint,  # noqa: F401
+                                          make_engine, run_engine)
 from vllm_distributed_tpu.core.sched.scheduler import EngineCoreOutput
 from vllm_distributed_tpu.correctness_plane import (CANARY_DECODE_TOKENS,
                                                     CorrectnessPlane,
@@ -19,6 +23,7 @@ from vllm_distributed_tpu.correctness_plane import (CANARY_DECODE_TOKENS,
                                                     flag_config_fingerprint,
                                                     reference_key)
 from vllm_distributed_tpu.metrics.prometheus import render_metrics
+from vllm_distributed_tpu.sampling_params import SamplingParams
 
 GOLD_TOKENS = list(range(100, 100 + CANARY_DECODE_TOKENS))
 
@@ -181,7 +186,7 @@ def test_forget_replica_resolves_round_with_survivors(plane):
 def test_flag_fingerprint_keys_disjoint_references(monkeypatch):
     sp = canary_sampling_params()
     fp_a = flag_config_fingerprint()
-    monkeypatch.setenv("VDT_BLOCK_FUSION", "1")
+    monkeypatch.setenv("VDT_FUSED_KV_WRITE", "0")
     fp_b = flag_config_fingerprint()
     assert fp_a != fp_b
     prompt = (11, 29, 7, 3, 17, 23, 5, 13)
@@ -203,7 +208,6 @@ def test_sentinel_knobs_excluded_from_fingerprint(monkeypatch):
 
 
 def test_numerics_tap_excludes_poisoned_step():
-    import numpy as np
     tap = NumericsTap()
     tap.dispatch(np.array([0.0, 1.5, 2.0], dtype=np.float32))
     tap.dispatch(np.array([3.0, float("nan"), 0.0], dtype=np.float32))
@@ -279,7 +283,6 @@ def test_render_correctness_families_are_per_replica():
 
 
 def test_render_numerics_keyed_and_flat():
-    import numpy as np
     tap = NumericsTap()
     tap.dispatch(np.array([0.0, 1.0, 2.0], dtype=np.float32))
     snap = tap.stats()
@@ -299,7 +302,6 @@ def test_render_excludes_dead_replica_mid_scrape():
     # The DP aggregator keys numerics by the ALIVE indices it polled;
     # a replica that died mid-scrape simply has no entry and must not
     # render as a zeroed ghost.
-    import numpy as np
     tap = NumericsTap()
     tap.dispatch(np.array([0.0, 1.0, 2.0], dtype=np.float32))
     text = render_metrics({"numerics": {0: tap.stats()},
@@ -314,3 +316,81 @@ def test_render_off_by_default():
     assert "vdt:canary" not in text
     assert "vdt:logits" not in text
     assert "vdt:replica_suspect" not in text
+
+
+# ---------------------------------------------------------------------------
+# Real engines: a clean soak, a corrupted replica, and the plane's cost in
+# tokens (none)
+# ---------------------------------------------------------------------------
+def _pump_probes(engine, plane, n, budget=4000):
+    """Drive the DP output pump until ``n`` more canary probes have
+    finished (its tick injects due probes and steps their replicas)."""
+    target = sum(plane.probes.values()) + n
+    while sum(plane.probes.values()) < target and budget > 0:
+        engine.engine_core.get_output()
+        budget -= 1
+    return sum(plane.probes.values()) >= target
+
+
+def test_real_engines_clean_soak_corruption_drill_and_token_parity(
+        checkpoint, monkeypatch):
+    """Two in-process replicas of a real engine under the sentinel:
+    60 canary probes with no divergence (the false-positive budget is
+    zero); replica 1's canary tokens perturbed where the plane absorbs
+    them are voted out within 3 probes, replica 1 ALONE is suspect and
+    a quarantine hint goes out; and tenant traffic's greedy tokens are
+    the same with the plane on and off."""
+    path, _ = checkpoint
+    monkeypatch.setenv("VDT_CORRECTNESS", "1")
+    # Interval 0: a new round every tick, so the soak and the drill are
+    # paced by probes, not by the clock.
+    monkeypatch.setenv("VDT_CANARY_INTERVAL_S", "0")
+    monkeypatch.setenv("VDT_CANARY_QUARANTINE_N", "2")
+    # The drill perturbs tokens, not logits: keep the drift detector
+    # out of the attribution under test.
+    monkeypatch.setenv("VDT_NUMERICS_DRIFT_FRAC", "0")
+    monkeypatch.setenv("VDT_FLEET", "0")
+    engine = make_engine(path, data_parallel_size=2)
+    plane = engine.engine_core.correctness
+    assert plane is not None
+    assert _pump_probes(engine, plane, 60), "soak stalled before 60 probes"
+    stats = plane.get_stats()
+    assert sum(stats["probes"].values()) >= 60
+    assert not any(sum(c.values()) for c in stats["divergences"].values())
+
+    absorb = plane.on_output
+
+    def corrupted(out):
+        if plane._replica_of(out.req_id) == 1 and out.new_token_ids:
+            out.new_token_ids = [t + 1 for t in out.new_token_ids]
+        absorb(out)
+
+    monkeypatch.setattr(plane, "on_output", corrupted)
+    before, detected_after = plane.probes.get(1, 0), None
+    for _ in range(3):
+        assert _pump_probes(engine, plane, 2)
+        if detected_after is None and plane.divergences.get(1):
+            detected_after = plane.probes.get(1, 0) - before
+    assert detected_after is not None and detected_after <= 3
+    stats = plane.get_stats()
+    assert [i for i, v in stats["suspects"].items() if v] == [1]
+    assert stats["quarantine_hints"] >= 1
+    engine.shutdown()
+
+    # A long interval parks the canary injector: what is left of the
+    # plane in a step is the numerics tap.
+    monkeypatch.setenv("VDT_CANARY_INTERVAL_S", "3600")
+    sp = SamplingParams(temperature=0.0, max_tokens=12, ignore_eos=True)
+    rng = np.random.default_rng(20)
+    prompts = [[int(x) for x in rng.integers(2, 127, size=24)]
+               for _ in range(8)]
+    outs = {}
+    for leg, flag in (("on", "1"), ("off", "0")):
+        monkeypatch.setenv("VDT_CORRECTNESS", flag)
+        engine = make_engine(path, data_parallel_size=2)
+        assert (engine.engine_core.correctness is not None) == (flag == "1")
+        outs[leg] = [o.outputs[0].token_ids for o in run_engine(
+            engine, prompts, [sp] * len(prompts))]
+        engine.shutdown()
+    assert len(outs["off"]) == len(prompts)
+    assert outs["on"] == outs["off"]

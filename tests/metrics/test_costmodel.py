@@ -2,8 +2,8 @@
 HBM-byte counts for the layouts the attribution plane must price —
 dense and GQA llama blocks, a DeepSeek MLA layer under TPLA TP=2 (the
 per-rank latent slice is read once per rank, the score psum is counted
-ONCE), the fused-block decode path, and an SSM (Mamba) scan — plus the
-roofline classifier and the per-chip peak tables bench.py shares."""
+ONCE) and an SSM (Mamba) scan — plus the roofline classifier and the
+per-chip peak tables."""
 
 import types
 
@@ -160,18 +160,6 @@ def test_mla_via_real_deepseek_model():
         assert cm.moe_layers == 2 and cm.num_experts == 4
     # TP=2 aggregate row costs one extra replicated rope sidecar.
     assert rows[2] > rows[1]
-
-
-def test_fused_block_costs_match_per_op_path():
-    """The fused decode-block kernel computes the SAME math as the
-    per-op path — the cost model prices a fused dispatch identically
-    (only the attribution LABEL differs, keyed by the runner)."""
-    cm_fused = CostModel.from_arch(_arch(block_fusion=True),
-                                   kv_row_bytes=512.0)
-    cm_plain = CostModel.from_arch(_arch(), kv_row_bytes=512.0)
-    a = cm_fused.wave_cost(8, 100.0, 8)
-    b = cm_plain.wave_cost(8, 100.0, 8)
-    assert a == b
 
 
 def test_ssm_scan_hand_count():

@@ -86,14 +86,14 @@ def test_matches_reference(seqs, max_q):
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
-def test_gqa_group_and_mha():
+@pytest.mark.parametrize("kvh", [1, 2, 8])
+def test_gqa_group_and_mha(kvh):
     rng = np.random.default_rng(1)
-    for kvh in (1, 2, 8):
-        case = build_case(rng, seqs=[(3, 11), (1, 4)], page_size=8,
-                          pages_per_req=4, num_q_heads=8, num_kv_heads=kvh,
-                          head_dim=128, max_q=8)
-        got, want = run_both(case)
-        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+    case = build_case(rng, seqs=[(3, 11), (1, 4)], page_size=8,
+                      pages_per_req=4, num_q_heads=8, num_kv_heads=kvh,
+                      head_dim=128, max_q=8)
+    got, want = run_both(case)
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
 def test_multi_q_tile_long_prefill():

@@ -3,19 +3,94 @@ compiler at real widths for a described (not attached) v5e — what
 interpret mode cannot show: a slice off the 128-lane tiling, too much
 VMEM, a kernel Mosaic refuses. Nothing runs; a pass is not a chip run.
 
+The K/V-paged kernels compile at the geometry of every benchmark
+configuration that runs them (heads, depth and engine limits read from
+``benchmarks/configs``, the pool sizes the chip gave: PERF.md section
+4) and over the serving lattice's own token buckets; the grouped GEMMs
+and a one-layer forward of each configuration compile with their
+weights as shapes.
+
 All of it lives in this one file and describes the topology inside a
 fixture: only the xdist worker that is handed this file loads libtpu.
 """
+
+import json
+import os
+import re
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-# Llama widths (3.2-1B heads; the 128-lane storage head dim the TPU path
-# uses), page 16, bf16, and the smoke's engine limits.
-QH, KVH, D, PS = 32, 8, 128, 16
-L, NP, R, PAGES_PER_REQ = 16, 2048, 64, 256
+from vllm_distributed_tpu.utils import cdiv, make_buckets, pad_to_bucket
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+PS = 16
+
+
+class Geometry(NamedTuple):
+    """What a K/V-paged kernel sees of a configuration on one chip."""
+    QH: int
+    KVH: int
+    D: int              # the stored head size (128 lanes on the TPU)
+    L: int
+    NP: int             # pages in the pool
+    R: int              # max_num_seqs
+    pages_per_req: int
+    max_tokens: int     # max_num_batched_tokens
+
+
+def bench_config(name: str) -> dict:
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           f"{name}.json")) as f:
+        return json.load(f)
+
+
+def bench_geometry(name: str, pages: int) -> Geometry:
+    cf = bench_config(name)
+    ea = cf["engine_args"]
+    assert ea["block_size"] == PS
+    heads = cf["num_attention_heads"]
+    return Geometry(heads, cf["num_key_value_heads"],
+                    cf["hidden_size"] // heads, cf["num_hidden_layers"],
+                    pages, ea["max_num_seqs"],
+                    cdiv(ea["max_model_len"], PS),
+                    ea["max_num_batched_tokens"])
+
+
+GEOMETRIES = {
+    # Llama-3.2-1B heads (head size 64 stored in 128 lanes), the
+    # smoke's engine limits.
+    "llama-3.2-1b": Geometry(32, 8, 128, 16, 2048, 64, 256, 2048),
+    # Pool sizes as the HBM profile gave them on the chip (PERF.md,
+    # section 4).
+    "olmo2-1b": bench_geometry("olmo2-1b", 4992),
+    "olmoe-1b-7b": bench_geometry("olmoe-1b-7b", 6912),
+}
+QH, KVH, D, L, NP, R, PAGES_PER_REQ, _ = GEOMETRIES["llama-3.2-1b"]
+# The token buckets the runner serves at the cells' engine limits
+# (worker/model_runner.py: make_buckets(16, max_num_batched_tokens)).
+TOKEN_BUCKETS = make_buckets(16, 2048)
+assert all(g.max_tokens == TOKEN_BUCKETS[-1] for g in GEOMETRIES.values())
+
+geometries = pytest.mark.parametrize("geo", list(GEOMETRIES.values()),
+                                     ids=list(GEOMETRIES))
+# The benchmark's configurations at every bucket; the smoke's at the two
+# ends of the lattice, as before (2.3 s a case, and the file has 150 s).
+served_lattice = pytest.mark.parametrize("geo,t_bucket", [
+    pytest.param(geo, t, id=f"{name}-{t}")
+    for name, geo in GEOMETRIES.items() for t in TOKEN_BUCKETS
+    if name != "llama-3.2-1b" or t in (TOKEN_BUCKETS[0], TOKEN_BUCKETS[-1])
+])
+
+
+def kv_write_runs(T: int, geo: Geometry) -> int:
+    """G of a step of T token rows, as ``_batch_shape`` pads it."""
+    buckets = make_buckets(8, cdiv(geo.max_tokens + 128, PS) + geo.R)
+    return pad_to_bucket(cdiv(T, PS) + geo.R, buckets)
 
 
 @pytest.fixture(scope="module")
@@ -65,56 +140,62 @@ def sds(shape, dtype=jnp.int32):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def attention_shapes(T, head_dim=D, max_reqs=R):
+def attention_shapes(T, geo=GEOMETRIES["llama-3.2-1b"], head_dim=None):
     """(q, k_pages, v_pages, seq_info, block_tables, layer) at T tokens."""
-    cache = sds((L, NP, KVH, PS, head_dim), jnp.bfloat16)
-    return (sds((T, QH, head_dim), jnp.bfloat16), cache, cache,
-            sds((max_reqs, 4)), sds((max_reqs, PAGES_PER_REQ)), sds((1, )))
+    head_dim = head_dim or geo.D
+    cache = sds((geo.L, geo.NP, geo.KVH, PS, head_dim), jnp.bfloat16)
+    return (sds((T, geo.QH, head_dim), jnp.bfloat16), cache, cache,
+            sds((geo.R, 4)), sds((geo.R, geo.pages_per_req)), sds((1, )))
 
 
-def unified_statics(head_dim=D):
+def unified_statics(geo=GEOMETRIES["llama-3.2-1b"], head_dim=None):
     from vllm_distributed_tpu.ops.pallas_attention import (
         decode_group_size, prefill_tile_size)
-    return prefill_tile_size(QH, head_dim), decode_group_size(QH, KVH)
+    return (prefill_tile_size(geo.QH, head_dim or geo.D),
+            decode_group_size(geo.QH, geo.KVH))
 
 
+@geometries
 @pytest.mark.parametrize("t_bucket", [16, 2048])
-def test_unified_attention_compiles(compile_for_chip, t_bucket):
+def test_unified_attention_compiles(compile_for_chip, geo, t_bucket):
     from vllm_distributed_tpu.ops.pallas_attention import (
         Q_TILE_PAD, num_partition_programs,
         unified_ragged_paged_attention_pallas)
-    bq, sb = unified_statics()
-    q, k, v, si, bt, layer = attention_shapes(t_bucket + Q_TILE_PAD)
-    P = num_partition_programs(t_bucket, R, bq=bq, sb=sb)
+    bq, sb = unified_statics(geo)
+    q, k, v, si, bt, layer = attention_shapes(t_bucket + Q_TILE_PAD, geo)
+    P = num_partition_programs(t_bucket, geo.R, bq=bq, sb=sb)
 
     def fn(q, k, v, desc, si, dl, bt, layer):
         return unified_ragged_paged_attention_pallas(
             q, k, v, desc, si, dl, bt, layer, sm_scale=0.125, bq=bq,
             sb=sb, interpret=False)
 
-    compile_for_chip(fn, q, k, v, sds((P, 3)), si, sds((R, )), bt, layer)
+    compile_for_chip(fn, q, k, v, sds((P, 3)), si, sds((geo.R, )), bt,
+                     layer)
 
 
-@pytest.mark.parametrize("t_bucket", [16, 2048])
-def test_unified_write_attend_compiles(compile_for_chip, t_bucket):
+@served_lattice
+def test_unified_write_attend_compiles(compile_for_chip, geo, t_bucket):
     """The fused KV-write + attention call the default path makes once a
-    layer (`ops/attention.write_kv_and_attend`)."""
+    layer (`ops/attention.write_kv_and_attend`): the kernel of every
+    K/V-paged cell, at each token bucket a step can present."""
     from vllm_distributed_tpu.ops.pallas_attention import (
         Q_TILE_PAD, num_partition_programs, unified_write_attend_pallas)
-    bq, sb = unified_statics()
+    bq, sb = unified_statics(geo)
     T = t_bucket + Q_TILE_PAD
-    G = 128
-    q, k, v, si, bt, layer = attention_shapes(T)
-    P = num_partition_programs(t_bucket, R, bq=bq, sb=sb, num_kv_writes=G)
-    new = sds((KVH, T + 3 * PS, D), jnp.bfloat16)
+    G = kv_write_runs(T, geo)
+    q, k, v, si, bt, layer = attention_shapes(T, geo)
+    P = num_partition_programs(t_bucket, geo.R, bq=bq, sb=sb,
+                               num_kv_writes=G)
+    new = sds((geo.KVH, T + 3 * PS, geo.D), jnp.bfloat16)
 
     def fn(q, k, v, kn, vn, desc, si, dl, runs, bt, layer):
         return unified_write_attend_pallas(
             q, k, v, kn, vn, desc, si, dl, runs, bt, layer, sm_scale=0.125,
             bq=bq, sb=sb, interpret=False)
 
-    compile_for_chip(fn, q, k, v, new, new, sds((P, 3)), si, sds((R, )),
-                     sds((G, 4)), bt, layer)
+    compile_for_chip(fn, q, k, v, new, new, sds((P, 3)), si,
+                     sds((geo.R, )), sds((G, 4)), bt, layer)
 
 
 @pytest.mark.parametrize("max_q", [1, 32, 256])
@@ -132,12 +213,14 @@ def test_ragged_attention_compiles(compile_for_chip, max_q):
     compile_for_chip(fn, q, k, v, si, sds((1, )), bt, layer)
 
 
-def test_kv_write_compiles(compile_for_chip):
+@geometries
+def test_kv_write_compiles(compile_for_chip, geo):
     from vllm_distributed_tpu.ops.pallas_kv_write import \
         write_kv_pages_pallas
-    T, G = 2048, 200
-    cache = sds((L, NP, KVH, PS, D), jnp.bfloat16)
-    new = sds((KVH, T + 3 * PS, D), jnp.bfloat16)
+    T = geo.max_tokens
+    G = kv_write_runs(T, geo)
+    cache = sds((geo.L, geo.NP, geo.KVH, PS, geo.D), jnp.bfloat16)
+    new = sds((geo.KVH, T + 3 * PS, geo.D), jnp.bfloat16)
 
     def fn(k, v, kn, vn, runs, n, layer):
         return write_kv_pages_pallas(k, v, kn, vn, runs, n, layer,
@@ -163,7 +246,7 @@ def test_true_head_dim_compiles_through_the_padding_helpers(
     monkeypatch.setenv("VDT_PALLAS_INTERPRET", "0")
     d_store = attention.storage_head_dim(64)
     assert d_store == 128
-    bq, sb = unified_statics(d_store)
+    bq, sb = unified_statics(head_dim=d_store)
     t_bucket, G = 256, 128
     T = t_bucket + Q_TILE_PAD
     P = num_partition_programs(t_bucket, R, bq=bq, sb=sb, num_kv_writes=G)
@@ -193,7 +276,7 @@ def test_unpadded_head_dim_is_refused(compile_for_chip):
     from vllm_distributed_tpu.ops.pallas_attention import (
         Q_TILE_PAD, num_partition_programs,
         unified_ragged_paged_attention_pallas)
-    bq, sb = unified_statics(64)
+    bq, sb = unified_statics(head_dim=64)
     q, k, v, si, bt, layer = attention_shapes(256 + Q_TILE_PAD, head_dim=64)
     P = num_partition_programs(256, R, bq=bq, sb=sb)
 
@@ -256,18 +339,31 @@ def test_mla_latent_attention_compiles(compile_for_chip, monkeypatch,
                      sds((1, )), sds((R, PAGES_PER_REQ)), sds((1, )))
 
 
-@pytest.mark.parametrize("max_q,T", [(1, 65), (2048, 2048 + 128)])
+def latent_lattice(buckets) -> list:
+    """(max_q, T) of the legacy lattice MLA batches keep (``_batch_shape``:
+    a chunk of t tokens runs at max_q = t and T = t + min(t, 128)), and
+    the decode batch of 64 requests."""
+    return [(1, 64 + 1)] + [(t, t + min(t, 128)) for t in buckets]
+
+
+@pytest.mark.parametrize("max_q,T", latent_lattice(TOKEN_BUCKETS))
 def test_mla_latent_attention_compiles_at_published_widths(
         compile_for_chip, monkeypatch, max_q, T):
     """GigaChat3 / DeepSeek-V3 widths as `gigachat3-702b-ep16` serves
-    them: 64 heads, 576 -> 640 stored lanes, page 16, block tables of
-    512 pages (max_model_len 8192), a decode batch and a 2048-token
-    chunk; and the custom call carries the kernel's own name."""
+    them: heads, latent and rope widths, depth and engine limits from its
+    configuration file (64 heads, 576 -> 640 stored lanes, block tables
+    of 512 pages), the pool the chip gave it (33 280 pages: PERF.md,
+    section 4), a decode batch and every chunk bucket of the lattice;
+    and the custom call carries the kernel's own name."""
     from vllm_distributed_tpu.ops import mla
     from vllm_distributed_tpu.ops.pallas_mla import (
         KERNEL_NAME, ragged_latent_attention_pallas)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    lkv, rope, heads = 512, 64, 64
+    cf = bench_config("gigachat3-702b-ep16")
+    ea = cf["engine_args"]
+    assert TOKEN_BUCKETS == make_buckets(16, ea["max_num_batched_tokens"])
+    lkv, rope = cf["kv_lora_rank"], cf["qk_rope_head_dim"]
+    heads, reqs = cf["num_attention_heads"], ea["max_num_seqs"]
     cs = mla.latent_storage_dim(lkv, rope)
     assert cs == 640
 
@@ -278,35 +374,146 @@ def test_mla_latent_attention_compiles_at_published_widths(
 
     compiled = compile_for_chip(
         fn, sds((T, heads, cs), jnp.bfloat16),
-        sds((6, 26000, PS, cs), jnp.bfloat16), sds((R, 4)), sds((1, )),
-        sds((R, 512)), sds((1, )))
+        sds((cf["num_hidden_layers"], 33280, PS, cs), jnp.bfloat16),
+        sds((reqs, 4)), sds((1, )),
+        sds((reqs, cdiv(ea["max_model_len"], PS))), sds((1, )))
     assert f"%{KERNEL_NAME}" in compiled.as_text()
 
 
-@pytest.mark.xfail(
-    strict=True,
-    reason="Mosaic failed to compile TPU kernel: infer-vector-layout: "
-    "unsupported shape cast (pallas_block.py, the active_rows bool "
-    "vector); off the default path (VDT_BLOCK_FUSION=0), ROADMAP D3")
-def test_fused_block_decode_compiles(compile_for_chip):
-    """Off the default path (VDT_BLOCK_FUSION=0); Llama-3-8B widths,
-    whose head size is the 128 the kernel requires."""
-    from vllm_distributed_tpu.ops.pallas_block import \
-        fused_block_decode_pallas
-    H, I, T = 4096, 14336, 64 + 8
-    cache = sds((L, NP, KVH, PS, D), jnp.bfloat16)
-    bf = jnp.bfloat16
+# ---------------------------------------------------------------------
+# The benchmark configurations' own programs, weights as shapes
+# ---------------------------------------------------------------------
+SPARSE = ("olmoe-1b-7b", "gigachat3-702b-ep16")
+# Depth cut to one layer of each kind a configuration has: widths,
+# heads, experts and engine limits stay the file's.
+ONE_LAYER = {
+    "olmo2-1b": {"num_hidden_layers": 1},
+    "olmoe-1b-7b": {"num_hidden_layers": 1},
+    "gigachat3-702b-ep16": {"num_hidden_layers": 2,
+                            "first_k_dense_replace": 1},
+}
 
-    def fn(h, k, v, wqkv, wo, wg, wu, wd, ln, rope, feat, si, ns, bt,
-           layer):
-        return fused_block_decode_pallas(
-            h, k, v, wqkv, wo, wg, wu, wd, ln, rope, feat, si, ns, bt,
-            layer, sm_scale=0.088, eps=1e-5, num_q_heads=QH, head_dim=D,
-            interpret=False)
 
-    compile_for_chip(
-        fn, sds((T, H), bf), cache, cache,
-        sds((H, QH * D + 2 * KVH * D), bf), sds((QH * D, H), bf),
-        sds((H, I), bf), sds((H, I), bf), sds((I, H), bf), sds((2, H), bf),
-        sds((2, T, D), jnp.float32), sds((2, QH), jnp.float32),
-        sds((R, 4)), sds((1, )), sds((R, PAGES_PER_REQ)), sds((1, )))
+@pytest.fixture()
+def abstract_runner(topo, no_compile_cache, monkeypatch, tmp_path):
+    """build(name) -> a ``TPUModelRunner`` of the benchmark configuration
+    ``name`` cut to ``ONE_LAYER``, on a mesh of the described chip, its
+    weights ``ShapeDtypeStruct``s in the serving layout: nothing is
+    allocated. The steering a program that asks ``jax.default_backend()``
+    needs is done here, in the test."""
+    from benchmarks.run import hf_config
+    from vllm_distributed_tpu.engine.arg_utils import EngineArgs
+    from vllm_distributed_tpu.models import loader, mixtral
+    from vllm_distributed_tpu.parallel.mesh import build_mesh
+    from vllm_distributed_tpu.worker.model_runner import TPUModelRunner
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
+    monkeypatch.setenv("VDT_PALLAS_INTERPRET", "0")
+    monkeypatch.setattr(
+        loader, "_init_dummy_params",
+        lambda model, rng, mesh: jax.eval_shape(model.init_params, rng))
+    monkeypatch.setattr(
+        mixtral, "_unstack",
+        lambda stack: tuple(sds(stack.shape[1:], stack.dtype)
+                            for _ in range(stack.shape[0])))
+
+    def build(name):
+        cf = bench_config(name)
+        model_dir = tmp_path / name
+        model_dir.mkdir()
+        with open(model_dir / "config.json", "w") as f:
+            json.dump({**hf_config(cf), **ONE_LAYER[name]}, f)
+        config = EngineArgs(
+            model=str(model_dir), load_format="dummy",
+            skip_tokenizer_init=True,
+            **cf["engine_args"]).create_engine_config()
+        mesh = build_mesh(config.parallel_config, devices=topo.devices[:1])
+        model, params = loader.get_model(config, mesh, shard=False)
+        params["layers"] = model.serving_layout(params["layers"])
+        return TPUModelRunner(config, mesh, model=model,
+                              params=on_chip(params, mesh))
+
+    return build
+
+
+def on_chip(tree, mesh):
+    """``tree``'s shapes, whole on the one chip of ``mesh``."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+    chip = NamedSharding(mesh, P())
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        tree)
+
+
+def slab_copies(compiled, slab: str) -> list:
+    """Instructions of the compiled program that MAKE a buffer of an
+    expert slab's shape: anything but the parameter that brings it in
+    (and the views of it that move nothing)."""
+    made = re.compile(rf"= {re.escape(slab)}(?:{{[^}}]*}})? (\S+?)\(")
+    return [op for op in made.findall(compiled.as_text())
+            if op not in ("parameter", "bitcast", "get-tuple-element")]
+
+
+def expert_slab(cf: dict) -> tuple:
+    """(shape string, bytes) of one layer's gate or up projection."""
+    E = cf.get("num_experts") or cf["n_routed_experts"]
+    H = cf["hidden_size"]
+    I = cf.get("moe_intermediate_size") or cf["intermediate_size"]
+    return f"bf16[{E},{H},{I}]", E * H * I * 2
+
+
+@pytest.mark.parametrize("rows", [64, 512 + 8])
+@pytest.mark.parametrize("name", SPARSE)
+def test_grouped_gemms_read_their_weights_in_place(abstract_runner, name,
+                                                   rows):
+    """One expert layer of a sparse configuration at its published
+    widths, the layer's weights arguments of the program, at a decode
+    batch and at the 512-token bucket: the three ``ragged_dot`` compiled
+    for the chip make no copy of a slab and hold a fraction of one in
+    temporaries (the CPU lowering at toy widths,
+    tests/models/test_expert_layout.py, cannot show the TPU
+    compiler's). The largest bucket's are compiled inside
+    ``test_one_layer_forward_compiles`` (16 s a case alone)."""
+    runner = abstract_runner(name)
+    model, cf = runner.model, bench_config(name)
+    layer = {k: v[-1] if isinstance(v, tuple) else sds(v.shape[1:], v.dtype)
+             for k, v in runner.params["layers"].items()}
+    x = sds((rows, cf["hidden_size"]), jnp.bfloat16)
+    with runner.mesh:
+        compiled = jax.jit(model.mlp_block).lower(
+            on_chip(layer, runner.mesh), on_chip(x, runner.mesh)).compile()
+    slab, slab_bytes = expert_slab(cf)
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 3
+    assert slab in text                          # it is an argument
+    assert slab_copies(compiled, slab) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes / 4
+
+
+@pytest.mark.parametrize("name,t_bucket", [
+    (name, t) for name in ONE_LAYER
+    for t in (TOKEN_BUCKETS[0], TOKEN_BUCKETS[-1])
+    # 20 s alone, and the file has 150 s: its latent kernel compiles at
+    # this bucket above, and olmoe-1b-7b's grouped GEMMs here.
+    if (name, t) != ("gigachat3-702b-ep16", TOKEN_BUCKETS[-1])])
+def test_one_layer_forward_compiles(abstract_runner, name, t_bucket):
+    """The runner's own forward program (packed step inputs, attention
+    kernel, the layer loop, the expert switch) of each benchmark
+    configuration at its published widths, at the smallest and the
+    largest token bucket."""
+    runner = abstract_runner(name)
+    _, shapes, shardings = runner._cache_layout(1024)
+    caches = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        shapes, shardings)
+    runner._build_step_fn()
+    T, max_q, G = runner._batch_shape(t_bucket, 2)
+    inputs = on_chip(runner._dummy_step_inputs(T, max_q, G), runner.mesh)
+    with runner.mesh:
+        compiled = runner._forward_fn.lower(runner.params, caches,
+                                            inputs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    if name in SPARSE:
+        slab, _ = expert_slab(bench_config(name))
+        assert slab_copies(compiled, slab) == []

@@ -102,14 +102,14 @@ def test_matches_reference(seqs):
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
-def test_gqa_group_and_mha():
+@pytest.mark.parametrize("kvh", [1, 2, 8])
+def test_gqa_group_and_mha(kvh):
     rng = np.random.default_rng(1)
-    for kvh in (1, 2, 8):
-        case = build_case(rng, seqs=[(3, 11), (1, 4)], page_size=8,
-                          pages_per_req=4, num_q_heads=8,
-                          num_kv_heads=kvh, head_dim=128)
-        got, want = run_both(case)
-        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+    case = build_case(rng, seqs=[(3, 11), (1, 4)], page_size=8,
+                      pages_per_req=4, num_q_heads=8, num_kv_heads=kvh,
+                      head_dim=128)
+    got, want = run_both(case)
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
 def test_multi_tile_prefill_spans_tiles_and_pages():
@@ -395,12 +395,12 @@ def test_descriptor_fast_decode_rows_bypass():
     assert list(dl[:3]) == [0, 1, 2]
 
 
-def test_descriptor_length_is_deterministic_in_bucket():
+@pytest.mark.parametrize("t", [16, 64, 256])
+def test_descriptor_length_is_deterministic_in_bucket(t):
     """num_partition_programs depends only on (t_bucket, max_reqs, bq,
     sb, kv bound) — the descriptor adds no compile-lattice dimension."""
-    for t in (16, 64, 256):
-        sizes = {
-            num_partition_programs(t, 8, bq=32, sb=4, num_kv_writes=g)
-            for g in (0, 0, 0)
-        }
-        assert len(sizes) == 1
+    sizes = {
+        num_partition_programs(t, 8, bq=32, sb=4, num_kv_writes=g)
+        for g in (0, 0, 0)
+    }
+    assert len(sizes) == 1

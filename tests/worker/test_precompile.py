@@ -67,6 +67,20 @@ def test_no_recompile_after_warmup(checkpoint, monkeypatch):
     assert not engine.has_unfinished_requests()
 
 
+def test_warmup_builds_the_cascade_variants(checkpoint, monkeypatch):
+    """With shared-prefix attention on, warm-up builds a second forward
+    program a shape, with the shared ids in the packed inputs' rest
+    (since the step inputs were packed, PR 26, it raised TypeError)."""
+    monkeypatch.setenv("VDT_PRECOMPILE", "1")
+    monkeypatch.setenv("VDT_CASCADE_ATTENTION", "1")
+    path, _ = checkpoint
+    engine = make_engine(path, max_num_batched_tokens=16, max_num_seqs=4)
+    r = _runner(engine)
+    fwd = [k for k in r._compiled_shapes if k[0] == "fwd"]
+    plain = {k[:-1] for k in fwd if not k[-1]}
+    assert plain and {k[:-1] for k in fwd if k[-1]} == plain
+
+
 def test_no_recompile_after_warmup_pp(checkpoint, monkeypatch):
     """The pipeline-parallel runner's per-stage warm-up must also close
     the lattice: mixed traffic after precompile() never compiles."""

@@ -141,66 +141,6 @@ def test_mixed_wave_dispatches_unified_kernel(checkpoint, monkeypatch):
     assert 'vdt:attn_kernel_calls_total{kernel="unified"}' in text
 
 
-def _greedy_tokens(engine, n_prompts=4, max_tokens=8):
-    rng = np.random.default_rng(21)
-    prompts = [[int(x) for x in rng.integers(2, 127, size=n)]
-               for n in (3, 9, 5, 12)][:n_prompts]
-    for i, p in enumerate(prompts):
-        engine.add_request(f"bf{i}", p,
-                           SamplingParams(temperature=0.0,
-                                          max_tokens=max_tokens,
-                                          ignore_eos=True))
-    done = {}
-    for _ in range(300):
-        for out in engine.step():
-            if out.finished:
-                done[out.request_id] = out
-        if not engine.has_unfinished_requests():
-            break
-    assert not engine.has_unfinished_requests()
-    return [done[f"bf{i}"].outputs[0].token_ids
-            for i in range(n_prompts)]
-
-
-def test_block_fusion_zero_recompiles_and_token_parity(checkpoint,
-                                                       monkeypatch):
-    """ISSUE 11 acceptance: with VDT_BLOCK_FUSION=1, decode-only waves
-    dispatch the fused block (ONE Pallas call per layer, counted by
-    vdt:block_fusion_calls_total), greedy output is token-identical to
-    VDT_BLOCK_FUSION=0, and the recompile guard reports zero
-    post-precompile graphs — fusion's variants are warmed by
-    precompile(), not compiled at serving time."""
-    monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
-    path, _ = checkpoint
-    base = _greedy_tokens(
-        make_engine(path, max_num_batched_tokens=16, max_num_seqs=4))
-
-    monkeypatch.setenv("VDT_BLOCK_FUSION", "1")
-    monkeypatch.setenv("VDT_PRECOMPILE", "1")
-    monkeypatch.setenv("VDT_ASSERT_NO_RECOMPILE", "1")
-    engine = make_engine(path, max_num_batched_tokens=16, max_num_seqs=4)
-    runner = _runner(engine)
-    assert runner._precompiled
-    assert runner.model.cfg.block_fusion
-    got = _greedy_tokens(engine)  # raises on any post-warmup compile
-    assert got == base
-    stats = engine.get_stats()
-    assert stats["num_recompiles"] == 0
-    assert stats["block_fusion_calls"] > 0
-    calls = stats["attn_kernel_calls"]
-    # Decode-only waves took the fused block; prefill/mixed waves kept
-    # the mega-kernel; nothing fell back to the XLA reference.
-    assert calls.get("fused_block", 0) == stats["block_fusion_calls"]
-    assert calls.get("unified", 0) > 0
-    assert "naive" not in calls
-    # Fallback reasons cover exactly the non-decode waves.
-    fb = stats["block_fusion_fallbacks"]
-    assert set(fb) <= {"mixed_wave", "cascade", "multi_step"}
-    text = render_metrics(stats)
-    assert 'vdt:attn_kernel_calls_total{kernel="fused_block"}' in text
-    assert "vdt:block_fusion_calls_total" in text
-
-
 def test_windowed_model_reaches_unified_kernel(tmp_path_factory,
                                                monkeypatch):
     """ISSUE 11 acceptance: sliding-window waves no longer increment the

@@ -402,9 +402,9 @@ class KVTransferConfig:
     # Disaggregated serving tier (engine/disagg.py): which pool this
     # engine replica belongs to — "prefill" | "decode" | None
     # (monolithic). Read by the model runner to prune the precompile
-    # lattice per role (a prefill replica never warms decode-burst or
-    # fused-block graph variants; a decode replica's token ladder is
-    # capped by its pool config).
+    # lattice per role (a prefill replica never warms decode-burst
+    # graph variants; a decode replica's token ladder is capped by its
+    # pool config).
     pool_role: Optional[str] = None
 
     @property

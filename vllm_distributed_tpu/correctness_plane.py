@@ -22,7 +22,7 @@ suspicion ladder:
   every replica's token output + final-position logprob fingerprint
   against a content-addressed **reference journal**: the key is a
   sha256 over (prompt ids, sampling knobs, flag-config fingerprint),
-  so a fusion-on fleet and a fusion-off fleet self-seed DISJOINT
+  so fleets under different kernel flags self-seed DISJOINT
   references and a flag flip can never masquerade as corruption. The
   first unanimous round seeds the journal; after that any replica that
   strays diverges with cause ``reference`` (tokens) or ``logprob``
@@ -120,7 +120,8 @@ def canary_sampling_params() -> SamplingParams:
 def flag_config_fingerprint() -> str:
     """Hash of the full VDT flag configuration (minus the sentinel's
     own knobs): the reference-journal key component that keeps
-    fusion-on and fusion-off references from ever crossing. Over-keying
+    references taken under different kernel flags from ever crossing
+    (VDT_FUSED_KV_WRITE on against off). Over-keying
     is safe — an unrelated flag flip merely re-seeds."""
     from vllm_distributed_tpu import envs
     parts = []

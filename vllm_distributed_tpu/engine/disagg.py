@@ -10,7 +10,7 @@ replicas SPECIALIZED for them, because they want opposite things:
   steals decode steps from every interactive stream on it (each mixed
   wave pads to the large token bucket the prefill chunk forces);
 * **decode** is bandwidth-bound and wants deep pure-decode batches,
-  TPLA/block-fusion-shaped kernels, and a SMALL compiled lattice.
+  TPLA-shaped kernels, and a SMALL compiled lattice.
 
 ``DisaggCoordinator`` composes the pieces previous PRs built — the
 versioned standard/latent KV wire formats that re-slice across

@@ -1055,17 +1055,6 @@ class DPEngineClient(EngineCoreClient):
                 for k, v in m.items():
                     merged_calls[k] = merged_calls.get(k, 0) + int(v)
             agg["attn_kernel_calls"] = merged_calls
-        # Fused-block fallback reasons: {reason: steps}, summed like the
-        # kernel dispatch map (block_fusion_calls itself is a flat
-        # numeric and already summed above).
-        fb_maps = [s["block_fusion_fallbacks"] for s in per
-                   if isinstance(s.get("block_fusion_fallbacks"), dict)]
-        if fb_maps:
-            merged_fb: dict = {}
-            for m in fb_maps:
-                for k, v in m.items():
-                    merged_fb[k] = merged_fb.get(k, 0) + int(v)
-            agg["block_fusion_fallbacks"] = merged_fb
         # Per-tenant QoS families: {tenant: {granted_tokens, kv_blocks,
         # preemptions}}, summed per tenant per leaf across replicas
         # (every scheduler buckets through qos.bucket_tenant so each

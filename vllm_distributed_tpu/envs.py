@@ -57,16 +57,6 @@ environment_variables: dict[str, Callable[[], Any]] = {
     # "0" keeps the separate write-then-attend pair for debugging.
     "VDT_FUSED_KV_WRITE":
     lambda: os.getenv("VDT_FUSED_KV_WRITE", "1") == "1",
-    # Fused transformer-block decode (ops/pallas_block.py): decode-only
-    # waves on an eligible dense model run each layer as ONE Pallas call
-    # (RMSNorm -> fused QKV -> rope + KV-page write + attention ->
-    # O-proj -> residual -> RMSNorm -> gated MLP -> residual), keeping
-    # activations in VMEM across the layer. Default OFF until the parity
-    # gates pin it; "0" reverts wholesale to the per-op mega-kernel
-    # path. Eligibility is decided ONCE in models/loader.py (arch shape,
-    # TP=1); read at model load.
-    "VDT_BLOCK_FUSION":
-    lambda: os.getenv("VDT_BLOCK_FUSION", "0") == "1",
     # Fraction of HBM usable for weights+KV (analogue of gpu_memory_utilization
     # default source).
     "VDT_MEMORY_FRACTION":

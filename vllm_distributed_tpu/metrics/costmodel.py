@@ -30,8 +30,8 @@ tests/metrics/test_costmodel.py pins them with hand-computed counts):
   the KV length it attends (``kv_terms``); causal prefill therefore
   charges ``ctx*n + n(n+1)/2`` pairs per request chunk and decode
   ``ctx+1``. A uniform sliding window clamps the span.
-* **Peaks are fleet peaks** — per-chip public-spec numbers (shared
-  with bench.py) times the mesh's device count; non-TPU backends get
+* **Peaks are fleet peaks** — per-chip public-spec numbers times the
+  mesh's device count; non-TPU backends get
   a nominal host peak so CPU-smoke MFU/MBU stay comparable
   run-to-run (they are not absolute utilization there).
 """
@@ -44,8 +44,7 @@ from vllm_distributed_tpu.logger import init_logger
 
 logger = init_logger(__name__)
 
-# Peak dense-matmul FLOP/s per chip (public specs, bf16). Single source
-# for bench.py and the in-engine plane.
+# Peak dense-matmul FLOP/s per chip (public specs, bf16).
 PEAK_FLOPS_PER_CHIP = {
     "v4": 275e12,
     "v5e": 197e12,
@@ -461,38 +460,6 @@ class CostModel:
                 "kv_row_bytes": float(kv_row_bytes),
             },
         )
-
-    @classmethod
-    def from_hf_dims(cls, hf: dict, *, dtype_bytes: int = 2,
-                     device_kind: str = "", num_chips: int = 1,
-                     kv_cache_dtype_bytes: Optional[int] = None,
-                     page_padded_head_dim: Optional[int] = None,
-                     ) -> "CostModel":
-        """bench.py entry: price the bench model straight from HF dims
-        (no engine needed), mirroring the llama storage layout."""
-        H = hf["hidden_size"]
-        hd = hf.get("head_dim") or H // hf["num_attention_heads"]
-        shd = page_padded_head_dim or hd
-
-        class _Arch:
-            pass
-
-        a = _Arch()
-        a.hidden_size = H
-        a.num_layers = hf["num_hidden_layers"]
-        a.intermediate_size = hf["intermediate_size"]
-        a.vocab_size = hf["vocab_size"]
-        a.head_dim = hd
-        a.num_q_heads = hf["num_attention_heads"]
-        a.num_kv_heads = hf.get("num_key_value_heads",
-                                hf["num_attention_heads"])
-        a.dtype = {2: "bfloat16", 4: "float32"}.get(dtype_bytes,
-                                                    "float32")
-        kv_bytes = kv_cache_dtype_bytes or dtype_bytes
-        kv_row = (2 * a.num_layers * a.num_kv_heads * shd * kv_bytes)
-        return cls.from_arch(a, kv_row_bytes=kv_row,
-                             num_chips=num_chips,
-                             device_kind=device_kind)
 
 
 def resolve_cost_model(model: Any, config: Any, mesh=None

@@ -97,12 +97,6 @@ _COUNTERS = {
     "precompile_graphs": ("vdt:precompile_graphs_total",
                           "XLA graphs compiled by the precompile "
                           "warm-up suite"),
-    # Fused decode-block dispatch (ops/pallas_block.py): rendered only
-    # while the loader enabled VDT_BLOCK_FUSION for this model.
-    "block_fusion_calls": ("vdt:block_fusion_calls_total",
-                           "Decode-only waves dispatched through the "
-                           "fused transformer-block kernel (one Pallas "
-                           "call per layer)"),
     # SSM state cache (core/state_cache.py): prefix-style admission at
     # snapshot boundaries for stateful (Mamba/Jamba) models.
     "ssm_state_cache_hits": ("vdt:ssm_state_cache_hits_total",
@@ -228,11 +222,8 @@ LABELED_METRICS = {
     "vdt:kv_tier_promotions_total": ("tier", ),
     "vdt:kv_tier_misses_total": ("tier", ),
     # Attention dispatch: which kernel family each step ran
-    # (fused_block|unified|decode|general|cascade|naive).
+    # (unified|decode|general|cascade|naive: five values).
     "vdt:attn_kernel_calls_total": ("kernel", ),
-    # Fused-block waves that fell back to the per-op path while fusion
-    # was enabled (mixed_wave|cascade|multi_step).
-    "vdt:block_fusion_fallbacks_total": ("reason", ),
     # Quantized communication plane (parallel/collectives.py +
     # kv_transfer/quant.py): per-path wire/disk bytes saved.
     "vdt:qcomm_bytes_saved_total": ("path", ),
@@ -887,19 +878,6 @@ def render_metrics(stats: dict) -> str:
                   f"# TYPE {name} counter"]
         lines += [f'{name}{{kernel="{k}"}} {int(calls[k])}'
                   for k in sorted(calls)]
-    # Fused-block fallback reasons ({reason: steps} from the runner,
-    # present only while VDT_BLOCK_FUSION is live for the model).
-    fb = stats.get("block_fusion_fallbacks")
-    if isinstance(fb, dict):
-        name = "vdt:block_fusion_fallbacks_total"
-        lines += [f"# HELP {name} Waves that fell back from the fused "
-                  "decode-block kernel to the per-op path while fusion "
-                  "was enabled (mixed_wave = prefill tokens or per-token "
-                  "features in the wave, cascade = shared-prefix split, "
-                  "multi_step = fused decode burst)",
-                  f"# TYPE {name} counter"]
-        lines += [f'{name}{{reason="{k}"}} {int(fb[k])}'
-                  for k in sorted(fb)]
     # Telemetry plane (worker device/compilation, transport, KV cache):
     # nested dicts shipped up the stats RPC, labeled at the source.
     workers = stats.get("workers")

@@ -126,12 +126,6 @@ class AttentionBatch:
     # disagree. 0 when no descriptor is attached.
     attn_bq: int = 0
     attn_sb: int = 0
-    # Fused transformer-block decode (ops/pallas_block.py): the runner
-    # sets this STATIC flag on decode-only waves of an eligible model
-    # under VDT_BLOCK_FUSION=1 — run_layers then executes each layer as
-    # ONE Pallas call instead of the per-op path. A meta field like
-    # max_q: flipping it selects a different (precompile-warmed) graph.
-    block_fused: bool = False
 
 
 @dataclasses.dataclass
@@ -161,9 +155,9 @@ jax.tree_util.register_dataclass(
     AttentionBatch,
     data_fields=[
         f.name for f in dataclasses.fields(AttentionBatch)
-        if f.name not in ("max_q", "attn_bq", "attn_sb", "block_fused")
+        if f.name not in ("max_q", "attn_bq", "attn_sb")
     ],
-    meta_fields=["max_q", "attn_bq", "attn_sb", "block_fused"],
+    meta_fields=["max_q", "attn_bq", "attn_sb"],
 )
 
 

@@ -159,12 +159,6 @@ class LlamaArchConfig:
     # on the model axis between blocks (see ParallelConfig.
     # enable_sequence_parallel).
     sequence_parallel: bool = False
-    # Fused transformer-block decode (ops/pallas_block.py): set ONCE by
-    # models/loader.py when VDT_BLOCK_FUSION=1 and the arch/parallel
-    # layout qualifies (standard dense pre-norm gated block, TP=1).
-    # Adds the re-laid "wqkv" fused projection to the param tree and
-    # unlocks the block_fused batch path in run_layers.
-    block_fusion: bool = False
     # Family knobs reused by Llama-shaped variants: embedding scale
     # (Gemma multiplies by sqrt(H)), MLP activation, per-head q/k
     # RMSNorm (Qwen3).
@@ -610,8 +604,6 @@ class LlamaForCausalLM:
                 "post_attn_ln": P(None, None),
                 "post_ffw_ln": P(None, None),
             })
-        if c.block_fusion:
-            layer["wqkv"] = P(None, None, MODEL_AXIS)
         self._add_scale_specs(layer)
         self._add_lora_specs(layer)
         specs = {
@@ -773,7 +765,6 @@ class LlamaForCausalLM:
                 "post_ffw_ln": jnp.ones((L, H), c.dtype),
             })
         self._maybe_replicate_kv(layers)
-        self._maybe_fuse_qkv(layers)
         self._install_lora_buffers(layers)
         embed = norm(next(keys), (c.vocab_size, H))
         out = {
@@ -795,32 +786,6 @@ class LlamaForCausalLM:
         if c.norm_bias:
             out["final_ln_b"] = jnp.zeros((H, ), c.dtype)
         return out
-
-    def _maybe_fuse_qkv(self, layers: dict) -> None:
-        """Re-lay the QKV projections for the fused decode block: one
-        stacked [L, H, Dq + 2*Dkv] concat that ops/pallas_block.py
-        streams as a SINGLE weight (no per-projection kernel
-        boundaries). Built only when the loader enabled block fusion
-        (VDT_BLOCK_FUSION, default off): the canonical wq/wk/wv stay
-        for the prefill/mixed per-op path, so fusion trades one extra
-        HBM copy of the QKV weights for the fused decode stream."""
-        if not getattr(self.cfg, "block_fusion", False):
-            return
-        if (self.cfg.attention_bias or self.cfg.attention_out_bias
-                or self.cfg.mlp_bias):
-            # Checkpoint auto-detection (undeclared qkv biases, Qwen2
-            # style) can flip bias flags AFTER the loader's eligibility
-            # decision; the fused kernel carries no biases, so revoke
-            # fusion rather than silently dropping them.
-            from vllm_distributed_tpu.logger import init_logger
-            init_logger(__name__).warning(
-                "block fusion revoked: checkpoint carries projection "
-                "biases the fused kernel does not; decode waves keep "
-                "the per-op mega-kernel path")
-            self.cfg.block_fusion = False
-            return
-        layers["wqkv"] = jnp.concatenate(
-            [layers["wq"], layers["wk"], layers["wv"]], axis=-1)
 
     def _maybe_replicate_kv(self, layers: dict) -> None:
         """Expand K/V projection weights in place when KV heads are
@@ -1023,7 +988,6 @@ class LlamaForCausalLM:
                     "model.layers.{}.pre_feedforward_layernorm.weight",
                     transpose=False)
         self._maybe_replicate_kv(layers)
-        self._maybe_fuse_qkv(layers)
         embed = jnp.asarray(t("model.embed_tokens.weight"), dtype=c.dtype)
         if c.tie_word_embeddings or "lm_head.weight" not in tensors:
             lm_head = embed.T
@@ -1319,38 +1283,6 @@ class LlamaForCausalLM:
 
         rm = c.residual_multiplier
 
-        # Fused transformer-block decode (ops/pallas_block.py): the
-        # runner flags decode-only waves of an eligible model (see the
-        # loader's block-fusion decision) and each layer collapses to
-        # ONE Pallas call — RMSNorm -> fused QKV -> rope + KV write +
-        # attention -> O-proj -> RMSNorm -> gated MLP, activations in
-        # VMEM throughout. Eligibility guarantees the standard dense
-        # pre-norm gated block (no biases/qk-norms/LoRA/quant/MoE), full
-        # head-dim rope and TP=1, so the per-op features below are
-        # structurally absent; window/softcap/ALiBi/sinks ride kernel
-        # statics + the head-feature sidecar like the mega-kernel.
-        use_fused = bool(getattr(batch, "block_fused", False)
-                         and c.block_fusion)
-        if use_fused:
-            from vllm_distributed_tpu.ops.pallas_block import \
-                fused_block_decode_pallas
-            rope_tab = jnp.stack([cos, sin])
-
-        def fused_body(h, k_all, v_all, lp, layer_idx, window):
-            from vllm_distributed_tpu.ops.attention import build_head_feat
-            ln_w = jnp.stack([lp["input_ln"], lp["post_ln"]])
-            feat = build_head_feat(
-                c.num_q_heads, slopes,
-                lp["sinks"] if c.attn_sinks else None)
-            return fused_block_decode_pallas(
-                h, k_all, v_all, lp["wqkv"], lp["wo"], lp["gate"],
-                lp["up"], lp["down"], ln_w, rope_tab, feat,
-                batch.seq_info, batch.num_seqs, batch.block_tables,
-                layer_idx, sm_scale=sm_scale, eps=c.rms_norm_eps,
-                num_q_heads=c.num_q_heads, head_dim=c.head_dim,
-                window=window, logit_cap=c.attn_logit_softcap,
-                has_alibi=c.alibi, has_sinks=c.attn_sinks)
-
         def mlp(lp, x, layer_idx):
             """The MLP block of the layer at ``layer_idx`` (traced). Its
             per-layer buffers are picked by a switch with one branch a
@@ -1369,9 +1301,6 @@ class LlamaForCausalLM:
 
         def layer_body(h, k_all, v_all, lp, layer_idx, window,
                        nope=False):
-            if use_fused:
-                return fused_body(h, k_all, v_all, lp, layer_idx,
-                                  window)
             if c.pre_norm:
                 x = self._norm(h, lp["input_ln"], lp.get("input_ln_b"))
             else:

@@ -324,28 +324,11 @@ def get_model(config: EngineConfig, mesh,
         logger.info(
             "replicating %d KV heads x%d to cover tensor_parallel_size=%d",
             arch.num_kv_heads, arch.num_kv_head_replicas, tp)
-    # Fused transformer-block decode (ops/pallas_block.py): decided
-    # ONCE here — the param tree (re-laid wqkv), the runner's dispatch
-    # and the forward all key on it. VDT_BLOCK_FUSION=0 (the default)
-    # reverts wholesale to the per-op mega-kernel path.
-    arch.block_fusion = False
-    from vllm_distributed_tpu import envs as _envs_bf
-    if _envs_bf.VDT_BLOCK_FUSION:
-        reason = block_fusion_ineligible_reason(arch, model_cls, config)
-        if reason is None:
-            arch.block_fusion = True
-            logger.info(
-                "block fusion ON: decode-only waves run one fused "
-                "Pallas call per layer (VDT_BLOCK_FUSION=1)")
-        else:
-            logger.info(
-                "block fusion requested but ineligible (%s); decode "
-                "waves keep the per-op mega-kernel path", reason)
     model = model_cls(arch)
 
     # Performance-attribution plane (metrics/costmodel.py): the analytic
     # per-dispatch FLOP/byte model is priced ONCE here, from the final
-    # arch shapes (post TPLA/fusion/quant decisions), and rides the arch
+    # arch shapes (post TPLA/quant decisions), and rides the arch
     # so every runner variant (single-program, PP stages) charges
     # dispatches against the same constants. VDT_PERF_ATTRIB=0 attaches
     # None — the runners' per-step charge degrades to one None check.
@@ -365,19 +348,6 @@ def get_model(config: EngineConfig, mesh,
         params = ocp.StandardCheckpointer().restore(
             os.path.abspath(ckpt_dir))
         logger.info("restored sharded state from %s", ckpt_dir)
-        if (getattr(arch, "block_fusion", False)
-                and "wqkv" not in params.get("layers", {})):
-            # Tree saved under the per-op path: build the fused
-            # projection now so VDT_BLOCK_FUSION=1 serves from any
-            # sharded-state snapshot (the method also re-checks the
-            # bias revoke).
-            model._maybe_fuse_qkv(params["layers"])
-        if not getattr(arch, "block_fusion", False):
-            # The reverse direction: a snapshot SAVED under fusion,
-            # reloaded with fusion off/revoked — drop the stale fused
-            # weight so the tree matches param_specs() again.
-            if isinstance(params.get("layers"), dict):
-                params["layers"].pop("wqkv", None)
     elif load_format == "dummy":
         rng = jax.random.PRNGKey(config.model_config.seed)
         params = _init_dummy_params(model, rng, mesh if shard else None)
@@ -443,59 +413,6 @@ def _init_dummy_params(model, rng, mesh) -> dict:
 
     return jax.jit(model.init_params,
                    out_shardings=shardings(abstract, specs))(rng)
-
-
-def block_fusion_ineligible_reason(arch, model_cls,
-                                   config) -> Optional[str]:
-    """Why the fused decode-block kernel (ops/pallas_block.py) cannot
-    serve this (arch, parallel layout) — None when eligible. The kernel
-    implements exactly the standard dense pre-norm gated Llama block
-    (RMSNorm / fused QKV / full-head rope / paged KV / SwiGLU) on one
-    chip; anything structurally different keeps the per-op path.
-    Sliding window / softcap / ALiBi / sinks are NOT exclusions — they
-    ride the kernel's per-layer statics + head-feature sidecar."""
-    pc = config.parallel_config
-    checks = (
-        (pc.tensor_parallel_size > 1, "tensor parallelism"),
-        (pc.pipeline_parallel_size > 1, "pipeline parallelism"),
-        (pc.token_parallel_size > 1, "token parallelism"),
-        (pc.enable_sequence_parallel, "sequence parallelism"),
-        (getattr(model_cls, "ENCODER_ONLY", False), "encoder-only arch"),
-        (getattr(model_cls, "CROSS_ATTENTION", False),
-         "cross-attention arch"),
-        (getattr(arch, "mla", False), "MLA latent cache"),
-        (getattr(arch, "stateful", False), "stateful (SSM) layers"),
-        (getattr(arch, "kv_cache_dtype", None) is not None,
-         "fp8 KV cache"),
-        (arch.num_experts > 0, "MoE layers"),
-        (arch.dense_prefix > 0, "mixed dense/sparse stack"),
-        (arch.quantization is not None, "weight quantization"),
-        (arch.max_loras > 0, "LoRA adapters"),
-        (not arch.pre_norm, "post-norm block"),
-        (not arch.mlp_gated, "non-gated MLP"),
-        (arch.norm_type != "rms", "non-RMS norms"),
-        (arch.parallel_block, "parallel-residual block"),
-        (arch.extra_layer_norms, "sandwich/post norms"),
-        (arch.qk_norm or arch.qk_norm_full, "q/k norms"),
-        (arch.attention_bias or arch.attention_out_bias
-         or arch.mlp_bias, "projection biases"),
-        (arch.qkv_clip is not None, "qkv clipping"),
-        (arch.pos_embedding != "rope", "non-rope positions"),
-        (arch.rotary_dim is not None
-         and arch.rotary_dim != arch.head_dim, "partial rotary"),
-        (arch.rope_interleaved, "pairwise rope"),
-        (arch.mrope_section is not None, "M-RoPE"),
-        (arch.nope_layers is not None, "NoPE layer mix"),
-        (arch.rope_theta_local is not None, "per-layer rope bases"),
-        (arch.residual_multiplier != 1.0, "residual multiplier"),
-    )
-    for bad, why in checks:
-        if bad:
-            return why
-    from vllm_distributed_tpu.ops.attention import storage_head_dim
-    if storage_head_dim(arch.head_dim) != arch.head_dim:
-        return "lane-padded KV storage (head_dim % 128 != 0 on TPU)"
-    return None
 
 
 def resolve_encoder_only(model_config) -> bool:

@@ -170,7 +170,7 @@ class TPUModelRunner:
         self.req_buckets = make_buckets(8, self.max_num_reqs)
         # Disagg pool role ("prefill" | "decode" | None): prunes the
         # precompile lattice per role — a prefill replica skips the
-        # fused-block/multi-step decode variants, a decode replica
+        # multi-step decode variants, a decode replica
         # skips the prompt-logprob graphs (plp requests are exempt from
         # handoff and serve on the prefill pool); the decode pool's
         # token ladder itself is already capped by its pool config.
@@ -298,19 +298,11 @@ class TPUModelRunner:
         self._step_layouts: dict[tuple[int, int], StepLayout] = {}
         self._xla_route_memo: Optional[bool] = None
         # Kernel-dispatch observability: one count per step per kernel
-        # family (fused_block|unified|decode|general|cascade|naive)
+        # family (unified|decode|general|cascade|naive: five outcomes)
         # behind vdt:attn_kernel_calls_total, plus the warmed-graph
         # count behind vdt:precompile_graphs_total.
         self.attn_kernel_calls: dict[str, int] = {}
         self.precompile_graphs = 0
-        # Fused decode-block dispatch (ops/pallas_block.py): steps that
-        # ran the fused path vs steps that fell back (by reason) while
-        # fusion was enabled+eligible — vdt:block_fusion_calls_total /
-        # vdt:block_fusion_fallbacks_total{reason}. Eligibility is the
-        # loader's once-per-load decision; None until the model exists.
-        self._block_fusion_memo: Optional[bool] = None
-        self.block_fusion_calls = 0
-        self.block_fusion_fallbacks: dict[str, int] = {}
         # Performance-attribution plane (metrics/costmodel.py): the
         # loader priced the model once (arch.cost_model; None when
         # VDT_PERF_ATTRIB=0). Every dispatch is charged analytic FLOPs
@@ -980,44 +972,6 @@ class TPUModelRunner:
             self._unified = "k" in self.model.kv_cache_specs()
         return self._unified
 
-    def _block_fusion_active(self) -> bool:
-        """Can this engine dispatch the fused decode-block path at all?
-        The loader decided arch eligibility once (cfg.block_fusion,
-        VDT_BLOCK_FUSION-gated); the runner adds the dispatch-side
-        requirements: the unified (descriptor) batch layout, no token
-        parallelism, and the Pallas backend (the XLA-composed reference
-        exists for tests, not serving — on the XLA backend the per-op
-        path IS the reference)."""
-        if self._block_fusion_memo is None:
-            if self.model is None:
-                return False  # don't memoize before the model exists
-            # Disagg prefill-pool replicas never see a pure-decode wave
-            # (their requests finish at the first sampled token), so
-            # fusion neither warms its graph variants nor dispatches —
-            # the per-role precompile-lattice prune (engine/disagg.py).
-            self._block_fusion_memo = bool(
-                getattr(self.model.cfg, "block_fusion", False)
-                and self.pool_role != "prefill"
-                and self._use_unified()
-                and self.tknp_size == 1
-                and resolve_attention_backend() == "pallas")
-        return self._block_fusion_memo
-
-    def _count_block_fusion(self, inputs: StepInputs = None,
-                            reason: str = None) -> None:
-        """Per-step fused-dispatch accounting, only while fusion is
-        enabled+eligible so the families stay silent otherwise."""
-        if not self._block_fusion_active():
-            return
-        if reason is None and inputs is not None:
-            if inputs.rest.block_fused:
-                self.block_fusion_calls += 1
-                return
-            reason = ("cascade" if inputs.rest.cascade_shared_ids is not None
-                      else "mixed_wave")
-        self.block_fusion_fallbacks[reason] = (
-            self.block_fusion_fallbacks.get(reason, 0) + 1)
-
     def _tile_params(self) -> tuple[int, int]:
         """The fixed (prefill tile rows, decode group width) of the
         mega-kernel, computed from LOCAL head counts (the kernel runs
@@ -1520,13 +1474,6 @@ class TPUModelRunner:
             max_q=max_q,
             attn_bq=bq,
             attn_sb=sb,
-            # Fused decode-block dispatch: the vectorized-prep fast path
-            # already proves this wave is pure single-token decode with
-            # none of the per-token features (spec drafts / M-RoPE /
-            # LoRA / tknp / plp / mm) the fused kernel would miss.
-            block_fused=bool(self._block_fusion_active()
-                             and fast is not None
-                             and cascade_ids is None),
         )
         plp = None
         if plp_rows:
@@ -1736,7 +1683,6 @@ class TPUModelRunner:
         with ph.span("vdt.launch", "launch"):
             attn_label = self._attn_kernel_label(inputs)
             self._count_attn_dispatch(attn_label)
-            self._count_block_fusion(inputs)
             perf = self._perf_charge(scheduler_output, attn_label,
                                      fwd_shape[0])
             drafts_arr, q_ids, q_probs, spec_truncate = spec_pack
@@ -2082,8 +2028,7 @@ class TPUModelRunner:
         pipeline-parallel runner overrides only the forward half."""
         with self.mesh:
             cascade = inputs.rest.cascade_shared_ids is not None
-            with self._compile_watch(("fwd", ) + fwd_shape +
-                                     (cascade, inputs.rest.block_fused)):
+            with self._compile_watch(("fwd", ) + fwd_shape + (cascade, )):
                 self.kv_caches, hidden = self._forward_fn(
                     self.params, self.kv_caches, inputs)
             return self._launch_sample(hidden, logits_indices,
@@ -2274,7 +2219,6 @@ class TPUModelRunner:
         """Run scheduler_output.multi_step fused decode steps (pure-decode
         batch; one host roundtrip for the whole burst)."""
         self._count_attn_dispatch(self._multi_step_label())
-        self._count_block_fusion(reason="multi_step")
         ib = self.input_batch
         n_steps = scheduler_output.multi_step
         req_ids = list(scheduler_output.num_scheduled_tokens)
@@ -2371,14 +2315,11 @@ class TPUModelRunner:
         """Which attention kernel family this step's batch dispatches to
         (mirrors the ops/attention.py routing, including the feature
         gates that force the XLA path): the vdt:attn_kernel_calls_total
-        {kernel} observability for the dispatch layer."""
-        from vllm_distributed_tpu.ops.attention import \
-            resolve_attention_backend
+        {kernel} observability for the dispatch layer. Five outcomes:
+        naive, cascade, unified, decode, general."""
         if (resolve_attention_backend() != "pallas"
                 or self._model_routes_xla()):
             return "naive"
-        if inputs.rest.block_fused:
-            return "fused_block"
         if inputs.rest.cascade_shared_ids is not None:
             return ("naive" if self._model_has_attn_features()
                     else "cascade")
@@ -2412,8 +2353,6 @@ class TPUModelRunner:
         in-jit batches carry no partition descriptor, so they ride the
         legacy SB decode kernel on the Pallas backend (and
         window/softcap/ALiBi/sink models the XLA path)."""
-        from vllm_distributed_tpu.ops.attention import \
-            resolve_attention_backend
         return ("decode" if (resolve_attention_backend() == "pallas"
                              and not self._model_routes_xla()
                              and not self._model_has_attn_features())
@@ -2501,7 +2440,9 @@ class TPUModelRunner:
         """Track/log compilations; after precompile() has run, any new
         shape is a recompile-guard violation (reference:
         tpu_model_runner.py:318 _update_num_xla_graphs /
-        _verify_num_xla_graphs)."""
+        _verify_num_xla_graphs). A forward's key has five elements:
+        ("fwd", T, max_q, G, cascade), the batch shape of
+        ``_batch_shape`` and whether the shared-prefix ids ride along."""
         new = key not in self._compiled_shapes
         if new:
             if self._precompiled:
@@ -2608,48 +2549,31 @@ class TPUModelRunner:
         tpu_model_runner.py:1248-1443 precompilation suite): all forward
         shapes, all sampler shapes, and the fused multi-step graph. After
         this, a compile during serving is a bug (_compile_watch)."""
+        import dataclasses
+
+        from vllm_distributed_tpu import envs
         assert self.kv_caches is not None, "initialize_kv_cache first"
         start = time.perf_counter()
         n = 0
+        cascade = envs.VDT_CASCADE_ATTENTION
         with self.mesh:
-            # Pure-decode waves can present any token bucket up to the
-            # request ceiling; those buckets additionally warm the
-            # fused-block variant when fusion is on.
-            fusion_t_max = (pad_to_bucket(self.max_num_reqs,
-                                          self.token_buckets)
-                            if self._block_fusion_active() else -1)
             for T, max_q, G in sorted(self.forward_shapes()):
                 inputs = self._dummy_step_inputs(T, max_q, G)
-                with self._compile_watch(("fwd", T, max_q, G, False,
-                                          False)):
+                with self._compile_watch(("fwd", T, max_q, G, False)):
                     self.kv_caches, hidden = self._forward_fn(
                         self.params, self.kv_caches, inputs)
                 jax.block_until_ready(hidden)
                 n += 1
-                import dataclasses as _dc
-
-                from vllm_distributed_tpu import envs as _envs
-                from vllm_distributed_tpu.ops.pallas_attention import \
-                    Q_TILE_PAD
-                if (max_q == 1 and inputs.layout.desc_programs
-                        and 0 <= T - Q_TILE_PAD <= fusion_t_max):
-                    with self._compile_watch(("fwd", T, max_q, G, False,
-                                              True)):
+                if cascade:
+                    shared = jnp.zeros((envs.VDT_CASCADE_SHARED_PAGES, ),
+                                       jnp.int32)
+                    with self._compile_watch(("fwd", T, max_q, G, True)):
                         self.kv_caches, hidden = self._forward_fn(
                             self.params, self.kv_caches,
-                            _dc.replace(inputs, rest=_dc.replace(
-                                inputs.rest, block_fused=True)))
-                    jax.block_until_ready(hidden)
-                    n += 1
-                if _envs.VDT_CASCADE_ATTENTION:
-                    S = _envs.VDT_CASCADE_SHARED_PAGES
-                    cinputs = _dc.replace(
-                        inputs,
-                        cascade_shared_ids=jnp.zeros((S, ), jnp.int32))
-                    with self._compile_watch(("fwd", T, max_q, G, True,
-                                              False)):
-                        self.kv_caches, hidden = self._forward_fn(
-                            self.params, self.kv_caches, cinputs)
+                            dataclasses.replace(
+                                inputs, rest=dataclasses.replace(
+                                    inputs.rest,
+                                    cascade_shared_ids=shared)))
                     jax.block_until_ready(hidden)
                     n += 1
             n += self._precompile_samplers(self.mesh)
@@ -2845,15 +2769,6 @@ class TPUModelRunner:
             # Correctness-sentinel numerics (per replica; the DP merge
             # keys this by replica index, never numeric-summed).
             stats["numerics"] = self._numerics.stats()
-        if self.model is not None and getattr(self.model.cfg,
-                                              "block_fusion", False):
-            # Fused decode-block dispatch (vdt:block_fusion_calls_total
-            # / vdt:block_fusion_fallbacks_total{reason}): rendered only
-            # while the loader enabled fusion, so the families are a
-            # positive signal that the flag is live.
-            stats["block_fusion_calls"] = self.block_fusion_calls
-            stats["block_fusion_fallbacks"] = dict(
-                self.block_fusion_fallbacks)
         if self.model is not None and getattr(self.model.cfg, "mla",
                                               False):
             # MLA latent-pool geometry (vdt:tpla_latent_shards /
