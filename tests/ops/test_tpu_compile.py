@@ -380,10 +380,53 @@ def test_mla_latent_attention_compiles_at_published_widths(
     assert f"%{KERNEL_NAME}" in compiled.as_text()
 
 
+@pytest.mark.parametrize("tokens,form", [
+    (1, "decode"), (2048, "chunk"), (2048, "auto")])
+def test_gated_delta_kernels_compile_at_published_widths(
+        compile_for_chip, tokens, form):
+    """``ops/kda.py`` as `ling3-flash-ep4` serves it: 32 heads of 128 x
+    128 float32 state, 96 state rows and the dump row, six layers in one
+    buffer; a decode batch (one token a row: ``kda_recurrent``), and a
+    2 048-token chunk bucket in the chunked form (``kda_chunk``), token
+    by token, and as the program picks between them at run time."""
+    from benchmarks.rooflines.gated_delta_state import kda_layers
+    from vllm_distributed_tpu.ops import kda
+    from vllm_distributed_tpu.ops.mamba import SegmentInfo
+    cf = bench_config("ling3-flash-ep4")
+    ea = cf["engine_args"]
+    N, D, S = cf["num_attention_heads"], cf["head_dim"], ea["max_num_seqs"]
+    layers = kda_layers(cf)
+    assert (N, D, S, layers) == (32, 128, 96, 6)
+    T = S + 1 if form == "decode" else tokens + 128
+    f32, flag = jnp.float32, jnp.bool_
+    seg = SegmentInfo(
+        row=sds((T, )), valid=sds((T, ), flag), off=sds((T, )),
+        start=sds((T, ), flag), end=sds((T, ), flag),
+        has_init=sds((T, ), flag), q_len_by_row=sds((S + 1, )),
+        q_start_by_row=sds((S + 1, )),
+        has_init_by_row=sds((S + 1, ), flag))
+    leaves, tree = jax.tree.flatten(seg)
+
+    def fn(q, k, v, g, beta, state, layer, *seg_leaves):
+        return kda.kda_ragged(
+            q, k, v, g, beta, state, layer,
+            jax.tree.unflatten(tree, seg_leaves),
+            decode_only=form == "decode",
+            prefill_form="auto" if form == "decode" else form,
+            interpret=False)
+
+    tok = sds((T, N, D), f32)
+    text = compile_for_chip(
+        fn, tok, tok, tok, tok, sds((T, N), f32),
+        sds((layers, S + 1, N, D, D), f32), sds((1, )), *leaves).as_text()
+    assert f"%{kda.KERNEL_RECURRENT}" in text
+    assert (f"%{kda.KERNEL_CHUNK}" in text) == (form in ("chunk", "auto"))
+
+
 # ---------------------------------------------------------------------
 # The benchmark configurations' own programs, weights as shapes
 # ---------------------------------------------------------------------
-SPARSE = ("olmoe-1b-7b", "gigachat3-702b-ep16")
+SPARSE = ("olmoe-1b-7b", "gigachat3-702b-ep16", "ling3-flash-ep4")
 # Depth cut to one layer of each kind a configuration has: widths,
 # heads, experts and engine limits stay the file's.
 ONE_LAYER = {
@@ -391,6 +434,9 @@ ONE_LAYER = {
     "olmoe-1b-7b": {"num_hidden_layers": 1},
     "gigachat3-702b-ep16": {"num_hidden_layers": 2,
                             "first_k_dense_replace": 1},
+    # KDA with the dense MLP, KDA with the experts, latent attention
+    # with the experts: a period of three in place of the file's six.
+    "ling3-flash-ep4": {"num_hidden_layers": 3, "layer_group_size": 3},
 }
 
 

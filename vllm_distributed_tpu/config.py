@@ -98,18 +98,21 @@ class ModelConfig:
                         self.model,
                         trust_remote_code=self.trust_remote_code)
             except Exception:
-                # Only fall back when the overrides actually pin down the
-                # architecture — a partial override on top of LlamaConfig
-                # defaults would silently run a different model.
+                hf_config = self._local_config_of_known_architecture()
+                # Otherwise only fall back when the overrides actually pin
+                # down the architecture — a partial override on top of
+                # LlamaConfig defaults would silently run a different
+                # model.
                 required = {"vocab_size", "hidden_size",
                             "num_hidden_layers", "num_attention_heads"}
-                if not required.issubset(self.hf_overrides):
-                    raise
-                from transformers import LlamaConfig
-                logger.warning(
-                    "could not resolve HF config for %r; building a "
-                    "LlamaConfig from hf_overrides", self.model)
-                hf_config = LlamaConfig()
+                if hf_config is None:
+                    if not required.issubset(self.hf_overrides):
+                        raise
+                    from transformers import LlamaConfig
+                    logger.warning(
+                        "could not resolve HF config for %r; building a "
+                        "LlamaConfig from hf_overrides", self.model)
+                    hf_config = LlamaConfig()
             for k, v in self.hf_overrides.items():
                 setattr(hf_config, k, v)
             self.hf_config = hf_config
@@ -117,6 +120,25 @@ class ModelConfig:
             derived = getattr(self.hf_config, "max_position_embeddings", 2048)
             self.max_model_len = int(derived)
         return self.hf_config
+
+    def _local_config_of_known_architecture(self) -> Any:
+        """A local ``config.json`` whose ``model_type`` transformers does
+        not know (or that has none) but whose ``architectures`` this
+        program registers: its keys as a plain ``PretrainedConfig``."""
+        import json
+        import os
+        path = os.path.join(self.model, "config.json")
+        if not os.path.isfile(path):
+            return None
+        with open(path) as f:
+            keys = json.load(f)
+        from vllm_distributed_tpu.models.registry import \
+            supported_architectures
+        if not set(keys.get("architectures") or ()) & set(
+                supported_architectures()):
+            return None
+        from transformers import PretrainedConfig
+        return PretrainedConfig(**keys)
 
     # -- Introspection helpers used by the worker/scheduler ---------------
     def get_vocab_size(self) -> int:
@@ -164,6 +186,11 @@ class CacheConfig:
     # Number of pages decided at init time (set by the engine after
     # profiling, like determine_available_memory in the reference).
     num_gpu_blocks: Optional[int] = None
+    # Slots of the state-snapshot pool (core/state_cache.py) where the
+    # HBM profile left less room than the default of twice
+    # max_num_seqs (set by the worker beside num_gpu_blocks; None ->
+    # the default).
+    num_state_slots: Optional[int] = None
     enable_prefix_caching: bool = True
     # KV cache dtype ("auto" follows model dtype).
     cache_dtype: str = "auto"

@@ -87,6 +87,12 @@ def resolve_state_slots(config) -> int:
     n = envs.VDT_SSM_STATE_CACHE_SLOTS
     if n > 0:
         return n
+    # What the worker's HBM profile cut the default to, if it had to.
+    sized = config.cache_config.num_state_slots
+    return sized or default_state_slots(config)
+
+
+def default_state_slots(config) -> int:
     return max(2 * config.scheduler_config.max_num_seqs, 8)
 
 

@@ -196,6 +196,8 @@ LABELED_METRICS = {
     # One chip's share of an expert layer (models/deepseek.py).
     "vdt:moe_experts_held": ("worker", ),
     "vdt:moe_experts_published": ("worker", ),
+    # One sequence's fixed-size recurrent state (models/bailing.py).
+    "vdt:state_row_bytes": ("worker", ),
     # Performance-attribution plane (metrics/costmodel.py): per-worker
     # utilization ratios, analytic HBM traffic by kind, and the
     # per-phase roofline placement.
@@ -467,6 +469,10 @@ def _render_worker_telemetry(workers: dict) -> list[str]:
         ("moe_experts_published", "vdt:moe_experts_published", "gauge",
          "Routed experts the router scores under an expert share: the "
          "published count of the deployment this worker is one chip of"),
+        ("state_row_bytes", "vdt:state_row_bytes", "gauge",
+         "HBM bytes of one sequence's recurrent and convolution state "
+         "over every linear-attention layer: what a request costs "
+         "whatever its length"),
         # Performance-attribution plane: this worker's analytic FLOPs
         # / bytes over its measured device time against its mesh peak.
         ("mfu", "vdt:mfu", "gauge",

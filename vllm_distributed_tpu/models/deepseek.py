@@ -595,6 +595,70 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
     # ------------------------------------------------------------------
     # Forward
     # ------------------------------------------------------------------
+    def latent_attention_block(self, lp: dict, x: jax.Array, caches: dict,
+                               layer_idx: jax.Array, batch: AttentionBatch,
+                               cos: jax.Array, sin: jax.Array,
+                               head_gate: Optional[jax.Array] = None,
+                               ) -> tuple[jax.Array, dict]:
+        """One layer's latent attention in the absorbed form on the
+        normed input ``x`` ``[T, H]``: writes the step's latent rows
+        into ``caches`` at ``layer_idx`` and returns (the block's output
+        after ``wo``, caches). ``head_gate`` ``[T, N]`` multiplies each
+        head's output before ``wo`` (models/bailing.py)."""
+        c = self.cfg
+        T = x.shape[0]
+        N = c.num_q_heads
+        Pn, R, V = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        Lkv = c.kv_lora_rank
+        sm_scale = self._sm_scale()
+        tpla = self.tpla_shards
+        if c.q_lora_rank:
+            qc = rms_norm(x @ self._w(lp, "q_a"), lp["q_a_ln"],
+                          c.rms_norm_eps)
+            q = qc @ self._w(lp, "q_b")
+        else:
+            q = x @ self._w(lp, "wq")
+        q = q.reshape(T, N, Pn + R)
+        q_nope, q_pe = q[..., :Pn], q[..., Pn:]
+        ckv = x @ self._w(lp, "kv_a")  # [T, Lkv + R]
+        kv_c = rms_norm(ckv[..., :Lkv], lp["kv_a_ln"], c.rms_norm_eps)
+        k_pe = apply_rope_pairwise(
+            ckv[..., Lkv:][:, None, :].astype(jnp.float32), cos,
+            sin)[:, 0].astype(c.dtype)
+        q_pe = apply_rope_pairwise(q_pe.astype(jnp.float32), cos,
+                                   sin).astype(c.dtype)
+        # Absorb W_UK into the query: MQA over the latent cache.
+        # Under TPLA w_uk is latent-sharded, so ql comes out sharded
+        # on its last dim — exactly the layout the sharded cache
+        # attention consumes (no collective here).
+        ql = jnp.einsum("tnp,knp->tnk", q_nope.astype(jnp.float32),
+                        self._w(lp, "w_uk").astype(jnp.float32))
+        if tpla > 1:
+            c_all, pe_all = write_latent_cache_tpla(
+                caches["c"], caches["pe"], kv_c, k_pe, batch,
+                layer_idx, shards=tpla, kv_lora_rank=Lkv)
+            caches = {**caches, "c": c_all, "pe": pe_all}
+            v = tpla_latent_attention(
+                ql.astype(c.dtype), q_pe, c_all, pe_all, batch,
+                self._w(lp, "w_uv"), sm_scale=sm_scale,
+                kv_lora_rank=Lkv, rope_dim=R, shards=tpla,
+                layer=layer_idx).astype(jnp.float32)
+        else:
+            cache = write_latent_cache(
+                caches["c"], jnp.concatenate([kv_c, k_pe], axis=-1),
+                batch, layer_idx)
+            caches = {**caches, "c": cache}
+            out_l = latent_attention(
+                ql.astype(c.dtype), q_pe, cache, batch,
+                sm_scale=sm_scale, kv_lora_rank=Lkv, rope_dim=R,
+                layer=layer_idx)
+            v = jnp.einsum("tnk,knv->tnv", out_l.astype(jnp.float32),
+                           self._w(lp, "w_uv").astype(jnp.float32))
+        if head_gate is not None:
+            v = v * head_gate.astype(jnp.float32)[:, :, None]
+        o = v.reshape(T, N * V).astype(c.dtype) @ self._w(lp, "wo")
+        return o, caches
+
     def run_layers(
         self,
         layer_params: dict,
@@ -604,64 +668,15 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
         first_layer: int = 0,
     ) -> tuple[jax.Array, dict]:
         c = self.cfg
-        T = hidden.shape[0]
-        N = c.num_q_heads
-        Pn, R, V = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
-        Lkv = c.kv_lora_rank
-        sm_scale = self._sm_scale()
         num_layers = layer_params["input_ln"].shape[0]
         cos, sin = compute_rope_cos_sin_pairwise(
-            batch.positions, R, c.rope_theta, c.rope_scaling,
-            c.max_position_embeddings)
-
-        tpla = self.tpla_shards
+            batch.positions, c.qk_rope_head_dim, c.rope_theta,
+            c.rope_scaling, c.max_position_embeddings)
 
         def attn_block(lp, h, caches, layer_idx):
             x = rms_norm(h, lp["input_ln"], c.rms_norm_eps)
-            if c.q_lora_rank:
-                qc = rms_norm(x @ self._w(lp, "q_a"), lp["q_a_ln"],
-                              c.rms_norm_eps)
-                q = qc @ self._w(lp, "q_b")
-            else:
-                q = x @ self._w(lp, "wq")
-            q = q.reshape(T, N, Pn + R)
-            q_nope, q_pe = q[..., :Pn], q[..., Pn:]
-            ckv = x @ self._w(lp, "kv_a")  # [T, Lkv + R]
-            kv_c = rms_norm(ckv[..., :Lkv], lp["kv_a_ln"],
-                            c.rms_norm_eps)
-            k_pe = apply_rope_pairwise(
-                ckv[..., Lkv:][:, None, :].astype(jnp.float32), cos,
-                sin)[:, 0].astype(c.dtype)
-            q_pe = apply_rope_pairwise(q_pe.astype(jnp.float32), cos,
-                                       sin).astype(c.dtype)
-            # Absorb W_UK into the query: MQA over the latent cache.
-            # Under TPLA w_uk is latent-sharded, so ql comes out sharded
-            # on its last dim — exactly the layout the sharded cache
-            # attention consumes (no collective here).
-            ql = jnp.einsum("tnp,knp->tnk", q_nope.astype(jnp.float32),
-                            self._w(lp, "w_uk").astype(jnp.float32))
-            if tpla > 1:
-                c_all, pe_all = write_latent_cache_tpla(
-                    caches["c"], caches["pe"], kv_c, k_pe, batch,
-                    layer_idx, shards=tpla, kv_lora_rank=Lkv)
-                caches = {"c": c_all, "pe": pe_all}
-                v = tpla_latent_attention(
-                    ql.astype(c.dtype), q_pe, c_all, pe_all, batch,
-                    self._w(lp, "w_uv"), sm_scale=sm_scale,
-                    kv_lora_rank=Lkv, rope_dim=R, shards=tpla,
-                    layer=layer_idx).astype(jnp.float32)
-            else:
-                cache = write_latent_cache(
-                    caches["c"], jnp.concatenate([kv_c, k_pe], axis=-1),
-                    batch, layer_idx)
-                caches = {"c": cache}
-                out_l = latent_attention(
-                    ql.astype(c.dtype), q_pe, cache, batch,
-                    sm_scale=sm_scale, kv_lora_rank=Lkv, rope_dim=R,
-                    layer=layer_idx)
-                v = jnp.einsum("tnk,knv->tnv", out_l.astype(jnp.float32),
-                               self._w(lp, "w_uv").astype(jnp.float32))
-            o = v.reshape(T, N * V).astype(c.dtype) @ self._w(lp, "wo")
+            o, caches = self.latent_attention_block(
+                lp, x, caches, layer_idx, batch, cos, sin)
             return h + o, caches
 
         attn_keys = [k for k in layer_params

@@ -219,6 +219,12 @@ def get_model(config: EngineConfig, mesh,
             raise ValueError(
                 "EPLB redundant experts over stateful hybrid models "
                 "are not wired; drop num_redundant_experts")
+        if (getattr(arch, "kda", False)
+                and config.parallel_config.tensor_parallel_size > 1):
+            raise ValueError(
+                "tensor parallelism over gated delta-rule (KDA) layers "
+                "is not wired (the state kernels of ops/kda.py run "
+                "unsharded); set tensor_parallel_size=1")
     if arch.pos_embedding == "learned":
         capacity = arch.max_position_embeddings - arch.pos_offset
         if config.scheduler_config.max_model_len > capacity:

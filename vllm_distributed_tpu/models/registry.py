@@ -67,6 +67,7 @@ from vllm_distributed_tpu.models.qwen2_vl import \
 from vllm_distributed_tpu.models.bart import BartForConditionalGeneration
 from vllm_distributed_tpu.models.whisper import \
     WhisperForConditionalGeneration
+from vllm_distributed_tpu.models.bailing import BailingMoeV3ForCausalLM
 from vllm_distributed_tpu.models.bamba import BambaForCausalLM
 from vllm_distributed_tpu.models.jamba import JambaForCausalLM
 from vllm_distributed_tpu.models.mamba import (FalconMambaForCausalLM,
@@ -163,6 +164,10 @@ _REGISTRY: dict[str, type] = {
     "JambaForCausalLM": JambaForCausalLM,
     # Hybrid Mamba-2/attention (models/bamba.py).
     "BambaForCausalLM": BambaForCausalLM,
+    # Ling-3.0: gated delta-rule linear attention (KDA) beside latent
+    # attention over DeepSeek-V3 experts (models/bailing.py; the name is
+    # the family's convention, not the source's).
+    "BailingMoeV3ForCausalLM": BailingMoeV3ForCausalLM,
     # GPT lineage: learned positions / parallel blocks / packed QKV
     # (models/families_gpt.py).
     "GPT2LMHeadModel": GPT2LMHeadModel,

@@ -508,7 +508,8 @@ class TPUModelRunner:
         if not restores or self._state_pool is None:
             return
         from vllm_distributed_tpu.core.state_cache import read_journal
-        with self.mesh:
+        with self.mesh, self.phases.span("vdt.state_copy",
+                                         restores=len(restores)):
             self._run_state_restores(restores, read_journal)
 
     def _run_state_restores(self, restores, read_journal) -> None:
@@ -604,7 +605,8 @@ class TPUModelRunner:
         if not saves or self._state_pool is None:
             return
         from vllm_distributed_tpu.core.state_cache import write_journal
-        with self.mesh:
+        with self.mesh, self.phases.span("vdt.state_copy",
+                                         saves=len(saves)):
             self._run_state_saves(saves, write_journal)
 
     def _run_state_saves(self, saves, write_journal) -> None:
@@ -2564,6 +2566,7 @@ class TPUModelRunner:
                         self.params, self.kv_caches, inputs)
                 jax.block_until_ready(hidden)
                 n += 1
+                self._precompile_row_gathers(hidden, self.mesh)
                 if cascade:
                     shared = jnp.zeros((envs.VDT_CASCADE_SHARED_PAGES, ),
                                        jnp.int32)
@@ -2597,6 +2600,20 @@ class TPUModelRunner:
         self.precompile_graphs = n
         logger.info("precompiled %d graphs in %.1fs", n,
                     time.perf_counter() - start)
+
+    def _precompile_row_gathers(self, hidden, mesh) -> None:
+        """The row gather between the forward and the samplers runs op
+        by op, one small program a (token shape, row bucket) pair: warm
+        every row bucket on a forward's own output, so that no pair
+        waits for traffic that fills the largest row bucket beside every
+        token bucket (``max_num_seqs`` 96 has a bucket of 96 that eight,
+        sixteen, ... decoders never reach)."""
+        S1 = self.spec_k + 1
+        for R in self.req_buckets:
+            sel = self._gather_sample_rows(
+                hidden, jnp.asarray(np.zeros((R * S1, ), np.int32)),
+                mesh=mesh)
+        jax.block_until_ready(sel)
 
     def _precompile_state_cache(self) -> int:
         """Warm the SSM snapshot/restore copies (one graph per state
@@ -2793,6 +2810,12 @@ class TPUModelRunner:
                 stats["moe_experts_held"] = int(held[1])
                 stats["moe_experts_published"] = int(
                     self.model.cfg.router_experts)
+        row_bytes = getattr(self.model, "state_row_bytes", None)
+        if row_bytes is not None:
+            # One sequence's recurrent and convolution state over every
+            # layer: what a request costs whatever its length
+            # (models/bailing.py).
+            stats["state_row_bytes"] = int(row_bytes())
         cm = self._cost_model()
         if cm is not None and self._perf_dispatches:
             # Performance-attribution plane: analytic totals + the

@@ -29,6 +29,9 @@ _MIN_PAGES = 16
 # Granularity of a profiled KV pool (pages); see
 # determine_num_available_blocks.
 _POOL_QUANTUM = 128
+_POOL_QUANTUM_BYTES = 12 << 20
+# Granularity of a state-snapshot pool that the profile cuts (slots).
+_SLOT_QUANTUM = 8
 
 # Where the persistent compile cache lives when the environment does not
 # place it: one fixed path inside the checkout (the path is part of the
@@ -163,9 +166,11 @@ class TPUWorker:
         # Fixed-size per-request state (SSM conv/ssm rows) PLUS the
         # state-snapshot pool (core/state_cache.py) are charged up
         # front; the page pool only gets what remains.
-        fixed = (self.model_runner.model_fixed_cache_bytes() +
-                 getattr(self.model_runner, "state_pool_bytes",
-                         lambda: 0)())
+        rows = self.model_runner.model_fixed_cache_bytes()
+        if avail is not None:
+            self._size_state_pool(avail - rows, page_bytes)
+        fixed = rows + getattr(self.model_runner, "state_pool_bytes",
+                               lambda: 0)()
         if avail is not None:
             if fixed and fixed > avail:
                 raise RuntimeError(
@@ -198,13 +203,16 @@ class TPUWorker:
                 f"{self.config.cache_config.gpu_memory_utilization} of "
                 f"the device's memory")
         pages = avail // page_bytes
-        if pages > 8 * _POOL_QUANTUM:
+        # In bytes too: a pool of small pages (one latent layer's rows:
+        # 20 KB a page) would else jitter with every few MB.
+        quantum = max(_POOL_QUANTUM, _POOL_QUANTUM_BYTES // page_bytes)
+        if pages > 8 * quantum:
             # The page count is a dimension of every forward program, so
             # the persistent compile cache only hits when two starts
             # profile the SAME count — and bytes-in-use jitters by a few
             # MB between starts (loaded executables, stray buffers).
             # Round down to a coarse quantum: <= 1.6% of the pool.
-            pages -= pages % _POOL_QUANTUM
+            pages -= pages % quantum
         shards = getattr(
             getattr(self.model_runner.model, "cfg", None),
             "tpla_shards", 1) or 1
@@ -221,6 +229,40 @@ class TPUWorker:
             logger.info("HBM for KV: %.2f GiB -> %d pages of %d bytes",
                         avail / 2**30, pages, page_bytes)
         return rounded(pages)
+
+    def _size_state_pool(self, room: int, page_bytes: int) -> None:
+        """Cut the state-snapshot pool (core/state_cache.py) to what the
+        profile left where its default of twice ``max_num_seqs`` slots
+        does not fit: the pages that every schedulable request needs at
+        full length come first (a snapshot resumes a request only
+        beside its prefix's pages), then snapshot slots up to the
+        default, then the pages get the rest. Written to
+        ``cache_config.num_state_slots``, where the scheduler's manager
+        and the runner's pool both read it."""
+        from vllm_distributed_tpu import envs
+        slot_bytes = getattr(self.model_runner, "state_pool_slot_bytes",
+                             lambda: 0)()
+        if not slot_bytes or envs.VDT_SSM_STATE_CACHE_SLOTS:
+            return
+        from vllm_distributed_tpu.core.state_cache import \
+            default_state_slots
+        want = default_state_slots(self.config)
+        reserve = (page_bytes * self.config.max_pages_per_req *
+                   self.config.scheduler_config.max_num_seqs)
+        fit = max(room - reserve, 0) // slot_bytes
+        if fit < want:
+            # A dimension of the snapshot copies, and what the pages
+            # get depends on it: coarse, like the page count.
+            fit -= fit % _SLOT_QUANTUM
+            slots = int(max(fit, min(want, _SLOT_QUANTUM)))
+            self.config.cache_config.num_state_slots = slots
+            logger.info(
+                "state-snapshot pool cut to %d slots of %.1f MiB (default "
+                "%d): %.2f GiB are left after the state rows, %.2f GiB of "
+                "them for the pages %d full-length requests need", slots,
+                slot_bytes / 2**20, want, room / 2**30,
+                reserve / 2**30,
+                self.config.scheduler_config.max_num_seqs)
 
     def initialize_kv_cache(self, num_pages: int) -> None:
         with global_mesh(self.mesh):
@@ -271,7 +313,8 @@ class TPUWorker:
         for key in ("device_wait_seconds", "device_memory_peak_bytes",
                     "device_memory_in_use_bytes", "tpla_latent_shards",
                     "mla_latent_page_bytes", "mla_latent_token_bytes",
-                    "moe_experts_held", "moe_experts_published", "mfu",
+                    "moe_experts_held", "moe_experts_published",
+                    "state_row_bytes", "mfu",
                     "mbu"):
             if key in stats:
                 per_worker[key] = stats.pop(key)
