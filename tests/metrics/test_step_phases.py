@@ -162,6 +162,50 @@ def test_kv_tokens_read_of_a_prefill_chunk_and_two_decodes(engine):
         assert family in text, family
 
 
+@pytest.mark.parametrize("mla,tpla,backend,max_q,want", [
+    # A chunk program on the latent kernels: the rows of sequences with
+    # more than one query row (17 + 2), not the decoding rows.
+    (True, 1, "pallas", 32, 19),
+    (True, 1, "pallas", 1, 0),       # a decode program
+    (True, 1, "xla", 32, 0),         # the XLA scan keeps the absorbed form
+    (True, 2, "pallas", 32, 0),      # so does TPLA
+    (False, 1, "pallas", 32, 0),     # no latent cache
+])
+def test_latent_prefill_tokens_of_a_step(monkeypatch, mla, tpla, backend,
+                                         max_q, want):
+    """``vdt:step_latent_prefill_tokens`` counts what
+    ``ops/mla.latent_attention_values`` serves in the multi-head form."""
+    import types
+
+    import numpy as np
+
+    from vllm_distributed_tpu.worker.model_runner import TPUModelRunner
+    monkeypatch.setenv("VDT_ATTENTION_BACKEND", backend)
+    runner = types.SimpleNamespace(
+        _latent_kernels=None,
+        model=types.SimpleNamespace(
+            cfg=types.SimpleNamespace(mla=mla), tpla_shards=tpla))
+    q_lens = np.asarray([1, 17, 1, 2, 1], np.int32)
+    if max_q == 1:
+        q_lens = np.ones(5, np.int32)
+    assert TPUModelRunner._latent_prefill_tokens(runner, q_lens,
+                                                 max_q) == want
+
+
+def test_latent_prefill_tokens_are_rendered(engine):
+    """A model without a latent cache observes 0 every step, and the
+    family is there for the benchmark's reader."""
+    core = _core(engine)
+    _add(engine, "lp-a", 70, 2)
+    while engine.has_unfinished_requests():
+        engine.step()
+    stats = core.get_stats()
+    h = stats["step_latent_prefill_tokens"]
+    assert h["count"] == stats["step_kv_tokens_read"]["count"] > 0
+    assert h["sum"] == 0
+    assert "vdt:step_latent_prefill_tokens_count" in render_metrics(stats)
+
+
 def _sched_step(scheduler):
     out = scheduler.schedule()
     if out.total_num_scheduled_tokens == 0:

@@ -49,8 +49,7 @@ def moe_layer(params: dict, j: int = 0) -> dict:
 # ---------------------------------------------------------------------
 # The served path against the reference's one full forward
 # ---------------------------------------------------------------------
-@pytest.fixture(scope="module")
-def served(tmp_path_factory):
+def serve(tmp_path_factory, n_requests: int = 9):
     """Prompts of 56-217 tokens go in as chunks of 64 and a tail of
     another length (the state crosses each boundary through its row,
     the latent rows through their pages), then 8 tokens are decoded;
@@ -70,7 +69,7 @@ def served(tmp_path_factory):
     args = run.engine_args(model_dir, cf, seed=11)
     llm = LLM(model=model_dir, load_format="dummy", seed=args.seed,
               skip_tokenizer_init=True, **cf["engine_args"])
-    reqs = traffic.scoring_requests(9, cf["vocab_size"])
+    reqs = traffic.scoring_requests(9, cf["vocab_size"])[:n_requests]
     assert sorted(len(r["prompt"]) % 64 for r in reqs)[-1] > 0
     outs = llm.generate(
         [r["prompt"] for r in reqs],
@@ -87,6 +86,30 @@ def served(tmp_path_factory):
     config.model_config.maybe_load_hf_config()
     mesh = build_mesh(config.parallel_config, jax.devices()[:1])
     return rows, get_model(config, mesh)[1], run.hf_config(cf), stats
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    return serve(tmp_path_factory)
+
+
+@pytest.fixture(scope="module")
+def served_on_kernels(tmp_path_factory):
+    """The same through the latent kernels (interpret): a chunk step's
+    latent layer attends its chunks in the multi-head form and its
+    decoding rows, gathered, in the absorbed one, each head's output
+    times the head-wise gate before ``wo``."""
+    from vllm_distributed_tpu.ops import mla
+    mixed, traced = mla._mixed_latent_attention, []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("VDT_ATTENTION_BACKEND", "pallas")
+        mp.setenv("VDT_PALLAS_INTERPRET", "1")
+        mp.setattr(
+            mla, "_mixed_latent_attention",
+            lambda *a, **kw: traced.append(a[3].max_q) or mixed(*a, **kw))
+        out = serve(tmp_path_factory, n_requests=4)
+    assert traced and min(traced) > 1
+    return out
 
 
 def worst_deviation(served, control=None) -> float:
@@ -377,6 +400,20 @@ def test_served_logits_against_the_reference(served, control):
     order). With one: the reference with that path broken lies at
     least a hundred tolerances away, so the comparison sees the path."""
     worst = worst_deviation(served, control)
+    if control is None:
+        assert worst < TOL, worst
+    else:
+        assert worst > 100 * TOL, (control, worst)
+
+
+@pytest.mark.parametrize("control", [None, "no_head_gate"])
+def test_chunk_steps_on_the_kernels_against_the_reference(
+        served_on_kernels, control):
+    """Prefill in chunks, then decode through the cache, on the Pallas
+    backend: the multi-head form's output meets the head-wise gate as
+    the absorbed form's does, to the tolerance the XLA path is held to;
+    the reference without the gate lies a hundred tolerances away."""
+    worst = worst_deviation(served_on_kernels, control)
     if control is None:
         assert worst < TOL, worst
     else:

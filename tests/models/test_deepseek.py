@@ -255,3 +255,162 @@ def test_v2_pallas_backend_matches_hf(v2_checkpoint, monkeypatch):
     got = run(make_engine(path, block_size=8), PROMPTS, "dspl")
     want = [hf_greedy(hf, p, 6) for p in PROMPTS]
     assert got == want
+
+
+@pytest.mark.parametrize("checkpoint,tp", [
+    ("v2_checkpoint", 1), ("v3_checkpoint", 1),
+    # Heads shard over two ranks, the latent cache replicated: each
+    # rank up-projects its heads' K and V and absorbs over its own.
+    ("v2_checkpoint", 2)])
+def test_pallas_chunk_steps_match_hf(checkpoint, tp, request, monkeypatch):
+    """A 38-token prompt goes in as chunks of a 16-token budget beside a
+    short request that is already decoding: every step but the first
+    holds a decoding row (the absorbed kernel, gathered) and a later
+    chunk of a request whose earlier chunks are in the pages (the
+    multi-head kernel), then both decode through the cache. Greedy
+    tokens are HF's, in float32."""
+    from vllm_distributed_tpu.ops import mla
+    monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
+    monkeypatch.setenv("VDT_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("VDT_TPLA", "0")
+    mixed, traced = mla._mixed_latent_attention, []
+    monkeypatch.setattr(
+        mla, "_mixed_latent_attention",
+        lambda *a, **kw: traced.append(a[3].max_q) or mixed(*a, **kw))
+    path, hf = request.getfixturevalue(checkpoint)
+    prompts = [PROMPTS[0], list(range(2, 40))]
+    engine = make_engine(path, block_size=8, max_num_batched_tokens=16,
+                         tensor_parallel_size=tp)
+    got = run(engine, prompts, "dsmix", max_tokens=8)
+    assert got == [hf_greedy(hf, p, 8) for p in prompts]
+    # The chunk programs took the mixed path, a decode program never.
+    assert traced and min(traced) > 1
+
+
+# ---------------------------------------------------------------------
+# What the two programs of a latent-attention model hold, read off the
+# jaxpr at GigaChat3's published widths (nothing runs, nothing compiles)
+# ---------------------------------------------------------------------
+def _eqns(jaxpr, times=1):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, with how
+    often it runs (a scan's length multiplies its body's)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, times
+        inner = times * eqn.params.get("length", 1) \
+            if eqn.primitive.name == "scan" else times
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v, )):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub, inner)
+
+
+@pytest.fixture(scope="module")
+def gigachat_programs():
+    """program(max_q) -> ({kernel name: launches}, the float32 products
+    against ``w_uk`` / ``w_uv`` as (token rows, weight shape)) of the forward
+    of `gigachat3-702b-ep16` on the latent kernels, the configuration's
+    heads, widths, depth and engine limits from its file."""
+    import collections
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import run as bench_run
+    from vllm_distributed_tpu.models.common import AttentionBatch
+    from vllm_distributed_tpu.models.deepseek import DeepseekV3ForCausalLM
+    from vllm_distributed_tpu.models.llama import LlamaArchConfig
+    from vllm_distributed_tpu.parallel import mesh as mesh_state
+    with open(os.path.join(bench_run.HERE, "configs",
+                           "gigachat3-702b-ep16.json")) as f:
+        cf = json.load(f)
+    hf = DeepseekV3Config(**{
+        k: v for k, v in bench_run.hf_config(cf).items()
+        if k not in ("architectures", "model_type") and v is not None})
+    arch = LlamaArchConfig.from_hf_config(hf, dtype=jnp.bfloat16)
+    DeepseekV3ForCausalLM.configure_arch(arch, hf)
+    arch.expert_parallel = False
+    arch.quantization = None
+    model = DeepseekV3ForCausalLM(arch)
+    ea = cf["engine_args"]
+    reqs, ps = ea["max_num_seqs"], ea["block_size"]
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    caches = jax.eval_shape(lambda: model.make_kv_caches(64, ps))
+    # einsum hands dot_general its operands in an order of its own:
+    # a weight is known by its sizes.
+    weights = {tuple(sorted((cf["kv_lora_rank"], cf["num_attention_heads"],
+                             d)))
+               for d in (cf["qk_nope_head_dim"], cf["v_head_dim"])}
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+
+    def program(max_q):
+        T = reqs + 1 if max_q == 1 else max_q + min(max_q, 128)
+
+        def fwd(params, caches, tok, req, pos, slot, tables, lens, info, n):
+            return model.forward(params, caches, tok, AttentionBatch(
+                req_idx=req, positions=pos, slot_mapping=slot,
+                block_tables=tables, seq_lens=lens, seq_info=info,
+                num_seqs=n, max_q=max_q))
+
+        # One chip: no mesh of an engine that another test started.
+        with pytest.MonkeyPatch.context() as mp, \
+                mesh_state.global_mesh(None):
+            mp.setenv("VDT_ATTENTION_BACKEND", "pallas")
+            mp.setenv("VDT_PALLAS_INTERPRET", "1")
+            jaxpr = jax.make_jaxpr(fwd)(
+                params, caches, i32(T), i32(T), i32(T), i32(T),
+                i32(reqs, ea["max_model_len"] // ps), i32(reqs),
+                i32(reqs, 4), i32(1))
+        kernels, absorbing = collections.Counter(), []
+        for eqn, times in _eqns(jaxpr.jaxpr):
+            if eqn.primitive.name == "pallas_call":
+                name = str(eqn.params.get("name")
+                           or eqn.params["name_and_src_info"])
+                kernels[name.split(" ")[0]] += times
+            elif eqn.primitive.name == "dot_general":
+                (lc, rc), (lb, rb) = eqn.params["dimension_numbers"]
+                for w, x, used in ((eqn.invars[1], eqn.invars[0], lc + lb),
+                                   (eqn.invars[0], eqn.invars[1], rc + rb)):
+                    if (tuple(sorted(w.aval.shape)) in weights
+                            and w.aval.dtype == jnp.float32):
+                        # The activation's free dimension: its rows.
+                        (rows, ) = (d for i, d in enumerate(x.aval.shape)
+                                    if i not in used)
+                        absorbing.append((rows, w.aval.shape))
+        return kernels, absorbing
+
+    return program, cf
+
+
+def test_decode_program_holds_the_absorbed_kernel_alone(gigachat_programs):
+    """``max_q == 1``: no multi-head kernel, exactly one launch of the
+    latent kernel a latent layer, the two contractions over the decode
+    batch: the parent's program."""
+    from vllm_distributed_tpu.ops.pallas_mla import (KERNEL_NAME,
+                                                     PREFILL_KERNEL_NAME)
+    program, cf = gigachat_programs
+    kernels, absorbing = program(1)
+    assert kernels[PREFILL_KERNEL_NAME] == 0
+    assert kernels[KERNEL_NAME] == cf["num_hidden_layers"]
+    rows = cf["engine_args"]["max_num_seqs"] + 1
+    assert len(absorbing) == 2 * cf["num_hidden_layers"]
+    assert {r for r, _ in absorbing} == {rows}
+
+
+@pytest.mark.parametrize("t_bucket", [16, 2048])
+def test_chunk_program_absorbs_over_the_decoding_rows_only(
+        gigachat_programs, t_bucket):
+    """A program that can hold a chunk has both kernels once a latent
+    layer, and its float32 absorbing contractions (``q_nope . w_uk``,
+    ``out_l . w_uv``) run over ``max_num_seqs`` gathered rows, not over
+    the token bucket: what PR 35's program lacked."""
+    from vllm_distributed_tpu.ops.pallas_mla import (KERNEL_NAME,
+                                                     PREFILL_KERNEL_NAME)
+    program, cf = gigachat_programs
+    kernels, absorbing = program(t_bucket)
+    layers = cf["num_hidden_layers"]
+    assert kernels[KERNEL_NAME] == kernels[PREFILL_KERNEL_NAME] == layers
+    assert len(absorbing) == 2 * layers
+    assert {r for r, _ in absorbing} == {cf["engine_args"]["max_num_seqs"]}

@@ -380,6 +380,57 @@ def test_mla_latent_attention_compiles_at_published_widths(
     assert f"%{KERNEL_NAME}" in compiled.as_text()
 
 
+@pytest.mark.parametrize("t_bucket", [TOKEN_BUCKETS[0], TOKEN_BUCKETS[-1]])
+@pytest.mark.parametrize("name", ["gigachat3-702b-ep16", "ling3-flash-ep4"])
+def test_mla_chunk_step_compiles_at_published_widths(
+        compile_for_chip, monkeypatch, name, t_bucket):
+    """One latent layer's attention of a chunk-bearing step
+    (``ops/mla.latent_attention_values`` at ``max_q`` > 1) as both
+    latent-attention configurations serve it, heads, widths and engine
+    limits from their files (64 heads and V 192, padded to 256 lanes;
+    32 heads and V 128): the multi-head kernel over the chunks, the
+    absorbed kernel over ``max_num_seqs`` gathered rows, in one program,
+    and K and V go to the kernel as the up-projection leaves them (no
+    copy of a context-sized buffer)."""
+    from vllm_distributed_tpu.ops import mla
+    from vllm_distributed_tpu.ops.pallas_mla import (KERNEL_NAME,
+                                                     PREFILL_KERNEL_NAME)
+    from vllm_distributed_tpu.parallel import mesh as mesh_state
+    from vllm_distributed_tpu.worker.model_runner import AttentionBatch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
+    monkeypatch.setenv("VDT_PALLAS_INTERPRET", "0")
+    cf = bench_config(name)
+    ea = cf["engine_args"]
+    lkv, rope = cf["kv_lora_rank"], cf["qk_rope_head_dim"]
+    heads, reqs = cf["num_attention_heads"], ea["max_num_seqs"]
+    nope, v_dim = cf["qk_nope_head_dim"], cf["v_head_dim"]
+    ctx = ea["max_model_len"]
+    T = t_bucket + min(t_bucket, 128)
+
+    def fn(qn, qp, c, wk, wv, si, ns, bt, slots, layer):
+        batch = AttentionBatch(
+            req_idx=slots, positions=slots, slot_mapping=slots,
+            block_tables=bt, seq_lens=ns, seq_info=si, num_seqs=ns,
+            max_q=t_bucket)
+        return mla.latent_attention_values(
+            qn, qp, c, batch, wk, wv, sm_scale=0.07, kv_lora_rank=lkv,
+            rope_dim=rope, layer=layer)
+
+    bf16 = jnp.bfloat16
+    # One chip: no mesh of an engine that another test file started.
+    monkeypatch.setattr(mesh_state, "_GLOBAL_MESH", None)
+    text = compile_for_chip(
+        fn, sds((T, heads, nope), bf16), sds((T, heads, rope), bf16),
+        sds((2, 4096, PS, mla.latent_storage_dim(lkv, rope)), bf16),
+        sds((lkv, heads, nope), bf16), sds((lkv, heads, v_dim), bf16),
+        sds((reqs, 4)), sds((1, )), sds((reqs, cdiv(ctx, PS))),
+        sds((T, )), sds((1, ))).as_text()
+    assert f"%{KERNEL_NAME}" in text and f"%{PREFILL_KERNEL_NAME}" in text
+    lanes = heads * (-(-v_dim // 128) * 128)
+    assert not re.search(rf"bf16\[{ctx},{lanes}\]\S* copy\(", text)
+
+
 @pytest.mark.parametrize("tokens,form", [
     (1, "decode"), (2048, "chunk"), (2048, "auto")])
 def test_gated_delta_kernels_compile_at_published_widths(

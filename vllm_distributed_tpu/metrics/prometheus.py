@@ -156,6 +156,12 @@ HISTOGRAMS = {
         "vdt:step_kv_tokens_read",
         "Per step, context lengths (computed + scheduled tokens) summed "
         "over the scheduled requests: K/V tokens the attention reads"),
+    "step_latent_prefill_tokens": (
+        "vdt:step_latent_prefill_tokens",
+        "Per step, query rows that latent attention served in the "
+        "multi-head form (sequences with more than one query row in a "
+        "chunk program on the latent kernels; 0 on a decode step and "
+        "for a model without a latent cache)"),
     "step_host_transfers": (
         "vdt:step_host_transfers",
         "Per step, host arrays the model runner's _prepare_inputs and "

@@ -66,7 +66,7 @@ from vllm_distributed_tpu.models.common import (AttentionBatch,
 from vllm_distributed_tpu.models.llama import (MODEL_AXIS, TOKEN_AXIS,
                                                LlamaForCausalLM)
 from vllm_distributed_tpu.models.mixtral import MixtralForCausalLM
-from vllm_distributed_tpu.ops.mla import (latent_attention,
+from vllm_distributed_tpu.ops.mla import (latent_attention_values,
                                           latent_shard_dim,
                                           latent_storage_dim,
                                           tpla_latent_attention,
@@ -600,11 +600,11 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
                                cos: jax.Array, sin: jax.Array,
                                head_gate: Optional[jax.Array] = None,
                                ) -> tuple[jax.Array, dict]:
-        """One layer's latent attention in the absorbed form on the
-        normed input ``x`` ``[T, H]``: writes the step's latent rows
-        into ``caches`` at ``layer_idx`` and returns (the block's output
-        after ``wo``, caches). ``head_gate`` ``[T, N]`` multiplies each
-        head's output before ``wo`` (models/bailing.py)."""
+        """One layer's latent attention on the normed input ``x``
+        ``[T, H]``: writes the step's latent rows into ``caches`` at
+        ``layer_idx`` and returns (the block's output after ``wo``,
+        caches). ``head_gate`` ``[T, N]`` multiplies each head's output
+        before ``wo`` (models/bailing.py)."""
         c = self.cfg
         T = x.shape[0]
         N = c.num_q_heads
@@ -627,13 +627,13 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
             sin)[:, 0].astype(c.dtype)
         q_pe = apply_rope_pairwise(q_pe.astype(jnp.float32), cos,
                                    sin).astype(c.dtype)
-        # Absorb W_UK into the query: MQA over the latent cache.
-        # Under TPLA w_uk is latent-sharded, so ql comes out sharded
-        # on its last dim — exactly the layout the sharded cache
-        # attention consumes (no collective here).
-        ql = jnp.einsum("tnp,knp->tnk", q_nope.astype(jnp.float32),
-                        self._w(lp, "w_uk").astype(jnp.float32))
         if tpla > 1:
+            # Absorb W_UK into the query: MQA over the latent cache.
+            # Under TPLA w_uk is latent-sharded, so ql comes out sharded
+            # on its last dim — exactly the layout the sharded cache
+            # attention consumes (no collective here).
+            ql = jnp.einsum("tnp,knp->tnk", q_nope.astype(jnp.float32),
+                            self._w(lp, "w_uk").astype(jnp.float32))
             c_all, pe_all = write_latent_cache_tpla(
                 caches["c"], caches["pe"], kv_c, k_pe, batch,
                 layer_idx, shards=tpla, kv_lora_rank=Lkv)
@@ -648,12 +648,12 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
                 caches["c"], jnp.concatenate([kv_c, k_pe], axis=-1),
                 batch, layer_idx)
             caches = {**caches, "c": cache}
-            out_l = latent_attention(
-                ql.astype(c.dtype), q_pe, cache, batch,
-                sm_scale=sm_scale, kv_lora_rank=Lkv, rope_dim=R,
-                layer=layer_idx)
-            v = jnp.einsum("tnk,knv->tnv", out_l.astype(jnp.float32),
-                           self._w(lp, "w_uv").astype(jnp.float32))
+            # Absorbed for a decoding row, multi-head for the rows of a
+            # chunk: ops/mla.latent_attention_values picks.
+            v = latent_attention_values(
+                q_nope, q_pe, cache, batch, self._w(lp, "w_uk"),
+                self._w(lp, "w_uv"), sm_scale=sm_scale, kv_lora_rank=Lkv,
+                rope_dim=R, layer=layer_idx)
         if head_gate is not None:
             v = v * head_gate.astype(jnp.float32)[:, :, None]
         o = v.reshape(T, N * V).astype(c.dtype) @ self._w(lp, "wo")

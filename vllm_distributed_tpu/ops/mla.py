@@ -7,17 +7,24 @@ cache stores ONE compressed row per token — the kv_lora_rank latent
 per-head K and V, cutting KV memory by ~an order of magnitude for
 DeepSeek-shaped models.
 
-Design choice: this implementation uses the reference's "data-movement
-friendly" ABSORBED form (common.py:96-120 `_forward_decode`) uniformly
-for prefill and decode. The model absorbs W_UK into the query
-(`ql = q_nope · W_UK`, done once per step outside this op) so attention
-is MQA with qk dim = Lkv + R and v dim = Lkv; W_UV is applied to the
-output afterwards. One uniform path keeps the jit bucket lattice
-additive (the reference keeps separate prefill/decode MLA kernels and
-pays a dispatch split); the compute overhead vs the "compute friendly"
-prefill form is bounded by (Lkv+R)/(P+R) on the score matmul, which the
-MXU absorbs at these widths. A Pallas kernel can later replace the page
-scan without changing this interface.
+Two factorisations of the one attention, as the reference keeps them
+(common.py's separate prefill and decode paths), picked by what a step
+brings (``latent_attention_values`` has the arithmetic and the rule):
+
+* **Absorbed**, the reference's "data-movement friendly" form
+  (common.py:96-120 `_forward_decode`): W_UK is absorbed into the query
+  (`ql = q_nope · W_UK`) so attention is MQA with qk dim = Lkv + R and
+  v dim = Lkv; W_UV is applied to the output afterwards. Every row of a
+  decode program, of the XLA scan (the CPU path and the kernels'
+  correctness reference) and of TPLA, and the single-token rows of a
+  chunk program.
+* **Multi-head**, the "compute friendly" form: K and V up-projected from
+  a sequence's latent rows once a step and attended by plain causal
+  flash attention at (P + R) and V lanes a head. The sequences that
+  bring more than one query row to a chunk program on the Pallas
+  backend.
+
+The latent cache, its page layout and the write are the same under both.
 
 Sharding — two layouts, selected at load (models/loader.py sets
 ``arch.tpla_shards``):
@@ -54,6 +61,8 @@ latent kernel needs the score psum between its two MXU matmuls
 blockwise scan on every backend and the Pallas kernel keeps serving the
 replicated layout.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -278,16 +287,57 @@ def tpla_latent_attention(
     return out.astype(ql.dtype)
 
 
-def latent_attention(q_absorbed, q_pe, c_all, batch, *, sm_scale,
-                     kv_lora_rank, rope_dim, layer=None):
-    """Model-facing entry: select the layer's page slab and run the
-    ragged latent attention (the MLA analogue of ops/attention.
-    paged_attention). Dispatches to the Pallas latent kernel
-    (ops/pallas_mla.py) on the pallas backend; the XLA scan is the
-    correctness reference and the CPU path. Token parallelism is
-    rejected upstream by the loader."""
+def _on_latent_kernel(c_all: jax.Array, batch) -> bool:
+    """Does this batch run on the Pallas latent kernels? They take the
+    stacked cache and the runner's per-sequence runs; everything else
+    (the CPU, in-jit batches without runs) takes the XLA scan."""
     from vllm_distributed_tpu.ops.attention import \
         resolve_attention_backend
+    return (resolve_attention_backend() == "pallas"
+            and getattr(batch, "seq_info", None) is not None
+            and c_all.ndim == 4)
+
+
+def _over_local_heads(fn, *head_sharded):
+    """Run ``fn`` on each tensor-parallel rank's slice of the head axis
+    (axis 1 of every argument and of the result). The latent cache is
+    MQA-shared and replicated, so a rank attends with its heads against
+    the whole cache and no collective is needed."""
+    from vllm_distributed_tpu.parallel import mesh as mesh_state
+    if not (mesh_state.has_global_mesh() and mesh_state.tp_size() > 1):
+        return fn(*head_sharded)
+    from jax.sharding import PartitionSpec as P
+
+    from vllm_distributed_tpu.config import MESH_AXIS_MODEL
+    head_spec = P(None, MESH_AXIS_MODEL, None)
+    return shard_map(
+        fn, mesh=mesh_state.get_global_mesh(),
+        in_specs=(head_spec, ) * len(head_sharded),
+        out_specs=head_spec, check_vma=False)(*head_sharded)
+
+
+def _absorbed_kernel(qc, c_all, seq_info, batch, layer, *, sm_scale,
+                     max_q, kv_lora_rank, rope_dim):
+    """The latent kernel on absorbed queries ``qc`` [rows, N, Lkv + R]
+    laid out as ``seq_info`` says; [rows, N, Lkv], whatever a row past
+    its sequence's q_len holds."""
+    from vllm_distributed_tpu.ops.pallas_mla import \
+        ragged_latent_attention_pallas
+    out = ragged_latent_attention_pallas(
+        _pad_last_dim(qc, c_all.shape[-1]), c_all, seq_info,
+        batch.num_seqs, batch.block_tables, layer, sm_scale=sm_scale,
+        max_q=max_q, kv_lora_rank=kv_lora_rank, rope_dim=rope_dim)
+    return out[..., :kv_lora_rank]
+
+
+def latent_attention(q_absorbed, q_pe, c_all, batch, *, sm_scale,
+                     kv_lora_rank, rope_dim, layer=None):
+    """Model-facing entry of the absorbed form: select the layer's page
+    slab and run the ragged latent attention (the MLA analogue of
+    ops/attention.paged_attention). Dispatches to the Pallas latent
+    kernel (ops/pallas_mla.py) on the pallas backend; the XLA scan is
+    the correctness reference and the CPU path. Token parallelism is
+    rejected upstream by the loader."""
     if getattr(batch, "tknp", None) is not None:
         raise NotImplementedError(
             "MLA under token parallelism (per-rank latent page pools "
@@ -295,43 +345,204 @@ def latent_attention(q_absorbed, q_pe, c_all, batch, *, sm_scale,
             "at admission — this trace-time guard is the backstop)")
     if layer is None:
         layer = jnp.zeros((1, ), jnp.int32)
-    if (resolve_attention_backend() == "pallas"
-            and getattr(batch, "seq_info", None) is not None
-            and c_all.ndim == 4):
-        from vllm_distributed_tpu.ops.pallas_mla import \
-            ragged_latent_attention_pallas
-        qc = jnp.concatenate([q_absorbed, q_pe], axis=-1)
-        Cs = c_all.shape[-1]
-        qc = _pad_last_dim(qc, Cs)
+    if _on_latent_kernel(c_all, batch):
 
         def call(q_):
-            out = ragged_latent_attention_pallas(
-                q_, c_all, batch.seq_info, batch.num_seqs,
-                batch.block_tables, layer, sm_scale=sm_scale,
-                max_q=batch.max_q, kv_lora_rank=kv_lora_rank,
-                rope_dim=rope_dim)
+            out = _absorbed_kernel(
+                q_, c_all, batch.seq_info, batch, layer,
+                sm_scale=sm_scale, max_q=batch.max_q,
+                kv_lora_rank=kv_lora_rank, rope_dim=rope_dim)
             # Rows the kernel never writes are uninitialized HBM; zero
             # them (padding tokens carry slot -1).
             valid = (batch.slot_mapping >= 0)[:, None, None]
-            return jnp.where(valid, out[..., :kv_lora_rank], 0)
+            return jnp.where(valid, out, 0)
 
-        from vllm_distributed_tpu.parallel import mesh as mesh_state
-        if mesh_state.has_global_mesh() and mesh_state.tp_size() > 1:
-            from jax.sharding import PartitionSpec as P
-
-            from vllm_distributed_tpu.config import MESH_AXIS_MODEL
-
-            # q heads shard; the latent cache is MQA-shared and
-            # replicated, so each rank runs the kernel on its head
-            # slice against the full cache.
-            head_spec = P(None, MESH_AXIS_MODEL, None)
-            return shard_map(
-                call, mesh=mesh_state.get_global_mesh(),
-                in_specs=(head_spec, ),
-                out_specs=head_spec, check_vma=False)(qc)
-        return call(qc)
+        return _over_local_heads(
+            call, jnp.concatenate([q_absorbed, q_pe], axis=-1))
     c_layer = c_all[layer[0]] if c_all.ndim == 4 else c_all
     return ragged_latent_attention(
         q_absorbed, q_pe, c_layer, batch.block_tables, batch.req_idx,
         batch.positions, sm_scale=sm_scale, kv_lora_rank=kv_lora_rank,
         rope_dim=rope_dim)
+
+
+def takes_multi_head_form(q_len):
+    """Which sequences of a chunk-bearing step on the latent kernels
+    attend in the multi-head form: those with more than one query row
+    (``latent_attention_values`` has the arithmetic). One line for the
+    traced program and for the runner's counter of the rows it served
+    (a host integer, a NumPy array and a traced array alike)."""
+    return q_len > 1
+
+
+def latent_attention_values(q_nope, q_pe, c_all, batch, w_uk, w_uv, *,
+                            sm_scale, kv_lora_rank, rope_dim, layer):
+    """One layer's attention over the latent cache from the queries as
+    projected (``q_nope`` [T, N, P], ``q_pe`` [T, N, R], rope applied)
+    to the heads' values [T, N, V] in float32, ready for ``wo``.
+
+    One attention over one cache in two factorisations, and what picks
+    between them is the ratio of queries to context:
+
+    * **Absorbed** (``ql = q_nope . w_uk``, MQA over the stored rows at
+      Lkv + R lanes, ``. w_uv`` after): a decoding row reads each
+      576-lane row once for every head. 2 (Lkv + R + Lkv) operations a
+      (query, key) pair and a head, nothing a context row.
+    * **Multi-head** (K and V up-projected from the context once, plain
+      causal flash attention at P + R and V lanes a head): 2 (P + R + V)
+      a pair and a head, and 2 Lkv (P + V) a context row and a head for
+      the up-projection. At DeepSeek-V3's widths that is 768 against
+      2 176 a pair and 327 680 a context row, so it wins from about
+      233 query rows a sequence in operations alone, and earlier in
+      time: the absorbed kernel's q tiles hold a sequence's rows times
+      all its heads, so a chunk's tiles are small and the kernel ran a
+      2 048-row chunk at a sixth of its roofline (PERF.md, Findings
+      PR 36, has the chip's readings).
+
+    A program that can hold a chunk (static ``batch.max_q`` > 1, on the
+    latent kernels) gives every sequence with more than one query row
+    the multi-head form and every single-row sequence the absorbed one,
+    each form's projections over its own rows only
+    (``_mixed_latent_attention``). A pure decode program, the XLA scan
+    and TPLA keep the absorbed form for every row."""
+    if _on_latent_kernel(c_all, batch) and batch.max_q > 1:
+        if getattr(batch, "tknp", None) is not None:
+            raise NotImplementedError(
+                "MLA under token parallelism is not wired")
+
+        def call(qn_, qp_, wuk_, wuv_):
+            return _mixed_latent_attention(
+                qn_, qp_, c_all, batch, wuk_, wuv_, layer,
+                sm_scale=sm_scale, kv_lora_rank=kv_lora_rank,
+                rope_dim=rope_dim)
+
+        # [Lkv, N, *] -> heads on axis 1, like the queries.
+        return _over_local_heads(call, q_nope, q_pe, w_uk, w_uv)
+    ql = jnp.einsum("tnp,knp->tnk", q_nope.astype(jnp.float32),
+                    w_uk.astype(jnp.float32))
+    out_l = latent_attention(
+        ql.astype(q_nope.dtype), q_pe, c_all, batch, sm_scale=sm_scale,
+        kv_lora_rank=kv_lora_rank, rope_dim=rope_dim, layer=layer)
+    return jnp.einsum("tnk,knv->tnv", out_l.astype(jnp.float32),
+                      w_uv.astype(jnp.float32))
+
+
+# Jitted so that a model's like layers share one trace and one lowered
+# function: six copies of it doubled the time a chunk program takes to
+# lower, which every start of a server pays for every bucket.
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "kv_lora_rank", "rope_dim"))
+def _mixed_latent_attention(q_nope, q_pe, c_all, batch, w_uk, w_uv, layer,
+                            *, sm_scale, kv_lora_rank, rope_dim):
+    """A chunk-bearing step on the latent kernels (see
+    ``latent_attention_values``): [T, N, V] float32, zero on padding
+    rows (slot -1).
+
+    Decoding rows (q_len == 1, at most one a sequence: ``seq_info``'s
+    row count, max_num_seqs) are gathered by their q_start, absorbed,
+    attended by ``mla_latent_pages`` at one q tile a sequence, taken
+    through ``w_uv`` and scattered back. Sequences with q_len > 1 are
+    walked one at a time, so one sequence's K and V are live: its
+    context rows (earlier chunks and this one, as ``write_latent_cache``
+    left them in its pages) are gathered and up-projected a block at a
+    time, only as far as kv_len reaches, and ``mla_latent_prefill``
+    attends the chunk against them, reading its query rows from the
+    step's rows and writing its output among them in place."""
+    from vllm_distributed_tpu.ops.pallas_mla import (
+        latent_prefill_attention_pallas, prefill_blocks)
+    T, N, Pn = q_nope.shape
+    Lkv, V = kv_lora_rank, w_uv.shape[-1]
+    dtype = q_nope.dtype
+    seq_info = batch.seq_info
+    num_rows = seq_info.shape[0]
+    q_start, q_len, kv_len = seq_info[:, 0], seq_info[:, 1], seq_info[:, 2]
+    live = jnp.arange(num_rows, dtype=jnp.int32) < batch.num_seqs[0]
+    in_chunk = live & takes_multi_head_form(q_len)
+    decoding = live & jnp.logical_not(in_chunk)
+
+    # --- The absorbed form over the decoding rows.
+    src = jnp.where(decoding, q_start, 0)
+    ql = jnp.einsum("tnp,knp->tnk", q_nope[src].astype(jnp.float32),
+                    w_uk.astype(jnp.float32))
+    row_info = jnp.stack(
+        [jnp.arange(num_rows, dtype=jnp.int32),
+         decoding.astype(jnp.int32), kv_len, seq_info[:, 3]], axis=1)
+    out_l = _absorbed_kernel(
+        jnp.concatenate([ql.astype(dtype), q_pe[src]], axis=-1), c_all,
+        row_info, batch, layer, sm_scale=sm_scale, max_q=1,
+        kv_lora_rank=Lkv, rope_dim=rope_dim)
+    # A sequence the kernel skips leaves its row uninitialized.
+    out_l = jnp.where(decoding[:, None, None], out_l, 0)
+    v_dec = jnp.einsum("tnk,knv->tnv", out_l.astype(jnp.float32),
+                       w_uv.astype(jnp.float32))
+
+    # --- The multi-head form over the chunk sequences.
+    PS = c_all.shape[2]
+    bq, bk, S = prefill_blocks(batch.max_q,
+                               batch.block_tables.shape[1] * PS)
+    # Context rows up-projected at a time: whole kv blocks of whole
+    # pages, about a thousand rows.
+    cb = bk * max(1, min(1024, S) // bk)
+    while S % cb:
+        cb -= bk
+    assert cb % PS == 0, (cb, PS)
+    tables = _pad_last_dim(batch.block_tables, S // PS)
+    # The kernel reads a head's block as a window of a row's lanes, in
+    # whole 128-lane tiles: the rope lanes and V's are padded with
+    # zeros, V's in the weights (a row of V at 192 lanes takes 256 in
+    # device memory anyway). Queries, K, V and the output are
+    # two-dimensional, a row's heads side by side, as the matrix
+    # products leave them; a q tile of room follows the step's rows.
+    Rp, Vp = -(-rope_dim // 128) * 128, -(-V // 128) * 128
+    w_k = w_uk.astype(dtype).reshape(Lkv, N * Pn)
+    w_v = _pad_last_dim(w_uv.astype(dtype), Vp).reshape(Lkv, N * Vp)
+    qn = jnp.pad(q_nope.reshape(T, N * Pn), ((0, bq), (0, 0)))
+    qp = jnp.pad(_pad_last_dim(q_pe, Rp).reshape(T, N * Rp),
+                 ((0, bq), (0, 0)))
+    chunk_order = jnp.argsort(jnp.logical_not(in_chunk), stable=True)
+
+    def one_sequence(i, carry):
+        out, k_nope, k_pe, v = carry
+        qs, qn_rows, kvn, row = (seq_info[chunk_order[i], j]
+                                 for j in range(4))
+
+        def up_project(b, kv):
+            k_nope, k_pe, v = kv
+            pages = jax.lax.dynamic_slice(tables[row], (b * (cb // PS), ),
+                                          (cb // PS, ))
+            rows = c_all[layer[0], pages].reshape(cb, -1).astype(dtype)
+            latent = rows[:, :Lkv]
+            # bf16 operands, float32 accumulation.
+            kb = jnp.dot(latent, w_k, preferred_element_type=jnp.float32)
+            vb = jnp.dot(latent, w_v, preferred_element_type=jnp.float32)
+            at = b * cb
+            return (
+                jax.lax.dynamic_update_slice(k_nope, kb.astype(dtype),
+                                             (at, 0)),
+                jax.lax.dynamic_update_slice(
+                    k_pe, _pad_last_dim(rows[:, Lkv:Lkv + rope_dim], Rp),
+                    (at, 0)),
+                jax.lax.dynamic_update_slice(v, vb.astype(dtype), (at, 0)))
+
+        k_nope, k_pe, v = jax.lax.fori_loop(
+            0, (kvn + cb - 1) // cb, up_project, (k_nope, k_pe, v))
+        out = latent_prefill_attention_pallas(
+            qn, qp, k_nope, k_pe, v, out, jnp.stack([qs, qn_rows, kvn]),
+            num_heads=N, max_q=batch.max_q, sm_scale=sm_scale)
+        return out, k_nope, k_pe, v
+
+    # Nothing is filled beforehand: the kernel reads K and V no further
+    # than the block that kv_len ends in, which the walk has written,
+    # and the rows of the output that no sequence writes are padding.
+    out, _, _, _ = jax.lax.fori_loop(
+        0, in_chunk.sum(), one_sequence,
+        (jax.lax.empty((T + bq, N * Vp), dtype),
+         jax.lax.empty((S, N * Pn), dtype), jax.lax.empty((S, Rp), dtype),
+         jax.lax.empty((S, N * Vp), dtype)))
+    # The decoding rows join in the program's dtype, as the chunks' rows
+    # are; the widening is the caller's first elementwise pass.
+    out = out[:T].reshape(T, N, Vp)[..., :V]
+    out = out.at[jnp.where(decoding, q_start, T)].set(
+        v_dec.astype(dtype), mode="drop")
+    valid = (batch.slot_mapping >= 0)[:, None, None]
+    return jnp.where(valid, out, 0).astype(jnp.float32)

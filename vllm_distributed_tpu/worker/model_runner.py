@@ -185,6 +185,11 @@ class TPUModelRunner:
         # summed over the scheduled requests): what the attention kernel
         # must read, in tokens.
         self.kv_tokens_read_hist = Histogram(STEP_TOKEN_BUCKETS)
+        # Query rows of the step that latent attention served in the
+        # multi-head form (ops/mla.latent_attention_values): 0 on a
+        # decode step and for a model without a latent cache.
+        self.latent_prefill_tokens_hist = Histogram(STEP_TOKEN_BUCKETS)
+        self._latent_kernels: Optional[bool] = None
         # Host arrays that _prepare_inputs and the launch handed to the
         # device for the step through _h2d (explicit hand-overs only:
         # not the scalars an eager op puts, not the multi-step burst):
@@ -974,6 +979,24 @@ class TPUModelRunner:
             self._unified = "k" in self.model.kv_cache_specs()
         return self._unified
 
+    def _latent_prefill_tokens(self, q_lens: np.ndarray,
+                               max_q: int) -> int:
+        """Query rows of a step that the latent layers attend in the
+        multi-head form: the condition ``ops/mla.latent_attention_values``
+        tests when it traces the program (a chunk program, on the
+        latent kernels, no TPLA) and its rule for a sequence."""
+        if self._latent_kernels is None:
+            from vllm_distributed_tpu.ops.attention import \
+                resolve_attention_backend
+            self._latent_kernels = (
+                bool(getattr(self.model.cfg, "mla", False))
+                and getattr(self.model, "tpla_shards", 1) <= 1
+                and resolve_attention_backend() == "pallas")
+        if max_q <= 1 or not self._latent_kernels:
+            return 0
+        from vllm_distributed_tpu.ops.mla import takes_multi_head_form
+        return int(q_lens[takes_multi_head_form(q_lens)].sum())
+
     def _tile_params(self) -> tuple[int, int]:
         """The fixed (prefill tile rows, decode group width) of the
         mega-kernel, computed from LOCAL head counts (the kernel runs
@@ -1267,6 +1290,8 @@ class TPUModelRunner:
         # seq_info rows are (q start, q len, kv len, batch row): kv len
         # is the context (computed + scheduled) a request's queries see.
         self.kv_tokens_read_hist.observe(int(seq_info[:num_runs, 2].sum()))
+        self.latent_prefill_tokens_hist.observe(
+            self._latent_prefill_tokens(seq_info[:num_runs, 1], max_q))
 
         # Mega-kernel partition descriptor: kv-write rows first (the
         # fused write+attend pass needs them to precede every attention
@@ -2775,6 +2800,8 @@ class TPUModelRunner:
         reported by the engine core, which shares ``self.phases``."""
         stats: dict = {
             "step_kv_tokens_read": self.kv_tokens_read_hist.to_dict(),
+            "step_latent_prefill_tokens":
+            self.latent_prefill_tokens_hist.to_dict(),
             "step_host_transfers": self.host_transfers_hist.to_dict(),
             "num_recompiles": self.num_recompiles,
             # Kernel-dispatch + lattice observability (vdt:attn_kernel_
