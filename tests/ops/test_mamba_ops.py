@@ -17,8 +17,7 @@ from vllm_distributed_tpu.ops.mamba import (SegmentInfo,
                                             build_segment_info,
                                             causal_conv1d_ragged,
                                             segmented_linear_scan,
-                                            selective_scan_ragged,
-                                            ssd_scan_ragged)
+                                            selective_scan_ragged)
 
 
 def _make_seg(chunks, T, S):
@@ -139,41 +138,6 @@ def test_selective_scan_ragged_matches_sequential():
         np.testing.assert_allclose(new_state[r], h, rtol=1e-4, atol=1e-4)
         t += n
     np.testing.assert_allclose(new_state[3], ssm_state[3])
-
-
-def test_ssd_scan_ragged_matches_sequential():
-    rng = np.random.default_rng(3)
-    Hm, P, N, G = 4, 3, 5, 2
-    seg = _make_seg(CHUNKS, T, S)
-    x = rng.normal(size=(T, Hm, P)).astype(np.float32)
-    dt = rng.uniform(0.01, 0.5, (T, Hm)).astype(np.float32)
-    A = -rng.uniform(0.1, 1.0, (Hm, )).astype(np.float32)
-    B = rng.normal(size=(T, G, N)).astype(np.float32)
-    C = rng.normal(size=(T, G, N)).astype(np.float32)
-    D = rng.normal(size=(Hm, )).astype(np.float32)
-    ssm_state = rng.normal(size=(S + 1, Hm, P, N)).astype(np.float32)
-
-    y, new_state = ssd_scan_ragged(
-        jnp.asarray(x), jnp.asarray(dt), jnp.asarray(A), jnp.asarray(B),
-        jnp.asarray(C), jnp.asarray(D), jnp.asarray(ssm_state), seg)
-    y, new_state = np.asarray(y), np.asarray(new_state)
-
-    rep = Hm // G
-    t = 0
-    for r, pos0, n in CHUNKS:
-        h = (ssm_state[r].copy() if pos0 > 0 else
-             np.zeros((Hm, P, N), np.float32))
-        for i in range(n):
-            for hd in range(Hm):
-                g = hd // rep
-                a = np.exp(dt[t + i, hd] * A[hd])
-                h[hd] = (a * h[hd] + dt[t + i, hd] *
-                         x[t + i, hd][:, None] * B[t + i, g][None])
-                want = h[hd] @ C[t + i, g] + D[hd] * x[t + i, hd]
-                np.testing.assert_allclose(y[t + i, hd], want, rtol=1e-4,
-                                           atol=1e-4)
-        np.testing.assert_allclose(new_state[r], h, rtol=1e-4, atol=1e-4)
-        t += n
 
 
 def test_build_segment_info_from_attention_batch():

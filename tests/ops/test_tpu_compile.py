@@ -474,6 +474,106 @@ def test_gated_delta_kernels_compile_at_published_widths(
     assert (f"%{kda.KERNEL_CHUNK}" in text) == (form in ("chunk", "auto"))
 
 
+def segment_shapes(T: int, S: int):
+    from vllm_distributed_tpu.ops.mamba import SegmentInfo
+    flag = jnp.bool_
+    seg = SegmentInfo(
+        row=sds((T, )), valid=sds((T, ), flag), off=sds((T, )),
+        start=sds((T, ), flag), end=sds((T, ), flag),
+        has_init=sds((T, ), flag), q_len_by_row=sds((S + 1, )),
+        q_start_by_row=sds((S + 1, )),
+        has_init_by_row=sds((S + 1, ), flag))
+    return jax.tree.flatten(seg)
+
+
+@pytest.mark.parametrize("tokens,form", [
+    (1, "decode"), (2048, "chunk"), (2048, "auto")])
+def test_mamba2_kernels_compile_at_published_widths(
+        compile_for_chip, tokens, form):
+    """``ops/ssd.py`` as `granite-4.0-h-micro` serves it: 64 heads of 64
+    channels on 128 state channels, a row of 128 x 4096 float32, 56
+    state rows and the dump row, 36 layers in one buffer of 4.9 GB that
+    no instruction of the program makes again; a decode batch (one
+    token a row: ``ssd_recurrent``), and the 2 048-token bucket in the
+    chunked form at Q = 256 (``ssd_chunk``) and as the program picks
+    between the forms at run time."""
+    from benchmarks.rooflines.ssd_state import mamba_layers
+    from vllm_distributed_tpu.ops import ssd
+    cf = bench_config("granite-4.0-h-micro")
+    Hm, Pd, N, G = (cf["mamba_n_heads"], cf["mamba_d_head"],
+                    cf["mamba_d_state"], cf["mamba_n_groups"])
+    S, layers = cf["engine_args"]["max_num_seqs"], mamba_layers(cf)
+    assert (Hm, Pd, N, G, S, layers) == (64, 64, 128, 1, 56, 36)
+    T = S + 8 if form == "decode" else tokens + 8
+    leaves, tree = segment_shapes(T, S)
+    f32 = jnp.float32
+
+    def fn(x, dt, A, B, C, D, state, layer, *seg_leaves):
+        return ssd.ssd_ragged(
+            x, dt, A, B, C, D, state, layer,
+            jax.tree.unflatten(tree, seg_leaves),
+            chunk_size=cf["mamba_chunk_size"],
+            decode_only=form == "decode",
+            prefill_form="auto" if form == "decode" else form,
+            interpret=False)
+
+    rows = (layers, S + 1, N, Hm * Pd)
+    compiled = compile_for_chip(
+        fn, sds((T, Hm, Pd), jnp.bfloat16), sds((T, Hm), f32),
+        sds((Hm, ), f32), sds((T, G, N), f32), sds((T, G, N), f32),
+        sds((Hm, ), f32), sds(rows, f32), sds((1, )), *leaves)
+    text = compiled.as_text()
+    assert f"%{ssd.KERNEL_RECURRENT}" in text
+    assert (f"%{ssd.KERNEL_CHUNK}" in text) == (form in ("chunk", "auto"))
+    # No state per token: the largest temporaries are the chunks' masks
+    # (13 chunks x 64 heads x 256 x 256), far under a state a token
+    # (2 MiB x 2 056).
+    assert compiled.memory_analysis().temp_size_in_bytes < 600 * 2 ** 20
+
+
+def test_two_64_lane_kv_heads_share_a_page_row(compile_for_chip,
+                                               monkeypatch):
+    """`granite-4.0-h-micro`'s attention: 32 query heads on 8 K/V heads
+    of 64 lanes, scale 1/64, no rotation. Mosaic refuses a 64-lane page
+    (``test_unpadded_head_dim_is_refused``); the model hands the kernel
+    4 K/V heads of 128 lanes, two heads to a row
+    (``models/bamba.py`` ``_attention``): 8 192 B of K and V a token and
+    no padding. The real dispatch entry compiles at those shapes."""
+    from vllm_distributed_tpu.ops import attention
+    from vllm_distributed_tpu.ops.pallas_attention import (
+        Q_TILE_PAD, num_partition_programs)
+    from vllm_distributed_tpu.worker.model_runner import AttentionBatch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
+    monkeypatch.setenv("VDT_PALLAS_INTERPRET", "0")
+    geo = Geometry(32, 4, 128, 4, 16384, 56, 256, 2048)
+    assert attention.storage_head_dim(2 * 64) == geo.D
+    # The tile sizes are the runner's, from the model's own 32 / 8 heads
+    # (worker/model_runner.py _tile_params).
+    bq, sb = unified_statics(geo._replace(KVH=8))
+    t_bucket = 64
+    T = t_bucket + Q_TILE_PAD
+    G = kv_write_runs(T, geo)
+    P = num_partition_programs(t_bucket, geo.R, bq=bq, sb=sb,
+                               num_kv_writes=G)
+    cache = sds((geo.L, geo.NP, geo.KVH, PS, geo.D), jnp.bfloat16)
+    new = sds((T, geo.KVH, geo.D), jnp.bfloat16)
+
+    def fn(q, k, v, kn, vn, desc, si, dl, runs, bt, slots, layer):
+        batch = AttentionBatch(
+            req_idx=slots, positions=slots, slot_mapping=slots,
+            block_tables=bt, seq_lens=dl, seq_info=si, num_seqs=layer,
+            kv_runs=runs, num_kv_runs=layer, attn_desc=desc,
+            decode_list=dl, max_q=1, attn_bq=bq, attn_sb=sb)
+        return attention.write_kv_and_attend(
+            q, k, v, kn, vn, batch, sm_scale=1 / 64, layer=layer)
+
+    compile_for_chip(
+        fn, sds((T, geo.QH, geo.D), jnp.bfloat16), cache, cache, new, new,
+        sds((P, 3)), sds((geo.R, 4)), sds((geo.R, )), sds((G, 4)),
+        sds((geo.R, geo.pages_per_req)), sds((T, )), sds((1, )))
+
+
 # ---------------------------------------------------------------------
 # The benchmark configurations' own programs, weights as shapes
 # ---------------------------------------------------------------------
@@ -488,6 +588,11 @@ ONE_LAYER = {
     # KDA with the dense MLP, KDA with the experts, latent attention
     # with the experts: a period of three in place of the file's six.
     "ling3-flash-ep4": {"num_hidden_layers": 3, "layer_group_size": 3},
+    # Two periods of the published ten: the walk is a scan whose body is
+    # one period whatever the depth.
+    "granite-4.0-h-micro": {
+        "num_hidden_layers": 20,
+        "layer_types": (["mamba"] * 5 + ["attention"] + ["mamba"] * 4) * 2},
 }
 
 
@@ -614,3 +719,13 @@ def test_one_layer_forward_compiles(abstract_runner, name, t_bucket):
     if name in SPARSE:
         slab, _ = expert_slab(bench_config(name))
         assert slab_copies(compiled, slab) == []
+    if name == "granite-4.0-h-micro":
+        # The state rows are updated in place (18 mamba layers x 57 rows
+        # of 2 MiB here), and the step holds no state per token.
+        assert slab_copies(compiled, "f32[18,57,128,4096]") == []
+        text = compiled.as_text()
+        assert not re.search(r"= f32\[(1,)?57,128,4096\]\S* "
+                             r"(copy|dynamic-slice|dynamic-update-slice)\(",
+                             text)
+        assert text.count(" while(") == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30

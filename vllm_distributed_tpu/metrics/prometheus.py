@@ -162,6 +162,13 @@ HISTOGRAMS = {
         "multi-head form (sequences with more than one query row in a "
         "chunk program on the latent kernels; 0 on a decode step and "
         "for a model without a latent cache)"),
+    "step_ssd_chunk_tokens": (
+        "vdt:step_ssd_chunk_tokens",
+        "Per step, tokens that the Mamba-2 layers took in the chunked "
+        "form (ops/ssd.py ssd_chunk: the segments longer than one token "
+        "of a step whose chunks fit the program's static count; 0 on a "
+        "decode step, on the token-by-token fallback and for a model "
+        "without such layers)"),
     "step_host_transfers": (
         "vdt:step_host_transfers",
         "Per step, host arrays the model runner's _prepare_inputs and "

@@ -7,9 +7,11 @@ layer, hybrid cache groups sizing attention pages separately from SSM
 state.
 
 TPU design mirrors models/jamba.py (per-kind stacked parameter
-subtrees, unrolled heterogeneous layer walk) with the Mamba-2 mixer of
-models/mamba.py (segmented SSD scan, split x / B-C depthwise convs)
-and llama-style partial rotary on the attention layers.
+subtrees) with the Mamba-2 mixer of models/mamba.py (ops/ssd.py's two
+kernels on in-place state rows, split x / B-C depthwise convs) and
+llama-style partial rotary on the attention layers; the heterogeneous
+layer walk is a scan over the periods of the layer pattern
+(``run_layers``). models/granite_hybrid.py builds on it.
 """
 
 from __future__ import annotations
@@ -25,8 +27,8 @@ from vllm_distributed_tpu.models.common import (apply_rope_single,
 from vllm_distributed_tpu.models.jamba import JambaForCausalLM
 from vllm_distributed_tpu.models.llama import MODEL_AXIS
 from vllm_distributed_tpu.models.mamba import Mamba2ForCausalLM
-from vllm_distributed_tpu.ops.attention import (paged_attention,
-                                                write_kv_cache)
+from vllm_distributed_tpu.ops.attention import (storage_head_dim,
+                                                write_kv_and_attend)
 from vllm_distributed_tpu.ops.mamba import build_segment_info
 
 
@@ -48,6 +50,7 @@ class BambaForCausalLM(JambaForCausalLM):
         arch.time_step_limit = tuple(
             getattr(hf, "time_step_limit", None)
             or (0.0, float("inf")))
+        arch.ssd_chunk_size = int(getattr(hf, "mamba_chunk_size", 256))
         arch.use_conv_bias = bool(getattr(hf, "mamba_conv_bias", True))
         if getattr(hf, "mamba_proj_bias", False):
             raise ValueError(
@@ -58,6 +61,8 @@ class BambaForCausalLM(JambaForCausalLM):
         arch.attn_indices = tuple(idx)
         factor = getattr(hf, "partial_rotary_factor", None) or 1.0
         arch.rotary_dim = int(arch.head_dim * factor)
+        # K/V heads that share one page row (see ``_attention``).
+        arch.kv_pack = 1
         arch.num_experts = 0
         if not hasattr(arch, "state_slots"):
             arch.state_slots = 0
@@ -253,12 +258,86 @@ class BambaForCausalLM(JambaForCausalLM):
         # to the single source of truth in models/mamba.py.
         return Mamba2ForCausalLM._state_shapes(self, depth)
 
+    def state_row_bytes(self) -> int:
+        return Mamba2ForCausalLM.state_row_bytes(self)
+
     def kv_cache_specs(self) -> dict:
         # Paged K/V specs from the hybrid base + Mamba-2 state specs.
         return {**JambaForCausalLM.kv_cache_specs(self),
                 **Mamba2ForCausalLM.kv_cache_specs(self)}
 
+    def _page_row(self) -> tuple[int, int]:
+        """K/V heads and lanes of a page row (``kv_pack`` heads share
+        one row of the lane tile)."""
+        c = self.cfg
+        return (c.total_kv_heads // c.kv_pack,
+                storage_head_dim(c.head_dim * c.kv_pack))
+
+    def make_kv_caches(self, num_pages: int, page_size: int,
+                       cache_dtype=None, num_layers=None) -> dict:
+        c = self.cfg
+        assert num_layers is None or num_layers == c.num_layers, \
+            "hybrid stacks are not sliceable per stage (no PP)"
+        heads, lanes = self._page_row()
+        shape = (len(self._attn_layers), num_pages, heads, page_size, lanes)
+        caches = {name: jnp.zeros(shape, cache_dtype or c.dtype)
+                  for name in ("k", "v")}
+        caches.update({name: jnp.zeros(s, d) for name, (s, d)
+                       in self.state_shapes().items()})
+        return caches
+
+    def kv_cache_page_bytes(self, page_size: int) -> int:
+        heads, lanes = self._page_row()
+        return (2 * len(self._attn_layers) * page_size * heads * lanes
+                * jnp.dtype(self.cfg.dtype).itemsize)
+
     # ------------------------------------------------------------------
+    # Forward: the layer kinds repeat with some period (the whole stack
+    # where they do not), so the walk is a scan of one period's body
+    # over the periods, the pages and state rows carried through it and
+    # updated in place. A forward program's size and compile time are a
+    # period's.
+    # ------------------------------------------------------------------
+    def _period(self) -> int:
+        L = self.cfg.num_layers
+        kinds = [self._is_attn(i) for i in range(L)]
+        return next(p for p in range(1, L + 1) if L % p == 0
+                    and all(kinds[i] == kinds[i % p] for i in range(L)))
+
+    def _attention(self, lp: dict, x: jax.Array, caches: dict, layer,
+                   batch, rope) -> tuple[jax.Array, dict]:
+        """GQA over the pages of attention layer ``layer``. With
+        ``kv_pack`` p > 1, p K/V heads of ``head_dim`` lanes share one
+        page row of ``p * head_dim``: the kernel sees ``KVH / p`` heads
+        of that width, a query head's lanes are zero outside its own
+        K/V head's slot (so its scores are its own), and of its output
+        only that slot is kept."""
+        c = self.cfg
+        T = x.shape[0]
+        QH, KVH, D, p = (c.num_q_heads, c.total_kv_heads, c.head_dim,
+                         c.kv_pack)
+        q = (x @ lp["wq"]).reshape(T, QH, D)
+        k = (x @ lp["wk"]).reshape(T, KVH, D)
+        v = (x @ lp["wv"]).reshape(T, KVH, D)
+        if rope is not None:
+            q, k = rope(q), rope(k)
+        if p > 1:
+            slot = (np.arange(QH) // (QH // KVH)) % p
+            own = jnp.asarray(slot[:, None] == np.arange(p), q.dtype)
+            q = (q[:, :, None, :] * own[None, :, :, None]).reshape(
+                T, QH, p * D)
+            k = k.reshape(T, KVH // p, p * D)
+            v = v.reshape(T, KVH // p, p * D)
+        k_all, v_all, attn = write_kv_and_attend(
+            q, caches["k"], caches["v"], k, v, batch,
+            sm_scale=c.sm_scale_override or D ** -0.5,
+            layer=jnp.reshape(layer, (1, )).astype(jnp.int32), window=0)
+        if p > 1:
+            attn = jnp.sum(attn.reshape(T, QH, p, D)
+                           * own[None, :, :, None], axis=2)
+        return (attn.reshape(T, QH * D) @ lp["wo"],
+                {**caches, "k": k_all, "v": v_all})
+
     def run_layers(
         self,
         layer_params: dict,
@@ -268,67 +347,63 @@ class BambaForCausalLM(JambaForCausalLM):
         first_layer: int = 0,
     ) -> tuple[jax.Array, dict]:
         c = self.cfg
-        T = hidden.shape[0]
         seg = build_segment_info(batch, kv_caches["ssm"].shape[1] - 1)
-        sm_scale = c.head_dim**-0.5
-        rd = c.rotary_dim or c.head_dim
-        cos, sin = compute_rope_cos_sin(batch.positions, rd,
-                                        c.rope_theta, c.rope_scaling,
-                                        dtype=jnp.float32)
+        # Static only where the batch's shape says so: under the unified
+        # attention kernel one program a token bucket serves every mix
+        # (max_q is pinned to 1) and ops/ssd.py picks the form at run
+        # time.
+        decode_only = (getattr(batch, "max_q", 0) == 1
+                       and getattr(batch, "attn_desc", None) is None)
+        rm = c.residual_multiplier
+        rope = None
+        if c.rotary_dim:
+            rd = c.rotary_dim
+            cos, sin = compute_rope_cos_sin(batch.positions, rd,
+                                            c.rope_theta, c.rope_scaling,
+                                            dtype=jnp.float32)
 
-        def rope(x):
-            x32 = x.astype(jnp.float32)
-            rot = apply_rope_single(x32[..., :rd], cos, sin)
-            if rd == c.head_dim:
-                return rot.astype(c.dtype)
-            return jnp.concatenate([rot, x32[..., rd:]],
-                                   axis=-1).astype(c.dtype)
+            def rope(x):
+                x32 = x.astype(jnp.float32)
+                rot = apply_rope_single(x32[..., :rd], cos, sin)
+                if rd == c.head_dim:
+                    return rot.astype(c.dtype)
+                return jnp.concatenate([rot, x32[..., rd:]],
+                                       axis=-1).astype(c.dtype)
 
-        def sub(prefix, j):
-            return {
-                k[len(prefix):]: v[j]
-                for k, v in layer_params.items() if k.startswith(prefix)
-            }
+        period = self._period()
+        periods = c.num_layers // period
+        kinds = [self._is_attn(i) for i in range(period)]
+        per = {"a_": sum(kinds), "m_": period - sum(kinds)}
 
-        h = hidden
-        k_all, v_all = kv_caches["k"], kv_caches["v"]
-        conv_all = kv_caches["conv"]
-        conv_bc_all = kv_caches["conv_bc"]
-        ssm_all = kv_caches["ssm"]
-        ai = mi = 0
-        for i in range(c.num_layers):
-            if self._is_attn(i):
-                lp = sub("a_", ai)
-                x = rms_norm(h, lp["ln"], c.rms_norm_eps)
-                q = rope((x @ lp["wq"]).reshape(T, c.num_q_heads,
-                                                c.head_dim))
-                k = rope((x @ lp["wk"]).reshape(T, c.total_kv_heads,
-                                                c.head_dim))
-                v = (x @ lp["wv"]).reshape(T, c.total_kv_heads,
-                                           c.head_dim)
-                li = jnp.full((1, ), ai, jnp.int32)
-                k_all, v_all = write_kv_cache(k_all, v_all, k, v, batch,
-                                              li)
-                attn = paged_attention(q, k_all, v_all, batch,
-                                       sm_scale=sm_scale, layer=li,
-                                       window=0)
-                h = h + attn.reshape(T, -1) @ lp["wo"]
+        def body(carry, pi):
+            h, caches = carry
+            at = {"a_": 0, "m_": 0}
+            for is_attn in kinds:
+                kind = "a_" if is_attn else "m_"
+                # The layer's index in its kind's stack; its weights are
+                # read where they lie (a period's would be a copy).
+                layer = pi * per[kind] + at[kind]
+                at[kind] += 1
+                lp = {k[2:]: jax.lax.dynamic_index_in_dim(v, layer, 0, False)
+                      for k, v in layer_params.items()
+                      if k.startswith(kind)}
+                if is_attn:
+                    x = rms_norm(h, lp["ln"], c.rms_norm_eps)
+                    out, caches = self._attention(lp, x, caches, layer,
+                                                  batch, rope)
+                else:
+                    x = rms_norm(h, lp["norm"], c.rms_norm_eps)
+                    out, caches = Mamba2ForCausalLM._mixer(
+                        self, lp, x, caches, layer, seg, decode_only)
+                h = h + rm * out
                 x2 = rms_norm(h, lp["pre_ln"], c.rms_norm_eps)
-                h = h + swiglu(x2, lp["gate"], lp["up"], lp["down"])
-                ai += 1
-            else:
-                lp = sub("m_", mi)
-                x = rms_norm(h, lp["norm"], c.rms_norm_eps)
-                out, conv_new, conv_bc_new, ssm_new = \
-                    Mamba2ForCausalLM._mixer(
-                        self, lp, x, conv_all[mi], conv_bc_all[mi],
-                        ssm_all[mi], seg)
-                conv_all = conv_all.at[mi].set(conv_new)
-                conv_bc_all = conv_bc_all.at[mi].set(conv_bc_new)
-                ssm_all = ssm_all.at[mi].set(ssm_new)
-                h = h + out
-                x2 = rms_norm(h, lp["pre_ln"], c.rms_norm_eps)
-                h = h + swiglu(x2, lp["gate"], lp["up"], lp["down"])
-                mi += 1
-        return h, {"k": k_all, "v": v_all, "conv": conv_all,
-                   "conv_bc": conv_bc_all, "ssm": ssm_all}
+                h = h + rm * swiglu(x2, lp["gate"], lp["up"], lp["down"])
+            return (h, caches), None
+
+        carry = (hidden, dict(kv_caches))
+        if periods == 1:
+            carry, _ = body(carry, 0)
+        else:
+            carry, _ = jax.lax.scan(
+                body, carry, jnp.arange(periods, dtype=jnp.int32))
+        return carry

@@ -330,6 +330,10 @@ def get_model(config: EngineConfig, mesh,
         logger.info(
             "replicating %d KV heads x%d to cover tensor_parallel_size=%d",
             arch.num_kv_heads, arch.num_kv_head_replicas, tp)
+    # K/V heads that share a page row (models/bamba.py) stay on one rank.
+    while (getattr(arch, "kv_pack", 1) > 1
+           and arch.total_kv_heads % (arch.kv_pack * tp)):
+        arch.kv_pack //= 2
     model = model_cls(arch)
 
     # Performance-attribution plane (metrics/costmodel.py): the analytic

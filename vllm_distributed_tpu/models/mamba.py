@@ -40,12 +40,36 @@ from vllm_distributed_tpu.models.llama import (MODEL_AXIS,
                                                LlamaForCausalLM)
 from vllm_distributed_tpu.ops.mamba import (build_segment_info,
                                             causal_conv1d_ragged,
-                                            selective_scan_ragged,
-                                            ssd_scan_ragged)
+                                            selective_scan_ragged)
+from vllm_distributed_tpu.ops.ssd import ssd_ragged
 
 
 def _softplus(x: jax.Array) -> jax.Array:
     return jax.nn.softplus(x)
+
+
+def ssd_on_mesh(xh, dt, A, B, C, D, ssm_all, layer, seg, chunk_size: int,
+                decode_only: bool):
+    """ops/ssd.py on this rank's heads (the state kernels know no
+    mesh): under tensor parallelism each rank runs them on its
+    d_inner shard, with B and C cut (or first repeated) so that a
+    rank holds the groups of its own heads."""
+    from vllm_distributed_tpu.parallel import mesh as mesh_state
+    kw = dict(chunk_size=chunk_size, decode_only=decode_only)
+    tp = (mesh_state.tp_size() if mesh_state.has_global_mesh() else 1)
+    if tp == 1:
+        return ssd_ragged(xh, dt, A, B, C, D, ssm_all, layer, seg, **kw)
+    rep = tp // math.gcd(B.shape[1], tp)
+    B, C = (jnp.repeat(v, rep, axis=1) for v in (B, C))
+    heads, vec = P(None, MODEL_AXIS, None), P(MODEL_AXIS)
+    rows = P(None, None, None, MODEL_AXIS)
+    return mesh_state.shard_map(
+        lambda *a: ssd_ragged(*a, **kw),
+        mesh=mesh_state.get_global_mesh(),
+        in_specs=(heads, P(None, MODEL_AXIS), vec, heads, heads, vec,
+                  rows, P(), P()),
+        out_specs=(heads, rows), check_vma=False)(
+            xh, dt, A, B, C, D, ssm_all, layer, seg)
 
 
 class MambaForCausalLM(LlamaForCausalLM):
@@ -400,10 +424,12 @@ class Mamba2ForCausalLM(MambaForCausalLM):
 
     Reference: vllm/model_executor/models/mamba2.py on
     layers/mamba/mamba_mixer2.py (chunked-SSD CUDA kernels). Here the
-    recurrence is the same segmented scan as Mamba-1 with head-major
-    shapes (ops/mamba.ssd_scan_ragged); the conv splits into x and B/C
+    recurrence is ops/ssd.py: two Pallas kernels (one token a step, and
+    the chunked form) that update the layer-stacked state rows in
+    place, with no state per token; the conv splits into x and B/C
     halves (depthwise, so two convs == one) to keep x head-sharded and
-    B/C replicated under TP.
+    B/C replicated under TP, where the kernels run per rank on its
+    heads.
     """
 
     @classmethod
@@ -425,6 +451,7 @@ class Mamba2ForCausalLM(MambaForCausalLM):
         arch.n_groups = getattr(hf, "n_groups", 1)
         arch.time_step_limit = tuple(
             getattr(hf, "time_step_limit", (0.0, float("inf"))))
+        arch.ssd_chunk_size = int(getattr(hf, "chunk_size", 256))
         arch.use_conv_bias = bool(getattr(hf, "use_conv_bias", True))
         arch.use_bias = bool(getattr(hf, "use_bias", False))
         if not hasattr(arch, "state_slots"):
@@ -562,7 +589,7 @@ class Mamba2ForCausalLM(MambaForCausalLM):
         return {
             "conv": P(None, None, None, MODEL_AXIS),
             "conv_bc": P(None, None, None, None),
-            "ssm": P(None, None, MODEL_AXIS, None, None),
+            "ssm": P(None, None, None, MODEL_AXIS),
         }
 
     def _state_shapes(self, depth: int) -> dict:
@@ -573,15 +600,26 @@ class Mamba2ForCausalLM(MambaForCausalLM):
             # x and B/C carry separate conv states (their convs split).
             "conv": ((depth, S, c.conv_kernel - 1, c.d_inner), c.dtype),
             "conv_bc": ((depth, S, c.conv_kernel - 1, GN2), c.dtype),
-            "ssm": ((depth, S, c.num_ssm_heads, c.ssm_head_dim,
-                     c.ssm_state_size), jnp.float32),
+            # State-major (ops/ssd.py): every head's channels on the
+            # lanes, so d_inner shards as it does everywhere else.
+            "ssm": ((depth, S, c.ssm_state_size, c.d_inner), jnp.float32),
         }
 
-    def _mixer(self, lp: dict, x: jax.Array, conv_state, conv_bc_state,
-               ssm_state, seg):
+    def state_row_bytes(self) -> int:
+        """One sequence's recurrent and convolution state, all layers."""
+        return sum(int(np.prod(shape[2:])) * shape[0]
+                   * jnp.dtype(dtype).itemsize
+                   for shape, dtype in self.state_shapes().values())
+
+    def _mixer(self, lp: dict, x: jax.Array, caches: dict, layer, seg,
+               decode_only: bool = False):
+        """The mixer of Mamba layer ``layer`` (a traced index into the
+        layer-stacked state rows ``caches``: ``conv``, ``conv_bc``,
+        ``ssm``) on the normed input. Returns (out, caches)."""
         c = self.cfg
         Hm, Pd, N, G = (c.num_ssm_heads, c.ssm_head_dim,
                         c.ssm_state_size, c.n_groups)
+        f32 = jnp.float32
         gate = x @ lp["in_gate"]
         xin = x @ lp["in_x"]
         bc = x @ lp["in_bc"]
@@ -593,31 +631,41 @@ class Mamba2ForCausalLM(MambaForCausalLM):
             xin = xin + b[Di:2 * Di]
             bc = bc + b[2 * Di:2 * Di + 2 * G * N]
             dt_r = dt_r + b[2 * Di + 2 * G * N:]
-        xc, conv_state = causal_conv1d_ragged(
-            xin, lp["conv_x_w"], lp.get("conv_x_b"), conv_state, seg)
-        bcc, conv_bc_state = causal_conv1d_ragged(
-            bc, lp["conv_bc_w"], lp.get("conv_bc_b"), conv_bc_state, seg)
-        xc = jax.nn.silu(xc)
-        bcc = jax.nn.silu(bcc)
+        caches = dict(caches)
+
+        def conv(name, v, w, bias):
+            # Accumulated in float32 over the inputs the row carries in
+            # the model's dtype.
+            rows = caches[name]
+            y, new = causal_conv1d_ragged(
+                v.astype(f32), w.astype(f32),
+                None if bias is None else bias.astype(f32),
+                jax.lax.dynamic_index_in_dim(rows, layer, 0, False), seg)
+            caches[name] = jax.lax.dynamic_update_index_in_dim(
+                rows, new.astype(rows.dtype), layer, 0)
+            return jax.nn.silu(y)
+
+        xc = conv("conv", xin, lp["conv_x_w"], lp.get("conv_x_b"))
+        bcc = conv("conv_bc", bc, lp["conv_bc_w"], lp.get("conv_bc_b"))
         B = bcc[:, :G * N].reshape(-1, G, N)
         C = bcc[:, G * N:].reshape(-1, G, N)
-        dt = _softplus(dt_r.astype(jnp.float32) + lp["dt_bias"])
+        dt = _softplus(dt_r.astype(f32) + lp["dt_bias"])
         lo, hi = c.time_step_limit
         if lo > 0.0 or hi != float("inf"):
             dt = jnp.clip(dt, lo, hi)
         A = -jnp.exp(lp["A_log"])  # [Hm]
-        xh = xc.astype(jnp.float32).reshape(-1, Hm, Pd)
-        y, ssm_state = ssd_scan_ragged(xh, dt, A, B, C, lp["D"],
-                                       ssm_state, seg)
+        y, caches["ssm"] = ssd_on_mesh(
+            xc.astype(c.dtype).reshape(-1, Hm, Pd), dt, A, B, C, lp["D"],
+            caches["ssm"], jnp.reshape(layer, (1, )).astype(jnp.int32),
+            seg, c.ssd_chunk_size, decode_only)
         y = y.reshape(-1, Hm * Pd)
         # Gated RMSNorm (norm(y * silu(gate)) * weight), f32 like HF.
-        y = y * jax.nn.silu(gate.astype(jnp.float32))
-        y = rms_norm(y, lp["gated_norm"].astype(jnp.float32),
-                     c.rms_norm_eps)
+        y = y * jax.nn.silu(gate.astype(f32))
+        y = rms_norm(y, lp["gated_norm"].astype(f32), c.rms_norm_eps)
         out = y.astype(c.dtype) @ lp["out_proj"]
         if c.use_bias:
             out = out + lp["out_b"]
-        return out, conv_state, conv_bc_state, ssm_state
+        return out, caches
 
     def run_layers(
         self,
@@ -629,27 +677,18 @@ class Mamba2ForCausalLM(MambaForCausalLM):
     ) -> tuple[jax.Array, dict]:
         c = self.cfg
         seg = build_segment_info(batch, kv_caches["ssm"].shape[1] - 1)
+        decode_only = getattr(batch, "max_q", 0) == 1
         num_layers = jax.tree_util.tree_leaves(layer_params)[0].shape[0]
         layer_ids = jnp.arange(num_layers, dtype=jnp.int32)
 
         def layer_body(carry, xs):
-            h, conv_all, conv_bc_all, ssm_all = carry
+            h, caches = carry
             lp, li = xs
             x = rms_norm(h, lp["norm"], c.rms_norm_eps)
-            out, conv_new, conv_bc_new, ssm_new = self._mixer(
-                lp, x, conv_all[li], conv_bc_all[li], ssm_all[li], seg)
-            conv_all = jax.lax.dynamic_update_index_in_dim(
-                conv_all, conv_new, li, 0)
-            conv_bc_all = jax.lax.dynamic_update_index_in_dim(
-                conv_bc_all, conv_bc_new, li, 0)
-            ssm_all = jax.lax.dynamic_update_index_in_dim(
-                ssm_all, ssm_new, li, 0)
-            return (h + out, conv_all, conv_bc_all, ssm_all), None
+            out, caches = self._mixer(lp, x, caches, li, seg, decode_only)
+            return (h + out, caches), None
 
-        carry = (hidden, kv_caches["conv"], kv_caches["conv_bc"],
-                 kv_caches["ssm"])
-        carry, _ = jax.lax.scan(layer_body, carry,
-                                (layer_params, layer_ids))
-        hidden, conv_all, conv_bc_all, ssm_all = carry
-        return hidden, {"conv": conv_all, "conv_bc": conv_bc_all,
-                        "ssm": ssm_all}
+        (hidden, caches), _ = jax.lax.scan(
+            layer_body, (hidden, dict(kv_caches)),
+            (layer_params, layer_ids))
+        return hidden, caches

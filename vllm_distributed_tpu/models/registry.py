@@ -69,6 +69,8 @@ from vllm_distributed_tpu.models.whisper import \
     WhisperForConditionalGeneration
 from vllm_distributed_tpu.models.bailing import BailingMoeV3ForCausalLM
 from vllm_distributed_tpu.models.bamba import BambaForCausalLM
+from vllm_distributed_tpu.models.granite_hybrid import \
+    GraniteMoeHybridForCausalLM
 from vllm_distributed_tpu.models.jamba import JambaForCausalLM
 from vllm_distributed_tpu.models.mamba import (FalconMambaForCausalLM,
                                                Mamba2ForCausalLM,
@@ -164,6 +166,9 @@ _REGISTRY: dict[str, type] = {
     "JambaForCausalLM": JambaForCausalLM,
     # Hybrid Mamba-2/attention (models/bamba.py).
     "BambaForCausalLM": BambaForCausalLM,
+    # Granite 4.0-H: Bamba's stack with Granite's multipliers
+    # (models/granite_hybrid.py).
+    "GraniteMoeHybridForCausalLM": GraniteMoeHybridForCausalLM,
     # Ling-3.0: gated delta-rule linear attention (KDA) beside latent
     # attention over DeepSeek-V3 experts (models/bailing.py; the name is
     # the family's convention, not the source's).

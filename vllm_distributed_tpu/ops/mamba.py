@@ -253,51 +253,3 @@ def selective_scan_ragged(
     # Repair the dump row to a fixed value so donation stays clean.
     new_state = new_state.at[dump].set(0.0)
     return y, new_state
-
-
-def ssd_scan_ragged(
-    x: jax.Array,  # [T, Hm, P] activated conv output
-    dt: jax.Array,  # [T, Hm] softplus'd step sizes
-    A: jax.Array,  # [Hm] negative reals (scalar per head)
-    B: jax.Array,  # [T, G, N]
-    C: jax.Array,  # [T, G, N]
-    D: jax.Array,  # [Hm]
-    ssm_state: jax.Array,  # [S+1, Hm, P, N] carried state (f32)
-    seg: SegmentInfo,
-) -> tuple[jax.Array, jax.Array]:
-    """Mamba-2 (SSD) scan over the ragged batch: scalar decay per head,
-    B/C shared across ``Hm // G`` heads per group (GQA-style).
-
-    h_t = exp(dt_t A_h) h_{t-1} + dt_t * x_t ⊗ B_t ; y = h · C + D x.
-    Same segmented scan as Mamba-1 with the head-major shapes of the
-    reference's mamba_mixer2 (vllm/model_executor/layers/mamba/
-    mamba_mixer2.py); the scalar-per-head decay keeps the scan elements
-    rank-4 instead of materializing per-channel decays.
-    Returns (y [T, Hm, P] f32, new state).
-    """
-    T, Hm, P = x.shape
-    G = B.shape[1]
-    rep = Hm // G
-    x32 = x.astype(jnp.float32)
-    dt32 = jnp.where(_bshape(seg.valid, dt), dt.astype(jnp.float32), 0.0)
-    Bh = jnp.repeat(B.astype(jnp.float32), rep, axis=1)  # [T, Hm, N]
-    Ch = jnp.repeat(C.astype(jnp.float32), rep, axis=1)
-    # Decay stays a broadcastable [T, Hm, 1, 1] leaf through the scan
-    # (the combine a1*a2 preserves it; a2*b1+b2 broadcasts), so the
-    # scalar-per-head structure costs 1/(P*N) of the drive's traffic —
-    # the same trick segmented_linear_scan applies to the reset flag.
-    a4 = jnp.exp(dt32 * A[None, :])[:, :, None, None]  # [T, Hm, 1, 1]
-    b = (dt32[:, :, None] * x32)[..., None] * Bh[:, :, None, :]
-
-    h_carry = ssm_state[seg.row]
-    inject = _bshape(seg.start & seg.has_init, h_carry)
-    b = b + jnp.where(inject, a4 * h_carry, 0.0)
-
-    h = segmented_linear_scan(a4, b, seg.start)  # [T, Hm, P, N]
-    y = jnp.einsum("thpn,thn->thp", h, Ch) + D[None, :, None] * x32
-
-    dump = ssm_state.shape[0] - 1
-    wrow = jnp.where(seg.end, seg.row, dump)
-    new_state = ssm_state.at[wrow].set(h)
-    new_state = new_state.at[dump].set(0.0)
-    return y, new_state

@@ -189,6 +189,7 @@ class TPUModelRunner:
         # multi-head form (ops/mla.latent_attention_values): 0 on a
         # decode step and for a model without a latent cache.
         self.latent_prefill_tokens_hist = Histogram(STEP_TOKEN_BUCKETS)
+        self.ssd_chunk_tokens_hist = Histogram(STEP_TOKEN_BUCKETS)
         self._latent_kernels: Optional[bool] = None
         # Host arrays that _prepare_inputs and the launch handed to the
         # device for the step through _h2d (explicit hand-overs only:
@@ -997,6 +998,16 @@ class TPUModelRunner:
         from vllm_distributed_tpu.ops.mla import takes_multi_head_form
         return int(q_lens[takes_multi_head_form(q_lens)].sum())
 
+    def _ssd_chunk_tokens(self, q_lens: np.ndarray, T: int) -> int:
+        """Tokens of a step that the Mamba-2 layers take in the chunked
+        form (``ops/ssd.chunk_form_tokens``: ``ssd_ragged``'s own rule
+        for a program of ``T`` tokens); 0 for a model without them."""
+        chunk = getattr(self.model.cfg, "ssd_chunk_size", 0)
+        if not chunk:
+            return 0
+        from vllm_distributed_tpu.ops.ssd import chunk_form_tokens
+        return chunk_form_tokens(q_lens, T, chunk)
+
     def _tile_params(self) -> tuple[int, int]:
         """The fixed (prefill tile rows, decode group width) of the
         mega-kernel, computed from LOCAL head counts (the kernel runs
@@ -1292,6 +1303,8 @@ class TPUModelRunner:
         self.kv_tokens_read_hist.observe(int(seq_info[:num_runs, 2].sum()))
         self.latent_prefill_tokens_hist.observe(
             self._latent_prefill_tokens(seq_info[:num_runs, 1], max_q))
+        self.ssd_chunk_tokens_hist.observe(
+            self._ssd_chunk_tokens(seq_info[:num_runs, 1], T))
 
         # Mega-kernel partition descriptor: kv-write rows first (the
         # fused write+attend pass needs them to precede every attention
@@ -2802,6 +2815,7 @@ class TPUModelRunner:
             "step_kv_tokens_read": self.kv_tokens_read_hist.to_dict(),
             "step_latent_prefill_tokens":
             self.latent_prefill_tokens_hist.to_dict(),
+            "step_ssd_chunk_tokens": self.ssd_chunk_tokens_hist.to_dict(),
             "step_host_transfers": self.host_transfers_hist.to_dict(),
             "num_recompiles": self.num_recompiles,
             # Kernel-dispatch + lattice observability (vdt:attn_kernel_
@@ -2841,7 +2855,7 @@ class TPUModelRunner:
         if row_bytes is not None:
             # One sequence's recurrent and convolution state over every
             # layer: what a request costs whatever its length
-            # (models/bailing.py).
+            # (models/bailing.py, models/mamba.py).
             stats["state_row_bytes"] = int(row_bytes())
         cm = self._cost_model()
         if cm is not None and self._perf_dispatches:
