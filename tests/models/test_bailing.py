@@ -317,7 +317,8 @@ def check_layer_kinds_and_caches(_monkeypatch=None):
     assert caches["c"].shape == (1, 32, 16, 48)
     assert caches["kda"].shape == (2, 9, 4, 16, 16)
     assert caches["kda"].dtype == jnp.float32
-    assert caches["conv"].shape == (2, 9, 3, 3 * 64)
+    # Three carried inputs of q, k and v, in ops/conv1d.py's tiles.
+    assert caches["conv"].shape == (2, 9, 3, 24, 8)
     # 2 layers x (4 heads x 16 x 16 x 4 B + 3 taps x 192 x 4 B).
     assert m.state_row_bytes() == 2 * (4096 + 2304)
     assert m.fixed_cache_bytes() == 9 * m.state_row_bytes()

@@ -127,6 +127,19 @@ def test_prefill_split_over_three_steps_is_one_pass(model_dir, monkeypatch,
         one[1], reference_logprobs(engine, tiny, one[0]), atol=2e-5)
 
 
+@pytest.mark.parametrize("left", [1, 2])
+def test_a_split_one_or_two_tokens_into_a_chunk_reaches_back_into_the_row(
+        model_dir, monkeypatch, tiny, left):
+    """A prompt of 32 tokens and ``left`` more under a budget of 16 a
+    step: the third step brings fewer tokens than the convolution
+    carries (three), so the row's new inputs are the chunk's and the
+    last of the old ones, and the decode step after it reads both."""
+    (got, ), engine = served(model_dir, monkeypatch, [prompt(32 + left)],
+                             max_num_batched_tokens=16)
+    np.testing.assert_allclose(
+        got[1], reference_logprobs(engine, tiny, got[0]), atol=2e-5)
+
+
 def test_preemption_with_resume_gives_the_uninterrupted_logits(
         model_dir, monkeypatch):
     """Four requests of 24 + 40 tokens against 15 pages of 16: one is

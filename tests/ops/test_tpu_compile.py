@@ -531,6 +531,54 @@ def test_mamba2_kernels_compile_at_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 600 * 2 ** 20
 
 
+@pytest.mark.parametrize("tokens", [64, 2048])
+@pytest.mark.parametrize("name,layers,rows,width,bias", [
+    ("granite-4.0-h-micro", 36, 56, 4096, True),
+    ("granite-4.0-h-micro", 36, 56, 256, True),
+    ("ling3-flash-ep4", 6, 96, 12288, False)])
+def test_conv_rows_kernel_compiles_at_published_widths(
+        one_chip, no_compile_cache, name, layers, rows, width, bias, tokens):
+    """``ops/conv1d.py`` as `granite-4.0-h-micro` serves it (the
+    convolution of x, 4 096 channels, and of B and C, 256, four taps and
+    a bias, 56 state rows and the dump row, 36 layers in one buffer)
+    and as `ling3-flash-ep4` does (q, k and v, 12 288 channels, no bias,
+    96 rows, 6 layers), at a decode bucket and at the 2 048-token one:
+    the stack keeps the layout it is given in (the shape
+    ``[L, S + 1, 3, Di]`` would come in with L on the sublanes and be
+    copied whole before and after the kernel), and nothing makes it
+    again."""
+    from vllm_distributed_tpu.ops import conv1d
+    cf = bench_config(name)
+    S = cf["engine_args"]["max_num_seqs"]
+    K = cf.get("mamba_d_conv") or cf["short_conv_kernel_size"]
+    assert (S, K) == (rows, 4)
+    T = tokens + 8
+    leaves, tree = segment_shapes(T, S)
+    bf16 = jnp.bfloat16
+
+    def fn(x, w, b, stack, layer, *seg_leaves):
+        seg = jax.tree.unflatten(tree, seg_leaves)
+        return conv1d.causal_conv1d_rows(
+            x, w, b if bias else None, stack, layer, seg,
+            conv1d.step_entries(seg, T, K), interpret=False)
+
+    shape = conv1d.rows_shape(layers, S + 1, K, width)
+    assert shape == (layers, S + 1, K - 1, width // 128, 128)
+    placed = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+              for a in (sds((T, width), bf16), sds((K, width), bf16),
+                        sds((width, ), bf16), sds(shape, bf16), sds((1, )),
+                        *leaves)]
+    # The stack is the program's to overwrite, as the runner's caches are.
+    compiled = jax.jit(fn, donate_argnums=(3, )).lower(*placed).compile()
+    text = compiled.as_text()
+    assert text.count(f"%{conv1d.KERNEL}") >= 2      # either list
+    slab = "bf16[" + ",".join(map(str, shape)) + "]"
+    # It comes in as it is read, a row's inputs in whole tiles...
+    assert slab + "{4,3,2,1,0:T(" in text.split("\n", 1)[0]
+    # ... and no instruction makes it again.
+    assert slab_copies(compiled, slab) == []
+
+
 def test_two_64_lane_kv_heads_share_a_page_row(compile_for_chip,
                                                monkeypatch):
     """`granite-4.0-h-micro`'s attention: 32 query heads on 8 K/V heads
@@ -729,3 +777,16 @@ def test_one_layer_forward_compiles(abstract_runner, name, t_bucket):
                              text)
         assert text.count(" while(") == 1
         assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
+    # The convolution's carried inputs are replaced in place too: no
+    # copy of a stack, no layer of one as a temporary.
+    for stack, layer in {
+            "granite-4.0-h-micro": [("18,57,3,32,128", "57,3,32,128"),
+                                    ("18,57,3,2,128", "57,3,2,128")],
+            "ling3-flash-ep4": [("2,97,3,96,128", "97,3,96,128")],
+    }.get(name, []):
+        assert f"bf16[{stack}]" in compiled.as_text()    # an argument
+        assert slab_copies(compiled, f"bf16[{stack}]") == []
+        assert not re.search(
+            rf"= bf16\[(1,)?{layer}\]\S* "
+            r"(copy|dynamic-slice|dynamic-update-slice|fusion)\(",
+            compiled.as_text())

@@ -60,8 +60,9 @@ from vllm_distributed_tpu.models.deepseek import (_DENSE_KEYS, _MOE_KEYS,
 from vllm_distributed_tpu.models.llama import (TOKEN_AXIS,
                                                LlamaForCausalLM)
 from vllm_distributed_tpu.ops import kda
-from vllm_distributed_tpu.ops.mamba import (build_segment_info,
-                                            causal_conv1d_ragged)
+from vllm_distributed_tpu.ops.conv1d import (causal_conv1d_rows, rows_shape,
+                                             step_entries)
+from vllm_distributed_tpu.ops.mamba import build_segment_info
 from vllm_distributed_tpu.ops.mla import latent_storage_dim
 
 _LATENT_KEYS = ("wq", "kv_a", "kv_a_ln", "w_uk", "w_uv", "wo", "w_hg")
@@ -243,7 +244,7 @@ class BailingMoeV3ForCausalLM(DeepseekV3ForCausalLM):
     def kv_cache_specs(self) -> dict:
         return {"c": P(None, TOKEN_AXIS, None, None),
                 "kda": P(None, None, None, None, None),
-                "conv": P(None, None, None, None)}
+                "conv": P(None, None, None, None, None)}
 
     def _state_shapes(self, depth: int) -> dict:
         c = self.cfg
@@ -251,7 +252,8 @@ class BailingMoeV3ForCausalLM(DeepseekV3ForCausalLM):
         N, D = c.num_q_heads, c.kda_head_dim
         return {
             "kda": ((depth, S, N, D, D), jnp.float32),
-            "conv": ((depth, S, c.conv_kernel - 1, 3 * N * D), c.dtype),
+            "conv": (rows_shape(depth, S, c.conv_kernel, 3 * N * D),
+                     c.dtype),
         }
 
     def state_shapes(self) -> dict:
@@ -297,17 +299,19 @@ class BailingMoeV3ForCausalLM(DeepseekV3ForCausalLM):
         return jax.nn.sigmoid((x @ w).astype(jnp.float32))      # [T, N]
 
     def kda_block(self, lp: dict, x: jax.Array, caches: dict, j: int,
-                  batch, seg) -> tuple[jax.Array, dict]:
-        """KDA layer ``j`` (of the KDA stack) on the normed input."""
+                  batch, seg, conv_entries: dict) -> tuple[jax.Array, dict]:
+        """KDA layer ``j`` (of the KDA stack) on the normed input;
+        ``conv_entries`` the step's ``ops/conv1d.step_entries``."""
         c = self.cfg
         T = x.shape[0]
         N, D = c.num_q_heads, c.kda_head_dim
         f32 = jnp.float32
+        layer = jnp.full((1, ), j, jnp.int32)
         # The convolutions accumulate in float32 over the bf16 inputs
-        # the state row carries.
-        qkv, conv_new = causal_conv1d_ragged(
-            (x @ lp["k_wqkv"]).astype(f32), lp["k_conv"].astype(f32), None,
-            caches["conv"][j], seg)
+        # the state row carries, the rows replaced where they lie.
+        qkv, conv = causal_conv1d_rows(
+            x @ lp["k_wqkv"], lp["k_conv"], None, caches["conv"], layer,
+            seg, conv_entries)
         qkv = jax.nn.silu(qkv).reshape(T, 3, N, D)
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
 
@@ -320,14 +324,11 @@ class BailingMoeV3ForCausalLM(DeepseekV3ForCausalLM):
             jnp.exp(lp["k_A"])[None, :, None] * gate_in.reshape(T, N, D))
         beta = jax.nn.sigmoid((x @ lp["k_wb"]).astype(f32))
         o, state = kda.kda_ragged(
-            unit(q) * D ** -0.5, unit(k), v, g, beta, caches["kda"],
-            jnp.full((1, ), j, jnp.int32), seg,
-            decode_only=getattr(batch, "max_q", 0) == 1)
+            unit(q) * D ** -0.5, unit(k), v, g, beta, caches["kda"], layer,
+            seg, decode_only=getattr(batch, "max_q", 0) == 1)
         o = rms_norm(o, lp["k_norm"], c.rms_norm_eps)
         o = o * self._head_gate(x, lp["k_wg"])[:, :, None]
-        caches = {**caches, "kda": state,
-                  "conv": caches["conv"].at[j].set(
-                      conv_new.astype(caches["conv"].dtype))}
+        caches = {**caches, "kda": state, "conv": conv}
         return o.reshape(T, N * D).astype(c.dtype) @ lp["k_wo"], caches
 
     def run_layers(self, layer_params: dict, kv_caches: dict,
@@ -335,6 +336,8 @@ class BailingMoeV3ForCausalLM(DeepseekV3ForCausalLM):
                    ) -> tuple[jax.Array, dict]:
         c = self.cfg
         seg = build_segment_info(batch, kv_caches["kda"].shape[1] - 1)
+        conv_entries = step_entries(seg, hidden.shape[0], c.conv_kernel,
+                                    getattr(batch, "max_q", 0) == 1)
         cos, sin = compute_rope_cos_sin_pairwise(
             batch.positions, c.qk_rope_head_dim, c.rope_theta,
             c.rope_scaling, c.max_position_embeddings)
@@ -357,7 +360,7 @@ class BailingMoeV3ForCausalLM(DeepseekV3ForCausalLM):
                 ai += 1
             else:
                 o, caches = self.kda_block(sub(kda_keys, ki), x, caches,
-                                           ki, batch, seg)
+                                           ki, batch, seg, conv_entries)
                 ki += 1
             h = h + o
             x2 = rms_norm(h, layer_params["post_ln"][i], c.rms_norm_eps)

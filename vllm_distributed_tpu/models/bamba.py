@@ -29,6 +29,7 @@ from vllm_distributed_tpu.models.llama import MODEL_AXIS
 from vllm_distributed_tpu.models.mamba import Mamba2ForCausalLM
 from vllm_distributed_tpu.ops.attention import (storage_head_dim,
                                                 write_kv_and_attend)
+from vllm_distributed_tpu.ops.conv1d import step_entries
 from vllm_distributed_tpu.ops.mamba import build_segment_info
 
 
@@ -354,6 +355,8 @@ class BambaForCausalLM(JambaForCausalLM):
         # time.
         decode_only = (getattr(batch, "max_q", 0) == 1
                        and getattr(batch, "attn_desc", None) is None)
+        entries = step_entries(seg, hidden.shape[0], c.conv_kernel,
+                               decode_only)
         rm = c.residual_multiplier
         rope = None
         if c.rotary_dim:
@@ -394,7 +397,8 @@ class BambaForCausalLM(JambaForCausalLM):
                 else:
                     x = rms_norm(h, lp["norm"], c.rms_norm_eps)
                     out, caches = Mamba2ForCausalLM._mixer(
-                        self, lp, x, caches, layer, seg, decode_only)
+                        self, lp, x, caches, layer, seg, entries,
+                        decode_only)
                 h = h + rm * out
                 x2 = rms_norm(h, lp["pre_ln"], c.rms_norm_eps)
                 h = h + rm * swiglu(x2, lp["gate"], lp["up"], lp["down"])

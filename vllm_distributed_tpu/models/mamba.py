@@ -38,6 +38,8 @@ from jax.sharding import PartitionSpec as P
 from vllm_distributed_tpu.models.common import rms_norm
 from vllm_distributed_tpu.models.llama import (MODEL_AXIS,
                                                LlamaForCausalLM)
+from vllm_distributed_tpu.ops.conv1d import (causal_conv1d_rows, rows_shape,
+                                             step_entries)
 from vllm_distributed_tpu.ops.mamba import (build_segment_info,
                                             causal_conv1d_ragged,
                                             selective_scan_ragged)
@@ -70,6 +72,26 @@ def ssd_on_mesh(xh, dt, A, B, C, D, ssm_all, layer, seg, chunk_size: int,
                   rows, P(), P()),
         out_specs=(heads, rows), check_vma=False)(
             xh, dt, A, B, C, D, ssm_all, layer, seg)
+
+
+def conv_on_mesh(x, weight, bias, rows, layer, seg, entries,
+                 sharded: bool):
+    """ops/conv1d.py on this rank's channels (the kernel knows no
+    mesh): under tensor parallelism each rank runs it on its shard of
+    the channels (``sharded``: d_inner) or on all of them (B and C, which
+    every rank holds)."""
+    from vllm_distributed_tpu.parallel import mesh as mesh_state
+    if not mesh_state.has_global_mesh() or mesh_state.tp_size() == 1:
+        return causal_conv1d_rows(x, weight, bias, rows, layer, seg,
+                                  entries)
+    axis = MODEL_AXIS if sharded else None
+    cols, tiles = P(None, axis), P(None, None, None, axis, None)
+    return mesh_state.shard_map(
+        causal_conv1d_rows, mesh=mesh_state.get_global_mesh(),
+        in_specs=(cols, cols, None if bias is None else P(axis), tiles,
+                  P(), P(), P()),
+        out_specs=(cols, tiles), check_vma=False)(
+            x, weight, bias, rows, layer, seg, entries)
 
 
 class MambaForCausalLM(LlamaForCausalLM):
@@ -587,8 +609,8 @@ class Mamba2ForCausalLM(MambaForCausalLM):
     # ------------------------------------------------------------------
     def kv_cache_specs(self) -> dict:
         return {
-            "conv": P(None, None, None, MODEL_AXIS),
-            "conv_bc": P(None, None, None, None),
+            "conv": P(None, None, None, MODEL_AXIS, None),
+            "conv_bc": P(None, None, None, None, None),
             "ssm": P(None, None, None, MODEL_AXIS),
         }
 
@@ -597,9 +619,11 @@ class Mamba2ForCausalLM(MambaForCausalLM):
         S = (c.state_slots or 256) + 1
         GN2 = 2 * c.n_groups * c.ssm_state_size
         return {
-            # x and B/C carry separate conv states (their convs split).
-            "conv": ((depth, S, c.conv_kernel - 1, c.d_inner), c.dtype),
-            "conv_bc": ((depth, S, c.conv_kernel - 1, GN2), c.dtype),
+            # x and B/C carry separate conv states (their convs split),
+            # each input in tiles of 128 lanes (ops/conv1d.py).
+            "conv": (rows_shape(depth, S, c.conv_kernel, c.d_inner),
+                     c.dtype),
+            "conv_bc": (rows_shape(depth, S, c.conv_kernel, GN2), c.dtype),
             # State-major (ops/ssd.py): every head's channels on the
             # lanes, so d_inner shards as it does everywhere else.
             "ssm": ((depth, S, c.ssm_state_size, c.d_inner), jnp.float32),
@@ -612,10 +636,11 @@ class Mamba2ForCausalLM(MambaForCausalLM):
                    for shape, dtype in self.state_shapes().values())
 
     def _mixer(self, lp: dict, x: jax.Array, caches: dict, layer, seg,
-               decode_only: bool = False):
+               conv_entries: dict, decode_only: bool = False):
         """The mixer of Mamba layer ``layer`` (a traced index into the
         layer-stacked state rows ``caches``: ``conv``, ``conv_bc``,
-        ``ssm``) on the normed input. Returns (out, caches)."""
+        ``ssm``) on the normed input; ``conv_entries`` the step's
+        ``ops/conv1d.step_entries``. Returns (out, caches)."""
         c = self.cfg
         Hm, Pd, N, G = (c.num_ssm_heads, c.ssm_head_dim,
                         c.ssm_state_size, c.n_groups)
@@ -632,17 +657,14 @@ class Mamba2ForCausalLM(MambaForCausalLM):
             bc = bc + b[2 * Di:2 * Di + 2 * G * N]
             dt_r = dt_r + b[2 * Di + 2 * G * N:]
         caches = dict(caches)
+        layer = jnp.reshape(layer, (1, )).astype(jnp.int32)
 
         def conv(name, v, w, bias):
             # Accumulated in float32 over the inputs the row carries in
-            # the model's dtype.
-            rows = caches[name]
-            y, new = causal_conv1d_ragged(
-                v.astype(f32), w.astype(f32),
-                None if bias is None else bias.astype(f32),
-                jax.lax.dynamic_index_in_dim(rows, layer, 0, False), seg)
-            caches[name] = jax.lax.dynamic_update_index_in_dim(
-                rows, new.astype(rows.dtype), layer, 0)
+            # the model's dtype, the rows replaced where they lie.
+            y, caches[name] = conv_on_mesh(
+                v, w, bias, caches[name], layer, seg, conv_entries,
+                sharded=name == "conv")
             return jax.nn.silu(y)
 
         xc = conv("conv", xin, lp["conv_x_w"], lp.get("conv_x_b"))
@@ -656,8 +678,7 @@ class Mamba2ForCausalLM(MambaForCausalLM):
         A = -jnp.exp(lp["A_log"])  # [Hm]
         y, caches["ssm"] = ssd_on_mesh(
             xc.astype(c.dtype).reshape(-1, Hm, Pd), dt, A, B, C, lp["D"],
-            caches["ssm"], jnp.reshape(layer, (1, )).astype(jnp.int32),
-            seg, c.ssd_chunk_size, decode_only)
+            caches["ssm"], layer, seg, c.ssd_chunk_size, decode_only)
         y = y.reshape(-1, Hm * Pd)
         # Gated RMSNorm (norm(y * silu(gate)) * weight), f32 like HF.
         y = y * jax.nn.silu(gate.astype(f32))
@@ -678,6 +699,8 @@ class Mamba2ForCausalLM(MambaForCausalLM):
         c = self.cfg
         seg = build_segment_info(batch, kv_caches["ssm"].shape[1] - 1)
         decode_only = getattr(batch, "max_q", 0) == 1
+        entries = step_entries(seg, hidden.shape[0], c.conv_kernel,
+                               decode_only)
         num_layers = jax.tree_util.tree_leaves(layer_params)[0].shape[0]
         layer_ids = jnp.arange(num_layers, dtype=jnp.int32)
 
@@ -685,7 +708,8 @@ class Mamba2ForCausalLM(MambaForCausalLM):
             h, caches = carry
             lp, li = xs
             x = rms_norm(h, lp["norm"], c.rms_norm_eps)
-            out, caches = self._mixer(lp, x, caches, li, seg, decode_only)
+            out, caches = self._mixer(lp, x, caches, li, seg, entries,
+                                      decode_only)
             return (h + out, caches), None
 
         (hidden, caches), _ = jax.lax.scan(

@@ -161,7 +161,10 @@ def causal_conv1d_ragged(
     conv_state: jax.Array,  # [S+1, K-1, Di] carried inputs per row
     seg: SegmentInfo,
 ) -> tuple[jax.Array, jax.Array]:
-    """Depthwise causal conv over the ragged batch with carried state.
+    """Depthwise causal conv over the ragged batch with carried state:
+    Mamba-1's (and Jamba's) on a layer's rows, and the oracle of
+    ``ops/conv1d.causal_conv1d_rows``, which the Mamba-2 and KDA mixers
+    run on the whole stack in place.
 
     Within a chunk, tap j reads x[t - j]; reads that cross the chunk
     start come from ``conv_state`` (the last K-1 inputs before the
