@@ -118,8 +118,57 @@ def test_phases_partition_the_synchronous_step(engine):
     # The host gap of a step lies inside its period; the stall inside
     # the time outside the device wait.
     assert 0 < d["step_host_gap_seconds"][1] < period_sum
-    assert 0 <= d["step_host_stall_seconds"][1] <= \
-        period_sum - d["phase.device_wait"][1]
+    # (Not held at zero step by step: the two clocks are read a few
+    # microseconds apart.)
+    assert -n * 2e-5 - 0.011 <= d["step_host_stall_seconds"][1] <= \
+        period_sum - d["phase.device_wait"][1] + 0.011
+    while engine.has_unfinished_requests():
+        engine.step()
+
+
+def test_cpu_and_kinds_of_a_running_engine(engine):
+    """The real clocks: counts, the CPU partition and the kinds' sum."""
+    core = _core(engine)
+    ph = core.phases
+    _add(engine, "cpu-a", 10, 60)
+    for _ in range(5):
+        engine.step()
+    own_a = ph.cpu_mark - ph._cpu_at_step_start
+    a = core.get_stats()
+    _add(engine, "cpu-b", 70, 4)    # a 64-token budget: two mixed steps
+    n = 30
+    for _ in range(n):
+        engine.step()
+    own_b = ph.cpu_mark - ph._cpu_at_step_start
+    b = core.get_stats()
+
+    def delta(key, sub=None):
+        ha, hb = (a[key], b[key]) if sub is None \
+            else (a[key][sub], b[key][sub])
+        return hb["count"] - ha["count"], hb["sum"] - ha["sum"]
+
+    cpu = {p: delta("step_phase_cpu_seconds", p) for p in PARTITION}
+    wall = {p: delta("step_phase_seconds", p) for p in PARTITION}
+    assert all(c[0] == n for c in cpu.values())
+    assert delta("step_cpu_seconds")[0] == n
+    assert sum(c[1] for c in cpu.values()) == pytest.approx(
+        delta("step_cpu_seconds")[1] + own_b - own_a, rel=1e-6)
+    # CPU <= wall up to the clocks' resolution: microseconds a read here,
+    # a 10 ms tick of the thread clock on a sandboxed host.
+    for p in PARTITION:
+        assert cpu[p][1] <= wall[p][1] + n * 2e-5 + 0.011, p
+    kinds = {k: delta("step_kind_seconds", k)
+             for k in b["step_kind_seconds"]}
+    # The period of step k is observed at step k+1's start: of the n
+    # periods counted here the first belongs to the decode step before.
+    assert kinds["mixed/period"][0] == kinds["mixed/device_wait"][0] == 2
+    assert kinds["decode/period"][0] == n - 2
+    assert kinds["mixed/period"][1] + kinds["decode/period"][1] == \
+        pytest.approx(delta("step_period_seconds")[1], rel=1e-9)
+    assert kinds["mixed/device_wait"][1] + kinds["decode/device_wait"][1] \
+        == pytest.approx(wall["device_wait"][1], rel=1e-9)
+    # The synchronous engine stamps no request: none observed.
+    assert b["input_queue_seconds"]["count"] == 0
     while engine.has_unfinished_requests():
         engine.step()
 
@@ -283,12 +332,12 @@ def test_span_observes_its_histogram_and_a_batch_queue_timeline_does_not_chain()
     assert second.start >= first.end      # its own clock read, no chain
     assert ph.hists["loop"].count == 0 and ph.period.count == 0
     ph.launch_issued()
-    ph.fetch_begins(second.start)
-    ph.fetch_done(second.end)
     assert ph.host_gap.count == ph.host_stall.count == 0
     assert set(ph.to_dict()) == {
-        "step_phase_seconds", "step_period_seconds",
-        "step_host_gap_seconds", "step_host_stall_seconds"}
+        "step_phase_seconds", "step_phase_cpu_seconds",
+        "step_kind_seconds", "step_period_seconds", "step_cpu_seconds",
+        "step_host_gap_seconds", "step_host_stall_seconds",
+        "input_queue_seconds"}
 
 
 def test_front_end_histograms_render():
@@ -299,3 +348,213 @@ def test_front_end_histograms_render():
     assert "vdt:frontend_process_outputs_seconds_count 1" in text
     assert "vdt:frontend_output_lag_seconds_count 1" in text
     assert 'vdt:frontend_output_lag_seconds_bucket{le="0.004"} 1' in text
+
+
+# ---------------------------------------------------------------------
+# CPU beside wall, and steps by kind (ISSUE 39), on scripted clocks: the
+# wall clock and the thread's CPU clock of metrics/stats.py advance only
+# where the test says, in multiples of 1/1024 s, so sums are exact.
+# ---------------------------------------------------------------------
+class _Clocks:
+    def __init__(self) -> None:
+        self.wall, self.cpu = 100.0, 7.0
+
+    def perf_counter(self) -> float:
+        return self.wall
+
+    def thread_time(self) -> float:
+        return self.cpu
+
+    def monotonic(self) -> float:
+        return self.wall
+
+    def advance(self, wall: int, cpu: int) -> None:
+        self.wall += wall / 1024
+        self.cpu += cpu / 1024
+
+
+@pytest.fixture()
+def clocks(monkeypatch):
+    from vllm_distributed_tpu.metrics import stats
+    c = _Clocks()
+    monkeypatch.setattr(stats, "time", c)
+    return c
+
+
+# (wall, cpu) of each phase in 1/1024 s; loop is what follows the step.
+_EVEN = {p: (4, 4) for p in PARTITION}
+_WAITS = dict(_EVEN, prepare_inputs=(9, 3), launch=(11, 5),
+              device_wait=(16, 1), loop=(6, 2))
+_CPU_IN_THE_WAIT = dict(_WAITS, device_wait=(16, 5), schedule=(3, 3))
+
+
+def _scripted_step(ph: StepPhases, clocks: _Clocks, costs: dict,
+                   kind: str) -> None:
+    with ph.begin_step():
+        for p in PARTITION[1:]:
+            with ph.span(f"vdt.{p}", p):
+                clocks.advance(*costs[p])
+            if p == "schedule":
+                ph.kind = kind      # as EngineCore._step_sync does
+            if p == "launch":
+                ph.launch_issued()
+    ph.end_step(more_work=True)
+    clocks.advance(*costs["loop"])
+
+
+@pytest.mark.parametrize("costs", [_EVEN, _WAITS, _CPU_IN_THE_WAIT],
+                         ids=["no-wait", "waits", "cpu-in-the-wait"])
+def test_cpu_partitions_the_step_as_wall_does(clocks, costs):
+    ph = StepPhases()
+    n = 5
+    for _ in range(n):
+        _scripted_step(ph, clocks, costs, "decode")
+    ph.begin_step()                     # closes the n-th period
+    wall = {p: ph.hists[p].total for p in PARTITION}
+    cpu = {p: ph.cpu_hists[p].total for p in PARTITION}
+    # The first loop is the instant between resume() and the first step.
+    assert ph.hists["loop"].count == n + 1
+    assert all(ph.cpu_hists[p].count == ph.hists[p].count
+               for p in PARTITION)
+    assert ph.period.count == ph.step_cpu.count == n
+    assert sum(wall.values()) == ph.period.total \
+        == n * sum(w for w, _ in costs.values()) / 1024
+    assert sum(cpu.values()) == ph.step_cpu.total \
+        == n * sum(c for _, c in costs.values()) / 1024
+    for p in PARTITION:
+        assert cpu[p] <= wall[p], p
+        assert cpu[p] == n * costs[p][1] / 1024
+    # The stall: wall less CPU of every phase between one fetch's return
+    # and the next fetch's start, which is every phase but device_wait.
+    waited = sum(w - c for p, (w, c) in costs.items()
+                 if p != "device_wait") / 1024
+    assert ph.host_stall.count == n - 1
+    assert ph.host_stall.total == (n - 1) * waited
+    # period - step CPU - device wait differs from it by the CPU that
+    # the thread burnt inside the wait.
+    per_step = (ph.period.total - ph.step_cpu.total
+                - wall["device_wait"]) / n
+    assert per_step == waited - costs["device_wait"][1] / 1024
+    assert ph.host_gap.count == n - 1
+
+
+def test_the_stall_of_a_ticking_cpu_clock_is_right_in_the_mean(clocks):
+    """A thread clock that advances in ticks longer than a step (10 ms
+    on the chip's sandboxed host): a step's stall reads a tick too low
+    or too high, below zero too, and the sum is still the phases' wall
+    less CPU outside device_wait."""
+    ph = StepPhases()
+    quiet = {p: (4, 0) for p in PARTITION}          # no tick in this step
+    ticked = dict(quiet, launch=(4, 40))            # a tick lands in launch
+    n = 6
+    for i in range(n):
+        _scripted_step(ph, clocks, ticked if i % 2 else quiet, "decode")
+    ph.begin_step()
+    # Windows from one fetch's return to the next fetch's start: finish,
+    # update, loop of step i, then schedule .. launch of step i + 1.
+    want = [(3 * 4 + 4 * 4) - (40 if (i + 1) % 2 else 0)
+            for i in range(n - 1)]
+    assert min(want) < 0 < max(want)
+    assert ph.host_stall.count == n - 1
+    assert ph.host_stall.total == sum(want) / 1024
+    assert ph.period.total - ph.step_cpu.total \
+        - ph.hists["device_wait"].total \
+        == sum((w - c) for costs in (quiet, ticked) for p, (w, c)
+               in costs.items() if p != "device_wait") * (n // 2) / 1024
+
+
+def test_a_period_has_the_kind_of_the_step_that_ended(clocks):
+    ph = StepPhases()
+    mixed = dict(_WAITS, device_wait=(64, 2))
+    kinds = ["mixed", "decode", "decode", "mixed", "decode"]
+    for kind in kinds:
+        _scripted_step(ph, clocks, mixed if kind == "mixed" else _WAITS,
+                       kind)
+    ph.begin_step()
+    got = {k: (h.count, h.total) for k, h in ph.kind_hists.items()}
+    period = {"mixed": sum(w for w, _ in mixed.values()) / 1024,
+              "decode": sum(w for w, _ in _WAITS.values()) / 1024}
+    assert got["mixed/period"] == (2, 2 * period["mixed"])
+    assert got["decode/period"] == (3, 3 * period["decode"])
+    assert got["mixed/device_wait"] == (2, 2 * 64 / 1024)
+    assert got["decode/device_wait"] == (3, 3 * 16 / 1024)
+    # The two kinds add up to the unlabelled families.
+    assert got["mixed/period"][0] + got["decode/period"][0] \
+        == ph.period.count
+    assert got["mixed/period"][1] + got["decode/period"][1] \
+        == ph.period.total
+    assert got["mixed/device_wait"][1] + got["decode/device_wait"][1] \
+        == ph.hists["device_wait"].total
+
+
+def test_an_idle_wait_is_in_no_cpu_account(clocks):
+    ph = StepPhases()
+    _scripted_step(ph, clocks, _WAITS, "decode")
+    with ph.begin_step():
+        pass
+    ph.end_step(more_work=False)
+    before = (ph.step_cpu.count, ph.step_cpu.total,
+              ph.cpu_hists["loop"].total)
+    clocks.advance(5000, 3)             # idle, then work arrives
+    ph.resume()
+    _scripted_step(ph, clocks, _WAITS, "decode")
+    assert (ph.step_cpu.count, ph.step_cpu.total,
+            ph.cpu_hists["loop"].total) == before
+    assert ph.host_stall.count == 0
+
+
+def test_a_batch_queue_timeline_claims_no_partition_of_cpu_or_kind(clocks):
+    ph = StepPhases(sync=False)
+    with ph.begin_step():
+        with ph.span("vdt.schedule", "schedule"):
+            clocks.advance(2, 2)
+        clocks.advance(7, 7)            # between blocks: nobody's
+        with ph.span("vdt.dispatch", "dispatch"):
+            with ph.span("vdt._prepare_inputs", "prepare_inputs"):
+                clocks.advance(3, 1)
+            clocks.advance(4, 2)
+        with ph.span("vdt.wait", "wait"):
+            with ph.span("vdt.device_wait", "device_wait"):
+                clocks.advance(16, 1)
+    ph.end_step(more_work=True)
+    ph.begin_step()
+    # Every block its own CPU; dispatch and wait contain the runner's.
+    assert ph.cpu_hists["schedule"].total == 2 / 1024
+    assert ph.cpu_hists["prepare_inputs"].total == 1 / 1024
+    assert ph.cpu_hists["dispatch"].total == 3 / 1024
+    assert ph.hists["dispatch"].total == 7 / 1024
+    assert ph.cpu_hists["wait"].total == 1 / 1024
+    assert ph.step_cpu.count == ph.period.count == 0
+    assert ph.cpu_hists["loop"].count == 0
+    assert all(h.count == 0 for h in ph.kind_hists.values())
+    assert ph.host_stall.count == 0
+
+
+def test_the_new_families_render_with_their_labels():
+    ph = StepPhases()
+    ph.kind_hists["mixed/period"].observe(0.05)
+    ph.cpu_hists["launch"].observe(0.004)
+    ph.step_cpu.observe(0.009)
+    ph.input_queue.observe(0.008)
+    text = render_metrics(ph.to_dict())
+    for line in (
+            'vdt:step_kind_seconds_count{kind="mixed",part="period"} 1',
+            'vdt:step_kind_seconds_bucket{kind="mixed",part="period",'
+            'le="0.05"} 1',
+            'vdt:step_kind_seconds_count{kind="decode",'
+            'part="device_wait"} 0',
+            'vdt:step_phase_cpu_seconds_count{phase="launch"} 1',
+            "vdt:step_cpu_seconds_count 1",
+            'vdt:input_queue_seconds_bucket{le="0.008"} 1'):
+        assert line in text, line
+    front = FrontendStats()
+    front.step_cpu.observe(0.006)
+    front.admit.observe(0.001)
+    front.metrics_render.observe(0.003)
+    front.batches_posted, front.batches_begun = 9, 7
+    text = front.render()
+    for line in ("vdt:frontend_step_cpu_seconds_count 1",
+                 "vdt:frontend_batches_pending 2",
+                 "vdt:frontend_admit_seconds_count 1",
+                 "vdt:metrics_render_seconds_count 1"):
+        assert line in text, line

@@ -63,6 +63,9 @@ class AsyncLLM:
 
         self.request_queues: dict[str, asyncio.Queue] = {}
         self._loop: Optional[asyncio.AbstractEventLoop] = None
+        # time.thread_time() of the loop's thread at the start of the
+        # last step batch (vdt:frontend_step_cpu_seconds).
+        self._cpu_at_batch = 0.0
         self._pump: Optional[threading.Thread] = None
         self._stopped = False
         # Event form of _stopped so recovery backoff sleeps wake
@@ -110,8 +113,12 @@ class AsyncLLM:
                                   and self._loop.is_closed()):
             # A recovered engine outlives asyncio.run() loops (the pump
             # thread survives core restarts): re-bind to the caller's
-            # live loop once the old one is gone.
+            # live loop once the old one is gone. What was posted to
+            # the old one is lost, and the CPU clock is this thread's.
             self._loop = loop
+            stats = self.output_processor.stats
+            stats.batches_begun = stats.batches_posted
+            self._cpu_at_batch = time.thread_time()
         if self._pump is not None:
             return
         self._pump = threading.Thread(target=self._pump_outputs,
@@ -149,12 +156,22 @@ class AsyncLLM:
                     self._fail_all(e)
                 return
             if outs:
-                while not self._post(self._process_batch, outs):
+                while not self._post_batch(outs):
                     # Bound loop closed between asyncio.run() calls:
                     # wait for a new consumer to re-bind it.
                     if self._stopped:
                         return
                     time.sleep(0.05)
+
+    def _post_batch(self, outs) -> bool:
+        """A step's outputs onto the event loop, counted (by this, the
+        pump thread, alone) for vdt:frontend_batches_pending."""
+        stats = self.output_processor.stats
+        stats.batches_posted += 1
+        if self._post(self._process_batch, outs):
+            return True
+        stats.batches_posted -= 1
+        return False
 
     # ------------------------------------------------------------------
     # Crash recovery: restart supervisor + in-flight request replay
@@ -265,6 +282,7 @@ class AsyncLLM:
             pending = list(self._journal.items())
         for rid, orig in pending:
             req = self._continuation_request(rid, orig)
+            req.submitted_at = time.monotonic()
             try:
                 self.core.add_request(req)
             except Exception as e:  # noqa: BLE001 - fail THIS request
@@ -312,11 +330,16 @@ class AsyncLLM:
         return self.core.recv_outputs(timeout_ms=int(timeout_s * 1000))
 
     def _process_batch(self, core_outputs) -> None:
+        """One step's outputs (core_proc.StepOutputs, under either
+        transport: the step's number and when the engine core handed
+        them over) to the request queues, on the event loop."""
         stats = self.output_processor.stats
-        # A batch of the in-process transport says which step it is of
-        # and when the engine core handed it over (core_proc.StepOutputs).
+        stats.batches_begun += 1
+        cpu = time.thread_time()
+        stats.step_cpu.observe(cpu - self._cpu_at_batch)
+        self._cpu_at_batch = cpu
         with span("vdt.process_outputs", stats.process_outputs,
-                  step=getattr(core_outputs, "step", 0)):
+                  step=core_outputs.step):
             processed = self.output_processor.process_outputs(
                 core_outputs)
         self._abort_in_core(processed.reqs_to_abort)
@@ -337,9 +360,8 @@ class AsyncLLM:
             q.put_nowait(ro)
             if ro.finished:
                 self.request_queues.pop(ro.request_id, None)
-        handed_over = getattr(core_outputs, "handed_over", None)
-        if handed_over is not None:
-            stats.output_lag.observe(time.monotonic() - handed_over)
+        stats.output_lag.observe(
+            time.monotonic() - core_outputs.handed_over)
 
     def _fail_request(self, request_id: str, err: Exception) -> None:
         """Terminal error for ONE request (replay rejection) while the
@@ -374,9 +396,14 @@ class AsyncLLM:
         lora_request: Optional[dict] = None,
         pooling_params: Optional[dict] = None,
         multi_modal_data: Optional[dict] = None,
+        received_at: Optional[float] = None,
     ) -> AsyncGenerator[RequestOutput, None]:
         """Async stream of accumulated RequestOutputs for one request
-        (reference: async_llm.py:277)."""
+        (reference: async_llm.py:277). ``received_at`` is
+        ``time.monotonic()`` at the top of the HTTP handler, where
+        vdt:frontend_admit_seconds starts (here, for other callers)."""
+        if received_at is None:
+            received_at = time.monotonic()
         if self._dead_error is not None:
             raise self._dead_error
         self._ensure_pump()
@@ -400,6 +427,7 @@ class AsyncLLM:
             # consumers) while this add waits its turn.
             await asyncio.get_running_loop().run_in_executor(
                 None, self._submit_to_core, core_req)
+            self._note_admission(core_req, received_at)
             while True:
                 item = await queue.get()
                 if item is _ABORTED:
@@ -417,8 +445,21 @@ class AsyncLLM:
                 self.output_processor.abort_requests([request_id])
                 self._abort_in_core([request_id])
 
+    def _note_admission(self, core_req, received_at: float) -> None:
+        """The request's way in, once it is on the transport: observed
+        here, on the event loop, like everything of FrontendStats, and
+        on the request's timeline where one is kept."""
+        out = self.output_processor
+        out.stats.admit.observe(core_req.submitted_at - received_at)
+        out.record_event(core_req.request_id, ev.RECEIVED, ts=received_at)
+        out.record_event(core_req.request_id, ev.SUBMITTED,
+                         ts=core_req.submitted_at)
+
     def _submit_to_core(self, core_req) -> None:
         with self._core_lock:
+            # The put's stamp rides on the request; the engine thread
+            # observes vdt:input_queue_seconds where it takes it.
+            core_req.submitted_at = time.monotonic()
             self.core.add_request(core_req)
             # Journaled only once the add landed in the CURRENT core
             # incarnation (both under the core lock): a restart+replay
@@ -536,7 +577,14 @@ class AsyncLLM:
 
     async def _utility(self, method: str, *args):
         if isinstance(self.core, BackgroundEngineCore):
-            return getattr(self.core.core, method)(*args)
+            call = getattr(self.core.core, method)
+            if method == "profile":
+                # start_trace and stop_trace take seconds: off the loop,
+                # which keeps serving (EngineCore.profile holds its own
+                # lock against the step's deadline sweep).
+                return await asyncio.get_running_loop().run_in_executor(
+                    None, call, *args)
+            return call(*args)
         # MP core: the pump thread owns the output socket; poll for the
         # stashed result. The send runs off-loop under the core lock.
         call_id = await asyncio.get_running_loop().run_in_executor(

@@ -25,6 +25,7 @@ keep the host from blocking and the scheduler's grant state coherent.
 """
 
 import os
+import threading
 import time
 from collections import deque
 from typing import Optional
@@ -152,6 +153,11 @@ class EngineCore:
         # monotonic deadline (VDT_PROFILE_MAX_S) after which the step
         # loop force-stops an unstopped trace — a wedged xprof client
         # (perf.capture_stall drill) can never wedge serving.
+        # start_trace and stop_trace take seconds, so the front end runs
+        # profile() on a thread of its own: the lock keeps a second
+        # start, and the step loop's deadline sweep, out of a capture
+        # that is starting or stopping.
+        self._profile_lock = threading.Lock()
         self._profile_dir: Optional[str] = None
         self._profile_deadline = 0.0
         self._profile_seq = 0
@@ -172,7 +178,6 @@ class EngineCore:
             # output never arrives, but the FIRST structured request
             # then never stalls the busy loop for the load's duration.
             # The file check skips weights-only dirs (most tests).
-            import threading
             self._vocab_bytes_thread = threading.Thread(
                 target=self._prefetch_vocab_bytes, daemon=True,
                 name="vocab-bytes-prefetch")
@@ -268,9 +273,18 @@ class EngineCore:
         wedged state the deadline exists to prevent. A stop_trace that
         itself fails re-arms the deadline for a bounded retry."""
         if (self._profile_dir is None
-                or time.monotonic() < self._profile_deadline):
+                or time.monotonic() < self._profile_deadline
+                or not self._profile_lock.acquire(blocking=False)):
             return
+        try:
+            self._expire_profile_locked()
+        finally:
+            self._profile_lock.release()
+
+    def _expire_profile_locked(self) -> None:
         trace_dir = self._profile_dir
+        if trace_dir is None:
+            return      # stopped while this sweep took the lock
         try:
             import jax
             jax.profiler.stop_trace()
@@ -322,6 +336,8 @@ class EngineCore:
             scheduler_output = self.scheduler.schedule()
         self.last_step_scheduled = \
             scheduler_output.total_num_scheduled_tokens > 0
+        ph.kind = ("mixed" if scheduler_output.num_prefill_tokens
+                   else "decode")
         runner_output = self.executor.execute_model(scheduler_output)
         with ph.span("vdt.update_from_output", "update"):
             return self.scheduler.update_from_output(scheduler_output,
@@ -552,8 +568,10 @@ class EngineCore:
         xplane dump even if the client dies before the stop lands. Fault point ``perf.capture_stall`` simulates that
         wedged client: the stop RPC fails and the deadline is what ends
         the capture, counted in vdt:fault_injections_total."""
-        import os
+        with self._profile_lock:
+            return self._profile_locked(action)
 
+    def _profile_locked(self, action: str) -> str:
         import jax
 
         from vllm_distributed_tpu import envs

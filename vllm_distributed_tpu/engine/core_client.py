@@ -15,6 +15,7 @@ from typing import Optional
 
 from vllm_distributed_tpu.config import EngineConfig
 from vllm_distributed_tpu.core.sched.scheduler import EngineCoreOutput
+from vllm_distributed_tpu.engine.core_proc import StepOutputs
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.request import EngineCoreRequest
 
@@ -364,15 +365,18 @@ class SyncMPClient(EngineCoreClient):
 
     def recv_outputs(
             self, timeout_ms: int) -> Optional[list[EngineCoreOutput]]:
-        """Pump-thread receive (AsyncLLM): next output batch or None on
-        timeout; utility results are stashed for fetch_result(). All
-        receives must come from ONE thread — zmq sockets are not
-        thread-safe."""
+        """Pump-thread receive (AsyncLLM): next output batch (as
+        ``StepOutputs``, with the step's number and the core's stamp of
+        the hand-over) or None on timeout; utility results are stashed
+        for fetch_result(). All receives must come from ONE thread — zmq
+        sockets are not thread-safe."""
         msg = self._recv(timeout_ms)
         if msg is None:
             return None
         if msg["t"] == "outputs":
-            outs = [self._serial.decode_output(v) for v in msg["outs"]]
+            outs = StepOutputs(
+                [self._serial.decode_output(v) for v in msg["outs"]],
+                msg.get("step", 0), msg.get("handed_over"))
             self._mark_finished(outs)
             return outs
         if msg["t"] == "result":

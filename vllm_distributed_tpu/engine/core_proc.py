@@ -13,6 +13,7 @@ import signal
 import threading
 import time
 import traceback
+from typing import Optional
 
 import zmq
 
@@ -117,7 +118,13 @@ def _try_add(core: EngineCore, req):
     """Add a request; a rejectable failure (e.g. a grammar the
     front-end validator missed) must not take the busy loop down — it
     bounces back as an aborted output so the client unblocks. Returns
-    the synthetic output, or None on success."""
+    the synthetic output, or None on success. This is where the engine
+    thread takes a request off the transport: the wait since the front
+    end put it there (one ``time.monotonic()`` for all processes of a
+    host) is observed, unless an old peer sent no stamp."""
+    if req.submitted_at is not None:
+        core.phases.input_queue.observe(
+            time.monotonic() - req.submitted_at)
     try:
         core.add_request(req)
         return None
@@ -140,16 +147,24 @@ def _raise_shutdown() -> None:
     raise _Shutdown()
 
 
+def _send_outputs(core: EngineCore, out: zmq.Socket, outputs) -> None:
+    """One step's outputs with what ``StepOutputs`` carries in process:
+    the step's number and ``time.monotonic()`` of the hand-over."""
+    out.send(serial.pack({
+        "t": "outputs",
+        "outs": [serial.encode_output(o) for o in outputs],
+        "step": core.phases.step,
+        "handed_over": time.monotonic(),
+    }))
+
+
 def _handle_msg(core: EngineCore, out: zmq.Socket, msg: dict) -> None:
     t = msg["t"]
     if t == "add":
         req = serial.decode_request(msg["req"])
         rejected = _try_add(core, req)
         if rejected is not None:
-            out.send(serial.pack({
-                "t": "outputs",
-                "outs": [serial.encode_output(rejected)],
-            }))
+            _send_outputs(core, out, [rejected])
     elif t == "abort":
         core.abort_requests(list(msg["ids"]))
     elif t == "call":
@@ -191,10 +206,7 @@ def _busy_loop(core: EngineCore, inp: zmq.Socket, out: zmq.Socket) -> None:
             continue
         outputs = core.step()
         if outputs:
-            out.send(serial.pack({
-                "t": "outputs",
-                "outs": [serial.encode_output(o) for o in outputs],
-            }))
+            _send_outputs(core, out, outputs)
         elif (not core.last_step_scheduled
               and not core.has_inflight_batches()):
             # Nothing ran on device (all requests held on async KV
@@ -213,16 +225,19 @@ def _busy_loop(core: EngineCore, inp: zmq.Socket, out: zmq.Socket) -> None:
 
 
 class StepOutputs(list):
-    """One step's outputs as the in-process transport hands them to the
-    front end: the list, the step's number (the ``step`` of its spans)
-    and ``time.monotonic()`` of the hand-over."""
+    """One step's outputs as a transport hands them to the front end:
+    the list, the step's number (the ``step`` of its spans) and
+    ``time.monotonic()`` of the hand-over (now, in process; the core's
+    stamp, off a socket)."""
 
     __slots__ = ("step", "handed_over")
 
-    def __init__(self, outputs, step: int) -> None:
+    def __init__(self, outputs, step: int,
+                 handed_over: Optional[float] = None) -> None:
         super().__init__(outputs)
         self.step = step
-        self.handed_over = time.monotonic()
+        self.handed_over = (time.monotonic() if handed_over is None
+                            else handed_over)
 
 
 class BackgroundEngineCore:
@@ -303,7 +318,8 @@ class BackgroundEngineCore:
                         if kind == "add":
                             rejected = _try_add(self.core, payload)
                             if rejected is not None:
-                                self.output_queue.put([rejected])
+                                self.output_queue.put(StepOutputs(
+                                    [rejected], self.core.phases.step))
                         elif kind == "abort":
                             self.core.abort_requests(payload)
                         elif kind == "shutdown":

@@ -35,6 +35,7 @@ from vllm_distributed_tpu.engine.core_client import (EngineCoreClient,
                                                      InprocClient,
                                                      RestartSupervisor,
                                                      SyncMPClient)
+from vllm_distributed_tpu.engine.core_proc import StepOutputs
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.metrics import events as ev
 from vllm_distributed_tpu.request import (EngineCoreRequest,
@@ -868,6 +869,7 @@ class DPEngineClient(EngineCoreClient):
         self._check_any_alive()
         per = max(timeout_ms // len(self.clients), 1)
         outs: list[EngineCoreOutput] = []
+        first = None
         polled = False
         for i, client in enumerate(self.clients):
             if i in self._down:
@@ -880,6 +882,7 @@ class DPEngineClient(EngineCoreClient):
                 continue
             if batch:
                 outs.extend(batch)
+                first = batch if first is None else first
         if not polled:
             # Every replica is down (resurrection probe in flight):
             # honor the caller's poll budget instead of busy-spinning
@@ -887,7 +890,12 @@ class DPEngineClient(EngineCoreClient):
             time.sleep(timeout_ms / 1000)
             return None
         outs = self._mark_finished(outs)
-        return outs or None
+        if not outs:
+            return None
+        # One batch of several replicas' steps: it carries the stamps of
+        # the first that was polled, the one that has waited longest.
+        return StepOutputs(outs, getattr(first, "step", 0),
+                           getattr(first, "handed_over", None))
 
     # ------------------------------------------------------------------
     def send_utility(self, method: str, *args) -> int:

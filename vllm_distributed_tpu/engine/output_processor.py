@@ -175,14 +175,17 @@ class OutputProcessor:
                                              ev.ABORTED, detail)
 
     def record_event(self, request_id: str, event: str,
-                     detail: Optional[dict] = None) -> None:
-        """External lifecycle events (AsyncLLM's engine-death/replay,
-        the admission gate's sheds) onto the request's timeline and the
-        front-end ledger."""
+                     detail: Optional[dict] = None,
+                     ts: Optional[float] = None) -> None:
+        """External lifecycle events (AsyncLLM's engine-death/replay and
+        the stamps of a request's way in, the admission gate's sheds)
+        onto the request's timeline and the front-end ledger, at ``ts``
+        (``time.monotonic()``; now by default)."""
         if not self.timeline_enabled:
             return
-        import time as _time
-        ts = _time.monotonic()
+        if ts is None:
+            import time as _time
+            ts = _time.monotonic()
         state = self.request_states.get(request_id)
         if state is not None:
             state.timeline.append((ts, event, detail))

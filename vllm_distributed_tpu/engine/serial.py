@@ -58,6 +58,9 @@ def encode_request(req: EngineCoreRequest) -> dict:
     # peer (tolerance pinned by tests/engine/test_serial_trace.py).
     if req.trace_ctx is not None:
         d["trace_ctx"] = req.trace_ctx
+    # Likewise additive: the front end's stamp of the put.
+    if req.submitted_at is not None:
+        d["submitted_at"] = req.submitted_at
     return d
 
 
@@ -75,6 +78,7 @@ def decode_request(d: dict) -> EngineCoreRequest:
         pooling_params=d.get("pooling_params"),
         # .get(): absent on the pre-trace-plane wire (old peer).
         trace_ctx=d.get("trace_ctx"),
+        submitted_at=d.get("submitted_at"),
         mm_inputs=([
             MultiModalInput(
                 embeds=np.frombuffer(m["embeds"],

@@ -22,6 +22,7 @@ from vllm_distributed_tpu.entrypoints.openai.admission import (
     AdmissionController, AdmissionRejected)
 from vllm_distributed_tpu.entrypoints.openai.protocol import RequestError
 from vllm_distributed_tpu.logger import init_logger
+from vllm_distributed_tpu.metrics.stats import span
 from vllm_distributed_tpu.utils import random_uuid
 
 logger = init_logger(__name__)
@@ -222,16 +223,26 @@ async def list_models(request: web.Request) -> web.Response:
 async def metrics(request: web.Request) -> web.Response:
     """Prometheus-format scrape of engine stats (reference:
     v1/metrics/prometheus.py mounted at /metrics)."""
-    from vllm_distributed_tpu.metrics.prometheus import render_metrics
     engine = request.app[ENGINE_KEY]
     try:
         stats = await engine.get_stats()
     except Exception:  # noqa: BLE001 - engine busy/dead
         stats = {}
+    # One synchronous block on the API thread, timed and named in a
+    # capture (a traced benchmark run scrapes twice a second).
+    processor = getattr(engine, "output_processor", None)
+    with span("vdt.metrics_render",
+              processor.stats.metrics_render if processor else None):
+        text = _metrics_text(request.app, engine, processor, stats)
+    return web.Response(text=text, content_type="text/plain")
+
+
+def _metrics_text(app: web.Application, engine, processor,
+                  stats: dict) -> str:
+    from vllm_distributed_tpu.metrics.prometheus import render_metrics
     text = render_metrics(stats)
     # Front-end latency histograms (TTFT / ITL / e2e; reference:
     # v1/metrics/loggers.py:143 PrometheusStatLogger families).
-    processor = getattr(engine, "output_processor", None)
     if processor is not None:
         # Follower-process counter snapshots (pid-deduped by the DP
         # aggregator) fold into the front-end's render so /metrics is
@@ -252,7 +263,7 @@ async def metrics(request: web.Request) -> web.Response:
             feed({t: good / max(scored, 1)
                   for t, (scored, good) in list(slo.items())},
                  degraded=(burn is not None and burn.degraded()))
-    ctrl = request.app.get(ADMISSION_KEY)
+    ctrl = app.get(ADMISSION_KEY)
     if ctrl is not None and ctrl.enabled:
         text += (
             "# HELP vdt:admission_queue_depth Admitted, unfinished "
@@ -273,7 +284,7 @@ async def metrics(request: web.Request) -> web.Response:
                 f'vdt:requests_shed_by_class_total{{class="{c}"}} '
                 f"{n}\n"
                 for c, n in sorted(ctrl.shed_by_class.items()))
-    return web.Response(text=text, content_type="text/plain")
+    return text
 
 
 def _profile_dirs(result) -> list[str]:
@@ -1173,6 +1184,7 @@ def _priority_tenant(body: dict) -> tuple[int, Optional[str]]:
 
 
 async def completions(request: web.Request) -> web.StreamResponse:
+    received_at = time.monotonic()  # vdt:frontend_admit_seconds starts
     engine = request.app[ENGINE_KEY]
     model = request.app[MODEL_KEY]
     try:
@@ -1237,7 +1249,8 @@ async def completions(request: web.Request) -> web.StreamResponse:
                 gens.append((idx, engine.generate(
                     prompt, child, request_id=f"{cid}-{idx}",
                     priority=priority, tenant=tenant,
-                    lora_request=lora, multi_modal_data=enc_mm)))
+                    lora_request=lora, multi_modal_data=enc_mm,
+                    received_at=received_at)))
 
         if stream:
             return await _stream_completions(request, cid, created, model,
@@ -1515,6 +1528,7 @@ def _chat_prompt(engine: AsyncLLM, messages: list):
 
 
 async def chat_completions(request: web.Request) -> web.StreamResponse:
+    received_at = time.monotonic()  # vdt:frontend_admit_seconds starts
     engine = request.app[ENGINE_KEY]
     model = request.app[MODEL_KEY]
     try:
@@ -1550,7 +1564,8 @@ async def chat_completions(request: web.Request) -> web.StreamResponse:
                                     request_id=f"{cid}-{i}",
                                     priority=priority, tenant=tenant,
                                     lora_request=lora,
-                                    multi_modal_data=mm))
+                                    multi_modal_data=mm,
+                                    received_at=received_at))
                 for i in range(n)]
         if stream:
             return await _stream_chat(request, cid, created, model, gens)

@@ -30,7 +30,9 @@ import time
 from typing import Any, Optional
 
 # Lifecycle event names (one vocabulary across all components).
+RECEIVED = "received"  # top of the HTTP handler (stamp of the way in)
 ARRIVED = "arrived"  # front-end accepted the request
+SUBMITTED = "submitted"  # put on the engine core's transport
 QUEUED = "queued"  # entered the scheduler's waiting queue
 SCHEDULED = "scheduled"  # first tokens granted (prefill start)
 PREFILL_CHUNK = "prefill_chunk"  # chunked-prefill progress
@@ -84,7 +86,9 @@ FLEET_QUARANTINE = "fleet_quarantine"  # suspect replica force-cycled
 # (the lint_metrics contract, applied to trace span types) — an event
 # name that drifts undocumented fails tier-1.
 EVENT_REGISTRY: dict[str, str] = {
+    RECEIVED: "HTTP handler entered (vdt:frontend_admit_seconds starts)",
     ARRIVED: "front-end accepted the request",
+    SUBMITTED: "put on the engine core's transport (admit ends)",
     QUEUED: "entered the scheduler's waiting queue",
     SCHEDULED: "first tokens granted (prefill start)",
     PREFILL_CHUNK: "chunked-prefill progress",

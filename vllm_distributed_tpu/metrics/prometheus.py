@@ -148,7 +148,18 @@ HISTOGRAMS = {
         "vdt:step_host_stall_seconds",
         "Per step, wall time outside the device wait less the "
         "engine-core thread's own CPU time: time it wanted to run and "
-        "did not (GIL held elsewhere, core taken, launch blocked)"),
+        "did not (GIL held elsewhere, core taken, launch blocked); a "
+        "mean: one step's reading errs by a tick of the CPU clock"),
+    "step_cpu_seconds": (
+        "vdt:step_cpu_seconds",
+        "The engine-core thread's CPU seconds (time.thread_time()) "
+        "between two step starts, one observation a period; the phases' "
+        "CPU seconds sum to it"),
+    "input_queue_seconds": (
+        "vdt:input_queue_seconds",
+        "A request on the transport: the front end's put to the "
+        "engine-core thread taking it (once a step while it has work); "
+        "one time.monotonic() for the processes of a host"),
     "request_queue_seconds": (
         "vdt:request_queue_seconds",
         "add_request to the first step that grants the request tokens"),
@@ -176,16 +187,28 @@ HISTOGRAMS = {
         "packed forward inputs, packed sampling inputs, sampled rows)"),
 }
 
-# Stats entries that map a label value to a histogram dict:
-# key -> (family, HELP, label name).
+# Stats entries that map label values to a histogram dict:
+# key -> (family, HELP, label names). A family of two labels keys its
+# map by the values joined with "/" ("decode/period").
 LABELED_HISTOGRAMS = {
     "step_phase_seconds": (
         "vdt:step_phase_seconds",
-        "Wall seconds per engine-core step phase", "phase"),
+        "Wall seconds per engine-core step phase", ("phase", )),
+    "step_phase_cpu_seconds": (
+        "vdt:step_phase_cpu_seconds",
+        "The engine-core thread's CPU seconds per step phase, read "
+        "where the wall clock is: a phase's wall less its CPU seconds "
+        "is what the thread waited there (GIL, a core, the runtime)",
+        ("phase", )),
+    "step_kind_seconds": (
+        "vdt:step_kind_seconds",
+        "Step period and device wait (part) told apart by the kind of "
+        "step: mixed = the grant held a prefill token, decode = none "
+        "(synchronous path)", ("kind", "part")),
     "step_tokens": (
         "vdt:step_tokens",
         "Tokens granted per non-empty step, by kind (prefill = requests "
-        "still in their prompt, decode = past it)", "kind"),
+        "still in their prompt, decode = past it)", ("kind", )),
 }
 
 
@@ -195,6 +218,8 @@ LABELED_HISTOGRAMS = {
 # table, so an undocumented label set fails tier-1).
 LABELED_METRICS = {
     "vdt:step_phase_seconds": ("phase", ),
+    "vdt:step_phase_cpu_seconds": ("phase", ),
+    "vdt:step_kind_seconds": ("kind", "part"),
     "vdt:step_tokens": ("kind", ),
     "vdt:fault_injections_total": ("point", ),
     # Telemetry plane: per-worker device/compilation series.
@@ -846,11 +871,12 @@ def _render_histogram(name: str, help_text: str, h: dict) -> list[str]:
                                   h.get("count", 0))
 
 
-def _render_labeled_histograms(name: str, help_text: str, label: str,
+def _render_labeled_histograms(name: str, help_text: str, labels: tuple,
                                hists: dict) -> list[str]:
     """One labeled histogram family (vdt:step_phase_seconds{phase},
-    vdt:step_tokens{kind}): HELP/TYPE once, then one series per label
-    value — bucket/+Inf shape comes from the shared exposition helper."""
+    vdt:step_kind_seconds{kind,part}): HELP/TYPE once, then one series
+    per label value(s) — bucket/+Inf shape comes from the shared
+    exposition helper."""
     from vllm_distributed_tpu.metrics.stats import render_histogram_lines
     lines = [f"# HELP {name} {help_text}", f"# TYPE {name} histogram"]
     for value in sorted(hists):
@@ -860,7 +886,9 @@ def _render_labeled_histograms(name: str, help_text: str, label: str,
         lines += render_histogram_lines(
             name, "", h.get("buckets", ()), h.get("counts", ()),
             h.get("sum", 0.0), h.get("count", 0),
-            label=f'{label}="{value}"', header=False)
+            label=",".join(f'{k}="{v}"' for k, v
+                           in zip(labels, value.split("/"))),
+            header=False)
     return lines
 
 
@@ -880,10 +908,10 @@ def render_metrics(stats: dict) -> str:
         value = stats.get(key)
         if isinstance(value, dict):
             lines += _render_histogram(name, help_text, value)
-    for key, (name, help_text, label) in LABELED_HISTOGRAMS.items():
+    for key, (name, help_text, labels) in LABELED_HISTOGRAMS.items():
         value = stats.get(key)
         if isinstance(value, dict) and value:
-            lines += _render_labeled_histograms(name, help_text, label,
+            lines += _render_labeled_histograms(name, help_text, labels,
                                                 value)
     # Attention kernel dispatch counts ({kernel: steps} from the runner,
     # summed per kernel across DP replicas).

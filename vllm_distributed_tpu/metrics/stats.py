@@ -140,28 +140,45 @@ class span:
 
 
 class _PhaseSpan(span):
-    """A span of a ``StepPhases`` timeline. Inside a synchronous step it
-    starts where the timeline's last phase ended, so that the phases
-    leave no hole between them."""
+    """A span of a ``StepPhases`` timeline. Inside a synchronous step a
+    phase starts where the timeline's last phase ended, on the wall
+    clock (``mark``) and on the thread's CPU clock (``cpu_mark``), so
+    that the phases leave no hole between them on either."""
 
-    __slots__ = ("_phases", )
+    __slots__ = ("_phases", "_phase", "_chained", "_cpu_start")
 
     def __init__(self, phases: "StepPhases", name: str,
-                 hist: Optional[Histogram], attrs: dict) -> None:
-        super().__init__(name, hist, step=phases.step, **attrs)
+                 phase: Optional[str], attrs: dict) -> None:
+        super().__init__(name, phases.hists[phase] if phase else None,
+                         step=phases.step, **attrs)
         self._phases = phases
+        self._phase = phase
 
     def __enter__(self) -> "span":
         super().__enter__()
+        if self._phase is None:
+            return self
         phases = self._phases
-        if self.hist is not None and phases.sync and not phases.idle:
+        self._chained = phases.sync and not phases.idle
+        if self._chained:
             self.start = phases.mark
+            self._cpu_start = phases.cpu_mark
+            if self._phase == "device_wait":
+                phases.fetch_begins()
+        else:
+            self._cpu_start = time.thread_time()
         return self
 
     def __exit__(self, *exc) -> None:
         super().__exit__(*exc)
-        if self.hist is not None:
-            self._phases.mark = self.end
+        if self._phase is None:
+            return
+        phases = self._phases
+        cpu = time.thread_time()
+        phases.cpu_hists[self._phase].observe(cpu - self._cpu_start)
+        phases.mark, phases.cpu_mark = self.end, cpu
+        if self._chained and self._phase == "device_wait":
+            phases.fetch_done(self.end - self.start)
 
 
 class StepPhases:
@@ -173,33 +190,56 @@ class StepPhases:
     update`` partition the thread's time between two step starts: each
     starts at ``mark``, the end of the one before it, so they neither
     overlap nor leave holes, and their sums add up to the sum of
-    ``period``. On the batch-queue path every phase times its own block,
-    and ``dispatch`` and ``wait`` contain the runner's phases.
+    ``period``. The thread's CPU time (``time.thread_time()``, read where
+    ``mark`` is) is partitioned the same way: ``cpu_hists`` sum to
+    ``step_cpu``, and a phase's wall time less its CPU time is what the
+    thread waited in it (for the GIL, a core, the runtime). On the
+    batch-queue path every phase times its own block on both clocks, and
+    ``dispatch`` and ``wait`` contain the runner's phases.
 
     ``host_gap`` estimates the device's idle time per step on the host's
     clock: from the return of a step's fetch to the launch of the next
     step's forward. ``host_stall`` is the time this thread wanted to run
-    and did not: the wall time from one fetch's return to the next
-    fetch's start less the thread's own CPU time over it. Near zero, the
-    host time of a step is this thread's own work; several milliseconds,
-    another thread held the GIL, the core was taken, or a launch blocked
-    inside the runtime."""
+    and did not: the phases' wall time less their CPU time, summed from
+    one fetch's return to the next fetch's start (every phase but
+    ``device_wait``). Near zero, the host time of a step is this thread's
+    own work; several milliseconds, another thread held the GIL, the core
+    was taken, or a launch blocked inside the runtime. Read the CPU
+    accounts by their means: ``time.thread_time()`` may advance in ticks
+    far longer than a phase, so one observation says little.
+
+    ``kind`` is ``mixed`` for a step whose grant holds a prefill token
+    and ``decode`` otherwise (the engine core sets it once the step is
+    scheduled); ``kind_hists`` split the period and the device wait by
+    it. ``input_queue`` is a request's wait on the transport, from the
+    front end's put to this thread's take."""
 
     PHASES = ("loop", "schedule", "update_states", "prepare_inputs",
               "launch", "device_wait", "finish", "update", "dispatch",
               "wait")
+    KINDS = ("decode", "mixed")
 
     def __init__(self, sync: bool = True) -> None:
         self.hists = {p: Histogram(STEP_PHASE_BUCKETS)
                       for p in self.PHASES}
+        self.cpu_hists = {p: Histogram(STEP_PHASE_BUCKETS)
+                          for p in self.PHASES}
         self.period = Histogram(STEP_PHASE_BUCKETS)
+        self.step_cpu = Histogram(STEP_PHASE_BUCKETS)
+        self.kind_hists = {f"{k}/{part}": Histogram(STEP_PHASE_BUCKETS)
+                           for k in self.KINDS
+                           for part in ("period", "device_wait")}
         self.host_gap = Histogram(HOST_GAP_BUCKETS)
         self.host_stall = Histogram(HOST_GAP_BUCKETS)
+        self.input_queue = Histogram(QUEUE_LAG_BUCKETS)
         self.sync = sync
         self.step = 0      # number of the current (or last) vdt_step
         self.idle = True   # the last step left no work behind
+        self.kind = "decode"   # of the current (or last) step
         self.mark = 0.0    # end of the last phase of this busy period
+        self.cpu_mark = 0.0    # the thread's CPU time at ``mark``
         self.step_start: Optional[float] = None
+        self._cpu_at_step_start = 0.0
         self._fetch_done: Optional[float] = None
         self._cpu_at_fetch_done = 0.0
         self._loop_span: Optional[span] = None
@@ -207,15 +247,15 @@ class StepPhases:
     def span(self, name: str, phase: Optional[str] = None,
              **attrs) -> span:
         """A span of the current step; with ``phase``, one that observes
-        that phase's histogram as well."""
-        return _PhaseSpan(self, name,
-                          self.hists[phase] if phase else None, attrs)
+        that phase's histograms as well."""
+        return _PhaseSpan(self, name, phase, attrs)
 
     def resume(self) -> None:
         """Work arrived at an idle engine: the wait for it belongs to no
         phase, period, gap or stall."""
         self.idle = False
         self.mark = time.perf_counter()
+        self.cpu_mark = time.thread_time()
         self.step_start = self._fetch_done = None
 
     def begin_step(self) -> StepTraceAnnotation:
@@ -227,12 +267,17 @@ class StepPhases:
         if self.idle:
             self.resume()
         if self.sync:
-            now = time.perf_counter()
+            now, cpu = time.perf_counter(), time.thread_time()
             self.hists["loop"].observe(now - self.mark)
-            self.mark = now
+            self.cpu_hists["loop"].observe(cpu - self.cpu_mark)
+            self.mark, self.cpu_mark = now, cpu
             if self.step_start is not None:
-                self.period.observe(now - self.step_start)
-            self.step_start = now
+                # ``kind`` is still that of the step that ended.
+                period = now - self.step_start
+                self.period.observe(period)
+                self.kind_hists[f"{self.kind}/period"].observe(period)
+                self.step_cpu.observe(cpu - self._cpu_at_step_start)
+            self.step_start, self._cpu_at_step_start = now, cpu
         self.step += 1
         return StepTraceAnnotation("vdt_step", step_num=self.step)
 
@@ -248,25 +293,38 @@ class StepPhases:
         if self.sync and self._fetch_done is not None:
             self.host_gap.observe(time.perf_counter() - self._fetch_done)
 
-    def fetch_begins(self, now: float) -> None:
-        if self.sync and self._fetch_done is not None:
-            cpu = time.thread_time() - self._cpu_at_fetch_done
+    def fetch_begins(self) -> None:
+        """The synchronous step's ``device_wait`` starts at ``mark``:
+        what the phases since the last fetch's return waited. Not held
+        at zero: where the thread's CPU clock advances in ticks (10 ms
+        on a sandboxed host) one step's reading is a tick too high or
+        too low, and only an unclamped sum keeps the mean unbiased and
+        equal to the phases' wall less CPU."""
+        if self._fetch_done is not None:
             self.host_stall.observe(
-                max(0.0, now - self._fetch_done - cpu))
+                (self.mark - self._fetch_done)
+                - (self.cpu_mark - self._cpu_at_fetch_done))
 
-    def fetch_done(self, now: float) -> None:
-        if self.sync:
-            self._fetch_done = now
-            self._cpu_at_fetch_done = time.thread_time()
+    def fetch_done(self, waited: float) -> None:
+        """... and has ended at ``mark``, after ``waited`` seconds."""
+        self._fetch_done = self.mark
+        self._cpu_at_fetch_done = self.cpu_mark
+        self.kind_hists[f"{self.kind}/device_wait"].observe(waited)
 
     def to_dict(self) -> dict:
         """The stats-feed entries of this timeline."""
         return {
             "step_phase_seconds": {p: h.to_dict()
                                    for p, h in self.hists.items()},
+            "step_phase_cpu_seconds": {p: h.to_dict()
+                                       for p, h in self.cpu_hists.items()},
+            "step_kind_seconds": {k: h.to_dict()
+                                  for k, h in self.kind_hists.items()},
             "step_period_seconds": self.period.to_dict(),
+            "step_cpu_seconds": self.step_cpu.to_dict(),
             "step_host_gap_seconds": self.host_gap.to_dict(),
             "step_host_stall_seconds": self.host_stall.to_dict(),
+            "input_queue_seconds": self.input_queue.to_dict(),
         }
 
 
@@ -382,6 +440,21 @@ class FrontendStats:
         default_factory=lambda: Histogram(STEP_PHASE_BUCKETS))
     output_lag: Histogram = field(
         default_factory=lambda: Histogram(QUEUE_LAG_BUCKETS))
+    # The API thread's accounts (all observed on the event loop's
+    # thread): its CPU time (``time.thread_time()``) from one step
+    # batch's start to the next, which is everything it did for a step
+    # since an idle loop burns none; the batches the pump thread has
+    # posted to the loop and those _process_batch has begun; a request's
+    # way from the HTTP handler's first line to the put on the engine
+    # core's transport; one /metrics rendering.
+    step_cpu: Histogram = field(
+        default_factory=lambda: Histogram(STEP_PHASE_BUCKETS))
+    batches_posted: int = 0
+    batches_begun: int = 0
+    admit: Histogram = field(
+        default_factory=lambda: Histogram(QUEUE_LAG_BUCKETS))
+    metrics_render: Histogram = field(
+        default_factory=lambda: Histogram(STEP_PHASE_BUCKETS))
     num_prompt_tokens: int = 0
     num_generation_tokens: int = 0
     num_finished: int = 0
@@ -533,6 +606,28 @@ class FrontendStats:
             "vdt:frontend_output_lag_seconds",
             "Engine core handing a step's outputs over to their "
             "RequestOutputs being on the request queues")
+        lines += self.step_cpu.render(
+            "vdt:frontend_step_cpu_seconds",
+            "CPU seconds of the API (event loop) thread from one step "
+            "batch's start to the next: process_outputs, the SSE writes "
+            "it woke, HTTP handling, admissions, scrapes")
+        lines += [
+            "# HELP vdt:frontend_batches_pending Step batches posted to "
+            "the event loop that _process_batch has not begun",
+            "# TYPE vdt:frontend_batches_pending gauge",
+            "vdt:frontend_batches_pending "
+            f"{self.batches_posted - self.batches_begun}",
+        ]
+        lines += self.admit.render(
+            "vdt:frontend_admit_seconds",
+            "Top of the HTTP handler (AsyncLLM.generate for other "
+            "callers) to the request's put on the engine core's "
+            "transport: parsing, templating, tokenisation, the executor "
+            "hop")
+        lines += self.metrics_render.render(
+            "vdt:metrics_render_seconds",
+            "One /metrics rendering on the API thread (span "
+            "vdt.metrics_render), the engine core's stats in hand")
         for name, help_text, value in (
             ("vdt:prompt_tokens_total",
              "Cumulative prompt tokens of finished requests",

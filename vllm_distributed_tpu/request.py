@@ -86,6 +86,12 @@ class EngineCoreRequest:
     # that is the cross-replica causal link. None when the plane is off
     # (serial.py then omits the key: old-wire byte-identical).
     trace_ctx: Optional[dict[str, Any]] = None
+    # ``time.monotonic()`` of the front end putting the request on the
+    # transport to the engine core (AsyncLLM._submit_to_core); the core
+    # observes vdt:input_queue_seconds where it takes the request. None
+    # from a caller that stamps nothing (the synchronous engine, an old
+    # peer): taken and not observed.
+    submitted_at: Optional[float] = None
 
 
 def continuation_request(orig: EngineCoreRequest,
@@ -97,6 +103,7 @@ def continuation_request(orig: EngineCoreRequest,
     with greedy sampling the resumed stream is token-identical to an
     uninterrupted run."""
     req = copy.deepcopy(orig)
+    req.submitted_at = None     # whoever puts it on a transport stamps it
     # Never replay a remote-KV pull: by replay time the producer's
     # deferred-free registration is consumed or expired, so re-entering
     # WAITING_FOR_REMOTE_KVS would only burn the watchdog ladder before

@@ -1795,7 +1795,6 @@ class TPUModelRunner:
         # charge the dispatch's analytic FLOPs/bytes against it.
         ph = self.phases
         with ph.span("vdt.device_wait", "device_wait") as wait:
-            ph.fetch_begins(wait.start)
             if handle.get("specv"):
                 verify = tuple(np.asarray(jax.device_get(x))
                                for x in handle["dev"][0])
@@ -1803,7 +1802,6 @@ class TPUModelRunner:
             else:
                 verify = None
                 sample = self._fetch_sample(handle["dev"])
-        ph.fetch_done(wait.end)
         wait_s = wait.seconds
         if self._device_telemetry:
             self.device_wait_hist.observe(wait_s)
