@@ -19,6 +19,7 @@ from vllm_distributed_tpu.models.bailing import BailingMoeV3ForCausalLM
 from vllm_distributed_tpu.models.llama import LlamaArchConfig
 from vllm_distributed_tpu.ops import kda
 from vllm_distributed_tpu.ops.mamba import SegmentInfo
+from vllm_distributed_tpu.utils import cdiv
 
 DATA = os.path.join(os.path.dirname(__file__), "..", "benchmarking", "data")
 CELL = "tiny-ling.tiny-decode"
@@ -391,6 +392,46 @@ PROPERTIES = {
 @pytest.mark.parametrize("name", list(PROPERTIES))
 def test_property(name, monkeypatch):
     PROPERTIES[name](monkeypatch)
+
+
+@pytest.mark.parametrize("case,passes", [
+    ("the_routers_picks", 1), ("every_pick_held", 3),
+    ("held_one_past_capacity", 2), ("no_pick_held", 0)])
+def test_share_dispatch_takes_the_passes_its_held_picks_need(
+        case, passes, monkeypatch):
+    """This class inherits the share's dispatch (models/deepseek.py): a
+    pass works on `share_capacity` rows (896 for 512 tokens of 4 picks,
+    a quarter held) and there are as many passes as the held picks need;
+    the result is the reference's expert sum for the same picks,
+    whatever they are."""
+    cfg = tiny_cfg()
+    model = build(cfg)
+    lo, n = model.cfg.experts_held
+    T, k = 512, cfg["num_experts_per_tok"]
+    C = model.share_capacity(T, k)
+    assert C == 896
+    lp = moe_layer(model.init_params(jax.random.PRNGKey(7)))
+    x = jax.random.normal(jax.random.PRNGKey(8), (T, 128), jnp.float32)
+    top_idx, top_vals = model._route(lp, x)
+    flat = np.full(T * k, lo + n, np.int32)      # absent
+    if case == "every_pick_held":
+        flat[:] = lo + np.arange(T * k) % n
+    elif case == "held_one_past_capacity":
+        where = np.random.default_rng(8).permutation(T * k)[:C + 1]
+        flat[where] = lo + np.arange(C + 1) * 5 % n
+    if case != "the_routers_picks":
+        top_idx = jnp.asarray(flat.reshape(T, k))
+    held = int(((top_idx >= lo) & (top_idx < lo + n)).sum())
+    assert cdiv(held, C) == passes, (held, C)
+    dense = jnp.zeros((T, model.cfg.router_experts), jnp.float32).at[
+        jnp.arange(T)[:, None], top_idx].add(top_vals)
+    monkeypatch.setattr(dref, "route", lambda *a, **kw: dense)
+    with jax.default_matmul_precision("highest"):
+        want = dref.moe(x, lp, dict(cfg, n_routed_experts=n),
+                        control="no_shared_expert")
+        got = jax.jit(model.moe_dispatch)(lp, x, top_idx, top_vals)
+    assert (held == 0) == (float(jnp.abs(want).max()) == 0.0)
+    np.testing.assert_allclose(got, want, atol=2e-6)
 
 
 @pytest.mark.parametrize("control", [None, *CONTROLS])

@@ -17,6 +17,7 @@ from benchmarks import run
 from benchmarks.references import deepseek as ref
 from vllm_distributed_tpu.models.deepseek import DeepseekV3ForCausalLM
 from vllm_distributed_tpu.models.llama import LlamaArchConfig
+from vllm_distributed_tpu.utils import cdiv
 
 DATA = os.path.join(os.path.dirname(__file__), "..", "benchmarking", "data")
 
@@ -306,3 +307,86 @@ def test_rows_past_the_groups_may_hold_anything(monkeypatch):
     got = model.moe_dispatch(lp, x, top_idx, top_vals)
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_allclose(got, want, atol=1e-6)
+
+
+def forced_picks(case: str, T: int, k: int, lo: int, n: int, C: int,
+                 routed) -> jax.Array:
+    """[T, k] expert ids of a routing the router itself would not give.
+    Absent picks land on expert 0 (this share holds 8-15)."""
+    flat = np.zeros(T * k, np.int32)
+    if case == "every_pick_held":
+        flat[:] = lo + np.arange(T * k) % n
+    elif case == "no_pick_held":
+        flat[:] = np.where(np.arange(T * k) % 2, lo - 1, lo + n)
+    elif case == "one_held_expert":
+        flat[:] = lo + 3
+    elif case in ("held_at_capacity", "held_one_past_capacity"):
+        # Scattered over the tokens, not the first rows of the sort.
+        held = C + (case == "held_one_past_capacity")
+        where = np.random.default_rng(8).permutation(T * k)[:held]
+        flat[where] = lo + np.arange(held) * 5 % n
+    else:
+        return routed
+    return jnp.asarray(flat.reshape(T, k))
+
+
+@pytest.mark.parametrize("case,T,passes", [
+    ("even_routing", 512, 1), ("every_pick_held", 512, 3),
+    ("no_pick_held", 512, 0), ("one_held_expert", 512, 3),
+    ("decode_bucket_with_padding_rows", 16, 1),
+    ("held_at_capacity", 512, 1), ("held_one_past_capacity", 512, 2)])
+def test_dispatch_takes_the_passes_its_held_picks_need(case, T, passes,
+                                                       monkeypatch):
+    """The share's dispatch works on `share_capacity` rows a pass (896
+    here for 512 tokens of 4 picks, a quarter held: seven tiles of 128
+    for the 768 that are a half over the even share; all 64 picks of a
+    16-row bucket) and takes as many passes as the held picks need;
+    whatever the routing, its result is the reference's expert sum
+    (benchmarks/references/deepseek.py `moe` without the shared expert,
+    given the same picks as a dense weight), in float32 to the
+    tolerance of the tests above."""
+    cfg = tiny_cfg()
+    model = build(cfg)
+    lo, n = model.cfg.experts_held
+    k = cfg["num_experts_per_tok"]
+    C = model.share_capacity(T, k)
+    assert C == {512: 896, 16: 64}[T]
+    lp = moe_layer(model.init_params(jax.random.PRNGKey(7)))
+    x = jax.random.normal(jax.random.PRNGKey(8), (T, 128), jnp.float32)
+    if case == "decode_bucket_with_padding_rows":
+        x = x.at[9:].set(x[8])           # as the runner pads a bucket
+    routed, top_vals = model._route(lp, x)
+    top_idx = forced_picks(case, T, k, lo, n, C, routed)
+    held = int(((top_idx >= lo) & (top_idx < lo + n)).sum())
+    assert cdiv(held, C) == passes, (held, C)
+    dense = jnp.zeros((T, model.cfg.router_experts), jnp.float32).at[
+        jnp.arange(T)[:, None], top_idx].add(top_vals)
+    monkeypatch.setattr(ref, "route", lambda *a, **kw: dense)
+    with jax.default_matmul_precision("highest"):
+        want = ref.moe(x, lp, cfg, control="no_shared_expert")
+        got = jax.jit(model.moe_dispatch)(lp, x, top_idx, top_vals)
+    assert (held == 0) == (float(jnp.abs(want).max()) == 0.0)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize("T,k,held,published,rows", [
+    (2176, 8, 16, 256, 1664),    # GigaChat's chunk step: 13 tiles for 1 632
+    (2176, 8, 128, 512, 6528),   # Ling's: 51 tiles
+    (65, 8, 16, 256, 520),       # a decode step is worked on whole
+    (97, 8, 128, 512, 776),
+    (129, 8, 16, 256, 128),      # 1 032 picks, 65 expected, 97 wanted
+    (1040, 8, 16, 256, 896),     # 780 wanted: 7 tiles
+    (1200, 8, 16, 256, 1152),    # 900 wanted: 8 tiles, made 9
+    (2176, 8, 200, 256, 17408),  # never above T x k
+])
+def test_capacity_follows_the_shapes(T, k, held, published, rows):
+    """`share_capacity` at the benchmark configurations' shapes and at
+    its edges: a half over the even share in an odd count of 128-row
+    tiles, the whole step at 1 024 picks or fewer, never above T x k."""
+    model = build(tiny_cfg())
+    model.cfg.experts_held = (0, held)
+    model.cfg.router_experts = published
+    C = model.share_capacity(T, k)
+    assert C == rows
+    if C < T * k:
+        assert C % 256 == 128 and C >= 1.5 * T * k * held / published

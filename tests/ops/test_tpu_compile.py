@@ -741,6 +741,20 @@ def test_grouped_gemms_read_their_weights_in_place(abstract_runner, name,
     assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes / 4
 
 
+def forward_lowered(runner, t_bucket: int):
+    """(the runner's forward program lowered at ``t_bucket`` for the
+    described chip, its padded token rows)."""
+    _, shapes, shardings = runner._cache_layout(1024)
+    caches = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        shapes, shardings)
+    runner._build_step_fn()
+    T, max_q, G = runner._batch_shape(t_bucket, 2)
+    inputs = on_chip(runner._dummy_step_inputs(T, max_q, G), runner.mesh)
+    with runner.mesh:
+        return runner._forward_fn.lower(runner.params, caches, inputs), T
+
+
 @pytest.mark.parametrize("name,t_bucket", [
     (name, t) for name in ONE_LAYER
     for t in (TOKEN_BUCKETS[0], TOKEN_BUCKETS[-1])
@@ -752,17 +766,7 @@ def test_one_layer_forward_compiles(abstract_runner, name, t_bucket):
     kernel, the layer loop, the expert switch) of each benchmark
     configuration at its published widths, at the smallest and the
     largest token bucket."""
-    runner = abstract_runner(name)
-    _, shapes, shardings = runner._cache_layout(1024)
-    caches = jax.tree.map(
-        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-        shapes, shardings)
-    runner._build_step_fn()
-    T, max_q, G = runner._batch_shape(t_bucket, 2)
-    inputs = on_chip(runner._dummy_step_inputs(T, max_q, G), runner.mesh)
-    with runner.mesh:
-        compiled = runner._forward_fn.lower(runner.params, caches,
-                                            inputs).compile()
+    compiled = forward_lowered(abstract_runner(name), t_bucket)[0].compile()
     assert "tpu_custom_call" in compiled.as_text()
     if name in SPARSE:
         slab, _ = expert_slab(bench_config(name))
@@ -790,3 +794,49 @@ def test_one_layer_forward_compiles(abstract_runner, name, t_bucket):
             rf"= bf16\[(1,)?{layer}\]\S* "
             r"(copy|dynamic-slice|dynamic-update-slice|fusion)\(",
             compiled.as_text())
+
+
+def test_share_dispatch_holds_no_array_of_all_the_picks(abstract_runner):
+    """The 2 048-token forward at the GigaChat cell's widths (16 of 256
+    experts held, 8 picks a token): nothing in the compiled program has
+    `[T x k, hidden]` or `[T x k, moe_intermediate]` rows, the shapes
+    the share's dispatch worked on until PR 40 (250 MB and 71 MB each,
+    for about 1 100 picks held); its passes are one loop around the
+    three grouped GEMMs, whose rows are `share_capacity`'s."""
+    name = "gigachat3-702b-ep16"
+    runner = abstract_runner(name)
+    cf = bench_config(name)
+    lowered, T = forward_lowered(runner, TOKEN_BUCKETS[-1])
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    picks = T * cf["num_experts_per_tok"]
+    C = runner.model.share_capacity(T, cf["num_experts_per_tok"])
+    assert T >= 2048 and C < picks / 8
+    for width in (cf["hidden_size"], cf["moe_intermediate_size"]):
+        assert not re.search(rf"\[{picks},{width}\]", text), (picks, width)
+        assert re.search(rf"bf16\[{C},{width}\]", text), (C, width)
+    assert text.count("ragged-dot") >= 3
+    slab, _ = expert_slab(cf)
+    assert slab_copies(compiled, slab) == []
+
+
+# sha256 of OLMoE's 64-token forward as lowered (StableHLO text without
+# source locations and without the attention kernel's Mosaic payload,
+# which carries its file's path) at commit f07cdeb, the parent of PR 40.
+# A PR that means to change that program records the new digest here
+# and says so.
+OLMOE_FORWARD_64 = (
+    "8d0bf2246de138f9c467c37993e64fc057245e60b9ea1a4c1fb3b12f537b6e6f")
+
+
+def test_a_model_without_a_share_lowers_to_the_same_program(
+        abstract_runner):
+    """PR 40 changed the dispatch of an expert SHARE only: the program
+    of a configuration without one (OLMoE, the Mixtral dispatch) is the
+    parent's, instruction for instruction."""
+    import hashlib
+    lowered, _ = forward_lowered(abstract_runner("olmoe-1b-7b"), 64)
+    text = re.sub(r'loc\([^)]*\)|^#loc.*$|\\22body\\22: \\22[^\\]*\\22',
+                  "", lowered.as_text(), flags=re.M)
+    assert "ragged_dot" in text and "tpu_custom_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == OLMOE_FORWARD_64
