@@ -641,6 +641,9 @@ ONE_LAYER = {
     "granite-4.0-h-micro": {
         "num_hidden_layers": 20,
         "layer_types": (["mamba"] * 5 + ["attention"] + ["mamba"] * 4) * 2},
+    # Every layer is of one kind; two, so that the scan and the expert
+    # switch are there.
+    "zaya1-8b": {"num_hidden_layers": 2, "layer_types": ["hybrid"] * 2},
 }
 
 
@@ -781,6 +784,28 @@ def test_one_layer_forward_compiles(abstract_runner, name, t_bucket):
                              text)
         assert text.count(" while(") == 1
         assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
+    if name == "zaya1-8b":
+        # The carried rows (u; c0 and vb widened to twelve tiles) come
+        # in as they are written and read, not with the layers on the
+        # sublanes as ten and eleven 128-lane tiles would
+        # (ops/conv1d.rows_shape); the experts' slabs are arguments that
+        # nothing makes again; the layers are one loop; every layer's
+        # kernels are there (two passes over the rows, either list).
+        text = compiled.as_text()
+        head = text.split("\n", 1)[0]
+        assert "bf16[2,65,1,2,640]{4,3,2,1,0:T(2,128)" in head
+        assert "bf16[2,65,1,4,384]{4,3,2,1,0:T(4,128)" in head
+        # (The 2 048-token program may move a stack of a few hundred KB
+        # to the fast memory and back, XLA's own prefetch: no re-layout.)
+        for stack in ("bf16[2,65,1,2,640]", "bf16[2,65,1,4,384]"):
+            assert set(slab_copies(compiled, stack)) <= {
+                "copy-start", "copy-done"}, stack
+        assert slab_copies(compiled, expert_slab(bench_config(name))[0]) \
+            == []
+        assert text.count(" while(") == 1
+        assert text.count("%conv_rows") >= 4
+        assert text.count("ragged-dot") >= 3
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
     # The convolution's carried inputs are replaced in place too: no
     # copy of a stack, no layer of one as a temporary.
     for stack, layer in {

@@ -63,3 +63,42 @@ def test_page_is_priced_at_one_devices_share(checkpoint, parallel):
               parallel.get("pipeline_parallel_size", 1))
     assert (r.kv_cache_bytes_per_page() * shards ==
             r.model.kv_cache_page_bytes(r.page_size))
+
+
+@pytest.mark.parametrize("name, slot, page, pages_per_req, seqs, room, want", [
+    # granite-4.0-h-micro at 64 rows (PR 37, call 1): 2.04 GiB after the
+    # rows, 2.00 GiB of them the pages of 64 full-length requests.
+    pytest.param("rows of 73 MiB", 76_437_504, 131_072, 256, 64,
+                 int(2.04 * 2 ** 30), 8, id="granite"),
+    # zaya1-8b (PR 41, call 1): 2.96 GiB after the rows, under the
+    # 4.00 GiB that 64 full-length requests' pages would need, and a
+    # default pool of 128 slots that costs 11 MB: kept whole.
+    pytest.param("rows of 88 KiB", 90_112, 262_144, 256, 64,
+                 int(2.96 * 2 ** 30), None, id="zaya"),
+    # The same small rows where even the pages fit: nothing to cut.
+    pytest.param("room for all", 90_112, 262_144, 256, 64,
+                 int(9.0 * 2 ** 30), None, id="zaya-roomy"),
+    # Rows as large as a request's pages are cut as before.
+    pytest.param("rows of 64 MiB", 2 ** 26, 262_144, 256, 64,
+                 int(5.0 * 2 ** 30), 16, id="large-rows"),
+])
+def test_snapshot_pool_is_cut_only_where_a_cut_buys_pages(
+        name, slot, page, pages_per_req, seqs, room, want):
+    """``TPUWorker._size_state_pool`` on the published geometries: the
+    pages of every schedulable request at full length come first and
+    the pool is cut to what is left, in eights, never under eight; but
+    a default pool (twice ``max_num_seqs`` slots) that costs less than
+    ONE full-length request's pages is left whole, whatever the room:
+    eight slots of 88 KiB for 64 sequences would save 10 MB of 3 GiB and
+    leave every chunk-boundary snapshot but eight without a slot."""
+    from types import SimpleNamespace
+
+    from vllm_distributed_tpu.worker.worker import TPUWorker
+    cache = SimpleNamespace(num_state_slots=None)
+    fake = SimpleNamespace(
+        model_runner=SimpleNamespace(state_pool_slot_bytes=lambda: slot),
+        config=SimpleNamespace(
+            cache_config=cache, max_pages_per_req=pages_per_req,
+            scheduler_config=SimpleNamespace(max_num_seqs=seqs)))
+    TPUWorker._size_state_pool(fake, room, page)
+    assert cache.num_state_slots == want, name

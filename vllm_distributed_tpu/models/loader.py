@@ -225,6 +225,13 @@ def get_model(config: EngineConfig, mesh,
                 "tensor parallelism over gated delta-rule (KDA) layers "
                 "is not wired (the state kernels of ops/kda.py run "
                 "unsharded); set tensor_parallel_size=1")
+        if (getattr(arch, "cca", False)
+                and config.parallel_config.tensor_parallel_size > 1):
+            raise ValueError(
+                "tensor parallelism over compressed convolutional "
+                "attention (Zaya) is not wired (the carried rows and the "
+                "router's state are unsharded); set "
+                "tensor_parallel_size=1")
     if arch.pos_embedding == "learned":
         capacity = arch.max_position_embeddings - arch.pos_offset
         if config.scheduler_config.max_model_len > capacity:

@@ -365,6 +365,10 @@ class MixtralForCausalLM(LlamaForCausalLM):
             y = self._moe_ep_ragged(lp, xs, se, sw)
         else:
             y = self._expert_ffn(lp, xs, jnp.bincount(se, length=Pn))
+            if getattr(c, "moe_skip_pick", False):
+                # A pick past the last expert (models/zaya.py) sorts
+                # behind every group: its rows were in no GEMM.
+                y = jnp.where((se < Pn)[:, None], y, 0)
         # Un-sort + combine the k expert outputs per token.
         with jax.named_scope("moe_combine"):
             if not c.expert_parallel:   # the EP path weights its own rows

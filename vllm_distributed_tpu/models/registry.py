@@ -80,6 +80,7 @@ from vllm_distributed_tpu.models.moe_mixed import (Dots1ForCausalLM,
                                                    Glm4MoeForCausalLM)
 from vllm_distributed_tpu.models.mixtral import (MixtralForCausalLM,
                                                  Qwen2MoeForCausalLM)
+from vllm_distributed_tpu.models.zaya import ZayaForCausalLM
 
 _REGISTRY: dict[str, type] = {
     "LlamaForCausalLM": LlamaForCausalLM,
@@ -169,6 +170,9 @@ _REGISTRY: dict[str, type] = {
     # Granite 4.0-H: Bamba's stack with Granite's multipliers
     # (models/granite_hybrid.py).
     "GraniteMoeHybridForCausalLM": GraniteMoeHybridForCausalLM,
+    # ZAYA1: compressed convolutional attention on carried rows, a
+    # top-1 MLP router with a state from layer to layer (models/zaya.py).
+    "ZayaForCausalLM": ZayaForCausalLM,
     # Ling-3.0: gated delta-rule linear attention (KDA) beside latent
     # attention over DeepSeek-V3 experts (models/bailing.py; the name is
     # the family's convention, not the source's).

@@ -61,9 +61,27 @@ def rows_shape(layers: int, rows: int, taps: int, channels: int) -> tuple:
     layout turns inside out: K - 1 = 3 rows fill no tile, so it puts L
     (or S + 1, whichever pads less) on the sublanes, a layer then lies
     strewn over every tile, and a kernel that wants the rows as they
-    read gets a copy of the whole stack before and after."""
-    lanes = 128 if channels % 128 == 0 else math.gcd(channels, 8)
-    return (layers, rows, taps - 1, channels // lanes, lanes)
+    read gets a copy of the whole stack before and after. The same
+    happens to a count of tiles that is neither 2, 4 nor a multiple of
+    8 (``[16, 65, 1, 10, 128]`` comes in as ``{4,0,3,2,1}``, probed on a
+    described v5e, PR 41): such an input is cut into the most of 8, 4 or
+    2 wider tiles that divide it (10 tiles: two of 640 lanes), and a
+    caller whose count is odd widens it first (``whole_tiles``)."""
+    if channels % 128:
+        lanes = math.gcd(channels, 8)
+        return (layers, rows, taps - 1, channels // lanes, lanes)
+    tiles = channels // 128
+    if tiles % 8 and tiles not in (2, 4):
+        tiles = next(n for n in (4, 2, 1) if tiles % n == 0)
+    return (layers, rows, taps - 1, tiles, channels // tiles)
+
+
+def whole_tiles(channels: int) -> int:
+    """``channels`` as ``rows_shape`` can keep them in the layout
+    written: an odd count of 128-lane tiles past the first takes one
+    more (1 408 channels are carried as 1 536)."""
+    tiles, rest = divmod(channels, 128)
+    return channels + 128 if not rest and tiles > 1 and tiles % 2 else channels
 
 
 def _kernel(layer_ref, row_ref, tok_ref, tail_ref, code_ref, w_ref, y_ref,

@@ -236,9 +236,12 @@ class TPUWorker:
         does not fit: the pages that every schedulable request needs at
         full length come first (a snapshot resumes a request only
         beside its prefix's pages), then snapshot slots up to the
-        default, then the pages get the rest. Written to
-        ``cache_config.num_state_slots``, where the scheduler's manager
-        and the runner's pool both read it."""
+        default, then the pages get the rest. A default pool that costs
+        less than the pages of ONE full-length request is kept whole
+        whatever fits: cutting it buys no request (rows of 88 KB under
+        pages of 16 KB a token, models/zaya.py: 128 slots are 11 MB).
+        Written to ``cache_config.num_state_slots``, where the
+        scheduler's manager and the runner's pool both read it."""
         from vllm_distributed_tpu import envs
         slot_bytes = getattr(self.model_runner, "state_pool_slot_bytes",
                              lambda: 0)()
@@ -247,8 +250,10 @@ class TPUWorker:
         from vllm_distributed_tpu.core.state_cache import \
             default_state_slots
         want = default_state_slots(self.config)
-        reserve = (page_bytes * self.config.max_pages_per_req *
-                   self.config.scheduler_config.max_num_seqs)
+        one_request = page_bytes * self.config.max_pages_per_req
+        if want * slot_bytes <= one_request:
+            return
+        reserve = one_request * self.config.scheduler_config.max_num_seqs
         fit = max(room - reserve, 0) // slot_bytes
         if fit < want:
             # A dimension of the snapshot copies, and what the pages
