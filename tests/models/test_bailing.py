@@ -128,12 +128,13 @@ def worst_deviation(served, control=None) -> float:
     return worst
 
 
-def ragged_batch(seed: int = 0):
-    """Three sequences in one flat batch: a fresh prompt of 150 tokens,
-    a continued one of 70 (its row holds a state), one decode token;
-    4 heads of 16 x 16, 8 state rows."""
-    N, D, S = 4, 16, 8
-    lens, rows, inits = (150, 70, 1), (5, 2, 7), (False, True, True)
+def ragged_batch(seed: int = 0, lens=(150, 70, 1), rows=(5, 2, 7),
+                 inits=(False, True, True), S: int = 8, g_at=None):
+    """Sequences in one flat batch of 256 slots, by default three: a
+    fresh prompt of 150 tokens, a continued one of 70 (its row holds a
+    state), one decode token; 4 heads of 16 x 16, ``S`` state rows.
+    ``g_at`` sets every channel's log decay."""
+    N, D = 4, 16
     T = 256
     rng = np.random.default_rng(seed)
 
@@ -144,6 +145,8 @@ def ragged_batch(seed: int = 0):
     k = unit(rng.standard_normal((T, N, D)) + 0.5)
     v = rng.standard_normal((T, N, D))
     g = -5.0 / (1.0 + np.exp(-(rng.standard_normal((T, N, D)) - 2.0)))
+    if g_at is not None:
+        g = np.full_like(g, g_at)
     beta = 1.0 / (1.0 + np.exp(-rng.standard_normal((T, N))))
     state = rng.standard_normal((2, S + 1, N, D, D)) * 0.3
     row = np.full(T, S, np.int32)
@@ -373,6 +376,64 @@ def check_the_program_is_the_same_in_every_process(_monkeypatch=None):
         assert p.returncode == 0, p.stderr[-2000:]
         digests.add(p.stdout.split()[-1])
     assert len(digests) == 1
+
+
+# lens, rows, inits of a 256-slot bucket over 12 state rows: 4 chunks of
+# its own and MAX_CHUNK_SEGMENTS more.
+CHUNK_CASES = {
+    # Its second and third chunks find the row's stored state, decayed
+    # and corrected by the first, in the output block (off > 0).
+    "continues_a_stored_state_mid_segment":
+        dict(lens=(140, 1, 90), rows=(3, 6, 9), inits=(True, True, True)),
+    "last_chunk_shorter_than_a_sub_chunk":
+        dict(lens=(131, 67), rows=(1, 4), inits=(False, True)),
+    "trailing_chunks_all_padding":
+        dict(lens=(5, 1, 3), rows=(0, 11, 2), inits=(True, True, False)),
+    "decay_at_the_lower_bound_for_whole_chunks":
+        dict(lens=(150, 70), rows=(5, 2), inits=(False, True), g_at=-5.0),
+    "five_segments_over_the_buckets_chunks_go_token_by_token":
+        dict(lens=(2, ) * 9, rows=tuple(range(9)),
+             inits=(True, False) * 4 + (True, )),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_chunked_form_case(case):
+    """The chunk kernel at its edges, against the token-by-token scan:
+    outputs and final states within ``check_forms_agree``'s tolerance,
+    padding slots zero, the dump row finite, idle rows and the other
+    layer bit for bit as they were."""
+    S = 12
+    spec = CHUNK_CASES[case]
+    arrays, seg, segments = ragged_batch(3, S=S, **spec)
+    fits = (sum(cdiv(n, kda.CHUNK) for n in spec["lens"] if n > 1)
+            <= cdiv(arrays[0].shape[0], kda.CHUNK) + kda.MAX_CHUNK_SEGMENTS)
+    layer = jnp.asarray([1], jnp.int32)
+    idle = sorted(set(range(S)) - set(spec["rows"]))
+    got = {}
+    with jax.default_matmul_precision("highest"):
+        want_o, want_state = reference_scan(arrays, segments, 1)
+        n = want_o.shape[0]
+        for form in ("chunk", "recurrent", "auto"):
+            o, state = kda.kda_ragged(*arrays, layer, seg, prefill_form=form)
+            got[form] = np.asarray(o)
+            assert np.isfinite(np.asarray(state)).all(), form
+            np.testing.assert_array_equal(state[0], arrays[5][0])
+            np.testing.assert_array_equal(state[1, idle], arrays[5][1, idle])
+            assert not np.asarray(o[n:]).any()
+            if form == "chunk" and not fits:
+                continue        # pinned past its chunk count: not served
+            np.testing.assert_allclose(o[:n], want_o, atol=2e-5, err_msg=form)
+            np.testing.assert_allclose(
+                state[1, list(spec["rows"])],
+                want_state[list(spec["rows"])], atol=2e-5, err_msg=form)
+    # What the program picked: the chunked form where the segments fit
+    # its static chunk count, else the recurrent kernel.
+    np.testing.assert_array_equal(got["auto"],
+                                  got["chunk" if fits else "recurrent"])
+    assert fits == (case != list(CHUNK_CASES)[-1])
+    if not fits:
+        assert np.abs(got["chunk"][:n] - np.asarray(want_o)).max() > 1e-3
 
 
 PROPERTIES = {

@@ -439,7 +439,10 @@ def test_gated_delta_kernels_compile_at_published_widths(
     128 float32 state, 96 state rows and the dump row, six layers in one
     buffer; a decode batch (one token a row: ``kda_recurrent``), and a
     2 048-token chunk bucket in the chunked form (``kda_chunk``), token
-    by token, and as the program picks between them at run time."""
+    by token, and as the program picks between them at run time. What a
+    chunk computes without its incoming state is formed inside
+    ``kda_chunk`` (PR 42): the program holds none of the arrays over 38
+    chunks that plain XLA made beside it."""
     from benchmarks.rooflines.gated_delta_state import kda_layers
     from vllm_distributed_tpu.ops import kda
     from vllm_distributed_tpu.ops.mamba import SegmentInfo
@@ -472,6 +475,10 @@ def test_gated_delta_kernels_compile_at_published_widths(
         sds((layers, S + 1, N, D, D), f32), sds((1, )), *leaves).as_text()
     assert f"%{kda.KERNEL_RECURRENT}" in text
     assert (f"%{kda.KERNEL_CHUNK}" in text) == (form in ("chunk", "auto"))
+    chunks = cdiv(T, kda.CHUNK) + kda.MAX_CHUNK_SEGMENTS
+    assert chunks == 38 or form == "decode"
+    for local in ("16,16", "64,64", "64,256", "64,128", "128,64"):
+        assert f"f32[{chunks},{N},{local}]" not in text, local
 
 
 def segment_shapes(T: int, S: int):

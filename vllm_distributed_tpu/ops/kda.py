@@ -21,23 +21,30 @@ sliced out or copied back:
   the state read once and written once, which is the whole cost. It is
   exact for any mix of segments, so it is also the fallback of the
   chunked form.
-* ``kda_chunk``: the chunked WY form for prefill. Per chunk of ``CHUNK``
-  tokens the products that do not depend on the incoming state (decayed
-  key-key and query-key matrices, the unit-triangular solve that turns
-  values into pseudo-values) are plain XLA, batched over chunks; ratios
-  of cumulative decays are formed inside sub-chunks of ``SUB`` tokens
-  only, where ``kda_lower_bound`` keeps them inside float32 (16 tokens
-  x -5 = e^-80), and across sub-chunks every factor is a decay <= 1.
-  The kernel then walks the chunks in order with the state in VMEM:
-  ``W = U - Wk S``, ``o = Qg S + Aq W``, ``S <- Diag(d) S + Kd^T W``.
-  No state per token is ever materialised.
+* ``kda_chunk``: the chunked WY form for prefill, a chunk of ``CHUNK``
+  tokens a grid step. The kernel reads the chunk's q, k, v and log-decay
+  rows where they lie in the flat batch (a ``CHUNK``-row window from the
+  chunk's first token on; rows of the window that are not the chunk's
+  are masked inert) and forms in VMEM what does not depend on the
+  incoming state: the cumulative log decay, the decayed key-key and
+  query-key matrices, and ``T = (I + Diag(b) A)^-1``, the unit-triangular
+  solve that turns values into pseudo-values. Ratios of cumulative
+  decays are formed inside sub-chunks of ``SUB`` tokens only, about the
+  sub-chunk's middle token, where ``kda_lower_bound`` keeps both factors
+  well inside float32 (8 tokens x -5 = e^-40 and e^40); across
+  sub-chunks every factor is a decay <= 1. Then, with the state in VMEM
+  from chunk to chunk: ``W = T b (V - K+ S)``, ``o = Qg S + Aq W``,
+  ``S <- Diag(d) S + Kd^T W``. Two heads go through the chunk's
+  matrices at once (block-diagonal, 128 rows: the MXU's tile). Float32
+  throughout, every product at ``Precision.HIGHEST``. No state per
+  token and nothing a chunk computes is ever an array in HBM.
 
 A step's segments are split by kind: one-token segments (decodes) run
-the recurrent kernel over the state rows; longer ones are laid out
-chunk-aligned (each starts on a chunk boundary, zero padding is inert:
-``b = 0, g = 0``) and run the chunked form when they fit its static
-chunk count (``MAX_CHUNK_SEGMENTS`` segments more than the bucket's own
-chunks), else the recurrent kernel token by token.
+the recurrent kernel over the state rows; longer ones are cut into
+chunks (each starts a chunk of its own; a last chunk may be short) and
+run the chunked form when they fit its static chunk count
+(``MAX_CHUNK_SEGMENTS`` segments more than the bucket's own chunks),
+else the recurrent kernel token by token.
 """
 
 from __future__ import annotations
@@ -60,6 +67,8 @@ SUB = 16
 # and still run the chunked form (each may add one partial chunk).
 MAX_CHUNK_SEGMENTS = 4
 _HEAD_BLOCK = 8
+# Heads whose chunk matrices share one product: 2 x CHUNK fills a tile.
+_HEADS_AT_ONCE = 2
 _HI = jax.lax.Precision.HIGHEST
 _VMEM_LIMIT = 48 * 1024 * 1024
 
@@ -158,113 +167,192 @@ def _recurrent_call(state, layer, rows, flags, q, k, v, a, beta,
 # ---------------------------------------------------------------------
 # kda_chunk: the chunked form
 # ---------------------------------------------------------------------
-def _sub_inverse(n_diag):
-    """(I + N)^-1 of strictly lower ``[..., SUB, SUB]`` blocks, N
-    nilpotent: (I - N)(I + N^2)(I + N^4)(I + N^8)."""
-    eye = jnp.eye(SUB, dtype=n_diag.dtype)
-    x = -n_diag
-    t = eye + x
-    for _ in range(SUB.bit_length() - 2):
-        x = jnp.einsum("...ij,...jk->...ik", x, x, precision=_HI)
-        t = t + jnp.einsum("...ij,...jk->...ik", t, x, precision=_HI)
-    return t
+def _dot(x, y):
+    return jnp.dot(x, y, preferred_element_type=jnp.float32, precision=_HI)
 
 
-def chunk_local(q, k, v, g, beta):
-    """What a chunk can compute without its incoming state. Inputs
-    ``[NC, CHUNK, N, D]`` float32 (beta ``[NC, CHUNK, N]``), g the log
-    decay (<= 0). Returns head-major ``[NC, N, ...]`` arrays:
-    ``u`` ``[C, Dv]`` and ``wk`` ``[C, Dk]`` with pseudo-values
-    ``W = u - wk S0``; ``qg`` ``[C, Dk]`` and ``aq`` ``[C, C]`` with
-    ``o = qg S0 + aq W``; ``kd_t`` ``[Dk, C]`` and ``dec`` ``[Dk]`` with
-    ``S1 = Diag(dec) S0 + kd_t W``."""
-    NC, C, N, Dk = k.shape
-    n_sub = C // SUB
+def _dot_nt(x, y):
+    """``x y^T``: both contract their lanes."""
+    return jax.lax.dot_general(x, y, (((1, ), (1, )), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=_HI)
 
-    def sub(x):
-        return x.reshape(NC, n_sub, SUB, *x.shape[2:])
 
-    q, k, g = sub(q), sub(k), sub(g)
-    gl = jnp.cumsum(g, axis=2)                     # inside the sub-chunk
-    z = gl[:, :, -1]                               # [NC, n_sub, N, Dk]
-    before = jnp.cumsum(z, axis=1) - z             # at the sub-chunk's start
-    total = before[:, -1] + z[:, -1]               # [NC, N, Dk]
-    kp, km = k * jnp.exp(gl), k * jnp.exp(-gl)
-    qp = q * jnp.exp(gl)
-    ke = k * jnp.exp(z[:, :, None] - gl)           # to the sub-chunk's end
+def _chunk_heads(q, k, v, g, b, states):
+    """One chunk of ``len(states)`` heads at once, their ``CHUNK`` tokens
+    stacked head after head (the matrices over a chunk's tokens are then
+    block-diagonal over the heads and fill the MXU's tile): q, k, g
+    ``[R, Dk]``, v ``[R, Dv]``, b ``[R, 1]``, each head's incoming state
+    ``[Dk, Dv]``, all float32. Returns the outputs ``[R, Dv]`` and each
+    head's state after the chunk."""
+    R, Dk = k.shape
+    C = CHUNK
+    n_sub, per_head = R // SUB, C // SUB
 
-    def pairs(x, y):                               # [.., s, N, D] x [.., r, N, D]
-        return jnp.einsum("...snd,...rnd->...nsr", x, y, precision=_HI)
+    def rows(i, n=SUB):
+        return slice(i * n, (i + 1) * n)
 
-    zero = jnp.zeros((NC, N, SUB, SUB), jnp.float32)
-    a_rows, aq_rows = [], []
+    def stack(parts):
+        return jnp.concatenate(parts, axis=0)
+
+    def spread(per_sub):             # n_sub rows [1, Dk] -> [R, Dk]
+        return stack([jnp.broadcast_to(r, (SUB, Dk)) for r in per_sub])
+
+    # The cumulative log decay inside each sub-chunk, by doubling.
+    at = jax.lax.broadcasted_iota(jnp.int32, (R, Dk), 0) % SUB
+    gl, step = g, 1
+    while step < SUB:
+        gl = gl + jnp.where(at >= step, pltpu.roll(gl, step, 0), 0.0)
+        step *= 2
+    z = [gl[(i + 1) * SUB - 1:(i + 1) * SUB] for i in range(n_sub)]
+    before = []                              # at the sub-chunk's start
     for i in range(n_sub):
-        a_row, aq_row = [], []
-        for j in range(n_sub):
-            if j > i:
-                a_row.append(zero)
-                aq_row.append(zero)
-            elif j == i:
-                a_row.append(pairs(kp[:, i], km[:, i]))
-                aq_row.append(pairs(qp[:, i], km[:, i]))
-            else:
-                # From the end of sub-chunk j to the start of i: a decay.
-                mid = jnp.exp(before[:, i] - before[:, j] - z[:, j])
-                a_row.append(pairs(kp[:, i] * mid[:, None], ke[:, j]))
-                aq_row.append(pairs(qp[:, i] * mid[:, None], ke[:, j]))
-        a_rows.append(jnp.concatenate(a_row, axis=-1))
-        aq_rows.append(jnp.concatenate(aq_row, axis=-1))
-    s_idx = jnp.arange(C)
-    a = jnp.where(s_idx[:, None] > s_idx[None, :],
-                  jnp.concatenate(a_rows, axis=-2), 0.0)     # [NC, N, C, C]
-    aq = jnp.where(s_idx[:, None] >= s_idx[None, :],
-                   jnp.concatenate(aq_rows, axis=-2), 0.0)
+        before.append(before[-1] + z[i - 1] if i % per_head
+                      else jnp.zeros_like(z[0]))
+    total = [before[i] + z[i]                # a head's whole chunk
+             for i in range(per_head - 1, n_sub, per_head)]
+    # Ratios inside a sub-chunk are taken about its middle token: both
+    # factors then stay within e^(+-SUB / 2 x the lower bound) and a
+    # product that matters never comes from a denormal.
+    centre = [gl[i * SUB + SUB // 2 - 1:i * SUB + SUB // 2]
+              for i in range(n_sub)]
+    grow = jnp.exp(gl - spread(centre))
+    kp, qp = k * grow, q * grow
+    km = k * jnp.exp(spread(centre) - gl)
+    ke = k * jnp.exp(spread(z) - gl)         # to the sub-chunk's end
+    g_all = gl + spread(before)
+    reach = jnp.exp(g_all)
+    k_plus, qg = k * reach, q * reach
+    kd = k * jnp.exp(spread([t for t in total for _ in range(per_head)])
+                     - g_all)
 
-    b = jnp.swapaxes(beta, 1, 2)                    # [NC, N, C]
-    n_mat = a * b[..., None]
-    g_all = (gl + before[:, :, None]).reshape(NC, C, N, Dk)
-    k_all, q_all = k.reshape(NC, C, N, Dk), q.reshape(NC, C, N, Dk)
-    k_plus = k_all * jnp.exp(g_all)
-    rhs = jnp.concatenate([v, k_plus], axis=-1)     # [NC, C, N, Dv + Dk]
-    rhs = jnp.swapaxes(rhs, 1, 2) * b[..., None]    # [NC, N, C, Dv + Dk]
-    # (I + N) X = rhs by block forward substitution over sub-chunks.
-    blocks = n_mat.reshape(NC, N, n_sub, SUB, n_sub, SUB)
-    solved = []
-    for i in range(n_sub):
-        r = rhs[:, :, i * SUB:(i + 1) * SUB]
-        for j in range(i):
-            r = r - jnp.einsum("...sr,...rd->...sd", blocks[:, :, i, :, j],
-                               solved[j], precision=_HI)
-        inv = _sub_inverse(blocks[:, :, i, :, i])
-        solved.append(jnp.einsum("...sr,...rd->...sd", inv, r,
-                                 precision=_HI))
-    x = jnp.concatenate(solved, axis=2)
-    Dv = v.shape[-1]
-    kd = k_all * jnp.exp(total[:, None] - g_all)
-    return dict(
-        u=x[..., :Dv], wk=x[..., Dv:],
-        qg=jnp.swapaxes(q_all * jnp.exp(g_all), 1, 2), aq=aq,
-        kd_t=jnp.transpose(kd, (0, 2, 3, 1)), dec=jnp.exp(total))
+    # Sub-chunk i of every head against the head's keys in one product:
+    # inside the sub-chunk a ratio of its own cumulative decays, against
+    # an earlier sub-chunk j the decay from j's end to i's middle token
+    # (z[j + 1] + ... + z[i - 1] + centre[i], a sum of log decays) times
+    # ke; later sub-chunks hold zero, and so does, once masked, whatever
+    # a head's rows make of another head's keys.
+    a_rows, aq_rows = [[] for _ in states], [[] for _ in states]
+    for i in range(per_head):
+        lhs, rhs = [], []
+        for h in range(len(states)):
+            at_i = h * per_head + i
+            lhs += [kp[rows(at_i)], qp[rows(at_i)]]
+            for j in range(h * per_head, at_i):
+                mid = centre[at_i]
+                for m in range(j + 1, at_i):
+                    mid = mid + z[m]
+                rhs.append(ke[rows(j)] * jnp.exp(mid))
+            rhs.append(km[rows(at_i)])
+            if i + 1 < per_head:
+                rhs.append(jnp.zeros(((per_head - 1 - i) * SUB, Dk),
+                                     jnp.float32))
+        both = _dot_nt(stack(lhs), stack(rhs))          # [heads x 2 SUB, R]
+        for h in range(len(states)):
+            a_rows[h].append(both[rows(2 * h)])
+            aq_rows[h].append(both[rows(2 * h + 1)])
+    r_idx = jax.lax.broadcasted_iota(jnp.int32, (R, R), 0)
+    c_idx = jax.lax.broadcasted_iota(jnp.int32, (R, R), 1)
+    own = r_idx // C == c_idx // C
+    n_mat = b * jnp.where(own & (r_idx > c_idx),
+                          stack(sum(a_rows, [])), 0.0)
+    aq = jnp.where(own & (r_idx >= c_idx), stack(sum(aq_rows, [])), 0.0)
+
+    # T = (I + N)^-1, N strictly lower. The MXU's time goes by the rows
+    # it is fed, so the blocks of a block-diagonal matrix go through
+    # side by side, [width, R], against blocks laid out in the square.
+    def block_of_column(width):
+        return jax.lax.broadcasted_iota(jnp.int32, (width, R), 1) // width
+
+    def side_by_side(m, width, down):
+        """Under block column c, block (c + down, c) of the square m."""
+        column = block_of_column(width)
+        out = jnp.zeros((width, R), jnp.float32)
+        for c in range(R // width - down):
+            out = jnp.where(column == c, m[rows(c + down, width)], out)
+        return out
+
+    def squared(side, width, down=0):
+        """``side_by_side``'s inverse, zero elsewhere."""
+        return jnp.where(r_idx // width == c_idx // width + down,
+                         stack([side] * (R // width)), 0.0)
+
+    # The diagonal SUB-blocks are nilpotent:
+    # (I - N)(I + N^2)(I + N^4)(I + N^8).
+    x = side_by_side(-n_mat, SUB, 0)
+    t = x + (jax.lax.broadcasted_iota(jnp.int32, (SUB, R), 0)
+             == jax.lax.broadcasted_iota(jnp.int32, (SUB, R), 1) % SUB
+             ).astype(jnp.float32)
+    x = _dot(x, squared(x, SUB))
+    for _ in range(SUB.bit_length() - 3):
+        both = _dot(stack([x, t]), squared(x, SUB))
+        x, t = both[:SUB], t + both[SUB:]
+    t = t + _dot(t, squared(x, SUB))
+    # Then pairs of blocks, doubling: [[A, 0], [B, D]]^-1 =
+    # [[A^-1, 0], [-D^-1 B A^-1, D^-1]].
+    width = SUB
+    while width < C:
+        first = block_of_column(width) % 2 == 0          # A's columns
+        b_a = _dot(jnp.where(first, side_by_side(n_mat, width, 1), 0.0),
+                   squared(t, width))
+        d_b_a = _dot(jnp.where(first, 0.0, t), squared(b_a, width, down=1))
+        t = stack([jnp.where(first, t, 0.0), jnp.where(first, -d_b_a, t)])
+        width *= 2
+    t = squared(t, C)
+
+    # What the incoming states add.
+    ks = [_dot(stack([k_plus[rows(h, C)], qg[rows(h, C)]]), s)
+          for h, s in enumerate(states)]                      # [2C, Dv]
+    w = _dot(t, b * (v - stack([x[:C] for x in ks])))         # pseudo-values
+    o = stack([x[C:] for x in ks]) + _dot(aq, w)
+    after = []
+    for h, s in enumerate(states):
+        # kd^T and the chunk's whole decay as a column, by one transpose.
+        turned = stack([kd[rows(h, C)],
+                        jnp.broadcast_to(jnp.exp(total[h]), (C, Dk))]).T
+        w_h = w[rows(h, C)]
+        after.append(turned[:, C:C + 1] * s
+                     + _dot(turned, stack([w_h, jnp.zeros_like(w_h)])))
+    return o, after
 
 
-def _chunk_kernel(layer_ref, row_ref, flag_ref, u_ref, wk_ref, qg_ref,
-                  aq_ref, kd_ref, dec_ref, s_ref, o_ref, so_ref):
+def _chunk_kernel(layer_ref, row_ref, flag_ref, win_ref, q_ref, k_ref, v_ref,
+                  g_ref, b_ref, s_ref, o_ref, so_ref):
     del layer_ref, row_ref
-    valid = _enter_row(flag_ref[pl.program_id(1)], s_ref, so_ref)
-    hb = u_ref.shape[1]
+    n = pl.program_id(1)
+    valid = _enter_row(flag_ref[n], s_ref, so_ref)
+    C, hb, Dk = k_ref.shape
+    # The chunk's tokens are rows [lo, hi) of the window; whatever the
+    # flat batch holds around them is inert (zero, b = 0, g = 0).
+    at = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    live = (at >= win_ref[1, n]) & (at < win_ref[2, n])
 
-    def dot(x, y):
-        return jnp.dot(x, y, preferred_element_type=jnp.float32,
-                       precision=_HI)
+    group = _HEADS_AT_ONCE if hb % _HEADS_AT_ONCE == 0 else 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (C, hb), 1)
+
+    def rows(ref, heads):
+        return jnp.concatenate(
+            [jnp.where(live, ref[:, h, :], 0.0) for h in heads], axis=0)
+
+    def some_heads(i, carry):
+        # One body for every group of the block: it is traced and
+        # lowered once, and the program's text holds it once.
+        heads = [group * i + j for j in range(group)]
+        beta = b_ref[0]
+        o, after = _chunk_heads(
+            rows(q_ref, heads), rows(k_ref, heads), rows(v_ref, heads),
+            rows(g_ref, heads),
+            jnp.concatenate([jnp.sum(jnp.where(lane == h, beta, 0.0), axis=1,
+                                     keepdims=True) for h in heads], axis=0),
+            [so_ref[0, 0, h] for h in heads])
+        for j, h in enumerate(heads):
+            o_ref[:, h, :] = o[j * C:(j + 1) * C]
+            so_ref[0, 0, h] = after[j]
+        return carry
 
     @pl.when(valid)
     def _():
-        for h in range(hb):
-            s = so_ref[0, 0, h]                                 # [Dk, Dv]
-            w = u_ref[0, h] - dot(wk_ref[0, h], s)
-            o_ref[0, h] = dot(qg_ref[0, h], s) + dot(aq_ref[0, h], w)
-            so_ref[0, 0, h] = (dec_ref[0, 0, :, h:h + 1] * s
-                               + dot(kd_ref[0, h], w))
+        jax.lax.fori_loop(0, hb // group, some_heads, 0)
 
     @pl.when(jnp.logical_not(valid))
     def _():
@@ -272,45 +360,50 @@ def _chunk_kernel(layer_ref, row_ref, flag_ref, u_ref, wk_ref, qg_ref,
         so_ref[...] = jnp.zeros_like(so_ref)
 
 
-def _chunk_call(state, layer, rows, flags, local, interpret: bool):
-    """``NC`` chunks in order, chunk n of state row ``rows[n]``."""
-    NC, N, C, Dv = local["u"].shape
-    Dk = local["wk"].shape[-1]
+def _chunk_call(state, layer, rows, flags, windows, q, k, v, g, beta,
+                interpret: bool):
+    """``NC`` chunks in order, chunk n of state row ``rows[n]``: its
+    tokens are rows ``lo ... hi`` of the ``CHUNK`` rows of the flat q, k,
+    g ``[T, N, Dk]`` and v ``[T, N, Dv]`` from ``start`` on, ``windows``
+    ``[3, NC]`` holding start, lo and hi; ``beta`` ``[NC * CHUNK, N]``
+    comes window by window, zero outside the tokens. Returns (o ``[NC *
+    CHUNK, N, Dv]`` window by window, state)."""
+    _, _, N, Dk, Dv = state.shape
+    P = beta.shape[0]
     hb = min(_HEAD_BLOCK, N)
-    assert N % hb == 0
-    # Decays as columns: [NC, N // hb, Dk, hb], a head is a lane.
-    dec = jnp.transpose(local["dec"].reshape(NC, N // hb, hb, Dk),
-                        (0, 1, 3, 2))
+    assert N % hb == 0 and P % CHUNK == 0
+    # A head block's beta: [N // hb, P, hb], a token is a sublane.
+    beta = jnp.swapaxes(beta.reshape(P, N // hb, hb), 0, 1)
 
-    def per_chunk(*tail):
-        return pl.BlockSpec((1, hb) + tail,
-                            lambda j, n, *_: (n, j) + (0, ) * len(tail))
+    def window(width):
+        # Rows from any token on: the offsets are elements, not blocks.
+        return pl.BlockSpec(
+            (pl.Element(CHUNK), pl.Element(hb), pl.Element(width)),
+            lambda j, n, layer, rows, flags, win: (win[0, n], j * hb, 0))
 
     row = pl.BlockSpec((1, 1, hb, Dk, Dv),
-                       lambda j, n, layer, rows, flags:
+                       lambda j, n, layer, rows, flags, win:
                        (layer[0], rows[n], j, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=(N // hb, NC),
-        in_specs=[per_chunk(C, Dv), per_chunk(C, Dk), per_chunk(C, Dk),
-                  per_chunk(C, C), per_chunk(Dk, C),
-                  pl.BlockSpec((1, 1, Dk, hb),
-                               lambda j, n, *_: (n, j, 0, 0)),
+        num_scalar_prefetch=4, grid=(N // hb, P // CHUNK),
+        in_specs=[window(Dk), window(Dk), window(Dv), window(Dk),
+                  pl.BlockSpec((1, CHUNK, hb), lambda j, n, *_: (j, n, 0)),
                   row],
-        out_specs=(per_chunk(C, Dv), row))
+        out_specs=(pl.BlockSpec((CHUNK, hb, Dv), lambda j, n, *_: (n, j, 0)),
+                   row))
     o, state = pl.pallas_call(
         _chunk_kernel,
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((NC, N, C, Dv), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct((P, N, Dv), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)),
-        # 3 scalar-prefetch operands, then u, wk, qg, aq, kd_t, dec, state.
+        # 4 scalar-prefetch operands, then q, k, v, g, beta, state.
         input_output_aliases={9: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name=KERNEL_CHUNK,
-    )(layer, rows, flags, local["u"], local["wk"], local["qg"],
-      local["aq"], local["kd_t"], dec, state)
+    )(layer, rows, flags, windows, q, k, v, g, beta, state)
     return o, state
 
 
@@ -345,31 +438,40 @@ def _token_part(state, layer, q, k, v, a, beta, seg: SegmentInfo, multi,
 
 
 def _chunk_part(state, layer, q, k, v, g, beta, seg: SegmentInfo, multi,
-                chunk_base, num_chunks: int, interpret: bool):
-    """Multi-token segments in the chunk-aligned layout."""
+                chunk_base, chunks, num_chunks: int, interpret: bool):
+    """Multi-token segments chunk by chunk: a row's segment is chunks
+    ``chunk_base ... chunk_base + chunks`` of the step, and the kernel
+    reads each chunk's tokens where they lie in the flat batch."""
     T = q.shape[0]
+    if T < CHUNK:                # a window is CHUNK rows of the batch
+        q, k, v, g, beta = (jnp.pad(x, ((0, CHUNK - T), ) + ((0, 0), )
+                                    * (x.ndim - 1))
+                            for x in (q, k, v, g, beta))
     S = seg.q_len_by_row.shape[0] - 1
-    P = num_chunks * CHUNK
-    pos = jnp.where(multi, chunk_base[seg.row] * CHUNK + seg.off, P)
-
-    def lay(x):
-        out = jnp.zeros((P, ) + x.shape[1:], x.dtype)
-        return out.at[pos].set(x, mode="drop").reshape(
-            (num_chunks, CHUNK) + x.shape[1:])
-
-    local = chunk_local(lay(q), lay(k), lay(v), lay(g), lay(beta))
-    # A chunk's first slot is a token whenever the chunk holds any.
-    head = jnp.full((P, ), -1, jnp.int32).at[pos].set(
-        jnp.arange(T, dtype=jnp.int32), mode="drop")[::CHUNK]
-    used = head >= 0
-    first_tok = jnp.maximum(head, 0)
+    n = jnp.arange(num_chunks, dtype=jnp.int32)
+    holds = ((chunk_base[None, :] <= n[:, None])
+             & (n[:, None] < (chunk_base + chunks)[None, :]))
+    used = holds.any(axis=1)
+    row = jnp.where(used, jnp.argmax(holds, axis=1).astype(jnp.int32), S)
+    done = (n - chunk_base[row]) * CHUNK         # of the segment, before n
+    first = seg.q_start_by_row[row] + done
+    count = jnp.where(used, jnp.minimum(seg.q_len_by_row[row] - done, CHUNK),
+                      0)
+    start = jnp.clip(first, 0, q.shape[0] - CHUNK)
+    lo = jnp.where(used, first - start, 0)
+    at = jnp.arange(CHUNK, dtype=jnp.int32)[None, :]
+    beta = jnp.where(((at >= lo[:, None]) & (at < (lo + count)[:, None])
+                      )[:, :, None], beta[start[:, None] + at], 0.0)
     o, state = _chunk_call(
-        state, layer, jnp.where(used, seg.row[first_tok], S),
-        _flags(used, seg.has_init[first_tok], seg.off[first_tok] > 0),
-        local, interpret)
-    o = jnp.swapaxes(o, 1, 2).reshape(P, o.shape[1], o.shape[-1])
+        state, layer, row,
+        _flags(used, seg.has_init_by_row[row], done > 0),
+        jnp.stack([start, lo, lo + count]), q, k, v, g,
+        beta.reshape(num_chunks * CHUNK, -1), interpret)
+    # Token t's output row: its chunk's window, lo rows in.
+    chunk = jnp.where(multi, chunk_base[seg.row] + seg.off // CHUNK, 0)
     return jnp.where(multi[:, None, None],
-                     o[jnp.minimum(pos, P - 1)], 0.0), state
+                     o[chunk * CHUNK + lo[chunk] + seg.off % CHUNK],
+                     0.0), state
 
 
 @functools.partial(jax.jit, static_argnames=("decode_only", "prefill_form",
@@ -414,7 +516,7 @@ def kda_ragged(q, k, v, g, beta, state, layer, seg: SegmentInfo, *,
 
     def chunked(state):
         return _chunk_part(state, layer, q, k, v, g, beta, seg, multi,
-                           chunk_base, num_chunks, interpret)
+                           chunk_base, chunks, num_chunks, interpret)
 
     def by_token(state):
         return _token_part(state, layer, q, k, v, a, beta, seg, multi,
