@@ -632,7 +632,8 @@ def test_two_64_lane_kv_heads_share_a_page_row(compile_for_chip,
 # ---------------------------------------------------------------------
 # The benchmark configurations' own programs, weights as shapes
 # ---------------------------------------------------------------------
-SPARSE = ("olmoe-1b-7b", "gigachat3-702b-ep16", "ling3-flash-ep4")
+SPARSE = ("olmoe-1b-7b", "gigachat3-702b-ep16", "ling3-flash-ep4",
+          "command-a-plus-ep8")
 # Depth cut to one layer of each kind a configuration has: widths,
 # heads, experts and engine limits stay the file's.
 ONE_LAYER = {
@@ -651,6 +652,11 @@ ONE_LAYER = {
     # Every layer is of one kind; two, so that the scan and the expert
     # switch are there.
     "zaya1-8b": {"num_hidden_layers": 2, "layer_types": ["hybrid"] * 2},
+    # A window layer and a full layer: both cache groups, both tables,
+    # the trimmed and the untrimmed kernel.
+    "command-a-plus-ep8": {
+        "num_hidden_layers": 2,
+        "layer_types": ["sliding_attention", "full_attention"]},
 }
 
 

@@ -11,9 +11,9 @@ from dataclasses import dataclass
 from typing import Optional
 
 from vllm_distributed_tpu.core.block_pool import BlockPool, KVCacheBlock
-from vllm_distributed_tpu.core.kv_cache_utils import (BlockHash,
-                                                      hash_block_tokens,
-                                                      hash_request_tokens)
+from vllm_distributed_tpu.core.kv_cache_utils import (
+    BlockHash, hash_block_tokens, hash_request_tokens,
+    num_window_dead_blocks)
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.request import Request
 from vllm_distributed_tpu.utils import cdiv
@@ -27,9 +27,17 @@ class KVCacheBlocks:
     worker for this request."""
 
     blocks: list[KVCacheBlock]
+    # The window group's new pages, slot for slot beside ``blocks``
+    # (GroupedKVCacheManager); None for a model with one group.
+    window_blocks: Optional[list[KVCacheBlock]] = None
 
     def get_block_ids(self) -> list[int]:
         return [b.block_id for b in self.blocks]
+
+    def get_window_block_ids(self) -> Optional[list[int]]:
+        if self.window_blocks is None:
+            return None
+        return [b.block_id for b in self.window_blocks]
 
     def __add__(self, other: "KVCacheBlocks") -> "KVCacheBlocks":
         return KVCacheBlocks(self.blocks + other.blocks)
@@ -59,14 +67,19 @@ class KVCacheManager:
             self.block_pool.on_evict = tier.note_evicted
         # Sliding-window page freeing (reference: the SlidingWindowManager
         # of v1/core/single_type_kv_cache_manager.py:444 replacing
-        # out-of-window blocks with the null block): when EVERY attention
-        # layer is windowed, pages whose last position can never again
-        # fall inside any future query's window are freed mid-request and
-        # their req_to_blocks slot nulled. The attention mask already
-        # excludes those positions, so a stale (possibly reused) page id
-        # in the block table is never read into a live score. None =
-        # some layer needs full history; no mid-request freeing.
+        # out-of-window blocks with the null block): when EVERY layer
+        # this manager's pages serve is windowed, pages whose last
+        # position can never again fall inside any future query's window
+        # are freed mid-request and their req_to_blocks slot nulled. The
+        # attention mask already excludes those positions, so a stale
+        # (possibly reused) page id in the block table is never read
+        # into a live score. None = some layer needs full history; no
+        # mid-request freeing. A model that mixes window and full layers
+        # gets one manager of each kind (GroupedKVCacheManager).
         self.free_window = free_window
+        # Pages handed back behind the window, over the manager's life
+        # (vdt:kv_window_pages_freed_total).
+        self.window_pages_freed = 0
         # req_id -> count of leading slots already window-freed (loop
         # resume point; dropped with the request's block list).
         self._num_window_freed: dict[str, int] = {}
@@ -240,13 +253,16 @@ class KVCacheManager:
 
     def _free_out_of_window(self, request: Request) -> None:
         """Null + free every block whose last position precedes
-        num_computed_tokens - window (no future query can attend it;
-        the window mask in ops/attention guarantees it is never read)."""
+        num_computed_tokens - window + 1 (``num_window_dead_blocks``: no
+        future query can attend it). The mask in ops/attention keeps
+        such a position out of every score, the mega-kernel's trimmed
+        page loop (ops/pallas_attention.py) does not walk that far
+        back, and the runner nulls the same slots in the table it ships
+        by the same rule, so the kernel never gets a freed page's id."""
         if self.free_window is None:
             return
-        num_dead = max(
-            0, request.num_computed_tokens - self.free_window + 1
-        ) // self.block_size
+        num_dead = int(num_window_dead_blocks(
+            request.num_computed_tokens, self.free_window, self.block_size))
         if num_dead <= 0:
             return
         blocks = self.req_to_blocks.get(request.request_id)
@@ -264,7 +280,22 @@ class KVCacheManager:
         if end > start:
             self._num_window_freed[request.request_id] = end
         if dead:
+            self.window_pages_freed += len(dead)
             self.block_pool.free_blocks(dead)
+
+    def _release_tail(self, request_id: str,
+                      blocks: list[KVCacheBlock]) -> None:
+        """Take back the pages an ``allocate_slots`` just appended (the
+        other group of a GroupedKVCacheManager could not follow)."""
+        if blocks:
+            del self.req_to_blocks[request_id][-len(blocks):]
+            self.block_pool.free_blocks(list(reversed(blocks)))
+
+    def num_held_blocks(self) -> int:
+        """Pages that requests hold now (freed slots excepted). Runs on
+        a scrape's thread too: containers are snapshotted first."""
+        return sum(1 for blocks in list(self.req_to_blocks.values())
+                   for b in list(blocks) if b is not None)
 
     # ------------------------------------------------------------------
     def free(self, request: Request) -> None:
@@ -323,14 +354,143 @@ class KVCacheManager:
         allocates/frees — every container is list()-snapshotted
         (GIL-atomic) before Python-level iteration."""
         stats = dict(self.block_pool.get_stats())
-        held = 0
-        for blocks in list(self.req_to_blocks.values()):
-            held += sum(1 for b in list(blocks) if b is not None)
-        stats["held_blocks"] = held
+        stats["held_blocks"] = self.num_held_blocks()
         recent = list(self._recent_queries)
         stats["window_queries"] = len(recent)
         stats["window_hits"] = sum(recent)
         return stats
+
+
+def _summed_telemetry(managers) -> dict:
+    """The managers' ``kv_telemetry`` counts, key by key."""
+    merged: dict = {}
+    for m in managers:
+        for k, v in m.kv_telemetry().items():
+            merged[k] = merged.get(k, 0) + v
+    return merged
+
+
+class GroupedKVCacheManager:
+    """Two kinds of K/V in one manager, for a model whose layers mix a
+    sliding window with full attention (reference: the hybrid manager of
+    v1/core/kv_cache_coordinator.py over single_type_kv_cache_manager.py
+    and the SlidingWindowSpec of v1/kv_cache_interface.py: a page table
+    a group, out-of-window blocks handed back).
+
+    The FULL group's pages serve the layers that keep every token: a
+    request holds ``cdiv(context, block)`` of them. The WINDOW group's
+    pages serve the window layers: a request holds the slots from the
+    first page a query can still see to the last scheduled token, at
+    most ``(window + scheduled) / block + 2``, and the rest is handed
+    back as the request moves on (``KVCacheManager._free_out_of_window``).
+    Both tables are position-aligned (slot ``i`` holds positions
+    ``[i x block, (i + 1) x block)``), so the groups allocate in step
+    and the worker's slot arithmetic is the same for both; a freed slot
+    reads the placeholder id 0 under the mask.
+
+    Two pools, not one: the window group's is sized to all it can ever
+    hold (``window_group_pool_blocks``, a function of the configuration)
+    and the full group's gets the rest of the chip (worker/worker.py), so
+    a page is as large as its own group's layers make it and neither
+    group's layout depends on the other's depth. Admission and
+    preemption follow what BOTH have free: an allocation is granted
+    whole or not at all. Prefix sharing is refused for such a model (a
+    hit would need the full group's pages and the window group's last
+    window beside them; the scheduler turns caching off and says so).
+    """
+
+    def __init__(self, block_size: int, num_blocks: int,
+                 num_window_blocks: int, window: int) -> None:
+        self.block_size = block_size
+        self.enable_caching = False
+        self.full_group = KVCacheManager(block_size, num_blocks,
+                                         enable_caching=False)
+        self.window_group = KVCacheManager(block_size, num_window_blocks,
+                                           enable_caching=False,
+                                           free_window=window)
+        # What the scheduler's per-pool walks read (_block_pools).
+        self.managers = [self.full_group, self.window_group]
+
+    @property
+    def req_to_blocks(self) -> dict:
+        return self.full_group.req_to_blocks
+
+    @property
+    def usage(self) -> float:
+        """Pages in use over pages there are, both groups counted."""
+        total = sum(m.block_pool.num_blocks for m in self.managers)
+        return 1.0 - self.get_num_free_blocks() / total
+
+    def get_num_free_blocks(self) -> int:
+        return sum(m.get_num_free_blocks() for m in self.managers)
+
+    def get_computed_blocks(
+            self, request: Request) -> tuple[KVCacheBlocks, int]:
+        return KVCacheBlocks([]), 0
+
+    def allocate_slots(self, request: Request, num_new_tokens: int,
+                       new_computed_blocks: Optional[KVCacheBlocks] = None,
+                       num_lookahead_tokens: int = 0,
+                       skip_allocation: bool = False,
+                       delay_caching: bool = False,
+                       ) -> Optional[KVCacheBlocks]:
+        """Pages for ``num_new_tokens`` more tokens in both groups, or
+        None and nothing taken (the caller preempts)."""
+        assert not skip_allocation and not (
+            new_computed_blocks and new_computed_blocks.blocks), \
+            "no prefix hit and no token-parallel peer under cache groups"
+        full = self.full_group.allocate_slots(
+            request, num_new_tokens,
+            num_lookahead_tokens=num_lookahead_tokens)
+        if full is None:
+            return None
+        win = self.window_group.allocate_slots(
+            request, num_new_tokens,
+            num_lookahead_tokens=num_lookahead_tokens)
+        if win is None:
+            self.full_group._release_tail(request.request_id, full.blocks)
+            return None
+        assert len(win.blocks) == len(full.blocks), \
+            "the groups' tables are position-aligned"
+        return KVCacheBlocks(full.blocks, window_blocks=win.blocks)
+
+    def free(self, request: Request) -> None:
+        for m in self.managers:
+            m.free(request)
+
+    def free_block_hashes(self, request: Request) -> None:
+        for m in self.managers:
+            m.free_block_hashes(request)
+
+    def transfer_ownership(self, old_id: str, new_id: str) -> None:
+        for m in self.managers:
+            m.transfer_ownership(old_id, new_id)
+
+    def get_block_ids(self, request_id: str) -> list[int]:
+        return self.full_group.get_block_ids(request_id)
+
+    def get_window_block_ids(self, request_id: str) -> list[int]:
+        return self.window_group.get_block_ids(request_id)
+
+    def reset_prefix_cache(self) -> bool:
+        return all([m.reset_prefix_cache() for m in self.managers])
+
+    def make_prefix_cache_stats(self) -> dict[str, float]:
+        return {"queries": 0, "hits": 0}
+
+    def group_stats(self) -> dict[str, int]:
+        """What each group holds and what the window group handed back
+        (vdt:kv_group_pages_held, vdt:kv_window_pages_freed_total)."""
+        return {
+            "kv_full_pages_held": self.full_group.num_held_blocks(),
+            "kv_window_pages_held": self.window_group.num_held_blocks(),
+            "kv_window_pages_freed": self.window_group.window_pages_freed,
+        }
+
+    def kv_telemetry(self) -> dict:
+        """Both pools summed; ``held_blocks`` counts a page of either
+        group as one."""
+        return _summed_telemetry(self.managers)
 
 
 class TokenParallelKVCacheManager:
@@ -470,8 +630,4 @@ class TokenParallelKVCacheManager:
         """Per-rank pools summed — one fleet view of the partitioned
         page array (per-rank free counts already ride get_stats as
         tknp_free_blocks_rank*)."""
-        merged: dict = {}
-        for m in self.managers:
-            for k, v in m.kv_telemetry().items():
-                merged[k] = merged.get(k, 0) + v
-        return merged
+        return _summed_telemetry(self.managers)

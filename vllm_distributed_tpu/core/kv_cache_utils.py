@@ -12,7 +12,10 @@ import struct
 from dataclasses import dataclass
 from typing import NamedTuple, Optional
 
+import numpy as np
+
 from vllm_distributed_tpu.request import Request
+from vllm_distributed_tpu.utils import cdiv
 
 
 class BlockHash(NamedTuple):
@@ -70,13 +73,58 @@ def hash_request_tokens(block_size: int,
     return hashes
 
 
+def num_window_dead_blocks(num_computed_tokens: int, window: int,
+                           block_size: int) -> int:
+    """Leading pages of a request that no query can see any more under a
+    sliding window: the next query sits at ``num_computed_tokens`` and
+    sees positions ``> num_computed_tokens - window``, so a page is dead
+    once its last position lies before that. ONE rule for the manager
+    that frees those pages (core/kv_cache_manager.py) and the runner
+    that nulls their slots in the table the kernel gets
+    (worker/model_runner.py), which asks for all its rows at once (an
+    array of computed tokens gives an array)."""
+    return np.maximum(0, num_computed_tokens - window + 1) // block_size
+
+
+def window_group_pool_blocks(window: int, block_size: int,
+                             max_num_seqs: int,
+                             max_num_batched_tokens: int,
+                             max_pages_per_req: int) -> int:
+    """Pages of the window group's pool: all it can ever hold. A request
+    at ``c`` computed tokens granted ``n`` more holds slots
+    ``[dead(c), cdiv(c + n, block))``, at most ``(window + n) / block +
+    2`` pages; the grants of a step sum to the token budget. Two more
+    pages a request cover a multi-step burst's lookahead and a grant
+    made ahead of the step that retires (async scheduling). Sized so,
+    the window group never refuses an allocation that the full group
+    grants; it follows from the configuration alone, so the scheduler
+    and the worker compute the same count without talking."""
+    per_req = min(cdiv(window, block_size) + 4, max_pages_per_req)
+    return (max_num_seqs * per_req
+            + cdiv(max_num_batched_tokens, block_size))
+
+
+def config_window_group_pool_blocks(config, window: int) -> int:
+    """``window_group_pool_blocks`` of an engine configuration: what the
+    scheduler (its manager) and the loader (the model's cache arrays)
+    both size the window group's pool by."""
+    sc = config.scheduler_config
+    return window_group_pool_blocks(
+        window, config.cache_config.block_size, sc.max_num_seqs,
+        sc.max_num_batched_tokens, config.max_pages_per_req)
+
+
 @dataclass
 class KVCacheSpec:
     """Geometry of one KV cache group (reference:
     v1/kv_cache_interface.py:20-208 FullAttentionSpec et al.).
 
-    Round 1 supports full attention only; sliding-window/mamba groups slot in
-    as additional specs later.
+    One spec a group. A model whose layers all keep every token (or all
+    share one window) has one group; a model that mixes window and full
+    layers has two, each with a pool and a page table of its own
+    (``GroupedKVCacheManager``); state rows (SSM, KDA, carried
+    convolution inputs) are a third kind that is not paged at all
+    (core/state_cache.py).
     """
 
     block_size: int

@@ -21,6 +21,10 @@ class NewRequestData:
     sampling_params: SamplingParams
     block_ids: list[int]
     num_computed_tokens: int
+    # The window group's page ids, slot for slot beside ``block_ids``
+    # (core/kv_cache_manager.py GroupedKVCacheManager); None for a model
+    # with one cache group.
+    window_block_ids: "list[int] | None" = None
     # Multi-LoRA adapter selection ({"name", "path"}; see models/lora.py).
     lora_request: "dict | None" = None
     # Pooling/embedding request marker ({"type": "last"}).
@@ -40,6 +44,9 @@ class CachedRequestData:
     # Tokens appended since last step (resumed requests carry all tokens).
     new_token_ids: list[list[int]] = field(default_factory=list)
     new_block_ids: list[list[int]] = field(default_factory=list)
+    # Beside ``new_block_ids``, entry for entry, under two cache groups;
+    # empty for a model with one.
+    new_window_block_ids: list[list[int]] = field(default_factory=list)
     num_computed_tokens: list[int] = field(default_factory=list)
 
 

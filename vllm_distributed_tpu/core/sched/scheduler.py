@@ -19,7 +19,8 @@ from typing import Iterable, Optional
 
 from vllm_distributed_tpu.config import EngineConfig
 from vllm_distributed_tpu.core.kv_cache_manager import (
-    KVCacheBlocks, KVCacheManager, TokenParallelKVCacheManager)
+    GroupedKVCacheManager, KVCacheBlocks, KVCacheManager,
+    TokenParallelKVCacheManager)
 from vllm_distributed_tpu.core.sched import qos as qos_mod
 from vllm_distributed_tpu.core.sched.output import (CachedRequestData,
                                                     ModelRunnerOutput,
@@ -129,10 +130,22 @@ class Scheduler:
         # request's prompt pages for a peer pull after they leave the
         # window; its deferred-free holds don't cover mid-request frees).
         from vllm_distributed_tpu.models.loader import (
-            resolve_encoder_only, resolve_free_window, resolve_stateful)
+            resolve_encoder_only, resolve_free_window, resolve_stateful,
+            resolve_window_group)
         free_window = (None if kv_connector is not None
                        else resolve_free_window(config.model_config))
         enable_caching = config.cache_config.enable_prefix_caching
+        # Window and full layers mixed, in a family that keeps a cache
+        # group for each (models/cohere_moe.py): two pools, two tables
+        # (GroupedKVCacheManager). get_model refuses the combinations
+        # the groups do not carry (KV transfer, token parallelism).
+        window_group = resolve_window_group(config.model_config)
+        if window_group is not None and enable_caching:
+            logger.info(
+                "prefix caching is off for this model: its window "
+                "group's pages are freed behind the window and are never "
+                "shared, and a hit on the full group alone is no hit")
+            enable_caching = False
         if resolve_encoder_only(config.model_config):
             # Encoder-only (BERT-family) archs: a bidirectional layer
             # needs the full sequence in one step, and there is no
@@ -225,7 +238,17 @@ class Scheduler:
                 and self.state_cache is None and free_window is None):
             from vllm_distributed_tpu.core.kv_tier import maybe_kv_tier
             self.kv_tier = maybe_kv_tier(config, kv_connector)
-        if self.tknp_size > 1:
+        self.window_grouped = window_group is not None
+        if self.window_grouped:
+            from vllm_distributed_tpu.core.kv_cache_utils import \
+                config_window_group_pool_blocks
+            self.kv_cache_manager = GroupedKVCacheManager(
+                block_size=config.cache_config.block_size,
+                num_blocks=num_blocks,
+                num_window_blocks=config_window_group_pool_blocks(
+                    config, window_group),
+                window=window_group)
+        elif self.tknp_size > 1:
             self.kv_cache_manager = TokenParallelKVCacheManager(
                 block_size=config.cache_config.block_size,
                 num_blocks=num_blocks,
@@ -790,6 +813,9 @@ class Scheduler:
                                       request.num_computed_tokens +
                                       num_new_tokens])
             cached_reqs.new_block_ids.append(new_blocks.get_block_ids())
+            if new_blocks.window_blocks is not None:
+                cached_reqs.new_window_block_ids.append(
+                    new_blocks.get_window_block_ids())
             cached_reqs.num_computed_tokens.append(
                 request.num_computed_tokens)
             if self.state_cache is not None:
@@ -1101,12 +1127,19 @@ class Scheduler:
 
                 all_block_ids = self.kv_cache_manager.get_block_ids(
                     request.request_id)
+                window_block_ids = (
+                    self.kv_cache_manager.get_window_block_ids(
+                        request.request_id)
+                    if self.window_grouped else None)
                 if resumed:
                     cached_reqs.req_ids.append(request.request_id)
                     cached_reqs.resumed_from_preemption.append(True)
                     cached_reqs.new_token_ids.append(
                         list(request.all_token_ids))
                     cached_reqs.new_block_ids.append(all_block_ids)
+                    if self.window_grouped:
+                        cached_reqs.new_window_block_ids.append(
+                            window_block_ids)
                     cached_reqs.num_computed_tokens.append(
                         num_computed_tokens)
                 else:
@@ -1116,6 +1149,7 @@ class Scheduler:
                             prompt_token_ids=list(request.prompt_token_ids),
                             sampling_params=request.sampling_params,
                             block_ids=all_block_ids,
+                            window_block_ids=window_block_ids,
                             num_computed_tokens=num_computed_tokens,
                             lora_request=request.lora_request,
                             pooling_params=request.pooling_params,
@@ -1836,6 +1870,8 @@ class Scheduler:
         }
         if self.state_cache is not None:
             stats.update(self.state_cache.stats())
+        if self.window_grouped:
+            stats.update(self.kv_cache_manager.group_stats())
         if self.kv_tier is not None:
             # Nested tier dict ({pages,bytes,demotions,promotions,
             # misses} by tier + promotion histogram + the router's

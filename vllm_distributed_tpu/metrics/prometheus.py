@@ -49,6 +49,10 @@ _GAUGES = {
 _COUNTERS = {
     "num_preemptions": ("vdt:num_preemptions_total",
                         "Cumulative preempted requests"),
+    "kv_window_pages_freed": ("vdt:kv_window_pages_freed_total",
+                              "Cumulative pages the window cache group "
+                              "handed back behind requests' windows "
+                              "(absent for a model with one cache group)"),
     "hits": ("vdt:prefix_cache_hits_total",
              "Cumulative prefix-cache token hits"),
     "queries": ("vdt:prefix_cache_queries_total",
@@ -173,6 +177,12 @@ HISTOGRAMS = {
         "multi-head form (sequences with more than one query row in a "
         "chunk program on the latent kernels; 0 on a decode step and "
         "for a model without a latent cache)"),
+    "step_kv_window_tokens_read": (
+        "vdt:step_kv_window_tokens_read",
+        "Per step, under a window cache group: min(context, window - 1 "
+        "+ scheduled tokens) summed over the scheduled requests, the K/V "
+        "tokens ONE window layer reads (vdt:step_kv_tokens_read is what "
+        "a full layer reads); absent for a model with one cache group"),
     "step_ssd_chunk_tokens": (
         "vdt:step_ssd_chunk_tokens",
         "Per step, tokens that the Mamba-2 layers took in the chunked "
@@ -209,6 +219,12 @@ LABELED_HISTOGRAMS = {
         "vdt:step_tokens",
         "Tokens granted per non-empty step, by kind (prefill = requests "
         "still in their prompt, decode = past it)", ("kind", )),
+    "step_attn_pairs": (
+        "vdt:step_attn_pairs",
+        "Per step, under a window cache group: query x key pairs inside "
+        "the masks of ONE layer of each kind (full: every key up to the "
+        "query; window: the last `sliding_window` of them)",
+        ("layer_kind", )),
 }
 
 
@@ -221,6 +237,9 @@ LABELED_METRICS = {
     "vdt:step_phase_cpu_seconds": ("phase", ),
     "vdt:step_kind_seconds": ("kind", "part"),
     "vdt:step_tokens": ("kind", ),
+    "vdt:step_attn_pairs": ("layer_kind", ),
+    # Two kinds of K/V in one manager (core/kv_cache_manager.py).
+    "vdt:kv_group_pages_held": ("group", ),
     "vdt:fault_injections_total": ("point", ),
     # Telemetry plane: per-worker device/compilation series.
     "vdt:recompiles_total": ("worker", ),
@@ -913,6 +932,17 @@ def render_metrics(stats: dict) -> str:
         if isinstance(value, dict) and value:
             lines += _render_labeled_histograms(name, help_text, labels,
                                                 value)
+    # Two kinds of K/V in one manager: what each group holds now.
+    held = [(g, stats[f"kv_{g}_pages_held"]) for g in ("full", "window")
+            if f"kv_{g}_pages_held" in stats]
+    if held:
+        name = "vdt:kv_group_pages_held"
+        lines += [f"# HELP {name} Pages that requests hold in each cache "
+                  "group of a model that mixes window and full attention "
+                  "layers (full: every token of a request; window: what "
+                  "lies inside its window, the rest is handed back)",
+                  f"# TYPE {name} gauge"]
+        lines += [f'{name}{{group="{g}"}} {float(v)}' for g, v in held]
     # Attention kernel dispatch counts ({kernel: steps} from the runner,
     # summed per kernel across DP replicas).
     calls = stats.get("attn_kernel_calls")

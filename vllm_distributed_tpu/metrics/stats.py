@@ -46,6 +46,10 @@ QUEUE_LAG_BUCKETS = (0.00025, 0.0005, 0.001, 0.002, 0.004, 0.008, 0.016,
                      0.032, 0.064, 0.1, 0.25, 0.5, 1.0, 2.0)
 # Tokens of one step (granted, or attended to by the step's queries).
 STEP_TOKEN_BUCKETS = tuple(2**i for i in range(21))
+# Query x key pairs of one step in one attention layer: a decode token
+# at a short context (256) to a token budget of queries over a model's
+# longest context (4**16 = 4.3e9).
+ATTN_PAIR_BUCKETS = tuple(4**i for i in range(4, 17))
 # Host-to-device transfers of one step: each count up to 8 has a bucket
 # of its own (the default path issues 3), then the per-array hand-over's
 # range and the optional fields'.

@@ -115,6 +115,16 @@ class AttentionBatch:
     # by the multi-step scan / EAGLE, and MLA models).
     attn_desc: Optional[jax.Array] = None
     decode_list: Optional[jax.Array] = None
+    # The window group's page ids (None: the model has one cache group).
+    # A model that mixes window and full layers keeps a pool and a table
+    # for each kind (core/kv_cache_manager.py GroupedKVCacheManager);
+    # the tables are position-aligned, so everything else of the batch
+    # (runs, descriptor, positions) serves both, and only what names a
+    # page differs: ``slot_mapping``, ``block_tables`` and the page
+    # column of ``kv_runs``. ``for_window_group`` swaps them in.
+    window_slot_mapping: Optional[jax.Array] = None
+    window_block_tables: Optional[jax.Array] = None
+    window_kv_runs: Optional[jax.Array] = None
     # Static: per-sequence query-length bucket (1 for pure decode);
     # changing it recompiles, like every other shape bucket. Ignored by
     # the unified kernel (pinned to 1 by the runner when a descriptor is
@@ -126,6 +136,14 @@ class AttentionBatch:
     # disagree. 0 when no descriptor is attached.
     attn_bq: int = 0
     attn_sb: int = 0
+
+    def for_window_group(self) -> "AttentionBatch":
+        """The batch as a window layer's cache ops read it: the window
+        group's pages where the full group's stood."""
+        return dataclasses.replace(
+            self, slot_mapping=self.window_slot_mapping,
+            block_tables=self.window_block_tables,
+            kv_runs=self.window_kv_runs)
 
 
 @dataclasses.dataclass

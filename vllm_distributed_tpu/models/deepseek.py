@@ -35,7 +35,9 @@ computed whole. That partial sum is the layer's output: what
 ``psum``. Nothing stands in for the absent chips. The dispatch works on
 the rows this chip can be expected to hold (``share_capacity``: T x k x
 held / published and a half more), not on all T x k picks, and takes
-further passes of as many rows when a router puts more here.
+further passes of as many rows when a router puts more here. The
+dispatch itself (``share_capacity``, ``_share_dispatch``) lives in
+models/mixtral.py, where models/cohere_moe.py reaches it too.
 
 **Expert weights are per-layer leaves** — ``w_gate`` / ``w_up`` /
 ``w_down`` are tuples of ``[E, H, Im]`` arrays, one a MoE layer, and
@@ -75,15 +77,10 @@ from vllm_distributed_tpu.ops.mla import (latent_attention_values,
                                           tpla_latent_attention,
                                           write_latent_cache,
                                           write_latent_cache_tpla)
-from vllm_distributed_tpu.utils import cdiv, round_up
 
 _DENSE_KEYS = frozenset({"gate", "up", "down"})
 _MOE_KEYS = frozenset({"router", "router_bias", "w_gate", "w_up", "w_down",
                        "shared_gate", "shared_up", "shared_down"})
-# A pass of the share's dispatch: its rows come in tiles of this many,
-# and a step of at most so many picks is one pass over all of them.
-_ROW_TILE = 128
-_WHOLE_PICKS = 1024
 
 
 class DeepseekV2ForCausalLM(MixtralForCausalLM):
@@ -534,78 +531,6 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
         gmask = jnp.zeros((T, G), bool).at[
             jnp.arange(T)[:, None], gidx].set(True)
         return jnp.repeat(gmask, E // G, axis=-1)
-
-    def share_capacity(self, T: int, k: int) -> int:
-        """Rows of one pass of the share's dispatch: what an even router
-        puts on the held experts at ``T`` tokens of ``k`` picks and a
-        half more, in whole row tiles, never above ``T x k``. The count
-        of tiles is odd, and a step of few picks is worked on whole:
-        ``ragged_dot``'s time on a v5e follows the largest power of two
-        that divides its rows (at 16 groups of 70 rows, 128: 3.7 ms for
-        the three GEMMs, 512: 5.2, 32: 6.6, 8: 19.4), and under a
-        thousand rows it does not fall with them (PERF.md, Findings
-        PR 40)."""
-        picks = T * k
-        if picks <= _WHOLE_PICKS:
-            return picks
-        c = self.cfg
-        expected = cdiv(picks * c.experts_held[1], c.router_experts)
-        tiles = cdiv(expected + expected // 2, _ROW_TILE) | 1
-        return min(picks, tiles * _ROW_TILE)
-
-    def moe_dispatch(self, lp: dict, x: jax.Array, top_idx: jax.Array,
-                     top_vals: jax.Array) -> jax.Array:
-        """Without an expert share: the Mixtral dispatch. With one
-        (module docstring): the picks among the held experts
-        ``[lo, lo + n)`` sort to the front by local id and the rest
-        behind them, and only the front is worked on, ``share_capacity``
-        rows a pass: one pass wherever the router is near even, as many
-        as the held picks need wherever it is not (all ``T x k`` may
-        land here), so no pick is dropped. A pass is
-        ``_moe_ep_ragged``'s ``rank_fn`` on one rank, without the
-        ``psum``, over its window of the sorted picks. ``ragged_dot``
-        does NOT zero the rows past its groups on the TPU (they come
-        back uninitialised, NaN among them, as measured on a v5e in
-        PR 28), so those rows are selected away, not multiplied by a
-        weight of 0."""
-        held = self.cfg.experts_held
-        if held is None:
-            return super().moe_dispatch(lp, x, top_idx, top_vals)
-        lo, n = held
-        T, k = top_idx.shape
-        C = self.share_capacity(T, k)
-        with jax.named_scope("moe_dispatch"):
-            local = top_idx.astype(jnp.int32).reshape(-1) - lo
-            local = jnp.where((local >= 0) & (local < n), local, n)
-            # Padded to whole passes: a window never runs off the end.
-            order = jnp.pad(jnp.argsort(local, stable=True),
-                            (0, round_up(T * k, C) - T * k))
-            sizes = jnp.bincount(local, length=n + 1)[:n]
-            ends = jnp.cumsum(sizes)
-            starts = ends - sizes
-            weights = top_vals.reshape(-1)
-
-        def one_pass(carry):
-            at, out = carry
-            with jax.named_scope("moe_dispatch"):
-                picks = jax.lax.dynamic_slice(order, (at,), (C,))
-                st = picks // k
-                xs = x[st]
-                # Each group clipped to the window [at, at + C).
-                group_sizes = jnp.maximum(
-                    jnp.minimum(ends, at + C) - jnp.maximum(starts, at), 0)
-            y = self._expert_ffn(lp, xs, group_sizes)
-            with jax.named_scope("moe_combine"):
-                in_group = at + jnp.arange(C) < ends[-1]
-                y = jnp.where(in_group[:, None],
-                              y * weights[picks][:, None].astype(y.dtype), 0)
-                out = out.at[st].add(y)
-            return at + C, out
-
-        _, out = jax.lax.while_loop(
-            lambda carry: carry[0] < ends[-1], one_pass,
-            (jnp.int32(0), jnp.zeros_like(x)))
-        return out
 
     def mlp_block(self, lp: dict, x: jax.Array,
                   lora_ctx=None) -> jax.Array:

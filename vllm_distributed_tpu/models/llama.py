@@ -1288,7 +1288,9 @@ class LlamaForCausalLM:
             per-layer buffers are picked by a switch with one branch a
             layer, so each branch's kernels read buffers that are
             arguments of the program, in place."""
-            if not per_layer:
+            if not per_layer or all(k in lp for k in per_layer):
+                # Nothing a layer, or the walk is unrolled and the
+                # layer's buffers stand in ``lp`` already.
                 return self.mlp_block(lp, x, lora_ctx)
             # One jitted block for all branches: traced once, called
             # with each layer's buffers.
@@ -1300,7 +1302,11 @@ class LlamaForCausalLM:
                     lora_ctx=lora_ctx) for i in range(num_layers)], x)
 
         def layer_body(h, k_all, v_all, lp, layer_idx, window,
-                       nope=False):
+                       nope=False, cache_idx=None, cache_batch=batch):
+            """``cache_idx`` / ``cache_batch``: the layer's row in the
+            cache it is handed and the batch its cache ops read, where a
+            window layer's K/V lives in a cache group of its own (below);
+            else the layer's index and the step's batch."""
             if c.pre_norm:
                 x = self._norm(h, lp["input_ln"], lp.get("input_ln_b"))
             else:
@@ -1341,8 +1347,9 @@ class LlamaForCausalLM:
             # (mega-kernel descriptor batches); otherwise this is the
             # classic write-then-attend pair.
             k_all, v_all, attn = write_kv_and_attend(
-                q, k_all, v_all, k, v, batch, sm_scale=sm_scale,
-                layer=layer_idx, window=window,
+                q, k_all, v_all, k, v, cache_batch, sm_scale=sm_scale,
+                layer=layer_idx if cache_idx is None else cache_idx,
+                window=window,
                 logit_cap=c.attn_logit_softcap, alibi_slopes=slopes,
                 sinks=(lp["sinks"] if c.attn_sinks else None))
             attn2d = attn.reshape(T, -1)
@@ -1387,6 +1394,12 @@ class LlamaForCausalLM:
         segments = self._plan_window_segments(layer_keys)
         layer_ids = (jnp.arange(num_layers, dtype=jnp.int32)[:, None]
                      + cache_layer_offset)
+        if "k_win" in kv_caches:
+            assert first_layer == 0 and cache_layer_offset == 0, \
+                "cache groups are not sliced per stage"
+            return self._run_layers_by_group(
+                layer_body, layer_params, per_layer, kv_caches,
+                sp(hidden), batch, layer_keys)
         carry = (sp(hidden), kv_caches["k"], kv_caches["v"])
         for start, count, pattern in segments:
             if len(segments) == 1:
@@ -1414,6 +1427,40 @@ class LlamaForCausalLM:
             carry, _ = jax.lax.scan(scan_fn, carry, (lp_seg, ids_seg))
         hidden, k_all, v_all = carry
         return hidden, {"k": k_all, "v": v_all}
+
+    def _run_layers_by_group(self, layer_body, layer_params: dict,
+                             per_layer: dict, kv_caches: dict, hidden,
+                             batch: AttentionBatch, layer_keys: tuple):
+        """The layer walk over two kinds of K/V (models/cohere_moe.py):
+        the window layers' pages live in ``k_win`` / ``v_win`` under a
+        table of their own (``batch.for_window_group()``), the full
+        layers' in ``k`` / ``v``; a layer's row in its group's cache is
+        its rank among the layers of its kind. The walk is UNROLLED and
+        every large weight is a buffer a layer (``serving_layout``): a
+        scan whose step is one period slices the period's weights out of
+        their stacks, and on the TPU each slice that feeds a product is
+        made as a copy (1.7 GB of temporaries a step at Command A+'s
+        widths, whatever the tokens)."""
+        window_batch = batch.for_window_group()
+        h = hidden
+        full = (kv_caches["k"], kv_caches["v"])
+        window = (kv_caches["k_win"], kv_caches["v_win"])
+        rows = {True: 0, False: 0}
+        for i, (w, nope) in enumerate(layer_keys):
+            lp = {**{k: v[i] for k, v in layer_params.items()},
+                  **{k: v[i] for k, v in per_layer.items()}}
+            row = jnp.full((1, ), rows[bool(w)], jnp.int32)
+            rows[bool(w)] += 1
+            layer_idx = jnp.full((1, ), i, jnp.int32)
+            if w:
+                h, *window = layer_body(h, *window, lp, layer_idx, w,
+                                        nope=nope, cache_idx=row,
+                                        cache_batch=window_batch)
+            else:
+                h, *full = layer_body(h, *full, lp, layer_idx, w,
+                                      nope=nope, cache_idx=row)
+        return h, {"k": full[0], "v": full[1], "k_win": window[0],
+                   "v_win": window[1]}
 
     def forward(
         self,

@@ -232,6 +232,32 @@ def get_model(config: EngineConfig, mesh,
                 "attention (Zaya) is not wired (the carried rows and the "
                 "router's state are unsharded); set "
                 "tensor_parallel_size=1")
+    if getattr(arch, "window_group", None):
+        # Window and full layers in cache groups of their own
+        # (models/cohere_moe.py, core/kv_cache_manager.py
+        # GroupedKVCacheManager): the window group's pool is all it can
+        # ever hold, a function of the configuration that the scheduler
+        # computes alike.
+        from vllm_distributed_tpu.core.kv_cache_utils import \
+            config_window_group_pool_blocks
+        sc, pc = config.scheduler_config, config.parallel_config
+        arch.window_group_pages = config_window_group_pool_blocks(
+            config, arch.window_group)
+        arch.window_group_page_size = config.cache_config.block_size
+        for bad, what in (
+                (pc.token_parallel_size > 1, "token parallelism"),
+                (pc.pipeline_parallel_size > 1, "pipeline parallelism"),
+                (config.kv_transfer_config.kv_connector, "KV transfer"),
+                (config.speculative_config.num_speculative_tokens,
+                 "speculative decoding"),
+                (sc.num_scheduler_steps > 1, "multi-step scheduling"),
+                (kv_dtype not in ("auto", None), "an fp8 KV cache"),
+                (config.lora_config.enable_lora, "LoRA")):
+            if bad:
+                raise ValueError(
+                    f"a model that keeps window and full layers in cache "
+                    f"groups of their own cannot be combined with {what} "
+                    "(each builds its batches over ONE page table)")
     if arch.pos_embedding == "learned":
         capacity = arch.max_position_embeddings - arch.pos_offset
         if config.scheduler_config.max_model_len > capacity:
@@ -511,30 +537,52 @@ def resolve_state_only(model_config) -> bool:
     return bool(getattr(model_cls, "STATE_ONLY", False))
 
 
-def resolve_free_window(model_config) -> Optional[int]:
-    """Token window below which KV pages can be freed mid-request: the
-    minimum layer window when EVERY attention layer is windowed, else
-    None (any full-attention layer needs the whole history). Resolved
-    through the same arch hooks get_model uses, so family overrides
-    (Gemma2 alternating layouts, Qwen2 max_window_layers) are honored
-    (reference: the per-group window specs of v1/kv_cache_interface.py
-    SlidingWindowSpec)."""
+def _resolved_arch(model_config):
+    """The arch config as get_model resolves it (family overrides
+    honoured: Gemma2 alternating layouts, Qwen2 max_window_layers), or
+    None where the configuration cannot be resolved."""
     try:
         hf_config = model_config.maybe_load_hf_config()
         model_cls = resolve_architecture(hf_config)
         arch = LlamaArchConfig.from_hf_config(
             model_cls.arch_config_source(hf_config))
         model_cls.configure_arch(arch, hf_config)
-    except Exception:  # noqa: BLE001 - conservative: no freeing
+    except Exception:  # noqa: BLE001 - conservative
+        return None
+    return arch
+
+
+def resolve_free_window(model_config) -> Optional[int]:
+    """Token window below which ALL of a request's KV pages can be freed
+    mid-request: the layer window when EVERY attention layer is windowed
+    alike, else None (some layer needs more history). A family that
+    mixes window and full layers and keeps a cache group for each frees
+    behind the window in its window group only (``resolve_window_group``)
+    and answers None here (reference: the per-group window specs of
+    v1/kv_cache_interface.py SlidingWindowSpec)."""
+    arch = _resolved_arch(model_config)
+    if arch is None:
         return None
     if arch.window_pattern is not None:
         pattern = arch.window_pattern
-        # Only a UNIFORM all-windowed pattern is safe to free against:
-        # with unequal windows the larger-window layers still attend
-        # pages the smaller window has left behind (freeing at
-        # min(pattern) would hand live history to the pool). Mixed and
-        # unequal layouts need per-group hybrid caches — not wired.
+        # Only a UNIFORM all-windowed pattern is safe to free against on
+        # ONE table: with unequal windows the larger-window layers still
+        # attend pages the smaller window has left behind (freeing at
+        # min(pattern) would hand live history to the pool). Window and
+        # full layers mixed take two cache groups
+        # (core/kv_cache_manager.py GroupedKVCacheManager), which a
+        # family opts into (``window_group``); unequal windows would
+        # take a group a window and are not wired.
         if all(pattern) and len(set(pattern)) == 1:
             return pattern[0]
         return None
     return arch.sliding_window
+
+
+def resolve_window_group(model_config) -> Optional[int]:
+    """The window of the model's second cache group, for a family whose
+    window layers and full layers each have a pool and a page table
+    (models/cohere_moe.py sets ``window_group``); None for every other
+    model, which keeps one table."""
+    arch = _resolved_arch(model_config)
+    return getattr(arch, "window_group", None) if arch else None

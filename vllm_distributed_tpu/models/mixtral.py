@@ -47,6 +47,12 @@ from jax.sharding import PartitionSpec as P
 from vllm_distributed_tpu.models.llama import (MODEL_AXIS,
                                                LlamaForCausalLM)
 from vllm_distributed_tpu.parallel.mesh import shard_map
+from vllm_distributed_tpu.utils import cdiv, round_up
+
+# A pass of an expert share's dispatch: its rows come in tiles of this
+# many, and a step of at most so many picks is one pass over all of them.
+_ROW_TILE = 128
+_WHOLE_PICKS = 1024
 
 
 @functools.lru_cache(maxsize=None)
@@ -326,9 +332,14 @@ class MixtralForCausalLM(LlamaForCausalLM):
     def moe_dispatch(self, lp: dict, x: jax.Array, top_idx: jax.Array,
                      top_vals: jax.Array) -> jax.Array:
         """Routing-agnostic grouped-GEMM dispatch of (token, expert)
-        assignments (see mlp_block docstring for the mechanism)."""
+        assignments (see mlp_block docstring for the mechanism); under
+        an expert share (``cfg.experts_held``, set by the families that
+        read ``expert_share``: models/deepseek.py, models/cohere_moe.py)
+        the share's own dispatch."""
         from vllm_distributed_tpu import envs
         c = self.cfg
+        if getattr(c, "experts_held", None) is not None:
+            return self._share_dispatch(lp, x, top_idx, top_vals)
         T = x.shape[0]
         k = top_idx.shape[-1]
         E = c.num_experts
@@ -375,6 +386,77 @@ class MixtralForCausalLM(LlamaForCausalLM):
                 y = y * sw[:, None].astype(y.dtype)
             out = jax.ops.segment_sum(y, st, num_segments=T)
         return out.astype(x.dtype)
+
+    def share_capacity(self, T: int, k: int) -> int:
+        """Rows of one pass of the share's dispatch: what an even router
+        puts on the held experts at ``T`` tokens of ``k`` picks and a
+        half more, in whole row tiles, never above ``T x k``. The count
+        of tiles is odd, and a step of few picks is worked on whole:
+        ``ragged_dot``'s time on a v5e follows the largest power of two
+        that divides its rows (at 16 groups of 70 rows, 128: 3.7 ms for
+        the three GEMMs, 512: 5.2, 32: 6.6, 8: 19.4), and under a
+        thousand rows it does not fall with them (PERF.md, Findings
+        PR 40)."""
+        picks = T * k
+        if picks <= _WHOLE_PICKS:
+            return picks
+        c = self.cfg
+        expected = cdiv(picks * c.experts_held[1], c.router_experts)
+        tiles = cdiv(expected + expected // 2, _ROW_TILE) | 1
+        return min(picks, tiles * _ROW_TILE)
+
+    def _share_dispatch(self, lp: dict, x: jax.Array, top_idx: jax.Array,
+                        top_vals: jax.Array) -> jax.Array:
+        """One chip's share of an expert layer (``cfg.experts_held``;
+        models/deepseek.py's module docstring has the deployment it
+        stands for): the picks among the held experts ``[lo, lo + n)``
+        sort to the front by local id and the rest behind them, and only
+        the front is worked on, ``share_capacity`` rows a pass: one pass
+        wherever the router is near even, as many as the held picks need
+        wherever it is not (all ``T x k`` may land here), so no pick is
+        dropped. A pass is ``_moe_ep_ragged``'s ``rank_fn`` on one rank,
+        without the ``psum``, over its window of the sorted picks.
+        ``ragged_dot`` does NOT zero the rows past its groups on the TPU
+        (they come back uninitialised, NaN among them, as measured on a
+        v5e in PR 28), so those rows are selected away, not multiplied
+        by a weight of 0."""
+        held = self.cfg.experts_held
+        lo, n = held
+        T, k = top_idx.shape
+        C = self.share_capacity(T, k)
+        with jax.named_scope("moe_dispatch"):
+            local = top_idx.astype(jnp.int32).reshape(-1) - lo
+            local = jnp.where((local >= 0) & (local < n), local, n)
+            # Padded to whole passes: a window never runs off the end.
+            order = jnp.pad(jnp.argsort(local, stable=True),
+                            (0, round_up(T * k, C) - T * k))
+            sizes = jnp.bincount(local, length=n + 1)[:n]
+            ends = jnp.cumsum(sizes)
+            starts = ends - sizes
+            weights = top_vals.reshape(-1)
+
+        def one_pass(carry):
+            at, out = carry
+            with jax.named_scope("moe_dispatch"):
+                picks = jax.lax.dynamic_slice(order, (at,), (C,))
+                st = picks // k
+                xs = x[st]
+                # Each group clipped to the window [at, at + C).
+                group_sizes = jnp.maximum(
+                    jnp.minimum(ends, at + C) - jnp.maximum(starts, at), 0)
+            y = self._expert_ffn(lp, xs, group_sizes)
+            with jax.named_scope("moe_combine"):
+                in_group = at + jnp.arange(C) < ends[-1]
+                y = jnp.where(in_group[:, None],
+                              y * weights[picks][:, None].astype(y.dtype), 0)
+                out = out.at[st].add(y)
+            return at + C, out
+
+        _, out = jax.lax.while_loop(
+            lambda carry: carry[0] < ends[-1], one_pass,
+            (jnp.int32(0), jnp.zeros_like(x)))
+        return out
+
 
     def _expert_ffn(self, lp: dict, xs: jax.Array,
                     group_sizes: jax.Array) -> jax.Array:

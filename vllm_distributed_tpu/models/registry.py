@@ -69,6 +69,7 @@ from vllm_distributed_tpu.models.whisper import \
     WhisperForConditionalGeneration
 from vllm_distributed_tpu.models.bailing import BailingMoeV3ForCausalLM
 from vllm_distributed_tpu.models.bamba import BambaForCausalLM
+from vllm_distributed_tpu.models.cohere_moe import Cohere2MoeForCausalLM
 from vllm_distributed_tpu.models.granite_hybrid import \
     GraniteMoeHybridForCausalLM
 from vllm_distributed_tpu.models.jamba import JambaForCausalLM
@@ -173,6 +174,11 @@ _REGISTRY: dict[str, type] = {
     # ZAYA1: compressed convolutional attention on carried rows, a
     # top-1 MLP router with a state from layer to layer (models/zaya.py).
     "ZayaForCausalLM": ZayaForCausalLM,
+    # Command A+: Cohere's parallel block over sigmoid-routed experts
+    # and averaged shared ones, window and full layers in cache groups
+    # of their own (models/cohere_moe.py; the name is the family's
+    # convention, the source gives ``model_type`` only).
+    "Cohere2MoeForCausalLM": Cohere2MoeForCausalLM,
     # Ling-3.0: gated delta-rule linear attention (KDA) beside latent
     # attention over DeepSeek-V3 experts (models/bailing.py; the name is
     # the family's convention, not the source's).
@@ -218,14 +224,23 @@ _REGISTRY: dict[str, type] = {
 
 
 def resolve_architecture(hf_config) -> type:
-    for arch in getattr(hf_config, "architectures", None) or []:
+    named = list(getattr(hf_config, "architectures", None) or [])
+    for arch in named:
         if arch in _REGISTRY:
             return _REGISTRY[arch]
-    # Config-shape fallback (tiny test configs may lack architectures).
+    if named:
+        # An architecture that is NAMED and unknown is refused: served
+        # as a Llama it would answer, fast and wrong (ROADMAP D15).
+        raise ValueError(
+            f"no supported architecture in {named} (model_type "
+            f"{getattr(hf_config, 'model_type', None)!r}); "
+            "supported_architectures() lists what is")
+    # Config-shape fallback: a configuration that names no architecture
+    # (tiny test configs) is a Llama.
     if hasattr(hf_config, "num_hidden_layers"):
         return LlamaForCausalLM
-    raise ValueError(
-        f"no supported architecture in {getattr(hf_config, 'architectures', None)}")
+    raise ValueError("the configuration names no architecture and has "
+                     "no num_hidden_layers")
 
 
 def supported_architectures() -> list[str]:

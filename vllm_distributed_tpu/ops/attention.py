@@ -713,8 +713,11 @@ def paged_attention(
 ) -> jax.Array:
     """Unified entry used by every model's attention layer; dispatches to
     the Pallas kernel or the XLA reference path per backend selection.
-    Sliding-window models take the XLA path (the Pallas kernel's
-    per-sequence runs don't carry a window bound yet).
+    A sliding window rides the mega-kernel as a per-layer static (its
+    page loop starts at the first block inside the window); the XLA
+    reference path gathers a request's whole table and masks, reading
+    the placeholder page under the mask where a window cache group has
+    handed a slot back.
 
     On a >1-wide tensor-parallel mesh the Pallas call is wrapped in
     shard_map over the "model" (head) axis — pallas_call is opaque to

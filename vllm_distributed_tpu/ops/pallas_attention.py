@@ -107,7 +107,10 @@ def prefill_tile_size(num_q_heads: int, head_dim: int) -> int:
     bucket) so the kernel has no per-composition statics; 32 rows fold to
     32*group score rows per kv head — MXU-filling for GQA groups >= 4.
     Shrinks (staying a multiple of 8, the IO chunk) for wide-head models
-    so per-program staging stays inside the VMEM budget."""
+    so per-program staging stays inside the VMEM budget: 128 query heads
+    of 128 lanes (a group of 16 on each of 8 K/V heads) take 16 rows, so
+    a tile is 256 score rows a K/V head and its float32 accumulators
+    (8 x 256 x 128) a megabyte."""
     bq = 32
     while bq > 8 and bq * num_q_heads * head_dim * 32 > 12 * 1024**2:
         bq //= 2
@@ -119,7 +122,8 @@ def decode_group_size(num_q_heads: int, num_kv_heads: int) -> int:
     program). Independent of the runtime batch size — inactive slots are
     masked — and sized against the worst-case 128-position kv block so
     the same sb is valid for every caller (the cascade suffix call sees
-    a shorter block table than the main call)."""
+    a shorter block table than the main call). 128 query heads on 8 K/V
+    heads give 2: 256 score rows against 2 x 8 x 128 key columns."""
     sb = max(1, min(8, 128 // max(1, num_q_heads // 4)))
     while sb > 1 and (sb * num_q_heads) * (sb * num_kv_heads * 128) * 8 \
             > 8 * 1024**2:
@@ -776,10 +780,17 @@ def _mega_kernel(
     soft-capping) are per-layer STATICS like the model's scan-segment
     plan; ALiBi slopes and sink logits arrive in the tiny ``feat``
     input ([2, QH] f32: row 0 slopes, row 1 sinks) so learned sinks and
-    TP-sharded head slices stay dynamic. Masking is feature-complete
-    but the page loop still walks the full block table — window layers
-    discard out-of-window blocks by mask, not by loop bounds (loop
-    trimming is a profiled follow-up)."""
+    TP-sharded head slices stay dynamic. With ``window > 0`` the page
+    loop is TRIMMED as well as masked: a prefill tile whose queries sit
+    at ``[p0, p0 + bq)`` starts at the kv block that holds
+    ``p0 - window + 1`` and a decode group's sequence at the block that
+    holds ``kv_len - window``, so a window layer reads (and the table
+    needs live page ids for) at most ``window + bq`` positions rounded
+    out to whole kv blocks, whatever the context. Slots in front of
+    that block are never read, which is what lets the window group of
+    core/kv_cache_manager.py hand those pages back mid-request; the few
+    freed slots inside the first block read the placeholder page under
+    the mask. ``window == 0`` traces exactly the untrimmed program."""
     if fuse_write:
         (q_hbm, k_new, v_new, _k_in, _v_in, feat_ref,
          out_hbm, k_cache, v_cache,
@@ -863,7 +874,15 @@ def _mega_kernel(
                     v_vmem.at[slot, 0, :, pl.ds(i * page_size, page_size)],
                     kv_sems.at[slot, 1, 0, i]).start()
 
-        fetch(0, 0)  # overlaps the q chunk DMAs in flight
+        if window > 0:
+            # The first kv block the tile's FIRST query can see; later
+            # rows see no further back.
+            first_block = jnp.maximum(
+                kv_len - q_len + tile_start - window + 1, 0) // blk
+            fetch(first_block, jax.lax.rem(first_block, 2))
+        else:
+            first_block = 0
+            fetch(0, 0)  # overlaps the q chunk DMAs in flight
         for c in range(nck):
             @pl.when(tile_start + 8 * c < q_len)
             def _rdw(c=c):
@@ -949,7 +968,8 @@ def _mega_kernel(
             tuple(jnp.zeros((rows, 1), jnp.float32) for _ in range(KVH)),
             tuple(jnp.zeros((rows, D), jnp.float32) for _ in range(KVH)),
         )
-        ms, ls, accs = jax.lax.fori_loop(0, num_blocks, body, init)
+        ms, ls, accs = jax.lax.fori_loop(first_block, num_blocks, body,
+                                         init)
 
         if has_sinks:
             # Learned per-head virtual key joining only the softmax
@@ -1029,7 +1049,29 @@ def _mega_kernel(
         max_kv = kv_lens[0]
         for i in range(1, sb):
             max_kv = jnp.maximum(max_kv, kv_lens[i])
-        num_blocks = jax.lax.div(max_kv - 1, blk) + 1
+        if window > 0:
+            # Each sequence walks from the block that holds the oldest
+            # position its one query (at kv_len - 1) can see; the loop
+            # index is relative to that block. An inactive slot aliases
+            # a live sequence's row (its scores are masked by a kv_len
+            # of 0): it walks THAT sequence's blocks, so that it too
+            # stays behind no window.
+            walked = [seq_info_ref[idx[i], 2] for i in range(sb)]
+            last_blocks = [jnp.maximum(jax.lax.div(walked[i] - 1, blk), 0)
+                           for i in range(sb)]
+            first_blocks = [jnp.maximum(walked[i] - window, 0) // blk
+                            for i in range(sb)]
+            num_blocks = last_blocks[0] - first_blocks[0] + 1
+            for i in range(1, sb):
+                num_blocks = jnp.maximum(
+                    num_blocks,
+                    jnp.where(jnp.asarray(i) < cnt,
+                              last_blocks[i] - first_blocks[i] + 1, 1))
+        else:
+            last_blocks = [
+                jnp.maximum(jax.lax.div(kv_lens[i] - 1, blk), 0)
+                for i in range(sb)]
+            num_blocks = jax.lax.div(max_kv - 1, blk) + 1
         ROWS = sb * QH
         C = sb * KVH * blk
 
@@ -1040,9 +1082,10 @@ def _mega_kernel(
 
         def fetch(bi, slot):
             for i in range(sb):
-                ci = jnp.clip(bi, 0,
-                              jnp.maximum(
-                                  jax.lax.div(kv_lens[i] - 1, blk), 0))
+                if window > 0:
+                    ci = jnp.minimum(first_blocks[i] + bi, last_blocks[i])
+                else:
+                    ci = jnp.clip(bi, 0, last_blocks[i])
                 for j in range(ppb):
                     page_id = block_tables_ref[rows_[i], ci * ppb + j]
                     pltpu.make_async_copy(
@@ -1068,8 +1111,27 @@ def _mega_kernel(
         vh_c = jax.lax.broadcasted_iota(jnp.int32, (ROWS, C), 1) // blk
         diag = vh_r == vh_c
         col_off = jax.lax.broadcasted_iota(jnp.int32, (ROWS, C), 1) % blk
-        kvlen_rows = jnp.concatenate(
-            [jnp.full((QH, ), kv_lens[i], jnp.int32) for i in range(sb)])
+
+        def per_row(values):
+            """``values[i]`` (a scalar a sequence) on sequence i's QH
+            score rows, as a ``[ROWS, 1]`` column. Mosaic joins 1-D
+            vectors only inside one 128-lane tile, which ``sb x QH``
+            outgrows at 128 query heads: such a group selects the column
+            from an iota instead (a narrower one keeps the program it
+            had)."""
+            if ROWS <= 128:
+                return jnp.concatenate(
+                    [jnp.full((QH, ), values[i], jnp.int32)
+                     for i in range(sb)])[:, None]
+            seq = jax.lax.broadcasted_iota(jnp.int32, (ROWS, 1), 0) // QH
+            col = jnp.zeros((ROWS, 1), jnp.int32)
+            for i in range(sb):
+                col = jnp.where(seq == i, values[i], col)
+            return col
+
+        kvlen_rows = per_row(kv_lens)
+        if window > 0:
+            first_rows = per_row(first_blocks)
         feat_val = (feat_ref[...].astype(jnp.float32)
                     if (has_alibi or has_sinks) else None)
         if has_alibi:
@@ -1104,17 +1166,18 @@ def _mega_kernel(
                 preferred_element_type=jnp.float32)
             if logit_cap > 0:
                 s = logit_cap * jnp.tanh(s / logit_cap)
+            if window > 0:
+                kv_pos = (first_rows + bi) * blk + col_off
+            else:
+                kv_pos = bi * blk + col_off
             if has_alibi:
                 # Decode q position is kv_len - 1 per sequence.
                 s = s + slope_rows * (
-                    bi * blk + col_off -
-                    (kvlen_rows[:, None] - 1)).astype(jnp.float32)
-            mask = jnp.logical_and(
-                diag, bi * blk + col_off < kvlen_rows[:, None])
+                    kv_pos - (kvlen_rows - 1)).astype(jnp.float32)
+            mask = jnp.logical_and(diag, kv_pos < kvlen_rows)
             if window > 0:
                 mask = jnp.logical_and(
-                    mask,
-                    bi * blk + col_off > kvlen_rows[:, None] - 1 - window)
+                    mask, kv_pos > kvlen_rows - 1 - window)
             s = jnp.where(mask, s, _MASK_VALUE)
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             pr = jnp.exp(s - m_new)

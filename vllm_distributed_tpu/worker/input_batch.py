@@ -32,6 +32,10 @@ class InputBatch:
         self.num_computed = np.zeros((R, ), np.int32)
         self.block_table = np.zeros((R, P), np.int32)
         self.num_blocks = np.zeros((R, ), np.int32)
+        # The window group's table, slot for slot beside block_table
+        # (made by the first request that brings window page ids: a
+        # model with one cache group never has it).
+        self.window_block_table: Optional[np.ndarray] = None
 
         self.temperature = np.zeros((R, ), np.float32)
         self.top_k = np.zeros((R, ), np.int32)
@@ -98,6 +102,10 @@ class InputBatch:
         self.block_table[row, :nb] = data.block_ids
         self.block_table[row, nb:] = 0
         self.num_blocks[row] = nb
+        if data.window_block_ids is not None:
+            if self.window_block_table is None:
+                self.window_block_table = np.zeros_like(self.block_table)
+            self._set_window_row(row, data.window_block_ids)
 
         sp: SamplingParams = data.sampling_params
         self.temperature[row] = sp.temperature
@@ -124,9 +132,24 @@ class InputBatch:
         self.mm[row] = data.mm_inputs
         return row
 
+    def _set_window_row(self, row: int, ids: list[int]) -> None:
+        self.window_block_table[row, :len(ids)] = ids
+        self.window_block_table[row, len(ids):] = 0
+
     def update_cached(self, data: CachedRequestData) -> None:
         for i, req_id in enumerate(data.req_ids):
             row = self.req_id_to_index[req_id]
+            window_ids = (data.new_window_block_ids[i]
+                          if data.new_window_block_ids else None)
+            if window_ids is not None:
+                # Before num_blocks moves: the groups' tables grow in
+                # step, so the new ids land at the same slots.
+                if data.resumed_from_preemption[i]:
+                    self._set_window_row(row, window_ids)
+                elif window_ids:
+                    nb = self.num_blocks[row]
+                    self.window_block_table[
+                        row, nb:nb + len(window_ids)] = window_ids
             if data.resumed_from_preemption[i]:
                 # Full state replacement: block table was re-allocated.
                 tokens = data.new_token_ids[i]
@@ -176,6 +199,8 @@ class InputBatch:
         self.num_computed[row] = 0
         self.num_blocks[row] = 0
         self.block_table[row, :] = 0
+        if self.window_block_table is not None:
+            self.window_block_table[row, :] = 0
         self.needs_extended[row] = False
         self.lora_slot[row] = 0
         self.pooling[row] = None

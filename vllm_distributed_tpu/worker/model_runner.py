@@ -29,7 +29,8 @@ from vllm_distributed_tpu.config import EngineConfig
 from vllm_distributed_tpu.core.sched.output import (ModelRunnerOutput,
                                                     SchedulerOutput)
 from vllm_distributed_tpu.logger import init_logger
-from vllm_distributed_tpu.metrics.stats import (STEP_PHASE_BUCKETS,
+from vllm_distributed_tpu.metrics.stats import (ATTN_PAIR_BUCKETS,
+                                                STEP_PHASE_BUCKETS,
                                                 STEP_TOKEN_BUCKETS,
                                                 STEP_TRANSFER_BUCKETS,
                                                 Histogram, StepPhases)
@@ -190,6 +191,12 @@ class TPUModelRunner:
         # decode step and for a model without a latent cache.
         self.latent_prefill_tokens_hist = Histogram(STEP_TOKEN_BUCKETS)
         self.ssd_chunk_tokens_hist = Histogram(STEP_TOKEN_BUCKETS)
+        # Under a window cache group (_fill_window_group): the tokens ONE
+        # window layer reads a step, and the query x key pairs a step
+        # inside the masks of one window layer and of one full layer.
+        self.kv_window_tokens_read_hist = Histogram(STEP_TOKEN_BUCKETS)
+        self.attn_pairs_hist = {kind: Histogram(ATTN_PAIR_BUCKETS)
+                                for kind in ("full", "window")}
         self._latent_kernels: Optional[bool] = None
         # Host arrays that _prepare_inputs and the launch handed to the
         # device for the step through _h2d (explicit hand-overs only:
@@ -1101,6 +1108,53 @@ class TPUModelRunner:
         self._step_transfers += 1
         return jnp.asarray(x)
 
+    @property
+    def _window_group(self) -> Optional[int]:
+        """The window of the model's second cache group; None: one
+        group, the packed step buffer and the programs every model had."""
+        return getattr(getattr(self.model, "cfg", None), "window_group",
+                       None)
+
+    def _fill_window_group(self, f: dict, num_tokens: int, num_runs: int,
+                           n_kv_runs: int) -> None:
+        """The window group's part of the packed buffer, from the full
+        group's: the tables are position-aligned, so a token's slot and
+        a write run's page are looked up at the same table index. The
+        slots the manager freed behind the window are nulled by its own
+        rule (``num_window_dead_blocks`` of the step's computed tokens):
+        the kernel never gets a freed page's id. Feeds the histograms of
+        what a window layer and a full layer do this step."""
+        from vllm_distributed_tpu.core.kv_cache_utils import \
+            num_window_dead_blocks
+        ib = self.input_batch
+        W, ps = self._window_group, self.page_size
+        table = f["window_block_tables"]
+        table[:] = ib.window_block_table
+        dead = num_window_dead_blocks(ib.num_computed, W, ps)
+        table[np.arange(table.shape[1])[None, :] < dead[:, None]] = 0
+        rows = f["req_idx"][:num_tokens]
+        pos = f["positions"][:num_tokens]
+        f["window_slot_mapping"][:num_tokens] = (
+            table[rows, pos // ps] * ps + pos % ps)
+        runs = f["kv_runs"][:n_kv_runs]
+        first = runs[:, 2] + runs[:, 1] - ps   # a run's first token
+        f["window_kv_runs"][:n_kv_runs] = runs
+        f["window_kv_runs"][:n_kv_runs, 0] = table[
+            f["req_idx"][first], f["positions"][first] // ps]
+        q_len = f["seq_info"][:num_runs, 1].astype(np.int64)
+        end = f["seq_info"][:num_runs, 2].astype(np.int64)
+        self.kv_window_tokens_read_hist.observe(
+            int(np.minimum(end, W - 1 + q_len).sum()))
+        # Query x key pairs inside the masks: a query at position p sees
+        # p + 1 keys in a full layer, min(p + 1, W) in a window layer.
+        full = q_len * (end - q_len) + q_len * (q_len + 1) // 2
+        over = np.clip(end - W, 0, q_len)      # queries with p + 1 > W
+        under = q_len - over
+        window = (over * W + under * (end - q_len)
+                  + under * (under + 1) // 2)
+        self.attn_pairs_hist["full"].observe(int(full.sum()))
+        self.attn_pairs_hist["window"].observe(int(window.sum()))
+
     def _step_layout(self, T: int, G: int) -> StepLayout:
         """The packed buffer's layout at forward shape (T, *, G)."""
         layout = self._step_layouts.get((T, G))
@@ -1114,7 +1168,8 @@ class TPUModelRunner:
                     T - Q_TILE_PAD, self.max_num_reqs, bq=bq, sb=sb,
                     num_kv_writes=G)
             layout = self._step_layouts[(T, G)] = StepLayout(
-                T, G, self.max_num_reqs, self.max_pages_per_req, P_desc)
+                T, G, self.max_num_reqs, self.max_pages_per_req, P_desc,
+                window_group=bool(self._window_group))
         return layout
 
     def _prepare_inputs(self, scheduler_output: SchedulerOutput):
@@ -1305,6 +1360,8 @@ class TPUModelRunner:
             self._latent_prefill_tokens(seq_info[:num_runs, 1], max_q))
         self.ssd_chunk_tokens_hist.observe(
             self._ssd_chunk_tokens(seq_info[:num_runs, 1], T))
+        if self._window_group:
+            self._fill_window_group(f, t, num_runs, n_kv_runs)
 
         # Mega-kernel partition descriptor: kv-write rows first (the
         # fused write+attend pass needs them to precede every attention
@@ -2841,14 +2898,21 @@ class TPUModelRunner:
             # is multiplied by for this family.
             stats["mla_latent_token_bytes"] = (
                 stats["mla_latent_page_bytes"] // self.page_size)
-            held = getattr(self.model.cfg, "experts_held", None)
-            if held is not None:
-                # One chip's share of an expert layer
-                # (models/deepseek.py): experts whose weights are here,
-                # of the experts the router scores.
-                stats["moe_experts_held"] = int(held[1])
-                stats["moe_experts_published"] = int(
-                    self.model.cfg.router_experts)
+        held = (getattr(self.model.cfg, "experts_held", None)
+                if self.model is not None else None)
+        if held is not None:
+            # One chip's share of an expert layer (models/mixtral.py
+            # moe_dispatch): experts whose weights are here, of the
+            # experts the router scores.
+            stats["moe_experts_held"] = int(held[1])
+            stats["moe_experts_published"] = int(
+                self.model.cfg.router_experts)
+        if self._window_group:
+            stats["step_kv_window_tokens_read"] = \
+                self.kv_window_tokens_read_hist.to_dict()
+            stats["step_attn_pairs"] = {
+                kind: h.to_dict()
+                for kind, h in self.attn_pairs_hist.items()}
         row_bytes = getattr(self.model, "state_row_bytes", None)
         if row_bytes is not None:
             # One sequence's recurrent and convolution state over every

@@ -27,13 +27,18 @@ class StepLayout:
     """Where each field lies in the packed buffer: ``T`` padded tokens,
     ``G`` KV-write runs, ``max_reqs`` batch rows of ``pages_per_req``
     pages, ``desc_programs`` rows of the mega-kernel's partition
-    descriptor (0: no descriptor, the legacy dispatch)."""
+    descriptor (0: no descriptor, the legacy dispatch).
+    ``window_group``: the model keeps a second cache group for its
+    window layers (core/kv_cache_manager.py GroupedKVCacheManager), so
+    the buffer carries that group's table, slots and write runs too; a
+    model with one group has the layout it always had."""
 
     T: int
     G: int
     max_reqs: int
     pages_per_req: int
     desc_programs: int
+    window_group: bool = False
 
     @cached_property
     def fields(self) -> tuple[tuple[str, int, int, tuple[int, ...]], ...]:
@@ -48,6 +53,10 @@ class StepLayout:
         if self.desc_programs:
             shapes += [("attn_desc", (self.desc_programs, 3)),
                        ("decode_list", (R, ))]
+        if self.window_group:
+            shapes += [("window_slot_mapping", (T, )),
+                       ("window_block_tables", (R, self.pages_per_req)),
+                       ("window_kv_runs", (G, 4))]
         out, start = [], 0
         for name, shape in shapes:
             stop = start + int(np.prod(shape))
@@ -62,7 +71,10 @@ class StepLayout:
     @cached_property
     def _blank(self) -> np.ndarray:
         buf = np.zeros((self.size, ), np.int32)
-        self.views(buf)["slot_mapping"][:] = -1   # padding: no KV write
+        views = self.views(buf)
+        views["slot_mapping"][:] = -1   # padding: no KV write
+        if self.window_group:
+            views["window_slot_mapping"][:] = -1
         return buf
 
     def new_buffer(self) -> np.ndarray:
