@@ -67,6 +67,14 @@ def run_both(case, sm_scale=0.125):
     return np.asarray(out_pallas)[:T], np.asarray(out_ref)[:T]
 
 
+def float32_reference(case):
+    """The dense reference on float32 copies of the case's inputs."""
+    want = naive_ragged_attention(
+        *(case[k].astype(jnp.float32) for k in ("q", "k_pages", "v_pages")),
+        case["block_tables"], case["req_idx"], case["q_pos"], sm_scale=0.125)
+    return np.asarray(want)[:case["T"]]
+
+
 @pytest.mark.parametrize("seqs,max_q", [
     # Pure decode: one token per sequence, varying kv lens.
     ([(1, 1), (1, 5), (1, 17), (1, 32)], 1),
@@ -158,6 +166,8 @@ def test_inactive_rows_and_bf16():
     case = build_case(rng, seqs=[(1, 9), (1, 3)], page_size=8,
                       pages_per_req=2, num_q_heads=4, num_kv_heads=2,
                       head_dim=128, max_q=1, dtype=jnp.bfloat16)
-    got, want = run_both(case)
-    np.testing.assert_allclose(np.float32(got), np.float32(want),
+    got, _ = run_both(case)
+    # Against float32 math on the same bf16 inputs: the kernel's own
+    # rounding, not the reference's.
+    np.testing.assert_allclose(np.float32(got), float32_reference(case),
                                rtol=3e-2, atol=3e-2)

@@ -41,6 +41,7 @@ class Geometry(NamedTuple):
     R: int              # max_num_seqs
     pages_per_req: int
     max_tokens: int     # max_num_batched_tokens
+    window: int = 0     # a window layer's bound: the trimmed page loop
 
 
 def bench_config(name: str) -> dict:
@@ -55,7 +56,8 @@ def bench_geometry(name: str, pages: int) -> Geometry:
     assert ea["block_size"] == PS
     heads = cf["num_attention_heads"]
     return Geometry(heads, cf["num_key_value_heads"],
-                    cf["hidden_size"] // heads, cf["num_hidden_layers"],
+                    cf.get("head_dim") or cf["hidden_size"] // heads,
+                    cf["num_hidden_layers"],
                     pages, ea["max_num_seqs"],
                     cdiv(ea["max_model_len"], PS),
                     ea["max_num_batched_tokens"])
@@ -70,7 +72,19 @@ GEOMETRIES = {
     "olmo2-1b": bench_geometry("olmo2-1b", 4992),
     "olmoe-1b-7b": bench_geometry("olmoe-1b-7b", 6912),
 }
-QH, KVH, D, L, NP, R, PAGES_PER_REQ, _ = GEOMETRIES["llama-3.2-1b"]
+# The other two shapes the fused kernel serves, at the largest bucket
+# (their one-layer forwards below hold it at both ends of the lattice):
+# ZAYA's 8 query heads on 2 K/V heads in groups of 8 sequences, and a
+# window layer of Command A+, 128 query heads on 8 K/V heads in tiles of
+# 16 rows and groups of 2, its three window layers' own pool: 128 and
+# 256 score rows a K/V head, where the OLMo geometries above have 32.
+WIDE_GEOMETRIES = {
+    "zaya1-8b": bench_geometry("zaya1-8b", 12032),
+    "command-a-plus-ep8": bench_geometry(
+        "command-a-plus-ep8", 6368)._replace(
+            L=3, window=bench_config("command-a-plus-ep8")["sliding_window"]),
+}
+QH, KVH, D, L, NP, R, PAGES_PER_REQ, _, _ = GEOMETRIES["llama-3.2-1b"]
 # The token buckets the runner serves at the cells' engine limits
 # (worker/model_runner.py: make_buckets(16, max_num_batched_tokens)).
 TOKEN_BUCKETS = make_buckets(16, 2048)
@@ -84,6 +98,9 @@ served_lattice = pytest.mark.parametrize("geo,t_bucket", [
     pytest.param(geo, t, id=f"{name}-{t}")
     for name, geo in GEOMETRIES.items() for t in TOKEN_BUCKETS
     if name != "llama-3.2-1b" or t in (TOKEN_BUCKETS[0], TOKEN_BUCKETS[-1])
+] + [
+    pytest.param(geo, TOKEN_BUCKETS[-1], id=f"{name}-{TOKEN_BUCKETS[-1]}")
+    for name, geo in WIDE_GEOMETRIES.items()
 ])
 
 
@@ -192,10 +209,54 @@ def test_unified_write_attend_compiles(compile_for_chip, geo, t_bucket):
     def fn(q, k, v, kn, vn, desc, si, dl, runs, bt, layer):
         return unified_write_attend_pallas(
             q, k, v, kn, vn, desc, si, dl, runs, bt, layer, sm_scale=0.125,
-            bq=bq, sb=sb, interpret=False)
+            bq=bq, sb=sb, interpret=False, window=geo.window)
 
     compile_for_chip(fn, q, k, v, new, new, sds((P, 3)), si,
                      sds((geo.R, )), sds((G, 4)), bt, layer)
+
+
+@pytest.mark.parametrize("features", [
+    dict(emit_state=True),
+    dict(window=4096, logit_cap=30.0, has_alibi=True, has_sinks=True),
+], ids=["cascade_state", "window_cap_alibi_sinks"])
+def test_prefill_tile_features_compile(compile_for_chip, features):
+    """What no cell runs of the prefill tile, whose queries lie on the
+    lanes (at ZAYA's geometry, 128 score rows a K/V head): the per-head
+    slopes and sinks broadcast along the lanes, the soft cap, and the
+    cascade state stacked down the sublanes and turned with the tile.
+    The state-emitting call has no K/V write; the rest is the fused call
+    a layer makes."""
+    from vllm_distributed_tpu.ops.pallas_attention import (
+        Q_TILE_PAD, num_partition_programs,
+        unified_ragged_paged_attention_pallas, unified_write_attend_pallas)
+    geo, t_bucket = WIDE_GEOMETRIES["zaya1-8b"], 256
+    bq, sb = unified_statics(geo)
+    assert bq * geo.QH // geo.KVH == 128
+    T = t_bucket + Q_TILE_PAD
+    q, k, v, si, bt, layer = attention_shapes(T, geo)
+    dl, feat = sds((geo.R, )), sds((2, geo.QH), jnp.float32)
+    if "emit_state" in features:
+        P = num_partition_programs(t_bucket, geo.R, bq=bq, sb=sb)
+
+        def fn(q, k, v, desc, si, dl, bt, layer):
+            return unified_ragged_paged_attention_pallas(
+                q, k, v, desc, si, dl, bt, layer, sm_scale=0.125, bq=bq,
+                sb=sb, interpret=False, **features)
+
+        compile_for_chip(fn, q, k, v, sds((P, 3)), si, dl, bt, layer)
+        return
+    G = kv_write_runs(T, geo)
+    P = num_partition_programs(t_bucket, geo.R, bq=bq, sb=sb,
+                               num_kv_writes=G)
+    new = sds((geo.KVH, T + 3 * PS, geo.D), jnp.bfloat16)
+
+    def fn(q, k, v, kn, vn, desc, si, dl, runs, bt, layer, feat):
+        return unified_write_attend_pallas(
+            q, k, v, kn, vn, desc, si, dl, runs, bt, layer, feat,
+            sm_scale=0.125, bq=bq, sb=sb, interpret=False, **features)
+
+    compile_for_chip(fn, q, k, v, new, new, sds((P, 3)), si, dl,
+                     sds((G, 4)), bt, layer, feat)
 
 
 @pytest.mark.parametrize("max_q", [1, 32, 256])

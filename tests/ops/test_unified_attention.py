@@ -23,8 +23,10 @@ from vllm_distributed_tpu.ops.pallas_attention import (
 
 
 def build_case(rng, *, seqs, page_size, pages_per_req, num_q_heads,
-               num_kv_heads, head_dim, dtype=jnp.float32):
-    """seqs: list of (q_len, kv_len) with kv_len >= q_len."""
+               num_kv_heads, head_dim, dtype=jnp.float32, bq=None, sb=None):
+    """seqs: list of (q_len, kv_len) with kv_len >= q_len. ``bq`` and
+    ``sb`` default to the model's own tile sizes; a smaller pair traces
+    a smaller interpreted program."""
     R = len(seqs)
     max_reqs = R + 1  # one inactive padding row
     num_pages = max_reqs * pages_per_req
@@ -52,8 +54,8 @@ def build_case(rng, *, seqs, page_size, pages_per_req, num_q_heads,
         q_pos[t:t + q_len] = np.arange(kv_len - q_len, kv_len)
         t += q_len
 
-    bq = prefill_tile_size(num_q_heads, head_dim)
-    sb = decode_group_size(num_q_heads, num_kv_heads)
+    bq = bq or prefill_tile_size(num_q_heads, head_dim)
+    sb = sb or decode_group_size(num_q_heads, num_kv_heads)
     P = num_partition_programs(T, max_reqs, bq=bq, sb=sb)
     desc, dl = build_partition_descriptor(seq_info, R, bq=bq, sb=sb,
                                           num_programs=P)
@@ -137,6 +139,7 @@ def test_many_decode_seqs_ragged_groups():
 
 
 def test_bf16_mixed():
+    """bf16 at the model's own tile and group sizes."""
     rng = np.random.default_rng(3)
     case = build_case(rng, seqs=[(1, 9), (6, 6), (1, 3)], page_size=8,
                       pages_per_req=2, num_q_heads=4, num_kv_heads=2,
@@ -146,7 +149,12 @@ def test_bf16_mixed():
                                rtol=3e-2, atol=3e-2)
 
 
-def test_emit_state_cascade_merge_matches_full_attention():
+@pytest.mark.parametrize("heads,tiles", [
+    ((8, 4), {}),
+    # 128 score rows a K/V head: the tile's lanes full.
+    ((16, 1), dict(bq=8, sb=2)),
+], ids=["rows64", "rows128"])
+def test_emit_state_cascade_merge_matches_full_attention(heads, tiles):
     """The cascade contract: shared-prefix dense phase + mega-kernel
     suffix phase (kv_len shifted, shared slots stripped) merged via the
     exported (m, l) state must equal plain attention over the full KV —
@@ -156,7 +164,8 @@ def test_emit_state_cascade_merge_matches_full_attention():
     page_size, S = 8, 2
     case = build_case(rng, seqs=[(1, 20), (4, 24), (1, 33), (6, 22)],
                       page_size=page_size, pages_per_req=6,
-                      num_q_heads=8, num_kv_heads=4, head_dim=128)
+                      num_q_heads=heads[0], num_kv_heads=heads[1],
+                      head_dim=128, **tiles)
     D = case["k_pages"].shape[-1]
     # Make the first S page-table slots literally shared.
     bt = case["block_tables_np"].copy()
@@ -261,7 +270,7 @@ def test_fused_write_attend_matches_write_then_naive():
 
 
 def run_both_feat(case, sm_scale=0.125, *, window=0, logit_cap=0.0,
-                  slopes=None, sinks=None):
+                  slopes=None, sinks=None, reference=True):
     QH = case["q"].shape[1]
     feat = jnp.stack([
         jnp.asarray(slopes if slopes is not None else np.zeros(QH),
@@ -275,14 +284,23 @@ def run_both_feat(case, sm_scale=0.125, *, window=0, logit_cap=0.0,
         None, feat, sm_scale=sm_scale, bq=case["bq"], sb=case["sb"],
         interpret=True, window=window, logit_cap=logit_cap,
         has_alibi=slopes is not None, has_sinks=sinks is not None)
+    got = np.asarray(out)[:case["T"]]
+    if not reference:
+        return got, None
+    return got, reference_feat(case, sm_scale, window=window,
+                               logit_cap=logit_cap, slopes=slopes,
+                               sinks=sinks)
+
+
+def reference_feat(case, sm_scale=0.125, *, window=0, logit_cap=0.0,
+                   slopes=None, sinks=None):
     want = naive_ragged_attention(
         case["q"], case["k_pages"], case["v_pages"],
         case["block_tables"], case["req_idx"], case["q_pos"],
         sm_scale=sm_scale, window=window, logit_cap=logit_cap,
         alibi_slopes=(tuple(slopes) if slopes is not None else None),
         sinks=(jnp.asarray(sinks) if sinks is not None else None))
-    T = case["T"]
-    return np.asarray(out)[:T], np.asarray(want)[:T]
+    return np.asarray(want)[:case["T"]]
 
 
 MIXED_SEQS = [(1, 13), (12, 12), (1, 30), (5, 21)]
@@ -296,9 +314,9 @@ def test_sliding_window_mixed_wave():
     got, want = run_both_feat(case, window=9)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
     # The window genuinely restricts attention (long kv sequences
-    # diverge from full-causal).
-    got_full, _ = run_both_feat(case)
-    assert np.max(np.abs(got - got_full)) > 1e-3
+    # diverge from full-causal; the reference's, which the cases above
+    # hold the kernel to, so that no second program is traced).
+    assert np.max(np.abs(got - reference_feat(case))) > 1e-3
 
 
 def test_softcap_mixed_wave():
@@ -342,6 +360,64 @@ def test_all_features_together_mixed_wave():
         slopes=np.asarray(alibi_slopes(8)),
         sinks=rng.standard_normal(8).astype(np.float32))
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# A prefill tile whose score rows fill the lanes they lie on (128 a K/V
+# head: 16 query heads on one K/V head under the smallest tile), and bf16
+# Q and pages as every served path passes them. The bf16 cases are held
+# to a float32 reference of the SAME bf16 inputs, so what is measured is
+# the kernel's own rounding (the probabilities and the output) and not
+# the inputs'.
+# ---------------------------------------------------------------------------
+
+BF16_SEQS = [(1, 13), (12, 12), (1, 30), (5, 21)]
+NARROW, WIDE = (4, 2), (16, 1)   # 16 and 128 score rows a K/V head
+BF16_CASES = {
+    "prefill": ([(7, 7), (12, 20)], NARROW, {}),
+    "decode": ([(1, 9), (1, 3), (1, 17)], NARROW, {}),
+    "mixed_wide": (BF16_SEQS, WIDE, {}),
+    "window": (BF16_SEQS, WIDE, dict(window=9)),
+    "logit_cap": (BF16_SEQS, WIDE, dict(logit_cap=5.0)),
+    "alibi": (BF16_SEQS, WIDE,
+              dict(slopes=np.float32(2.0) ** -np.arange(1, 17, dtype=np.float32))),
+    "sinks": (BF16_SEQS, WIDE,
+              dict(sinks=np.linspace(-1.5, 2.0, 16, dtype=np.float32))),
+}
+
+
+def test_all_features_together_at_128_score_rows():
+    """Window, soft cap, ALiBi and sinks at once on a tile of 128 score
+    rows a K/V head, in float32 at the float32 tolerance."""
+    rng = np.random.default_rng(15)
+    qh, kvh = WIDE
+    case = build_case(rng, seqs=BF16_SEQS, page_size=16, pages_per_req=2,
+                      num_q_heads=qh, num_kv_heads=kvh, head_dim=128,
+                      bq=8, sb=2)
+    got, want = run_both_feat(
+        case, window=11, logit_cap=4.0,
+        slopes=np.float32(2.0) ** -np.arange(1, qh + 1, dtype=np.float32),
+        sinks=rng.standard_normal(qh).astype(np.float32))
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("name", list(BF16_CASES))
+def test_bf16_matches_float32_reference(name):
+    seqs, (qh, kvh), feats = BF16_CASES[name]
+    rng = np.random.default_rng(3)
+    # The smallest tile and group: a trace of seconds (``test_bf16_mixed``
+    # and the float32 cases above run the model's own sizes).
+    case = build_case(rng, seqs=seqs, page_size=16, pages_per_req=2,
+                      num_q_heads=qh, num_kv_heads=kvh, head_dim=128,
+                      dtype=jnp.bfloat16, bq=8, sb=2)
+    assert case["q"].dtype == case["k_pages"].dtype == jnp.bfloat16
+    got, _ = run_both_feat(case, reference=False, **feats)
+    assert got.dtype == jnp.bfloat16
+    upcast = dict(case, **{k: case[k].astype(jnp.float32)
+                           for k in ("q", "k_pages", "v_pages")})
+    want = reference_feat(upcast, **feats)
+    np.testing.assert_allclose(np.float32(got), want, rtol=3e-2,
+                               atol=3e-2)
 
 
 # ---------------------------------------------------------------------------

@@ -896,21 +896,32 @@ def _mega_kernel(
                 bq * group, D) for h in range(KVH)
         ]
         rows = bq * group
-        row_pos = (kv_len - q_len + tile_start +
-                   jax.lax.broadcasted_iota(jnp.int32, (rows, blk), 0) //
-                   group)
-        col_base = jax.lax.broadcasted_iota(jnp.int32, (rows, blk), 1)
-        row_valid = (jax.lax.broadcasted_iota(jnp.int32, (rows, blk), 0) //
-                     group + tile_start) < q_len
+        # The scores are formed TRANSPOSED, ``[BLK, rows]`` with the
+        # queries on the lanes: the running max and sum are ``[1, rows]``
+        # (two vregs at 256 rows, where ``[rows, 1]`` takes 32 each with
+        # one lane in 128 used), their reductions run down the sublanes
+        # as elementwise operations instead of one cross-lane reduce a
+        # vreg, and the accumulator is ``[D, rows]``, turned once when
+        # the tile is written. With the keys on the lanes the page loop
+        # was bound by the vector load and store slots, filled by spills
+        # of exactly these carries (PERF.md, Findings PR 44).
+        q_row = jax.lax.broadcasted_iota(jnp.int32, (blk, rows),
+                                         1) // group
+        row_pos = kv_len - q_len + tile_start + q_row
+        col_base = jax.lax.broadcasted_iota(jnp.int32, (blk, rows), 0)
+        row_valid = (q_row + tile_start) < q_len
         feat_val = (feat_ref[...].astype(jnp.float32)
                     if (has_alibi or has_sinks) else None)
-        # Per-row head feature vectors: tile rows are q-row-major then
-        # group (row r belongs to q head h*group + r % group).
+
+        def head_rows(feat_row, h):
+            """A K/V head's entries of a ``[QH]`` feature row along its
+            score rows (q-row-major, then group: row r belongs to q head
+            ``h * group + r % group``), as ``[1, rows]``."""
+            return jnp.tile(feat_val[feat_row, h * group:(h + 1) * group],
+                            (bq, ))[None, :]
+
         if has_alibi:
-            slopes_rows = [
-                jnp.tile(feat_val[0, h * group:(h + 1) * group],
-                         (bq, ))[:, None] for h in range(KVH)
-            ]
+            slopes_rows = [head_rows(0, h) for h in range(KVH)]
 
         def body(bi, carry):
             ms, ls, accs = carry
@@ -939,9 +950,9 @@ def _mega_kernel(
             new_ms, new_ls, new_accs = [], [], []
             for h in range(KVH):
                 s = jax.lax.dot_general(
-                    q_heads[h], k_blk[h].astype(jnp.float32),
+                    k_blk[h].astype(jnp.float32), q_heads[h],
                     dimension_numbers=(((1, ), (1, )), ((), ())),
-                    preferred_element_type=jnp.float32)
+                    preferred_element_type=jnp.float32)  # [BLK, rows]
                 if logit_cap > 0:
                     s = logit_cap * jnp.tanh(s / logit_cap)
                 if has_alibi:
@@ -949,13 +960,14 @@ def _mega_kernel(
                         kv_pos - row_pos).astype(jnp.float32)
                 s = jnp.where(mask, s, _MASK_VALUE)
                 m_prev, l_prev, acc_prev = ms[h], ls[h], accs[h]
-                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+                m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
                 pr = jnp.exp(s - m_new)
                 alpha = jnp.exp(m_prev - m_new)
-                l_new = l_prev * alpha + pr.sum(axis=-1, keepdims=True)
+                l_new = l_prev * alpha + pr.sum(axis=0, keepdims=True)
+                # [BLK, D] x [BLK, rows] -> [D, rows]: the keys contract.
                 pv = jax.lax.dot_general(
-                    pr.astype(v_blk.dtype), v_blk[h],
-                    dimension_numbers=(((1, ), (0, )), ((), ())),
+                    v_blk[h], pr.astype(v_blk.dtype),
+                    dimension_numbers=(((0, ), (0, )), ((), ())),
                     preferred_element_type=jnp.float32)
                 new_ms.append(m_new)
                 new_ls.append(l_new)
@@ -963,10 +975,10 @@ def _mega_kernel(
             return tuple(new_ms), tuple(new_ls), tuple(new_accs)
 
         init = (
-            tuple(jnp.full((rows, 1), _MASK_VALUE, jnp.float32)
+            tuple(jnp.full((1, rows), _MASK_VALUE, jnp.float32)
                   for _ in range(KVH)),
-            tuple(jnp.zeros((rows, 1), jnp.float32) for _ in range(KVH)),
-            tuple(jnp.zeros((rows, D), jnp.float32) for _ in range(KVH)),
+            tuple(jnp.zeros((1, rows), jnp.float32) for _ in range(KVH)),
+            tuple(jnp.zeros((D, rows), jnp.float32) for _ in range(KVH)),
         )
         ms, ls, accs = jax.lax.fori_loop(first_block, num_blocks, body,
                                          init)
@@ -975,20 +987,17 @@ def _mega_kernel(
             # Learned per-head virtual key joining only the softmax
             # denominator (softmax shift-invariance makes the running
             # max of the REAL scores a valid reference point).
-            ls = tuple(
-                ls[h] + jnp.exp(
-                    jnp.tile(feat_val[1, h * group:(h + 1) * group],
-                             (bq, ))[:, None] - ms[h])
-                for h in range(KVH))
+            ls = tuple(ls[h] + jnp.exp(head_rows(1, h) - ms[h])
+                       for h in range(KVH))
         for h in range(KVH):
-            o_h = accs[h] / jnp.maximum(ls[h], 1e-20)
+            o_h = (accs[h] / jnp.maximum(ls[h], 1e-20)).T  # [rows, D]
             out_stage[0:bq, h * group:(h + 1) * group, :] = (
                 o_h.reshape(bq, group, D).astype(out_stage.dtype))
             if emit_state:
                 st = jnp.concatenate([
-                    jnp.broadcast_to(ms[h], (rows, half)),
-                    jnp.broadcast_to(ls[h], (rows, D - half)),
-                ], axis=-1)
+                    jnp.broadcast_to(ms[h], (half, rows)),
+                    jnp.broadcast_to(ls[h], (D - half, rows)),
+                ], axis=0).T
                 state_stage[0:bq, h * group:(h + 1) * group, :] = (
                     st.reshape(bq, group, D))
 
