@@ -146,20 +146,115 @@ def test_seeded_sampling_reproducible(checkpoint):
     assert a != c  # overwhelmingly likely
 
 
-def test_pallas_backend_e2e(checkpoint, monkeypatch):
+# What an interpreted kernel costs is its lowering, once a token bucket,
+# and that grows with the pages in a 128-position kv block
+# (tests/ops/test_unified_attention.py's docstring): 16 under this file's
+# 4-token pages and 64-token contexts, a minute a program. Pages of 8 in
+# a context of 32 make it 4, and the tokens do not depend on the page
+# size. Other files' Pallas engines take the same geometry.
+PALLAS_GEOMETRY = dict(block_size=8, num_gpu_blocks_override=64,
+                       max_model_len=32)
+
+
+@pytest.fixture(scope="module")
+def pallas_engine(checkpoint):
+    """One engine for the tests below: each would lower the same
+    programs again. The backend is read when a program is traced, so
+    each test sets it for its own steps."""
+    path, _ = checkpoint
+    return make_engine(path, max_num_batched_tokens=16, **PALLAS_GEOMETRY)
+
+
+@pytest.mark.parametrize("prompts", [
+    [[3, 17, 92, 45, 8]],
+    [[3, 17, 92, 45, 8], list(range(2, 25))],  # 23 tokens -> 2 chunks
+], ids=["single", "chunked"])
+def test_pallas_backend_e2e(checkpoint, pallas_engine, monkeypatch, prompts):
     """Full engine stack through the Pallas kernel (interpret mode on CPU):
-    chunked prefill + decode must match HF greedy exactly."""
+    one prompt alone, and chunked prefill + decode, must match HF greedy
+    exactly."""
     monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
-    path, hf = checkpoint
-    engine = make_engine(path, max_num_batched_tokens=16)
-    prompts = [[3, 17, 92, 45, 8],
-               list(range(2, 25))]  # 23 tokens -> 2 prefill chunks
+    _, hf = checkpoint
     sps = [SamplingParams(temperature=0.0, max_tokens=5, ignore_eos=True)
            for _ in prompts]
-    outs = run_engine(engine, prompts, sps)
+    outs = run_engine(pallas_engine, prompts, sps)
     for prompt, out in zip(prompts, outs):
         assert out.outputs[0].token_ids == hf_greedy(hf, prompt, 5), \
             f"pallas mismatch for prompt {prompt}"
+
+
+def test_mixed_wave_dispatches_unified_kernel(pallas_engine, monkeypatch):
+    """Acceptance: mixed prefill+decode waves dispatch to the unified
+    mega-kernel, asserted via vdt:attn_kernel_calls_total through the
+    full stats path (interpret-mode Pallas backend on CPU)."""
+    from vllm_distributed_tpu.metrics.prometheus import render_metrics
+    monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
+    engine = pallas_engine
+    before = engine.get_stats()["attn_kernel_calls"].get("unified", 0)
+    rng = np.random.default_rng(12)
+    for i in range(2):
+        engine.add_request(
+            f"uk{i}", [int(x) for x in rng.integers(2, 127, size=3)],
+            SamplingParams(temperature=0.0, max_tokens=10,
+                           ignore_eos=True))
+    for _ in range(3):
+        engine.step()
+    engine.add_request(
+        "uk-long", [int(x) for x in rng.integers(2, 127, size=24)],
+        SamplingParams(temperature=0.0, max_tokens=3, ignore_eos=True))
+    for _ in range(200):
+        engine.step()
+        if not engine.has_unfinished_requests():
+            break
+    assert not engine.has_unfinished_requests()
+    stats = engine.get_stats()
+    calls = stats["attn_kernel_calls"]
+    # Every step (decode-only, prefill-only, and the mixed waves) rides
+    # the ONE unified kernel; no step, of this test or of those that
+    # used the engine before it, fell back to the per-composition legacy
+    # kernels.
+    assert calls.get("unified", 0) > before
+    assert "general" not in calls and "decode" not in calls
+    text = render_metrics(stats)
+    assert 'vdt:attn_kernel_calls_total{kernel="unified"}' in text
+
+
+def test_cascade_end_to_end_pallas_backend(checkpoint, pallas_engine,
+                                           monkeypatch):
+    """The shared-prefix trigger of ``tests/ops/test_cascade_attention.py``
+    on the Pallas backend (interpret mode): the suffix runs the kernel
+    with emit_state=True and merges with the dense shared phase (VERDICT
+    r3 weak #4 — cascade previously bailed whenever backend == pallas).
+    The engine with the cascade is this test's own, with
+    ``pallas_engine``'s arguments (the flags are read as it runs); the
+    one without is ``pallas_engine``."""
+    monkeypatch.setenv("VDT_CASCADE_ATTENTION", "1")
+    monkeypatch.setenv("VDT_CASCADE_SHARED_PAGES", "2")
+    monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
+    monkeypatch.setenv("VDT_PALLAS_INTERPRET", "1")
+    path, _ = checkpoint
+    prefix = list(range(3, 19))  # 2 full size-8 pages
+    prompts = [prefix + [50 + i] for i in range(3)]
+    sps = [SamplingParams(temperature=0.0, max_tokens=5, ignore_eos=True)
+           for _ in prompts]
+
+    def run(engine):
+        return [out.outputs[0].token_ids
+                for out in run_engine(engine, prompts, sps)]
+
+    cascade_engine = make_engine(path, max_num_batched_tokens=16,
+                                 **PALLAS_GEOMETRY)
+    got = run(cascade_engine)
+    runner = cascade_engine.engine_core.executor.worker.model_runner
+    assert runner.cascade_steps > 0, "cascade never triggered on pallas"
+
+    monkeypatch.setenv("VDT_CASCADE_ATTENTION", "0")
+    before = pallas_engine.engine_core.executor.worker.model_runner \
+        .cascade_steps
+    want = run(pallas_engine)
+    assert pallas_engine.engine_core.executor.worker.model_runner \
+        .cascade_steps == before
+    assert got == want
 
 
 def test_multi_step_decode_matches_hf(checkpoint):

@@ -221,7 +221,7 @@ def test_scheduler_broadcast_executor(tmp_path, transport):
     outs = []
     for p in procs:
         try:
-            out, _ = p.communicate(timeout=420)
+            out, _ = p.communicate(timeout=240)
         except subprocess.TimeoutExpired:
             for q in procs:
                 q.kill()
@@ -236,7 +236,10 @@ def test_scheduler_broadcast_executor(tmp_path, transport):
     assert driver_line and "mh-0" in driver_line[0]
 
 
-def _run_spmd(n_hosts, dev_per_host, tp, pp, timeout=600):
+# A wait is bounded by five times what the slowest of these tests took
+# beside five loaded workers (46 s, PR 45's profile of its parent): a hang
+# fails its test and leaves the suite's clock to the others.
+def _run_spmd(n_hosts, dev_per_host, tp, pp, timeout=240):
     _require_multiprocess_cpu()
     port = get_open_port()
     procs = [

@@ -7,6 +7,7 @@ import torch
 from transformers import LlamaConfig
 from transformers import LlamaForCausalLM as HFLlama
 
+from tests.engine.test_llm_engine import PALLAS_GEOMETRY
 from vllm_distributed_tpu.engine.arg_utils import EngineArgs
 from vllm_distributed_tpu.engine.llm_engine import LLMEngine
 from vllm_distributed_tpu.sampling_params import SamplingParams
@@ -93,9 +94,15 @@ def test_pp3_uneven_layers_matches_baseline(checkpoint, baseline):
 
 
 def test_pp2_pallas_matches_baseline(checkpoint, baseline, monkeypatch):
+    """An interpreted kernel is lowered once a stage and token bucket:
+    ``PALLAS_GEOMETRY``'s pages (a sequence of 13-15 tokens still spans
+    two), and a budget of 16 tokens a step, which keeps the 15 prompt
+    tokens and the decode steps in one bucket (two steps of prefill, the
+    second beside a decode row)."""
     monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
     got = run(make_engine(checkpoint, pipeline_parallel_size=2,
-                          max_num_batched_tokens=32), PROMPTS, "pp2pl")
+                          max_num_batched_tokens=16, **PALLAS_GEOMETRY),
+              PROMPTS, "pp2pl")
     assert got == baseline
 
 

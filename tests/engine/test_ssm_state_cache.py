@@ -8,6 +8,13 @@ state rows and attention KV pages coherently); preempt-park-resume must
 match the no-preempt run; journal replay of a stateful request must
 resume from the last checkpoint, re-prefilling at most
 ``VDT_SSM_CKPT_INTERVAL`` tokens instead of O(prompt).
+
+At the end, the same through Ling-3.0's two kinds of cache for one
+request (state rows beside latent pages; ``models/bailing.py``) at a toy
+width. An engine built is what a case here costs (its forward programs
+compiled again: JAX caches by function object), so what two tests would
+compute alike (the cache-off session, the baseline of the crash drills,
+Ling's engine with room for all) is a module's fixture.
 """
 
 import asyncio
@@ -107,17 +114,26 @@ def _ssm_stats(engine):
             if k.startswith("ssm_")}
 
 
+@pytest.fixture(scope="module")
+def mamba_off_session(mamba_ckpt):
+    """The three-turn session on the cache-off engine with
+    ``_make_engine``'s own arguments: its outputs, and the engine."""
+    with pytest.MonkeyPatch.context() as mp:
+        engine = _make_engine(mamba_ckpt, mp, cache_on=False)
+        return _run_session(engine, "off"), engine
+
+
 # ---------------------------------------------------------------------------
 # Greedy parity matrix
 # ---------------------------------------------------------------------------
-def test_mamba_multi_turn_parity_and_hits(mamba_ckpt, monkeypatch):
+def test_mamba_multi_turn_parity_and_hits(mamba_ckpt, mamba_off_session,
+                                          monkeypatch):
     """Turn N+1 resumes from turn N's snapshot; greedy outputs are
     token-identical to the cache-off engine on identical traffic."""
     on_engine = _make_engine(mamba_ckpt, monkeypatch, cache_on=True)
     on = _run_session(on_engine, "on")
     stats = _ssm_stats(on_engine)
-    off_engine = _make_engine(mamba_ckpt, monkeypatch, cache_on=False)
-    off = _run_session(off_engine, "off")
+    off, off_engine = mamba_off_session
     assert on == off
     assert stats["ssm_state_cache_hits"] >= 2, stats
     assert stats["ssm_resume_tokens_saved"] > 0, stats
@@ -193,7 +209,8 @@ def test_jamba_preempt_park_resume_parity(jamba_ckpt, monkeypatch):
     assert stats["ssm_resume_tokens_saved"] > 0, stats
 
 
-def test_mamba_async_scheduling_parity(mamba_ckpt, monkeypatch):
+def test_mamba_async_scheduling_parity(mamba_ckpt, mamba_off_session,
+                                       monkeypatch):
     """Async run-ahead grants snapshot at speculative boundaries whose
     key resolves at commit; a stop before the boundary discards the
     snapshot. Greedy outputs must still match the sync cache-off run."""
@@ -201,8 +218,7 @@ def test_mamba_async_scheduling_parity(mamba_ckpt, monkeypatch):
                              async_scheduling=True)
     on = _run_session(on_engine, "on")
     stats = _ssm_stats(on_engine)
-    off_engine = _make_engine(mamba_ckpt, monkeypatch, cache_on=False)
-    off = _run_session(off_engine, "off")
+    off, _ = mamba_off_session
     assert on == off
     assert stats["ssm_state_cache_hits"] >= 1, stats
 
@@ -240,20 +256,26 @@ async def _collect(engine, rid, die_after=False):
     return final.outputs[0].token_ids
 
 
-def test_replay_resumes_from_checkpoint(mamba_ckpt, monkeypatch,
-                                        tmp_path):
+@pytest.fixture(scope="module")
+def crash_baseline(mamba_ckpt, tmp_path_factory):
+    """The prompt's 20 tokens from an engine that is never killed."""
+    with pytest.MonkeyPatch.context() as mp:
+        base = _make_async_engine(mamba_ckpt, mp,
+                                  tmp_path_factory.mktemp("base"))
+        try:
+            return asyncio.run(asyncio.wait_for(
+                _collect(base, "base-0"), timeout=120))
+        finally:
+            base.shutdown()
+
+
+def test_replay_resumes_from_checkpoint(mamba_ckpt, crash_baseline,
+                                        monkeypatch, tmp_path):
     """Kill the core mid-decode: the journaled request replays into the
     respawned core, which resumes from the last host checkpoint — the
     replayed prefill is bounded by VDT_SSM_CKPT_INTERVAL (8), not the
     40-token prompt — and the stream stays token-identical."""
-    base = _make_async_engine(mamba_ckpt, monkeypatch,
-                              tmp_path / "base")
-    try:
-        baseline = asyncio.run(asyncio.wait_for(
-            _collect(base, "base-0"), timeout=120))
-    finally:
-        base.shutdown()
-
+    baseline = crash_baseline
     engine = _make_async_engine(mamba_ckpt, monkeypatch,
                                 tmp_path / "rec")
     try:
@@ -274,19 +296,12 @@ def test_replay_resumes_from_checkpoint(mamba_ckpt, monkeypatch,
         engine.shutdown()
 
 
-def test_restore_corrupt_degrades_to_reprefill(mamba_ckpt, monkeypatch,
-                                               tmp_path):
+def test_restore_corrupt_degrades_to_reprefill(mamba_ckpt, crash_baseline,
+                                               monkeypatch, tmp_path):
     """ssm.restore_corrupt simulates a checksum mismatch on every
     journal read: recovery must degrade to a full re-prefill (counted)
     and stay token-identical."""
-    base = _make_async_engine(mamba_ckpt, monkeypatch,
-                              tmp_path / "base")
-    try:
-        baseline = asyncio.run(asyncio.wait_for(
-            _collect(base, "base-0"), timeout=120))
-    finally:
-        base.shutdown()
-
+    baseline = crash_baseline
     engine = _make_async_engine(mamba_ckpt, monkeypatch,
                                 tmp_path / "rec")
     try:
@@ -559,3 +574,148 @@ def test_dp_merge_sums_ssm_counters():
     assert "vdt:ssm_state_cache_hits_total 5.0" in text
     assert "vdt:ssm_checkpoints_total 10.0" in text
     assert "vdt:ssm_state_bytes_held 150.0" in text
+
+
+# ---------------------------------------------------------------------------
+# State rows beside latent pages (Ling-3.0's two kinds of cache for one
+# request; ``models/bailing.py``, ``core/state_cache.py``) through the
+# scheduler, the KV manager and the runner at a toy width: a preempted
+# request parks its row and resumes to the logits of an uninterrupted
+# run; a snapshot taken at a chunk boundary restores the state, and only
+# while the latent pages of its prefix are resident.
+# ---------------------------------------------------------------------------
+LING_DATA = os.path.join(os.path.dirname(__file__), "..", "benchmarking",
+                         "data")
+
+
+@pytest.fixture(scope="module")
+def ling_dir(tmp_path_factory):
+    from benchmarks import run
+    cell = run.load_cell("tiny-ling.tiny-decode", LING_DATA)
+    work = run.WORK
+    run.WORK = str(tmp_path_factory.mktemp("kda-rows"))
+    try:
+        return run.write_model_dir(cell["config"], cell["config_file"])
+    finally:
+        run.WORK = work
+
+
+def _make_ling_engine(model_dir, monkeypatch, cache_on=True, **over):
+    return _make_engine(model_dir, monkeypatch, cache_on=cache_on,
+                        interval=16, load_format="dummy", seed=5,
+                        block_size=16, max_model_len=128, max_num_seqs=4,
+                        **over)
+
+
+@pytest.fixture(scope="module")
+def ling_engine(ling_dir):
+    """Ling's engine with ``_make_ling_engine``'s own arguments (a pool
+    of 128 pages, the state cache on), for the tests below in turn: they
+    serve prompts that share no page, and read counters as differences."""
+    with pytest.MonkeyPatch.context() as mp:
+        return _make_ling_engine(ling_dir, mp)
+
+
+def _drain_logprobs(engine, max_steps=600):
+    """{request: (tokens, the chosen tokens' logprobs)}."""
+    done = {}
+    for _ in range(max_steps):
+        for out in engine.step():
+            if out.finished:
+                c = out.outputs[0]
+                done[out.request_id] = (
+                    list(c.token_ids),
+                    [d[t] for t, d in zip(c.token_ids, c.logprobs)])
+        if not engine.has_unfinished_requests():
+            break
+    return done
+
+
+def _close(a, b, tol=2e-4):
+    return len(a) == len(b) and all(abs(x - y) < tol for x, y in zip(a, b))
+
+
+def test_preempted_request_parks_its_row_and_resumes(ling_dir, ling_engine,
+                                                     monkeypatch):
+    """Four requests of 24 + 40 tokens against a pool of 15 pages of 16
+    (a request needs 4, so the last to want its fourth page is
+    preempted while the others hold theirs to the end): its row is
+    parked in the snapshot pool, the pages of its prefix stay in the
+    prefix cache, and it resumes as a continuation. Tokens and logprobs
+    equal those of a run that was never interrupted (a pool of 128
+    pages). The engine of its own differs by
+    ``num_gpu_blocks_override=15``."""
+    sp = SamplingParams(temperature=0.0, max_tokens=40, ignore_eos=True,
+                        logprobs=1)
+    prompts = [[(i * 37 + j * 11 + 3) % 500 for j in range(24)]
+               for i in range(4)]
+
+    def go(engine):
+        before = int(engine.get_stats()["num_preemptions"])
+        for i, p in enumerate(prompts):
+            engine.add_request(f"r-{i}", p, sp)
+        done = _drain_logprobs(engine)
+        stats = engine.get_stats()
+        free = engine.engine_core.engine_core.scheduler \
+            .kv_cache_manager.get_num_free_blocks()
+        return (done, int(stats["num_preemptions"]) - before,
+                _ssm_stats(engine), free)
+
+    calm, none, _, _ = go(ling_engine)
+    tight, preempted, stats, free = go(_make_ling_engine(
+        ling_dir, monkeypatch, num_gpu_blocks_override=15))
+    assert none == 0 and preempted > 0
+    assert free == 15                       # nothing leaked
+    for rid, (tokens, lps) in calm.items():
+        assert tight[rid][0] == tokens, rid
+        assert _close(tight[rid][1], lps), rid
+    assert stats["ssm_state_cache_hits"] >= 1, stats
+    assert stats["ssm_resume_tokens_saved"] > 0, stats
+
+
+def test_chunk_boundary_snapshot_needs_the_prefix_pages(ling_dir,
+                                                        ling_engine,
+                                                        monkeypatch):
+    """A second turn whose prompt extends the first restores the state
+    row from the snapshot at the last chunk boundary AND reuses the
+    latent pages of that prefix. With the pages dropped from the prefix
+    cache the snapshot alone is no resume point: no hit, and the whole
+    prompt is prefilled again (the same second turn once more on the
+    same engine: the snapshots are all still there, the pages are not).
+    The logits are the same every way. The engine with the state cache
+    off is its own."""
+    sp = SamplingParams(temperature=0.0, max_tokens=6, ignore_eos=True,
+                        logprobs=1)
+    first = [(j * 7 + 3) % 500 for j in range(70)]
+
+    def second_turn(engine, tag, drop_pages=False):
+        before = _ssm_stats(engine)
+        sched = engine.engine_core.engine_core.scheduler
+        if drop_pages:
+            assert sched.kv_cache_manager.reset_prefix_cache()
+        engine.add_request(f"{tag}-1", first + tokens + [17, 18, 19], sp)
+        return (_drain_logprobs(engine)[f"{tag}-1"], before,
+                _ssm_stats(engine), sched)
+
+    with monkeypatch.context() as off:    # the runner reads it later too
+        engine = _make_ling_engine(ling_dir, off, cache_on=False)
+        engine.add_request("plain-0", list(first), sp)
+        tokens, _ = _drain_logprobs(engine)["plain-0"]
+        plain, _, _, _ = second_turn(engine, "plain")
+    ling_engine.add_request("hit-0", list(first), sp)
+    assert _drain_logprobs(ling_engine)["hit-0"][0] == tokens
+    hit, before, after, sched = second_turn(ling_engine, "hit")
+    assert sched.kv_cache_manager.enable_caching     # forced on: hybrid
+    assert sched.state_cache.paged_kv
+    assert after["ssm_state_cache_hits"] == \
+        before.get("ssm_state_cache_hits", 0) + 1, (before, after)
+    # 64 of the 79 prompt tokens were not computed again.
+    assert after["ssm_resume_tokens_saved"] >= \
+        before.get("ssm_resume_tokens_saved", 0) + 64
+    miss, before, after, _ = second_turn(ling_engine, "miss",
+                                         drop_pages=True)
+    assert after["ssm_state_cache_hits"] == \
+        before.get("ssm_state_cache_hits", 0), (before, after)
+    for got in (hit, miss):
+        assert got[0] == plain[0]
+        assert _close(got[1], plain[1])

@@ -13,6 +13,7 @@ import torch
 from transformers import LlamaConfig
 from transformers import LlamaForCausalLM as HFLlama
 
+from tests.engine.test_llm_engine import PALLAS_GEOMETRY
 from vllm_distributed_tpu.engine.arg_utils import EngineArgs
 from vllm_distributed_tpu.engine.llm_engine import LLMEngine
 from vllm_distributed_tpu.sampling_params import SamplingParams
@@ -90,10 +91,15 @@ def test_tknp4_matches_baseline(checkpoint, baseline):
 
 def test_tknp2_pallas_matches_baseline(checkpoint, baseline, monkeypatch):
     """Token parallelism through the Pallas kernels (interpret mode):
-    per-rank seq lists + local page tables + the in-place KV-write runs."""
+    per-rank seq lists + local page tables + the in-place KV-write runs.
+    An interpreted kernel is lowered once a token bucket:
+    ``PALLAS_GEOMETRY``'s pages (a sequence of 13-15 tokens still spans
+    two of its rank's partition), and a budget of 16 tokens a step, which
+    keeps the 22 prompt tokens and the decode steps in one bucket."""
     monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
     got = run(make_engine(checkpoint, token_parallel_size=2,
-                          max_num_batched_tokens=32), PROMPTS, "tknp2pl")
+                          max_num_batched_tokens=16, **PALLAS_GEOMETRY),
+              PROMPTS, "tknp2pl")
     assert got == baseline
 
 

@@ -366,15 +366,23 @@ def check_the_program_is_the_same_in_every_process(_monkeypatch=None):
     import subprocess
     import sys
     here = os.path.dirname(os.path.abspath(__file__))
+    # Both at once: each is a start of Python and JAX and one lowering,
+    # about 20 s alone (300 s bounds a hang).
+    procs = [subprocess.Popen(
+        [sys.executable, "-c", HASH_SCRIPT.format(here=here)],
+        env=dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu",
+                 VDT_PALLAS_INTERPRET="1"),
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        for seed in ("1", "2")]
     digests = set()
-    for seed in ("1", "2"):
-        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu",
-                   VDT_PALLAS_INTERPRET="1")
-        p = subprocess.run(
-            [sys.executable, "-c", HASH_SCRIPT.format(here=here)], env=env,
-            capture_output=True, text=True, timeout=600)
-        assert p.returncode == 0, p.stderr[-2000:]
-        digests.add(p.stdout.split()[-1])
+    try:
+        for p in procs:
+            out, err = p.communicate(timeout=300)
+            assert p.returncode == 0, err[-2000:]
+            digests.add(out.split()[-1])
+    finally:
+        for p in procs:
+            p.kill()
     assert len(digests) == 1
 
 

@@ -13,8 +13,12 @@ import pytest
 from benchmarks import run
 from benchmarks.references import cohere_moe as reference
 # The engine, its steps and the prompts as the other models' tests make
-# them (float32, 4 rows, a 64-token budget, seed 5).
-from test_granite_hybrid import drain, make_engine, prompt, served
+# them (float32, 4 rows, a 64-token budget, seed 5), and one engine a
+# module for the tests that want those arguments (that file's docstring
+# says what an engine costs and what sharing one asks).
+from test_granite_hybrid import engine  # noqa: F401 (this module's own)
+from test_granite_hybrid import (jit_logprobs, make_engine, prompt,
+                                 runner_of, serve, served)
 from vllm_distributed_tpu import SamplingParams
 
 DATA = os.path.join(os.path.dirname(__file__), "..", "benchmarking", "data")
@@ -38,10 +42,6 @@ def model_dir(tiny, tmp_path_factory):
         run.WORK = work
 
 
-def runner_of(engine):
-    return engine.engine_core.engine_core.executor.worker.model_runner
-
-
 def manager_of(engine):
     return engine.engine_core.engine_core.scheduler.kv_cache_manager
 
@@ -49,13 +49,8 @@ def manager_of(engine):
 def reference_logprobs(engine, cfg, tokens, control=None, params=None):
     """The reference's logprobs of ``tokens[1:]`` on the engine's own
     seeded weights."""
-    import jax
-    import jax.numpy as jnp
-    with jax.default_matmul_precision("highest"):
-        table = reference.logprobs(
-            params or runner_of(engine).params, cfg,
-            jnp.asarray(tokens, jnp.int32), control)
-    return np.asarray(table)[np.arange(len(tokens) - 1), tokens[1:]]
+    return jit_logprobs(reference, params or runner_of(engine).params, cfg,
+                        tokens, control)
 
 
 @pytest.mark.parametrize("budget", [64, 16, 5])
@@ -65,7 +60,9 @@ def test_chunked_prefill_then_decode_matches_the_reference(
     long) in one batch, the prompt in chunks of ``budget`` tokens (one
     pass, multiples of the page, chunks that straddle pages and windows),
     then twelve decode steps: every logprob equals the reference's one
-    full forward, and the window group handed pages back on the way."""
+    full forward, and the window group handed pages back on the way.
+    An engine of its own each, for its counters from zero (the budget of
+    64 is ``make_engine``'s own, 16 and 5 are not)."""
     got, engine = served(model_dir, monkeypatch,
                          [prompt(70), prompt(37, 1), prompt(5, 2)], 12,
                          max_num_batched_tokens=budget,
@@ -86,11 +83,10 @@ def test_chunked_prefill_then_decode_matches_the_reference(
 
 
 @pytest.fixture(scope="module")
-def served_once(model_dir, tiny):
+def served_once(engine, tiny):
     """One served request of seven windows, its engine and the
     reference's logprobs."""
-    with pytest.MonkeyPatch.context() as mp:
-        (got, ), engine = served(model_dir, mp, [prompt(58)], 6)
+    (got, ) = serve(engine, [prompt(58)], 6)
     return got, engine, reference_logprobs(engine, run.hf_config(tiny),
                                            got[0])
 
@@ -175,16 +171,18 @@ def test_the_program_s_share_is_the_reference_s(served_once, tiny):
 
 
 def test_preemption_and_resume_give_the_uninterrupted_logits(
-        model_dir, monkeypatch, tiny):
+        engine, model_dir, monkeypatch, tiny):
     """Four requests of 40 + 30 tokens against a full group of 13 pages:
     one is preempted and prefills again from token 0 through both
-    groups; same tokens and logprobs as with room for all."""
-    prompts = [prompt(40, i) for i in range(4)]
-    calm, _ = served(model_dir, monkeypatch, prompts, 30, None)
-    tight, engine = served(model_dir, monkeypatch, prompts, 30, None,
-                           num_gpu_blocks_override=13)
-    assert int(engine.get_stats()["num_preemptions"]) > 0
-    mgr = manager_of(engine)
+    groups; same tokens and logprobs as with room for all. The engine of
+    its own differs by ``num_gpu_blocks_override=13``; the prompts are
+    no other test's."""
+    prompts = [prompt(40, 10 + i) for i in range(4)]
+    calm = serve(engine, prompts, 30, None)
+    tight, small = served(model_dir, monkeypatch, prompts, 30, None,
+                          num_gpu_blocks_override=13)
+    assert int(small.get_stats()["num_preemptions"]) > 0
+    mgr = manager_of(small)
     assert mgr.full_group.get_num_free_blocks() == 13
     assert mgr.window_group.get_num_free_blocks() == \
         mgr.window_group.block_pool.num_blocks
@@ -198,7 +196,8 @@ def test_caches_and_tables_by_group(model_dir, monkeypatch, tiny):
     the sized pool, three window layers' from the window group's fixed
     pool); a request past its window holds every page in the full group
     and a window's worth in the other, and the table the kernel gets
-    carries no freed page."""
+    carries no freed page. An engine of its own: ``max_num_seqs=2``
+    sizes the window group's pool."""
     from vllm_distributed_tpu.core.kv_cache_utils import \
         window_group_pool_blocks
     engine = make_engine(model_dir, monkeypatch, max_num_seqs=2)

@@ -107,9 +107,18 @@ def run(engine, prompts, tag, max_tokens=6):
     return [done[k].outputs[0].token_ids for k in order]
 
 
-def test_v2_greedy_matches_hf(v2_checkpoint):
+@pytest.fixture(scope="module")
+def v2_engine(v2_checkpoint):
+    """The V2 checkpoint's engine with ``make_engine``'s own arguments,
+    for the tests that want no other (an engine built compiles its
+    programs again); each serves prompts that share no page with
+    another's."""
+    return make_engine(v2_checkpoint[0])
+
+
+def test_v2_greedy_matches_hf(v2_checkpoint, v2_engine):
     path, hf = v2_checkpoint
-    got = run(make_engine(path), PROMPTS, "ds")
+    got = run(v2_engine, PROMPTS, "ds")
     want = [hf_greedy(hf, p, 6) for p in PROMPTS]
     assert got == want
 
@@ -149,10 +158,10 @@ def test_v2_expert_parallel_matches_hf(v2_checkpoint):
     assert got == want
 
 
-def test_v2_prefill_logprobs_match_hf(v2_checkpoint):
+def test_v2_prefill_logprobs_match_hf(v2_checkpoint, v2_engine):
     path, hf = v2_checkpoint
-    engine = make_engine(path)
-    prompt = PROMPTS[0]
+    engine = v2_engine
+    prompt = PROMPTS[0][::-1]      # no cached page of the test above
     k = 5
     engine.add_request("lg-0", prompt,
                        SamplingParams(temperature=0.0, max_tokens=1,
@@ -173,11 +182,10 @@ def test_v2_prefill_logprobs_match_hf(v2_checkpoint):
         assert abs(got[tok] - val) < 5e-3, (tok, got[tok], val)
 
 
-def test_latent_cache_is_an_order_smaller(v2_checkpoint):
+def test_latent_cache_is_an_order_smaller(v2_engine):
     """The point of MLA: page bytes store Lkv+R per token instead of
     2 * heads * head_dim per layer."""
-    path, _ = v2_checkpoint
-    engine = make_engine(path)
+    engine = v2_engine
     runner = (engine.engine_core.engine_core.executor
               .worker.model_runner)
     model = runner.model

@@ -3,8 +3,28 @@ on the CPU: the checkpoint's names against transformers' own model, the
 engine's steps against the plain reference
 (``benchmarks/references/granite_hybrid.py``), state rows beside packed
 K/V pages through preemption and a snapshot, what each multiplier, the
-scale and the missing rotation do, and the refusals."""
+scale and the missing rotation do, and the refusals.
 
+**What a case costs.** Not its steps: an ``LLMEngine`` built (the
+weights seeded, the pool sized) and then its forward programs compiled,
+two or three of them at 4-8 s each, because JAX caches a compiled program
+by the function object and every engine closes over new ones. A test
+that calls ``make_engine`` for itself pays 25-40 s before its first
+assertion; a file of such tests compiled the same programs fifteen
+times. So tests that want the same ``EngineArgs`` and flags take the
+module's ``engine`` fixture (``make_engine``'s own arguments) or another
+module-scoped one (``engine_16``), add their requests with ``serve``
+and leave it drained; what one leaves behind (cached prefix pages,
+snapshots, counters) the next must not read, so a test that serves
+without prompt logprobs uses prompts of its own (``prompt(n, k)`` with a
+``k`` no other test has) and counters are read as differences. A test
+whose point is another budget, pool, flag or checkpoint keeps its own
+engine and says which argument makes it differ. The float32 reference
+goes through ``jit_logprobs``: one program a sequence length where
+eager dispatch compiled some 280 one-operation programs. ``test_zaya.py``
+and ``test_cohere_moe.py`` import this harness."""
+
+import itertools
 import json
 import os
 
@@ -51,6 +71,19 @@ def make_engine(model_dir, monkeypatch, cache_on=True, interval=16, **over):
     return LLMEngine(EngineArgs(**args).create_engine_config())
 
 
+@pytest.fixture(scope="module")
+def engine(model_dir):
+    """The one engine with ``make_engine``'s own arguments of the module
+    that asks (``test_zaya.py`` and ``test_cohere_moe.py`` import it, and
+    each gets its own from its own ``model_dir``)."""
+    with pytest.MonkeyPatch.context() as mp:
+        return make_engine(model_dir, mp)
+
+
+def runner_of(engine):
+    return engine.engine_core.engine_core.executor.worker.model_runner
+
+
 def drain(engine, max_steps=600):
     """{request: (prompt + tokens, their logprobs from position 1; the
     generated tokens' alone where no prompt logprobs were asked for)}."""
@@ -69,58 +102,87 @@ def drain(engine, max_steps=600):
     return done
 
 
-def served(model_dir, monkeypatch, prompts, max_tokens=6, prompt_lps=1,
-           **over):
-    engine = make_engine(model_dir, monkeypatch, **over)
+_BATCH = itertools.count()
+
+
+def serve(engine, prompts, max_tokens=6, prompt_lps=1):
+    """The prompts as one batch through an engine that other tests use
+    too (request ids are never reused), until it is drained."""
     # A request that wants its prompt's logprobs computes every prompt
     # position and so resumes from no snapshot.
     sp = SamplingParams(temperature=0.0, max_tokens=max_tokens,
                         ignore_eos=True, logprobs=1,
                         prompt_logprobs=prompt_lps)
-    for i, p in enumerate(prompts):
-        engine.add_request(f"r-{i}", list(p), sp)
+    ids = [f"r{next(_BATCH)}-{i}" for i in range(len(prompts))]
+    for rid, p in zip(ids, prompts):
+        engine.add_request(rid, list(p), sp)
     done = drain(engine)
-    return [done[f"r-{i}"] for i in range(len(prompts))], engine
+    return [done[rid] for rid in ids]
+
+
+def served(model_dir, monkeypatch, prompts, max_tokens=6, prompt_lps=1,
+           **over):
+    """``serve`` through an engine of the test's own."""
+    engine = make_engine(model_dir, monkeypatch, **over)
+    return serve(engine, prompts, max_tokens, prompt_lps), engine
+
+
+_JITTED = {}
+
+
+def jit_logprobs(reference, params, cfg, tokens, control=None):
+    """``reference.logprobs`` under one ``jax.jit`` a (reference,
+    configuration, control), at the precision the references ask their
+    callers for; the logprobs of ``tokens[1:]``."""
+    import jax
+    import jax.numpy as jnp
+    key = (reference.__name__, json.dumps(cfg, sort_keys=True), control)
+    if key not in _JITTED:
+        _JITTED[key] = jax.jit(
+            lambda params, ids: reference.logprobs(params, cfg, ids,
+                                                   control))
+    with jax.default_matmul_precision("highest"):
+        table = _JITTED[key](params, jnp.asarray(tokens, jnp.int32))
+    return np.asarray(table)[np.arange(len(tokens) - 1), tokens[1:]]
 
 
 def reference_logprobs(engine, tiny, tokens, control=None, cfg=None):
     """The reference's logprobs of ``tokens[1:]`` on the engine's own
     seeded weights."""
-    import jax
-    import jax.numpy as jnp
-    params = engine.engine_core.engine_core.executor.worker \
-        .model_runner.params
-    with jax.default_matmul_precision("highest"):
-        table = reference.logprobs(params, cfg or run.hf_config(tiny),
-                                   jnp.asarray(tokens, jnp.int32), control)
-    return np.asarray(table)[np.arange(len(tokens) - 1), tokens[1:]]
+    return jit_logprobs(reference, runner_of(engine).params,
+                        cfg or run.hf_config(tiny), tokens, control)
 
 
 def prompt(n, k=0):
     return [(j * 37 + k * 101 + 3) % 500 for j in range(n)]
 
 
-def test_prefill_then_decode_matches_the_reference(model_dir, monkeypatch,
-                                                   tiny):
+def test_prefill_then_decode_matches_the_reference(engine, tiny):
     """Three requests of different lengths in one batch (a prompt that
     crosses several 8-token chunks, one under a chunk, one of two
     tokens), prefill then six decode steps through state rows and pages:
     every logprob equals the reference's one full forward."""
-    got, engine = served(model_dir, monkeypatch,
-                         [prompt(37), prompt(7, 1), prompt(2, 2)], 6)
+    got = serve(engine, [prompt(37), prompt(7, 1), prompt(2, 2)], 6)
     for tokens, lps in got:
         want = reference_logprobs(engine, tiny, tokens)
         np.testing.assert_allclose(lps, want, atol=2e-5)
 
 
-def test_prefill_split_over_three_steps_is_one_pass(model_dir, monkeypatch,
+@pytest.fixture(scope="module")
+def engine_16(model_dir):
+    """Differs by ``max_num_batched_tokens=16``: a prompt goes in over
+    several steps."""
+    with pytest.MonkeyPatch.context() as mp:
+        return make_engine(model_dir, mp, max_num_batched_tokens=16)
+
+
+def test_prefill_split_over_three_steps_is_one_pass(engine, engine_16,
                                                     tiny):
     """A 40-token prompt under a budget of 16 tokens a step: three
     steps, each resuming the row's state and the pages; a budget of 64
     takes it in one. Both equal the reference."""
-    (one, ), engine = served(model_dir, monkeypatch, [prompt(40)])
-    (three, ), _ = served(model_dir, monkeypatch, [prompt(40)],
-                          max_num_batched_tokens=16)
+    (one, ) = serve(engine, [prompt(40)])
+    (three, ) = serve(engine_16, [prompt(40)])
     assert one[0] == three[0]
     np.testing.assert_allclose(three[1], one[1], atol=2e-5)
     np.testing.assert_allclose(
@@ -129,52 +191,61 @@ def test_prefill_split_over_three_steps_is_one_pass(model_dir, monkeypatch,
 
 @pytest.mark.parametrize("left", [1, 2])
 def test_a_split_one_or_two_tokens_into_a_chunk_reaches_back_into_the_row(
-        model_dir, monkeypatch, tiny, left):
+        engine_16, tiny, left):
     """A prompt of 32 tokens and ``left`` more under a budget of 16 a
     step: the third step brings fewer tokens than the convolution
     carries (three), so the row's new inputs are the chunk's and the
     last of the old ones, and the decode step after it reads both."""
-    (got, ), engine = served(model_dir, monkeypatch, [prompt(32 + left)],
-                             max_num_batched_tokens=16)
+    (got, ) = serve(engine_16, [prompt(32 + left)])
     np.testing.assert_allclose(
-        got[1], reference_logprobs(engine, tiny, got[0]), atol=2e-5)
+        got[1], reference_logprobs(engine_16, tiny, got[0]), atol=2e-5)
 
 
 def test_preemption_with_resume_gives_the_uninterrupted_logits(
-        model_dir, monkeypatch):
+        engine, model_dir, monkeypatch):
     """Four requests of 24 + 40 tokens against 15 pages of 16: one is
     preempted, parks its 76 KB row in the snapshot pool and resumes as
-    a continuation beside its prefix's pages."""
-    prompts = [prompt(24, i) for i in range(4)]
-    calm, _ = served(model_dir, monkeypatch, prompts, 40, None)
-    tight, engine = served(model_dir, monkeypatch, prompts, 40, None,
-                           num_gpu_blocks_override=15)
-    stats = engine.get_stats()
+    a continuation beside its prefix's pages. The engine of its own
+    differs by ``num_gpu_blocks_override=15``; the prompts are no other
+    test's, so the calm pass finds no page of theirs."""
+    prompts = [prompt(24, 10 + i) for i in range(4)]
+    calm = serve(engine, prompts, 40, None)
+    tight, small = served(model_dir, monkeypatch, prompts, 40, None,
+                          num_gpu_blocks_override=15)
+    stats = small.get_stats()
     assert int(stats["num_preemptions"]) > 0
     assert stats["ssm_state_cache_hits"] >= 1
-    assert engine.engine_core.engine_core.scheduler.kv_cache_manager \
+    assert small.engine_core.engine_core.scheduler.kv_cache_manager \
         .get_num_free_blocks() == 15
     for a, b in zip(calm, tight):
         assert a[0] == b[0]
         np.testing.assert_allclose(b[1], a[1], atol=2e-5)
 
 
-def test_snapshot_restore_gives_the_one_pass_logits(model_dir, monkeypatch):
+def two_turns(engine, first_prompt):
+    """A turn, then one that extends it by three tokens; the second's
+    tokens and logprobs, and what the two added to the counters."""
+    before = engine.get_stats()
+    (first, ) = serve(engine, [first_prompt], 6, None)
+    (second, ) = serve(engine, [first[0] + [17, 18, 19]], 6, None)
+    after = engine.get_stats()
+    return second, after, {
+        k: after[k] - before[k]
+        for k in ("ssm_state_cache_hits", "ssm_resume_tokens_saved")
+        if k in after}
+
+
+def test_snapshot_restore_gives_the_one_pass_logits(engine, model_dir,
+                                                    monkeypatch):
     """A second turn that extends the first restores the row from the
     chunk-boundary snapshot and reuses the prefix's pages: same logits
-    as with the state cache off."""
-    sp = SamplingParams(temperature=0.0, max_tokens=6, ignore_eos=True,
-                        logprobs=1)
-
-    def two_turns(cache_on):
-        engine = make_engine(model_dir, monkeypatch, cache_on=cache_on)
-        engine.add_request("t-0", prompt(70), sp)
-        first = drain(engine)["t-0"][0]
-        engine.add_request("t-1", first + [17, 18, 19], sp)
-        return drain(engine)["t-1"], engine.get_stats()
-
-    plain, _ = two_turns(False)
-    hit, stats = two_turns(True)
+    as with the state cache off (an engine of its own: the flag is read
+    at construction and, by the runner, after it). The first turn's
+    prompt is no other test's."""
+    with monkeypatch.context() as off:    # the runner reads it later too
+        plain, _, _ = two_turns(make_engine(model_dir, off, cache_on=False),
+                                prompt(70, 20))
+    hit, _, stats = two_turns(engine, prompt(70, 20))
     assert stats["ssm_state_cache_hits"] >= 1
     assert stats["ssm_resume_tokens_saved"] >= 64
     assert hit[0] == plain[0]
@@ -182,10 +253,9 @@ def test_snapshot_restore_gives_the_one_pass_logits(model_dir, monkeypatch):
 
 
 @pytest.fixture(scope="module")
-def served_once(model_dir, tiny):
+def served_once(engine, tiny):
     """One served request, its engine and the reference's logprobs."""
-    with pytest.MonkeyPatch.context() as mp:
-        (got, ), engine = served(model_dir, mp, [prompt(37)], 4)
+    (got, ) = serve(engine, [prompt(37)], 4)
     return got, engine, reference_logprobs(engine, tiny, got[0])
 
 
@@ -278,20 +348,19 @@ def test_unwired_sources_are_refused_by_name(tiny, tmp_path, key, value,
         ).create_engine_config())
 
 
-def test_the_walk_is_a_scan_over_the_periods(model_dir, monkeypatch):
+def test_the_walk_is_a_scan_over_the_periods(engine):
     """Two periods of (mamba, mamba, attention, mamba): the forward's
     jaxpr holds one scan of length 2 whose body has three Mamba-2 mixers
     and one attention, whatever the depth; two K/V heads share a page
     row."""
     import jax
-    engine = make_engine(model_dir, monkeypatch)
-    runner = engine.engine_core.engine_core.executor.worker.model_runner
+    runner = runner_of(engine)
     model = runner.model
     assert model._period() == 4 and model.cfg.kv_pack == 2
     assert runner.kv_caches["k"].shape[2:] == (1, 16, 32)
     assert runner.kv_caches["ssm"].shape == (6, 5, 16, 64)
     assert model.state_row_bytes() == 6 * (16 * 64 * 4 + 3 * (64 + 32) * 4)
-    runner._build_step_fn()
+    assert runner._forward_fn is not None   # built with the engine
     T, max_q, G = runner._batch_shape(16, 2)
     text = str(jax.make_jaxpr(
         lambda p, c, i: runner._forward_fn(p, c, i))(

@@ -10,6 +10,7 @@ import torch
 from transformers import LlamaConfig
 from transformers import LlamaForCausalLM as HFLlama
 
+from tests.engine.test_llm_engine import PALLAS_GEOMETRY
 from vllm_distributed_tpu.engine.arg_utils import EngineArgs
 from vllm_distributed_tpu.engine.llm_engine import LLMEngine
 from vllm_distributed_tpu.sampling_params import SamplingParams
@@ -213,6 +214,10 @@ def test_quant_decode_via_fused_pallas_kernel(checkpoint, monkeypatch):
 
     want = run_one(base)
     monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
-    fused = make_engine(checkpoint, quantization="int4")
+    # The attention kernel comes along with the backend, and an
+    # interpreted one is a minute a program to lower under this file's
+    # 4-token pages: PALLAS_GEOMETRY's. The weights' kernel, which this
+    # test is about, does not see the pages.
+    fused = make_engine(checkpoint, quantization="int4", **PALLAS_GEOMETRY)
     got = run_one(fused)
     assert got == want

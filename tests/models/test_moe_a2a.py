@@ -59,13 +59,19 @@ def test_a2a_carries_the_dispatch(ep_setup, monkeypatch):
     assert "psum" not in jaxpr
 
 
+def block_in_mode(model, lp, x, mode, monkeypatch):
+    """``mlp_block`` under one ``jax.jit`` (the mode is read when it is
+    traced): one program where eager dispatch compiled every operation
+    of the block, across four devices, on its own."""
+    monkeypatch.setenv("VDT_MOE_EP_MODE", mode)
+    return np.asarray(jax.jit(lambda x_: model.mlp_block(lp, x_))(x))
+
+
 def test_a2a_matches_replicate_path(ep_setup, monkeypatch):
     mesh, model, lp, x = ep_setup
     with global_mesh(mesh), mesh:
-        monkeypatch.setenv("VDT_MOE_EP_MODE", "a2a")
-        got = np.asarray(model.mlp_block(lp, x))
-        monkeypatch.setenv("VDT_MOE_EP_MODE", "replicate")
-        want = np.asarray(model.mlp_block(lp, x))
+        got = block_in_mode(model, lp, x, "a2a", monkeypatch)
+        want = block_in_mode(model, lp, x, "replicate", monkeypatch)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
@@ -96,7 +102,7 @@ def test_indivisible_bucket_falls_back(ep_setup, monkeypatch):
     x7 = jnp.asarray(rng.normal(size=(7, H)), jnp.float32)
     with global_mesh(mesh), mesh:
         assert not model._a2a_applicable(7)
-        out = np.asarray(model.mlp_block(lp, x7))
+        out = block_in_mode(model, lp, x7, "a2a", monkeypatch)
     assert out.shape == (7, H)
     assert np.isfinite(out).all()
 
@@ -139,8 +145,6 @@ def test_a2a_composes_with_eplb(monkeypatch):
             lp[w] = lp[w].at[emap[e, 1]].set(lp[w][e])
     x = jnp.asarray(rng.normal(size=(T, H)), jnp.float32)
     with global_mesh(mesh), mesh:
-        monkeypatch.setenv("VDT_MOE_EP_MODE", "a2a")
-        got = np.asarray(model.mlp_block(lp, x))
-        monkeypatch.setenv("VDT_MOE_EP_MODE", "replicate")
-        want = np.asarray(model.mlp_block(lp, x))
+        got = block_in_mode(model, lp, x, "a2a", monkeypatch)
+        want = block_in_mode(model, lp, x, "replicate", monkeypatch)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)

@@ -105,11 +105,34 @@ def _restore_tpla_env():
 
 
 @pytest.fixture(scope="module")
-def baseline_tokens():
+def engines():
+    """``engines(tp, tpla)``: the engine of that layout on the default
+    (XLA) backend with no connector, built at its first use and kept to
+    the module's end. An engine built is what a test here costs (its
+    programs compiled again: JAX caches by function object), and the
+    layout is fixed when the model is loaded, so tests that want the
+    same one share it, run with ``shutdown=False`` and read nothing the
+    other left (counters, which pages are cached). A test on another
+    backend, with a quantized plane or with a connector builds its
+    own."""
+    built = {}
+
+    def get(tp, tpla=True):
+        if (tp, tpla) not in built:
+            built[tp, tpla] = make_engine(tp=tp, tpla=tpla)
+        return built[tp, tpla]
+
+    yield get
+    for engine in built.values():
+        engine.shutdown()
+
+
+@pytest.fixture(scope="module")
+def baseline_tokens(engines):
     """TP=1 replicated-layout greedy outputs (the parity reference; the
     dummy loader's seeded init gives every engine of this config
     identical weights)."""
-    return run(make_engine(tp=1), "base")
+    return run(engines(1), "base", shutdown=False)
 
 
 # ---------------------------------------------------------------------------
@@ -117,12 +140,13 @@ def baseline_tokens():
 # XLA scan and interpret-mode Pallas.
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("tp", [2, 4])
-def test_tpla_token_identical_xla(baseline_tokens, tp, monkeypatch):
+def test_tpla_token_identical_xla(baseline_tokens, engines, tp,
+                                  monkeypatch):
     monkeypatch.setenv("VDT_ATTENTION_BACKEND", "xla")
-    engine = make_engine(tp=tp)
+    engine = engines(tp)
     assert _runner(engine).model.tpla_shards == tp
     assert set(_runner(engine).kv_caches) == {"c", "pe"}
-    assert run(engine, f"tpla{tp}") == baseline_tokens
+    assert run(engine, f"tpla{tp}", shutdown=False) == baseline_tokens
 
 
 @pytest.mark.parametrize("tp", [2, 4])
@@ -165,8 +189,8 @@ def test_tpla_combine_rides_quantized_plane(baseline_tokens,
 # ---------------------------------------------------------------------------
 # VDT_TPLA=0 reverts wholesale to the replicated layout.
 # ---------------------------------------------------------------------------
-def test_tpla_off_reverts_to_replicated_layout(baseline_tokens):
-    engine = make_engine(tp=2, tpla=False)
+def test_tpla_off_reverts_to_replicated_layout(baseline_tokens, engines):
+    engine = engines(2, tpla=False)
     runner = _runner(engine)
     assert runner.model.tpla_shards == 1
     assert set(runner.kv_caches) == {"c"}  # no rope sidecar
@@ -174,7 +198,7 @@ def test_tpla_off_reverts_to_replicated_layout(baseline_tokens):
     from vllm_distributed_tpu.config import MESH_AXIS_TOKEN
     assert runner.model.kv_cache_specs() == {
         "c": P(None, MESH_AXIS_TOKEN, None, None)}
-    assert run(engine, "repl2") == baseline_tokens
+    assert run(engine, "repl2", shutdown=False) == baseline_tokens
 
 
 def test_tpla_falls_back_when_lkv_indivisible():
@@ -216,24 +240,20 @@ def test_latent_pool_capacity_scales_with_tp(monkeypatch):
     assert pages_tpla4 == budget // (3 * 4 * 24 * 4)  # L*PS*lanes*f32
 
 
-def test_tpla_gauges_flow_to_metrics():
+def test_tpla_gauges_flow_to_metrics(engines):
     from vllm_distributed_tpu.metrics.prometheus import render_metrics
 
-    engine = make_engine(tp=2)
-    try:
-        stats = engine.get_stats()
-        workers = stats.get("workers") or {}
-        assert workers, "worker telemetry map missing from stats"
-        entry = next(iter(workers.values()))
-        assert entry["tpla_latent_shards"] == 2
-        # 3 layers x page_size 4 x (32 + 8) lanes x 4 bytes.
-        assert entry["mla_latent_page_bytes"] == 3 * 4 * 40 * 4
-        text = render_metrics(stats)
-        assert "vdt:tpla_latent_shards{" in text
-        assert "vdt:mla_latent_page_bytes{" in text
-        assert 'vdt:kv_blocks{state="free"}' in text
-    finally:
-        engine.shutdown()
+    stats = engines(2).get_stats()
+    workers = stats.get("workers") or {}
+    assert workers, "worker telemetry map missing from stats"
+    entry = next(iter(workers.values()))
+    assert entry["tpla_latent_shards"] == 2
+    # 3 layers x page_size 4 x (32 + 8) lanes x 4 bytes.
+    assert entry["mla_latent_page_bytes"] == 3 * 4 * 40 * 4
+    text = render_metrics(stats)
+    assert "vdt:tpla_latent_shards{" in text
+    assert "vdt:mla_latent_page_bytes{" in text
+    assert 'vdt:kv_blocks{state="free"}' in text
 
 
 # ---------------------------------------------------------------------------
@@ -297,7 +317,7 @@ def test_check_latent_wire_rejects_foreign_stores():
 
 
 @pytest.mark.parametrize("tp,tpla", [(2, True), (1, True), (2, False)])
-def test_latent_stage_and_chunked_scatter_roundtrip(tp, tpla):
+def test_latent_stage_and_chunked_scatter_roundtrip(engines, tp, tpla):
     """The dcn_pull staging path (stage_pages -> donated
     scatter_pages_chunk) must round-trip latent pages bit-exactly in
     every layout: TPLA-sharded, TP=1 replicated, and TP>1 replicated
@@ -307,27 +327,26 @@ def test_latent_stage_and_chunked_scatter_roundtrip(tp, tpla):
 
     from vllm_distributed_tpu.distributed.kv_transfer import page_io
 
-    engine = make_engine(tp=tp, tpla=tpla)
-    try:
-        runner = _runner(engine)
-        run(engine, f"warm{tp}{tpla}", shutdown=False)  # populate pages
-        page_ids = [0, 1, 2]
-        k_w, v_w = page_io.gather_pages(runner, page_ids)
-        assert k_w.shape[-1] == 64 and v_w.shape[-1] == 8
-        # Wipe the pages, then restore them through the staged +
-        # chunked donated-scatter path the async pull uses.
-        for key, arr in list(runner.kv_caches.items()):
-            runner.kv_caches[key] = arr.at[:, jnp.asarray(page_ids)].set(0)
-        k_dev, v_dev = page_io.stage_pages(runner, k_w, v_w)
-        page_io.scatter_pages_chunk(runner, page_ids, k_dev, v_dev,
-                                    lo=0, chunk=2)
-        page_io.scatter_pages_chunk(runner, page_ids, k_dev, v_dev,
-                                    lo=2, chunk=2)
-        k_2, v_2 = page_io.gather_pages(runner, page_ids)
-        assert np.array_equal(k_2, k_w)
-        assert np.array_equal(v_2, v_w)
-    finally:
-        engine.shutdown()
+    engine = engines(tp, tpla)
+    runner = _runner(engine)
+    run(engine, f"warm{tp}{tpla}", shutdown=False)  # populate pages
+    page_ids = [0, 1, 2]
+    k_w, v_w = page_io.gather_pages(runner, page_ids)
+    assert k_w.shape[-1] == 64 and v_w.shape[-1] == 8
+    assert np.abs(k_w).max() > 0
+    # Wipe the pages, then restore them through the staged +
+    # chunked donated-scatter path the async pull uses (the engine
+    # gets back what it held).
+    for key, arr in list(runner.kv_caches.items()):
+        runner.kv_caches[key] = arr.at[:, jnp.asarray(page_ids)].set(0)
+    k_dev, v_dev = page_io.stage_pages(runner, k_w, v_w)
+    page_io.scatter_pages_chunk(runner, page_ids, k_dev, v_dev,
+                                lo=0, chunk=2)
+    page_io.scatter_pages_chunk(runner, page_ids, k_dev, v_dev,
+                                lo=2, chunk=2)
+    k_2, v_2 = page_io.gather_pages(runner, page_ids)
+    assert np.array_equal(k_2, k_w)
+    assert np.array_equal(v_2, v_w)
 
 
 def test_tpla_producer_feeds_replicated_consumer(tmp_path,

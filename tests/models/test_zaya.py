@@ -13,8 +13,12 @@ import pytest
 from benchmarks import run
 from benchmarks.references import zaya as reference
 # The engine, its steps and the prompts as the other carried-state
-# model's tests make them (float32, 4 rows, a 64-token budget, seed 5).
-from test_granite_hybrid import drain, make_engine, prompt, served
+# model's tests make them (float32, 4 rows, a 64-token budget, seed 5),
+# and one engine a module for the tests that want those arguments (that
+# file's docstring says what an engine costs and what sharing one asks).
+from test_granite_hybrid import engine  # noqa: F401 (this module's own)
+from test_granite_hybrid import (drain, jit_logprobs, make_engine, prompt,
+                                 runner_of, serve, served, two_turns)
 from vllm_distributed_tpu import SamplingParams
 from vllm_distributed_tpu.engine.arg_utils import EngineArgs
 from vllm_distributed_tpu.engine.llm_engine import LLMEngine
@@ -39,55 +43,61 @@ def model_dir(tiny, tmp_path_factory):
         run.WORK = work
 
 
-def runner_of(engine):
-    return engine.engine_core.engine_core.executor.worker.model_runner
-
-
 def reference_logprobs(engine, tiny, tokens, control=None):
     """The reference's logprobs of ``tokens[1:]`` on the engine's own
     seeded weights."""
-    import jax
-    import jax.numpy as jnp
-    with jax.default_matmul_precision("highest"):
-        table = reference.logprobs(runner_of(engine).params,
-                                   run.hf_config(tiny),
-                                   jnp.asarray(tokens, jnp.int32), control)
-    return np.asarray(table)[np.arange(len(tokens) - 1), tokens[1:]]
+    return jit_logprobs(reference, runner_of(engine).params,
+                        run.hf_config(tiny), tokens, control)
 
 
-def test_prefill_then_decode_matches_the_reference(model_dir, monkeypatch,
-                                                   tiny):
+def test_prefill_then_decode_matches_the_reference(engine, tiny):
     """Three requests of different lengths in one batch (37, 7 and 2
     tokens), prefill then six decode steps through the carried rows and
     the pages: every logprob equals the reference's one full forward."""
-    got, engine = served(model_dir, monkeypatch,
-                         [prompt(37), prompt(7, 1), prompt(2, 2)], 6)
+    got = serve(engine, [prompt(37), prompt(7, 1), prompt(2, 2)], 6)
     for tokens, lps in got:
         want = reference_logprobs(engine, tiny, tokens)
         np.testing.assert_allclose(lps, want, atol=2e-5)
 
 
+def carried_rows(engine):
+    """The two carried rows, in every layer, of the sequence the engine
+    served last and alone (its row is still its own: a finished request
+    leaves the batch at the next step)."""
+    runner = runner_of(engine)
+    (row, ) = runner.input_batch.req_id_to_index.values()
+    return {name: np.asarray(runner.kv_caches[name])[:, row]
+            for name in ("cca_u", "cca_cv")}
+
+
+@pytest.fixture(scope="module")
+def one_pass(engine, tiny):
+    """A 40-token prompt in one pass under the budget of 64: its tokens
+    and logprobs, held to the reference, and the rows it left."""
+    (one, ) = serve(engine, [prompt(40)])
+    np.testing.assert_allclose(
+        one[1], reference_logprobs(engine, tiny, one[0]), atol=2e-5)
+    return one, carried_rows(engine)
+
+
 @pytest.mark.parametrize("budget", [16, 1])
 def test_a_prompt_split_over_steps_leaves_the_same_rows_and_logprobs(
-        model_dir, monkeypatch, tiny, budget):
+        model_dir, monkeypatch, one_pass, budget):
     """A 40-token prompt under a budget of 16 tokens a step (three
     chunks, each resuming the rows and the pages) and of one token a
     step (forty one-token segments, the decode form all the way)
     against one pass under a budget of 64: the same logprobs, and after
-    the last decode step the same two rows in every layer."""
-    (one, ), whole = served(model_dir, monkeypatch, [prompt(40)])
+    the last decode step the same two rows in every layer. An engine of
+    its own each: ``max_num_batched_tokens`` and ``max_num_seqs``."""
+    one, rows = one_pass
     (cut, ), engine = served(model_dir, monkeypatch, [prompt(40)],
                              max_num_batched_tokens=budget,
                              max_num_seqs=min(4, budget))
     assert one[0] == cut[0]
     np.testing.assert_allclose(cut[1], one[1], atol=2e-5)
-    np.testing.assert_allclose(
-        one[1], reference_logprobs(whole, tiny, one[0]), atol=2e-5)
-    for name in ("cca_u", "cca_cv"):
-        a, b = (np.asarray(runner_of(e).kv_caches[name])[:, 0]
-                for e in (whole, engine))
-        assert np.abs(a).max() > 0.05
-        np.testing.assert_allclose(b, a, atol=2e-6)
+    for name, b in carried_rows(engine).items():
+        assert np.abs(rows[name]).max() > 0.05
+        np.testing.assert_allclose(b, rows[name], atol=2e-6)
 
 
 def test_a_fresh_row_is_zeros_the_bias_and_zeros(model_dir, monkeypatch,
@@ -109,42 +119,39 @@ def test_a_fresh_row_is_zeros_the_bias_and_zeros(model_dir, monkeypatch,
 
 
 def test_preemption_with_resume_gives_the_uninterrupted_logits(
-        model_dir, monkeypatch):
+        engine, model_dir, monkeypatch):
     """Four requests of 24 + 40 tokens against 15 pages of 16: one is
     preempted, parks its rows in the snapshot pool and resumes as a
-    continuation beside its prefix's pages."""
-    prompts = [prompt(24, i) for i in range(4)]
-    calm, _ = served(model_dir, monkeypatch, prompts, 40, None)
-    tight, engine = served(model_dir, monkeypatch, prompts, 40, None,
-                           num_gpu_blocks_override=15)
-    stats = engine.get_stats()
+    continuation beside its prefix's pages. The engine of its own
+    differs by ``num_gpu_blocks_override=15``; the prompts are no other
+    test's, so the calm pass finds no page of theirs."""
+    prompts = [prompt(24, 10 + i) for i in range(4)]
+    calm = serve(engine, prompts, 40, None)
+    tight, small = served(model_dir, monkeypatch, prompts, 40, None,
+                          num_gpu_blocks_override=15)
+    stats = small.get_stats()
     assert int(stats["num_preemptions"]) > 0
     assert stats["ssm_state_cache_hits"] >= 1
-    assert engine.engine_core.engine_core.scheduler.kv_cache_manager \
+    assert small.engine_core.engine_core.scheduler.kv_cache_manager \
         .get_num_free_blocks() == 15
     for a, b in zip(calm, tight):
         assert a[0] == b[0]
         np.testing.assert_allclose(b[1], a[1], atol=2e-5)
 
 
-def test_snapshot_restore_gives_the_one_pass_logits(model_dir, monkeypatch):
+def test_snapshot_restore_gives_the_one_pass_logits(engine, model_dir,
+                                                    monkeypatch):
     """A second turn that extends the first restores the rows from the
     chunk-boundary snapshot and reuses the prefix's pages: same logits
-    as with the state cache off; the pool's bytes are the rows'."""
-    sp = SamplingParams(temperature=0.0, max_tokens=6, ignore_eos=True,
-                        logprobs=1)
-
-    def two_turns(cache_on):
-        engine = make_engine(model_dir, monkeypatch, cache_on=cache_on)
-        engine.add_request("t-0", prompt(70), sp)
-        first = drain(engine)["t-0"][0]
-        engine.add_request("t-1", first + [17, 18, 19], sp)
-        return drain(engine)["t-1"], engine.get_stats(), engine
-
-    plain, _, _ = two_turns(False)
-    hit, stats, engine = two_turns(True)
-    assert stats["ssm_state_cache_hits"] >= 1
-    assert stats["ssm_resume_tokens_saved"] >= 64
+    as with the state cache off (an engine of its own: the flag is read
+    at construction and, by the runner, after it); the pool's bytes are
+    the rows'. The first turn's prompt is no other test's."""
+    with monkeypatch.context() as off:    # the runner reads it later too
+        plain, _, _ = two_turns(make_engine(model_dir, off, cache_on=False),
+                                prompt(70, 20))
+    hit, stats, added = two_turns(engine, prompt(70, 20))
+    assert added["ssm_state_cache_hits"] >= 1
+    assert added["ssm_resume_tokens_saved"] >= 64
     assert hit[0] == plain[0]
     np.testing.assert_allclose(hit[1], plain[1], atol=2e-5)
     runner = runner_of(engine)
@@ -156,10 +163,9 @@ def test_snapshot_restore_gives_the_one_pass_logits(model_dir, monkeypatch):
 
 
 @pytest.fixture(scope="module")
-def served_once(model_dir, tiny):
+def served_once(engine, tiny):
     """One served request, its engine and the reference's logprobs."""
-    with pytest.MonkeyPatch.context() as mp:
-        (got, ), engine = served(model_dir, mp, [prompt(37)], 4)
+    (got, ) = serve(engine, [prompt(37)], 4)
     return got, engine, reference_logprobs(engine, tiny, got[0])
 
 
@@ -247,14 +253,12 @@ def test_unwired_sources_are_refused_by_name(tiny, tmp_path, key, value,
         ).create_engine_config())
 
 
-def test_the_walk_is_one_scan_that_carries_the_router_s_state(
-        model_dir, monkeypatch):
+def test_the_walk_is_one_scan_that_carries_the_router_s_state(engine):
     """The forward's jaxpr holds one scan over the four layers whose
     body runs the carried-row kernel twice (``u``; ``c0`` and ``vb``)
     and carries a ``[T, 8]`` float32 beside the stream; the expert
     weights are per-layer buffers behind a switch."""
     import jax
-    engine = make_engine(model_dir, monkeypatch)
     runner = runner_of(engine)
     lay = runner.params["layers"]
     assert isinstance(lay["w_gate"], tuple) and len(lay["w_gate"]) == 4
@@ -263,7 +267,7 @@ def test_the_walk_is_one_scan_that_carries_the_router_s_state(
     assert runner.kv_caches["k"].shape == (4, 128, 2, 16, 16)
     assert runner.kv_caches["cca_u"].shape == (4, 5, 1, 12, 8)
     assert runner.kv_caches["cca_cv"].shape == (4, 5, 1, 14, 8)
-    runner._build_step_fn()
+    assert runner._forward_fn is not None   # built with the engine
     T, max_q, G = runner._batch_shape(16, 2)
     text = str(jax.make_jaxpr(
         lambda p, c, i: runner._forward_fn(p, c, i))(

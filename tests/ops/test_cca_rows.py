@@ -74,7 +74,10 @@ def test_rows_carry_what_the_whole_sequence_convolutions_read(
         chunks = [(r, done[r], w[step]) for r, w in walks.items()
                   if w[step]]
         n = sum(c[2] for c in chunks)
-        T = n + 3                                      # padding tokens
+        # One bucket for every step (at least three padding tokens): a
+        # walk's steps then run one program, not one each.
+        T = 27
+        assert T >= n + 3
         seg = _make_seg(chunks, T, S)
         flat_u = np.zeros((T, C), f32)
         flat_vb = np.zeros((T, half), f32)

@@ -14,14 +14,22 @@ from vllm_distributed_tpu.ops.pallas_attention import (
 
 
 def build_case(rng, *, seqs, page_size, pages_per_req, num_q_heads,
-               num_kv_heads, head_dim, max_q, dtype=jnp.float32):
-    """seqs: list of (q_len, kv_len) with kv_len >= q_len."""
+               num_kv_heads, head_dim, max_q, dtype=jnp.float32,
+               t_bucket=None, max_reqs=None):
+    """seqs: list of (q_len, kv_len) with kv_len >= q_len. ``t_bucket``
+    (token rows) and ``max_reqs`` (table rows) pad the arrays as the
+    runner's buckets do: cases that pass the same pair, geometry and
+    ``max_q`` run one compiled program, and the interpreted kernel's
+    lowering is all a case costs (tests/ops/test_unified_attention.py's
+    docstring). Left out, they fit the case."""
     R = len(seqs)
-    max_reqs = R + 1  # one inactive padding row
+    max_reqs = max_reqs or R + 1  # at least one inactive padding row
+    assert max_reqs > R
     num_pages = max_reqs * pages_per_req
     T = sum(q for q, _ in seqs)
     bq = min(max_q, 128)
-    T_pad = T + bq
+    T_pad = (t_bucket or T) + bq
+    assert T_pad >= T + bq
 
     k_pages = jnp.asarray(rng.standard_normal(
         (num_pages, num_kv_heads, page_size, head_dim)), dtype)
@@ -75,6 +83,12 @@ def float32_reference(case):
     return np.asarray(want)[:case["T"]]
 
 
+# One geometry and one bucket (32 token rows, 12 table rows) for the
+# cases that differ only in their lengths: one program a ``max_q``.
+BUCKET = dict(page_size=8, pages_per_req=4, num_q_heads=8, num_kv_heads=4,
+              head_dim=128, t_bucket=32, max_reqs=12)
+
+
 @pytest.mark.parametrize("seqs,max_q", [
     # Pure decode: one token per sequence, varying kv lens.
     ([(1, 1), (1, 5), (1, 17), (1, 32)], 1),
@@ -87,9 +101,7 @@ def float32_reference(case):
 ])
 def test_matches_reference(seqs, max_q):
     rng = np.random.default_rng(0)
-    case = build_case(rng, seqs=seqs, page_size=8, pages_per_req=4,
-                      num_q_heads=8, num_kv_heads=4, head_dim=128,
-                      max_q=max_q)
+    case = build_case(rng, seqs=seqs, max_q=max_q, **BUCKET)
     got, want = run_both(case)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
@@ -120,9 +132,7 @@ def test_batched_decode_many_seqs():
     programs, ragged kv lens, an sb that does not divide num_seqs."""
     rng = np.random.default_rng(7)
     seqs = [(1, k) for k in (1, 5, 17, 32, 9, 25, 13, 2, 31, 8, 20)]
-    case = build_case(rng, seqs=seqs, page_size=8, pages_per_req=4,
-                      num_q_heads=8, num_kv_heads=4, head_dim=128,
-                      max_q=1)
+    case = build_case(rng, seqs=seqs, max_q=1, **BUCKET)
     got, want = run_both(case)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
@@ -131,9 +141,8 @@ def test_batched_decode_scattered_q_start():
     """Decode rows addressed through q_start, not the run index — the
     layout token parallelism's per-rank compacted seq lists produce."""
     rng = np.random.default_rng(8)
-    case = build_case(rng, seqs=[(1, 7), (1, 19), (1, 3)], page_size=8,
-                      pages_per_req=4, num_q_heads=8, num_kv_heads=4,
-                      head_dim=128, max_q=1)
+    case = build_case(rng, seqs=[(1, 7), (1, 19), (1, 3)], max_q=1,
+                      **BUCKET)
     # Scatter the three queries to rows 3, 0, 2 of the token array.
     si = np.asarray(case["seq_info"]).copy()
     perm = [3, 0, 2]

@@ -110,7 +110,11 @@ def chunk_step(dims, runs, max_q, rng, rows=8, layers=2):
     N, Lkv, Pn, R, V = dims
     ppr = 4
     T = sum(q for q, _ in runs)
-    T_pad = T + min(max_q, 128)
+    # One bucket of token rows for every step of ``STEPS``: what differs
+    # between them is then data, and a configuration's steps run the
+    # programs its first one compiled.
+    T_pad = 48
+    assert T + min(max_q, 128) <= T_pad
     pages = rng.permutation(rows * ppr) + 1
     bt = np.zeros((rows, ppr), np.int32)
     seq_info = np.zeros((rows, 4), np.int32)

@@ -452,13 +452,19 @@ def test_moe_ep_quantized_bounded_divergence(ep_setup, monkeypatch,
                                              mode):
     mesh, model, lp, x = ep_setup
     monkeypatch.setenv("VDT_MOE_EP_MODE", mode)
+    def block():
+        # Under one jit a reading (the flags are read when it is
+        # traced): eager dispatch compiles every operation of the
+        # block, across four devices, on its own.
+        return np.asarray(jax.jit(lambda x_: model.mlp_block(lp, x_))(x))
+
     with global_mesh(mesh), mesh:
         monkeypatch.setenv("VDT_QCOMM", "1")
         collectives.refresh()
-        got = np.asarray(model.mlp_block(lp, x))
+        got = block()
         monkeypatch.setenv("VDT_QCOMM", "0")
         collectives.refresh()
-        want = np.asarray(model.mlp_block(lp, x))
+        want = block()
     assert np.max(np.abs(got - want)) < 0.05 * (np.max(np.abs(want))
                                                 + 1.0)
 

@@ -5,12 +5,31 @@ kernel (ISSUE 6 tentpole): mixed prefill+decode batches, GQA grouping,
 q_len spanning page boundaries and multiple q tiles, single-token
 prefill tails, the emit_state cascade path, and the fused KV-write +
 attend variant. The partition-descriptor builder is unit-tested on the
-same cases."""
+same cases; the page loop under a window (trimmed, not only masked) is
+held to poisoned table slots at the end of the file.
+
+**What a case costs.** The data are free; the program is not. A call
+with new shapes or new static arguments lowers ``_mega_kernel``'s
+interpreted body again (the first call of a geometry is seconds to a
+minute, four fifths of it jaxpr-to-MLIR conversion that no cache keeps;
+the second is hundredths of a second). The body's size, and so the time,
+grows with what the kernel unrolls in Python: ``sb x ppb`` (a decode
+group's page copies; ``ppb`` is pages a 128-position kv block, at most
+the table's width) first, then ``bq`` (a tile's 8-row chunks). Query and
+K/V heads, ``head_dim``, the number of pages and of programs cost
+nothing. So a new case that differs from an old one only in its data
+(lengths, seed, poisoned slots) passes the old one's ``t_bucket`` and
+``max_reqs`` to ``build_case`` and reuses its program, as the runner's
+own buckets do; a case that needs statics of its own (a feature, a
+window, ``emit_state``) takes ``SMALL`` (the 8-row tile, groups of two,
+two pages a kv block: 3-5 s) unless its point is the model's own tile,
+and says so."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from vllm_distributed_tpu.core.kv_cache_utils import num_window_dead_blocks
 from vllm_distributed_tpu.ops.attention import (_shared_prefix_state,
                                                 merge_attention_states,
                                                 naive_ragged_attention,
@@ -22,16 +41,28 @@ from vllm_distributed_tpu.ops.pallas_attention import (
     unified_write_attend_pallas)
 
 
+# The cheapest geometry to lower: the smallest tile and group over two
+# pages a kv block (the module docstring says what the time grows with).
+SMALL = dict(page_size=16, pages_per_req=2, bq=8, sb=2)
+
+
 def build_case(rng, *, seqs, page_size, pages_per_req, num_q_heads,
-               num_kv_heads, head_dim, dtype=jnp.float32, bq=None, sb=None):
+               num_kv_heads, head_dim, dtype=jnp.float32, bq=None, sb=None,
+               t_bucket=None, max_reqs=None):
     """seqs: list of (q_len, kv_len) with kv_len >= q_len. ``bq`` and
     ``sb`` default to the model's own tile sizes; a smaller pair traces
-    a smaller interpreted program."""
+    a smaller interpreted program. ``t_bucket`` (token rows) and
+    ``max_reqs`` (table rows) pad the arrays as the runner's buckets do:
+    cases that pass the same pair, geometry and statics share one
+    compiled program. Left out, they fit the case."""
     R = len(seqs)
-    max_reqs = R + 1  # one inactive padding row
+    max_reqs = max_reqs or R + 1  # at least one inactive padding row
+    assert max_reqs > R
     num_pages = max_reqs * pages_per_req
     T = sum(q for q, _ in seqs)
-    T_pad = T + Q_TILE_PAD
+    t_bucket = t_bucket or T
+    assert t_bucket >= T
+    T_pad = t_bucket + Q_TILE_PAD
 
     k_pages = jnp.asarray(rng.standard_normal(
         (num_pages, num_kv_heads, page_size, head_dim)), dtype)
@@ -56,7 +87,7 @@ def build_case(rng, *, seqs, page_size, pages_per_req, num_q_heads,
 
     bq = bq or prefill_tile_size(num_q_heads, head_dim)
     sb = sb or decode_group_size(num_q_heads, num_kv_heads)
-    P = num_partition_programs(T, max_reqs, bq=bq, sb=sb)
+    P = num_partition_programs(t_bucket, max_reqs, bq=bq, sb=sb)
     desc, dl = build_partition_descriptor(seq_info, R, bq=bq, sb=sb,
                                           num_programs=P)
     return dict(
@@ -82,6 +113,14 @@ def run_both(case, sm_scale=0.125):
     return np.asarray(out)[:T], np.asarray(want)[:T]
 
 
+# The model's own tile and group (32 rows, 8 sequences) over four pages a
+# kv block, in a bucket of 32 tokens and 12 table rows: every float32
+# case without a feature that differs from another only in its lengths
+# runs this ONE program.
+MODEL_TILE = dict(page_size=8, pages_per_req=4, num_q_heads=8,
+                  num_kv_heads=4, head_dim=128, t_bucket=32, max_reqs=12)
+
+
 @pytest.mark.parametrize("seqs", [
     # Pure decode: one token per sequence, varying kv lens.
     [(1, 1), (1, 5), (1, 17), (1, 32)],
@@ -98,18 +137,18 @@ def run_both(case, sm_scale=0.125):
 ])
 def test_matches_reference(seqs):
     rng = np.random.default_rng(0)
-    case = build_case(rng, seqs=seqs, page_size=8, pages_per_req=4,
-                      num_q_heads=8, num_kv_heads=4, head_dim=128)
+    case = build_case(rng, seqs=seqs, **MODEL_TILE)
     got, want = run_both(case)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
 @pytest.mark.parametrize("kvh", [1, 2, 8])
 def test_gqa_group_and_mha(kvh):
+    # The grouping is the kernel's ``group`` static and the K/V buffers'
+    # width, whatever the tile: the small one.
     rng = np.random.default_rng(1)
-    case = build_case(rng, seqs=[(3, 11), (1, 4)], page_size=8,
-                      pages_per_req=4, num_q_heads=8, num_kv_heads=kvh,
-                      head_dim=128)
+    case = build_case(rng, seqs=[(3, 11), (1, 4)], num_q_heads=8,
+                      num_kv_heads=kvh, head_dim=128, **SMALL)
     got, want = run_both(case)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
@@ -117,12 +156,15 @@ def test_gqa_group_and_mha(kvh):
 def test_multi_tile_prefill_spans_tiles_and_pages():
     """q_len > bq spans several prefill tiles of one sequence; kv spans
     several pages. The exact chunked writeback must stitch tiles
-    seamlessly (no spill into the neighbouring decode row)."""
+    seamlessly (no spill into the neighbouring decode row). A tile of
+    16 rows is two 8-row chunks, so both a tile's inner stitch and the
+    stitch between tiles are there: three tiles a sequence, the second
+    one's last of a single row."""
     rng = np.random.default_rng(2)
     case = build_case(rng, seqs=[(40, 40), (1, 30), (33, 48)],
                       page_size=8, pages_per_req=8, num_q_heads=4,
-                      num_kv_heads=4, head_dim=128)
-    assert case["bq"] < 40  # the case really is multi-tile
+                      num_kv_heads=4, head_dim=128, bq=16, sb=2)
+    assert 8 < case["bq"] < 33  # the case really is multi-tile
     got, want = run_both(case)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
@@ -132,8 +174,8 @@ def test_many_decode_seqs_ragged_groups():
     that does not divide the batch."""
     rng = np.random.default_rng(7)
     seqs = [(1, k) for k in (1, 5, 17, 32, 9, 25, 13, 2, 31, 8, 20)]
-    case = build_case(rng, seqs=seqs, page_size=8, pages_per_req=4,
-                      num_q_heads=8, num_kv_heads=4, head_dim=128)
+    case = build_case(rng, seqs=seqs, **MODEL_TILE)
+    assert len(seqs) % case["sb"] and len(seqs) > case["sb"]
     got, want = run_both(case)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
@@ -161,9 +203,11 @@ def test_emit_state_cascade_merge_matches_full_attention(heads, tiles):
     including decode rows, whose state now comes from the decode-group
     branch."""
     rng = np.random.default_rng(4)
-    page_size, S = 8, 2
+    # Sixteen shared positions in one page: the suffix call's table is
+    # then two pages wide, one kv block of two page copies a sequence.
+    page_size, S = 16, 1
     case = build_case(rng, seqs=[(1, 20), (4, 24), (1, 33), (6, 22)],
-                      page_size=page_size, pages_per_req=6,
+                      page_size=page_size, pages_per_req=3,
                       num_q_heads=heads[0], num_kv_heads=heads[1],
                       head_dim=128, **tiles)
     D = case["k_pages"].shape[-1]
@@ -199,13 +243,15 @@ def test_emit_state_cascade_merge_matches_full_attention(heads, tiles):
 def test_fused_write_attend_matches_write_then_naive():
     """kind-3 kv-write programs + attention in ONE call: the cache must
     come back bit-identical to the XLA scatter, and the attention output
-    must see this step's freshly written pages."""
+    must see this step's freshly written pages. The kv-write programs
+    do not know the attention's tile: the small one (the engine tests
+    run the fused call at the model's own)."""
     rng = np.random.default_rng(5)
     page_size = 8
     seqs = [(1, 20), (5, 24), (1, 33), (7, 22)]
     case = build_case(rng, seqs=seqs, page_size=page_size,
                       pages_per_req=6, num_q_heads=8, num_kv_heads=4,
-                      head_dim=128)
+                      head_dim=128, bq=8, sb=2)
     T, bq, sb = case["T"], case["bq"], case["sb"]
     max_reqs = case["seq_info_np"].shape[0]
     kvh, hd = 4, 128
@@ -304,13 +350,16 @@ def reference_feat(case, sm_scale=0.125, *, window=0, logit_cap=0.0,
 
 
 MIXED_SEQS = [(1, 13), (12, 12), (1, 30), (5, 21)]
+# Each feature is a static argument and so a program of its own: on the
+# small tile (a 12-token prompt is still two tiles, four decode rows two
+# groups), the sequences of 30 and 21 past the window of 9.
+MIXED = dict(seqs=MIXED_SEQS, num_q_heads=8, num_kv_heads=4, head_dim=128,
+             **SMALL)
 
 
 def test_sliding_window_mixed_wave():
     rng = np.random.default_rng(10)
-    case = build_case(rng, seqs=MIXED_SEQS, page_size=8,
-                      pages_per_req=4, num_q_heads=8, num_kv_heads=4,
-                      head_dim=128)
+    case = build_case(rng, **MIXED)
     got, want = run_both_feat(case, window=9)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
     # The window genuinely restricts attention (long kv sequences
@@ -321,9 +370,7 @@ def test_sliding_window_mixed_wave():
 
 def test_softcap_mixed_wave():
     rng = np.random.default_rng(11)
-    case = build_case(rng, seqs=MIXED_SEQS, page_size=8,
-                      pages_per_req=4, num_q_heads=8, num_kv_heads=4,
-                      head_dim=128)
+    case = build_case(rng, **MIXED)
     got, want = run_both_feat(case, logit_cap=5.0)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
@@ -331,18 +378,14 @@ def test_softcap_mixed_wave():
 def test_alibi_mixed_wave():
     from vllm_distributed_tpu.models.common import alibi_slopes
     rng = np.random.default_rng(12)
-    case = build_case(rng, seqs=MIXED_SEQS, page_size=8,
-                      pages_per_req=4, num_q_heads=8, num_kv_heads=4,
-                      head_dim=128)
+    case = build_case(rng, **MIXED)
     got, want = run_both_feat(case, slopes=np.asarray(alibi_slopes(8)))
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
 def test_sinks_mixed_wave():
     rng = np.random.default_rng(13)
-    case = build_case(rng, seqs=MIXED_SEQS, page_size=8,
-                      pages_per_req=4, num_q_heads=8, num_kv_heads=4,
-                      head_dim=128)
+    case = build_case(rng, **MIXED)
     got, want = run_both_feat(
         case, sinks=rng.standard_normal(8).astype(np.float32))
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
@@ -391,9 +434,8 @@ def test_all_features_together_at_128_score_rows():
     rows a K/V head, in float32 at the float32 tolerance."""
     rng = np.random.default_rng(15)
     qh, kvh = WIDE
-    case = build_case(rng, seqs=BF16_SEQS, page_size=16, pages_per_req=2,
-                      num_q_heads=qh, num_kv_heads=kvh, head_dim=128,
-                      bq=8, sb=2)
+    case = build_case(rng, seqs=BF16_SEQS, num_q_heads=qh,
+                      num_kv_heads=kvh, head_dim=128, **SMALL)
     got, want = run_both_feat(
         case, window=11, logit_cap=4.0,
         slopes=np.float32(2.0) ** -np.arange(1, qh + 1, dtype=np.float32),
@@ -407,9 +449,11 @@ def test_bf16_matches_float32_reference(name):
     rng = np.random.default_rng(3)
     # The smallest tile and group: a trace of seconds (``test_bf16_mixed``
     # and the float32 cases above run the model's own sizes).
-    case = build_case(rng, seqs=seqs, page_size=16, pages_per_req=2,
-                      num_q_heads=qh, num_kv_heads=kvh, head_dim=128,
-                      dtype=jnp.bfloat16, bq=8, sb=2)
+    # One bucket of 24 tokens and 5 table rows, so that cases with the
+    # same heads and features ("prefill", "decode") are one program.
+    case = build_case(rng, seqs=seqs, num_q_heads=qh, num_kv_heads=kvh,
+                      head_dim=128, dtype=jnp.bfloat16, t_bucket=24,
+                      max_reqs=5, **SMALL)
     assert case["q"].dtype == case["k_pages"].dtype == jnp.bfloat16
     got, _ = run_both_feat(case, reference=False, **feats)
     assert got.dtype == jnp.bfloat16
@@ -418,6 +462,103 @@ def test_bf16_matches_float32_reference(name):
     want = reference_feat(upcast, **feats)
     np.testing.assert_allclose(np.float32(got), want, rtol=3e-2,
                                atol=3e-2)
+
+
+# ---------------------------------------------------------------------------
+# The page loop under a window: trimmed, not only masked.
+#
+# A window layer's prefill tile starts at the kv block that holds the
+# oldest position its first query sees and a decode sequence at the block
+# that holds ``kv_len - window`` (``_mega_kernel``). Held against the
+# masked XLA path over the WHOLE table, with every table slot in front of
+# the first visited block pointed at a page of NaN: an untrimmed loop
+# multiplies a zero probability by NaN and shows it. Dead slots inside the
+# first visited block carry the placeholder page, as the window group's
+# tables do (core/kv_cache_manager.py).
+#
+# What the trim needs is whole 128-position kv blocks in front of the
+# window: four a request, as before. What it does not need is sixteen
+# pages in each (the 8-token page these cases had: a minute a program to
+# lower): pages of 32 make a block of four, so a first visited block still
+# holds up to three dead slots.
+# ---------------------------------------------------------------------------
+
+TRIM_PAGE, TRIM_PAGES_PER_REQ, BLK = 32, 16, 128
+
+# (q_len, kv_len): prefill tiles past the window, decode groups whose
+# sequences start their walks at different blocks, a short one.
+TRIM_SEQS = [(1, 500), (40, 300), (1, 140), (1, 9), (24, 480), (1, 260)]
+
+
+def _trimmed_table(case, window: int):
+    """The case's table as a window group hands it over: slots that
+    ``num_window_dead_blocks`` frees read the placeholder page 0, and
+    every slot in front of a sequence's first visited kv block reads
+    the poisoned page."""
+    bt = case["block_tables_np"].copy()
+    poison = int(bt.max()) + 1
+    ppb = BLK // TRIM_PAGE
+    placeholders = 0
+    for q_start, q_len, kv_len, row in case["seq_info_np"][:case["num_seqs"]]:
+        computed = kv_len - q_len
+        dead = num_window_dead_blocks(computed, window, TRIM_PAGE)
+        first_block = max(computed - window + 1, 0) // BLK
+        bt[row, :dead] = 0
+        bt[row, :first_block * ppb] = poison
+        placeholders += max(dead - first_block * ppb, 0)
+    return bt, poison, placeholders
+
+
+def _run_on_poisoned_table(case, bt, **static):
+    nan_page = jnp.full((1, ) + case["k_pages"].shape[1:], jnp.nan,
+                        case["k_pages"].dtype)
+    # Page 0 is request row 0's first page: live data of another
+    # request, finite, read under the mask only.
+    got = unified_ragged_paged_attention_pallas(
+        case["q"], jnp.concatenate([case["k_pages"], nan_page]),
+        jnp.concatenate([case["v_pages"], nan_page]), case["desc"],
+        case["seq_info"], case["decode_list"], jnp.asarray(bt),
+        sm_scale=0.125, bq=case["bq"], sb=case["sb"], interpret=True,
+        **static)
+    return np.asarray(got)[:case["T"]]
+
+
+@pytest.mark.parametrize("window,qh,kvh,tiles", [
+    # A multiple of the 128-position kv block, and two that are not,
+    # under a group of 16 query heads on one K/V head (128 score rows a
+    # tile of 8: the window is a static, so each is a program, and the
+    # small tile's; its groups of two still pair sequences that start
+    # their walks at different blocks); two blocks under a group of 2 on
+    # four, at the model's own tile and group.
+    (128, 16, 1, dict(bq=8, sb=2)), (100, 16, 1, dict(bq=8, sb=2)),
+    (130, 16, 1, dict(bq=8, sb=2)), (256, 8, 4, {})],
+    ids=["128-16-1", "100-16-1", "130-16-1", "256-8-4"])
+def test_trimmed_loop_matches_masked_reference(window, qh, kvh, tiles):
+    rng = np.random.default_rng(window + qh)
+    case = build_case(rng, seqs=TRIM_SEQS, page_size=TRIM_PAGE,
+                      pages_per_req=TRIM_PAGES_PER_REQ, num_q_heads=qh,
+                      num_kv_heads=kvh, head_dim=128, **tiles)
+    want = naive_ragged_attention(
+        case["q"], case["k_pages"], case["v_pages"], case["block_tables"],
+        case["req_idx"], case["q_pos"], sm_scale=0.125, window=window)
+    bt, poison, placeholders = _trimmed_table(case, window)
+    assert (bt == poison).any(), "the case must trim something"
+    assert placeholders, "no dead slot inside a first visited block"
+    got = _run_on_poisoned_table(case, bt, window=window)
+    assert np.isfinite(got).all(), "a slot in front of the window was read"
+    np.testing.assert_allclose(got, np.asarray(want)[:case["T"]],
+                               rtol=2e-3, atol=2e-3)
+
+
+def test_untrimmed_program_reads_the_whole_table():
+    """The control of the test above: without a window the same table
+    poisons the output, so the poison is where the loop would walk."""
+    rng = np.random.default_rng(5)
+    case = build_case(rng, seqs=TRIM_SEQS[:2], page_size=TRIM_PAGE,
+                      pages_per_req=TRIM_PAGES_PER_REQ, num_q_heads=8,
+                      num_kv_heads=4, head_dim=128, bq=8, sb=2)
+    bt, poison, _ = _trimmed_table(case, 128)
+    assert not np.isfinite(_run_on_poisoned_table(case, bt)).all()
 
 
 # ---------------------------------------------------------------------------

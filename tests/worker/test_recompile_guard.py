@@ -9,7 +9,8 @@ worker label -> engine get_stats -> /metrics rendering)."""
 
 import numpy as np
 
-from tests.engine.test_llm_engine import checkpoint, make_engine  # noqa: F401
+from tests.engine.test_llm_engine import (PALLAS_GEOMETRY,  # noqa: F401
+                                          checkpoint, make_engine)
 from vllm_distributed_tpu.metrics.prometheus import render_metrics
 from vllm_distributed_tpu.sampling_params import SamplingParams
 
@@ -107,46 +108,13 @@ def test_mixed_wave_zero_recompiles_after_precompile(checkpoint,
             in render_metrics(stats))
 
 
-def test_mixed_wave_dispatches_unified_kernel(checkpoint, monkeypatch):
-    """Acceptance: mixed prefill+decode waves dispatch to the unified
-    mega-kernel, asserted via vdt:attn_kernel_calls_total through the
-    full stats path (interpret-mode Pallas backend on CPU)."""
-    monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
-    path, _ = checkpoint
-    engine = make_engine(path, max_num_batched_tokens=16, max_num_seqs=4)
-    rng = np.random.default_rng(12)
-    for i in range(2):
-        engine.add_request(
-            f"uk{i}", [int(x) for x in rng.integers(2, 127, size=3)],
-            SamplingParams(temperature=0.0, max_tokens=10,
-                           ignore_eos=True))
-    for _ in range(3):
-        engine.step()
-    engine.add_request(
-        "uk-long", [int(x) for x in rng.integers(2, 127, size=24)],
-        SamplingParams(temperature=0.0, max_tokens=3, ignore_eos=True))
-    for _ in range(200):
-        engine.step()
-        if not engine.has_unfinished_requests():
-            break
-    assert not engine.has_unfinished_requests()
-    stats = engine.get_stats()
-    calls = stats["attn_kernel_calls"]
-    # Every step (decode-only, prefill-only, and the mixed waves) rides
-    # the ONE unified kernel; no step fell back to the per-composition
-    # legacy kernels.
-    assert calls.get("unified", 0) > 0
-    assert "general" not in calls and "decode" not in calls
-    text = render_metrics(stats)
-    assert 'vdt:attn_kernel_calls_total{kernel="unified"}' in text
-
-
 def test_windowed_model_reaches_unified_kernel(tmp_path_factory,
                                                monkeypatch):
     """ISSUE 11 acceptance: sliding-window waves no longer increment the
     XLA-fallback counter — the window rides the mega-kernel's per-layer
     statics, so a Mistral-class model's every wave dispatches
-    unified."""
+    unified. (The mixed waves of a model without a window:
+    ``tests/engine/test_llm_engine.py``, on that file's Pallas engine.)"""
     import torch
     from transformers import MistralConfig, MistralForCausalLM
     monkeypatch.setenv("VDT_ATTENTION_BACKEND", "pallas")
@@ -160,7 +128,7 @@ def test_windowed_model_reaches_unified_kernel(tmp_path_factory,
     MistralForCausalLM(cfg).save_pretrained(path,
                                             safe_serialization=True)
     engine = make_engine(str(path), max_num_batched_tokens=16,
-                         max_num_seqs=4)
+                         max_num_seqs=4, **PALLAS_GEOMETRY)
     _run_traffic(engine, max_tokens=8)
     stats = engine.get_stats()
     calls = stats["attn_kernel_calls"]
