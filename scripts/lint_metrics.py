@@ -33,6 +33,15 @@ registry does not declare.
   ``VDT_QOS_MAX_TRACKED_TENANTS`` hash/bucket cap). A row that names
   such a family without naming its bound fails.
 
+* **stats keys are read** — a top-level key that a runner, worker or
+  scheduler ``get_stats`` writes (``stats["key"] = ...``, a key of the
+  ``stats = {...}`` / ``stats.update({...})`` / ``return {...}``
+  literal) must be named, quoted, by one of its readers:
+  ``metrics/prometheus.py`` (the render), ``engine/dp_client.py`` (the
+  DP merge) or ``entrypoints/openai/api_server.py`` (the ``/debug/*``
+  handlers). A key none of them names is an orphan: a counter kept up
+  on the step path for nobody.
+
 Usage::
 
     python scripts/lint_metrics.py [--package DIR] [--readme FILE]
@@ -41,6 +50,7 @@ Exit 0 when clean; exit 1 listing violations otherwise.
 """
 
 import argparse
+import ast
 import re
 import sys
 from pathlib import Path
@@ -62,6 +72,69 @@ EXPOSITION_MODULES = ("metrics/prometheus.py", "metrics/stats.py")
 # a family carrying one must document its cardinality bound — the
 # named token must appear on the metric's README table row.
 DYNAMIC_LABELS = {"tenant": "VDT_QOS_MAX_TRACKED_TENANTS"}
+
+
+# get_stats of the step path's three layers, and who may read a key.
+STATS_WRITERS = ("worker/model_runner.py", "worker/worker.py",
+                 "core/sched/scheduler.py")
+STATS_READERS = ("metrics/prometheus.py", "engine/dp_client.py",
+                 "entrypoints/openai/api_server.py")
+
+
+def _written_stats_keys(source: str) -> dict[str, int]:
+    """-> {top-level key a ``get_stats`` of this module writes: line}."""
+    keys: dict[str, int] = {}
+
+    def is_stats(node) -> bool:
+        return isinstance(node, ast.Name) and node.id == "stats"
+
+    for fn in ast.walk(ast.parse(source)):
+        if not (isinstance(fn, ast.FunctionDef)
+                and fn.name == "get_stats"):
+            continue
+        for node in ast.walk(fn):
+            literal = None
+            if isinstance(node, (ast.Assign, ast.AnnAssign)):
+                targets = (node.targets if isinstance(node, ast.Assign)
+                           else [node.target])
+                for t in targets:
+                    if (isinstance(t, ast.Subscript) and is_stats(t.value)
+                            and isinstance(t.slice, ast.Constant)
+                            and isinstance(t.slice.value, str)):
+                        keys[t.slice.value] = node.lineno
+                    elif is_stats(t):
+                        literal = node.value
+            elif (isinstance(node, ast.Call)
+                  and isinstance(node.func, ast.Attribute)
+                  and node.func.attr == "update"
+                  and is_stats(node.func.value) and node.args):
+                literal = node.args[0]
+            elif isinstance(node, ast.Return):
+                literal = node.value
+            if isinstance(literal, ast.Dict):
+                for k in literal.keys:
+                    if (isinstance(k, ast.Constant)
+                            and isinstance(k.value, str)):
+                        keys[k.value] = k.lineno
+    return keys
+
+
+def orphan_stats_keys(package: Path) -> list[tuple[str, str, int]]:
+    """-> [(writer module, key, line)] of keys no reader names."""
+    readers = "".join((package / rel).read_text(encoding="utf-8")
+                      for rel in STATS_READERS
+                      if (package / rel).is_file())
+    orphans = []
+    for rel in STATS_WRITERS:
+        path = package / rel
+        if not path.is_file():
+            continue
+        written = _written_stats_keys(path.read_text(encoding="utf-8"))
+        orphans += [(rel, key, line)
+                    for key, line in sorted(written.items())
+                    if f'"{key}"' not in readers
+                    and f"'{key}'" not in readers]
+    return orphans
 
 
 def collect(package: Path) -> tuple[set, set]:
@@ -182,6 +255,11 @@ def main(argv: list[str]) -> int:
                     f"{name}: carries a dynamic label but its README "
                     f"row has no cardinality note (mention `{bound}`, "
                     f"the bucketing bound, on the row)")
+    for rel, key, line in orphan_stats_keys(args.package):
+        problems.append(
+            f'{rel}:{line}: get_stats writes "{key}" and no reader '
+            f"names it ({', '.join(STATS_READERS)}): an orphaned "
+            f"stats key")
     if not problems:
         return 0
     print("vdt: metric documentation drift:", file=sys.stderr)

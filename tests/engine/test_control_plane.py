@@ -385,50 +385,48 @@ def test_standby_resurrect_is_fenced_noop(ha):
 
 
 # ---------------------------------------------------------------------------
-# Richer scaling signals (VDT_FLEET_SIGNALS): decision matrix
+# The goodput signal (VDT_FLEET_SIGNALS): decision matrix
 # ---------------------------------------------------------------------------
-_BANDWIDTH_PHASE = {"device_seconds": 1.0, "host_seconds": 0.0,
-                    "flops": 1.0, "bytes": 1e12}
-_COMPUTE_PHASE = {"device_seconds": 1.0, "host_seconds": 0.0,
-                  "flops": 1e12, "bytes": 1.0}
-_PEAKS = {"flops": 1e12, "hbm": 1e12}
-
-
-def _feed_phases(dp, entry) -> None:
-    for c in dp.clients:
-        c.stats["perf_phases"] = {"decode": dict(entry)}
-        c.stats["perf_peaks"] = dict(_PEAKS)
-
-
 def test_signals_off_is_occupancy_only(monkeypatch):
-    """Default: bandwidth-bound phases and starved tenants shift
-    nothing — the decision is exactly PR 16's occupancy comparison."""
+    """Default: starved tenants shift nothing — the decision is
+    exactly PR 16's occupancy comparison."""
     dp = make_fleet(monkeypatch, VDT_FLEET_LOW_WATERMARK="0")
     assert dp.fleet.signals is False
     _pressure(dp, 5)  # occupancy 10/16 = 0.625 < 0.85
-    _feed_phases(dp, _BANDWIDTH_PHASE)
     dp.observe_goodput({"gold": 0.1})  # stored, but not consulted
     assert dp.fleet._goodput == {"gold": 0.1}
     _tick(dp, 3)
     assert len(dp.clients) == 2 and dp.fleet.scale_outs == 0
 
 
-def test_signals_roofline_phase_shifts_scale_out(monkeypatch):
-    """A memory-bound fleet scales out at occupancy a compute-bound
-    one rides: 0.625 * (1 + 0.5 * bandwidth_frac) crosses 0.85 only
-    when the attributed phases sit on the bandwidth roof."""
-    dp = make_fleet(monkeypatch, VDT_FLEET_SIGNALS="1",
-                    VDT_FLEET_ROOFLINE_WEIGHT="0.5",
-                    VDT_FLEET_LOW_WATERMARK="0")
-    _pressure(dp, 5)
-    _feed_phases(dp, _COMPUTE_PHASE)
-    _tick(dp, 2)
-    assert len(dp.clients) == 2  # compute-bound: no inflation
-    _feed_phases(dp, _BANDWIDTH_PHASE)
-    _tick(dp)
-    assert dp.fleet._memory_bound_frac([0, 1]) == 1.0
-    assert len(dp.clients) == 3  # same occupancy, memory-bound: grow
-    assert dp.fleet.scale_outs == 1
+@pytest.mark.parametrize("waiting, replicas, outs, ins", [
+    (0, 1, 0, 1),   # occupancy 0 <= 0.3: one replica retires
+    (5, 2, 0, 0),   # 10/16 = 0.625, between the watermarks: nothing
+    (7, 3, 1, 0),   # 14/16 = 0.875 >= 0.85: one replica more
+], ids=["under_low", "between", "over_high"])
+def test_signals_on_with_healthy_tenants_decides_as_off(
+        monkeypatch, waiting, replicas, outs, ins):
+    """What is left of VDT_FLEET_SIGNALS is the goodput floor: with no
+    tenant under it the decision is the occupancy comparison, tick for
+    tick, on either side of both watermarks."""
+    decided = {}
+    for signals in ("0", "1"):
+        dp = make_fleet(monkeypatch, VDT_FLEET_SIGNALS=signals,
+                        VDT_FLEET_LOW_WATERMARK="0.3",
+                        VDT_FLEET_HIGH_WATERMARK="0.85",
+                        VDT_FLEET_EVAL_TICKS="2")
+        assert dp.fleet.signals is (signals == "1")
+        dp.observe_goodput({"gold": 0.9, "free": 0.5})  # floor 0.5
+        _pressure(dp, waiting)
+        walk = []
+        for _ in range(3):
+            _tick(dp)
+            walk.append((len(dp._live), len(dp.fleet._active()),
+                         dp.fleet.scale_outs, dp.fleet.scale_ins,
+                         dict(dp.fleet.freezes)))
+        decided[signals] = walk
+    assert decided["1"] == decided["0"]
+    assert decided["1"][-1][1:4] == (replicas, outs, ins)
 
 
 def test_signals_goodput_floor_forces_out_and_vetoes_in(monkeypatch):

@@ -1,8 +1,10 @@
 """The step's phases and counters (ISSUE 25), counts and structure only:
 phases that partition the synchronous step, the period they sum to, the
 host gap and the host stall; the scheduler's queue wait and step tokens;
-the runner's K/V tokens read."""
+the runner's K/V tokens read and its one closed form of a step's attention
+pairs."""
 
+import numpy as np
 import pytest
 from transformers import LlamaConfig
 
@@ -10,6 +12,7 @@ from tests.conftest import make_config, make_request
 from vllm_distributed_tpu.config import (CacheConfig, EngineConfig,
                                          LoadConfig, ModelConfig,
                                          SchedulerConfig)
+from vllm_distributed_tpu.core.kv_cache_utils import window_step_work
 from vllm_distributed_tpu.core.sched.output import ModelRunnerOutput
 from vllm_distributed_tpu.core.sched.scheduler import Scheduler
 from vllm_distributed_tpu.engine.llm_engine import LLMEngine
@@ -209,6 +212,60 @@ def test_kv_tokens_read_of_a_prefill_chunk_and_two_decodes(engine):
                    "vdt:step_host_stall_seconds_count",
                    'vdt:step_phase_seconds_count{phase="loop"}'):
         assert family in text, family
+
+
+def _work_token_by_token(rows, window: int):
+    """(window tokens read, full pairs, window pairs) of each row, one
+    query at a time: the query at position p sees the keys (p - window,
+    p], all of them in a full layer; a window layer reads a key once
+    however many of the row's queries see it."""
+    out = []
+    for ctx, n in rows:
+        read, full, windowed = set(), 0, 0
+        for p in range(ctx, ctx + n):
+            seen = range(max(0, p - window + 1), p + 1)
+            read.update(seen)
+            windowed += len(seen)
+            full += p + 1
+        out.append((len(read), full, windowed))
+    return out
+
+
+_MIXED = [(40, 24), (63, 1), (7, 1)]
+_SEVERAL = [(0, 8), (20, 30), (100, 16), (31, 1), (5, 64)]
+
+
+@pytest.mark.parametrize("rows, window", [
+    ([(0, 8)], 32),      # a prompt inside the window
+    ([(20, 30)], 32),    # a chunk that crosses it
+    ([(100, 16)], 32),   # a chunk wholly past it
+    ([(31, 1)], 32),     # the last decode that sees everything
+    ([(32, 1)], 32),     # the first that does not
+    ([(0, 1)], 32),
+    ([(31, 2)], 32),     # straddles by one
+    ([(5, 64)], 32),     # a chunk longer than the window, from under it
+    (_MIXED, 32),        # a chunk and two decodes
+    (_SEVERAL, 32),
+    (_SEVERAL, 4096),    # a window no context reaches
+], ids=lambda v: "-".join(f"{c}+{n}" for c, n in v)
+    if isinstance(v, list) else f"W{v}")
+def test_a_steps_attention_work_token_by_token(rows, window):
+    """(context, scheduled) rows as ``_prepare_inputs`` hands them over:
+    ``seq_info``'s query lengths and context ends, int32."""
+    q_len = np.array([n for _, n in rows], np.int32)
+    end = np.array([c + n for c, n in rows], np.int32)
+    got = np.stack(window_step_work(q_len, end, window), axis=1)
+    assert got.dtype == np.int64
+    assert got.tolist() == [list(r) for r in
+                            _work_token_by_token(rows, window)]
+    # A batch is its rows, one by one.
+    alone = [np.stack(window_step_work(q_len[i:i + 1], end[i:i + 1],
+                                       window), axis=1)[0]
+             for i in range(len(rows))]
+    assert got.sum(axis=0).tolist() == np.sum(alone, axis=0).tolist()
+    if window > int(end.max()):
+        assert got[:, 0].tolist() == end.tolist()
+        assert got[:, 1].tolist() == got[:, 2].tolist()
 
 
 @pytest.mark.parametrize("mla,tpla,backend,max_q,want", [

@@ -3,8 +3,10 @@
 Runs scripts/lint_metrics.py over the real package + README (tier-1
 mechanical check) and unit-tests the linter's failure modes on
 synthetic trees: an emitted-but-undocumented metric, a metric without
-HELP/TYPE exposition, and an orphaned README row."""
+HELP/TYPE exposition, an orphaned README row, and a stats key that
+``get_stats`` writes for no reader."""
 
+import importlib.util
 import subprocess
 import sys
 from pathlib import Path
@@ -156,3 +158,43 @@ def test_dynamic_label_with_cardinality_note_passes(tmp_path):
         prometheus=_TENANT_PROM)
     res = _run("--package", str(pkg), "--readme", str(readme))
     assert res.returncode == 0, res.stderr
+
+
+# ---------------------------------------------------------------------------
+# Stats keys: what a runner, worker or scheduler get_stats writes, one of
+# the render, the DP merge or a /debug handler names.
+# ---------------------------------------------------------------------------
+def test_package_stats_keys_all_have_a_reader():
+    spec = importlib.util.spec_from_file_location("lint_metrics", SCRIPT)
+    lint_metrics = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(lint_metrics)
+    package = REPO / "vllm_distributed_tpu"
+    written = {rel: lint_metrics._written_stats_keys(
+        (package / rel).read_text()) for rel in lint_metrics.STATS_WRITERS}
+    # The walk finds the keys (an empty walk would pass anything).
+    assert "step_kv_tokens_read" in written["worker/model_runner.py"]
+    assert "state_row_bytes" in written["worker/model_runner.py"]
+    assert "workers" in written["worker/worker.py"]
+    assert "num_preemptions" in written["core/sched/scheduler.py"]
+    assert lint_metrics.orphan_stats_keys(package) == []
+
+
+def test_orphaned_stats_key_is_caught(tmp_path):
+    """A key that outlived its readers (PR 46 took both ends of the
+    attribution table at once): written on the step path, named by no
+    render, merge or handler."""
+    pkg, readme = _tree(tmp_path, "x = 1\n", "# nothing here\n",
+                        prometheus='KEYS = ("kept", "also_kept")\n')
+    (pkg / "worker").mkdir()
+    (pkg / "worker" / "model_runner.py").write_text(
+        "class Runner:\n"
+        "    def get_stats(self):\n"
+        '        stats = {"kept": 1}\n'
+        '        stats["ghost_key"] = 2\n'
+        '        stats.update({"also_kept": 3})\n'
+        "        return stats\n")
+    res = _run("--package", str(pkg), "--readme", str(readme))
+    assert res.returncode == 1
+    assert '"ghost_key"' in res.stderr
+    assert "orphaned stats key" in res.stderr
+    assert "kept\"" not in res.stderr

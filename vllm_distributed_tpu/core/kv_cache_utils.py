@@ -86,6 +86,28 @@ def num_window_dead_blocks(num_computed_tokens: int, window: int,
     return np.maximum(0, num_computed_tokens - window + 1) // block_size
 
 
+def window_step_work(q_len, end, window: int):
+    """What one step's rows cost a window layer and a full layer, row by
+    row: ``q_len`` queries a row whose context ends at ``end`` (computed
+    plus scheduled). A query at position ``p`` sees ``p + 1`` keys in a
+    full layer and ``min(p + 1, window)`` in a window layer (the window
+    rule of ``num_window_dead_blocks``), so a window layer reads the
+    ``min(end, window - 1 + q_len)`` tokens that some query of the row
+    sees. Returns (window tokens read, full pairs, window pairs), int64
+    arrays of the rows' shape. The one closed form of a step's attention
+    pairs: the runner's histograms observe its sums
+    (worker/model_runner.py ``_fill_window_group``)."""
+    q_len = np.asarray(q_len, np.int64)
+    end = np.asarray(end, np.int64)
+    tokens_read = np.minimum(end, window - 1 + q_len)
+    full = q_len * (end - q_len) + q_len * (q_len + 1) // 2
+    over = np.clip(end - window, 0, q_len)   # queries with p + 1 > window
+    under = q_len - over
+    windowed = (over * window + under * (end - q_len)
+                + under * (under + 1) // 2)
+    return tokens_read, full, windowed
+
+
 def window_group_pool_blocks(window: int, block_size: int,
                              max_num_seqs: int,
                              max_num_batched_tokens: int,

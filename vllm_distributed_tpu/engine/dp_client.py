@@ -1086,35 +1086,6 @@ class DPEngineClient(EngineCoreClient):
                     for k, v in leaves.items():
                         dst[k] = dst.get(k, 0) + int(v)
             agg["tenants"] = merged_tenants
-        # Performance-attribution plane: nested numeric maps summed per
-        # label across replicas — hbm_bytes {kind: bytes}, perf_attrib
-        # {key: {device_seconds, flops, bytes, dispatches}} and
-        # perf_phases {phase: {...}} (model_flops is flat and already
-        # summed above; mfu/mbu are per-worker ratios riding the
-        # workers map union below, never summed). Peaks take the max —
-        # replicas share identical hardware, and summing a peak would
-        # fabricate a fleet-wide roofline no chip has.
-        for perf_key in ("hbm_bytes", "perf_attrib", "perf_phases"):
-            maps = [s[perf_key] for s in per
-                    if isinstance(s.get(perf_key), dict)]
-            if not maps:
-                continue
-            merged_perf: dict = {}
-            for m in maps:
-                for k, v in m.items():
-                    if isinstance(v, dict):
-                        dst = merged_perf.setdefault(k, {})
-                        for leaf, n in v.items():
-                            dst[leaf] = dst.get(leaf, 0) + n
-                    elif isinstance(v, (int, float)):
-                        merged_perf[k] = merged_perf.get(k, 0) + v
-            agg[perf_key] = merged_perf
-        peak_maps = [s["perf_peaks"] for s in per
-                     if isinstance(s.get("perf_peaks"), dict)]
-        if peak_maps:
-            agg["perf_peaks"] = {
-                k: max(float(p.get(k, 0.0)) for p in peak_maps)
-                for k in {k for p in peak_maps for k in p}}
         # Labeled histogram families (step phases, step tokens):
         # {label value -> histogram dict}, merged per label value.
         for key in LABELED_HISTOGRAMS:

@@ -100,12 +100,10 @@ class FleetController:
         self.wedge_s = envs.VDT_FLEET_WEDGE_S
         self.drain_s = envs.VDT_FLEET_DRAIN_S
         self.resplit_ratio = envs.VDT_FLEET_RESPLIT_RATIO
-        # Richer scaling signals (VDT_FLEET_SIGNALS): the roofline
-        # phase inflates occupancy for a memory-bound fleet; a tenant
-        # under its goodput floor is scale-out pressure and a scale-in
-        # veto. Off (default) the decision is occupancy-only.
+        # The goodput signal (VDT_FLEET_SIGNALS): a tenant under its
+        # goodput floor is scale-out pressure and a scale-in veto. Off
+        # (default) the decision is occupancy-only.
         self.signals = envs.VDT_FLEET_SIGNALS
-        self.roofline_weight = envs.VDT_FLEET_ROOFLINE_WEIGHT
         self.goodput_floor = envs.VDT_FLEET_GOODPUT_FLOOR
         self._goodput: dict[str, float] = {}
         self.max_num_seqs = max(1, config.scheduler_config.max_num_seqs)
@@ -447,46 +445,14 @@ class FleetController:
                   .get("num_waiting_reqs", 0)) for i in members)
         return (live + waiting) / cap
 
-    def _memory_bound_frac(self, members: list[int]) -> float:
-        """Device-time fraction of the fleet's attributed phases that
-        sit on the bandwidth roof (PR 14's classifier over the
-        per-replica perf_phases/perf_peaks riding the stats feed)."""
-        from vllm_distributed_tpu.metrics.costmodel import \
-            classify_roofline
-        total = bound = 0.0
-        for i in members:
-            stats = self._snap.get(i, ({}, 0.0))[0]
-            phases = stats.get("perf_phases")
-            peaks = stats.get("perf_peaks")
-            if not isinstance(phases, dict) or not isinstance(peaks,
-                                                              dict):
-                continue
-            for entry in phases.values():
-                if not isinstance(entry, dict):
-                    continue
-                dev_s = float(entry.get("device_seconds", 0.0) or 0.0)
-                if dev_s <= 0.0:
-                    continue
-                total += dev_s
-                if classify_roofline(entry, peaks) == "bandwidth":
-                    bound += dev_s
-        return bound / total if total > 0.0 else 0.0
-
     def _evaluate_scaling(self, now: float) -> None:
         active = self._active()
         occ = self._occupancy(active)
         starved = False
-        if self.signals:
-            # Memory-bound waves gain little from batching deeper on
-            # the same replicas — inflate effective occupancy so the
-            # fleet scales out earlier and resists scale-in.
-            occ *= 1.0 + self.roofline_weight \
-                * self._memory_bound_frac(active)
+        if self.signals and self.goodput_floor > 0 and self._goodput:
             # An SLO-starved tenant is scale-out pressure regardless
             # of occupancy, and vetoes scale-in.
-            if self.goodput_floor > 0 and self._goodput:
-                starved = (min(self._goodput.values())
-                           < self.goodput_floor)
+            starved = min(self._goodput.values()) < self.goodput_floor
         self._high_ticks = self._high_ticks + 1 \
             if occ >= self.high_wm or starved else 0
         self._low_ticks = self._low_ticks + 1 \

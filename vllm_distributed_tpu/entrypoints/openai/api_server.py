@@ -499,87 +499,11 @@ async def _debug_kv_cache_json(engine: AsyncLLM) -> dict:
     return {"now_monotonic": time.monotonic(), "engine_cores": cores}
 
 
-async def _debug_perf_json(engine: AsyncLLM) -> dict:
-    """Performance-attribution snapshot (metrics/costmodel.py): the
-    top-N (kernel family x phase x token bucket) rows by measured
-    device-seconds, each with analytic FLOPs/bytes and achieved vs
-    peak rates, plus fleet totals (MFU/MBU per worker, HBM traffic by
-    kind, roofline placement per phase). DP-merged the same way
-    /metrics is; include_events=False so this poll never steals the
-    scrape's timeline drain."""
-    from vllm_distributed_tpu import envs
-    from vllm_distributed_tpu.metrics.costmodel import classify_roofline
-    stats = await engine.get_stats(include_events=False)
-    attrib = stats.get("perf_attrib") or {}
-    peaks = stats.get("perf_peaks") or {}
-    peak_f = float(peaks.get("flops", 0.0))
-    peak_b = float(peaks.get("hbm", 0.0))
-    rows = []
-    for key, e in attrib.items():
-        if not isinstance(e, dict):
-            continue
-        parts = key.split("/")
-        dev_s = float(e.get("device_seconds", 0.0))
-        flops = float(e.get("flops", 0.0))
-        byts = float(e.get("bytes", 0.0))
-        rows.append({
-            "key": key,
-            "kernel": parts[0] if parts else key,
-            "phase": parts[1] if len(parts) > 1 else "",
-            "bucket": parts[2] if len(parts) > 2 else "",
-            "dispatches": int(e.get("dispatches", 0)),
-            "device_seconds": dev_s,
-            "flops": flops,
-            "hbm_bytes": byts,
-            "tflops_per_s": flops / dev_s / 1e12 if dev_s else 0.0,
-            "gb_per_s": byts / dev_s / 1e9 if dev_s else 0.0,
-            "frac_peak_flops": (flops / (dev_s * peak_f)
-                                if dev_s and peak_f else 0.0),
-            "frac_peak_bw": (byts / (dev_s * peak_b)
-                             if dev_s and peak_b else 0.0),
-        })
-    rows.sort(key=lambda r: r["device_seconds"], reverse=True)
-    top_n = envs.VDT_PERF_TOPN
-    dropped = max(len(rows) - top_n, 0)
-    phases = stats.get("perf_phases") or {}
-    roofline = {p: classify_roofline(e, peaks)
-                for p, e in phases.items() if isinstance(e, dict)}
-    workers = stats.get("workers") or {}
-    utilization = {
-        w: {"mfu": s.get("mfu"), "mbu": s.get("mbu")}
-        for w, s in sorted(workers.items())
-        if isinstance(s, dict) and ("mfu" in s or "mbu" in s)
-    }
-    return {
-        "attribution": rows[:top_n],
-        "rows_dropped": dropped,
-        "totals": {
-            "model_flops": stats.get("model_flops"),
-            "hbm_bytes": stats.get("hbm_bytes"),
-            "device_seconds": sum(
-                float(e.get("device_seconds", 0.0))
-                for e in attrib.values() if isinstance(e, dict)),
-        },
-        "utilization": utilization,
-        "roofline_bound": roofline,
-        "phases": phases,
-        "peaks": peaks,
-    }
-
-
 async def debug_requests(request: web.Request) -> web.Response:
     """Live per-request state: current phase, per-phase ages from the
     lifecycle timeline, progress counters, KV footprint."""
     return web.json_response(
         await _debug_requests_json(request.app[ENGINE_KEY]))
-
-
-async def debug_perf(request: web.Request) -> web.Response:
-    """Performance attribution: kernel-family device-seconds / FLOPs /
-    bytes table, MFU/MBU, roofline placement. Admission-exempt GET —
-    a saturated server is exactly the one worth attributing."""
-    return web.json_response(
-        await _debug_perf_json(request.app[ENGINE_KEY]))
 
 
 async def debug_kv_cache(request: web.Request) -> web.Response:
@@ -1129,10 +1053,7 @@ async def start_profile(request: web.Request) -> web.Response:
 
 
 async def stop_profile(request: web.Request) -> web.Response:
-    """End the capture. The response bundles the trace dirs WITH the
-    current performance-attribution snapshot, so one
-    RPC pair yields an xplane trace plus the self-describing analytic
-    table that explains it."""
+    """End the capture; the answer names the trace directories."""
     try:
         dirs = _profile_dirs(
             await request.app[ENGINE_KEY].profile("stop"))
@@ -1140,13 +1061,8 @@ async def stop_profile(request: web.Request) -> web.Response:
         return web.json_response(
             {"error": {"message": str(e), "type": "capture_conflict",
                        "code": 409}}, status=409)
-    body = {"status": "stopped", "dir": dirs[0], "dirs": dirs}
-    try:
-        body["perf"] = await _debug_perf_json(request.app[ENGINE_KEY])
-    except Exception:  # noqa: BLE001 - the trace dirs are the payload;
-        # a stats hiccup must not fail the stop.
-        pass
-    return web.json_response(body)
+    return web.json_response({"status": "stopped", "dir": dirs[0],
+                              "dirs": dirs})
 
 
 # ---------------------------------------------------------------------------
@@ -1695,7 +1611,6 @@ def build_app(engine: AsyncLLM, model_name: str,
     app.router.add_get("/debug/requests", debug_requests)
     app.router.add_get("/debug/engine", debug_engine)
     app.router.add_get("/debug/kv_cache", debug_kv_cache)
-    app.router.add_get("/debug/perf", debug_perf)
     app.router.add_get("/debug/trace", debug_trace)
     app.router.add_get("/debug/correctness", debug_correctness)
     app.router.add_post("/v1/completions", completions)

@@ -83,19 +83,6 @@ environment_variables: dict[str, Callable[[], Any]] = {
     # perf.capture_stall fault drill pins this).
     "VDT_PROFILE_MAX_S":
     lambda: float(os.getenv("VDT_PROFILE_MAX_S", "120")),
-    # Performance-attribution plane (metrics/costmodel.py): "1" builds
-    # the analytic per-dispatch cost model at model load and charges
-    # every runner dispatch with FLOPs/HBM bytes against measured
-    # device time (vdt:mfu / vdt:mbu / vdt:hbm_bytes_total /
-    # vdt:roofline_bound + GET /debug/perf). "0" reverts wholesale:
-    # no cost model is constructed and the runner's per-step charge is
-    # a single None check.
-    "VDT_PERF_ATTRIB":
-    lambda: os.getenv("VDT_PERF_ATTRIB", "1") == "1",
-    # Row cap of the GET /debug/perf attribution table (rows ranked by
-    # device-seconds; the response reports how many were dropped).
-    "VDT_PERF_TOPN":
-    lambda: max(1, int(os.getenv("VDT_PERF_TOPN", "20"))),
     # Directory where multi-host follower processes publish their
     # telemetry snapshots (shm-ring read side + device stats) for host
     # 0's stats plane to fold in; "" disables the export.
@@ -303,20 +290,15 @@ environment_variables: dict[str, Callable[[], Any]] = {
     # elected leader replays or aborts whatever it finds here.
     "VDT_FLEET_JOURNAL_DIR":
     lambda: os.getenv("VDT_FLEET_JOURNAL_DIR", ""),
-    # Richer scaling signals: "1" folds the roofline phase (memory- vs
-    # compute-bound fraction, PR 14's cost model) and per-tenant goodput
-    # (PR 13's SLO scoring) into the scale-out/in decision — a memory-
-    # bound or goodput-starved fleet scales out earlier and resists
-    # scale-in. "0" (default) decides on occupancy alone.
+    # The goodput signal: "1" folds per-tenant goodput (PR 13's SLO
+    # scoring) into the scale-out/in decision — a goodput-starved fleet
+    # scales out earlier and resists scale-in. "0" (default) decides on
+    # occupancy alone.
     "VDT_FLEET_SIGNALS":
     lambda: os.getenv("VDT_FLEET_SIGNALS", "0") == "1",
-    # Signal weights: occupancy is inflated by (1 + WEIGHT *
-    # memory_bound_fraction), and a min per-tenant goodput below FLOOR
-    # counts as scale-out pressure / vetoes scale-in. FLOOR <= 0
-    # disables the goodput term even with signals on.
-    "VDT_FLEET_ROOFLINE_WEIGHT":
-    lambda: max(0.0, float(os.getenv("VDT_FLEET_ROOFLINE_WEIGHT",
-                                     "0.5"))),
+    # A min per-tenant goodput below FLOOR counts as scale-out pressure
+    # / vetoes scale-in. FLOOR <= 0 disables the term even with signals
+    # on.
     "VDT_FLEET_GOODPUT_FLOOR":
     lambda: float(os.getenv("VDT_FLEET_GOODPUT_FLOOR", "0.5")),
     # --- SSM state cache (core/state_cache.py) --------------------------

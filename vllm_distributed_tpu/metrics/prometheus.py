@@ -124,12 +124,6 @@ _COUNTERS = {
                               "checkpoint journal instead of discarded "
                               "(hierarchical tiering's journal-as-"
                               "second-tier; VDT_KV_TIERING=1)"),
-    # Performance-attribution plane (metrics/costmodel.py): analytic
-    # model FLOPs charged per dispatch, summed across DP replicas.
-    "model_flops": ("vdt:model_flops_total",
-                    "Analytic model FLOPs charged for dispatched "
-                    "waves (useful FLOPs over real tokens; the "
-                    "vdt:mfu numerator)"),
 }
 
 
@@ -255,13 +249,6 @@ LABELED_METRICS = {
     "vdt:moe_experts_published": ("worker", ),
     # One sequence's fixed-size recurrent state (models/bailing.py).
     "vdt:state_row_bytes": ("worker", ),
-    # Performance-attribution plane (metrics/costmodel.py): per-worker
-    # utilization ratios, analytic HBM traffic by kind, and the
-    # per-phase roofline placement.
-    "vdt:mfu": ("worker", ),
-    "vdt:mbu": ("worker", ),
-    "vdt:hbm_bytes_total": ("kind", ),
-    "vdt:roofline_bound": ("phase", ),
     # Telemetry plane: per-connector KV transfer + shm ring.
     "vdt:kv_transfer_bytes_total": ("connector", "direction"),
     "vdt:kv_transfer_failures_total": ("connector", ),
@@ -530,14 +517,6 @@ def _render_worker_telemetry(workers: dict) -> list[str]:
          "HBM bytes of one sequence's recurrent and convolution state "
          "over every linear-attention layer: what a request costs "
          "whatever its length"),
-        # Performance-attribution plane: this worker's analytic FLOPs
-        # / bytes over its measured device time against its mesh peak.
-        ("mfu", "vdt:mfu", "gauge",
-         "Model FLOPs utilization: analytic useful FLOPs over "
-         "measured device seconds x mesh peak FLOP/s"),
-        ("mbu", "vdt:mbu", "gauge",
-         "Memory-bandwidth utilization: analytic HBM bytes over "
-         "measured device seconds x mesh peak bandwidth"),
     )
     for key, name, kind, help_text in families:
         series = [(w, s[key]) for w, s in sorted(workers.items())
@@ -667,46 +646,6 @@ def _render_qcomm(transport_qcomm, remote=None) -> list[str]:
               "inapplicable axis, sub-byte dtype)",
               f"# TYPE {name} counter",
               f"{name} {sum(int(e['fallbacks']) for e in merged.values())}"]
-    return lines
-
-
-def _render_perf(stats: dict) -> list[str]:
-    """Performance-attribution families: analytic HBM traffic by kind
-    and the per-phase roofline placement, classified at RENDER time
-    from the (possibly DP-merged) phase accumulators + hardware peaks
-    — classifications are never merged, only recomputed."""
-    lines: list[str] = []
-    hbm = stats.get("hbm_bytes")
-    if isinstance(hbm, dict) and hbm:
-        name = "vdt:hbm_bytes_total"
-        lines += [f"# HELP {name} Analytic HBM bytes charged for "
-                  "dispatched waves, by traffic kind (weights = "
-                  "streamed parameters, kv_read/kv_write = paged KV + "
-                  "SSM state rows, activations = residual stream + "
-                  "logits)",
-                  f"# TYPE {name} counter"]
-        lines += [f'{name}{{kind="{k}"}} {int(hbm[k])}'
-                  for k in sorted(hbm)
-                  if isinstance(hbm[k], (int, float))]
-    phases = stats.get("perf_phases")
-    peaks = stats.get("perf_peaks")
-    if (isinstance(phases, dict) and phases
-            and isinstance(peaks, dict)):
-        from vllm_distributed_tpu.metrics.costmodel import (
-            ROOFLINE_CODES, classify_roofline)
-        name = "vdt:roofline_bound"
-        lines += [f"# HELP {name} Roofline placement of each step "
-                  "phase from measured device time vs analytic "
-                  "FLOPs/bytes (0 = host-bound, 1 = bandwidth-bound, "
-                  "2 = compute-bound)",
-                  f"# TYPE {name} gauge"]
-        for phase in sorted(phases):
-            entry = phases[phase]
-            if not isinstance(entry, dict):
-                continue
-            bound = classify_roofline(entry, peaks)
-            lines.append(f'{name}{{phase="{phase}"}} '
-                         f'{ROOFLINE_CODES[bound]}')
     return lines
 
 
@@ -966,7 +905,6 @@ def render_metrics(stats: dict) -> str:
     lines += _render_qcomm((transport or {}).get("qcomm")
                            if isinstance(transport, dict) else None,
                            stats.get("qcomm_traced_remote"))
-    lines += _render_perf(stats)
     kv_cache = stats.get("kv_cache")
     if isinstance(kv_cache, dict) and kv_cache:
         lines += _render_kv_cache(kv_cache)

@@ -369,15 +369,6 @@ def get_model(config: EngineConfig, mesh,
         arch.kv_pack //= 2
     model = model_cls(arch)
 
-    # Performance-attribution plane (metrics/costmodel.py): the analytic
-    # per-dispatch FLOP/byte model is priced ONCE here, from the final
-    # arch shapes (post TPLA/quant decisions), and rides the arch
-    # so every runner variant (single-program, PP stages) charges
-    # dispatches against the same constants. VDT_PERF_ATTRIB=0 attaches
-    # None — the runners' per-step charge degrades to one None check.
-    from vllm_distributed_tpu.metrics.costmodel import resolve_cost_model
-    arch.cost_model = resolve_cost_model(model, config, mesh=mesh)
-
     load_format = config.load_config.load_format
     model_path = config.model_config.model
     if load_format == "sharded_state":

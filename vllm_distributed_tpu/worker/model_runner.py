@@ -316,24 +316,6 @@ class TPUModelRunner:
         # count behind vdt:precompile_graphs_total.
         self.attn_kernel_calls: dict[str, int] = {}
         self.precompile_graphs = 0
-        # Performance-attribution plane (metrics/costmodel.py): the
-        # loader priced the model once (arch.cost_model; None when
-        # VDT_PERF_ATTRIB=0). Every dispatch is charged analytic FLOPs
-        # and HBM bytes keyed by (kernel family, phase, token bucket)
-        # and reconciled against the measured device wait in
-        # wait_model — the numerators behind vdt:mfu / vdt:mbu /
-        # vdt:hbm_bytes_total{kind} / vdt:roofline_bound{phase} and the
-        # GET /debug/perf table. All dict-bump accounting on the
-        # single engine-core thread; get_stats snapshots read
-        # GIL-atomically like the other runner counters.
-        self._perf_memo: Optional[bool] = None
-        self._perf_attrib: dict[str, dict] = {}
-        self._perf_phases: dict[str, dict] = {}
-        self._perf_bytes = {"weights": 0.0, "kv_read": 0.0,
-                            "kv_write": 0.0, "activations": 0.0}
-        self._perf_flops = 0.0
-        self._perf_device_s = 0.0
-        self._perf_dispatches = 0
         # SSM state-snapshot pool (core/state_cache.py): per-state-array
         # device buffers of `resolve_state_slots` slots, written/read by
         # the scheduler's state_saves/state_restores directives. Built
@@ -1124,8 +1106,8 @@ class TPUModelRunner:
         rule (``num_window_dead_blocks`` of the step's computed tokens):
         the kernel never gets a freed page's id. Feeds the histograms of
         what a window layer and a full layer do this step."""
-        from vllm_distributed_tpu.core.kv_cache_utils import \
-            num_window_dead_blocks
+        from vllm_distributed_tpu.core.kv_cache_utils import (
+            num_window_dead_blocks, window_step_work)
         ib = self.input_batch
         W, ps = self._window_group, self.page_size
         table = f["window_block_tables"]
@@ -1141,17 +1123,9 @@ class TPUModelRunner:
         f["window_kv_runs"][:n_kv_runs] = runs
         f["window_kv_runs"][:n_kv_runs, 0] = table[
             f["req_idx"][first], f["positions"][first] // ps]
-        q_len = f["seq_info"][:num_runs, 1].astype(np.int64)
-        end = f["seq_info"][:num_runs, 2].astype(np.int64)
-        self.kv_window_tokens_read_hist.observe(
-            int(np.minimum(end, W - 1 + q_len).sum()))
-        # Query x key pairs inside the masks: a query at position p sees
-        # p + 1 keys in a full layer, min(p + 1, W) in a window layer.
-        full = q_len * (end - q_len) + q_len * (q_len + 1) // 2
-        over = np.clip(end - W, 0, q_len)      # queries with p + 1 > W
-        under = q_len - over
-        window = (over * W + under * (end - q_len)
-                  + under * (under + 1) // 2)
+        tokens_read, full, window = window_step_work(
+            f["seq_info"][:num_runs, 1], f["seq_info"][:num_runs, 2], W)
+        self.kv_window_tokens_read_hist.observe(int(tokens_read.sum()))
         self.attn_pairs_hist["full"].observe(int(full.sum()))
         self.attn_pairs_hist["window"].observe(int(window.sum()))
 
@@ -1754,34 +1728,22 @@ class TPUModelRunner:
             self._poll_kv_connector(scheduler_output, out)
             return {"ready": out}
         if scheduler_output.multi_step > 1:
-            # Perf attribution: the burst blocks for its device results
-            # inside _execute_multi_step, so the elapsed wall here IS
-            # the dispatch's device time as this worker sees it (the
-            # same approximation vdt:device_wait_seconds makes).
-            pending = self._perf_charge(
-                scheduler_output, self._multi_step_label(),
-                pad_to_bucket(len(scheduler_output.num_scheduled_tokens),
-                              self.req_buckets),
-                n_steps=scheduler_output.multi_step)
-            with ph.span("vdt.device_wait", "device_wait") as burst:
+            # The burst blocks for its device results inside
+            # _execute_multi_step.
+            with ph.span("vdt.device_wait", "device_wait"):
                 out = self._execute_multi_step(scheduler_output)
-            if pending is not None:
-                self._perf_commit(pending, burst.seconds)
             self._apply_kv_tier_post(tier_pending)
             return {"ready": out}
 
-        with ph.span("vdt._prepare_inputs", "prepare_inputs") as prep:
+        with ph.span("vdt._prepare_inputs", "prepare_inputs"):
             (inputs, logits_indices, sampling_packed, sampling_req_ids,
              fwd_shape, R, spec_pack, ext_md, want_topk, vocab_mask, plp,
              chain) = self._prepare_inputs(scheduler_output)
-        prep_s = prep.seconds
         # Launch: from the prepared inputs to the handle (forward and
         # sampler launches, the async chain, state saves).
         with ph.span("vdt.launch", "launch"):
             attn_label = self._attn_kernel_label(inputs)
             self._count_attn_dispatch(attn_label)
-            perf = self._perf_charge(scheduler_output, attn_label,
-                                     fwd_shape[0])
             drafts_arr, q_ids, q_probs, spec_truncate = spec_pack
             if chain is not None:
                 # Async run-ahead rows: substitute the previous dispatch's
@@ -1837,8 +1799,7 @@ class TPUModelRunner:
                     "sampling_req_ids": sampling_req_ids,
                     "drafts_arr": drafts_arr, "R": R,
                     "specv": spec_q is not None,
-                    "plp_meta": plp[2] if plp else None,
-                    "perf": perf, "perf_prep_s": prep_s}
+                    "plp_meta": plp[2] if plp else None}
 
     def wait_model(self, handle: dict) -> ModelRunnerOutput:
         """Blocking half: fetch the sampled tokens, fold them into the
@@ -1848,8 +1809,6 @@ class TPUModelRunner:
         # Device-vs-host attribution: this fetch is where the host
         # blocks on the device (everything since dispatch ran async), so
         # its duration IS the step's device wait as seen by this worker.
-        # The perf-attribution plane rides the same timing pair to
-        # charge the dispatch's analytic FLOPs/bytes against it.
         ph = self.phases
         with ph.span("vdt.device_wait", "device_wait") as wait:
             if handle.get("specv"):
@@ -1862,9 +1821,6 @@ class TPUModelRunner:
         wait_s = wait.seconds
         if self._device_telemetry:
             self.device_wait_hist.observe(wait_s)
-        perf = handle.get("perf")
-        if perf is not None:
-            self._perf_commit(perf, wait_s, handle.get("perf_prep_s", 0.0))
         with ph.span("vdt.finish", "finish"):
             return self._finish_step(handle, verify, sample)
 
@@ -2428,21 +2384,6 @@ class TPUModelRunner:
         self.attn_kernel_calls[label] = (
             self.attn_kernel_calls.get(label, 0) + 1)
 
-    # ------------------------------------------------------------------
-    # Performance-attribution plane (metrics/costmodel.py)
-    # ------------------------------------------------------------------
-    def _cost_model(self):
-        """The loader-attached analytic cost model; None = plane off
-        (VDT_PERF_ATTRIB=0) and every per-step perf hook is this one
-        memoized check."""
-        memo = self._perf_memo
-        if memo is None:
-            if self.model is None:
-                return None
-            self._perf_cm = getattr(self.model.cfg, "cost_model", None)
-            self._perf_memo = memo = self._perf_cm is not None
-        return self._perf_cm if memo else None
-
     def _multi_step_label(self) -> str:
         """Kernel family the fused multi-step burst dispatches: the
         in-jit batches carry no partition descriptor, so they ride the
@@ -2452,82 +2393,6 @@ class TPUModelRunner:
                              and not self._model_routes_xla()
                              and not self._model_has_attn_features())
                 else "naive")
-
-    def _perf_charge(self, scheduler_output, label: str, bucket: int,
-                     n_steps: int = 1):
-        """Analytic price of one dispatch, from the scheduler grant +
-        the input batch's pre-step context lengths: (attribution key,
-        phase, WaveCost) — or None with the plane off / nothing
-        scheduled. FLOPs count real (unpadded) tokens; attention pairs
-        clamp to a uniform sliding window; a multi-step burst charges
-        n_steps in-graph decode steps with the KV span growing per
-        step."""
-        cm = self._cost_model()
-        if cm is None:
-            return None
-        ib = self.input_batch
-        prefill_toks = 0
-        decode_toks = 0
-        kv_terms = 0.0
-        for rid, n in scheduler_output.num_scheduled_tokens.items():
-            row = ib.req_id_to_index.get(rid)
-            if row is not None:
-                ctx = float(ib.num_computed[row])
-                # Phase by the PROMPT boundary, not the grant width: a
-                # spec-decode verify wave grants 1+k tokens but is
-                # decode, and a chunked prefill's final 1-token chunk
-                # is still prefill — the grant-width heuristic would
-                # mislabel both and corrupt the roofline buckets.
-                generating = ctx >= float(ib.prompt_len[row])
-            else:
-                ctx, generating = 0.0, False
-            if n_steps > 1:
-                n = n_steps
-            kv_terms += cm.span_sum(ctx, n)
-            if generating:
-                decode_toks += n
-            else:
-                prefill_toks += n
-        total = prefill_toks + decode_toks
-        if total == 0:
-            return None
-        rows = len(scheduler_output.num_scheduled_tokens) * n_steps
-        cost = cm.wave_cost(total, kv_terms, rows, passes=n_steps)
-        phase = ("decode" if prefill_toks == 0
-                 else "prefill" if decode_toks == 0 else "mixed")
-        return (f"{label}/{phase}/b{bucket}", phase, cost)
-
-    def _perf_commit(self, pending, device_s: float,
-                     host_s: float = 0.0) -> None:
-        """Reconcile one priced dispatch against its measured device
-        wait. Single engine-core thread; stats polls snapshot with
-        GIL-atomic dict copies."""
-        key, phase, cost = pending
-        e = self._perf_attrib.get(key)
-        if e is None:
-            e = self._perf_attrib[key] = {
-                "device_seconds": 0.0, "flops": 0.0, "bytes": 0.0,
-                "dispatches": 0}
-        e["device_seconds"] += device_s
-        e["flops"] += cost.flops
-        e["bytes"] += cost.total_bytes
-        e["dispatches"] += 1
-        p = self._perf_phases.get(phase)
-        if p is None:
-            p = self._perf_phases[phase] = {
-                "device_seconds": 0.0, "host_seconds": 0.0,
-                "flops": 0.0, "bytes": 0.0}
-        p["device_seconds"] += device_s
-        p["host_seconds"] += host_s
-        p["flops"] += cost.flops
-        p["bytes"] += cost.total_bytes
-        self._perf_bytes["weights"] += cost.weight_bytes
-        self._perf_bytes["kv_read"] += cost.kv_read_bytes
-        self._perf_bytes["kv_write"] += cost.kv_write_bytes
-        self._perf_bytes["activations"] += cost.act_bytes
-        self._perf_flops += cost.flops
-        self._perf_device_s += device_s
-        self._perf_dispatches += 1
 
     # ------------------------------------------------------------------
     @contextmanager
@@ -2919,25 +2784,6 @@ class TPUModelRunner:
             # layer: what a request costs whatever its length
             # (models/bailing.py, models/mamba.py).
             stats["state_row_bytes"] = int(row_bytes())
-        cm = self._cost_model()
-        if cm is not None and self._perf_dispatches:
-            # Performance-attribution plane: analytic totals + the
-            # per-(kernel, phase, bucket) attribution table and phase
-            # accumulators (roofline classification happens at render
-            # time from the DP-merged accumulators, never by merging
-            # classifications). mfu/mbu move into workers[label] at the
-            # worker layer — the DP numeric-sum must not add ratios.
-            dev_s = self._perf_device_s
-            stats["model_flops"] = self._perf_flops
-            stats["hbm_bytes"] = dict(self._perf_bytes)
-            stats["perf_attrib"] = {k: dict(v)
-                                    for k, v in self._perf_attrib.items()}
-            stats["perf_phases"] = {k: dict(v)
-                                    for k, v in self._perf_phases.items()}
-            stats["perf_peaks"] = {"flops": cm.peak_flops,
-                                   "hbm": cm.peak_hbm}
-            stats["mfu"] = cm.mfu(self._perf_flops, dev_s)
-            stats["mbu"] = cm.mbu(sum(self._perf_bytes.values()), dev_s)
         if self._device_telemetry:
             from vllm_distributed_tpu.metrics import telemetry
             stats["device_wait_seconds"] = self.device_wait_hist.to_dict()

@@ -150,18 +150,6 @@ class PPModelRunner(TPUModelRunner):
     def _not_supported(*_a, **_k):  # pragma: no cover - guard
         raise RuntimeError("single-program forward is not used under PP")
 
-    def dispatch_model(self, scheduler_output):
-        """Perf-attribution host share under PP: the dominant host cost
-        of a dispatch is the synchronous stage loop (inter-stage hops +
-        per-stage launches), not ``_prepare_inputs`` — fold the whole
-        dispatch wall into the pending charge's host seconds so the
-        roofline's host-bound classification stays honest per stage."""
-        t0 = time.perf_counter()
-        handle = super().dispatch_model(scheduler_output)
-        if isinstance(handle, dict) and handle.get("perf") is not None:
-            handle["perf_prep_s"] = time.perf_counter() - t0
-        return handle
-
     # ------------------------------------------------------------------
     def _stage_first_layer(self, p: int) -> int:
         """Global layer offset of stage p — nonzero only for mixed

@@ -311,16 +311,11 @@ class TPUWorker:
         stats = self.model_runner.get_stats()
         from vllm_distributed_tpu.metrics import telemetry
         per_worker = {}
-        # mfu/mbu are per-worker RATIOS against this worker's own
-        # device time and peak — the DP flat numeric-sum would add
-        # them into nonsense, so they ride the labeled map like the
-        # memory peaks (union merge, never summed).
         for key in ("device_wait_seconds", "device_memory_peak_bytes",
                     "device_memory_in_use_bytes", "tpla_latent_shards",
                     "mla_latent_page_bytes", "mla_latent_token_bytes",
                     "moe_experts_held", "moe_experts_published",
-                    "state_row_bytes", "mfu",
-                    "mbu"):
+                    "state_row_bytes"):
             if key in stats:
                 per_worker[key] = stats.pop(key)
         if "num_recompiles" in stats:
