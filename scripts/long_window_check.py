@@ -1,14 +1,16 @@
 #!/usr/bin/env python3
-"""The window, past 4 096 tokens, on the chip.
+"""The window, past its edge, on the chip.
 
     python3 scripts/long_window_check.py [--seed N] [--cell NAME]
         [--lengths 4300,5200,6100]
 
 The comparison that decides ``correct`` in a benchmark run scores prompts
 of 48 to 209 tokens (``benchmarks/traffic.py`` ``scoring_requests``), so
-it never crosses Command A+'s 4 096-token window and a reference without
-the window mask cannot be told from the right one there. This script
-holds the window on the chip: it starts the cell's engine (the cell's own
+it never crosses the cell's window (4 096 tokens in both cells that have
+one: ``command-a-plus-ep8.long-document``,
+``smallthinker-21b-a3b.mixed-length``) and a reference without the window
+mask cannot be told from the right one there. This script holds the
+cell's window on the chip: it starts the cell's engine (the cell's own
 ``engine_args``, seeded weights, the same scheduler, cache groups and
 runner a server has, through ``LLM.generate``), serves seeded prompts
 longer than the window with echo, logprobs and 8 greedy tokens, and hands
@@ -113,9 +115,11 @@ def main(argv=None) -> int:
     import jax
     cell = run.load_cell(a.cell, a.data_root)
     cf = cell["config_file"]
-    window = cf["sliding_window"]
+    # The cell's window, under the key its family publishes it by.
+    window = cf.get("sliding_window") or cf["sliding_window_size"]
     lengths = [int(n) for n in a.lengths.split(",")]
-    assert min(lengths) > window, "every prompt has to cross the window"
+    assert min(lengths) > window, \
+        "every prompt has to cross the cell's window"
     rng = traffic.run_rng(a.seed, 2)
     prompts = [rng.integers(1, cf["vocab_size"], size=n).tolist()
                for n in lengths]

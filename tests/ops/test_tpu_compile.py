@@ -84,6 +84,19 @@ WIDE_GEOMETRIES = {
         "command-a-plus-ep8", 6368)._replace(
             L=3, window=bench_config("command-a-plus-ep8")["sliding_window"]),
 }
+# SmallThinker's 28 query heads on 4 K/V heads: groups of SEVEN, which
+# the launcher pads to eight rows (``padded_q_heads``; Mosaic refuses a
+# slice of 28 sublanes): its six window layers on the trimmed loop over
+# the window group's pool, its two full layers over a sized pool, each at
+# a decode bucket and at the chunk bucket.
+GROUP_OF_SEVEN = {
+    "smallthinker-21b-a3b-window": bench_geometry(
+        "smallthinker-21b-a3b", 8448)._replace(
+            L=6, window=bench_config(
+                "smallthinker-21b-a3b")["sliding_window_size"]),
+    "smallthinker-21b-a3b-full": bench_geometry(
+        "smallthinker-21b-a3b", 16384)._replace(L=2),
+}
 QH, KVH, D, L, NP, R, PAGES_PER_REQ, _, _ = GEOMETRIES["llama-3.2-1b"]
 # The token buckets the runner serves at the cells' engine limits
 # (worker/model_runner.py: make_buckets(16, max_num_batched_tokens)).
@@ -101,6 +114,10 @@ served_lattice = pytest.mark.parametrize("geo,t_bucket", [
 ] + [
     pytest.param(geo, TOKEN_BUCKETS[-1], id=f"{name}-{TOKEN_BUCKETS[-1]}")
     for name, geo in WIDE_GEOMETRIES.items()
+] + [
+    pytest.param(geo, t, id=f"{name}-{t}")
+    for name, geo in GROUP_OF_SEVEN.items()
+    for t in (32, TOKEN_BUCKETS[-1])
 ])
 
 
@@ -718,6 +735,11 @@ ONE_LAYER = {
     "command-a-plus-ep8": {
         "num_hidden_layers": 2,
         "layer_types": ["sliding_attention", "full_attention"]},
+    # A full layer without rope and a window layer with it, in that
+    # order: both cache groups, the early router, the ReLU experts.
+    "smallthinker-21b-a3b": {
+        "num_hidden_layers": 2, "sliding_window_layout": [0, 1],
+        "rope_layout": [0, 1]},
 }
 
 
@@ -784,9 +806,11 @@ def slab_copies(compiled, slab: str) -> list:
 
 def expert_slab(cf: dict) -> tuple:
     """(shape string, bytes) of one layer's gate or up projection."""
-    E = cf.get("num_experts") or cf["n_routed_experts"]
+    E = (cf.get("num_experts") or cf.get("n_routed_experts")
+         or cf["moe_num_primary_experts"])
     H = cf["hidden_size"]
-    I = cf.get("moe_intermediate_size") or cf["intermediate_size"]
+    I = (cf.get("moe_intermediate_size") or cf.get("intermediate_size")
+         or cf["moe_ffn_hidden_size"])
     return f"bf16[{E},{H},{I}]", E * H * I * 2
 
 
@@ -845,9 +869,19 @@ def test_one_layer_forward_compiles(abstract_runner, name, t_bucket):
     largest token bucket."""
     compiled = forward_lowered(abstract_runner(name), t_bucket)[0].compile()
     assert "tpu_custom_call" in compiled.as_text()
-    if name in SPARSE:
+    if name in SPARSE or name == "smallthinker-21b-a3b":
         slab, _ = expert_slab(bench_config(name))
+        assert slab in compiled.as_text()
         assert slab_copies(compiled, slab) == []
+    if name == "smallthinker-21b-a3b":
+        # Two layers through the padded launcher (one call a layer) and
+        # their grouped GEMMs; the attention matrices come in a buffer a
+        # layer (XLA may prefetch one to the fast memory and back, as it
+        # does ZAYA's stacks: no copy in HBM).
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 2
+        assert text.count("ragged-dot") >= 6
+        assert "bf16[2,2560,3584]" not in text.split("\n", 1)[0]
     if name == "granite-4.0-h-micro":
         # The state rows are updated in place (18 mamba layers x 57 rows
         # of 2 MiB here), and the step holds no state per token.

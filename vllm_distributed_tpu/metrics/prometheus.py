@@ -177,6 +177,12 @@ HISTOGRAMS = {
         "+ scheduled tokens) summed over the scheduled requests, the K/V "
         "tokens ONE window layer reads (vdt:step_kv_tokens_read is what "
         "a full layer reads); absent for a model with one cache group"),
+    "step_requests_past_window": (
+        "vdt:step_requests_past_window",
+        "Per step, under a window cache group: the scheduled requests "
+        "whose context (computed + scheduled tokens) is longer than the "
+        "window, the ones whose window pages are being freed behind them; "
+        "absent for a model with one cache group"),
     "step_ssd_chunk_tokens": (
         "vdt:step_ssd_chunk_tokens",
         "Per step, tokens that the Mamba-2 layers took in the chunked "

@@ -50,6 +50,10 @@ STEP_TOKEN_BUCKETS = tuple(2**i for i in range(21))
 # at a short context (256) to a token budget of queries over a model's
 # longest context (4**16 = 4.3e9).
 ATTN_PAIR_BUCKETS = tuple(4**i for i in range(4, 17))
+# Requests of one step (a count of the scheduled ones that share some
+# property): 0 and 1 have a bucket each, then powers of two to the
+# largest running batch.
+STEP_REQUEST_BUCKETS = (0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)
 # Host-to-device transfers of one step: each count up to 8 has a bucket
 # of its own (the default path issues 3), then the per-array hand-over's
 # range and the optional fields'.

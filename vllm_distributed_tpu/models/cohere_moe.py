@@ -31,15 +31,13 @@ family adds:
   all picks, the shared experts and attention are whole. The dispatch is
   ``MixtralForCausalLM._share_dispatch`` over ``share_capacity`` rows,
   the one the DeepSeek family uses.
-* **Two kinds of K/V** (``cfg.window_group``): the full layers' pages
-  hold every token of a request, the window layers' pages hold what lies
-  inside the window and are handed back behind it
-  (core/kv_cache_manager.py ``GroupedKVCacheManager``). The caches are
-  ``k`` / ``v`` ``[full layers, pages, ...]`` from the pool the worker
-  sizes and ``k_win`` / ``v_win`` ``[window layers, window pages, ...]``
-  of a fixed size (``cfg.window_group_pages``, all the window group can
-  ever hold, charged like state rows: ``fixed_cache_bytes``). A window
-  layer's cache ops read ``AttentionBatch.for_window_group()``, and the
+* **Two kinds of K/V** (``cfg.window_group``, which this family sets:
+  ``LlamaForCausalLM.window_group_of``, where the cache-group methods
+  live since a second family, models/smallthinker.py, shares them): the
+  full layers' pages hold every token of a request, the window layers'
+  pages hold what lies inside the window and are handed back behind it
+  (core/kv_cache_manager.py ``GroupedKVCacheManager``). A window layer's
+  cache ops read ``AttentionBatch.for_window_group()``, and the
   mega-kernel starts its page loop at the first block inside the window.
 
 Expert weights are per-layer leaves in the serving layout
@@ -63,12 +61,10 @@ not loaded. A checkpoint's tensor names are not available offline:
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from vllm_distributed_tpu.models.families_ext import Cohere2ForCausalLM
-from vllm_distributed_tpu.models.llama import (MODEL_AXIS,
-                                               LlamaForCausalLM)
+from vllm_distributed_tpu.models.llama import MODEL_AXIS
 from vllm_distributed_tpu.models.mixtral import MixtralForCausalLM
 
 
@@ -126,23 +122,7 @@ class Cohere2MoeForCausalLM(MixtralForCausalLM):
             arch.router_experts = published
             arch.experts_held = (index * arch.num_experts,
                                  arch.num_experts)
-        arch.window_group = cls._window_group(arch)
-
-    @staticmethod
-    def _window_group(arch):
-        """The window of the second cache group, where window layers and
-        full layers are mixed; a model of one kind of layer keeps one
-        group (and ``resolve_free_window`` frees behind an all-window
-        model's one table)."""
-        pattern = arch.window_pattern
-        if pattern is None:
-            return None
-        if len(set(pattern)) != 2:
-            raise ValueError(
-                f"cohere2_moe: layer_types mixes more than one window with "
-                f"full layers ({sorted(set(pattern))}); a cache group a "
-                "window is not wired")
-        return max(pattern)
+        arch.window_group = cls.window_group_of(arch)
 
     # ------------------------------------------------------------------
     # Parameters
@@ -181,45 +161,6 @@ class Cohere2MoeForCausalLM(MixtralForCausalLM):
         raise NotImplementedError(
             "cohere2_moe: a checkpoint's tensor names are not available "
             "offline; only load_format='dummy' is wired")
-
-    # ------------------------------------------------------------------
-    # Two kinds of K/V
-    # ------------------------------------------------------------------
-    def _group_depths(self) -> tuple[int, int]:
-        """(full layers, window layers)."""
-        pattern = self.cfg.window_pattern or (0, ) * self.cfg.num_layers
-        n_window = sum(1 for w in pattern if w)
-        return len(pattern) - n_window, n_window
-
-    def kv_cache_specs(self) -> dict:
-        specs = super().kv_cache_specs()
-        if self.cfg.window_group:
-            specs.update(k_win=specs["k"], v_win=specs["v"])
-        return specs
-
-    def make_kv_caches(self, num_pages: int, page_size: int,
-                       cache_dtype=None, num_layers=None) -> dict:
-        if not self.cfg.window_group:
-            return super().make_kv_caches(num_pages, page_size,
-                                          cache_dtype, num_layers)
-        assert num_layers is None, "cache groups are not sliced per stage"
-        n_full, n_window = self._group_depths()
-        make = LlamaForCausalLM.make_kv_caches
-        caches = make(self, num_pages, page_size, cache_dtype, n_full)
-        window = make(self, self.cfg.window_group_pages, page_size,
-                      cache_dtype, n_window)
-        caches.update(k_win=window["k"], v_win=window["v"])
-        return caches
-
-    def fixed_cache_bytes(self) -> int:
-        """The window group's pool, which does not grow with the pool the
-        worker sizes (worker/worker.py charges it before the pages)."""
-        if not self.cfg.window_group:
-            return 0
-        shapes = jax.eval_shape(lambda: self.make_kv_caches(
-            1, self.cfg.window_group_page_size))
-        return sum(int(np.prod(shapes[k].shape)) * shapes[k].dtype.itemsize
-                   for k in ("k_win", "v_win"))
 
     # ------------------------------------------------------------------
     # The feed-forward

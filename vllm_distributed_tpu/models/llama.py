@@ -144,6 +144,13 @@ class LlamaArchConfig:
     # full (reference: per-layer sliding_window in models/gemma2.py and
     # models/qwen2.py attention construction).
     window_pattern: Optional[tuple] = None
+    # The window of a second cache group, for a family that keeps its
+    # window layers' pages apart from its full layers' (set by its
+    # ``configure_arch`` from ``LlamaForCausalLM.window_group_of``; None:
+    # one group), and that group's pool as models/loader.py sizes it.
+    window_group: Optional[int] = None
+    window_group_pages: int = 0
+    window_group_page_size: int = 0
     # Logit soft-capping, cap*tanh(x/cap); 0 disables (Gemma2,
     # reference: attn_logit_softcapping/final_logit_softcapping in
     # models/gemma2.py).
@@ -682,10 +689,58 @@ class LlamaForCausalLM:
         # token-parallel axis (each rank's page-pool partition is its
         # shard; no-op when the axis is 1) and kv heads on the TP axis
         # (head-major page layout; see ops/attention.write_kv_pages).
-        return {
+        specs = {
             "k": P(None, TOKEN_AXIS, MODEL_AXIS, None, None),
             "v": P(None, TOKEN_AXIS, MODEL_AXIS, None, None),
         }
+        if self.cfg.window_group:
+            # The window layers' own cache group (``window_group_of``).
+            specs.update(k_win=specs["k"], v_win=specs["v"])
+        return specs
+
+    # ------------------------------------------------------------------
+    # Two kinds of K/V: a family whose window and full layers are mixed
+    # opts into a cache group for each by setting ``cfg.window_group =
+    # window_group_of(arch)`` in its ``configure_arch``
+    # (models/cohere_moe.py, models/smallthinker.py). The full layers'
+    # pages (``k`` / ``v``, from the pool the worker sizes) hold every
+    # token of a request; the window layers' (``k_win`` / ``v_win``,
+    # ``cfg.window_group_pages`` of them, set by models/loader.py: all
+    # the group can ever hold) hold what lies inside the window and are
+    # handed back behind it (core/kv_cache_manager.py
+    # ``GroupedKVCacheManager``). The walk is ``_run_layers_by_group``.
+    # ------------------------------------------------------------------
+    @staticmethod
+    def window_group_of(arch) -> Optional[int]:
+        """The window of the second cache group, where window layers and
+        full layers are mixed; a model of one kind of layer keeps one
+        group (and ``resolve_free_window`` frees behind an all-window
+        model's one table)."""
+        pattern = arch.window_pattern
+        if pattern is None:
+            return None
+        if len(set(pattern)) != 2:
+            raise ValueError(
+                f"the layer pattern mixes more than one window with full "
+                f"layers ({sorted(set(pattern))}); a cache group a window "
+                "is not wired")
+        return max(pattern)
+
+    def _group_depths(self) -> tuple[int, int]:
+        """(full layers, window layers)."""
+        pattern = self.cfg.window_pattern or (0, ) * self.cfg.num_layers
+        n_window = sum(1 for w in pattern if w)
+        return len(pattern) - n_window, n_window
+
+    def fixed_cache_bytes(self) -> int:
+        """The window group's pool, which does not grow with the pool the
+        worker sizes (worker/worker.py charges it before the pages)."""
+        if not self.cfg.window_group:
+            return 0
+        shapes = jax.eval_shape(lambda: self.make_kv_caches(
+            1, self.cfg.window_group_page_size))
+        return sum(int(np.prod(shapes[k].shape)) * shapes[k].dtype.itemsize
+                   for k in ("k_win", "v_win"))
 
     def init_params(self, rng: jax.Array, scale: float = 0.02) -> dict:
         """Random (dummy-loader) initialization, HF-shaped."""
@@ -844,14 +899,22 @@ class LlamaForCausalLM:
         a pipeline stage's local slice."""
         from vllm_distributed_tpu.ops.attention import storage_head_dim
         c = self.cfg
-        depth = num_layers if num_layers is not None else c.num_layers
-        shape = (depth, num_pages, c.total_kv_heads,
-                 page_size, storage_head_dim(c.head_dim))
         dtype = cache_dtype or self.cache_dtype()
-        return {
-            "k": jnp.zeros(shape, dtype),
-            "v": jnp.zeros(shape, dtype),
-        }
+
+        def pages(depth: int, count: int) -> jax.Array:
+            return jnp.zeros((depth, count, c.total_kv_heads, page_size,
+                              storage_head_dim(c.head_dim)), dtype)
+
+        if c.window_group:
+            assert num_layers is None, \
+                "cache groups are not sliced per stage"
+            n_full, n_window = self._group_depths()
+            return {"k": pages(n_full, num_pages),
+                    "v": pages(n_full, num_pages),
+                    "k_win": pages(n_window, c.window_group_pages),
+                    "v_win": pages(n_window, c.window_group_pages)}
+        depth = num_layers if num_layers is not None else c.num_layers
+        return {"k": pages(depth, num_pages), "v": pages(depth, num_pages)}
 
     # ------------------------------------------------------------------
     # Weight loading from an HF checkpoint state dict
@@ -1057,6 +1120,15 @@ class LlamaForCausalLM:
         if b is not None:
             out = out + b.astype(jnp.float32)
         return out.astype(x.dtype)
+
+    def pre_attention(self, lp: dict, x: jax.Array):
+        """What the layer's feed-forward needs of the ATTENTION's input
+        ``x`` (normed where the block is pre-norm): nothing, for every
+        family but one whose router stands before attention
+        (models/smallthinker.py). What is returned reaches the family's
+        own ``mlp_block`` as ``early=``; None adds nothing to the
+        program."""
+        return None
 
     def mlp_block(self, lp: dict, x: jax.Array,
                   lora_ctx=None) -> jax.Array:
@@ -1283,23 +1355,32 @@ class LlamaForCausalLM:
 
         rm = c.residual_multiplier
 
-        def mlp(lp, x, layer_idx):
+        def mlp(lp, x, layer_idx, early=None):
             """The MLP block of the layer at ``layer_idx`` (traced). Its
             per-layer buffers are picked by a switch with one branch a
             layer, so each branch's kernels read buffers that are
-            arguments of the program, in place."""
+            arguments of the program, in place. ``early`` is what the
+            family's ``pre_attention`` made of the attention's input
+            (None for all but models/smallthinker.py): it reaches
+            ``mlp_block`` as a keyword, through the switch as an operand."""
             if not per_layer or all(k in lp for k in per_layer):
                 # Nothing a layer, or the walk is unrolled and the
                 # layer's buffers stand in ``lp`` already.
-                return self.mlp_block(lp, x, lora_ctx)
+                if early is None:
+                    return self.mlp_block(lp, x, lora_ctx)
+                return self.mlp_block(lp, x, lora_ctx, early=early)
             # One jitted block for all branches: traced once, called
             # with each layer's buffers.
             block = jax.jit(self.mlp_block)
+            branches = [functools.partial(
+                block, {**lp, **{k: v[i] for k, v in per_layer.items()}},
+                lora_ctx=lora_ctx) for i in range(num_layers)]
+            index = layer_idx[0] - cache_layer_offset
+            if early is None:
+                return jax.lax.switch(index, branches, x)
             return jax.lax.switch(
-                layer_idx[0] - cache_layer_offset,
-                [functools.partial(
-                    block, {**lp, **{k: v[i] for k, v in per_layer.items()}},
-                    lora_ctx=lora_ctx) for i in range(num_layers)], x)
+                index, [lambda x, e, b=b: b(x, early=e) for b in branches],
+                x, early)
 
         def layer_body(h, k_all, v_all, lp, layer_idx, window,
                        nope=False, cache_idx=None, cache_batch=batch):
@@ -1311,6 +1392,7 @@ class LlamaForCausalLM:
                 x = self._norm(h, lp["input_ln"], lp.get("input_ln_b"))
             else:
                 x = h  # Olmo2 post-norm block: sub-layers see raw h
+            early = self.pre_attention(lp, x)
             q = self._mm(lp, "wq", x) + self._lora_delta(lp, "wq", x,
                                                          lora_ctx)
             k = self._mm(lp, "wk", x) + self._lora_delta(lp, "wk", x,
@@ -1367,13 +1449,13 @@ class LlamaForCausalLM:
                 # input state; one residual add.
                 x2 = (x if c.shared_block_ln else
                       self._norm(h, lp["post_ln"], lp.get("post_ln_b")))
-                mlp_out = mlp(lp, x2, layer_idx)
+                mlp_out = mlp(lp, x2, layer_idx, early)
                 h = sp(h + rm * (attn_out + mlp_out))
                 return h, k_all, v_all
             h = sp(h + rm * attn_out)
             x2 = (self._norm(h, lp["post_ln"], lp.get("post_ln_b"))
                   if c.pre_norm else h)
-            mlp_out = mlp(lp, x2, layer_idx)
+            mlp_out = mlp(lp, x2, layer_idx, early)
             if "post_ffw_ln" in lp:
                 mlp_out = self._norm(mlp_out, lp["post_ffw_ln"],
                                      lp.get("post_ffw_ln_b"))
@@ -1431,7 +1513,7 @@ class LlamaForCausalLM:
     def _run_layers_by_group(self, layer_body, layer_params: dict,
                              per_layer: dict, kv_caches: dict, hidden,
                              batch: AttentionBatch, layer_keys: tuple):
-        """The layer walk over two kinds of K/V (models/cohere_moe.py):
+        """The layer walk over two kinds of K/V (``window_group_of``):
         the window layers' pages live in ``k_win`` / ``v_win`` under a
         table of their own (``batch.for_window_group()``), the full
         layers' in ``k`` / ``v``; a layer's row in its group's cache is

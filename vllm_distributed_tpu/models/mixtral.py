@@ -460,13 +460,15 @@ class MixtralForCausalLM(LlamaForCausalLM):
 
     def _expert_ffn(self, lp: dict, xs: jax.Array,
                     group_sizes: jax.Array) -> jax.Array:
-        """SwiGLU over expert-sorted rows: three grouped GEMMs. Rows
-        beyond sum(group_sizes) come back zero on the CPU and
-        UNINITIALISED on the TPU (measured on a v5e, PR 28: NaN among
-        them): a caller that leaves rows out of the groups selects
-        them away afterwards."""
+        """Gated experts over expert-sorted rows, ``(act(x Wg) * (x
+        Wu)) Wd`` in three grouped GEMMs; ``act`` is the family's
+        ``_act`` (SiLU where ``hidden_act`` is unset: SwiGLU; ReLU for
+        models/smallthinker.py: ReGLU). Rows beyond sum(group_sizes)
+        come back zero on the CPU and UNINITIALISED on the TPU (measured
+        on a v5e, PR 28: NaN among them): a caller that leaves rows out
+        of the groups selects them away afterwards."""
         with jax.named_scope("moe_gate_gemm"):
-            g = jax.nn.silu(jax.lax.ragged_dot(
+            g = self._act(jax.lax.ragged_dot(
                 xs, self._w(lp, "w_gate"), group_sizes))
         with jax.named_scope("moe_up_gemm"):
             u = jax.lax.ragged_dot(xs, self._w(lp, "w_up"), group_sizes)
@@ -571,7 +573,7 @@ class MixtralForCausalLM(LlamaForCausalLM):
             order2 = jnp.argsort(eid, stable=True)
             xs2 = recv_x.reshape(ep * Rk, H)[order2]
             gs = jnp.bincount(eid[order2], length=E_local + 1)[:-1]
-            g = jax.nn.silu(jax.lax.ragged_dot(xs2, w_gate, gs))
+            g = self._act(jax.lax.ragged_dot(xs2, w_gate, gs))
             u = jax.lax.ragged_dot(xs2, w_up, gs)
             y = jax.lax.ragged_dot(g * u, w_down, gs)
             y = y * recv_w[order2][:, None].astype(y.dtype)
@@ -635,7 +637,7 @@ class MixtralForCausalLM(LlamaForCausalLM):
             # dropped from group_sizes; ragged_dot leaves their rows
             # uninitialised on the TPU, so they are selected away.
             group_sizes = jnp.bincount(local_ids, length=E_local + 1)[:-1]
-            g = jax.nn.silu(jax.lax.ragged_dot(xs_l, w_gate, group_sizes))
+            g = self._act(jax.lax.ragged_dot(xs_l, w_gate, group_sizes))
             u = jax.lax.ragged_dot(xs_l, w_up, group_sizes)
             y = jax.lax.ragged_dot(g * u, w_down, group_sizes)
             y = jnp.where(is_local[part][:, None],
@@ -668,7 +670,7 @@ class MixtralForCausalLM(LlamaForCausalLM):
             top_idx = lp["expert_map"][top_idx, 0]
         gates = jnp.zeros((T, self.num_physical), jnp.float32).at[
             rows, top_idx].set(top_vals)
-        g = jax.nn.silu(
+        g = self._act(
             jnp.einsum("th,ehi->eti", x, self._w(lp, "w_gate")))
         u = jnp.einsum("th,ehi->eti", x, self._w(lp, "w_up"))
         y = jnp.einsum("eti,eih->eth", g * u, self._w(lp, "w_down"))

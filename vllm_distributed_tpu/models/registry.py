@@ -73,6 +73,7 @@ from vllm_distributed_tpu.models.cohere_moe import Cohere2MoeForCausalLM
 from vllm_distributed_tpu.models.granite_hybrid import \
     GraniteMoeHybridForCausalLM
 from vllm_distributed_tpu.models.jamba import JambaForCausalLM
+from vllm_distributed_tpu.models.smallthinker import SmallThinkerForCausalLM
 from vllm_distributed_tpu.models.mamba import (FalconMambaForCausalLM,
                                                Mamba2ForCausalLM,
                                                MambaForCausalLM)
@@ -179,6 +180,11 @@ _REGISTRY: dict[str, type] = {
     # of their own (models/cohere_moe.py; the name is the family's
     # convention, the source gives ``model_type`` only).
     "Cohere2MoeForCausalLM": Cohere2MoeForCausalLM,
+    # SmallThinker: the router reads the attention's input, ReLU-gated
+    # experts, full layers without rope among window layers, in cache
+    # groups of their own (models/smallthinker.py; the name is the
+    # family's convention, the source gives ``model_name`` only).
+    "SmallThinkerForCausalLM": SmallThinkerForCausalLM,
     # Ling-3.0: gated delta-rule linear attention (KDA) beside latent
     # attention over DeepSeek-V3 experts (models/bailing.py; the name is
     # the family's convention, not the source's).

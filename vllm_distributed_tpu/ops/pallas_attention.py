@@ -102,6 +102,51 @@ KIND_KV_WRITE = 3
 Q_TILE_PAD = 8
 
 
+def padded_q_heads(num_q_heads: int, num_kv_heads: int) -> int:
+    """Query heads as the kernels see them. The staged q and out tiles
+    are ``[rows, QH, D]`` with the heads on the sublanes, and Mosaic
+    takes a slice of them only in whole tiles of 8 ("Slice shape along
+    dimension 1 must be aligned to tiling (8), but is 28"). A head count
+    that is no multiple of 8 (28 heads in groups of 7 on 4 K/V heads)
+    is padded group by group to the next one that is (groups of 8): the
+    launchers add zero query rows and drop their output rows
+    (``_pad_query_groups``), no K/V byte more is read, and an eighth of
+    the score rows is spent on nothing. Groups of 1, 4 and 16 on head
+    counts of 16, 32 and 128 come back as they are."""
+    group = num_q_heads // num_kv_heads
+    while (group * num_kv_heads) % 8:
+        group += 1
+    return group * num_kv_heads
+
+
+def _pad_query_groups(x: jax.Array, num_kv_heads: int,
+                      axis: int = 1) -> jax.Array:
+    """``x`` with each K/V head's group of query heads, along ``axis``,
+    zero-padded to ``padded_q_heads``' group."""
+    heads = x.shape[axis]
+    padded = padded_q_heads(heads, num_kv_heads)
+    if padded == heads:
+        return x
+    shape = x.shape
+    x = x.reshape(*shape[:axis], num_kv_heads, heads // num_kv_heads,
+                  *shape[axis + 1:])
+    pad = [(0, 0)] * x.ndim
+    pad[axis + 1] = (0, (padded - heads) // num_kv_heads)
+    return jnp.pad(x, pad).reshape(*shape[:axis], padded,
+                                   *shape[axis + 1:])
+
+
+def _unpad_query_groups(x: jax.Array, num_q_heads: int,
+                        num_kv_heads: int) -> jax.Array:
+    """The ``num_q_heads`` real heads of a kernel output ``[T, padded,
+    D]``."""
+    T, padded, D = x.shape
+    if padded == num_q_heads:
+        return x
+    x = x.reshape(T, num_kv_heads, padded // num_kv_heads, D)
+    return x[:, :, :num_q_heads // num_kv_heads].reshape(T, num_q_heads, D)
+
+
 def prefill_tile_size(num_q_heads: int, head_dim: int) -> int:
     """Static prefill q-tile rows. Fixed (never a function of the token
     bucket) so the kernel has no per-composition statics; 32 rows fold to
@@ -123,7 +168,9 @@ def decode_group_size(num_q_heads: int, num_kv_heads: int) -> int:
     masked — and sized against the worst-case 128-position kv block so
     the same sb is valid for every caller (the cascade suffix call sees
     a shorter block table than the main call). 128 query heads on 8 K/V
-    heads give 2: 256 score rows against 2 x 8 x 128 key columns."""
+    heads give 2: 256 score rows against 2 x 8 x 128 key columns; 28 on
+    4 are sized as the 32 they are padded to (``padded_q_heads``): 8."""
+    num_q_heads = padded_q_heads(num_q_heads, num_kv_heads)
     sb = max(1, min(8, 128 // max(1, num_q_heads // 4)))
     while sb > 1 and (sb * num_q_heads) * (sb * num_kv_heads * 128) * 8 \
             > 8 * 1024**2:
@@ -662,20 +709,25 @@ def ragged_paged_attention_pallas(
         v_pages = v_pages[None]
     if layer is None:
         layer = jnp.zeros((1, ), jnp.int32)
-    T_pad, num_q_heads, head_dim = q.shape
+    true_q_heads = q.shape[1]
     _, num_pages, num_kv_heads, page_size, _ = k_pages.shape
-    assert num_q_heads % num_kv_heads == 0
+    assert true_q_heads % num_kv_heads == 0
+    q = _pad_query_groups(q, num_kv_heads)
+    T_pad, num_q_heads, head_dim = q.shape
     group = num_q_heads // num_kv_heads
     R = seq_info.shape[0]
     pages_per_req = block_tables.shape[1]
+
+    def unpad(x):
+        return _unpad_query_groups(x, true_q_heads, num_kv_heads)
 
     if max_q == 1 and not emit_state:
         # Pure decode: the SB-batched kernel fills the MXU (see
         # _decode_kernel). Cascade's emit_state decode stays on the
         # general kernel (it exports per-row softmax state).
-        return _decode_call(q, k_pages, v_pages, seq_info, num_seqs,
-                            block_tables, layer, sm_scale=sm_scale,
-                            interpret=interpret)
+        return unpad(_decode_call(
+            q, k_pages, v_pages, seq_info, num_seqs, block_tables, layer,
+            sm_scale=sm_scale, interpret=interpret))
 
     bq = min(max_q, 128)
     # Keep the per-program footprint (q/out staging, f32 accumulators and
@@ -736,8 +788,8 @@ def ragged_paged_attention_pallas(
         interpret=interpret,
     )(seq_info, num_seqs, layer, block_tables, q, k_pages, v_pages)
     if emit_state:
-        return tuple(result)
-    return result[0]
+        return tuple(unpad(x) for x in result)
+    return unpad(result[0])
 
 
 # ---------------------------------------------------------------------------
@@ -1250,10 +1302,14 @@ def _mega_call(q, k_pages, v_pages, desc, seq_info, decode_list, kv_runs,
                has_sinks=False):
     """Shared launcher for the attention-only and fused write+attend
     variants of the mega-kernel."""
-    T_pad, num_q_heads, head_dim = q.shape
+    true_q_heads = q.shape[1]
     _, _, num_kv_heads, page_size, _ = k_pages.shape
-    assert num_q_heads % num_kv_heads == 0
+    assert true_q_heads % num_kv_heads == 0
     assert bq % 8 == 0 and bq >= 8
+    q = _pad_query_groups(q, num_kv_heads)
+    if feat is not None:
+        feat = _pad_query_groups(feat, num_kv_heads)
+    T_pad, num_q_heads, head_dim = q.shape
     group = num_q_heads // num_kv_heads
     pages_per_req = block_tables.shape[1]
     ppb = max(1, min(128 // page_size, pages_per_req))
@@ -1335,14 +1391,21 @@ def _mega_call(q, k_pages, v_pages, desc, seq_info, decode_list, kv_runs,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    result = pl.pallas_call(
+    result = list(pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
     )(desc, seq_info, decode_list, kv_runs, layer, block_tables,
-      *operands)
+      *operands))
+    if num_q_heads != true_q_heads:
+        # out first, the cascade state last: both [T, padded heads, D].
+        result[0] = _unpad_query_groups(result[0], true_q_heads,
+                                        num_kv_heads)
+        if emit_state:
+            result[-1] = _unpad_query_groups(result[-1], true_q_heads,
+                                             num_kv_heads)
     return tuple(result)
 
 

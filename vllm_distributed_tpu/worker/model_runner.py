@@ -31,6 +31,7 @@ from vllm_distributed_tpu.core.sched.output import (ModelRunnerOutput,
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.metrics.stats import (ATTN_PAIR_BUCKETS,
                                                 STEP_PHASE_BUCKETS,
+                                                STEP_REQUEST_BUCKETS,
                                                 STEP_TOKEN_BUCKETS,
                                                 STEP_TRANSFER_BUCKETS,
                                                 Histogram, StepPhases)
@@ -197,6 +198,9 @@ class TPUModelRunner:
         self.kv_window_tokens_read_hist = Histogram(STEP_TOKEN_BUCKETS)
         self.attn_pairs_hist = {kind: Histogram(ATTN_PAIR_BUCKETS)
                                 for kind in ("full", "window")}
+        # ... and how many of the step's requests have a context longer
+        # than the window: the ones whose window pages are being freed.
+        self.requests_past_window_hist = Histogram(STEP_REQUEST_BUCKETS)
         self._latent_kernels: Optional[bool] = None
         # Host arrays that _prepare_inputs and the launch handed to the
         # device for the step through _h2d (explicit hand-overs only:
@@ -1123,9 +1127,10 @@ class TPUModelRunner:
         f["window_kv_runs"][:n_kv_runs] = runs
         f["window_kv_runs"][:n_kv_runs, 0] = table[
             f["req_idx"][first], f["positions"][first] // ps]
-        tokens_read, full, window = window_step_work(
-            f["seq_info"][:num_runs, 1], f["seq_info"][:num_runs, 2], W)
+        q_len, end = f["seq_info"][:num_runs, 1], f["seq_info"][:num_runs, 2]
+        tokens_read, full, window = window_step_work(q_len, end, W)
         self.kv_window_tokens_read_hist.observe(int(tokens_read.sum()))
+        self.requests_past_window_hist.observe(int((end > W).sum()))
         self.attn_pairs_hist["full"].observe(int(full.sum()))
         self.attn_pairs_hist["window"].observe(int(window.sum()))
 
@@ -2778,6 +2783,8 @@ class TPUModelRunner:
             stats["step_attn_pairs"] = {
                 kind: h.to_dict()
                 for kind, h in self.attn_pairs_hist.items()}
+            stats["step_requests_past_window"] = \
+                self.requests_past_window_hist.to_dict()
         row_bytes = getattr(self.model, "state_row_bytes", None)
         if row_bytes is not None:
             # One sequence's recurrent and convolution state over every
