@@ -814,6 +814,13 @@ def expert_slab(cf: dict) -> tuple:
     return f"bf16[{E},{H},{I}]", E * H * I * 2
 
 
+def last_layer(runner) -> dict:
+    """The last layer's weights as shapes on the runner's chip."""
+    return on_chip(
+        {k: v[-1] if isinstance(v, tuple) else sds(v.shape[1:], v.dtype)
+         for k, v in runner.params["layers"].items()}, runner.mesh)
+
+
 @pytest.mark.parametrize("rows", [64, 512 + 8])
 @pytest.mark.parametrize("name", SPARSE)
 def test_grouped_gemms_read_their_weights_in_place(abstract_runner, name,
@@ -828,18 +835,60 @@ def test_grouped_gemms_read_their_weights_in_place(abstract_runner, name,
     ``test_one_layer_forward_compiles`` (16 s a case alone)."""
     runner = abstract_runner(name)
     model, cf = runner.model, bench_config(name)
-    layer = {k: v[-1] if isinstance(v, tuple) else sds(v.shape[1:], v.dtype)
-             for k, v in runner.params["layers"].items()}
     x = sds((rows, cf["hidden_size"]), jnp.bfloat16)
     with runner.mesh:
         compiled = jax.jit(model.mlp_block).lower(
-            on_chip(layer, runner.mesh), on_chip(x, runner.mesh)).compile()
+            last_layer(runner), on_chip(x, runner.mesh)).compile()
     slab, slab_bytes = expert_slab(cf)
     text = compiled.as_text()
     assert text.count("ragged-dot") >= 3
+    assert ragged_dot_rows(compiled) == {dispatch_rows(model, rows)}
     assert slab in text                          # it is an argument
     assert slab_copies(compiled, slab) == []
     assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes / 4
+
+
+def ragged_dot_rows(compiled) -> set:
+    """Row counts of the compiled program's grouped GEMMs."""
+    return {int(rows) for rows in re.findall(
+        r"%ragged-dot-none\S* = \w+\[(\d+),", compiled.as_text())}
+
+
+def dispatch_rows(model, T: int) -> int:
+    """Rows the model's dispatch hands ``ragged_dot`` at ``T`` tokens:
+    the whole-expert branch pads its sorted picks (``padded_picks``), a
+    share's works a pass at a time (``share_capacity``)."""
+    from vllm_distributed_tpu.models.mixtral import padded_picks
+    k = model.cfg.num_experts_per_tok
+    if getattr(model.cfg, "experts_held", None) is not None:
+        return model.share_capacity(T, k)
+    return padded_picks(T * k, model.num_physical)
+
+
+WHOLE_EXPERT = ("olmoe-1b-7b", "zaya1-8b", "smallthinker-21b-a3b")
+
+
+@pytest.mark.parametrize("name", WHOLE_EXPERT)
+def test_a_decode_step_s_grouped_gemms_take_the_rule_s_rows(
+        abstract_runner, name):
+    """One expert layer's dispatch at published widths at the cell's
+    decode batch (its sequences' token bucket plus the attention
+    kernel's 8 rows), compiled for the chip: every ``ragged-dot`` has
+    ``padded_picks``' rows. The 2 048-token bucket's are read off
+    ``test_one_layer_forward_compiles``' programs, the shares' off
+    those and ``test_grouped_gemms_read_their_weights_in_place``'s."""
+    from vllm_distributed_tpu.ops.pallas_attention import Q_TILE_PAD
+    runner = abstract_runner(name)
+    model, c = runner.model, runner.model.cfg
+    T = pad_to_bucket(bench_config(name)["engine_args"]["max_num_seqs"],
+                      TOKEN_BUCKETS) + Q_TILE_PAD
+    k = c.num_experts_per_tok
+    args = (sds((T, c.hidden_size), jnp.bfloat16), sds((T, k)),
+            sds((T, k), jnp.float32))
+    with runner.mesh:
+        compiled = jax.jit(model.moe_dispatch).lower(
+            last_layer(runner), *on_chip(args, runner.mesh)).compile()
+    assert ragged_dot_rows(compiled) == {dispatch_rows(model, T)}
 
 
 def forward_lowered(runner, t_bucket: int):
@@ -867,8 +916,14 @@ def test_one_layer_forward_compiles(abstract_runner, name, t_bucket):
     kernel, the layer loop, the expert switch) of each benchmark
     configuration at its published widths, at the smallest and the
     largest token bucket."""
-    compiled = forward_lowered(abstract_runner(name), t_bucket)[0].compile()
+    runner = abstract_runner(name)
+    lowered, T = forward_lowered(runner, t_bucket)
+    compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if name in SPARSE + WHOLE_EXPERT:
+        # The grouped GEMMs' rows are the dispatch's rule's, at the
+        # 2 048-token bucket a count in tiles of 128 rows or more.
+        assert ragged_dot_rows(compiled) == {dispatch_rows(runner.model, T)}
     if name in SPARSE or name == "smallthinker-21b-a3b":
         slab, _ = expert_slab(bench_config(name))
         assert slab in compiled.as_text()
@@ -955,18 +1010,21 @@ def test_share_dispatch_holds_no_array_of_all_the_picks(abstract_runner):
 
 # sha256 of OLMoE's 64-token forward as lowered (StableHLO text without
 # source locations and without the attention kernel's Mosaic payload,
-# which carries its file's path) at commit f07cdeb, the parent of PR 40.
-# A PR that means to change that program records the new digest here
-# and says so.
+# which carries its file's path). A PR that means to change that program
+# records the new digest here and says so. PR 48 did: the whole-expert
+# dispatch selects away every row in no group (at this bucket's 576 rows
+# the padding is none; 8d0bf2246de138f9... from PR 40's parent until
+# then).
 OLMOE_FORWARD_64 = (
-    "8d0bf2246de138f9c467c37993e64fc057245e60b9ea1a4c1fb3b12f537b6e6f")
+    "968400df0b5be3b4af56897d4111b84df027154bd39598d79db4a8c0ef40cc94")
 
 
 def test_a_model_without_a_share_lowers_to_the_same_program(
         abstract_runner):
-    """PR 40 changed the dispatch of an expert SHARE only: the program
-    of a configuration without one (OLMoE, the Mixtral dispatch) is the
-    parent's, instruction for instruction."""
+    """A PR that changes the dispatch of an expert SHARE only (PR 40),
+    or nothing of the Mixtral dispatch, leaves the program of a
+    configuration without a share (OLMoE) as it was, instruction for
+    instruction."""
     import hashlib
     lowered, _ = forward_lowered(abstract_runner("olmoe-1b-7b"), 64)
     text = re.sub(r'loc\([^)]*\)|^#loc.*$|\\22body\\22: \\22[^\\]*\\22',

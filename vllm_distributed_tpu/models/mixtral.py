@@ -55,6 +55,37 @@ _ROW_TILE = 128
 _WHOLE_PICKS = 1024
 
 
+# XLA's ``ragged_dot`` on the TPU works in row tiles of the largest power
+# of two that divides its rows, this many at most, and a (tile, group)
+# pair that shares a row is one visit: tiles + groups - 1 of them at
+# worst. A visit loads the group's matrix whatever the tile holds of the
+# group, which on a v5e costs what the product of about 330 rows costs
+# (scripts/ragged_rows.py; PERF.md section 5 has the table).
+_MAX_ROW_TILE = 4 * _ROW_TILE
+_VISIT_ROWS = 330
+
+
+def _ragged_dot_cost(rows: int, groups: int) -> int:
+    tile = min(rows & -rows, _MAX_ROW_TILE)
+    return (rows // tile + groups - 1) * (_VISIT_ROWS + tile)
+
+
+def padded_picks(picks: int, groups: int) -> int:
+    """Rows the one-chip whole-expert dispatch hands ``ragged_dot`` for
+    ``picks`` sorted picks over ``groups`` experts: ``picks`` as they
+    are or, where ``_ragged_dot_cost`` is lower there, the next odd
+    multiple of 128 or of 256 (odd, so that the tile is that and no
+    larger) or the next multiple of 512. 2 056 tokens of 6 picks over
+    64 experts are 12 336 rows in tiles of 16, 12 544 are 49 tiles of
+    256 and three and a half times faster; 32 tokens' 240 rows stay
+    240. A function of the program's shapes alone."""
+    return min([picks,
+                (cdiv(picks, _ROW_TILE) | 1) * _ROW_TILE,
+                (cdiv(picks, 2 * _ROW_TILE) | 1) * 2 * _ROW_TILE,
+                round_up(picks, _MAX_ROW_TILE)],
+               key=lambda rows: _ragged_dot_cost(rows, groups))
+
+
 @functools.lru_cache(maxsize=None)
 def _take_layer(sharding):
     return jax.jit(
@@ -304,7 +335,12 @@ class MixtralForCausalLM(LlamaForCausalLM):
         weights (the XLA grouped-GEMM that replaces the reference's
         fused_moe CUDA kernels / moe_pallas.py seed), and segment-sum
         the weighted rows back. Cost is k/E of the dense all-expert
-        form — only selected (token, expert) pairs hit the MXU.
+        form — only selected (token, expert) pairs hit the MXU. On one
+        chip the sorted picks are padded behind the last group to a row
+        count that ``ragged_dot`` tiles well (``padded_picks``: the
+        same products in larger tiles), and every row in no group, the
+        padding and a pick past the last expert alike, is selected away
+        before the sum.
 
         VDT_MOE_BACKEND=dense restores the all-expert einsum baseline
         (also used by the FLOP-reduction regression test).
@@ -370,20 +406,26 @@ class MixtralForCausalLM(LlamaForCausalLM):
             order = jnp.argsort(flat_e, stable=True)
             se, st = flat_e[order], flat_t[order]
             sw = flat_w[order]
-            xs = x[st]                                        # [T*k, H]
+            if not c.expert_parallel:
+                # Rows that ragged_dot tiles well (padded_picks): the
+                # padding is no expert's, so it sorts behind every group
+                # as a pick past the last expert does (models/zaya.py).
+                pad = (0, padded_picks(T * k, Pn) - T * k)
+                se = jnp.pad(se, pad, constant_values=Pn)
+                st, sw = jnp.pad(st, pad), jnp.pad(sw, pad)
+            xs = x[st]                                        # [rows, H]
 
         if c.expert_parallel:
-            y = self._moe_ep_ragged(lp, xs, se, sw)
+            y = self._moe_ep_ragged(lp, xs, se, sw)  # weights its own rows
         else:
             y = self._expert_ffn(lp, xs, jnp.bincount(se, length=Pn))
-            if getattr(c, "moe_skip_pick", False):
-                # A pick past the last expert (models/zaya.py) sorts
-                # behind every group: its rows were in no GEMM.
-                y = jnp.where((se < Pn)[:, None], y, 0)
+            with jax.named_scope("moe_combine"):
+                # Rows in no group were in no GEMM: selected away, not
+                # multiplied by a weight of 0 (_expert_ffn's docstring).
+                y = jnp.where((se < Pn)[:, None],
+                              y * sw[:, None].astype(y.dtype), 0)
         # Un-sort + combine the k expert outputs per token.
         with jax.named_scope("moe_combine"):
-            if not c.expert_parallel:   # the EP path weights its own rows
-                y = y * sw[:, None].astype(y.dtype)
             out = jax.ops.segment_sum(y, st, num_segments=T)
         return out.astype(x.dtype)
 
@@ -396,7 +438,9 @@ class MixtralForCausalLM(LlamaForCausalLM):
         that divides its rows (at 16 groups of 70 rows, 128: 3.7 ms for
         the three GEMMs, 512: 5.2, 32: 6.6, 8: 19.4), and under a
         thousand rows it does not fall with them (PERF.md, Findings
-        PR 40)."""
+        PR 40). The whole-expert branch of ``moe_dispatch`` works on all
+        ``T x k`` picks and pads them by the same finding, priced a
+        visit at a time (``padded_picks``, PR 48)."""
         picks = T * k
         if picks <= _WHOLE_PICKS:
             return picks

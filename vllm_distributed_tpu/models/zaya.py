@@ -192,9 +192,6 @@ class ZayaForCausalLM(MixtralForCausalLM):
         arch.norm_topk_prob = False
         arch.moe_intermediate_size = int(hf.moe_intermediate_size)
         arch.router_hidden_size = int(hf.router_hidden_size)
-        # A pick past the last expert is in no grouped GEMM's groups
-        # (models/mixtral.py moe_dispatch).
-        arch.moe_skip_pick = True
         arch.stateful = True
         arch.cca = True
         if not hasattr(arch, "state_slots"):
