@@ -4,7 +4,10 @@ HTTP to a served model)."""
 
 import asyncio
 import json
+import pathlib
+import re
 import threading
+import time
 
 import httpx
 import pytest
@@ -17,6 +20,8 @@ from vllm_distributed_tpu.engine.async_llm import AsyncLLM
 from vllm_distributed_tpu.utils import get_open_port
 
 VOCAB = 128
+# The served engine and its event loop, for the tests that watch the loop.
+_SERVER = {}
 
 
 def _save_checkpoint_with_tokenizer(path) -> HFLlama:
@@ -67,6 +72,7 @@ def server(tmp_path_factory):
         stop = asyncio.Event()
         stop_holder["stop"] = stop
         stop_holder["loop"] = loop
+        _SERVER.update(loop=loop, engine=engine)
         loop.run_until_complete(serve(engine, path, "127.0.0.1", port,
                                       ready_event=ready, stop_event=stop))
         loop.close()
@@ -444,3 +450,163 @@ def test_spec_stats_render_in_metrics():
     assert "vdt:spec_decode_num_draft_tokens_total 30.0" in text
     assert "vdt:spec_decode_num_accepted_tokens_total 21.0" in text
     assert "vdt:spec_decode_acceptance_rate 0.7" in text
+
+
+# ---------------------------------------------------------------------------
+# The per-token path (PR 49): bytes on the wire, the liveness guard, no
+# Task a token.
+def _sse_bytes(base: str, path: str, body: dict) -> bytes:
+    """A streamed response's raw bytes, ``id``, ``created`` and
+    ``model`` (random, wall-clock, a temporary path) normalised, chunk
+    boundaries as the server wrote them."""
+    with httpx.stream("POST", f"{base}{path}", timeout=300,
+                      json=dict(body, stream=True)) as r:
+        assert r.status_code == 200
+        raw = b"".join(r.iter_raw())
+    raw = re.sub(rb'"id": "[^"]+"', b'"id": "ID"', raw)
+    raw = re.sub(rb'"model": "[^"]+"', b'"model": "M"', raw)
+    return re.sub(rb'"created": \d+', b'"created": 0', raw)
+
+
+_GOLDEN_DIR = pathlib.Path(__file__).parent / "golden"
+_GOLDEN_REQUESTS = {
+    "stream_completion_greedy": ("/v1/completions", {
+        "model": "tiny", "prompt": "w9 w8 w7", "max_tokens": 8,
+        "temperature": 0.0, "ignore_eos": True}),
+    "stream_completion_seeded": ("/v1/completions", {
+        "model": "tiny", "prompt": "w3 w17 w92 w45 w8", "max_tokens": 12,
+        "temperature": 0.8, "seed": 1234, "ignore_eos": True}),
+    "stream_completion_stop": ("/v1/completions", {
+        "model": "tiny", "prompt": "w9 w8 w7", "max_tokens": 8,
+        "temperature": 0.0, "ignore_eos": True, "stop": ["w63 w80"]}),
+    "stream_chat_greedy": ("/v1/chat/completions", {
+        "model": "tiny",
+        "messages": [{"role": "user", "content": "w11 w12"}],
+        "max_tokens": 6, "temperature": 0.0, "ignore_eos": True}),
+    "stream_chat_seeded": ("/v1/chat/completions", {
+        "model": "tiny",
+        "messages": [{"role": "user", "content": "w5 w6 w7"}],
+        "max_tokens": 10, "temperature": 0.8, "seed": 99,
+        "ignore_eos": True}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_GOLDEN_REQUESTS))
+def test_streamed_bytes_equal_parent_golden(server, name):
+    """The SSE bytes of seeded streams, chunk for chunk, equal what the
+    commit before the delta path (PR 48) wrote for the same requests
+    (tests/entrypoints/golden/, taken there with this function)."""
+    base, _ = server
+    path, body = _GOLDEN_REQUESTS[name]
+    got = _sse_bytes(base, path, body)
+    assert got == (_GOLDEN_DIR / f"{name}.txt").read_bytes()
+
+
+def _tasks_created_while(fn) -> int:
+    """asyncio Tasks the server's event loop creates while ``fn`` runs
+    (here, on the test's thread), counted through a task factory."""
+    loop = _SERVER["loop"]
+    created = []
+
+    def factory(loop, coro, **kwargs):
+        created.append(coro)
+        return asyncio.Task(coro, loop=loop, **kwargs)
+
+    loop.call_soon_threadsafe(loop.set_task_factory, factory)
+    try:
+        fn()
+    finally:
+        loop.call_soon_threadsafe(loop.set_task_factory, None)
+    return len(created)
+
+
+def test_stream_creates_no_task_a_token(server):
+    """Four child streams of 50 tokens against four of 5: the loop's
+    Tasks are the connection's, the handler's and the four pumps', the
+    same for 200 tokens as for 20 (the parent made one more a token)."""
+    base, _ = server
+    body = {"model": "tiny", "prompt": "w9 w8 w7", "n": 4,
+            "temperature": 0.0, "ignore_eos": True}
+    counts = {}
+    for max_tokens in (5, 50, 5):
+        def stream():
+            raw = _sse_bytes(base, "/v1/completions",
+                             dict(body, max_tokens=max_tokens))
+            assert raw.count(b"data: ") == 4 * max_tokens + 1
+        counts.setdefault(max_tokens, []).append(
+            _tasks_created_while(stream))
+    assert min(counts[5]) >= 4
+    assert counts[50][0] <= max(counts[5]) + 2, counts
+    # All of it went out as deltas over the one-decode path.
+    stats = _SERVER["engine"].output_processor.stats
+    assert stats.request_outputs["delta"] >= 240
+    assert stats.detokenized_tokens["window"] == 0
+
+
+class _FakeTransport:
+    closing = False
+
+    def is_closing(self):
+        return self.closing
+
+
+class _FakeRequest(dict):
+    """What the stream guard reads of a web.Request."""
+
+    def __init__(self):
+        super().__init__()
+        self.transport = _FakeTransport()
+
+
+@pytest.mark.parametrize("cause", ["disconnect", "deadline", "cancel",
+                                   "alive"])
+def test_stream_guard_without_any_output(cause):
+    """An engine that yields nothing for 1.5 s: the guard still sees a
+    client gone or a deadline passed within about a second, cancels the
+    waiting handler (whose generate() unwinds into its abort path) and
+    raises what it found; someone else's cancellation stays one; a
+    stream that is alive is left alone and its timer taken down."""
+    from vllm_distributed_tpu.entrypoints.openai import api_server
+
+    async def main():
+        request = _FakeRequest()
+        unwound = []
+
+        async def silent_engine():
+            try:
+                await asyncio.sleep(1.5)
+                yield "late"
+            finally:
+                unwound.append(time.monotonic())
+
+        async def pump():
+            return [out async for out in silent_engine()]
+
+        async def handler():
+            with api_server._stream_guard(request):
+                return await asyncio.gather(pump(), pump())
+
+        loop = asyncio.get_running_loop()
+        t0 = time.monotonic()
+        task = asyncio.ensure_future(handler())
+        if cause == "disconnect":
+            loop.call_later(0.2, setattr, request.transport, "closing",
+                            True)
+            want, within = ConnectionResetError, 1.4
+        elif cause == "deadline":
+            request[api_server.DEADLINE_AT_KEY] = t0 + 0.3
+            want, within = asyncio.TimeoutError, 0.6
+        elif cause == "cancel":
+            loop.call_later(0.2, task.cancel)
+            want, within = asyncio.CancelledError, 0.4
+        else:
+            assert await task == [["late"], ["late"]]
+            await asyncio.sleep(1.1)  # a timer left armed would fire
+            return
+        with pytest.raises(want):
+            await task
+        took = time.monotonic() - t0
+        assert took < within, took
+        assert len(unwound) == 2 and max(unwound) - t0 < within
+
+    asyncio.run(main())

@@ -26,7 +26,7 @@ from vllm_distributed_tpu.engine.processor import Processor
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.metrics import events as ev
 from vllm_distributed_tpu.metrics.stats import span
-from vllm_distributed_tpu.outputs import RequestOutput
+from vllm_distributed_tpu.outputs import RequestOutput, RequestOutputKind
 from vllm_distributed_tpu.sampling_params import SamplingParams
 
 logger = init_logger(__name__)
@@ -397,11 +397,14 @@ class AsyncLLM:
         pooling_params: Optional[dict] = None,
         multi_modal_data: Optional[dict] = None,
         received_at: Optional[float] = None,
+        output_kind: RequestOutputKind = RequestOutputKind.CUMULATIVE,
     ) -> AsyncGenerator[RequestOutput, None]:
-        """Async stream of accumulated RequestOutputs for one request
-        (reference: async_llm.py:277). ``received_at`` is
-        ``time.monotonic()`` at the top of the HTTP handler, where
-        vdt:frontend_admit_seconds starts (here, for other callers)."""
+        """Async stream of RequestOutputs for one request (reference:
+        async_llm.py:277): accumulated by default, the step's own under
+        ``RequestOutputKind.DELTA``, one at the finish under
+        ``FINAL_ONLY``. ``received_at`` is ``time.monotonic()`` at the
+        top of the HTTP handler, where vdt:frontend_admit_seconds starts
+        (here, for other callers)."""
         if received_at is None:
             received_at = time.monotonic()
         if self._dead_error is not None:
@@ -419,7 +422,8 @@ class AsyncLLM:
         queue: asyncio.Queue = asyncio.Queue()
         self.request_queues[request_id] = queue
         self.output_processor.add_request(
-            core_req, prompt=prompt if isinstance(prompt, str) else None)
+            core_req, prompt=prompt if isinstance(prompt, str) else None,
+            output_kind=output_kind)
         try:
             # Submission runs off-loop: during a supervisor restart the
             # core lock is held for the respawn's duration, and the

@@ -15,7 +15,8 @@ from vllm_distributed_tpu.metrics import events as ev
 from vllm_distributed_tpu.metrics.stats import RequestTimes
 from vllm_distributed_tpu.outputs import (CompletionOutput,
                                           PoolingOutput,
-                                          RequestOutput)
+                                          RequestOutput,
+                                          RequestOutputKind)
 from vllm_distributed_tpu.request import EngineCoreRequest
 from vllm_distributed_tpu.sampling_params import SamplingParams
 
@@ -31,8 +32,11 @@ class RequestState:
     prompt_token_ids: list[int]
     params: SamplingParams
     detokenizer: Optional[IncrementalDetokenizer]
+    output_kind: RequestOutputKind = RequestOutputKind.CUMULATIVE
     output_token_ids: list[int] = field(default_factory=list)
     logprobs: list[dict[int, float]] = field(default_factory=list)
+    # Running sum of the sampled tokens' logprobs (None without any).
+    cumulative_logprob: Optional[float] = None
     num_cached_tokens: int = 0
     finished: bool = False
     finish_reason: Optional[str] = None
@@ -124,13 +128,14 @@ class OutputProcessor:
         # reporting; bounded FIFO per phase.
         self.phase_durations: dict[str, list[float]] = {}
 
-    def add_request(self, request: EngineCoreRequest,
-                    prompt: Optional[str] = None) -> None:
+    def add_request(
+        self, request: EngineCoreRequest, prompt: Optional[str] = None,
+        output_kind: RequestOutputKind = RequestOutputKind.CUMULATIVE
+    ) -> None:
         params = request.sampling_params
         detok = None
         if self.tokenizer is not None and params.detokenize:
-            detok = IncrementalDetokenizer(self.tokenizer, params,
-                                           request.prompt_token_ids)
+            detok = IncrementalDetokenizer(self.tokenizer, params)
         import time as _time
         arrival = _time.monotonic()
         tenant = None
@@ -144,6 +149,7 @@ class OutputProcessor:
             prompt_token_ids=request.prompt_token_ids,
             params=params,
             detokenizer=detok,
+            output_kind=output_kind,
             times=RequestTimes(arrival=arrival),
             tenant=tenant,
             trace_ctx=request.trace_ctx,
@@ -271,11 +277,17 @@ class OutputProcessor:
                         (state.times.first_token, ev.FIRST_TOKEN, None))
             if out.logprobs:
                 state.logprobs.extend(out.logprobs)
+                state.cumulative_logprob = sum(
+                    (next(iter(lp.values())) for lp in out.logprobs),
+                    state.cumulative_logprob or 0.0)
             state.num_cached_tokens = out.num_cached_tokens
 
             stop_str = None
-            if state.detokenizer is not None:
-                stop_str = state.detokenizer.update(out.new_token_ids)
+            detok = state.detokenizer
+            if detok is not None:
+                stop_str = detok.update(out.new_token_ids)
+                self.stats.detokenized_tokens[detok.path] += len(
+                    out.new_token_ids)
 
             finish_reason = out.finish_reason
             stop_reason = out.stop_reason
@@ -312,7 +324,11 @@ class OutputProcessor:
                     # Emit any text held back waiting for more context.
                     state.detokenizer.flush()
 
-            request_outputs.append(self._make_request_output(state))
+            kind = state.output_kind
+            if finished or kind is not RequestOutputKind.FINAL_ONLY:
+                request_outputs.append(
+                    self._make_request_output(state, out))
+                self.stats.request_outputs[kind.value] += 1
             if finished:
                 del self.request_states[out.req_id]
         return ProcessedOutputs(request_outputs, reqs_to_abort)
@@ -352,17 +368,24 @@ class OutputProcessor:
             attrs[SA.GEN_AI_TRACE_ID] = state.trace_ctx.get("trace_id")
         self.tracer.emit(attrs, phases=phases, events=events)
 
-    def _make_request_output(self, state: RequestState) -> RequestOutput:
-        text = (state.detokenizer.output_text
-                if state.detokenizer is not None else "")
+    def _make_request_output(self, state: RequestState,
+                             out: EngineCoreOutput) -> RequestOutput:
+        detok = state.detokenizer
+        if state.output_kind is RequestOutputKind.DELTA:
+            # The step's own: nothing of the request's whole is copied.
+            text = detok.new_text if detok is not None else ""
+            token_ids = out.new_token_ids
+            logprobs = out.logprobs or None
+        else:
+            text = detok.output_text if detok is not None else ""
+            token_ids = list(state.output_token_ids)
+            logprobs = list(state.logprobs) if state.logprobs else None
         completion = CompletionOutput(
             index=0,
             text=text,
-            token_ids=list(state.output_token_ids),
-            logprobs=list(state.logprobs) if state.logprobs else None,
-            cumulative_logprob=(sum(
-                next(iter(lp.values())) for lp in state.logprobs)
-                                if state.logprobs else None),
+            token_ids=token_ids,
+            logprobs=logprobs,
+            cumulative_logprob=state.cumulative_logprob,
             finish_reason=state.finish_reason,
             stop_reason=state.stop_reason,
         )

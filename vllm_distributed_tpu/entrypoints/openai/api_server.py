@@ -8,6 +8,7 @@ engine death — reference: entrypoints/launcher.py).
 """
 
 import asyncio
+import contextlib
 import json
 import signal
 import time
@@ -23,6 +24,7 @@ from vllm_distributed_tpu.entrypoints.openai.admission import (
 from vllm_distributed_tpu.entrypoints.openai.protocol import RequestError
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.metrics.stats import span
+from vllm_distributed_tpu.outputs import RequestOutputKind
 from vllm_distributed_tpu.utils import random_uuid
 
 logger = init_logger(__name__)
@@ -71,8 +73,8 @@ def _error_response(e: Exception) -> web.Response:
 
 # Monotonic instant (stashed on the request by the admission
 # middleware) past which a STREAMING handler must abort its pumps; the
-# pumps enforce it because a fresh 408 response cannot be written once
-# the SSE stream has started.
+# stream's guard (_stream_guard) enforces it because a fresh 408
+# response cannot be written once the SSE stream has started.
 DEADLINE_AT_KEY = "vdt_deadline_at"
 
 
@@ -145,8 +147,9 @@ async def _admission_middleware_factory(app, handler):
             deadline, stream, _ = fields
             if deadline > 0 and stream:
                 # A 408 cannot be written once the SSE stream begins:
-                # the stream pumps poll this instant and end the stream
-                # cleanly (abort + [DONE]-less EOF) when it passes.
+                # the stream's guard arms its timer for this instant and
+                # ends the stream cleanly (abort + [DONE]-less EOF) when
+                # it passes.
                 request[DEADLINE_AT_KEY] = time.monotonic() + deadline
             elif deadline > 0:
                 try:
@@ -913,7 +916,8 @@ async def responses(request: web.Request) -> web.Response:
                                              priority=priority,
                                              tenant=tenant,
                                              lora_request=lora,
-                                             multi_modal_data=mm))
+                                             multi_modal_data=mm,
+                                             output_kind=RequestOutputKind.FINAL_ONLY))
         text = final.outputs[0].text
         return web.json_response({
             "id": rid,
@@ -1023,7 +1027,8 @@ async def transcriptions(request: web.Request) -> web.Response:
         final = await _drain(engine.generate(
             prompt, params,
             request_id=protocol.completion_id().replace("cmpl", "trsc"),
-            multi_modal_data={"audio": wav}))
+            multi_modal_data={"audio": wav},
+            output_kind=RequestOutputKind.FINAL_ONLY))
         return web.json_response({
             "text": final.outputs[0].text,
             "model": model,
@@ -1166,7 +1171,8 @@ async def completions(request: web.Request) -> web.StreamResponse:
                     prompt, child, request_id=f"{cid}-{idx}",
                     priority=priority, tenant=tenant,
                     lora_request=lora, multi_modal_data=enc_mm,
-                    received_at=received_at)))
+                    received_at=received_at,
+                    output_kind=_output_kind(stream))))
 
         if stream:
             return await _stream_completions(request, cid, created, model,
@@ -1196,6 +1202,14 @@ async def completions(request: web.Request) -> web.StreamResponse:
         })
     except (RequestError, EngineDeadError, ValueError) as e:
         return _error_response(e)
+
+
+def _output_kind(stream: bool) -> RequestOutputKind:
+    """What a handler asks generate() for, from its own request: the
+    streaming handlers write deltas, every other (``_drain``) reads the
+    last output alone."""
+    return (RequestOutputKind.DELTA if stream
+            else RequestOutputKind.FINAL_ONLY)
 
 
 async def _drain(gen):
@@ -1264,39 +1278,49 @@ def _check_stream_alive(request: web.Request) -> None:
         raise asyncio.TimeoutError("stream exceeded its deadline")
 
 
-async def _stream_outputs(request: web.Request, gen):
-    """Iterate an engine stream, enforcing the liveness guard once a
-    second even when NO output arrives — a request stalled in the
-    engine (queued, remote-KV hold) must still honor disconnects and
-    deadlines instead of keeping its slot until the next token. The
-    pending __anext__ survives across polls; it is cancelled only when
-    the guard trips, which unwinds generate() into the abort path."""
-    aiter = gen.__aiter__()
-    task = None
-    try:
-        while True:
-            if task is None:
-                task = asyncio.ensure_future(aiter.__anext__())
-            done, _ = await asyncio.wait({task}, timeout=1.0)
+@contextlib.contextmanager
+def _stream_guard(request: web.Request):
+    """The liveness guard of one streamed response: ONE timer, re-armed
+    once a second (sooner where the deadline falls sooner), runs
+    ``_check_stream_alive`` whether or not any output arrives — a
+    request stalled in the engine (queued, remote-KV hold) must still
+    honor disconnects and deadlines instead of keeping its slot until
+    the next token. When the guard trips it cancels the handler's task,
+    which unwinds every generate() it waits on into the abort path, and
+    the block raises what the guard found in the cancellation's place.
+    The pumps inside await the engine's streams directly: no Task, no
+    timer handle a token."""
+    loop = asyncio.get_running_loop()
+    task = asyncio.current_task()
+    deadline_at = request.get(DEADLINE_AT_KEY)
+    tripped: list[Exception] = []
+    handle = None
+
+    def arm() -> None:
+        nonlocal handle
+        delay = 1.0
+        if deadline_at is not None:
+            delay = min(delay, max(deadline_at - time.monotonic(), 0.0) + 1e-3)
+        handle = loop.call_later(delay, check)
+
+    def check() -> None:
+        try:
             _check_stream_alive(request)
-            if not done:
-                continue
-            try:
-                out = task.result()
-            except StopAsyncIteration:
-                task = None
-                return
-            task = None
-            yield out
-    finally:
-        if task is not None and not task.done():
+        except (ConnectionResetError, asyncio.TimeoutError) as e:
+            tripped.append(e)
             task.cancel()
-            try:
-                # Let the cancellation reach generate()'s finally (the
-                # upstream abort) before the handler returns.
-                await task
-            except BaseException:  # noqa: BLE001 - cancelled/aborted
-                pass
+        else:
+            arm()
+
+    arm()
+    try:
+        yield
+    except asyncio.CancelledError:
+        if not tripped or task.uncancel() > 0:
+            raise  # cancelled by someone else (as well)
+        raise tripped[0] from None
+    finally:
+        handle.cancel()
 
 
 async def _abort_stream(request: web.Request, cid: str,
@@ -1311,38 +1335,38 @@ async def _abort_stream(request: web.Request, cid: str,
             pass
 
 
-async def _stream_completions(request, cid, created, model,
-                              gens) -> web.StreamResponse:
+async def _stream_sse(request, cid, obj, created, model, gens, choice,
+                      opening=None) -> web.StreamResponse:
+    """One SSE response over the delta streams ``gens`` (generate() with
+    RequestOutputKind.DELTA): a chunk for every output that carries text
+    or a finish, its one choice built by ``choice(idx, delta, finish)``,
+    after ``opening(idx)``'s chunk where the dialect has one."""
     resp = web.StreamResponse(headers={
         "Content-Type": "text/event-stream",
         "Cache-Control": "no-cache",
     })
     await resp.prepare(request)
+    # The chunk's constant fields, rendered once a stream: json.dumps of
+    # the whole chunk puts exactly these bytes in front of its choices.
+    head = json.dumps({"id": cid, "object": obj, "created": created,
+                       "model": model})
+    head = f'data: {head[:-1]}, "choices": '
+
+    async def send(one_choice: dict) -> None:
+        await resp.write(
+            f"{head}{json.dumps([one_choice])}}}\n\n".encode())
 
     async def pump(idx, gen):
-        sent = 0
-        async for out in _stream_outputs(request, gen):
-            text = out.outputs[0].text
-            delta = text[sent:]
-            sent = len(text)
-            finish = out.outputs[0].finish_reason
-            if delta or finish:
-                chunk = {
-                    "id": cid,
-                    "object": "text_completion",
-                    "created": created,
-                    "model": model,
-                    "choices": [{
-                        "index": idx,
-                        "text": delta,
-                        "finish_reason": finish,
-                    }],
-                }
-                await resp.write(
-                    f"data: {json.dumps(chunk)}\n\n".encode())
+        if opening is not None:
+            await send(opening(idx))
+        async for out in gen:
+            comp = out.outputs[0]
+            if comp.text or comp.finish_reason:
+                await send(choice(idx, comp.text, comp.finish_reason))
 
     try:
-        await asyncio.gather(*(pump(idx, gen) for idx, gen in gens))
+        with _stream_guard(request):
+            await asyncio.gather(*(pump(idx, gen) for idx, gen in gens))
         await resp.write(b"data: [DONE]\n\n")
     except (EngineDeadError, ConnectionResetError,
             asyncio.TimeoutError) as e:
@@ -1350,6 +1374,13 @@ async def _stream_completions(request, cid, created, model,
         await _abort_stream(request, cid, gens)
     await resp.write_eof()
     return resp
+
+
+def _stream_completions(request, cid, created, model, gens):
+    return _stream_sse(
+        request, cid, "text_completion", created, model, gens,
+        lambda idx, delta, finish: {
+            "index": idx, "text": delta, "finish_reason": finish})
 
 
 # ---------------------------------------------------------------------------
@@ -1481,7 +1512,8 @@ async def chat_completions(request: web.Request) -> web.StreamResponse:
                                     priority=priority, tenant=tenant,
                                     lora_request=lora,
                                     multi_modal_data=mm,
-                                    received_at=received_at))
+                                    received_at=received_at,
+                                    output_kind=_output_kind(stream)))
                 for i in range(n)]
         if stream:
             return await _stream_chat(request, cid, created, model, gens)
@@ -1535,48 +1567,15 @@ async def chat_completions(request: web.Request) -> web.StreamResponse:
         return _error_response(e)
 
 
-async def _stream_chat(request, cid, created, model,
-                       gens) -> web.StreamResponse:
-    resp = web.StreamResponse(headers={
-        "Content-Type": "text/event-stream",
-        "Cache-Control": "no-cache",
-    })
-    await resp.prepare(request)
-
-    async def send(choices):
-        chunk = {
-            "id": cid,
-            "object": "chat.completion.chunk",
-            "created": created,
-            "model": model,
-            "choices": choices,
-        }
-        await resp.write(f"data: {json.dumps(chunk)}\n\n".encode())
-
-    async def pump(idx, gen):
-        await send([{"index": idx,
-                     "delta": {"role": "assistant", "content": ""},
-                     "finish_reason": None}])
-        sent = 0
-        async for out in _stream_outputs(request, gen):
-            text = out.outputs[0].text
-            delta = text[sent:]
-            sent = len(text)
-            finish = out.outputs[0].finish_reason
-            if delta or finish:
-                await send([{"index": idx,
-                             "delta": ({"content": delta} if delta else {}),
-                             "finish_reason": finish}])
-
-    try:
-        await asyncio.gather(*(pump(idx, gen) for idx, gen in gens))
-        await resp.write(b"data: [DONE]\n\n")
-    except (EngineDeadError, ConnectionResetError,
-            asyncio.TimeoutError) as e:
-        logger.warning("stream aborted: %s", e)
-        await _abort_stream(request, cid, gens)
-    await resp.write_eof()
-    return resp
+def _stream_chat(request, cid, created, model, gens):
+    return _stream_sse(
+        request, cid, "chat.completion.chunk", created, model, gens,
+        lambda idx, delta, finish: {
+            "index": idx, "delta": {"content": delta} if delta else {},
+            "finish_reason": finish},
+        opening=lambda idx: {
+            "index": idx, "delta": {"role": "assistant", "content": ""},
+            "finish_reason": None})
 
 
 # ---------------------------------------------------------------------------

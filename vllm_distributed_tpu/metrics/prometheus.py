@@ -233,6 +233,9 @@ LABELED_HISTOGRAMS = {
 # literal and cross-checks every entry against the README metrics
 # table, so an undocumented label set fails tier-1).
 LABELED_METRICS = {
+    # The front end's per-token path (metrics/stats.py FrontendStats).
+    "vdt:frontend_detokenized_tokens_total": ("path", ),
+    "vdt:frontend_request_outputs_total": ("kind", ),
     "vdt:step_phase_seconds": ("phase", ),
     "vdt:step_phase_cpu_seconds": ("phase", ),
     "vdt:step_kind_seconds": ("kind", "part"),

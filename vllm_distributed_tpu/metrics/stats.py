@@ -463,6 +463,15 @@ class FrontendStats:
         default_factory=lambda: Histogram(QUEUE_LAG_BUCKETS))
     metrics_render: Histogram = field(
         default_factory=lambda: Histogram(STEP_PHASE_BUCKETS))
+    # The per-token path's two counts (OutputProcessor.process_outputs):
+    # tokens detokenized, by the carrier that decoded them
+    # (engine/detokenizer.py: one DecodeStream step, or the two-decode
+    # window), and RequestOutputs built, by the kind their caller asked
+    # for (outputs.RequestOutputKind).
+    detokenized_tokens: dict = field(
+        default_factory=lambda: {"stream": 0, "window": 0})
+    request_outputs: dict = field(
+        default_factory=lambda: {"cumulative": 0, "delta": 0, "final": 0})
     num_prompt_tokens: int = 0
     num_generation_tokens: int = 0
     num_finished: int = 0
@@ -626,6 +635,20 @@ class FrontendStats:
             "vdt:frontend_batches_pending "
             f"{self.batches_posted - self.batches_begun}",
         ]
+        for name, help_text, label, counts in (
+            ("vdt:frontend_detokenized_tokens_total",
+             "Tokens detokenized, by carrier: one DecodeStream step a "
+             "token (fast tokenizer backend) or the two-decode window",
+             "path", self.detokenized_tokens),
+            ("vdt:frontend_request_outputs_total",
+             "RequestOutputs built by process_outputs, by the output "
+             "kind the request's caller asked for", "kind",
+             self.request_outputs),
+        ):
+            lines += [f"# HELP {name} {help_text}",
+                      f"# TYPE {name} counter"]
+            lines += [f'{name}{{{label}="{value}"}} {count}'
+                      for value, count in counts.items()]
         lines += self.admit.render(
             "vdt:frontend_admit_seconds",
             "Top of the HTTP handler (AsyncLLM.generate for other "

@@ -1,7 +1,22 @@
 """User-facing request outputs (reference: vllm/outputs.py)."""
 
+import enum
 from dataclasses import dataclass, field
 from typing import Optional
+
+
+class RequestOutputKind(enum.Enum):
+    """What each RequestOutput of a request's stream holds (reference:
+    vllm/sampling_params.py RequestOutputKind). The CALLER of
+    ``generate`` chooses it from what its own request says; the values
+    are vdt:frontend_request_outputs_total's ``kind`` labels."""
+
+    # The whole completion so far, in every output.
+    CUMULATIVE = "cumulative"
+    # What the step added: its text, its token ids, their logprobs.
+    DELTA = "delta"
+    # Nothing until the request finishes, then the whole completion.
+    FINAL_ONLY = "final"
 
 
 @dataclass
@@ -9,6 +24,8 @@ class CompletionOutput:
     """One generated completion for a request."""
 
     index: int
+    # Under RequestOutputKind.DELTA text, token_ids and logprobs are the
+    # step's own; cumulative_logprob is the request's so far in any kind.
     text: str
     token_ids: list[int]
     cumulative_logprob: Optional[float] = None
