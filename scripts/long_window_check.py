@@ -2,7 +2,7 @@
 """The window, past its edge, on the chip.
 
     python3 scripts/long_window_check.py [--seed N] [--cell NAME]
-        [--lengths 4300,5200,6100]
+        [--lengths 4300,5200,6100] [--targets]
 
 The comparison that decides ``correct`` in a benchmark run scores prompts
 of 48 to 209 tokens (``benchmarks/traffic.py`` ``scoring_requests``), so
@@ -26,6 +26,15 @@ gives the same distances position by position: the program's tail over
 all positions, and the queries past the window (where alone the two
 references can differ) apart from those inside it. Not part of a
 benchmark run.
+
+``--targets`` is the same check for a cell WITHOUT a window whose memory
+is a recurrent state (``brumby-14b.long-document``, with ``--lengths
+300,2600,9000``: inside one 2 048-token chunk step, across two, across
+five, each then 8 tokens through the recurrent kernel): no window to
+cross and no control to add; each prompt is scored apart, through the
+reference's ``targets`` (the targets' log-probabilities alone, the head
+a block of rows at a time, so that 9 000 tokens fit beside the weights),
+and the exit is 0 when every prompt is within the file's ``tolerance``.
 """
 
 import argparse
@@ -98,6 +107,34 @@ def position_deviations(cell: dict, args, scoring: list,
     return out
 
 
+def target_deviations(cell: dict, args, scoring: list) -> list:
+    """``position_deviations`` without a control for a reference that
+    scores ``targets`` alone: no ``[S, vocabulary]`` table, each
+    sequence at its own length."""
+    import jax
+    import jax.numpy as jnp
+
+    from vllm_distributed_tpu.models.loader import get_model
+    from vllm_distributed_tpu.parallel.mesh import build_mesh
+    cf = cell["config_file"]
+    cfg = run.hf_config(cf)
+    ref = importlib.import_module(f"benchmarks.references.{cf['reference']}")
+    config = args.create_engine_config()
+    config.model_config.maybe_load_hf_config()
+    _, params = get_model(config, build_mesh(config.parallel_config,
+                                             jax.devices()[:1]))
+    fn = jax.jit(lambda p, ids, tgt: ref.logprobs(p, cfg, ids,
+                                                  targets=tgt))
+    out = []
+    for s in scoring:
+        seq = jnp.asarray(s["tokens"], jnp.int32)
+        with jax.default_matmul_precision("highest"):
+            want = jax.device_get(fn(params, seq, jnp.roll(seq, -1)))[:-1, 0]
+        out.append([abs(float(w) - g) for w, g in
+                    zip(want, s["token_logprobs"][1:])])
+    return out
+
+
 def summary(devs: list) -> dict:
     devs = sorted(devs)
     n = len(devs)
@@ -110,22 +147,37 @@ def main(argv=None) -> int:
     ap.add_argument("--cell", default="command-a-plus-ep8.long-document")
     ap.add_argument("--seed", type=int, default=4300000001)
     ap.add_argument("--lengths", default="4300,5200,6100")
+    ap.add_argument("--targets", action="store_true",
+                    help="a cell without a window: each prompt apart, "
+                    "through the reference's targets")
     ap.add_argument("--data-root", default=run.HERE)
     a = ap.parse_args(argv)
     import jax
     cell = run.load_cell(a.cell, a.data_root)
     cf = cell["config_file"]
-    # The cell's window, under the key its family publishes it by.
-    window = cf.get("sliding_window") or cf["sliding_window_size"]
     lengths = [int(n) for n in a.lengths.split(",")]
-    assert min(lengths) > window, \
-        "every prompt has to cross the cell's window"
+    if not a.targets:
+        # The cell's window, under the key its family publishes it by.
+        window = cf.get("sliding_window") or cf["sliding_window_size"]
+        assert min(lengths) > window, \
+            "every prompt has to cross the cell's window"
     rng = traffic.run_rng(a.seed, 2)
     prompts = [rng.integers(1, cf["vocab_size"], size=n).tolist()
                for n in lengths]
     model_dir = run.write_model_dir(cell["config"], cf)
     args = run.engine_args(model_dir, cf, a.seed)
     scoring, stats = serve(cf, model_dir, a.seed, prompts)
+    if a.targets:
+        tol = cf["tolerance"]
+        per_prompt = [summary(d)
+                      for d in target_deviations(cell, args, scoring)]
+        ok = all(p["max"] <= tol["max"] and p["mean"] <= tol["mean"]
+                 for p in per_prompt)
+        print(json.dumps({
+            "cell": a.cell, "seed": a.seed, "lengths": lengths,
+            "platform": jax.devices()[0].platform, "tolerance": tol,
+            "prompts": per_prompt, "ok": ok}), flush=True)
+        return 0 if ok else 1
     # For these prompts alone, ``no_window`` joins the controls that the
     # comparison must catch at three tolerances.
     ref = importlib.import_module(f"benchmarks.references.{cf['reference']}")

@@ -559,6 +559,49 @@ def test_gated_delta_kernels_compile_at_published_widths(
         assert f"f32[{chunks},{N},{local}]" not in text, local
 
 
+@pytest.mark.parametrize("tokens,form", [
+    (1, "decode"), (2048, "chunk"), (2048, "auto")])
+def test_power_retention_kernels_compile_at_published_widths(
+        compile_for_chip, tokens, form):
+    """``ops/power_retention.py`` as `brumby-14b` serves it: 40 query
+    heads on 8 K/V heads of 128, a state of 65 tiles of 128 x 128 float32
+    a K/V head (4.26 MB: one block of the recurrent kernel), 16 state
+    rows and the dump row, six layers in one buffer; a decode batch (one
+    token a row: ``power_retention_recurrent``), and a 2 048-token chunk
+    bucket in the chunked form (``power_retention_chunk``) and as the
+    program picks between the forms at run time. ``phi(q)`` is never an
+    array of the program: nothing has 8 320 (or 8 256) rows a token."""
+    from vllm_distributed_tpu.ops import power_retention as pr
+    cf = bench_config("brumby-14b")
+    ea = cf["engine_args"]
+    Hq, Hkv, D = (cf["num_attention_heads"], cf["num_key_value_heads"],
+                  cf["head_dim"])
+    S, layers = ea["max_num_seqs"], cf["num_hidden_layers"]
+    assert (Hq, Hkv, D, S, layers) == (40, 8, 128, 16, 6)
+    T = S if form == "decode" else tokens
+    leaves, tree = segment_shapes(T, S)
+
+    def fn(q, k, v, log_g, state, zstate, layer, *seg_leaves):
+        return pr.retention_ragged(
+            q, k, v, log_g, state, zstate, layer,
+            jax.tree.unflatten(tree, seg_leaves),
+            decode_only=form == "decode",
+            prefill_form="auto" if form == "decode" else form,
+            interpret=False)
+
+    f32 = jnp.float32
+    ret, ret_z = pr.state_shapes(layers, S + 1, Hkv, D)
+    assert ret == (6, 17, 8, 65, 128, 128)
+    text = compile_for_chip(
+        fn, sds((T, Hq, D), f32), sds((T, Hkv, D), f32),
+        sds((T, Hkv, D), f32), sds((T, Hkv), f32), sds(ret, f32),
+        sds(ret_z, f32), sds((1, )), *leaves).as_text()
+    assert f"%{pr.KERNEL_RECURRENT}" in text
+    assert (f"%{pr.KERNEL_CHUNK}" in text) == (form in ("chunk", "auto"))
+    for rows in (8320, 8256, 65 * 128 * 5):
+        assert f",{rows}," not in text and f",{rows}]" not in text, rows
+
+
 def segment_shapes(T: int, S: int):
     from vllm_distributed_tpu.ops.mamba import SegmentInfo
     flag = jnp.bool_
@@ -740,6 +783,8 @@ ONE_LAYER = {
     "smallthinker-21b-a3b": {
         "num_hidden_layers": 2, "sliding_window_layout": [0, 1],
         "rope_layout": [0, 1]},
+    # Every layer is a retention layer; two, so that the scan is there.
+    "brumby-14b": {"num_hidden_layers": 2},
 }
 
 
@@ -969,6 +1014,23 @@ def test_one_layer_forward_compiles(abstract_runner, name, t_bucket):
         assert text.count("%conv_rows") >= 4
         assert text.count("ragged-dot") >= 3
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+    if name == "brumby-14b":
+        # Both kernels at either bucket (a step that may hold a segment
+        # of two tokens has the chunked form); the state rows (2 layers
+        # x 17 rows x 8 K/V heads of 65 tiles of 128 x 128) are an
+        # argument that nothing makes again, the layers one loop.
+        from vllm_distributed_tpu.ops import power_retention
+        text = compiled.as_text()
+        assert f"%{power_retention.KERNEL_RECURRENT}" in text
+        assert f"%{power_retention.KERNEL_CHUNK}" in text
+        assert "f32[2,17,8,65,128,128]" in text.split("\n", 1)[0]
+        assert slab_copies(compiled, "f32[2,17,8,65,128,128]") == []
+        # (XLA may take the normaliser, 10 MB, to the fast memory and
+        # back, its own prefetch: no re-layout, which 65 rows a K/V head
+        # in place of 72 brought, a copy either side of every call.)
+        assert set(slab_copies(compiled, "f32[2,17,8,72,128]")) <= {
+            "custom-call", "copy-start", "copy-done"}
+        assert text.count(" while(") == 1
     # The convolution's carried inputs are replaced in place too: no
     # copy of a stack, no layer of one as a temporary.
     for stack, layer in {

@@ -102,3 +102,41 @@ def test_snapshot_pool_is_cut_only_where_a_cut_buys_pages(
             scheduler_config=SimpleNamespace(max_num_seqs=seqs)))
     TPUWorker._size_state_pool(fake, room, page)
     assert cache.num_state_slots == want, name
+
+
+@pytest.mark.parametrize("name, slot, seqs, room, want", [
+    # brumby-14b at 16 rows: a slot is the row, 6 layers x 8 K/V heads x
+    # (65 x 128 x 128 + 72 x 128) float32; the default asks for 32 of
+    # them, 6.6 GB. What the profile leaves after the rows decides.
+    pytest.param("four fit", 206_241_792, 16, int(0.95 * 2 ** 30), 4,
+                 id="brumby-four"),
+    pytest.param("one fits", 206_241_792, 16, int(0.35 * 2 ** 30), 1,
+                 id="brumby-one"),
+    pytest.param("none fits: one is kept", 206_241_792, 16, 10 << 20, 1,
+                 id="brumby-none"),
+    pytest.param("eleven fit: eight", 206_241_792, 16, int(2.2 * 2 ** 30),
+                 8, id="brumby-eights"),
+    # A small pure-state model (Mamba-130m: rows of 2.6 MB, 256 slots of
+    # them 0.7 GB): room for all, nothing written.
+    pytest.param("room for all", 2_654_208, 128, 4 << 30, None,
+                 id="mamba-roomy"),
+])
+def test_a_model_without_pages_gets_the_slots_that_fit(name, slot, seqs,
+                                                       room, want):
+    """``TPUWorker._size_state_pool`` with ``page_bytes`` 0 (no paged
+    layer: models/brumby.py, models/mamba.py): there is no request's
+    pages to reserve and none to take room from, so the pool is cut to
+    the slots that fit, in eights while eight fit and one by one under
+    that, never under one; where pages exist the floor of eight stands
+    (the cases above: Granite's 8, a 64 MiB row's 16)."""
+    from types import SimpleNamespace
+
+    from vllm_distributed_tpu.worker.worker import TPUWorker
+    cache = SimpleNamespace(num_state_slots=None)
+    fake = SimpleNamespace(
+        model_runner=SimpleNamespace(state_pool_slot_bytes=lambda: slot),
+        config=SimpleNamespace(
+            cache_config=cache, max_pages_per_req=2048,
+            scheduler_config=SimpleNamespace(max_num_seqs=seqs)))
+    TPUWorker._size_state_pool(fake, room, 0)
+    assert cache.num_state_slots == want, name

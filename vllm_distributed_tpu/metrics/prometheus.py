@@ -190,6 +190,13 @@ HISTOGRAMS = {
         "of a step whose chunks fit the program's static count; 0 on a "
         "decode step, on the token-by-token fallback and for a model "
         "without such layers)"),
+    "step_retention_chunk_tokens": (
+        "vdt:step_retention_chunk_tokens",
+        "Per step, tokens that the power-retention layers took in the "
+        "chunked form (ops/power_retention.py power_retention_chunk: the "
+        "segments longer than one token of a step whose chunks fit the "
+        "program's static count; 0 on a decode step, on the "
+        "token-by-token fallback and for a model without such layers)"),
     "step_host_transfers": (
         "vdt:step_host_transfers",
         "Per step, host arrays the model runner's _prepare_inputs and "

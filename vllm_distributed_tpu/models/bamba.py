@@ -36,6 +36,8 @@ from vllm_distributed_tpu.ops.mamba import build_segment_info
 class BambaForCausalLM(JambaForCausalLM):
     """Hybrid Mamba-2 / partial-rotary-attention stack."""
 
+    chunk_form_tokens = Mamba2ForCausalLM.chunk_form_tokens
+
     @classmethod
     def configure_arch(cls, arch, hf) -> None:
         arch.stateful = True

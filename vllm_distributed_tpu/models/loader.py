@@ -225,6 +225,12 @@ def get_model(config: EngineConfig, mesh,
                 "tensor parallelism over gated delta-rule (KDA) layers "
                 "is not wired (the state kernels of ops/kda.py run "
                 "unsharded); set tensor_parallel_size=1")
+        if (getattr(arch, "power_retention", False)
+                and config.parallel_config.tensor_parallel_size > 1):
+            raise ValueError(
+                "tensor parallelism over power-retention layers is not "
+                "wired (the state kernels of ops/power_retention.py run "
+                "unsharded); set tensor_parallel_size=1")
         if (getattr(arch, "cca", False)
                 and config.parallel_config.tensor_parallel_size > 1):
             raise ValueError(

@@ -43,7 +43,7 @@ from vllm_distributed_tpu.ops.conv1d import (causal_conv1d_rows, rows_shape,
 from vllm_distributed_tpu.ops.mamba import (build_segment_info,
                                             causal_conv1d_ragged,
                                             selective_scan_ragged)
-from vllm_distributed_tpu.ops.ssd import ssd_ragged
+from vllm_distributed_tpu.ops.ssd import chunk_form_tokens, ssd_ragged
 
 
 def _softplus(x: jax.Array) -> jax.Array:
@@ -460,6 +460,14 @@ class Mamba2ForCausalLM(MambaForCausalLM):
         src.tie_word_embeddings = getattr(hf, "tie_word_embeddings",
                                           False)
         return src
+
+    def chunk_form_tokens(self, q_lens, num_tokens: int) -> dict:
+        """Tokens of a step (``q_lens`` its segments' lengths, in a
+        program of ``num_tokens``) that the layers' state kernel takes in
+        the chunked form, under the kernel family's name: what the
+        runner's ``vdt:step_ssd_chunk_tokens`` counts."""
+        return {"ssd": chunk_form_tokens(q_lens, num_tokens,
+                                         self.cfg.ssd_chunk_size)}
 
     @classmethod
     def configure_arch(cls, arch, hf) -> None:

@@ -68,6 +68,7 @@ from vllm_distributed_tpu.models.bart import BartForConditionalGeneration
 from vllm_distributed_tpu.models.whisper import \
     WhisperForConditionalGeneration
 from vllm_distributed_tpu.models.bailing import BailingMoeV3ForCausalLM
+from vllm_distributed_tpu.models.brumby import BrumbyForCausalLM
 from vllm_distributed_tpu.models.bamba import BambaForCausalLM
 from vllm_distributed_tpu.models.cohere_moe import Cohere2MoeForCausalLM
 from vllm_distributed_tpu.models.granite_hybrid import \
@@ -189,6 +190,10 @@ _REGISTRY: dict[str, type] = {
     # attention over DeepSeek-V3 experts (models/bailing.py; the name is
     # the family's convention, not the source's).
     "BailingMoeV3ForCausalLM": BailingMoeV3ForCausalLM,
+    # Brumby: Qwen3's block with power retention in every layer, a
+    # float32 state a layer and no K/V page (models/brumby.py; the name
+    # is the family's convention, the source gives ``model_type`` only).
+    "BrumbyForCausalLM": BrumbyForCausalLM,
     # GPT lineage: learned positions / parallel blocks / packed QKV
     # (models/families_gpt.py).
     "GPT2LMHeadModel": GPT2LMHeadModel,

@@ -57,38 +57,29 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from vllm_distributed_tpu import envs
-from vllm_distributed_tpu.ops.mamba import SegmentInfo
+from vllm_distributed_tpu.ops import mamba
+from vllm_distributed_tpu.ops.mamba import MAX_CHUNK_SEGMENTS  # noqa: F401
+from vllm_distributed_tpu.ops.mamba import (SLOT_CONT, SLOT_HAS_INIT,
+                                            SLOT_VALID, SegmentInfo)
 
 KERNEL_RECURRENT = "kda_recurrent"
 KERNEL_CHUNK = "kda_chunk"
 CHUNK = 64
 SUB = 16
-# Multi-token segments a step may bring beyond its bucket's own chunks
-# and still run the chunked form (each may add one partial chunk).
-MAX_CHUNK_SEGMENTS = 4
 _HEAD_BLOCK = 8
 # Heads whose chunk matrices share one product: 2 x CHUNK fills a tile.
 _HEADS_AT_ONCE = 2
 _HI = jax.lax.Precision.HIGHEST
 _VMEM_LIMIT = 48 * 1024 * 1024
 
-_VALID, _HAS_INIT, _CONT = 1, 2, 4
-
-
-def _flags(valid, has_init, cont):
-    return (valid.astype(jnp.int32) * _VALID
-            + has_init.astype(jnp.int32) * _HAS_INIT
-            + cont.astype(jnp.int32) * _CONT)
-
-
 def _enter_row(flags, s_ref, so_ref):
     """Both kernels' first lines: a continued run finds its state in the
     output block (same block index as the step before: the buffer
     stays); a new run brings the row's stored state, or zero at a
     sequence's start. Returns whether the slot is live."""
-    valid = (flags & _VALID) != 0
-    has_init = (flags & _HAS_INIT) != 0
-    fresh = valid & ((flags & _CONT) == 0)
+    valid = (flags & SLOT_VALID) != 0
+    has_init = (flags & SLOT_HAS_INIT) != 0
+    fresh = valid & ((flags & SLOT_CONT) == 0)
 
     @pl.when(fresh & has_init)
     def _():
@@ -413,27 +404,18 @@ def _chunk_call(state, layer, rows, flags, windows, q, k, v, g, beta,
 def _decode_part(state, layer, q, k, v, a, beta, seg: SegmentInfo,
                  interpret: bool):
     """One-token segments, one slot a state row, live rows first."""
-    T = q.shape[0]
-    S = seg.q_len_by_row.shape[0] - 1
-    live = seg.q_len_by_row[:S] == 1
-    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
-    live = live[order]
-    tok = jnp.where(live, seg.q_start_by_row[order], 0)
+    rows, flags, tok, live = mamba.decode_slots(seg)
     o, state = _recurrent_call(
-        state, layer, jnp.where(live, order, S),
-        _flags(live, seg.has_init_by_row[order], jnp.zeros_like(live)),
+        state, layer, rows, flags,
         q[tok], k[tok], v[tok], a[tok], beta[tok], interpret)
-    out = jnp.zeros((T, ) + o.shape[1:], o.dtype)
-    return out.at[jnp.where(live, tok, T)].set(o, mode="drop"), state
+    return mamba.scatter_slots(o, tok, live, q.shape[0]), state
 
 
 def _token_part(state, layer, q, k, v, a, beta, seg: SegmentInfo, multi,
                 interpret: bool):
     """Multi-token segments token by token: the recurrent form."""
-    S = seg.q_len_by_row.shape[0] - 1
     return _recurrent_call(
-        state, layer, jnp.where(multi, seg.row, S),
-        _flags(multi, seg.has_init, jnp.logical_not(seg.start)),
+        state, layer, *mamba.token_slots(seg, multi),
         q, k, v, a, beta, interpret)
 
 
@@ -447,16 +429,8 @@ def _chunk_part(state, layer, q, k, v, g, beta, seg: SegmentInfo, multi,
         q, k, v, g, beta = (jnp.pad(x, ((0, CHUNK - T), ) + ((0, 0), )
                                     * (x.ndim - 1))
                             for x in (q, k, v, g, beta))
-    S = seg.q_len_by_row.shape[0] - 1
-    n = jnp.arange(num_chunks, dtype=jnp.int32)
-    holds = ((chunk_base[None, :] <= n[:, None])
-             & (n[:, None] < (chunk_base + chunks)[None, :]))
-    used = holds.any(axis=1)
-    row = jnp.where(used, jnp.argmax(holds, axis=1).astype(jnp.int32), S)
-    done = (n - chunk_base[row]) * CHUNK         # of the segment, before n
-    first = seg.q_start_by_row[row] + done
-    count = jnp.where(used, jnp.minimum(seg.q_len_by_row[row] - done, CHUNK),
-                      0)
+    used, row, done, first, count = mamba.chunk_windows(
+        seg, chunk_base, chunks, num_chunks, CHUNK)
     start = jnp.clip(first, 0, q.shape[0] - CHUNK)
     lo = jnp.where(used, first - start, 0)
     at = jnp.arange(CHUNK, dtype=jnp.int32)[None, :]
@@ -464,11 +438,11 @@ def _chunk_part(state, layer, q, k, v, g, beta, seg: SegmentInfo, multi,
                       )[:, :, None], beta[start[:, None] + at], 0.0)
     o, state = _chunk_call(
         state, layer, row,
-        _flags(used, seg.has_init_by_row[row], done > 0),
+        mamba.slot_flags(used, seg.has_init_by_row[row], done > 0),
         jnp.stack([start, lo, lo + count]), q, k, v, g,
         beta.reshape(num_chunks * CHUNK, -1), interpret)
     # Token t's output row: its chunk's window, lo rows in.
-    chunk = jnp.where(multi, chunk_base[seg.row] + seg.off // CHUNK, 0)
+    chunk = mamba.chunk_of_token(seg, multi, chunk_base, CHUNK)
     return jnp.where(multi[:, None, None],
                      o[chunk * CHUNK + lo[chunk] + seg.off % CHUNK],
                      0.0), state
@@ -500,16 +474,8 @@ def kda_ragged(q, k, v, g, beta, state, layer, seg: SegmentInfo, *,
     if decode_only:
         return o, state
 
-    T = q.shape[0]
-    S = seg.q_len_by_row.shape[0] - 1
-    q_len = seg.q_len_by_row.at[S].set(0)
-    row_multi = q_len > 1
-    multi = live & row_multi[seg.row]
-    chunks = jnp.where(row_multi, -(-q_len // CHUNK), 0)
-    # Chunks of the segments that start earlier in the flat batch.
-    earlier = seg.q_start_by_row[None, :] < seg.q_start_by_row[:, None]
-    chunk_base = jnp.sum(jnp.where(earlier, chunks[None, :], 0), axis=1)
-    num_chunks = -(-T // CHUNK) + MAX_CHUNK_SEGMENTS
+    multi, chunks, chunk_base = mamba.multi_token_segments(seg, CHUNK)
+    num_chunks = mamba.num_chunks_for(q.shape[0], CHUNK)
 
     def none(state):
         return jnp.zeros_like(o), state
@@ -522,14 +488,6 @@ def kda_ragged(q, k, v, g, beta, state, layer, seg: SegmentInfo, *,
         return _token_part(state, layer, q, k, v, a, beta, seg, multi,
                            interpret)
 
-    if prefill_form == "chunk":
-        o_multi, state = chunked(state)
-    elif prefill_form == "recurrent":
-        o_multi, state = by_token(state)
-    else:
-        total = chunks.sum()
-        which = jnp.where(total == 0, 0,
-                          jnp.where(total <= num_chunks, 1, 2))
-        o_multi, state = jax.lax.switch(which, (none, chunked, by_token),
-                                        state)
+    o_multi, state = mamba.by_form(prefill_form, chunks, num_chunks, none,
+                                   chunked, by_token, state)
     return o + o_multi, state

@@ -126,6 +126,124 @@ def build_segment_info(batch, num_state_rows: int) -> SegmentInfo:
                        & (chunk_pos0 > 0))
 
 
+# ---------------------------------------------------------------------
+# A step's segments by kind, for the state kernels that run one-token
+# segments slot by slot and longer ones chunk by chunk (ops/kda.py,
+# ops/power_retention.py): the slots, the chunks and the choice of form.
+# ---------------------------------------------------------------------
+# What a kernel is told of a slot or chunk: live; the row's stored state
+# enters; the state of the grid step before stays (a continued run).
+SLOT_VALID, SLOT_HAS_INIT, SLOT_CONT = 1, 2, 4
+# Multi-token segments a step may bring beyond its bucket's own chunks
+# and still run the chunked form (each may add one partial chunk).
+MAX_CHUNK_SEGMENTS = 4
+
+
+def slot_flags(valid, has_init, cont):
+    return (valid.astype(jnp.int32) * SLOT_VALID
+            + has_init.astype(jnp.int32) * SLOT_HAS_INIT
+            + cont.astype(jnp.int32) * SLOT_CONT)
+
+
+def num_chunks_for(num_tokens: int, chunk: int) -> int:
+    """The chunked form's static chunk count in a program of
+    ``num_tokens``."""
+    return -(-num_tokens // chunk) + MAX_CHUNK_SEGMENTS
+
+
+def chunk_form_tokens(q_lens, num_tokens: int, chunk: int) -> int:
+    """Tokens of a step (``q_lens`` its segments' lengths as a numpy
+    array, in a program of ``num_tokens``) that take the chunked form:
+    ``by_form``'s rule, for the runner's counter."""
+    multi = q_lens[q_lens > 1]
+    if int((-(-multi // chunk)).sum()) > num_chunks_for(num_tokens, chunk):
+        return 0
+    return int(multi.sum())
+
+
+def decode_slots(seg: SegmentInfo):
+    """One-token segments, one slot a state row, live rows first:
+    (state row, flags, flat token, live) a slot."""
+    S = seg.q_len_by_row.shape[0] - 1
+    live = seg.q_len_by_row[:S] == 1
+    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
+    live = live[order]
+    tok = jnp.where(live, seg.q_start_by_row[order], 0)
+    return (jnp.where(live, order, S),
+            slot_flags(live, seg.has_init_by_row[order],
+                       jnp.zeros_like(live)), tok, live)
+
+
+def scatter_slots(o, tok, live, num_tokens: int):
+    """The slots' outputs at their tokens of the flat batch, zero
+    elsewhere."""
+    out = jnp.zeros((num_tokens, ) + o.shape[1:], o.dtype)
+    return out.at[jnp.where(live, tok, num_tokens)].set(o, mode="drop")
+
+
+def token_slots(seg: SegmentInfo, multi):
+    """Multi-token segments token by token: (state row, flags) a token
+    of the flat batch."""
+    S = seg.q_len_by_row.shape[0] - 1
+    return (jnp.where(multi, seg.row, S),
+            slot_flags(multi, seg.has_init, jnp.logical_not(seg.start)))
+
+
+def multi_token_segments(seg: SegmentInfo, chunk: int):
+    """(``multi`` ``[T]``: the token is of a segment longer than one;
+    ``chunks`` ``[S + 1]``: a row's chunks of ``chunk`` tokens, each
+    segment starting a chunk of its own; ``chunk_base`` ``[S + 1]``: the
+    chunks of the segments that start earlier in the flat batch)."""
+    S = seg.q_len_by_row.shape[0] - 1
+    q_len = seg.q_len_by_row.at[S].set(0)
+    row_multi = q_len > 1
+    multi = seg.valid & row_multi[seg.row]
+    chunks = jnp.where(row_multi, -(-q_len // chunk), 0)
+    earlier = seg.q_start_by_row[None, :] < seg.q_start_by_row[:, None]
+    chunk_base = jnp.sum(jnp.where(earlier, chunks[None, :], 0), axis=1)
+    return multi, chunks, chunk_base
+
+
+def chunk_windows(seg: SegmentInfo, chunk_base, chunks, num_chunks: int,
+                  chunk: int):
+    """The step's ``num_chunks`` chunks in order: (``used``; the state
+    ``row``, S where unused; ``done``, the segment's tokens before the
+    chunk; ``first``, its first token's flat index; ``count``, its
+    tokens)."""
+    S = seg.q_len_by_row.shape[0] - 1
+    n = jnp.arange(num_chunks, dtype=jnp.int32)
+    holds = ((chunk_base[None, :] <= n[:, None])
+             & (n[:, None] < (chunk_base + chunks)[None, :]))
+    used = holds.any(axis=1)
+    row = jnp.where(used, jnp.argmax(holds, axis=1).astype(jnp.int32), S)
+    done = (n - chunk_base[row]) * chunk         # of the segment, before n
+    first = seg.q_start_by_row[row] + done
+    count = jnp.where(used, jnp.minimum(seg.q_len_by_row[row] - done, chunk),
+                      0)
+    return used, row, done, first, count
+
+
+def chunk_of_token(seg: SegmentInfo, multi, chunk_base, chunk: int):
+    """The chunk that holds each token of a multi-token segment (0
+    elsewhere); the token stands ``seg.off % chunk`` into it."""
+    return jnp.where(multi, chunk_base[seg.row] + seg.off // chunk, 0)
+
+
+def by_form(prefill_form: str, chunks, num_chunks: int, none, chunked,
+            by_token, *carry):
+    """Run the multi-token segments' form on ``carry``: nothing where
+    the step has none, ``chunked`` where their chunks fit the static
+    count, else ``by_token``; ``prefill_form`` ``"chunk"`` /
+    ``"recurrent"`` pins it (tests)."""
+    if prefill_form == "chunk":
+        return chunked(*carry)
+    if prefill_form == "recurrent":
+        return by_token(*carry)
+    total = chunks.sum()
+    which = jnp.where(total == 0, 0, jnp.where(total <= num_chunks, 1, 2))
+    return jax.lax.switch(which, (none, chunked, by_token), *carry)
+
+
 def _bshape(flag: jax.Array, like: jax.Array) -> jax.Array:
     """Reshape a [T] flag for broadcasting against [T, ...]."""
     return flag.reshape(flag.shape + (1, ) * (like.ndim - 1))

@@ -56,14 +56,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from vllm_distributed_tpu import envs
-from vllm_distributed_tpu.ops.kda import _enter_row, _flags
-from vllm_distributed_tpu.ops.mamba import SegmentInfo
+from vllm_distributed_tpu.ops import mamba
+from vllm_distributed_tpu.ops.kda import _enter_row
+from vllm_distributed_tpu.ops.mamba import MAX_CHUNK_SEGMENTS  # noqa: F401
+from vllm_distributed_tpu.ops.mamba import SegmentInfo, slot_flags
 
 KERNEL_RECURRENT = "ssd_recurrent"
 KERNEL_CHUNK = "ssd_chunk"
-# Multi-token segments a step may bring beyond its bucket's own chunks
-# and still run the chunked form (each may add one partial chunk).
-MAX_CHUNK_SEGMENTS = 4
 # Lanes of the state a grid step of the chunk kernel holds, and lanes a
 # pass of the recurrent kernel's body works on.
 _CHUNK_LANES = 1024
@@ -80,8 +79,8 @@ def chunk_size_for(num_tokens: int, chunk_size: int) -> int:
 
 def num_chunks_for(num_tokens: int, chunk_size: int) -> int:
     """The static chunk count of a ``num_tokens`` program."""
-    q = chunk_size_for(num_tokens, chunk_size)
-    return -(-num_tokens // q) + MAX_CHUNK_SEGMENTS
+    return mamba.num_chunks_for(num_tokens,
+                                chunk_size_for(num_tokens, chunk_size))
 
 
 def chunk_form_tokens(q_lens: np.ndarray, num_tokens: int,
@@ -89,11 +88,8 @@ def chunk_form_tokens(q_lens: np.ndarray, num_tokens: int,
     """Tokens of a step (``q_lens`` its segments' lengths, in a program
     of ``num_tokens``) that take the chunked form: ``ssd_ragged``'s rule,
     for the runner's counter."""
-    multi = q_lens[q_lens > 1]
-    q = chunk_size_for(num_tokens, chunk_size)
-    if int((-(-multi // q)).sum()) > num_chunks_for(num_tokens, chunk_size):
-        return 0
-    return int(multi.sum())
+    return mamba.chunk_form_tokens(q_lens, num_tokens,
+                                   chunk_size_for(num_tokens, chunk_size))
 
 
 def _lane_block(lanes: int, want: int) -> int:
@@ -296,20 +292,17 @@ def _decode_part(state, layer, x, dt_a, dt, b, c, seg: SegmentInfo,
     a, dtx = _token_rows(x[tok], dt_a[tok], dt[tok], P)
     y, state = _recurrent_call(
         state, layer, jnp.where(live, order, S),
-        _flags(live, seg.has_init_by_row[order], jnp.zeros_like(live)),
+        slot_flags(live, seg.has_init_by_row[order], jnp.zeros_like(live)),
         a, dtx, b[tok], c[tok], interpret)
-    out = jnp.zeros((T, y.shape[1]), y.dtype)
-    return out.at[jnp.where(live, tok, T)].set(y, mode="drop"), state
+    return mamba.scatter_slots(y, tok, live, T), state
 
 
 def _token_part(state, layer, x, dt_a, dt, b, c, seg: SegmentInfo, multi,
                 interpret: bool):
     """Multi-token segments token by token: the recurrent form."""
-    S = seg.q_len_by_row.shape[0] - 1
     a, dtx = _token_rows(x, dt_a, dt, x.shape[2])
     return _recurrent_call(
-        state, layer, jnp.where(multi, seg.row, S),
-        _flags(multi, seg.has_init, jnp.logical_not(seg.start)),
+        state, layer, *mamba.token_slots(seg, multi),
         a, dtx, b, c, interpret)
 
 
@@ -336,7 +329,7 @@ def _chunk_part(state, layer, x, dt_a, dt, b, c, seg: SegmentInfo, multi,
     first_tok = jnp.maximum(head, 0)
     y_state, state = _chunk_call(
         state, layer, jnp.where(used, seg.row[first_tok], S),
-        _flags(used, seg.has_init[first_tok], seg.off[first_tok] > 0),
+        slot_flags(used, seg.has_init[first_tok], seg.off[first_tok] > 0),
         local, interpret)
     y = (local["y"] + y_state).reshape(Pn, -1)
     return jnp.where(multi[:, None], y[jnp.minimum(pos, Pn - 1)], 0.0), state
@@ -368,16 +361,9 @@ def ssd_ragged(x, dt, A, B, C, D, state, layer, seg: SegmentInfo, *,
     y, state = _decode_part(state, layer, x32, dt_a, dt, b32, c32, seg,
                             interpret)
     if not decode_only:
-        S = seg.q_len_by_row.shape[0] - 1
         Q = chunk_size_for(T, chunk_size)
         num_chunks = num_chunks_for(T, chunk_size)
-        q_len = seg.q_len_by_row.at[S].set(0)
-        row_multi = q_len > 1
-        multi = seg.valid & row_multi[seg.row]
-        chunks = jnp.where(row_multi, -(-q_len // Q), 0)
-        # Chunks of the segments that start earlier in the flat batch.
-        earlier = seg.q_start_by_row[None, :] < seg.q_start_by_row[:, None]
-        chunk_base = jnp.sum(jnp.where(earlier, chunks[None, :], 0), axis=1)
+        multi, chunks, chunk_base = mamba.multi_token_segments(seg, Q)
 
         def none(state):
             return jnp.zeros_like(y), state
@@ -391,16 +377,8 @@ def ssd_ragged(x, dt, A, B, C, D, state, layer, seg: SegmentInfo, *,
             return _token_part(state, layer, x32, dt_a, dt, b32, c32, seg,
                                multi, interpret)
 
-        if prefill_form == "chunk":
-            y_multi, state = chunked(state)
-        elif prefill_form == "recurrent":
-            y_multi, state = by_token(state)
-        else:
-            total = chunks.sum()
-            which = jnp.where(total == 0, 0,
-                              jnp.where(total <= num_chunks, 1, 2))
-            y_multi, state = jax.lax.switch(
-                which, (none, chunked, by_token), state)
+        y_multi, state = mamba.by_form(prefill_form, chunks, num_chunks,
+                                       none, chunked, by_token, state)
         y = y + y_multi
     y = y.reshape(T, Hm, P) + D.astype(f32)[None, :, None] * x32
     return y, state

@@ -192,6 +192,7 @@ class TPUModelRunner:
         # decode step and for a model without a latent cache.
         self.latent_prefill_tokens_hist = Histogram(STEP_TOKEN_BUCKETS)
         self.ssd_chunk_tokens_hist = Histogram(STEP_TOKEN_BUCKETS)
+        self.retention_chunk_tokens_hist = Histogram(STEP_TOKEN_BUCKETS)
         # Under a window cache group (_fill_window_group): the tokens ONE
         # window layer reads a step, and the query x key pairs a step
         # inside the masks of one window layer and of one full layer.
@@ -991,15 +992,13 @@ class TPUModelRunner:
         from vllm_distributed_tpu.ops.mla import takes_multi_head_form
         return int(q_lens[takes_multi_head_form(q_lens)].sum())
 
-    def _ssd_chunk_tokens(self, q_lens: np.ndarray, T: int) -> int:
-        """Tokens of a step that the Mamba-2 layers take in the chunked
-        form (``ops/ssd.chunk_form_tokens``: ``ssd_ragged``'s own rule
-        for a program of ``T`` tokens); 0 for a model without them."""
-        chunk = getattr(self.model.cfg, "ssd_chunk_size", 0)
-        if not chunk:
-            return 0
-        from vllm_distributed_tpu.ops.ssd import chunk_form_tokens
-        return chunk_form_tokens(q_lens, T, chunk)
+    def _chunk_form_tokens(self, q_lens: np.ndarray, T: int) -> dict:
+        """Tokens of a step that the model's state kernels take in the
+        chunked form, by kernel family (the model's own
+        ``chunk_form_tokens``: its op's rule for a program of ``T``
+        tokens); nothing for a model without such layers."""
+        hook = getattr(self.model, "chunk_form_tokens", None)
+        return hook(q_lens, T) if hook is not None else {}
 
     def _tile_params(self) -> tuple[int, int]:
         """The fixed (prefill tile rows, decode group width) of the
@@ -1337,8 +1336,10 @@ class TPUModelRunner:
         self.kv_tokens_read_hist.observe(int(seq_info[:num_runs, 2].sum()))
         self.latent_prefill_tokens_hist.observe(
             self._latent_prefill_tokens(seq_info[:num_runs, 1], max_q))
-        self.ssd_chunk_tokens_hist.observe(
-            self._ssd_chunk_tokens(seq_info[:num_runs, 1], T))
+        chunked = self._chunk_form_tokens(seq_info[:num_runs, 1], T)
+        self.ssd_chunk_tokens_hist.observe(chunked.get("ssd", 0))
+        self.retention_chunk_tokens_hist.observe(
+            chunked.get("retention", 0))
         if self._window_group:
             self._fill_window_group(f, t, num_runs, n_kv_runs)
 
@@ -2741,6 +2742,8 @@ class TPUModelRunner:
             "step_latent_prefill_tokens":
             self.latent_prefill_tokens_hist.to_dict(),
             "step_ssd_chunk_tokens": self.ssd_chunk_tokens_hist.to_dict(),
+            "step_retention_chunk_tokens":
+            self.retention_chunk_tokens_hist.to_dict(),
             "step_host_transfers": self.host_transfers_hist.to_dict(),
             "num_recompiles": self.num_recompiles,
             # Kernel-dispatch + lattice observability (vdt:attn_kernel_

@@ -240,6 +240,11 @@ class TPUWorker:
         less than the pages of ONE full-length request is kept whole
         whatever fits: cutting it buys no request (rows of 88 KB under
         pages of 16 KB a token, models/zaya.py: 128 slots are 11 MB).
+        A model WITHOUT pages (models/brumby.py, models/mamba.py) has
+        no request to reserve for and none to take room from: its pool
+        is cut to the slots that fit, in eights while eight fit and
+        one by one under that, down to one (a slot of 206 MB: eight
+        would be 1.6 GB that the profile did not leave).
         Written to ``cache_config.num_state_slots``, where the
         scheduler's manager and the runner's pool both read it."""
         from vllm_distributed_tpu import envs
@@ -258,8 +263,16 @@ class TPUWorker:
         if fit < want:
             # A dimension of the snapshot copies, and what the pages
             # get depends on it: coarse, like the page count.
-            fit -= fit % _SLOT_QUANTUM
-            slots = int(max(fit, min(want, _SLOT_QUANTUM)))
+            if page_bytes:
+                fit -= fit % _SLOT_QUANTUM
+                slots = int(max(fit, min(want, _SLOT_QUANTUM)))
+            else:
+                # No pages to take the room from: a slot that does not
+                # fit is not there. One is kept, as the scheduler's
+                # manager and the runner's copies are built for a pool.
+                if fit >= _SLOT_QUANTUM:
+                    fit -= fit % _SLOT_QUANTUM
+                slots = int(max(fit, 1))
             self.config.cache_config.num_state_slots = slots
             logger.info(
                 "state-snapshot pool cut to %d slots of %.1f MiB (default "
