@@ -1,5 +1,5 @@
 """The step's phases and counters (ISSUE 25), counts and structure only:
-phases that partition the synchronous step, the period they sum to, the
+phases that partition the synchronous and the pipelined step, the period they sum to, the
 host gap and the host stall; the scheduler's queue wait and step tokens;
 the runner's K/V tokens read and its one closed form of a step's attention
 pairs."""
@@ -542,6 +542,73 @@ def test_a_period_has_the_kind_of_the_step_that_ended(clocks):
         == ph.period.total
     assert got["mixed/device_wait"][1] + got["decode/device_wait"][1] \
         == ph.hists["device_wait"].total
+
+
+def _pipelined_step(ph: StepPhases, clocks: _Clocks, costs: dict,
+                    launches, retires) -> None:
+    """EngineCore._step_pipelined's order: the first five phases for the
+    batch it launches, the last three for the one it retires (None:
+    pipeline fill / drain). A batch is its kind."""
+    with ph.begin_step():
+        if launches is not None:
+            for p in PARTITION[1:5]:
+                with ph.span(f"vdt.{p}", p):
+                    clocks.advance(*costs[p])
+                if p == "launch":
+                    ph.launch_issued()
+        if retires is None:
+            ph.kind = launches
+        else:
+            ph.kind = retires
+            ph.forward_queued = launches is not None
+            for p in PARTITION[5:]:
+                with ph.span(f"vdt.{p}", p):
+                    clocks.advance(*costs[p])
+    ph.end_step(more_work=True)
+    clocks.advance(*costs["loop"])
+
+
+@pytest.mark.parametrize("batches", [
+    ["mixed", "decode", "decode", "mixed", "decode", "decode"],
+    ["decode"] * 4,
+    ["mixed", "mixed", "decode"],
+], ids=["chunks-between-decodes", "decodes", "two-chunks"])
+def test_a_pipelined_step_has_one_period_and_the_kind_it_retires(clocks,
+                                                                 batches):
+    """K forwards through the depth-2 pipeline: K + 1 steps (fill ..
+    drain), K periods, each of the kind of the batch the step RETIRED
+    (the fill's: of the batch it launched), the phases partitioning wall
+    and CPU time as on the synchronous path, a zero gap for every fetch
+    that had a forward queued behind it."""
+    ph = StepPhases()
+    costs = {"mixed": dict(_WAITS, device_wait=(64, 2)), "decode": _WAITS}
+    K = len(batches)
+    for launches, retires in zip(batches + [None], [None] + batches):
+        _pipelined_step(ph, clocks, costs[retires or launches], launches,
+                        retires)
+    assert ph.step == K + 1
+    ph.begin_step()                     # closes the drain's period
+    assert ph.period.count == ph.step_cpu.count == K + 1
+    wall = sum(ph.hists[p].total for p in PARTITION)
+    assert wall == ph.period.total
+    assert sum(ph.cpu_hists[p].total for p in PARTITION) \
+        == ph.step_cpu.total
+    assert all(ph.hists[p].count == K for p in PARTITION[1:])
+    got = {k: h.count for k, h in ph.kind_hists.items()}
+    # The fill's period bears the first batch's kind a second time.
+    want = {k: batches.count(k) + (batches[0] == k)
+            for k in ("mixed", "decode")}
+    assert got["mixed/period"] == want["mixed"]
+    assert got["decode/period"] == want["decode"]
+    assert got["mixed/device_wait"] == batches.count("mixed")
+    assert got["decode/device_wait"] == batches.count("decode")
+    assert ph.kind_hists["mixed/device_wait"].total \
+        == batches.count("mixed") * 64 / 1024
+    assert got["mixed/period"] + got["decode/period"] == ph.period.count
+    # Every fetch but the drain's had its successor queued: no gap; the
+    # stall is counted from fetch to fetch as ever.
+    assert (ph.host_gap.count, ph.host_gap.total) == (K - 1, 0.0)
+    assert ph.host_stall.count == K - 1
 
 
 def test_an_idle_wait_is_in_no_cpu_account(clocks):

@@ -349,20 +349,29 @@ class SchedulerConfig:
     # v1/core/encoder_cache_manager.py); image requests past the budget
     # wait.
     encoder_cache_budget: int = 8192
-    # Async scheduling (reference: the V1 --async-scheduling path):
-    # overlap host scheduling/input-prep with device execution by
-    # keeping a depth-2 batch pipeline in flight on the non-PP path.
-    # The scheduler grants step N+1 (advancing each running request by
-    # one speculative position) while step N executes; the runner
-    # chains decode input tokens device-to-device so the host never
-    # round-trips sampled tokens on the hot path. Stop/EOS detection
-    # lags one step (the over-issued position's work is discarded).
+    # The pipelined step (async scheduling; reference: the V1
+    # --async-scheduling path): ONE engine-core step schedules, prepares
+    # and launches step N+1 and then fetches and reconciles step N, so
+    # the next forward is in the device's queue before the host blocks
+    # (engine/core.py _step_pipelined, a batch queue of depth 2 on the
+    # non-PP path). The scheduler grants N+1 by advancing each running
+    # decode request one speculative position while N executes; the
+    # forward reads the input tokens the host does not know yet from a
+    # device-resident mirror that N's sampler wrote, so no sampled token
+    # round-trips through the host on the hot path. Stop/EOS detection
+    # lags one step: the over-issued position's work is discarded and
+    # the stream still ends on the stop token exactly.
+    # WHO RUNS IT: `vdt serve` (run_server) by default; a library
+    # construction (LLM, LLMEngine, an EngineCore stepped by hand) keeps
+    # the synchronous step unless EngineArgs.async_scheduling says
+    # otherwise (None there means "the entry decides").
     # Auto-disabled (see EngineConfig.__post_init__) with pipeline
     # parallelism, speculative decoding, multi-step bursts, KV-transfer
     # connectors, token parallelism, and multi-host execution; requests
     # needing host-synchronous sampling state (structured output,
     # prompt_logprobs, pooling, penalties/min-tokens) individually fall
-    # back to synchronous one-step-lag scheduling.
+    # back to the synchronous step, one batch at a time
+    # (Scheduler._needs_sync_step).
     async_scheduling: bool = False
 
     def __post_init__(self) -> None:

@@ -599,7 +599,8 @@ class Scheduler:
                      < request.sampling_params.max_tokens))
 
     def _async_schedulable(self, request: Request) -> bool:
-        if self._needs_sync_step(request):
+        if (self._needs_sync_step(request)
+                or request.request_id in self._deferred_finishes):
             return request.request_id not in self.in_flight_req_ids
         if request.num_tokens_with_spec > request.num_computed_tokens:
             return True  # known-token backlog (prefill chunks)
@@ -682,11 +683,14 @@ class Scheduler:
             request = self.running[req_index]
             if (request.request_id in self.in_flight_req_ids
                     and (not self.async_scheduling
-                         or self._needs_sync_step(request))):
+                         or self._needs_sync_step(request)
+                         or request.request_id in self._deferred_finishes)):
                 # Another dispatched batch owns this request's next
                 # token (PP batch queue, or an async request that needs
                 # host-synchronous sampling state); it becomes
-                # schedulable when that batch retires.
+                # schedulable when that batch retires. A request whose
+                # abort waits for that batch is granted nothing more:
+                # run ahead again and it would never leave the device.
                 req_index += 1
                 continue
             num_new_tokens = (request.num_tokens_with_spec -
@@ -845,8 +849,12 @@ class Scheduler:
         # ---- 2. Waiting requests (new or resumed-from-preemption) ----
         # Don't admit new work in a step where we had to preempt.
         if not preempted:
+            # A request that finished with a later batch still in
+            # flight keeps its row of the worker's batch until that
+            # batch retires: it counts against the rows.
             while (self.waiting and token_budget > 0
-                   and len(self.running) < self.max_num_seqs):
+                   and len(self.running) + len(self._finished_pending_retire)
+                   < self.max_num_seqs):
                 request = (self.waiting[0] if self.qos is None
                            else self._qos_pick_waiting())
 

@@ -19,6 +19,21 @@ from vllm_distributed_tpu.config import (CacheConfig, DeviceConfig,
                                          SpeculativeConfig)
 
 
+# Help text of the switches whose default is not plain on or off.
+_CLI_HELP = {
+    "async_scheduling":
+    "the pipelined engine step: step N+1 is scheduled, prepared and "
+    "launched while the device runs step N (one forward a step, its "
+    "outputs one call later). Default: on under `vdt serve`, off for "
+    "library use. Falls back to the synchronous step with pipeline "
+    "parallelism, speculative decoding, --num-scheduler-steps > 1, a "
+    "KV connector, token parallelism or several hosts, and per request "
+    "for structured output, prompt logprobs, pooling, penalties and "
+    "min_tokens. A stop token is detected one step late and the stream "
+    "still ends on it exactly.",
+}
+
+
 @dataclass
 class EngineArgs:
     model: str = "meta-llama/Meta-Llama-3-8B"
@@ -64,10 +79,15 @@ class EngineArgs:
     scheduling_policy: str = "fcfs"
     num_scheduler_steps: int = 1
     encoder_cache_budget: int = 8192
-    # Overlap host scheduling with device execution (depth-2 in-flight
-    # batch pipeline; auto-off with spec decode / PP / multi-step /
-    # KV connectors — see SchedulerConfig.async_scheduling).
-    async_scheduling: bool = False
+    # The pipelined step: step N+1 scheduled, prepared and launched
+    # while the device runs step N (see SchedulerConfig.async_scheduling
+    # for the exclusions, the per-request fallback and the one-step lag
+    # of stop detection). None: the entry decides — `vdt serve`
+    # (run_server) turns it on, a library construction (LLM, LLMEngine,
+    # EngineCore driven by hand) keeps the synchronous step, whose
+    # step() returns the outputs of the batch it scheduled.
+    # --async-scheduling / --no-async-scheduling override either.
+    async_scheduling: Optional[bool] = None
 
     device: str = "auto"
     load_format: str = "auto"
@@ -147,7 +167,7 @@ class EngineArgs:
                 policy=self.scheduling_policy,
                 num_scheduler_steps=self.num_scheduler_steps,
                 encoder_cache_budget=self.encoder_cache_budget,
-                async_scheduling=self.async_scheduling,
+                async_scheduling=bool(self.async_scheduling),
             ),
             device_config=DeviceConfig(device=self.device),
             load_config=LoadConfig(
@@ -201,7 +221,8 @@ class EngineArgs:
             if ts in ("bool", str(bool)) or "bool" in ts:
                 parser.add_argument(name,
                                     action=argparse.BooleanOptionalAction,
-                                    default=f.default)
+                                    default=f.default,
+                                    help=_CLI_HELP.get(f.name))
             else:
                 typ = str
                 if "int" in ts:

@@ -6,17 +6,22 @@ variants layer transport on top — here the in-process core comes first and
 the ZMQ front-ends reuse it unchanged, mirroring InprocClient).
 
 The batch queue (reference: core.py:242 ``step_with_batch_queue``)
-serves two overlap modes with one loop:
+holds the batches that are on the device, for two overlap modes:
 
-* **Pipeline parallelism** (depth = pipeline_parallel_size): up to one
-  scheduler output per stage is dispatched before blocking on the
-  oldest, so stage p of batch i+1 executes under stage p+1 of batch i;
-  in-flight requests are skipped by the scheduler.
-* **Async scheduling** (non-PP, depth 2; reference: the V1
-  --async-scheduling path): the scheduler grants step N+1 — advancing
-  each running decode request by one speculative position — while step
-  N executes on device; the runner chains the unknown input token
-  device-to-device and ``update_from_output`` reconciles when the
+* **Pipeline parallelism** (depth = pipeline_parallel_size,
+  ``step_with_batch_queue``): up to one scheduler output per stage is
+  dispatched before blocking on the oldest, so stage p of batch i+1
+  executes under stage p+1 of batch i; in-flight requests are skipped
+  by the scheduler. A call dispatches or retires, never both.
+* **The pipelined step** (non-PP, depth 2, ``_step_pipelined``; what
+  ``vdt serve`` runs, ``SchedulerConfig.async_scheduling``; reference:
+  the V1 --async-scheduling path): ONE call schedules, prepares and
+  launches step N+1 and then fetches and reconciles step N, so the
+  forward of N+1 is in the device's queue before the host blocks on N.
+  The scheduler grants N+1 by advancing each running decode request by
+  one speculative position; the runner's forward reads the input token
+  the host does not know yet from the device-resident mirror the
+  sampler of N wrote, and ``update_from_output`` reconciles when the
   sampled tokens land (stop/EOS detection lags one step).
 
 On TPU the overlap itself comes from JAX async dispatch — the runner's
@@ -42,6 +47,12 @@ from vllm_distributed_tpu.request import (EngineCoreRequest, Request,
 from vllm_distributed_tpu.utils import fault_injection
 
 logger = init_logger(__name__)
+
+
+def _kind(scheduler_output) -> str:
+    """StepPhases' kind of a batch: ``mixed`` with a prefill token."""
+    return "mixed" if scheduler_output.num_prefill_tokens else "decode"
+
 
 class EngineCore:
 
@@ -139,8 +150,10 @@ class EngineCore:
         # time of this thread goes, as vdt:step_phase_seconds{phase} and
         # its companions on /metrics and as vdt.* spans in a capture of
         # the profile RPC. Always on. The model runner times its own
-        # part of the step into the same object.
-        self.phases = StepPhases(sync=self.batch_queue is None)
+        # part of the step into the same object. The synchronous and
+        # the pipelined step share one timeline; only pipeline
+        # parallelism's queue of ``pp`` batches has none.
+        self.phases = StepPhases(sync=pp <= 1)
         runner = getattr(getattr(self.executor, "worker", None),
                          "model_runner", None)
         if hasattr(runner, "phases"):
@@ -322,8 +335,12 @@ class EngineCore:
             return []
         ph = self.phases
         with ph.begin_step():
-            outputs = (self._step_sync() if self.batch_queue is None
-                       else self.step_with_batch_queue())
+            if self.batch_queue is None:
+                outputs = self._step_sync()
+            elif self.async_scheduling:
+                outputs = self._step_pipelined()
+            else:
+                outputs = self.step_with_batch_queue()
         ph.end_step(more_work=self.has_unfinished_requests()
                     or self.scheduler.has_kv_transfer_work())
         return outputs
@@ -336,19 +353,106 @@ class EngineCore:
             scheduler_output = self.scheduler.schedule()
         self.last_step_scheduled = \
             scheduler_output.total_num_scheduled_tokens > 0
-        ph.kind = ("mixed" if scheduler_output.num_prefill_tokens
-                   else "decode")
+        ph.kind = _kind(scheduler_output)
         runner_output = self.executor.execute_model(scheduler_output)
         with ph.span("vdt.update_from_output", "update"):
             return self.scheduler.update_from_output(scheduler_output,
                                                      runner_output)
 
+    def _step_pipelined(self) -> list[EngineCoreOutput]:
+        """The depth-2 pipelined step: schedule, prepare and launch step
+        N+1, THEN fetch and reconcile step N, which the call before
+        launched. The forward of N+1 is in the device's queue before the
+        host blocks on N, so the device goes from one forward to the
+        next while the host schedules; the outputs returned are N's.
+
+        One call is one ``vdt_step``, one forward launched and one
+        period, in the phases of the synchronous step and in their
+        order (StepPhases): ``schedule, update_states, prepare_inputs,
+        launch`` for N+1, ``device_wait, finish, update`` for N. The
+        step's ``kind`` is that of the batch it retires.
+
+        The edges: a call that finds nothing in flight only launches
+        (pipeline fill, returns nothing) unless the scheduler could
+        grant nothing behind that batch (requests that need the
+        synchronous step, ``Scheduler._needs_sync_step``; a last
+        position): then it retires it at once, which is the synchronous
+        step. A call that finds nothing to schedule only retires
+        (drain). An empty grant despite schedulable work (pool
+        exhausted, budget edge) still carries finished_req_ids for the
+        worker's row cleanup: it runs through synchronously, which is
+        safe beside a batch in flight ONLY because a zero-token batch
+        does no device work (test_zero_token_dispatch_does_no_device_work),
+        and the call goes on to retire, which frees pages for the next
+        attempt. KV connectors are config-gated off this path."""
+        ph, sched, queue = self.phases, self.scheduler, self.batch_queue
+        launched = None
+        if (len(queue) < self.batch_queue_size
+                and sched.has_schedulable_requests()):
+            with ph.span("vdt.schedule", "schedule"):
+                scheduler_output = sched.schedule()
+            if scheduler_output.total_num_scheduled_tokens > 0:
+                launched = scheduler_output
+                sched.mark_in_flight(scheduler_output.num_scheduled_tokens)
+                self.steps_dispatched += 1
+                if queue:
+                    self.steps_overlapped += 1
+                handle = self.executor.execute_model_async(scheduler_output)
+                queue.appendleft((scheduler_output, handle))
+                self.last_step_scheduled = True
+                self.max_concurrent_batches = max(
+                    self.max_concurrent_batches, len(queue))
+                if self.events.enabled:
+                    self.events.record("", ev.BATCH_DISPATCH, {
+                        "reqs": len(scheduler_output.num_scheduled_tokens),
+                        "tokens":
+                            scheduler_output.total_num_scheduled_tokens,
+                        "depth": len(queue),
+                    })
+            else:
+                sched.update_from_output(
+                    scheduler_output,
+                    self.executor.execute_model(scheduler_output))
+        if not queue:
+            return []
+        if (launched is not None and len(queue) < self.batch_queue_size
+                and sched.has_schedulable_requests()):
+            ph.kind = _kind(launched)
+            return []
+        scheduler_output, handle = queue.pop()
+        if fault_injection.registry.active:
+            self._reconcile_stall_drill()
+        ph.kind = _kind(scheduler_output)
+        ph.forward_queued = bool(queue)
+        runner_output = self.executor.wait_model(handle)
+        if self.events.enabled:
+            self.events.record("", ev.BATCH_RETIRE, {
+                "reqs": len(scheduler_output.num_scheduled_tokens),
+                "depth": len(queue),
+            })
+        sched.unmark_in_flight(scheduler_output.num_scheduled_tokens)
+        with ph.span("vdt.update_from_output", "update"):
+            return sched.update_from_output(scheduler_output,
+                                            runner_output)
+
+    @staticmethod
+    def _reconcile_stall_drill() -> None:
+        """step.reconcile_stall: with delay_s it stalls the host between
+        device completion and reconciliation (the window the pipeline
+        keeps covered); without a delay it kills the core mid-pipeline
+        so the crash-recovery ladder is exercised with batches in
+        flight."""
+        if fault_injection.registry.delay_of("step.reconcile_stall"):
+            fault_injection.maybe_delay("step.reconcile_stall")
+        else:
+            fault_injection.fire_or_raise("step.reconcile_stall")
+
     def step_with_batch_queue(self) -> list[EngineCoreOutput]:
-        """One iteration of the batch queue (PP microbatches or the
-        async depth-2 pipeline; reference: core.py:242): dispatch a
-        fresh batch whenever there is room and schedulable work;
-        otherwise retire the oldest. Each call does at most one of the
-        two, so dispatches outnumber waits until the pipeline fills."""
+        """One iteration of pipeline parallelism's batch queue
+        (reference: core.py:242): dispatch a fresh batch whenever there
+        is room and schedulable work; otherwise retire the oldest. Each
+        call does at most one of the two, so dispatches outnumber waits
+        until the pipeline fills."""
         ph = self.phases
         if (len(self.batch_queue) < self.batch_queue_size
                 and self.scheduler.has_schedulable_requests()):
@@ -407,15 +511,7 @@ class EngineCore:
             return []
         scheduler_output, handle = self.batch_queue.pop()
         if fault_injection.registry.active:
-            # step.reconcile_stall: with delay_s it stalls the host
-            # between device completion and reconciliation (the window
-            # the async pipeline keeps covered); without a delay it
-            # kills the core mid-pipeline so the crash-recovery ladder
-            # is exercised with batches in flight.
-            if fault_injection.registry.delay_of("step.reconcile_stall"):
-                fault_injection.maybe_delay("step.reconcile_stall")
-            else:
-                fault_injection.fire_or_raise("step.reconcile_stall")
+            self._reconcile_stall_drill()
         with ph.span("vdt.wait", "wait") as wait:
             runner_output = self.executor.wait_model(handle)
         self._last_wait_done = wait.end

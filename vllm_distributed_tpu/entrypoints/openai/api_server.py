@@ -9,6 +9,7 @@ engine death — reference: entrypoints/launcher.py).
 
 import asyncio
 import contextlib
+import dataclasses
 import json
 import signal
 import time
@@ -1707,7 +1708,13 @@ async def serve(engine: AsyncLLM, model_name: str, host: str,
 def run_server(engine_args, host: str = "0.0.0.0", port: int = 8000,
                lora_modules: Optional[dict] = None,
                tool_call_parser: Optional[str] = None) -> None:
-    """Blocking entry used by the CLI (reference: api_server.py:1672)."""
+    """Blocking entry used by the CLI (reference: api_server.py:1672).
+    The server runs the engine core's pipelined step unless told
+    otherwise (``EngineArgs.async_scheduling`` None: this entry decides,
+    and ``EngineConfig`` still falls back where the step cannot run)."""
+    if engine_args.async_scheduling is None:
+        engine_args = dataclasses.replace(engine_args,
+                                          async_scheduling=True)
     engine = AsyncLLM.from_engine_args(engine_args)
     asyncio.run(serve(engine, engine_args.model, host, port,
                       lora_modules=lora_modules,

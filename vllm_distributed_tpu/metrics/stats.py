@@ -148,10 +148,11 @@ class span:
 
 
 class _PhaseSpan(span):
-    """A span of a ``StepPhases`` timeline. Inside a synchronous step a
-    phase starts where the timeline's last phase ended, on the wall
-    clock (``mark``) and on the thread's CPU clock (``cpu_mark``), so
-    that the phases leave no hole between them on either."""
+    """A span of a ``StepPhases`` timeline. Inside a step of the one
+    timeline (``sync``: every step but pipeline parallelism's) a phase
+    starts where the timeline's last phase ended, on the wall clock
+    (``mark``) and on the thread's CPU clock (``cpu_mark``), so that the
+    phases leave no hole between them on either."""
 
     __slots__ = ("_phases", "_phase", "_chained", "_cpu_start")
 
@@ -193,34 +194,45 @@ class StepPhases:
     """Where the time of the engine-core thread goes, step by step: one
     object per engine core, shared with its model runner.
 
-    On the synchronous path (``sync``, no batch queue) the phases ``loop,
-    schedule, update_states, prepare_inputs, launch, device_wait, finish,
-    update`` partition the thread's time between two step starts: each
-    starts at ``mark``, the end of the one before it, so they neither
-    overlap nor leave holes, and their sums add up to the sum of
-    ``period``. The thread's CPU time (``time.thread_time()``, read where
-    ``mark`` is) is partitioned the same way: ``cpu_hists`` sum to
-    ``step_cpu``, and a phase's wall time less its CPU time is what the
-    thread waited in it (for the GIL, a core, the runtime). On the
-    batch-queue path every phase times its own block on both clocks, and
-    ``dispatch`` and ``wait`` contain the runner's phases.
+    On the one timeline (``sync``: the synchronous step and the
+    pipelined step, engine/core.py) the phases ``loop, schedule,
+    update_states, prepare_inputs, launch, device_wait, finish, update``
+    partition the thread's time between two step starts: each starts at
+    ``mark``, the end of the one before it, so they neither overlap nor
+    leave holes, and their sums add up to the sum of ``period``. The
+    thread's CPU time (``time.thread_time()``, read where ``mark`` is) is
+    partitioned the same way: ``cpu_hists`` sum to ``step_cpu``, and a
+    phase's wall time less its CPU time is what the thread waited in it
+    (for the GIL, a core, the runtime). A pipelined step runs the same
+    phases in the same order, the first five for the batch it launches
+    and the last three for the batch it retires, the one launched a step
+    earlier: one ``vdt_step`` and one period a forward either way. Under
+    pipeline parallelism (``sync`` false) every phase times its own
+    block on both clocks, ``dispatch`` and ``wait`` contain the runner's
+    phases, and there is no period.
 
     ``host_gap`` estimates the device's idle time per step on the host's
-    clock: from the return of a step's fetch to the launch of the next
-    step's forward. ``host_stall`` is the time this thread wanted to run
-    and did not: the phases' wall time less their CPU time, summed from
-    one fetch's return to the next fetch's start (every phase but
-    ``device_wait``). Near zero, the host time of a step is this thread's
-    own work; several milliseconds, another thread held the GIL, the core
-    was taken, or a launch blocked inside the runtime. Read the CPU
-    accounts by their means: ``time.thread_time()`` may advance in ticks
-    far longer than a phase, so one observation says little.
+    clock, once a fetch: from the fetch's return to the issue of the next
+    forward, or 0 where that forward was issued before the fetch began
+    (``forward_queued``, which the pipelined step sets: the device went
+    from one forward to the next without the host). ``host_stall`` is the
+    time this thread wanted to run and did not: the phases' wall time
+    less their CPU time, summed from one fetch's return to the next
+    fetch's start (every phase but ``device_wait``). Near zero, the host
+    time of a step is this thread's own work; several milliseconds,
+    another thread held the GIL, the core was taken, or a launch blocked
+    inside the runtime. Read the CPU accounts by their means:
+    ``time.thread_time()`` may advance in ticks far longer than a phase,
+    so one observation says little.
 
-    ``kind`` is ``mixed`` for a step whose grant holds a prefill token
-    and ``decode`` otherwise (the engine core sets it once the step is
-    scheduled); ``kind_hists`` split the period and the device wait by
-    it. ``input_queue`` is a request's wait on the transport, from the
-    front end's put to this thread's take."""
+    ``kind`` is ``mixed`` for a step whose batch holds a prefill token
+    and ``decode`` otherwise; ``kind_hists`` split the period and the
+    device wait by it. The batch is the one whose results the step
+    FETCHES: the one it scheduled on the synchronous path, the one it
+    retires on the pipelined path, since that batch's device time is
+    what the period held (a step that only fills the pipeline takes the
+    kind of the batch it launches). ``input_queue`` is a request's wait
+    on the transport, from the front end's put to this thread's take."""
 
     PHASES = ("loop", "schedule", "update_states", "prepare_inputs",
               "launch", "device_wait", "finish", "update", "dispatch",
@@ -244,12 +256,16 @@ class StepPhases:
         self.step = 0      # number of the current (or last) vdt_step
         self.idle = True   # the last step left no work behind
         self.kind = "decode"   # of the current (or last) step
+        # A forward is in the device's queue behind the one whose fetch
+        # comes next (set by the pipelined step before each fetch).
+        self.forward_queued = False
         self.mark = 0.0    # end of the last phase of this busy period
         self.cpu_mark = 0.0    # the thread's CPU time at ``mark``
         self.step_start: Optional[float] = None
         self._cpu_at_step_start = 0.0
         self._fetch_done: Optional[float] = None
         self._cpu_at_fetch_done = 0.0
+        self._gap_open = False   # a fetch returned, no forward issued since
         self._loop_span: Optional[span] = None
 
     def span(self, name: str, phase: Optional[str] = None,
@@ -265,6 +281,7 @@ class StepPhases:
         self.mark = time.perf_counter()
         self.cpu_mark = time.thread_time()
         self.step_start = self._fetch_done = None
+        self._gap_open = False
 
     def begin_step(self) -> StepTraceAnnotation:
         """At the start of a step that has work: close ``loop`` and the
@@ -298,11 +315,12 @@ class StepPhases:
             self._loop_span.__enter__()
 
     def launch_issued(self) -> None:
-        if self.sync and self._fetch_done is not None:
+        if self._gap_open:
+            self._gap_open = False
             self.host_gap.observe(time.perf_counter() - self._fetch_done)
 
     def fetch_begins(self) -> None:
-        """The synchronous step's ``device_wait`` starts at ``mark``:
+        """A step's ``device_wait`` starts at ``mark``:
         what the phases since the last fetch's return waited. Not held
         at zero: where the thread's CPU clock advances in ticks (10 ms
         on a sandboxed host) one step's reading is a tick too high or
@@ -314,9 +332,14 @@ class StepPhases:
                 - (self.cpu_mark - self._cpu_at_fetch_done))
 
     def fetch_done(self, waited: float) -> None:
-        """... and has ended at ``mark``, after ``waited`` seconds."""
+        """... and has ended at ``mark``, after ``waited`` seconds. The
+        device idles from here to the next forward's issue, unless that
+        forward is queued already: no gap then."""
         self._fetch_done = self.mark
         self._cpu_at_fetch_done = self.cpu_mark
+        self._gap_open = not self.forward_queued
+        if self.forward_queued:
+            self.host_gap.observe(0.0)
         self.kind_hists[f"{self.kind}/device_wait"].observe(waited)
 
     def to_dict(self) -> dict:

@@ -29,18 +29,24 @@ class SamplingMetadata:
     seeds: jax.Array
 
 
-def pack_sampling(temperature, top_k, top_p, min_p, seeds) -> np.ndarray:
+def pack_sampling(temperature, top_k, top_p, min_p, seeds,
+                  record_rows=None) -> np.ndarray:
     """The five [R] host arrays as one [5, R] int32 buffer, one transfer
     a step. Floats ride as their bit patterns (bit-exact); the int64
     seeds wrap to the int32 the programs have always received (JAX
-    without x64 made the same cast on the way to the device)."""
-    return np.stack([
+    without x64 made the same cast on the way to the device).
+    ``record_rows`` (the pipelined step) rides as a sixth row: the batch
+    row each sampled token is recorded under (``record_sampled``)."""
+    fields = [
         np.asarray(temperature, np.float32).view(np.int32),
         np.asarray(top_k, np.int32),
         np.asarray(top_p, np.float32).view(np.int32),
         np.asarray(min_p, np.float32).view(np.int32),
         np.asarray(seeds).astype(np.int32),
-    ])
+    ]
+    if record_rows is not None:
+        fields.append(np.asarray(record_rows, np.int32))
+    return np.stack(fields)
 
 
 def unpack_sampling(packed: jax.Array) -> SamplingMetadata:
@@ -52,6 +58,16 @@ def unpack_sampling(packed: jax.Array) -> SamplingMetadata:
     return SamplingMetadata(temperature=f32(packed[0]), top_k=packed[1],
                             top_p=f32(packed[2]), min_p=f32(packed[3]),
                             seeds=packed[4])
+
+
+def record_sampled(last: jax.Array, packed: jax.Array,
+                   tokens: jax.Array) -> jax.Array:
+    """Inside the jitted sampler: the step's sampled tokens scattered
+    into ``last``, the [max_num_reqs] mirror of each batch row's last
+    sample that the next step's forward reads its unknown input tokens
+    from (worker/step_inputs.py). Padding rows carry an out-of-range
+    index and drop."""
+    return last.at[packed[5]].set(tokens, mode="drop")
 
 
 @jax.tree_util.register_dataclass

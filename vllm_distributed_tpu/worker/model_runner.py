@@ -24,6 +24,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from vllm_distributed_tpu.config import EngineConfig
 from vllm_distributed_tpu.core.sched.output import (ModelRunnerOutput,
@@ -41,6 +42,7 @@ from vllm_distributed_tpu.ops.attention import resolve_attention_backend
 from vllm_distributed_tpu.sample.metadata import (ExtendedSamplingMetadata,
                                                   SamplingMetadata,
                                                   pack_sampling,
+                                                  record_sampled,
                                                   unpack_sampling)
 from vllm_distributed_tpu.sample.sampler import (MAX_LOGPROBS, sample_tokens,
                                                  sample_tokens_extended)
@@ -97,25 +99,6 @@ class TPUModelRunner:
         upload the journaled state directly into the request's rows."""
         return cache.at[:, row].set(value)
 
-    @staticmethod
-    @functools.partial(jax.jit, donate_argnums=(0, ))
-    def _chain_record(last, rows, tokens):
-        """Async scheduling: scatter this step's sampled tokens (still
-        on device) into the per-row last-sampled mirror at DISPATCH
-        time; padding rows carry an out-of-range index and drop."""
-        return last.at[rows].set(tokens, mode="drop")
-
-    @staticmethod
-    @functools.partial(jax.jit, donate_argnums=(0, ))
-    def _chain_apply(token_ids, pos, last, rows):
-        """Async scheduling: overwrite the flat input positions whose
-        token the host does not know yet with the previous step's
-        on-device sample — step N+1's input rows read step N's output
-        without a host round-trip (the same device-to-device chaining
-        the multi-step lax.scan burst does within a burst). Padding
-        entries point one past the array and drop."""
-        return token_ids.at[pos].set(last[rows], mode="drop")
-
     def __init__(self, config: EngineConfig, mesh,
                  model=None, params=None) -> None:
         self.config = config
@@ -129,11 +112,13 @@ class TPUModelRunner:
         self.model = model
         self.params = params
         self.kv_caches: Optional[dict] = None
-        # Async scheduling: device-resident [max_num_reqs] mirror of
-        # each row's most recently sampled token, written at dispatch
-        # time (_chain_record) and read by the next dispatch's input
-        # chain (_chain_apply) — the host never round-trips decode
-        # tokens on the hot path.
+        # The pipelined step (async scheduling): device-resident
+        # [max_num_reqs] mirror of each row's most recently sampled
+        # token, written by the step's own sampler program
+        # (record_sampled) and read by the next step's forward as it
+        # unpacks its inputs (StepInputs.unpack) — the host never
+        # round-trips decode tokens on the hot path, and the chain costs
+        # no program, dispatch or transfer of its own.
         self._async_chain = config.scheduler_config.async_scheduling
         self._last_sampled_dev: Optional[jax.Array] = None
         # Device-resident sampling-history mirror (see _hist_rows_device).
@@ -358,8 +343,6 @@ class TPUModelRunner:
                 self._draft_spec, self.model.cfg.dtype,
                 max_num_reqs=self.max_num_reqs)
         if self._eagle_spec is not None:
-            from jax.sharding import NamedSharding
-
             from vllm_distributed_tpu.spec_decode.eagle import EagleDrafter
             self._eagle = EagleDrafter(self._eagle_spec, self.model,
                                        self.max_num_reqs, self.page_size)
@@ -402,7 +385,6 @@ class TPUModelRunner:
         """(make, shapes, shardings) of the stacked caches on ``mesh``
         (default: the whole mesh); ``depth`` overrides the layer count
         for a pipeline stage's slice."""
-        from jax.sharding import NamedSharding
         mesh = self.mesh if mesh is None else mesh
         if depth is None and self._eagle is not None:
             # EAGLE's draft KV layers stack onto the target's cache
@@ -456,8 +438,6 @@ class TPUModelRunner:
     def _init_state_pool(self) -> None:
         if not self._state_cache_active():
             return
-        from jax.sharding import NamedSharding
-
         from vllm_distributed_tpu.core.state_cache import \
             resolve_state_slots
         n_slots = resolve_state_slots(self.config)
@@ -779,19 +759,31 @@ class TPUModelRunner:
 
         # The samplers take the [5, R] packed sampling buffer
         # (sample/metadata.py pack_sampling) and unpack it first.
-        def sample(params, hidden_sel, sampling_packed):
+        # ``last`` (the pipelined step): the mirror of each row's last
+        # sample, donated, returned with this step's tokens recorded
+        # and replicated as it came.
+        replicated = NamedSharding(self.mesh, PartitionSpec())
+
+        def record(last, sampling_packed, tokens):
+            if last is None:
+                return None
+            return jax.lax.with_sharding_constraint(
+                record_sampled(last, sampling_packed, tokens), replicated)
+
+        def sample(params, hidden_sel, sampling_packed, last=None):
             logits = model.compute_logits(params, hidden_sel)
             tokens, logprobs = sample_tokens(
                 logits, unpack_sampling(sampling_packed))
-            return tokens, logprobs
+            return tokens, logprobs, record(last, sampling_packed, tokens)
 
         def sample_ext(params, hidden_sel, sampling_packed,
                        ext: ExtendedSamplingMetadata, want_topk: bool,
-                       vocab_mask=None):
+                       vocab_mask=None, last=None):
             logits = model.compute_logits(params, hidden_sel)
-            return sample_tokens_extended(
+            out = sample_tokens_extended(
                 logits, unpack_sampling(sampling_packed), ext, want_topk,
                 vocab_mask)
+            return *out, record(last, sampling_packed, out[0])
 
         def prompt_lp(params, sel, targets):
             """Score prompt positions: log-softmax over the LM head at
@@ -854,9 +846,10 @@ class TPUModelRunner:
         if self._numerics is not None:
             self._numerics_fn = jax.jit(numerics)
         self._plp_fn = jax.jit(prompt_lp)
-        self._sample_fn = jax.jit(sample)
+        self._sample_fn = jax.jit(sample, donate_argnames=("last", ))
         self._sample_ext_fn = jax.jit(sample_ext,
-                                      static_argnames=("want_topk", ))
+                                      static_argnames=("want_topk", ),
+                                      donate_argnames=("last", ))
         self._spec_verify_fn = jax.jit(spec_verify,
                                        static_argnames=("truncate", ))
         self._build_multi_step_fn()
@@ -1081,10 +1074,17 @@ class TPUModelRunner:
             return None
         return rows, req_ids
 
-    def _ensure_last_sampled(self) -> jax.Array:
+    def _last_sampled(self) -> Optional[jax.Array]:
+        """The pipelined step's mirror of each row's last sample; None
+        off that path."""
+        if not self._async_chain:
+            return None
         if self._last_sampled_dev is None:
-            self._last_sampled_dev = jnp.zeros((self.max_num_reqs, ),
-                                               jnp.int32)
+            # Replicated over the mesh, as the samplers return it: one
+            # sharding whoever made it, so one forward program.
+            self._last_sampled_dev = jax.device_put(
+                np.zeros((self.max_num_reqs, ), np.int32),
+                NamedSharding(self.mesh, PartitionSpec()))
         return self._last_sampled_dev
 
     def _h2d(self, x) -> jax.Array:
@@ -1147,7 +1147,8 @@ class TPUModelRunner:
                     num_kv_writes=G)
             layout = self._step_layouts[(T, G)] = StepLayout(
                 T, G, self.max_num_reqs, self.max_pages_per_req, P_desc,
-                window_group=bool(self._window_group))
+                window_group=bool(self._window_group),
+                chain=self._async_chain)
         return layout
 
     def _prepare_inputs(self, scheduler_output: SchedulerOutput):
@@ -1199,11 +1200,10 @@ class TPUModelRunner:
         plp_targets: list[int] = []
         # (req_id, entry_index, k, target_token) per scored position.
         plp_meta: list[tuple[str, int, int, int]] = []
-        # Async scheduling: flat positions whose input token is still on
-        # device (step N's sample, not yet landed on the host) and the
-        # batch row to chain it from (_chain_apply).
-        chain_pos: list[int] = []
-        chain_rows: list[int] = []
+        # The pipelined step: a flat position whose input token is still
+        # on the device (the sample of the step before, not yet landed
+        # on the host) names the batch row to take it from.
+        chain_src = f.get("chain_src")
 
         fast = self._fast_decode_rows(scheduler_output)
         if fast is not None:
@@ -1231,10 +1231,9 @@ class TPUModelRunner:
             sampling_rows = [int(r) for r in rows_np]
             sampling_req_ids = fast_req_ids
             logits_idx = [int(i) for i in idx]
-            if self._async_chain:
-                chained = starts >= ib.num_tokens[rows_np]
-                chain_pos = [int(i) for i in idx[chained]]
-                chain_rows = [int(r) for r in rows_np[chained]]
+            if chain_src is not None:
+                chain_src[:N] = np.where(starts >= ib.num_tokens[rows_np],
+                                         rows_np, -1)
             t = N
             loop_items = ()
         else:
@@ -1245,13 +1244,11 @@ class TPUModelRunner:
             row = ib.req_id_to_index[req_id]
             start = ib.num_computed[row]
             end = start + n
-            if self._async_chain:
+            if chain_src is not None:
                 # Positions past the host's committed tokens take the
-                # previous step's on-device sample (async run-ahead).
-                known = int(ib.num_tokens[row])
-                for p in range(max(start, known), end):
-                    chain_pos.append(t + (p - start))
-                    chain_rows.append(row)
+                # previous step's on-device sample (run-ahead).
+                known = max(start, int(ib.num_tokens[row]))
+                chain_src[t + (known - start):t + n] = row
             drafts = (scheduler_output.scheduled_spec_decode_tokens.get(
                 req_id, []) if self.spec_k else [])
             if drafts:
@@ -1425,9 +1422,17 @@ class TPUModelRunner:
         if self.spec_k:
             seeds_e = seeds_e + 7919 * np.tile(np.arange(S1), R)
         # Shipped by _launch_sample, once the forward is on the device.
+        record_rows = None
+        if self._async_chain:
+            # Where the sampler records each token for the next step's
+            # forward; padding rows drop. (No spec decode on this path:
+            # one sampled position a request.)
+            record_rows = np.full((R, ), self.max_num_reqs, np.int32)
+            record_rows[:len(sampling_rows)] = sampling_rows
         sampling_packed = pack_sampling(
             expand(ib.temperature[rows]), expand(ib.top_k[rows]),
-            expand(ib.top_p[rows]), expand(ib.min_p[rows]), seeds_e)
+            expand(ib.top_p[rows]), expand(ib.min_p[rows]), seeds_e,
+            record_rows)
         ext_md = None
         want_topk = False
         if any(ib.extended_active(r) for r in sampling_rows):
@@ -1541,6 +1546,7 @@ class TPUModelRunner:
         inputs = StepInputs.of(
             self._h2d(buf),
             layout,
+            chain_last=self._last_sampled(),
             tknp=tknp,
             lora=lora_ctx,
             cascade_shared_ids=cascade_ids,
@@ -1567,22 +1573,10 @@ class TPUModelRunner:
         spec_truncate = bool(self.spec_k) and bool(
             (ib.top_k[rows] > 0).any() or (ib.top_p[rows] < 1.0).any()
             or (ib.min_p[rows] > 0.0).any())
-        chain = None
-        if chain_pos:
-            # Padded to the request bucket. The token ids lead the packed
-            # buffer, so a flat position indexes it as it is; pad
-            # positions point past the buffer's end so _chain_apply
-            # drops them.
-            C = pad_to_bucket(len(chain_pos), self.req_buckets)
-            cp = np.full((C, ), layout.size, np.int32)
-            cr = np.zeros((C, ), np.int32)
-            cp[:len(chain_pos)] = chain_pos
-            cr[:len(chain_rows)] = chain_rows
-            chain = (self._h2d(cp), self._h2d(cr))
         return (inputs, logits_indices, sampling_packed,
                 sampling_req_ids, (T, max_q, G), R,
                 (drafts_arr, q_ids, q_probs, spec_truncate), ext_md,
-                want_topk, vocab_mask, plp, chain)
+                want_topk, vocab_mask, plp)
 
     # Fixed sparse-bias width; keeps the graph keyed by R. Admission-time
     # validation in SamplingParams guarantees every request fits.
@@ -1743,25 +1737,14 @@ class TPUModelRunner:
 
         with ph.span("vdt._prepare_inputs", "prepare_inputs"):
             (inputs, logits_indices, sampling_packed, sampling_req_ids,
-             fwd_shape, R, spec_pack, ext_md, want_topk, vocab_mask, plp,
-             chain) = self._prepare_inputs(scheduler_output)
+             fwd_shape, R, spec_pack, ext_md, want_topk, vocab_mask,
+             plp) = self._prepare_inputs(scheduler_output)
         # Launch: from the prepared inputs to the handle (forward and
-        # sampler launches, the async chain, state saves).
+        # sampler launches, state saves).
         with ph.span("vdt.launch", "launch"):
             attn_label = self._attn_kernel_label(inputs)
             self._count_attn_dispatch(attn_label)
             drafts_arr, q_ids, q_probs, spec_truncate = spec_pack
-            if chain is not None:
-                # Async run-ahead rows: substitute the previous dispatch's
-                # on-device samples for the host-unknown input tokens. JAX
-                # program order serializes this gather after the previous
-                # step's _chain_record scatter, so the value is always the
-                # real sampled token by the time the forward reads it.
-                with self.mesh:
-                    inputs.packed = self._chain_apply(
-                        inputs.packed, chain[0],
-                        self._ensure_last_sampled(), chain[1])
-
             kv_meta = scheduler_output.kv_connector_metadata
             if self.kv_connector is not None and kv_meta is not None:
                 # External KV lands in the paged cache BEFORE the forward
@@ -1781,18 +1764,6 @@ class TPUModelRunner:
                                            sampling_packed, fwd_shape,
                                            ext_md, want_topk, vocab_mask,
                                            plp=plp, spec_q=spec_q)
-            if self._async_chain and spec_q is None:
-                # Record this step's samples for the next dispatch's chain
-                # (device-to-device; no host sync). Padding rows scatter out
-                # of range and drop.
-                rows_pad = np.full((R, ), self.max_num_reqs, np.int32)
-                rows_pad[:len(sampling_req_ids)] = [
-                    self.input_batch.req_id_to_index[r]
-                    for r in sampling_req_ids]
-                with self.mesh:
-                    self._last_sampled_dev = self._chain_record(
-                        self._ensure_last_sampled(), self._h2d(rows_pad),
-                        dev[0])
             # State snapshots AFTER the forward dispatch: program order on
             # the (donated) cache arrays makes the copy read post-step rows.
             self._apply_state_saves(scheduler_output)
@@ -2143,15 +2114,23 @@ class TPUModelRunner:
             if ext_md is not None:
                 with self._compile_watch(("sampleX", n_rows, want_topk,
                                           vocab_mask is not None)):
-                    tokens, logprobs, topv, topi = self._sample_ext_fn(
-                        self.params, hidden_sel, sampling_packed, ext_md,
-                        want_topk, vocab_mask)
+                    tokens, logprobs, topv, topi, last = \
+                        self._sample_ext_fn(
+                            self.params, hidden_sel, sampling_packed,
+                            ext_md, want_topk, vocab_mask,
+                            last=self._last_sampled())
                 if want_topk:
                     topk_dev = (topv, topi)
             else:
                 with self._compile_watch(("sample", n_rows)):
-                    tokens, logprobs = self._sample_fn(
-                        self.params, hidden_sel, sampling_packed)
+                    tokens, logprobs, last = self._sample_fn(
+                        self.params, hidden_sel, sampling_packed,
+                        last=self._last_sampled())
+            # The pipelined step's mirror, with this step's tokens
+            # recorded by the sampler itself (donated in, None off that
+            # path): program order puts the next forward's read behind
+            # this write.
+            self._last_sampled_dev = last
             # hidden_sel rides along for pooling requests (fetched lazily).
             return tokens, logprobs, topk_dev, hidden_sel, plp_dev
 
@@ -2436,7 +2415,6 @@ class TPUModelRunner:
         a REPLICATED sharding: jax.jit keys its cache on input sharding, so
         the sampler must see the same sharding at warm-up and serving or
         every ('sample', R) shape would recompile on a >1-device mesh."""
-        from jax.sharding import NamedSharding, PartitionSpec
         sel = hidden[logits_indices]
         return jax.device_put(sel, NamedSharding(mesh or self.mesh,
                                                  PartitionSpec()))
@@ -2471,6 +2449,7 @@ class TPUModelRunner:
         return StepInputs.of(
             jnp.asarray(layout.new_buffer()),
             layout,
+            chain_last=self._last_sampled(),
             tknp=tknp,
             lora=self._dummy_lora_batch(T),
             mrope_positions=(jnp.zeros((T, 3), jnp.int32)
@@ -2640,7 +2619,9 @@ class TPUModelRunner:
             rows = R * S1  # sampler sees S+1 rows/request with spec
             hidden_sel, md, ext, mask = self._sampler_dummy_args(rows, mesh)
             with self._compile_watch(("sample", rows)):
-                tokens, _ = self._sample_fn(self.params, hidden_sel, md)
+                tokens, _, self._last_sampled_dev = self._sample_fn(
+                    self.params, hidden_sel, md,
+                    last=self._last_sampled())
             jax.block_until_ready(tokens)
             n += 1
             if self._numerics_fn is not None:
@@ -2662,9 +2643,11 @@ class TPUModelRunner:
                 for vocab_mask in (None, mask):
                     with self._compile_watch(("sampleX", rows, want_topk,
                                               vocab_mask is not None)):
-                        tokens, _, _, _ = self._sample_ext_fn(
-                            self.params, hidden_sel, md, ext, want_topk,
-                            vocab_mask)
+                        tokens, _, _, _, self._last_sampled_dev = \
+                            self._sample_ext_fn(
+                                self.params, hidden_sel, md, ext,
+                                want_topk, vocab_mask,
+                                last=self._last_sampled())
                     jax.block_until_ready(tokens)
                     n += 1
         return n
@@ -2678,10 +2661,12 @@ class TPUModelRunner:
 
     def _sampler_dummy_args(self, rows: int, mesh) -> tuple:
         """(hidden_sel, packed sampling md, extended md, vocab mask) for
-        one sampler row bucket."""
+        one sampler row bucket. On the pipelined path the buffer has its
+        record row, every entry out of range."""
         zeros = np.zeros((rows, ), np.float32)
-        md = jnp.asarray(pack_sampling(zeros, zeros, zeros + 1, zeros,
-                                       zeros))
+        md = jnp.asarray(pack_sampling(
+            zeros, zeros, zeros + 1, zeros, zeros,
+            zeros + self.max_num_reqs if self._async_chain else None))
         hidden_sel = self._gather_sample_rows(
             jnp.zeros((rows, self.model.cfg.hidden_size),
                       self.model.cfg.dtype),
@@ -2895,7 +2880,8 @@ class TPUModelRunner:
             hidden_sel, md, ext, mask = self._sampler_dummy_args(
                 R * (self.spec_k + 1), mesh)
             programs = [self._sample_ext_fn.lower(
-                self.params, hidden_sel, md, ext, True, mask)]
+                self.params, hidden_sel, md, ext, True, mask,
+                last=self._last_sampled())]
             if self.spec_k:
                 programs.append(self._spec_verify_fn.lower(
                     self.params, hidden_sel,

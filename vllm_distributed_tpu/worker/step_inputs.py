@@ -10,13 +10,22 @@ function of shapes the forward program is compiled for anyway
 per-array signature keyed. Optional fields (token parallelism, LoRA,
 multimodal rows, M-RoPE ids, cascade pages) ride beside the buffer as
 arrays of their own: their steps are rare.
+
+Under the pipelined step (``StepLayout.chain``) the buffer also says, a
+token, which batch row's last sample stands in for a token id the host
+does not know yet, and the forward reads it from the runner's
+device-resident mirror (``StepInputs.chain_last``) as it unpacks: no
+program and no transfer of its own.
 """
 
 import dataclasses
 from dataclasses import dataclass
 from functools import cached_property
 
+from typing import Optional
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from vllm_distributed_tpu.models.common import AttentionBatch
@@ -31,7 +40,11 @@ class StepLayout:
     ``window_group``: the model keeps a second cache group for its
     window layers (core/kv_cache_manager.py GroupedKVCacheManager), so
     the buffer carries that group's table, slots and write runs too; a
-    model with one group has the layout it always had."""
+    model with one group has the layout it always had. ``chain``: the
+    runner serves the pipelined step, so the buffer carries
+    ``chain_src``, per token the batch row whose last sampled token (on
+    the device, ``StepInputs.chain_last``) is this position's input, or
+    -1 where ``token_ids`` holds it."""
 
     T: int
     G: int
@@ -39,6 +52,7 @@ class StepLayout:
     pages_per_req: int
     desc_programs: int
     window_group: bool = False
+    chain: bool = False
 
     @cached_property
     def fields(self) -> tuple[tuple[str, int, int, tuple[int, ...]], ...]:
@@ -57,6 +71,8 @@ class StepLayout:
             shapes += [("window_slot_mapping", (T, )),
                        ("window_block_tables", (R, self.pages_per_req)),
                        ("window_kv_runs", (G, 4))]
+        if self.chain:
+            shapes += [("chain_src", (T, ))]
         out, start = [], 0
         for name, shape in shapes:
             stop = start + int(np.prod(shape))
@@ -75,6 +91,8 @@ class StepLayout:
         views["slot_mapping"][:] = -1   # padding: no KV write
         if self.window_group:
             views["window_slot_mapping"][:] = -1
+        if self.chain:
+            views["chain_src"][:] = -1      # every token id is the host's
         return buf
 
     def new_buffer(self) -> np.ndarray:
@@ -103,21 +121,31 @@ class StepInputs:
     rest: AttentionBatch
     # Static: where each field lies in ``packed``.
     layout: StepLayout
+    # [max_reqs] int32, with ``layout.chain``: each batch row's last
+    # sampled token as the sampler left it on the device.
+    chain_last: Optional[jax.Array] = None
 
     @classmethod
-    def of(cls, packed, layout: StepLayout, **rest) -> "StepInputs":
+    def of(cls, packed, layout: StepLayout, chain_last=None,
+           **rest) -> "StepInputs":
         """``rest``: AttentionBatch's optional fields and statics."""
-        packed_fields = [name for name, *_ in layout.fields[1:]]
+        packed_fields = [name for name, *_ in layout.fields[1:]
+                         if name != "chain_src"]
         return cls(packed, AttentionBatch(**dict.fromkeys(packed_fields),
-                                          **rest), layout)
+                                          **rest), layout, chain_last)
 
     def unpack(self) -> tuple[jax.Array, AttentionBatch]:
         """(token_ids, AttentionBatch); inside a jitted program the
         slices are static and cost nothing."""
         f = self.layout.views(self.packed)
         token_ids = f.pop("token_ids")
+        src = f.pop("chain_src", None)
+        if src is not None:
+            token_ids = jnp.where(
+                src >= 0, self.chain_last[jnp.maximum(src, 0)], token_ids)
         return token_ids, dataclasses.replace(self.rest, **f)
 
 
 jax.tree_util.register_dataclass(
-    StepInputs, data_fields=["packed", "rest"], meta_fields=["layout"])
+    StepInputs, data_fields=["packed", "rest", "chain_last"],
+    meta_fields=["layout"])
