@@ -13,12 +13,17 @@ JSON lines go to ``chiprun_out/pairs/<time>-<tree>-<cell>-t<trace>-<seed>
 .jsonl`` with the end of its standard error, the window's two ``/metrics``
 scrapes and the share of forwards issued while another was in flight
 (``vdt:engine_steps_overlapped_total`` over
-``vdt:engine_steps_dispatched_total``) beside it; one summary line a run
-is printed. Nothing here is a benchmark file: the cells, their traffic and
+``vdt:engine_steps_dispatched_total``) beside it, and for a traced run what
+``scripts/device_timeline_check.py`` reads off its capture
+(``.timeline.json``; with ``CHIP_PAIRS_KEEP_TRACE`` 1 or the cell's name the capture
+itself comes back too, 6 to 17 MB a run); one summary line a run is
+printed. Nothing here is a benchmark file: the cells, their traffic and
 their metrics are ``BENCHMARK.json``'s.
 """
+import glob
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -58,7 +63,12 @@ for n, spec in enumerate(sys.argv[1:]):
              if k.startswith(("engine.", "device.", "model.", "kernel."))
              and ("roofline" in k or "period" in k or "idle" in k
                   or "step_device" in k or "step_cpu" in k
-                  or "host_gap" in k or "host_stall" in k)}
+                  or "host_gap" in k or "host_stall" in k
+                  or "device_" in k)}
+    extra.update({k: (v["value"] if isinstance(v, dict) else v)
+                  for k, v in m.items()
+                  if k.startswith("runner.") and ("device_wait" in k
+                                                  or "fetch" in k)})
     # The engagement counters, from the window's two /metrics scrapes.
     overlap = {}
     try:
@@ -88,6 +98,22 @@ for n, spec in enumerate(sys.argv[1:]):
         overlap = {"overlap_error": repr(e)[:200]}
     with open(os.path.join(OUT, tag + ".overlap.json"), "w") as f:
         json.dump(overlap, f)
+    # A traced run's capture, held against what the host believed of the
+    # chip (PR 52), while the capture is still there.
+    check = os.path.join(TREES[tree], "scripts", "device_timeline_check.py")
+    if trace == "1" and os.path.exists(check):
+        c = subprocess.run(
+            [sys.executable, check, "--cell", cell, "--out",
+             os.path.join(OUT, tag + ".timeline.json")],
+            cwd=TREES[tree], capture_output=True, text=True)
+        print(c.stdout.strip()[-6000:] or c.stderr[-2000:], flush=True)
+        if os.environ.get("CHIP_PAIRS_KEEP_TRACE") in ("1", cell):
+            found = sorted(glob.glob(os.path.join(
+                TREES[tree], ".bench_work", cell.rsplit(".", 1)[0],
+                "trace", "**", "*.xplane.pb"), recursive=True))
+            if found:
+                shutil.copy(found[-1], os.path.join(OUT,
+                                                    tag + ".xplane.pb"))
     print(json.dumps({"run": tag, "rc": p.returncode, **overlap,
                       "wall_s": round(time.time() - t0, 1),
                       "correct": last.get("correct"),

@@ -13,7 +13,7 @@ import types
 import pytest
 from transformers import LlamaConfig
 
-from tests.metrics.test_step_phases import HF, PARTITION
+from tests.metrics.test_step_phases import CPU_PARTS, HF, PARTITION
 from vllm_distributed_tpu.config import (CacheConfig, EngineConfig,
                                          LoadConfig, ModelConfig,
                                          ParallelConfig, SchedulerConfig)
@@ -111,7 +111,11 @@ def test_the_engine_threads_cpu_is_exported_phase_by_phase(served):
     steps = core["step_phase_seconds"]["schedule"]["count"]
     assert steps >= 12
     cpu = core["step_phase_cpu_seconds"]
-    for p in PARTITION:
+    # Two phases and the rest (ISSUE 52): the six series nothing read
+    # are no longer kept up, on either transport.
+    assert set(cpu) == set(CPU_PARTS) | {"dispatch", "wait"}
+    assert not set(cpu) & (set(PARTITION) - set(CPU_PARTS))
+    for p in CPU_PARTS[:2]:
         assert cpu[p]["count"] == core["step_phase_seconds"][p]["count"]
         # CPU <= wall up to the clocks' resolution: microseconds a read
         # here, a 10 ms tick of the thread clock on a sandboxed host.
@@ -119,8 +123,14 @@ def test_the_engine_threads_cpu_is_exported_phase_by_phase(served):
             + steps * 2e-5 + 0.011, p
     periods = core["step_period_seconds"]["count"]
     assert core["step_cpu_seconds"]["count"] == periods > 0
+    assert cpu["other"]["count"] == periods
     assert 0 <= core["step_cpu_seconds"]["sum"] \
         <= core["step_period_seconds"]["sum"] + 0.011
+    # One busy period or several: the three parts sum to step_cpu but
+    # for the two phases of the steps whose period never closed (the
+    # last of a busy period), which only add.
+    parts = sum(cpu[p]["sum"] for p in CPU_PARTS)
+    assert parts >= core["step_cpu_seconds"]["sum"] - 1e-9
 
 
 def test_the_kinds_add_up_to_the_period_and_the_device_wait(served):
@@ -136,6 +146,75 @@ def test_the_kinds_add_up_to_the_period_and_the_device_wait(served):
     assert kinds["mixed/device_wait"]["count"] + \
         kinds["decode/device_wait"]["count"] == \
         core["step_phase_seconds"]["device_wait"]["count"]
+
+
+def test_the_devices_timeline_counts_each_forward_once(served):
+    """The four families of ISSUE 52 over either transport's stats feed:
+    one observation a forward, the synchronous step finds the chip idle
+    at every issue, and a device time wherever the fetch waited."""
+    core = served["core"]
+    forwards = core["step_phase_seconds"]["launch"]["count"]
+    assert forwards >= 12
+    idle, seen = core["step_device_idle_at_issue"], core["step_device_seen"]
+    assert idle["buckets"] == seen["buckets"] == [0, 1]
+    assert idle["count"] == seen["count"] == forwards
+    assert idle["sum"] == forwards
+    fetch, device = core["step_fetch_seconds"], core["step_device_seconds"]
+    assert set(fetch) == {"0", "1"}
+    assert set(device) == {"decode", "mixed", "chunk"}
+    assert fetch["0"]["count"] + fetch["1"]["count"] == forwards
+    assert sum(h["count"] for h in device.values()) == seen["sum"] \
+        == fetch["0"]["count"]
+    assert device["mixed"]["count"] + device["chunk"]["count"] <= \
+        core["step_kind_seconds"]["mixed/device_wait"]["count"]
+    # Read by quantile, so on the fine edges; the phases keep theirs.
+    assert len(device["decode"]["buckets"]) > 40
+    assert fetch["1"]["buckets"] == device["decode"]["buckets"]
+    assert len(core["step_phase_seconds"]["device_wait"]["buckets"]) == 16
+
+
+def test_a_pipelined_engine_asks_the_device_once_a_forward():
+    """The real runner under the pipelined step (what ``vdt serve``
+    runs): one ``is_ready()`` at each issue and one before each fetch, a
+    busy period's first forward found idle, and a device time for every
+    forward whose start and end the host saw, none for the rest."""
+    from vllm_distributed_tpu.engine.llm_engine import LLMEngine
+    config = make_config(False)
+    config.scheduler_config.async_scheduling = True
+    engine = LLMEngine(config, load_tokenizer=False)
+    try:
+        core = engine.engine_core.engine_core
+        assert core.async_scheduling and core.phases.sync
+        sp = SamplingParams(temperature=0.0, max_tokens=24,
+                            ignore_eos=True)
+        for period in range(2):
+            for i in range(3):
+                engine.add_request(
+                    f"p{period}-{i}",
+                    [2 + (i * 7 + j) % 120 for j in range(10 + 60 * (i % 2))],
+                    sp)
+            while engine.has_unfinished_requests():
+                engine.step()
+        d = core.phases.to_dict()
+        forwards = core.steps_dispatched
+        idle, seen = d["step_device_idle_at_issue"], d["step_device_seen"]
+        assert idle["count"] == seen["count"] == forwards > 40
+        # Each busy period's first forward finds nothing in flight.
+        assert 2 <= idle["sum"] <= forwards
+        fetch = d["step_fetch_seconds"]
+        assert fetch["0"]["count"] + fetch["1"]["count"] == forwards
+        by_shape = {s: h["count"]
+                    for s, h in d["step_device_seconds"].items()}
+        assert sum(by_shape.values()) == seen["sum"] <= fetch["0"]["count"]
+        # A 70-token prompt under a 64-token budget: one whole chunk.
+        assert by_shape["chunk"] <= 2
+        # An is_ready() is no transfer.
+        assert core.get_stats()["step_host_transfers"]["sum"] \
+            == 3 * forwards
+        # The timeline keeps no forward's array past its fetch.
+        assert core.phases._newest.array is None
+    finally:
+        engine.engine_core.shutdown()
 
 
 def test_each_request_is_stamped_once_on_its_way_in(served):
@@ -174,6 +253,15 @@ def test_metrics_carries_every_new_family_and_times_its_rendering(served):
     first, second = served["metrics"]
     for family in ("vdt:step_cpu_seconds_count",
                    'vdt:step_phase_cpu_seconds_count{phase="launch"}',
+                   'vdt:step_phase_cpu_seconds_count{phase="other"}',
+                   'vdt:step_device_seconds_count{shape="decode"}',
+                   'vdt:step_device_seconds_count{shape="chunk"}',
+                   "vdt:step_device_seen_count",
+                   "vdt:step_device_idle_at_issue_count",
+                   'vdt:step_fetch_seconds_count{ready="0"}',
+                   'vdt:step_fetch_seconds_count{ready="1"}',
+                   'vdt:device_wait_seconds_bucket{worker="dp0-h0",'
+                   'le="0.017"}',
                    'vdt:step_kind_seconds_count{kind="mixed",'
                    'part="period"}',
                    'vdt:step_kind_seconds_count{kind="decode",'
@@ -185,6 +273,10 @@ def test_metrics_carries_every_new_family_and_times_its_rendering(served):
                    "vdt:frontend_output_lag_seconds_count"):
         assert family in first, family
     assert "vdt:frontend_output_lag_seconds_count 0" not in first
+    for gone in ("loop", "schedule", "update_states", "device_wait",
+                 "finish", "update"):
+        assert f'vdt:step_phase_cpu_seconds_count{{phase="{gone}"}}' \
+            not in first, gone
     # One observation a scrape, seen by the next.
     assert "vdt:metrics_render_seconds_count 0" in first
     assert "vdt:metrics_render_seconds_count 1" in second

@@ -28,6 +28,8 @@ HF = dict(vocab_size=128, hidden_size=64, intermediate_size=128,
           architectures=["LlamaForCausalLM"])
 PARTITION = ("loop", "schedule", "update_states", "prepare_inputs",
              "launch", "device_wait", "finish", "update")
+# The thread's CPU seconds: two phases and the rest (ISSUE 52).
+CPU_PARTS = ("prepare_inputs", "launch", "other")
 
 
 @pytest.fixture(scope="module")
@@ -136,13 +138,15 @@ def test_cpu_and_kinds_of_a_running_engine(engine):
     _add(engine, "cpu-a", 10, 60)
     for _ in range(5):
         engine.step()
-    own_a = ph.cpu_mark - ph._cpu_at_step_start
+    # ``other`` closes with the period; the two phases of the step that
+    # has not closed yet are the boundary.
+    own_a = ph.cpu_named
     a = core.get_stats()
     _add(engine, "cpu-b", 70, 4)    # a 64-token budget: two mixed steps
     n = 30
     for _ in range(n):
         engine.step()
-    own_b = ph.cpu_mark - ph._cpu_at_step_start
+    own_b = ph.cpu_named
     b = core.get_stats()
 
     def delta(key, sub=None):
@@ -150,16 +154,21 @@ def test_cpu_and_kinds_of_a_running_engine(engine):
             else (a[key][sub], b[key][sub])
         return hb["count"] - ha["count"], hb["sum"] - ha["sum"]
 
-    cpu = {p: delta("step_phase_cpu_seconds", p) for p in PARTITION}
+    assert set(b["step_phase_cpu_seconds"]) == \
+        set(CPU_PARTS) | {"dispatch", "wait"}
+    cpu = {p: delta("step_phase_cpu_seconds", p) for p in CPU_PARTS}
     wall = {p: delta("step_phase_seconds", p) for p in PARTITION}
     assert all(c[0] == n for c in cpu.values())
     assert delta("step_cpu_seconds")[0] == n
     assert sum(c[1] for c in cpu.values()) == pytest.approx(
-        delta("step_cpu_seconds")[1] + own_b - own_a, rel=1e-6)
+        delta("step_cpu_seconds")[1] + own_b - own_a, rel=1e-6, abs=1e-9)
     # CPU <= wall up to the clocks' resolution: microseconds a read here,
     # a 10 ms tick of the thread clock on a sandboxed host.
-    for p in PARTITION:
+    for p in CPU_PARTS[:2]:
         assert cpu[p][1] <= wall[p][1] + n * 2e-5 + 0.011, p
+    assert cpu["other"][1] <= sum(
+        wall[p][1] for p in PARTITION if p not in CPU_PARTS) \
+        + n * 2e-5 + 0.011
     kinds = {k: delta("step_kind_seconds", k)
              for k in b["step_kind_seconds"]}
     # The period of step k is observed at step k+1's start: of the n
@@ -394,7 +403,8 @@ def test_span_observes_its_histogram_and_a_batch_queue_timeline_does_not_chain()
         "step_phase_seconds", "step_phase_cpu_seconds",
         "step_kind_seconds", "step_period_seconds", "step_cpu_seconds",
         "step_host_gap_seconds", "step_host_stall_seconds",
-        "input_queue_seconds"}
+        "input_queue_seconds", "step_device_seconds", "step_device_seen",
+        "step_device_idle_at_issue", "step_fetch_seconds"}
 
 
 def test_front_end_histograms_render():
@@ -468,19 +478,23 @@ def test_cpu_partitions_the_step_as_wall_does(clocks, costs):
         _scripted_step(ph, clocks, costs, "decode")
     ph.begin_step()                     # closes the n-th period
     wall = {p: ph.hists[p].total for p in PARTITION}
-    cpu = {p: ph.cpu_hists[p].total for p in PARTITION}
+    cpu = {p: ph.cpu_hists[p].total for p in CPU_PARTS}
     # The first loop is the instant between resume() and the first step.
     assert ph.hists["loop"].count == n + 1
     assert all(ph.cpu_hists[p].count == ph.hists[p].count
-               for p in PARTITION)
-    assert ph.period.count == ph.step_cpu.count == n
+               for p in CPU_PARTS[:2])
+    assert ph.period.count == ph.step_cpu.count \
+        == ph.cpu_hists["other"].count == n
     assert sum(wall.values()) == ph.period.total \
         == n * sum(w for w, _ in costs.values()) / 1024
+    # The three parts: two phases and, by subtraction, the rest.
     assert sum(cpu.values()) == ph.step_cpu.total \
         == n * sum(c for _, c in costs.values()) / 1024
-    for p in PARTITION:
+    for p in CPU_PARTS[:2]:
         assert cpu[p] <= wall[p], p
         assert cpu[p] == n * costs[p][1] / 1024
+    assert cpu["other"] == n * sum(
+        c for p, (_, c) in costs.items() if p not in CPU_PARTS) / 1024
     # The stall: wall less CPU of every phase between one fetch's return
     # and the next fetch's start, which is every phase but device_wait.
     waited = sum(w - c for p, (w, c) in costs.items()
@@ -591,7 +605,7 @@ def test_a_pipelined_step_has_one_period_and_the_kind_it_retires(clocks,
     assert ph.period.count == ph.step_cpu.count == K + 1
     wall = sum(ph.hists[p].total for p in PARTITION)
     assert wall == ph.period.total
-    assert sum(ph.cpu_hists[p].total for p in PARTITION) \
+    assert sum(ph.cpu_hists[p].total for p in CPU_PARTS) \
         == ph.step_cpu.total
     assert all(ph.hists[p].count == K for p in PARTITION[1:])
     got = {k: h.count for k, h in ph.kind_hists.items()}
@@ -618,12 +632,12 @@ def test_an_idle_wait_is_in_no_cpu_account(clocks):
         pass
     ph.end_step(more_work=False)
     before = (ph.step_cpu.count, ph.step_cpu.total,
-              ph.cpu_hists["loop"].total)
+              ph.cpu_hists["other"].total)
     clocks.advance(5000, 3)             # idle, then work arrives
     ph.resume()
     _scripted_step(ph, clocks, _WAITS, "decode")
     assert (ph.step_cpu.count, ph.step_cpu.total,
-            ph.cpu_hists["loop"].total) == before
+            ph.cpu_hists["other"].total) == before
     assert ph.host_stall.count == 0
 
 
@@ -642,14 +656,17 @@ def test_a_batch_queue_timeline_claims_no_partition_of_cpu_or_kind(clocks):
                 clocks.advance(16, 1)
     ph.end_step(more_work=True)
     ph.begin_step()
-    # Every block its own CPU; dispatch and wait contain the runner's.
-    assert ph.cpu_hists["schedule"].total == 2 / 1024
+    # Every block that has a CPU account its own CPU; dispatch and wait
+    # contain the runner's. (``schedule`` lost its account with the five
+    # other series nothing read: ISSUE 52.)
+    assert "schedule" not in ph.cpu_hists
+    assert ph.hists["schedule"].total == 2 / 1024
     assert ph.cpu_hists["prepare_inputs"].total == 1 / 1024
     assert ph.cpu_hists["dispatch"].total == 3 / 1024
     assert ph.hists["dispatch"].total == 7 / 1024
     assert ph.cpu_hists["wait"].total == 1 / 1024
     assert ph.step_cpu.count == ph.period.count == 0
-    assert ph.cpu_hists["loop"].count == 0
+    assert ph.cpu_hists["other"].count == 0
     assert all(h.count == 0 for h in ph.kind_hists.values())
     assert ph.host_stall.count == 0
 
@@ -682,3 +699,482 @@ def test_the_new_families_render_with_their_labels():
                  "vdt:frontend_admit_seconds_count 1",
                  "vdt:metrics_render_seconds_count 1"):
         assert line in text, line
+
+
+# ---------------------------------------------------------------------
+# The chip's side of the step, read on the host (ISSUE 52): scripted
+# clocks, and a fetch array whose readiness the test decides.
+# ---------------------------------------------------------------------
+class _Results:
+    """What a forward's fetch waits for: ``jax.Array.is_ready()``."""
+
+    def __init__(self) -> None:
+        self.ready = False
+
+    def is_ready(self) -> bool:
+        return self.ready
+
+
+def _device_counts(ph: StepPhases) -> dict:
+    return {"idle": (ph.idle_at_issue.count, ph.idle_at_issue.total),
+            "seen": (ph.device_seen.count, ph.device_seen.total),
+            "device": {s: h.count for s, h in ph.device_step.items()},
+            "fetch": {r: h.count for r, h in ph.fetch_hists.items()}}
+
+
+def _issue(ph: StepPhases, clocks: _Clocks, shape: str = "decode"):
+    """prepare_inputs and launch of one forward: (flight, array, stamp
+    of the issue)."""
+    with ph.span("vdt._prepare_inputs", "prepare_inputs"):
+        clocks.advance(3, 3)
+    with ph.span("vdt.launch", "launch"):
+        clocks.advance(1, 1)
+        with ph.forward_issue(shape, 16):
+            issued = clocks.wall
+            clocks.advance(2, 2)
+        array = _Results()
+        return ph.in_flight(array), array, issued
+
+
+def _retire(ph: StepPhases, clocks: _Clocks, flight, array,
+            ready: bool) -> float:
+    """device_wait, finish and update of one forward: the fetch's
+    return."""
+    array.ready = ready
+    with ph.fetch(flight):
+        clocks.advance(1 if ready else 8, 1)
+    returned = clocks.wall
+    array.ready = True
+    for p in ("finish", "update"):
+        with ph.span(f"vdt.{p}", p):
+            clocks.advance(1, 1)
+    return returned
+
+
+def _pipelined_forwards(ph: StepPhases, clocks: _Clocks, script) -> list:
+    """EngineCore._step_pipelined's order over ``script``, one entry a
+    forward: (it ended before the next forward's issue, its results were
+    there when its fetch began). -> [(issue stamp, fetch's return)]."""
+    flights, stamps = [], []
+    K = len(script)
+    for k in range(K + 1):
+        with ph.begin_step():
+            if k < K:
+                if k and script[k - 1][0]:
+                    flights[k - 1][1].ready = True
+                flights.append(_issue(ph, clocks))
+            if k:
+                ph.forward_queued = k < K
+                flight, array, issued = flights[k - 1]
+                stamps.append((issued, _retire(ph, clocks, flight, array,
+                                               script[k - 1][1])))
+        ph.end_step(more_work=k < K)
+        clocks.advance(1, 1)
+    return stamps
+
+
+_BUSY, _DONE_EARLY, _DONE_AT_FETCH = (False, False), (True, True), \
+    (False, True)
+
+
+@pytest.mark.parametrize("script, idle, seen, starts", [
+    # Device-bound: every issue but the first finds the chip busy, every
+    # fetch waits: a step lasts from fetch return to fetch return.
+    ([_BUSY] * 4, [1, 0, 0, 0], [1, 1, 1, 1],
+     ["issue", "prev", "prev", "prev"]),
+    # Host-bound: every forward has ended before the next is issued, no
+    # fetch waits, no step's end is seen.
+    ([_DONE_EARLY] * 4, [1, 1, 1, 1], [0, 0, 0, 0], [None] * 4),
+    # Found idle and a waited fetch: from the issue to the return; the
+    # step behind it starts where it ended.
+    ([_DONE_EARLY, _BUSY, _BUSY], [1, 1, 0], [0, 1, 1],
+     [None, "issue", "prev"]),
+    # Ready at the fetch: no end, so the step queued behind it has no
+    # start either, though its own fetch waited.
+    ([_BUSY, _DONE_AT_FETCH, _BUSY, _BUSY], [1, 0, 0, 0], [1, 0, 0, 1],
+     ["issue", None, None, "prev"]),
+], ids=["device-bound", "host-bound", "idle-then-waited",
+        "ready-at-the-fetch"])
+def test_the_devices_timeline_step_by_step(clocks, script, idle, seen,
+                                           starts):
+    ph = StepPhases()
+    stamps = _pipelined_forwards(ph, clocks, script)
+    K = len(script)
+    # Once a forward: the first of a busy period counts as found idle.
+    assert (ph.idle_at_issue.count, ph.idle_at_issue.total) \
+        == (K, sum(idle))
+    assert (ph.device_seen.count, ph.device_seen.total) == (K, sum(seen))
+    want = [stamps[k][1] - (stamps[k][0] if starts[k] == "issue"
+                            else stamps[k - 1][1])
+            for k in range(K) if seen[k]]
+    assert ph.device_step["decode"].count == sum(seen)
+    assert ph.device_step["decode"].total == pytest.approx(sum(want),
+                                                           abs=1e-12)
+    assert all(v > 0 for v in want)
+    ready = [r for _, r in script]
+    assert ph.fetch_hists["1"].count == sum(ready)
+    assert ph.fetch_hists["0"].count == K - sum(ready)
+    # A fetch is timed from its own start: 8 ticks waited, 1 copied.
+    assert ph.fetch_hists["0"].total == (K - sum(ready)) * 8 / 1024
+    assert ph.fetch_hists["1"].total == sum(ready) * 1 / 1024
+    # The host's bookkeeping says nothing of all this: 0 a fetch.
+    assert (ph.host_gap.count, ph.host_gap.total) == (K - 1, 0.0)
+    # ... and the timeline holds no array once its fetch is over.
+    assert ph._newest.array is None and ph._newest.after is None
+
+
+@pytest.mark.parametrize("ready", [False, True],
+                         ids=["waited", "ready-at-the-fetch"])
+def test_the_synchronous_step_obeys_the_same_rules(clocks, ready):
+    """LLM.generate's step: every forward finds the chip idle; a fetch
+    that waited gives ret - issue, one that found its results gives no
+    device time."""
+    ph = StepPhases()
+    n, want = 4, []
+    for _ in range(n):
+        with ph.begin_step():
+            with ph.span("vdt.schedule", "schedule"):
+                clocks.advance(1, 1)
+            flight, array, issued = _issue(ph, clocks, "mixed")
+            want.append(_retire(ph, clocks, flight, array, ready) - issued)
+        ph.end_step(more_work=True)
+        clocks.advance(1, 1)
+    assert (ph.idle_at_issue.count, ph.idle_at_issue.total) == (n, n)
+    assert (ph.device_seen.count, ph.device_seen.total) \
+        == (n, 0 if ready else n)
+    assert ph.device_step["mixed"].count == (0 if ready else n)
+    assert ph.device_step["mixed"].total == pytest.approx(
+        0 if ready else sum(want), abs=1e-12)
+    assert ph.device_step["decode"].count == 0
+    assert ph.fetch_hists["1" if ready else "0"].count == n
+
+
+def test_pipeline_parallelism_and_the_burst_observe_no_device_time(clocks):
+    nothing = {"idle": (0, 0.0), "seen": (0, 0.0),
+               "device": {"decode": 0, "mixed": 0, "chunk": 0},
+               "fetch": {"0": 0, "1": 0}}
+    # Pipeline parallelism's queue: no flight, a plain device_wait.
+    pp = StepPhases(sync=False)
+    with pp.begin_step():
+        with pp.span("vdt.dispatch", "dispatch"):
+            flight, _, _ = _issue(pp, clocks)
+        assert flight is None
+        with pp.span("vdt.wait", "wait"):
+            with pp.fetch(flight):
+                clocks.advance(8, 1)
+    pp.end_step(more_work=True)
+    assert _device_counts(pp) == nothing
+    assert pp.hists["device_wait"].count == 1
+    # The multi-step burst blocks inside a device_wait of its own: a
+    # fetch of the timeline (kind, stall), no forward of the device's.
+    ph = StepPhases()
+    for _ in range(3):
+        with ph.begin_step():
+            with ph.span("vdt.device_wait", "device_wait"):
+                clocks.advance(16, 1)
+        ph.end_step(more_work=True)
+        clocks.advance(1, 1)
+    assert _device_counts(ph) == nothing
+    assert ph.kind_hists["decode/device_wait"].count == 3
+    assert ph.host_stall.count == 2
+
+
+def test_a_pipelined_step_reads_the_thread_clock_five_times(monkeypatch):
+    """PR 39's debt: eight reads a step at 6 us each on the chip's host.
+    Five are left: the step's start, both ends of prepare_inputs, the
+    end of launch (where the fetch starts) and the fetch's return."""
+    from vllm_distributed_tpu.metrics import stats
+
+    class Counting(_Clocks):
+        reads = 0
+
+        def thread_time(self) -> float:
+            self.reads += 1
+            return self.cpu
+
+    c = Counting()
+    monkeypatch.setattr(stats, "time", c)
+    ph = StepPhases()
+    flights = []
+    per_step = []
+    for k in range(6):
+        before = c.reads
+        with ph.begin_step():
+            with ph.span("vdt.schedule", "schedule"):
+                c.advance(1, 1)
+            with ph.span("vdt._update_states", "update_states"):
+                c.advance(1, 1)
+            flights.append(_issue(ph, c))
+            if k:
+                ph.forward_queued = True
+                flight, array, _ = flights[k - 1]
+                _retire(ph, c, flight, array, ready=False)
+        ph.end_step(more_work=True)
+        c.advance(1, 1)
+        per_step.append(c.reads - before)
+    assert per_step == [4] + [5] * 5     # the fill fetches nothing
+    # A drain (nothing launched): the start and the fetch's return.
+    before = c.reads
+    with ph.begin_step():
+        flight, array, _ = flights[-1]
+        _retire(ph, c, flight, array, ready=False)
+    ph.end_step(more_work=False)
+    assert c.reads - before == 2
+    # And the partition of three still sums, with the stall as it was.
+    assert sum(ph.cpu_hists[p].total for p in CPU_PARTS) \
+        == ph.step_cpu.total
+    assert ph.host_stall.count == 5
+
+
+@pytest.mark.parametrize("tokens, prefill, want", [
+    (5, 0, ("decode", 16)),
+    (64, 0, ("decode", 64)),         # the largest bucket, all decodes
+    (20, 12, ("mixed", 32)),
+    (32, 32, ("mixed", 32)),         # below the largest bucket
+    (33, 30, ("chunk", 64)),         # pads into the largest bucket
+    (64, 60, ("chunk", 64)),
+], ids=["decode", "decode-64", "mixed", "mixed-32", "chunk-33", "chunk"])
+def test_shape_of_a_step_from_its_scheduler_output(engine, tokens, prefill,
+                                                   want):
+    from types import SimpleNamespace
+    runner = _core(engine).executor.worker.model_runner
+    assert runner.token_buckets[-1] == 64
+    out = SimpleNamespace(total_num_scheduled_tokens=tokens,
+                          num_prefill_tokens=prefill)
+    assert runner._step_shape(out) == want
+
+
+def test_a_running_engine_observes_each_forward_once(engine):
+    """The real runner on the synchronous step: one observation a
+    forward in the three per-forward families, every issue finds the
+    chip idle, a 64-token chunk is a ``chunk`` and its tail a
+    ``mixed``."""
+    core = _core(engine)
+    ph = core.phases
+    a = _device_counts(ph)
+    _add(engine, "dev-a", 70, 6)     # 64-token budget: a chunk, a tail
+    steps = 0
+    while engine.has_unfinished_requests():
+        engine.step()
+        steps += 1
+    b = _device_counts(ph)
+    assert steps == 7
+    assert b["idle"][0] - a["idle"][0] == steps
+    assert b["idle"][1] - a["idle"][1] == steps
+    assert b["seen"][0] - a["seen"][0] == steps
+    fetches = sum(b["fetch"].values()) - sum(a["fetch"].values())
+    assert fetches == steps
+    seen = b["seen"][1] - a["seen"][1]
+    by_shape = {s: b["device"][s] - a["device"][s] for s in b["device"]}
+    assert sum(by_shape.values()) == seen
+    # What was seen is what waited (on this backend most fetches do).
+    assert seen == b["fetch"]["0"] - a["fetch"]["0"]
+    assert by_shape["chunk"] <= 1 and by_shape["mixed"] <= 1
+    assert by_shape["decode"] <= 5
+
+
+def test_the_devices_families_render_and_have_readers():
+    from vllm_distributed_tpu.metrics.prometheus import (
+        HISTOGRAMS, LABELED_HISTOGRAMS, LABELED_METRICS)
+    from vllm_distributed_tpu.metrics.stats import (DEVICE_STEP_BUCKETS,
+                                                    STEP_PHASE_BUCKETS)
+    ph = StepPhases()
+    # Every stats key of the timeline has a renderer, and every labelled
+    # family its label set in the registry scripts/lint_metrics.py reads.
+    for key in ph.to_dict():
+        assert key in HISTOGRAMS or key in LABELED_HISTOGRAMS, key
+        if key in LABELED_HISTOGRAMS:
+            name, _, labels = LABELED_HISTOGRAMS[key]
+            assert LABELED_METRICS[name] == labels
+    ph.device_step["chunk"].observe(0.113)
+    ph.device_seen.observe(1)
+    ph.device_seen.observe(0)
+    ph.idle_at_issue.observe(0)
+    ph.fetch_hists["1"].observe(0.0011)
+    text = render_metrics(ph.to_dict())
+    for line in (
+            'vdt:step_device_seconds_count{shape="chunk"} 1',
+            'vdt:step_device_seconds_bucket{shape="chunk",le="0.115"} 1',
+            'vdt:step_device_seconds_bucket{shape="chunk",le="0.11"} 0',
+            'vdt:step_device_seconds_count{shape="decode"} 0',
+            'vdt:step_device_seen_bucket{le="0"} 1',
+            "vdt:step_device_seen_count 2",
+            "vdt:step_device_seen_sum 1",
+            'vdt:step_device_idle_at_issue_bucket{le="0"} 1',
+            'vdt:step_fetch_seconds_count{ready="1"} 1',
+            'vdt:step_fetch_seconds_bucket{ready="1",le="0.00125"} 1',
+            'vdt:step_fetch_seconds_count{ready="0"} 0',
+            'vdt:step_phase_cpu_seconds_count{phase="other"} 0'):
+        assert line in text, line
+    # The six series nothing read are gone from the exposition.
+    for gone in ("loop", "schedule", "update_states", "device_wait",
+                 "finish", "update"):
+        assert f'vdt:step_phase_cpu_seconds_count{{phase="{gone}"}}' \
+            not in text
+        assert f'vdt:step_phase_seconds_count{{phase="{gone}"}}' in text
+    # Read by quantile: neighbours at most 1.25x apart under 200 ms, from
+    # 1 ms to 1 s; the phase families, read by their means, keep theirs.
+    edges = DEVICE_STEP_BUCKETS
+    assert edges[0] == 0.001 and edges[-1] == 1.0
+    assert all(b / a <= 1.25 + 1e-9 for a, b in zip(edges, edges[1:])
+               if b <= 0.2)
+    assert ph.fetch_hists["0"].buckets == edges
+    assert ph.hists["device_wait"].buckets == STEP_PHASE_BUCKETS
+
+
+# Observed before the window and inside it, seconds (flags: 0 / 1).
+_RECORDED = {
+    "idle": ([1, 1], [1, 0, 0, 0]),
+    "seen": ([0, 0], [1, 1, 1, 0]),
+    # 4 in (16 ms, 17 ms]: rank 2 of 4 lies halfway through it.
+    "decode": ([0.3], [0.0165] * 4),
+    # 2 in (110 ms, 115 ms], one a bucket up: rank 1.5 of 3.
+    "chunk": ([0.9], [0.113, 0.113, 0.118]),
+    # 6 in (1 ms, 1.25 ms].
+    "ready": ([0.5], [0.0011] * 6),
+}
+_READ = {
+    "engine.device_found_idle_share": 25.0,
+    "engine.device_step_seen_share": 75.0,
+    "engine.device_decode_step_ms_p50": 16.5,
+    "engine.device_chunk_step_ms_p50": 110 + 5 * 1.5 / 2,
+    "runner.fetch_ready_p50_ms": 1.125,
+}
+
+
+def _recorded_scrape(upto: int) -> list:
+    from benchmarks import metrics
+    ph = StepPhases()
+    hists = {"idle": ph.idle_at_issue, "seen": ph.device_seen,
+             "decode": ph.device_step["decode"],
+             "chunk": ph.device_step["chunk"],
+             "ready": ph.fetch_hists["1"]}
+    for key, h in hists.items():
+        for part in _RECORDED[key][:upto]:
+            for v in part:
+                h.observe(v)
+    return metrics.parse_prometheus(render_metrics(ph.to_dict()))
+
+
+@pytest.mark.parametrize("name", list(_READ))
+def test_the_five_reader_files_read_two_recorded_scrapes(name):
+    import json
+    import os
+
+    from benchmarks import layers, metrics
+
+    def ctx(before, after):
+        return layers.Context(before=before, after=after, gauges=[],
+                              client={}, trace_dir="/nonexistent")
+
+    got = layers.evaluate_all([{"name": name}],
+                              ctx(_recorded_scrape(1), _recorded_scrape(2)))
+    assert got == {name: pytest.approx(_READ[name])}
+    # Nothing observed inside the window, and a program without the
+    # family (the parent commit): no value, no error.
+    assert layers.evaluate_all(
+        [{"name": name}],
+        ctx(_recorded_scrape(2), _recorded_scrape(2))) == {name: None}
+    parent = metrics.parse_prometheus("vdt:step_period_seconds_count 7\n")
+    assert layers.evaluate_all([{"name": name}],
+                               ctx(parent, parent)) == {name: None}
+    # Its manifest entry: appended, a program counter, listed only where
+    # the end-to-end metric it moves is reported.
+    repo = os.path.normpath(os.path.join(os.path.dirname(__file__), "..",
+                                         ".."))
+    with open(os.path.join(repo, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert [n for n in names if n in _READ] == [
+        "engine.device_found_idle_share",
+        "engine.device_decode_step_ms_p50",
+        "engine.device_step_seen_share", "runner.fetch_ready_p50_ms",
+        "engine.device_chunk_step_ms_p50"]
+    entry = manifest["per_layer"][names.index(name)]
+    cells = [w["name"] for w in manifest["workloads"]]
+    reports = {e["name"]: e.get("workloads", cells)
+               for e in manifest["end_to_end"]}
+    assert set(entry["workloads"]) <= set(reports[entry["moves"]])
+    assert entry["source"] == "program_counter"
+
+
+def test_the_timeline_check_holds_the_hosts_answers_to_a_device_plane():
+    """scripts/device_timeline_check.py on a capture written by hand:
+    three steps on the device, the host right about two issues and two
+    fetches and wrong about one of each."""
+    import importlib.util
+    import os
+    path = os.path.normpath(os.path.join(
+        os.path.dirname(__file__), "..", "..", "scripts",
+        "device_timeline_check.py"))
+    spec = importlib.util.spec_from_file_location("timeline_check", path)
+    check = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(check)
+
+    def step(t, forward=0.010, sampler=0.002):
+        return [("jit_forward(123)", t, forward),
+                ("jit_gather(7)", t + forward, 0.0),
+                ("jit_sample(45)", t + forward, sampler)]
+
+    modules = [("jit_add(1)", 0.990, 0.001)] + step(1.000) + step(1.012) \
+        + step(1.040) + [("jit__state_row_to_pool(9)", 1.052, 0.001)]
+    ops = [("fusion.1", s, d) for _, s, d in modules if d]
+
+    def issue(t, step, shape, idle):
+        return (t, 0.0005, {"step": step, "shape": shape, "t_bucket": 16,
+                            "found_idle": idle, "_r": 1})
+
+    issued = [issue(0.999, 1, "decode", 1),     # idle: right
+              issue(1.005, 2, "decode", 0),     # forward 1 runs: right
+              issue(1.039, 3, "chunk", 0),      # idle since 1.024: WRONG
+              issue(2.000, 4, "decode", 1)]     # past the capture
+    waits = [(1.006, 0.006, {"step": 2, "ready": 0, "issued": 1}),
+             (1.030, 0.001, {"step": 3, "ready": 1, "issued": 2}),
+             (1.045, 0.001, {"step": 4, "ready": 1, "issued": 3}),  # WRONG
+             (1.046, 0.001, {"step": 4}),                # a burst's wait
+             (2.100, 0.001, {"step": 5, "ready": 0, "issued": 4})]
+    got = check.timeline(ops, modules, issued, waits)
+    assert got["issues"] == 3
+    assert got["found_idle"]["agree"] == 2
+    assert got["found_idle"]["share"] == pytest.approx(66.67)
+    assert got["found_idle"]["host_said_idle"] == 1
+    assert [d["step"] for d in got["found_idle"]["disagree"]] == [3]
+    assert got["ready"]["events"] == 3 and got["ready"]["agree"] == 2
+    assert got["ready"]["disagree"][0]["issued"] == 3
+    assert got["ready"]["disagree"][0]["sampler_end_before_fetch_ms"] \
+        == pytest.approx(-7.0)
+    assert got["shapes"]["decode"] == {
+        "steps_in_capture": 2, "span_ms_p50": pytest.approx(12.0),
+        "period_ms_p50": pytest.approx(12.0)}      # one period: step 2's
+    assert got["shapes"]["chunk"] == {
+        "steps_in_capture": 1, "span_ms_p50": pytest.approx(12.0),
+        "period_ms_p50": pytest.approx(28.0)}
+    # Beside the run's own medians, where the window's scrapes are given.
+    before, after = _recorded_scrape(1), _recorded_scrape(2)
+    got = check.timeline(ops, modules, issued, waits, (before, after))
+    assert got["shapes"]["decode"]["host_ms_p50"] == pytest.approx(16.5)
+    assert got["shapes"]["decode"]["host_steps_seen"] == 4
+    assert got["shapes"]["decode"]["host_over_device_pct"] \
+        == pytest.approx(37.5)
+    assert got["found_idle_share_window"] == 25.0
+    assert got["seen_share_window"] == 75.0
+    # A capture of the parent (no such events): nothing judged, no error.
+    assert check.timeline(ops, modules, [], [])["found_idle"]["share"] \
+        is None
+    # The capture's two clocks, 2 ms apart (the chip's captures read 0.8
+    # and 2.1): the offset is read off the waited fetches' returns, less
+    # what the copies cost (the ready fetches' length), and the verdicts
+    # are the same.
+    ahead = 0.002
+    late = [(t + ahead, d, st) for t, d, st in issued]
+    copies = [(1.2 + k / 100 + ahead, 0.001, {"ready": 1, "issued": 90 + k})
+              for k in range(5)]
+    # ... a waited fetch returns a copy's length after its sampler's end.
+    shifted = [(t + ahead, d + (0.001 if not st.get("ready", 1) else 0),
+                st) for t, d, st in waits] + copies
+    moved = check.timeline(ops, modules, late, shifted)
+    assert moved["host_clock_ahead_ms"] == pytest.approx(2.0)
+    assert moved["found_idle"]["agree"] == 2 and moved["issues"] == 3
+    assert moved["ready"]["events"] == 3 and moved["ready"]["agree"] == 2
+    assert check.timeline(ops, modules, issued, waits)[
+        "host_clock_ahead_ms"] == 0.0

@@ -27,6 +27,15 @@ holds the batches that are on the device, for two overlap modes:
 On TPU the overlap itself comes from JAX async dispatch — the runner's
 dispatch half enqueues programs without blocking; the queue's job is to
 keep the host from blocking and the scheduler's grant state coherent.
+
+What the queue tells the timeline (metrics/stats.py ``StepPhases``) is
+the HOST's bookkeeping only: ``forward_queued`` says that a forward was
+issued behind the one being fetched, not that the chip still had work,
+so ``host_gap`` reads 0 on every pipelined step. Whether the chip had
+run dry when a forward was issued, whether a fetch waited, and from
+these how long the chip took for each step, the runner asks of the
+device where the work happens (``forward_issue`` and ``fetch``:
+``jax.Array.is_ready()``, which does not block).
 """
 
 import os
@@ -139,10 +148,8 @@ class EngineCore:
         # Peak in-flight depth (tests/metrics: proves overlap happened).
         self.max_concurrent_batches = 0
         # Overlap observability: dispatches issued while another batch
-        # was already in flight, and the host gap between a wait_model
-        # return and the next dispatch (the time the device sits idle
-        # waiting on host scheduling/input prep — the async path exists
-        # to drive this toward zero).
+        # was already in flight, and (pipeline parallelism's queue) the
+        # host gap between a wait_model return and the next dispatch.
         self.steps_dispatched = 0
         self.steps_overlapped = 0
         self._last_wait_done: Optional[float] = None

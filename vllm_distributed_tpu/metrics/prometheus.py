@@ -136,12 +136,15 @@ HISTOGRAMS = {
         "vdt:step_host_gap_seconds",
         "Host gap between the return of a step's fetch and the launch "
         "of the next step's forward (batch queue: wait_model return to "
-        "next dispatch): the device's idle time per step as the host "
-        "clock sees it"),
+        "next dispatch), by the HOST's bookkeeping: 0 wherever the next "
+        "forward was issued before the fetch began, which is every "
+        "pipelined step, whether or not that forward had already ended "
+        "(vdt:step_device_idle_at_issue asks the device)"),
     "step_period_seconds": (
         "vdt:step_period_seconds",
-        "Step start to next step start while the engine has work "
-        "(synchronous path); the step phases sum to it"),
+        "Step start to next step start while the engine has work (the "
+        "synchronous and the pipelined step); the step phases sum to "
+        "it"),
     "step_host_stall_seconds": (
         "vdt:step_host_stall_seconds",
         "Per step, wall time outside the device wait less the "
@@ -151,8 +154,20 @@ HISTOGRAMS = {
     "step_cpu_seconds": (
         "vdt:step_cpu_seconds",
         "The engine-core thread's CPU seconds (time.thread_time()) "
-        "between two step starts, one observation a period; the phases' "
-        "CPU seconds sum to it"),
+        "between two step starts, one observation a period; "
+        "vdt:step_phase_cpu_seconds prepare_inputs, launch and other "
+        "sum to it"),
+    "step_device_seen": (
+        "vdt:step_device_seen",
+        "Per forward, 1 where vdt:step_device_seconds observed it (the "
+        "host saw both ends of it on the device), else 0: the mean is "
+        "the share of the window's forwards that family covers"),
+    "step_device_idle_at_issue": (
+        "vdt:step_device_idle_at_issue",
+        "Per forward, 1 where at its issue nothing was in flight or the "
+        "forward in flight had already ended (is_ready() of the array "
+        "its fetch waits for), else 0: the mean is the share of "
+        "forwards issued to a chip that had run dry"),
     "input_queue_seconds": (
         "vdt:input_queue_seconds",
         "A request on the transport: the front end's put to the "
@@ -213,15 +228,31 @@ LABELED_HISTOGRAMS = {
         "Wall seconds per engine-core step phase", ("phase", )),
     "step_phase_cpu_seconds": (
         "vdt:step_phase_cpu_seconds",
-        "The engine-core thread's CPU seconds per step phase, read "
-        "where the wall clock is: a phase's wall less its CPU seconds "
-        "is what the thread waited there (GIL, a core, the runtime)",
-        ("phase", )),
+        "The engine-core thread's CPU seconds in prepare_inputs and in "
+        "launch (wall less CPU seconds of the same phase is what the "
+        "thread waited there: GIL, a core, the runtime) and in other, "
+        "the rest of a period by subtraction: the three sum to "
+        "vdt:step_cpu_seconds (dispatch, wait: pipeline parallelism's "
+        "own blocks)", ("phase", )),
     "step_kind_seconds": (
         "vdt:step_kind_seconds",
         "Step period and device wait (part) told apart by the kind of "
-        "step: mixed = the grant held a prefill token, decode = none "
-        "(synchronous path)", ("kind", "part")),
+        "step: mixed = the batch whose results the step fetched held a "
+        "prefill token, decode = none", ("kind", "part")),
+    "step_device_seconds": (
+        "vdt:step_device_seconds",
+        "The chip's time for one forward and its sampler on the host's "
+        "clock, where the host saw both ends: from its issue if that "
+        "found the chip idle, else from the end of the forward before "
+        "it, to the return of a fetch that truly waited; by shape: "
+        "decode = no prefill token, chunk = a prefill token in the "
+        "largest token bucket, mixed = any other", ("shape", )),
+    "step_fetch_seconds": (
+        "vdt:step_fetch_seconds",
+        "Fetch of a forward's results, by whether they were there when "
+        "it began (is_ready()): ready 1 = what the copies cost with "
+        "nothing to wait for, ready 0 = a true wait for the device",
+        ("ready", )),
     "step_tokens": (
         "vdt:step_tokens",
         "Tokens granted per non-empty step, by kind (prefill = requests "
@@ -246,6 +277,8 @@ LABELED_METRICS = {
     "vdt:step_phase_seconds": ("phase", ),
     "vdt:step_phase_cpu_seconds": ("phase", ),
     "vdt:step_kind_seconds": ("kind", "part"),
+    "vdt:step_device_seconds": ("shape", ),
+    "vdt:step_fetch_seconds": ("ready", ),
     "vdt:step_tokens": ("kind", ),
     "vdt:step_attn_pairs": ("layer_kind", ),
     # Two kinds of K/V in one manager (core/kv_cache_manager.py).

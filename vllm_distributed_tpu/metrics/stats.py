@@ -35,10 +35,26 @@ HOST_GAP_BUCKETS = (0.0002, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.02,
                     0.05, 0.1, 0.25, 1.0)
 # Engine-core step phases (schedule / prepare_inputs / dispatch /
 # device wait / update_from_output): microseconds for the host control
-# plane up to seconds for a first-compile device wait.
+# plane up to seconds for a first-compile device wait. Edges a factor
+# 2 to 2.5 apart: for families read by their MEANS (a quantile read off
+# them is a bucket's middle wherever a window's values share a bucket;
+# what is read by quantile takes DEVICE_STEP_BUCKETS or
+# QUEUE_LAG_BUCKETS).
 STEP_PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
                       0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                       5.0, 10.0)
+# One step on the device, and a fetch of its results (families read by
+# quantile): 1 ms to 1 s, neighbours at most a factor 1.25 apart under
+# 200 ms and a tenth or less where the cells' steps lie (8 to 200 ms),
+# so that an interpolated median is within a few percent of the value.
+DEVICE_STEP_BUCKETS = tuple(ms / 1000 for ms in (
+    1, 1.25, 1.5, 1.75, 2, 2.5, 3, 3.5, 4, 4.5, 5, 5.5, 6, 6.5, 7, 7.5, 8,
+    8.5, 9, 9.5, 10, 11, 12, 13, 14, 15, 16, 17, 18, 19, 20, 22, 24, 26,
+    28, 30, 32, 34, 36, 38, 40, 44, 48, 52, 56, 60, 65, 70, 75, 80, 85,
+    90, 95, 100, 105, 110, 115, 120, 125, 130, 140, 150, 160, 170, 180,
+    190, 200, 250, 320, 400, 500, 640, 800, 1000))
+# A yes or no of one step, observed as 0 or 1: the mean is the share.
+FLAG_BUCKETS = (0, 1)
 # Waits read by quantile (a request's wait for its first grant, a step's
 # outputs on their way to the request queues): 0.25 ms to 2 s, at most a
 # factor 2 apart below 100 ms so that a median is resolved.
@@ -150,11 +166,13 @@ class span:
 class _PhaseSpan(span):
     """A span of a ``StepPhases`` timeline. Inside a step of the one
     timeline (``sync``: every step but pipeline parallelism's) a phase
-    starts where the timeline's last phase ended, on the wall clock
-    (``mark``) and on the thread's CPU clock (``cpu_mark``), so that the
-    phases leave no hole between them on either."""
+    starts where the timeline's last phase ended (``mark``), so that the
+    phases leave no hole between them. The thread's CPU clock is read
+    only at the ends that an account needs (``StepPhases.cpu_hists`` and
+    the fetch, for ``host_stall``): a read costs microseconds on a
+    sandboxed host."""
 
-    __slots__ = ("_phases", "_phase", "_chained", "_cpu_start")
+    __slots__ = ("_phases", "_phase", "_chained", "_timed", "_cpu_start")
 
     def __init__(self, phases: "StepPhases", name: str,
                  phase: Optional[str], attrs: dict) -> None:
@@ -165,65 +183,124 @@ class _PhaseSpan(span):
 
     def __enter__(self) -> "span":
         super().__enter__()
-        if self._phase is None:
+        phase, phases = self._phase, self._phases
+        if phase is None:
             return self
-        phases = self._phases
         self._chained = phases.sync and not phases.idle
+        self._timed = phase in phases.cpu_hists
         if self._chained:
+            if phase == "device_wait":
+                phases.fetch_begins(self.start)
             self.start = phases.mark
-            self._cpu_start = phases.cpu_mark
-            if self._phase == "device_wait":
-                phases.fetch_begins()
-        else:
+            if self._timed:
+                self._cpu_start = phases.cpu_at_mark()
+        elif self._timed:
             self._cpu_start = time.thread_time()
         return self
 
     def __exit__(self, *exc) -> None:
         super().__exit__(*exc)
-        if self._phase is None:
+        phase, phases = self._phase, self._phases
+        if phase is None:
             return
-        phases = self._phases
-        cpu = time.thread_time()
-        phases.cpu_hists[self._phase].observe(cpu - self._cpu_start)
-        phases.mark, phases.cpu_mark = self.end, cpu
-        if self._chained and self._phase == "device_wait":
+        phases.mark = self.end
+        fetched = self._chained and phase == "device_wait"
+        if self._timed or fetched:
+            cpu = time.thread_time()
+            phases.cpu_mark, phases.cpu_mark_at = cpu, self.end
+            if self._timed:
+                spent = cpu - self._cpu_start
+                phases.cpu_hists[phase].observe(spent)
+                if self._chained:
+                    phases.cpu_named += spent
+        if fetched:
             phases.fetch_done(self.end - self.start)
 
 
+class _Flight:
+    """One forward between its issue and its fetch, as the host saw it
+    (``StepPhases``, the device's timeline): the array its fetch will
+    wait for, its ``shape``, the issue's stamp where the issue found
+    the chip idle (else ``after``, the forward it queued behind), and
+    ``end``, the fetch's return where that fetch truly waited."""
+
+    __slots__ = ("array", "step", "shape", "start", "after", "ready",
+                 "end")
+
+    def __init__(self, step: int, shape: str, start: Optional[float],
+                 after: Optional["_Flight"]) -> None:
+        self.array = None
+        self.step, self.shape = step, shape
+        self.start, self.after = start, after
+        self.ready = False
+        self.end: Optional[float] = None
+
+
 class StepPhases:
-    """Where the time of the engine-core thread goes, step by step: one
-    object per engine core, shared with its model runner.
+    """Where the time of the engine-core thread goes, step by step, and
+    what the chip did meanwhile as far as the host can know: one object
+    per engine core, shared with its model runner.
 
     On the one timeline (``sync``: the synchronous step and the
     pipelined step, engine/core.py) the phases ``loop, schedule,
     update_states, prepare_inputs, launch, device_wait, finish, update``
     partition the thread's time between two step starts: each starts at
     ``mark``, the end of the one before it, so they neither overlap nor
-    leave holes, and their sums add up to the sum of ``period``. The
-    thread's CPU time (``time.thread_time()``, read where ``mark`` is) is
-    partitioned the same way: ``cpu_hists`` sum to ``step_cpu``, and a
-    phase's wall time less its CPU time is what the thread waited in it
-    (for the GIL, a core, the runtime). A pipelined step runs the same
-    phases in the same order, the first five for the batch it launches
-    and the last three for the batch it retires, the one launched a step
-    earlier: one ``vdt_step`` and one period a forward either way. Under
-    pipeline parallelism (``sync`` false) every phase times its own
-    block on both clocks, ``dispatch`` and ``wait`` contain the runner's
-    phases, and there is no period.
+    leave holes, and their sums add up to the sum of ``period``. A
+    pipelined step runs the same phases in the same order, the first
+    five for the batch it launches and the last three for the batch it
+    retires, the one launched a step earlier: one ``vdt_step`` and one
+    period a forward either way. Under pipeline parallelism (``sync``
+    false) every phase times its own block, ``dispatch`` and ``wait``
+    contain the runner's phases, and there is no period.
 
-    ``host_gap`` estimates the device's idle time per step on the host's
-    clock, once a fetch: from the fetch's return to the issue of the next
-    forward, or 0 where that forward was issued before the fetch began
-    (``forward_queued``, which the pipelined step sets: the device went
-    from one forward to the next without the host). ``host_stall`` is the
-    time this thread wanted to run and did not: the phases' wall time
-    less their CPU time, summed from one fetch's return to the next
-    fetch's start (every phase but ``device_wait``). Near zero, the host
-    time of a step is this thread's own work; several milliseconds,
-    another thread held the GIL, the core was taken, or a launch blocked
-    inside the runtime. Read the CPU accounts by their means:
+    The thread's CPU time (``time.thread_time()``) is partitioned in
+    three: ``cpu_hists`` ``prepare_inputs``, ``launch`` and ``other``
+    sum to ``step_cpu``, ``other`` being ``step_cpu`` less the two by
+    subtraction, once a period. The clock is read five times a step (the
+    step's start, both ends of ``prepare_inputs``, the end of ``launch``
+    where the fetch starts, the fetch's return) and not at every phase's
+    end: a read costs 6 us on the chip's host. Under pipeline
+    parallelism ``prepare_inputs``, ``launch``, ``dispatch`` and
+    ``wait`` read it around their own blocks and nothing sums.
+    ``host_stall`` is the time this thread wanted to run and did not:
+    wall time less CPU time from one fetch's return to the next fetch's
+    start (every phase but ``device_wait``). Near zero, the host time of
+    a step is this thread's own work; several milliseconds, another
+    thread held the GIL, the core was taken, or a launch blocked inside
+    the runtime. Read the CPU accounts by their means:
     ``time.thread_time()`` may advance in ticks far longer than a phase,
     so one observation says little.
+
+    The device's timeline, forward by forward, from two questions that
+    do not block (``jax.Array.is_ready()`` of the array a fetch waits
+    for: the sampled tokens, or the verifier's first output). At the
+    ISSUE of forward N (``forward_issue``): is nothing in flight, or is
+    the forward in flight done already? Then the chip had run dry
+    (``idle_at_issue`` 1) and N starts at its issue; else N starts where
+    the forward before it ends. Just before N's FETCH (``fetch``): is
+    N's array there already (``ready``)? Then the fetch only copies
+    (``fetch_hists["1"]``: what ``_fetch_sample`` costs) and N's end was
+    not seen; else the fetch waited (``fetch_hists["0"]``) and its
+    return IS N's end on the device. Where both ends of N are known,
+    ``device_step[shape]`` observes ``end - start``, the chip's time for
+    the forward and its sampler on the host's clock, and ``device_seen``
+    1; else ``device_seen`` 0. A device-bound engine sees nearly every
+    step; a CPU-bound one nearly none, and says so by ``idle_at_issue``.
+    ``shape`` is the runner's: ``decode`` (no prefill token), ``chunk``
+    (a prefill token in the largest token bucket the configuration
+    compiles), ``mixed`` (any other step with a prefill token). The
+    synchronous step obeys the same rules (every issue finds the chip
+    idle); pipeline parallelism and the multi-step burst observe
+    nothing.
+
+    ``host_gap`` is the HOST's bookkeeping of the same gap and says less
+    since the pipelined step: from a fetch's return to the issue of the
+    next forward, or 0 where that forward was issued before the fetch
+    began (``forward_queued``, which the pipelined step sets from its
+    queue). It reads 0 on every pipelined step whether or not the
+    forward in the queue had already ended; ``idle_at_issue`` is the
+    question asked of the device.
 
     ``kind`` is ``mixed`` for a step whose batch holds a prefill token
     and ``decode`` otherwise; ``kind_hists`` split the period and the
@@ -237,13 +314,17 @@ class StepPhases:
     PHASES = ("loop", "schedule", "update_states", "prepare_inputs",
               "launch", "device_wait", "finish", "update", "dispatch",
               "wait")
+    # The phases whose CPU seconds something reads (``other`` is no
+    # phase: the rest of a period, by subtraction).
+    CPU_PHASES = ("prepare_inputs", "launch", "dispatch", "wait")
     KINDS = ("decode", "mixed")
+    SHAPES = ("decode", "mixed", "chunk")
 
     def __init__(self, sync: bool = True) -> None:
         self.hists = {p: Histogram(STEP_PHASE_BUCKETS)
                       for p in self.PHASES}
         self.cpu_hists = {p: Histogram(STEP_PHASE_BUCKETS)
-                          for p in self.PHASES}
+                          for p in self.CPU_PHASES + ("other", )}
         self.period = Histogram(STEP_PHASE_BUCKETS)
         self.step_cpu = Histogram(STEP_PHASE_BUCKETS)
         self.kind_hists = {f"{k}/{part}": Histogram(STEP_PHASE_BUCKETS)
@@ -252,6 +333,12 @@ class StepPhases:
         self.host_gap = Histogram(HOST_GAP_BUCKETS)
         self.host_stall = Histogram(HOST_GAP_BUCKETS)
         self.input_queue = Histogram(QUEUE_LAG_BUCKETS)
+        self.device_step = {s: Histogram(DEVICE_STEP_BUCKETS)
+                            for s in self.SHAPES}
+        self.device_seen = Histogram(FLAG_BUCKETS)
+        self.idle_at_issue = Histogram(FLAG_BUCKETS)
+        self.fetch_hists = {r: Histogram(DEVICE_STEP_BUCKETS)
+                            for r in ("0", "1")}
         self.sync = sync
         self.step = 0      # number of the current (or last) vdt_step
         self.idle = True   # the last step left no work behind
@@ -260,13 +347,18 @@ class StepPhases:
         # comes next (set by the pipelined step before each fetch).
         self.forward_queued = False
         self.mark = 0.0    # end of the last phase of this busy period
-        self.cpu_mark = 0.0    # the thread's CPU time at ``mark``
+        self.cpu_mark = 0.0    # the thread's CPU time ...
+        self.cpu_mark_at = -1.0    # ... at this value of ``mark``
+        self.cpu_named = 0.0   # prepare_inputs + launch of this period
         self.step_start: Optional[float] = None
         self._cpu_at_step_start = 0.0
         self._fetch_done: Optional[float] = None
         self._cpu_at_fetch_done = 0.0
         self._gap_open = False   # a fetch returned, no forward issued since
         self._loop_span: Optional[span] = None
+        self._newest: Optional[_Flight] = None   # the forward issued last
+        self._fetching: Optional[_Flight] = None
+        self._fetch_start = 0.0
 
     def span(self, name: str, phase: Optional[str] = None,
              **attrs) -> span:
@@ -274,12 +366,18 @@ class StepPhases:
         that phase's histograms as well."""
         return _PhaseSpan(self, name, phase, attrs)
 
+    def cpu_at_mark(self) -> float:
+        """The thread's CPU time at ``mark``: read now, unless the phase
+        that ended there read it."""
+        if self.cpu_mark_at != self.mark:
+            self.cpu_mark, self.cpu_mark_at = time.thread_time(), self.mark
+        return self.cpu_mark
+
     def resume(self) -> None:
         """Work arrived at an idle engine: the wait for it belongs to no
         phase, period, gap or stall."""
         self.idle = False
         self.mark = time.perf_counter()
-        self.cpu_mark = time.thread_time()
         self.step_start = self._fetch_done = None
         self._gap_open = False
 
@@ -294,15 +392,19 @@ class StepPhases:
         if self.sync:
             now, cpu = time.perf_counter(), time.thread_time()
             self.hists["loop"].observe(now - self.mark)
-            self.cpu_hists["loop"].observe(cpu - self.cpu_mark)
-            self.mark, self.cpu_mark = now, cpu
+            self.mark = self.cpu_mark_at = now
+            self.cpu_mark = cpu
             if self.step_start is not None:
                 # ``kind`` is still that of the step that ended.
                 period = now - self.step_start
                 self.period.observe(period)
                 self.kind_hists[f"{self.kind}/period"].observe(period)
-                self.step_cpu.observe(cpu - self._cpu_at_step_start)
+                step_cpu = cpu - self._cpu_at_step_start
+                self.step_cpu.observe(step_cpu)
+                self.cpu_hists["other"].observe(
+                    step_cpu - self.cpu_named)
             self.step_start, self._cpu_at_step_start = now, cpu
+            self.cpu_named = 0.0
         self.step += 1
         return StepTraceAnnotation("vdt_step", step_num=self.step)
 
@@ -319,28 +421,88 @@ class StepPhases:
             self._gap_open = False
             self.host_gap.observe(time.perf_counter() - self._fetch_done)
 
-    def fetch_begins(self) -> None:
-        """A step's ``device_wait`` starts at ``mark``:
-        what the phases since the last fetch's return waited. Not held
-        at zero: where the thread's CPU clock advances in ticks (10 ms
-        on a sandboxed host) one step's reading is a tick too high or
-        too low, and only an unclamped sum keeps the mean unbiased and
-        equal to the phases' wall less CPU."""
+    def forward_issue(self, shape: str, t_bucket: int) -> span:
+        """Around the dispatch of a forward and its sampler (the span
+        ``vdt.forward_issue``): closes the host's gap and asks whether
+        the chip has anything left to run (``found_idle``). The caller
+        hands the array that this forward's fetch will wait for to
+        ``in_flight`` once the dispatch has returned it."""
+        self.launch_issued()
+        attrs = {}
+        if self.sync:
+            before = self._newest
+            found_idle = (before is None or before.array is None
+                          or before.array.is_ready())
+            self.idle_at_issue.observe(int(found_idle))
+            self._newest = _Flight(
+                self.step, shape,
+                time.perf_counter() if found_idle else None,
+                None if found_idle else before)
+            attrs["found_idle"] = int(found_idle)
+        return span("vdt.forward_issue", step=self.step, shape=shape,
+                    t_bucket=t_bucket, **attrs)
+
+    def in_flight(self, array) -> Optional[_Flight]:
+        """The forward just issued, for its handle (``fetch`` takes it
+        back); ``None`` under pipeline parallelism."""
+        if not self.sync:
+            return None
+        self._newest.array = array
+        return self._newest
+
+    def fetch(self, flight: Optional[_Flight]) -> span:
+        """The ``device_wait`` phase of a forward that ``in_flight``
+        returned: asks first whether its results are there already
+        (``ready``: the fetch copies and does not wait). ``issued`` is
+        the step whose ``vdt.forward_issue`` this fetch retires."""
+        attrs = {}
+        if flight is not None and flight.array is not None:
+            flight.ready = bool(flight.array.is_ready())
+            attrs = {"ready": int(flight.ready), "issued": flight.step}
+            self._fetching = flight
+        return _PhaseSpan(self, "vdt.device_wait", "device_wait", attrs)
+
+    def fetch_begins(self, now: float) -> None:
+        """A step's ``device_wait`` starts at ``mark`` (``now`` on the
+        clock: the block's own start): what the phases since the last
+        fetch's return waited. Not held at zero: where the thread's CPU
+        clock advances in ticks (10 ms on a sandboxed host) one step's
+        reading is a tick too high or too low, and only an unclamped sum
+        keeps the mean unbiased and equal to the phases' wall less
+        CPU."""
+        self._fetch_start = now
+        cpu = self.cpu_at_mark()
         if self._fetch_done is not None:
             self.host_stall.observe(
                 (self.mark - self._fetch_done)
-                - (self.cpu_mark - self._cpu_at_fetch_done))
+                - (cpu - self._cpu_at_fetch_done))
 
     def fetch_done(self, waited: float) -> None:
-        """... and has ended at ``mark``, after ``waited`` seconds. The
-        device idles from here to the next forward's issue, unless that
-        forward is queued already: no gap then."""
+        """... and has ended at ``mark``, after ``waited`` seconds. By
+        the host's bookkeeping the device idles from here to the next
+        forward's issue, unless that forward is queued already: no gap
+        then. Where the fetch was of a forward in flight, its return is
+        that forward's end on the device if it truly waited."""
         self._fetch_done = self.mark
         self._cpu_at_fetch_done = self.cpu_mark
         self._gap_open = not self.forward_queued
         if self.forward_queued:
             self.host_gap.observe(0.0)
         self.kind_hists[f"{self.kind}/device_wait"].observe(waited)
+        flight, self._fetching = self._fetching, None
+        if flight is None:
+            return
+        self.fetch_hists["1" if flight.ready else "0"].observe(
+            self.mark - self._fetch_start)
+        if not flight.ready:
+            flight.end = self.mark
+        start = (flight.start if flight.after is None
+                 else flight.after.end)
+        seen = flight.end is not None and start is not None
+        if seen:
+            self.device_step[flight.shape].observe(flight.end - start)
+        self.device_seen.observe(int(seen))
+        flight.array = flight.after = None
 
     def to_dict(self) -> dict:
         """The stats-feed entries of this timeline."""
@@ -356,6 +518,12 @@ class StepPhases:
             "step_host_gap_seconds": self.host_gap.to_dict(),
             "step_host_stall_seconds": self.host_stall.to_dict(),
             "input_queue_seconds": self.input_queue.to_dict(),
+            "step_device_seconds": {s: h.to_dict()
+                                    for s, h in self.device_step.items()},
+            "step_device_seen": self.device_seen.to_dict(),
+            "step_device_idle_at_issue": self.idle_at_issue.to_dict(),
+            "step_fetch_seconds": {r: h.to_dict()
+                                   for r, h in self.fetch_hists.items()},
         }
 
 

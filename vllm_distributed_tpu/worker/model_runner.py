@@ -31,7 +31,7 @@ from vllm_distributed_tpu.core.sched.output import (ModelRunnerOutput,
                                                     SchedulerOutput)
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.metrics.stats import (ATTN_PAIR_BUCKETS,
-                                                STEP_PHASE_BUCKETS,
+                                                DEVICE_STEP_BUCKETS,
                                                 STEP_REQUEST_BUCKETS,
                                                 STEP_TOKEN_BUCKETS,
                                                 STEP_TRANSFER_BUCKETS,
@@ -207,7 +207,7 @@ class TPUModelRunner:
         from vllm_distributed_tpu.metrics import telemetry
         self._device_telemetry = telemetry.device_telemetry_enabled()
         self._telemetry = telemetry.current_recorder()
-        self.device_wait_hist = Histogram(STEP_PHASE_BUCKETS)
+        self.device_wait_hist = Histogram(DEVICE_STEP_BUCKETS)
         self.num_recompiles = 0
 
         # Speculative decoding (ngram drafts verified in-step; reference:
@@ -1759,11 +1759,14 @@ class TPUModelRunner:
             if (self.spec_k and ext_md is None and vocab_mask is None):
                 spec_q = (self._h2d(drafts_arr), self._h2d(q_ids),
                           self._h2d(q_probs), spec_truncate)
-            ph.launch_issued()
-            dev = self._launch_device_step(inputs, logits_indices,
-                                           sampling_packed, fwd_shape,
-                                           ext_md, want_topk, vocab_mask,
-                                           plp=plp, spec_q=spec_q)
+            with ph.forward_issue(*self._step_shape(scheduler_output)):
+                dev = self._launch_device_step(
+                    inputs, logits_indices, sampling_packed, fwd_shape,
+                    ext_md, want_topk, vocab_mask, plp=plp, spec_q=spec_q)
+            # What the fetch will wait for: the sampled tokens, or the
+            # verifier's first output.
+            flight = ph.in_flight(dev[0][0] if spec_q is not None
+                                  else dev[0])
             # State snapshots AFTER the forward dispatch: program order on
             # the (donated) cache arrays makes the copy read post-step rows.
             self._apply_state_saves(scheduler_output)
@@ -1773,21 +1776,39 @@ class TPUModelRunner:
             self._apply_kv_tier_post(tier_pending)
             self.host_transfers_hist.observe(self._step_transfers)
             return {"so": scheduler_output, "dev": dev, "kv_meta": kv_meta,
+                    "flight": flight,
                     "sampling_req_ids": sampling_req_ids,
                     "drafts_arr": drafts_arr, "R": R,
                     "specv": spec_q is not None,
                     "plp_meta": plp[2] if plp else None}
+
+    def _step_shape(self, scheduler_output: SchedulerOutput
+                    ) -> tuple[str, int]:
+        """(shape, token bucket) of a step, as StepPhases labels the
+        chip's time for it: ``decode`` without a prefill token, ``chunk``
+        with one in the largest token bucket this configuration compiles
+        (a whole ``max_num_batched_tokens`` step), ``mixed`` otherwise."""
+        t_bucket = pad_to_bucket(
+            scheduler_output.total_num_scheduled_tokens,
+            self.token_buckets)
+        if not scheduler_output.num_prefill_tokens:
+            return "decode", t_bucket
+        return ("chunk" if t_bucket == self.token_buckets[-1]
+                else "mixed"), t_bucket
 
     def wait_model(self, handle: dict) -> ModelRunnerOutput:
         """Blocking half: fetch the sampled tokens, fold them into the
         persistent batch, build the runner output."""
         if "ready" in handle:
             return handle["ready"]
-        # Device-vs-host attribution: this fetch is where the host
-        # blocks on the device (everything since dispatch ran async), so
-        # its duration IS the step's device wait as seen by this worker.
+        # This fetch is where the host blocks on the device (everything
+        # since dispatch ran async). Under the pipelined step the next
+        # forward is already issued when it starts, and its results may
+        # be there already: ``ph.fetch`` asks first (``ready``), so a
+        # wait is told from a copy and a waited fetch's return dates the
+        # step's end on the device (StepPhases).
         ph = self.phases
-        with ph.span("vdt.device_wait", "device_wait") as wait:
+        with ph.fetch(handle.get("flight")) as wait:
             if handle.get("specv"):
                 verify = tuple(np.asarray(jax.device_get(x))
                                for x in handle["dev"][0])
