@@ -68,7 +68,10 @@ for n, spec in enumerate(sys.argv[1:]):
     extra.update({k: (v["value"] if isinstance(v, dict) else v)
                   for k, v in m.items()
                   if k.startswith("runner.") and ("device_wait" in k
-                                                  or "fetch" in k)})
+                                                  or "fetch" in k
+                                                  or "prepare_inputs" in k)
+                  or k.startswith("kernel.") and ("attn_share" in k
+                                                  or "tokens" in k)})
     # The engagement counters, from the window's two /metrics scrapes.
     overlap = {}
     try:

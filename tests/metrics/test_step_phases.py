@@ -223,6 +223,41 @@ def test_kv_tokens_read_of_a_prefill_chunk_and_two_decodes(engine):
         assert family in text, family
 
 
+def test_decode_kv_tokens_walked_of_decodes_beside_a_prefill_chunk(engine):
+    """``vdt:step_decode_kv_tokens_walked``: a step's single-token rows
+    at whole kv blocks up to each one's own end (pages of 4 in blocks of
+    128 here), a prefill chunk's rows left out, observed once a step
+    beside ``vdt:step_kv_tokens_read``."""
+    core = _core(engine)
+    # Ids whose prompts (``_add``) share no first block with another
+    # test's: a prefix-cache hit would move the chunks.
+
+    def walked():
+        stats = core.get_stats()
+        h = stats["step_decode_kv_tokens_walked"]
+        assert h["count"] == stats["step_kv_tokens_read"]["count"]
+        return h["sum"]
+
+    _add(engine, "dw-x", 10, 12)
+    _add(engine, "dw-y", 126, 12)
+    s0 = walked()
+    engine.step()     # dw-x's prompt and 54 tokens of dw-y's: no decode
+    assert walked() - s0 == 0
+    engine.step()     # dw-x at 11, a chunk of 63
+    engine.step()     # dw-x at 12, dw-y's last 9
+    assert walked() - s0 == 2 * 128
+    sums = []
+    for _ in range(4):  # dw-x at 13..16, dw-y at 127, 128, 129, 130
+        engine.step()
+        sums.append(walked())
+    assert np.diff([s0 + 2 * 128] + sums).tolist() == [
+        128 + 128, 128 + 128, 128 + 256, 128 + 256]
+    while engine.has_unfinished_requests():
+        engine.step()
+    assert "vdt:step_decode_kv_tokens_walked_count" in render_metrics(
+        core.get_stats())
+
+
 def _work_token_by_token(rows, window: int):
     """(window tokens read, full pairs, window pairs) of each row, one
     query at a time: the query at position p sees the keys (p - window,

@@ -276,6 +276,38 @@ def test_prefill_tile_features_compile(compile_for_chip, features):
                      sds((G, 4)), bt, layer, feat)
 
 
+@pytest.mark.parametrize("name", [
+    "olmo2-1b", "zaya1-8b", "command-a-plus-ep8",
+    "smallthinker-21b-a3b-window", "smallthinker-21b-a3b-full"])
+def test_decode_groups_compile_with_the_cascade_state(compile_for_chip,
+                                                      name):
+    """The decode group that starts and waits for a slot's page copies
+    only while the slot's own sequence has a kv block left (PR 54), in
+    the call no cell makes: the attention-only launcher with the
+    online-softmax state out, at a decode bucket, for each group width
+    the cells have (4 sequences OLMo-2's, 8 on ZAYA's two K/V heads, 2 at
+    Command A+'s 128 query heads where the per-row columns come from an
+    iota, 8 on SmallThinker's 28 padded to 32), under the window where
+    the configuration has one. The served lattice above holds the fused
+    call at the same widths."""
+    from vllm_distributed_tpu.ops.pallas_attention import (
+        Q_TILE_PAD, num_partition_programs,
+        unified_ragged_paged_attention_pallas)
+    geo = {**GEOMETRIES, **WIDE_GEOMETRIES, **GROUP_OF_SEVEN}[name]
+    t_bucket = 64
+    bq, sb = unified_statics(geo)
+    q, k, v, si, bt, layer = attention_shapes(t_bucket + Q_TILE_PAD, geo)
+    P = num_partition_programs(t_bucket, geo.R, bq=bq, sb=sb)
+
+    def fn(q, k, v, desc, si, dl, bt, layer):
+        return unified_ragged_paged_attention_pallas(
+            q, k, v, desc, si, dl, bt, layer, sm_scale=0.125, bq=bq,
+            sb=sb, interpret=False, emit_state=True, window=geo.window)
+
+    compile_for_chip(fn, q, k, v, sds((P, 3)), si, sds((geo.R, )), bt,
+                     layer)
+
+
 @pytest.mark.parametrize("max_q", [1, 32, 256])
 def test_ragged_attention_compiles(compile_for_chip, max_q):
     """The descriptor-less kernel (multi-step bursts, EAGLE, cascade)."""

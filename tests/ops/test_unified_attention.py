@@ -36,9 +36,9 @@ from vllm_distributed_tpu.ops.attention import (_shared_prefix_state,
                                                 write_kv_pages)
 from vllm_distributed_tpu.ops.pallas_attention import (
     KIND_DECODE, KIND_KV_WRITE, KIND_NOOP, KIND_PREFILL, Q_TILE_PAD,
-    build_partition_descriptor, decode_group_size, num_partition_programs,
-    prefill_tile_size, unified_ragged_paged_attention_pallas,
-    unified_write_attend_pallas)
+    build_partition_descriptor, decode_group_size, decode_kv_tokens_walked,
+    kv_block_pages, num_partition_programs, prefill_tile_size,
+    unified_ragged_paged_attention_pallas, unified_write_attend_pallas)
 
 
 # The cheapest geometry to lower: the smallest tile and group over two
@@ -562,6 +562,111 @@ def test_untrimmed_program_reads_the_whole_table():
 
 
 # ---------------------------------------------------------------------------
+# Decode groups: formed by length, and no page moved past a sequence's end.
+#
+# ``build_partition_descriptor`` cuts the single-token rows into groups
+# longest context first, and a group's slot starts and waits for its page
+# copies only while its own sequence has a kv block left
+# (``_mega_kernel``). Who shares a group changes no row's result (the
+# block-diagonal mask) and a trip a slot sits out adds exactly nothing to
+# it, so the two orders give the same BITS; the slot's buffers then hold
+# what an earlier trip, an earlier program or nobody left there, which is
+# why the poisoned tables below also alias the inactive slots to a table
+# row of NaN pages (interpret mode starts scratch buffers as NaN).
+# ---------------------------------------------------------------------------
+
+# Thirteen single-token sequences of 1 to 512 positions, some equal, over
+# kv blocks of 128 (two pages of 64): 13 divides by none of the widths.
+ORDER_LENS = (1, 5, 130, 512, 9, 257, 128, 2, 384, 300, 129, 130, 9)
+ORDER = dict(seqs=[(1, k) for k in ORDER_LENS], page_size=64,
+             pages_per_req=8, num_q_heads=4, num_kv_heads=2, head_dim=128,
+             bq=8)
+
+
+@pytest.mark.parametrize("sb", [2, 4, 8])
+@pytest.mark.parametrize("window", [0, 200], ids=["full", "window200"])
+def test_length_order_and_arrival_order_give_the_same_bits(window, sb):
+    rng = np.random.default_rng(100 * sb + window)
+    case = build_case(rng, sb=sb, **ORDER)
+    n = len(ORDER_LENS)
+    assert n % sb and n > sb
+    by_length = np.asarray(case["decode_list"])
+    assert (np.diff(np.asarray(ORDER_LENS)[by_length[:n]]) <= 0).all()
+    arrival = by_length.copy()
+    arrival[:n] = np.arange(n)
+    assert (arrival != by_length).any()
+    got = [np.asarray(unified_ragged_paged_attention_pallas(
+        case["q"], case["k_pages"], case["v_pages"], case["desc"],
+        case["seq_info"], jnp.asarray(dl), case["block_tables"],
+        sm_scale=0.125, bq=case["bq"], sb=sb, interpret=True,
+        window=window))[:n] for dl in (by_length, arrival)]
+    np.testing.assert_array_equal(got[0], got[1])
+    want = naive_ragged_attention(
+        case["q"], case["k_pages"], case["v_pages"], case["block_tables"],
+        case["req_idx"], case["q_pos"], sm_scale=0.125, window=window)
+    np.testing.assert_allclose(got[0], np.asarray(want)[:n], rtol=2e-3,
+                               atol=2e-3)
+
+
+# Five single-token rows in groups of two (500 and 390, 260 and 140, 9
+# beside an inactive slot) and two prefill rows.
+ENDED_SEQS = TRIM_SEQS + [(1, 390)]
+
+
+def _ended_case(window: int):
+    """The trimmed-loop geometry with, besides what ``_trimmed_table``
+    poisons in front of a window, every table slot past a sequence's
+    last kv block poisoned, and the inactive slots aliased to a padding
+    row whose whole table row is poisoned (they alias sequence 0 as the
+    builder leaves them: a live row, which proves nothing)."""
+    rng = np.random.default_rng(window + 7)
+    case = build_case(rng, seqs=ENDED_SEQS, page_size=TRIM_PAGE,
+                      pages_per_req=TRIM_PAGES_PER_REQ, num_q_heads=16,
+                      num_kv_heads=1, head_dim=128, bq=8, sb=2)
+    if window:
+        bt, poison, _ = _trimmed_table(case, window)
+    else:
+        bt = case["block_tables_np"].copy()
+        poison = int(bt.max()) + 1
+    ppb = BLK // TRIM_PAGE
+    R = case["num_seqs"]
+    for _, _, kv_len, row in case["seq_info_np"][:R]:
+        bt[row, -(-kv_len // BLK) * ppb:] = poison
+    assert (bt[:R] == poison).sum() >= 5 * ppb
+    bt[R] = poison
+    si = case["seq_info_np"].copy()
+    si[R] = (0, 0, 0, R)
+    dl = np.asarray(case["decode_list"]).copy()
+    n_dec = int((si[:R, 1] == 1).sum())
+    assert n_dec % case["sb"], "no inactive slot"
+    dl[n_dec:] = R
+    case.update(seq_info=jnp.asarray(si), decode_list=jnp.asarray(dl))
+    return case, bt
+
+
+@pytest.mark.parametrize("window", [0, 128], ids=["full", "window128"])
+def test_no_page_moved_past_a_sequences_end_nor_by_an_inactive_slot(window):
+    case, bt = _ended_case(window)
+    static = dict(window=window) if window else {}
+    want = naive_ragged_attention(
+        case["q"], case["k_pages"], case["v_pages"], case["block_tables"],
+        case["req_idx"], case["q_pos"], sm_scale=0.125, **static)
+    got = _run_on_poisoned_table(case, bt, **static)
+    assert np.isfinite(got).all(), "a page nobody can see was moved"
+    np.testing.assert_allclose(got, np.asarray(want)[:case["T"]],
+                               rtol=2e-3, atol=2e-3)
+    # The control: the same table with the last group's inactive slot
+    # counted as live (a kv_len of 0 still fetches one block) brings the
+    # aliased row's NaN into the group.
+    desc = case["desc_np"].copy()
+    last = np.nonzero(desc[:, 0] == KIND_DECODE)[0][-1]
+    assert desc[last, 2] == 1
+    desc[last, 2] = 2
+    case["desc"] = jnp.asarray(desc)
+    assert not np.isfinite(_run_on_poisoned_table(case, bt, **static)).all()
+
+
+# ---------------------------------------------------------------------------
 # Descriptor builder
 # ---------------------------------------------------------------------------
 
@@ -590,7 +695,8 @@ def test_descriptor_partition_shape():
     groups = desc[kinds == KIND_DECODE]
     # 3 decode rows in sb=2 groups: (start 0, 2 slots), (start 2, 1).
     assert [(int(a), int(b)) for _, a, b in groups] == [(0, 2), (2, 1)]
-    assert list(dl[:3]) == [1, 3, 4]
+    # Longest context first: rows 4 (30), 1 (9), 3 (2).
+    assert list(dl[:3]) == [4, 1, 3]
     # Everything else is noop padding.
     n_active = 4 + len(prefill) + len(groups)
     assert (kinds[n_active:] == KIND_NOOP).all()
@@ -598,9 +704,11 @@ def test_descriptor_partition_shape():
 
 def test_descriptor_fast_decode_rows_bypass():
     """The runner's pure-decode fast path hands its row vector straight
-    in; the builder must not rescan q_lens."""
+    in; the builder must not rescan q_lens. The rows are cut by length
+    on this way in too."""
     si = np.zeros((4, 4), np.int32)
     si[:, 1] = 99  # garbage q_lens: must be ignored with decode_rows
+    si[:3, 2] = (7, 300, 41)
     desc, dl = build_partition_descriptor(
         si, 3, bq=32, sb=8,
         num_programs=num_partition_programs(16, 4, bq=32, sb=8),
@@ -609,7 +717,72 @@ def test_descriptor_fast_decode_rows_bypass():
     assert (kinds != KIND_PREFILL).all()
     groups = desc[kinds == KIND_DECODE]
     assert [(int(a), int(b)) for _, a, b in groups] == [(0, 3)]
-    assert list(dl[:3]) == [0, 1, 2]
+    assert list(dl[:3]) == [1, 2, 0]
+
+
+@pytest.mark.parametrize("fast", [False, True], ids=["scan", "decode_rows"])
+def test_descriptor_cuts_groups_by_length_stable_among_equals(fast):
+    """Both ways in: the single-token rows longest context first, rows
+    of one length in arrival order, prefill rows where they were; the
+    groups' starts and counts are those of the arrival order."""
+    kv = [40, 7, 300, 7, 129, 40, 7, 512, 1, 300, 40]
+    si = np.zeros((16, 4), np.int32)
+    si[:len(kv), 1] = 1
+    si[:len(kv), 2] = kv
+    rows = np.arange(len(kv), dtype=np.int32)
+    if not fast:
+        si[4, 1] = si[4, 2] = 20   # a prefill row among them
+        rows = np.delete(rows, 4)
+    sb = 4
+    desc, dl = build_partition_descriptor(
+        si, len(kv), bq=32, sb=sb,
+        num_programs=num_partition_programs(64, 16, bq=32, sb=sb),
+        decode_rows=rows if fast else None)
+    want = sorted(rows, key=lambda r: -si[r, 2])   # sorted() is stable
+    assert list(dl[:len(rows)]) == want
+    assert (dl[len(rows):] == 0).all()
+    lens = si[dl[:len(rows)], 2]
+    assert (np.diff(lens) <= 0).all()
+    groups = desc[desc[:, 0] == KIND_DECODE]
+    n = len(rows)
+    assert [(int(a), int(b)) for _, a, b in groups] == [
+        (g, min(sb, n - g)) for g in range(0, n, sb)]
+    if not fast:
+        assert [(int(a), int(b)) for _, a, b in
+                desc[desc[:, 0] == KIND_PREFILL]] == [(4, 0)]
+
+
+def test_walked_counter_is_what_the_descriptor_groups_fetch():
+    """``decode_kv_tokens_walked`` (the runner's
+    ``vdt:step_decode_kv_tokens_walked``) against a walk over the
+    descriptor of a hand-made step as ``_mega_kernel`` makes it: a
+    group's slot fetches a block a trip while its own sequence has one
+    left. Beside it the rule before the groups were cut by length
+    (every live slot fetched on every trip of its group's longest
+    walk), on the arrival order: what ordering and skipping save."""
+    kv = np.array([40, 700, 129, 1, 128, 1030, 257, 90, 513, 300, 5],
+                  np.int32)
+    si = np.zeros((16, 4), np.int32)
+    si[:len(kv), 1] = 1
+    si[:len(kv), 2] = kv
+    si[3, 1] = si[3, 2] = 77    # a prefill row: not a decode slot
+    sb, page_size, pages_per_req = 4, 16, 80
+    blk = page_size * kv_block_pages(page_size, pages_per_req)
+    assert blk == 128
+    desc, dl = build_partition_descriptor(
+        si, len(kv), bq=32, sb=sb,
+        num_programs=num_partition_programs(128, 16, bq=32, sb=sb))
+    fetched = arrival = 0
+    decode_rows = np.nonzero(si[:len(kv), 1] == 1)[0]
+    for _, a, cnt in desc[desc[:, 0] == KIND_DECODE]:
+        blocks = [-(-int(si[dl[a + i], 2]) // blk) for i in range(cnt)]
+        for trip in range(max(blocks)):
+            fetched += sum(trip < n for n in blocks)
+        old = [-(-int(si[r, 2]) // blk) for r in decode_rows[a:a + cnt]]
+        arrival += cnt * max(old)
+    got = decode_kv_tokens_walked(si[decode_rows, 2], blk)
+    assert got == fetched * blk == 32 * 128
+    assert arrival * blk == 66 * 128
 
 
 @pytest.mark.parametrize("t", [16, 64, 256])

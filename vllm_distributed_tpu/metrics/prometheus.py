@@ -180,6 +180,14 @@ HISTOGRAMS = {
         "vdt:step_kv_tokens_read",
         "Per step, context lengths (computed + scheduled tokens) summed "
         "over the scheduled requests: K/V tokens the attention reads"),
+    "step_decode_kv_tokens_walked": (
+        "vdt:step_decode_kv_tokens_walked",
+        "Per step, the K/V token slots that the paged attention kernel's "
+        "decode groups move in one full layer: every single-token "
+        "sequence's whole kv blocks (128 positions, or the page table's "
+        "width) up to its own end. Over the single-token part of "
+        "vdt:step_kv_tokens_read it is what the groups move for what "
+        "their queries see; absent for a model without K/V pages"),
     "step_latent_prefill_tokens": (
         "vdt:step_latent_prefill_tokens",
         "Per step, query rows that latent attention served in the "

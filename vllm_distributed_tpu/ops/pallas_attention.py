@@ -82,7 +82,10 @@ _MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 #
 # ``decode_list`` holds the seq_info row indices of every q_len == 1
 # sequence; any single-token run (a decode step OR a one-token chunked-
-# prefill tail — the attention math is identical) lands there.
+# prefill tail — the attention math is identical) lands there, LONGEST
+# kv_len FIRST (arrival order among equals): a group walks as many kv
+# blocks as its longest sequence has, so sequences of like length share
+# a group, and a slot moves no page past its own sequence's last block.
 #
 # The compile-lattice math: descriptor length and q padding are
 # deterministic functions of the token bucket, and no kernel static
@@ -165,11 +168,16 @@ def prefill_tile_size(num_q_heads: int, head_dim: int) -> int:
 def decode_group_size(num_q_heads: int, num_kv_heads: int) -> int:
     """Static decode-group width (sequences stacked as virtual heads per
     program). Independent of the runtime batch size — inactive slots are
-    masked — and sized against the worst-case 128-position kv block so
-    the same sb is valid for every caller (the cascade suffix call sees
-    a shorter block table than the main call). 128 query heads on 8 K/V
-    heads give 2: 256 score rows against 2 x 8 x 128 key columns; 28 on
-    4 are sized as the 32 they are padded to (``padded_q_heads``): 8."""
+    masked and move no page — and sized against the worst-case
+    128-position kv block so the same sb is valid for every caller (the
+    cascade suffix call sees a shorter block table than the main call).
+    128 query heads on 8 K/V heads give 2: 256 score rows against
+    2 x 8 x 128 key columns; 28 on 4 are sized as the 32 they are padded
+    to (``padded_q_heads``): 8. WHICH sequences share a group is the
+    descriptor's choice (``build_partition_descriptor``: by length); a
+    group's trips are its longest sequence's blocks and each trip's
+    product runs over all ``sb x KVH x blk`` columns, so the width is
+    also what a group of unlike lengths wastes."""
     num_q_heads = padded_q_heads(num_q_heads, num_kv_heads)
     sb = max(1, min(8, 128 // max(1, num_q_heads // 4)))
     while sb > 1 and (sb * num_q_heads) * (sb * num_kv_heads * 128) * 8 \
@@ -187,6 +195,25 @@ def num_partition_programs(t_bucket: int, max_num_reqs: int, *, bq: int,
             -(-max_num_reqs // sb))
 
 
+def kv_block_pages(page_size: int, pages_per_req: int) -> int:
+    """Pages a kv block of a kernel's page loop: as many as 128
+    positions hold, at least one, at most the table's width, and a
+    divisor of it."""
+    ppb = max(1, min(128 // page_size, pages_per_req))
+    while pages_per_req % ppb:
+        ppb -= 1
+    return ppb
+
+
+def decode_kv_tokens_walked(kv_lens: np.ndarray, blk: int) -> int:
+    """K/V token slots that the decode groups' page DMAs move in one
+    full layer for single-token sequences of these context lengths:
+    every sequence's whole kv blocks of ``blk`` positions up to its own
+    end and none past it, whoever shares its group. Over
+    ``kv_lens.sum()`` it is the last block's rounding alone."""
+    return int((-(-kv_lens // blk)).sum()) * blk
+
+
 def build_partition_descriptor(
     seq_info: np.ndarray,  # [R, 4] int32 host copy
     num_seqs: int,
@@ -201,7 +228,16 @@ def build_partition_descriptor(
 
     Returns ``(desc [num_programs, 3], decode_list [R])``. Pass
     ``decode_rows`` (row indices into seq_info) to skip the q_len scan —
-    the runner's pure-decode fast path feeds its row vector directly."""
+    the runner's pure-decode fast path feeds its row vector directly.
+
+    The single-token rows are cut into groups of ``sb`` LONGEST kv_len
+    FIRST, arrival order among equals (one stable argsort, on both ways
+    in): a group takes as many trips as its longest sequence has kv
+    blocks, so like lengths share a group, and the one group that may be
+    short of ``sb`` holds the shortest. ``decode_list`` carries the
+    order and outputs land through ``q_start``, so nothing downstream
+    sees it; under a window the same order serves the window layers
+    (``min(kv_len, window)`` is monotone in kv_len)."""
     R = seq_info.shape[0]
     desc = np.zeros((num_programs, 3), np.int32)
     dl = np.zeros((R, ), np.int32)
@@ -219,7 +255,8 @@ def build_partition_descriptor(
             desc[p:p + nt, 2] = np.arange(nt, dtype=np.int32) * bq
             p += nt
     n_dec = len(decode_rows)
-    dl[:n_dec] = decode_rows
+    dl[:n_dec] = decode_rows[np.argsort(-seq_info[decode_rows, 2],
+                                        kind="stable")]
     starts = np.arange(0, n_dec, sb, dtype=np.int32)
     ng = len(starts)
     assert p + ng <= num_programs, "partition descriptor overflow"
@@ -737,10 +774,7 @@ def ragged_paged_attention_pallas(
         bq //= 2
     num_q_tiles = pl.cdiv(max_q, bq)
     assert T_pad >= bq, "q must be padded to at least one tile"
-    # ~128 kv positions per block, at least one page.
-    ppb = max(1, min(128 // page_size, pages_per_req))
-    while pages_per_req % ppb:
-        ppb -= 1
+    ppb = kv_block_pages(page_size, pages_per_req)
     blk = ppb * page_size
 
     grid = (R, num_q_tiles)
@@ -826,6 +860,18 @@ def _mega_kernel(
     writes (fused variant) land first so attention reads this step's
     pages.
 
+    A decode group takes as many trips as its longest sequence has kv
+    blocks in sight, and the host puts like lengths together
+    (``build_partition_descriptor``). On each trip a slot starts, and
+    waits for, its page DMAs only while its OWN sequence has a block
+    left; an inactive slot moves nothing. The columns of a slot that
+    sat a trip out are masked like any other invisible position (their
+    probabilities are exactly 0 and the carries come through
+    bit-for-bit: ``alpha = 1``, ``pv = 0``), which asks one thing of
+    the buffers: what a skipped slot's V holds must be finite, so the
+    V scratch is zeroed by the call's first program and only pages of
+    the cache are written to it after.
+
     Per-model attention features ride along so windowed / soft-capped /
     ALiBi / sink models reach this kernel instead of the XLA fallback:
     ``window`` (sliding-window bound) and ``logit_cap`` (tanh
@@ -871,6 +917,20 @@ def _mega_kernel(
     blk = ppb * page_size
     half = D // 2
     nck = bq // 8  # 8-row IO chunks per prefill tile
+
+    @pl.when(p == 0)
+    def _clear():
+        # A decode slot that moves no page on a trip (its sequence has
+        # ended, or the slot is inactive) leaves its V buffer as it was,
+        # and the group's ``pr @ v_all`` multiplies those bits by a zero
+        # probability: they have to be finite. A scratch buffer starts
+        # as whatever VMEM held; everything written to it later is a
+        # page of the cache. k_vmem needs none of this: the stale
+        # columns of a score are replaced by ``where`` before the max,
+        # never multiplied. Once, at program 0, serves every later
+        # program only while the grid runs in order on one core
+        # (_mega_call sets no dimension_semantics).
+        v_vmem[...] = jnp.zeros(v_vmem.shape, v_vmem.dtype)
 
     if fuse_write:
 
@@ -1101,38 +1161,32 @@ def _mega_kernel(
         cnt = b
         R_dl = dl_ref.shape[0]
         idx = [dl_ref[jnp.minimum(a + i, R_dl - 1)] for i in range(sb)]
-        kv_lens = [
-            jnp.where(jnp.asarray(i) < cnt, seq_info_ref[idx[i], 2], 0)
-            for i in range(sb)
-        ]
+        live = [jnp.asarray(i) < cnt for i in range(sb)]
+        # An inactive slot aliases a live sequence's row; a kv_len of 0
+        # masks its scores and it moves no page.
+        kv_lens = [jnp.where(live[i], seq_info_ref[idx[i], 2], 0)
+                   for i in range(sb)]
         rows_ = [seq_info_ref[idx[i], 3] for i in range(sb)]
         q_starts = [seq_info_ref[idx[i], 0] for i in range(sb)]
-        max_kv = kv_lens[0]
-        for i in range(1, sb):
-            max_kv = jnp.maximum(max_kv, kv_lens[i])
+        last_blocks = [jnp.maximum(jax.lax.div(kv_lens[i] - 1, blk), 0)
+                       for i in range(sb)]
         if window > 0:
             # Each sequence walks from the block that holds the oldest
             # position its one query (at kv_len - 1) can see; the loop
-            # index is relative to that block. An inactive slot aliases
-            # a live sequence's row (its scores are masked by a kv_len
-            # of 0): it walks THAT sequence's blocks, so that it too
-            # stays behind no window.
-            walked = [seq_info_ref[idx[i], 2] for i in range(sb)]
-            last_blocks = [jnp.maximum(jax.lax.div(walked[i] - 1, blk), 0)
-                           for i in range(sb)]
-            first_blocks = [jnp.maximum(walked[i] - window, 0) // blk
+            # index is relative to that block.
+            first_blocks = [jnp.maximum(kv_lens[i] - window, 0) // blk
                             for i in range(sb)]
-            num_blocks = last_blocks[0] - first_blocks[0] + 1
-            for i in range(1, sb):
-                num_blocks = jnp.maximum(
-                    num_blocks,
-                    jnp.where(jnp.asarray(i) < cnt,
-                              last_blocks[i] - first_blocks[i] + 1, 1))
         else:
-            last_blocks = [
-                jnp.maximum(jax.lax.div(kv_lens[i] - 1, blk), 0)
-                for i in range(sb)]
-            num_blocks = jax.lax.div(max_kv - 1, blk) + 1
+            first_blocks = [0] * sb
+        # The kv blocks a slot's query can see. A slot moves those and
+        # no other: the group takes its longest slot's trips (the
+        # descriptor puts like lengths together), and a trip past a
+        # slot's own end leaves the slot's buffer as it was.
+        trips = [jnp.where(live[i], last_blocks[i] - first_blocks[i] + 1, 0)
+                 for i in range(sb)]
+        num_blocks = trips[0]
+        for i in range(1, sb):
+            num_blocks = jnp.maximum(num_blocks, trips[i])
         ROWS = sb * QH
         C = sb * KVH * blk
 
@@ -1143,22 +1197,21 @@ def _mega_kernel(
 
         def fetch(bi, slot):
             for i in range(sb):
-                if window > 0:
-                    ci = jnp.minimum(first_blocks[i] + bi, last_blocks[i])
-                else:
-                    ci = jnp.clip(bi, 0, last_blocks[i])
-                for j in range(ppb):
-                    page_id = block_tables_ref[rows_[i], ci * ppb + j]
-                    pltpu.make_async_copy(
-                        k_cache.at[layer, page_id],
-                        k_vmem.at[slot, i, :,
-                                  pl.ds(j * page_size, page_size)],
-                        kv_sems.at[slot, 0, i, j]).start()
-                    pltpu.make_async_copy(
-                        v_cache.at[layer, page_id],
-                        v_vmem.at[slot, i, :,
-                                  pl.ds(j * page_size, page_size)],
-                        kv_sems.at[slot, 1, i, j]).start()
+                @pl.when(bi < trips[i])
+                def _start(i=i):
+                    ci = first_blocks[i] + bi
+                    for j in range(ppb):
+                        page_id = block_tables_ref[rows_[i], ci * ppb + j]
+                        pltpu.make_async_copy(
+                            k_cache.at[layer, page_id],
+                            k_vmem.at[slot, i, :,
+                                      pl.ds(j * page_size, page_size)],
+                            kv_sems.at[slot, 0, i, j]).start()
+                        pltpu.make_async_copy(
+                            v_cache.at[layer, page_id],
+                            v_vmem.at[slot, i, :,
+                                      pl.ds(j * page_size, page_size)],
+                            kv_sems.at[slot, 1, i, j]).start()
 
         fetch(0, 0)
         for i in range(sb):
@@ -1208,17 +1261,19 @@ def _mega_kernel(
                 fetch(bi + 1, jax.lax.rem(bi + 1, 2))
 
             for i in range(sb):
-                for j in range(ppb):
-                    pltpu.make_async_copy(
-                        k_cache.at[0, 0],
-                        k_vmem.at[slot, i, :,
-                                  pl.ds(j * page_size, page_size)],
-                        kv_sems.at[slot, 0, i, j]).wait()
-                    pltpu.make_async_copy(
-                        v_cache.at[0, 0],
-                        v_vmem.at[slot, i, :,
-                                  pl.ds(j * page_size, page_size)],
-                        kv_sems.at[slot, 1, i, j]).wait()
+                @pl.when(bi < trips[i])
+                def _wait(i=i):
+                    for j in range(ppb):
+                        pltpu.make_async_copy(
+                            k_cache.at[0, 0],
+                            k_vmem.at[slot, i, :,
+                                      pl.ds(j * page_size, page_size)],
+                            kv_sems.at[slot, 0, i, j]).wait()
+                        pltpu.make_async_copy(
+                            v_cache.at[0, 0],
+                            v_vmem.at[slot, i, :,
+                                      pl.ds(j * page_size, page_size)],
+                            kv_sems.at[slot, 1, i, j]).wait()
             k_all = k_vmem[slot].reshape(C, D)
             v_all = v_vmem[slot].reshape(C, D)
             s = jax.lax.dot_general(
@@ -1311,10 +1366,7 @@ def _mega_call(q, k_pages, v_pages, desc, seq_info, decode_list, kv_runs,
         feat = _pad_query_groups(feat, num_kv_heads)
     T_pad, num_q_heads, head_dim = q.shape
     group = num_q_heads // num_kv_heads
-    pages_per_req = block_tables.shape[1]
-    ppb = max(1, min(128 // page_size, pages_per_req))
-    while pages_per_req % ppb:
-        ppb -= 1
+    ppb = kv_block_pages(page_size, block_tables.shape[1])
     blk = ppb * page_size
     stage_rows = max(bq, sb)
     if feat is None:
@@ -1384,6 +1436,11 @@ def _mega_call(q, k_pages, v_pages, desc, seq_info, decode_list, kv_runs,
     if fuse_write:
         scratch.append(pltpu.SemaphoreType.DMA((4, )))
 
+    # The grid must stay sequential (no ``dimension_semantics``, so
+    # "arbitrary"): the kv-write programs precede the attention programs
+    # that read their pages, and ``_clear`` at p == 0 zeroes V's scratch
+    # for every later program. Marked "parallel" on a two-core chip, the
+    # second core's scratch would start as whatever VMEM held.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(desc.shape[0], ),

@@ -172,6 +172,16 @@ class TPUModelRunner:
         # summed over the scheduled requests): what the attention kernel
         # must read, in tokens.
         self.kv_tokens_read_hist = Histogram(STEP_TOKEN_BUCKETS)
+        # K/V token slots the paged attention kernel's decode groups
+        # move in one full layer (whole kv blocks up to each
+        # single-token sequence's end): beside kv_tokens_read's
+        # single-token part, what the groups move for what they must.
+        # A formula over the step's lengths (the block rounding), not a
+        # reading of the kernel.
+        self.decode_kv_walked_hist = Histogram(STEP_TOKEN_BUCKETS)
+        # ... in kv blocks of this many positions: set once, with
+        # _tile_params' memo.
+        self._decode_kv_block = 0
         # Query rows of the step that latent attention served in the
         # multi-head form (ops/mla.latent_attention_values): 0 on a
         # decode step and for a model without a latent cache.
@@ -1003,7 +1013,7 @@ class TPUModelRunner:
             from vllm_distributed_tpu.ops.attention import \
                 storage_head_dim
             from vllm_distributed_tpu.ops.pallas_attention import (
-                decode_group_size, prefill_tile_size)
+                decode_group_size, kv_block_pages, prefill_tile_size)
             cfg = self.model.cfg
             tp = max(1, self.config.parallel_config.tensor_parallel_size)
             qh = max(1, cfg.num_q_heads // tp)
@@ -1012,6 +1022,8 @@ class TPUModelRunner:
             hd = storage_head_dim(cfg.head_dim)
             self._tile_params_memo = (prefill_tile_size(qh, hd),
                                       decode_group_size(qh, kvh))
+            self._decode_kv_block = self.page_size * kv_block_pages(
+                self.page_size, self.max_pages_per_req)
         return self._tile_params_memo
 
     def _batch_shape(self, total_tokens: int,
@@ -1347,9 +1359,14 @@ class TPUModelRunner:
         bq = sb = 0
         P_desc = layout.desc_programs
         if P_desc:
-            from vllm_distributed_tpu.ops.pallas_attention import \
-                build_partition_descriptor
+            from vllm_distributed_tpu.ops.pallas_attention import (
+                build_partition_descriptor, decode_kv_tokens_walked)
             bq, sb = self._tile_params()
+            kv_lens = seq_info[:num_runs, 2]
+            if fast is None:
+                kv_lens = kv_lens[seq_info[:num_runs, 1] == 1]
+            self.decode_kv_walked_hist.observe(
+                decode_kv_tokens_walked(kv_lens, self._decode_kv_block))
             f["attn_desc"][:], f["decode_list"][:] = \
                 build_partition_descriptor(
                     seq_info, num_runs, bq=bq, sb=sb,
@@ -2786,6 +2803,9 @@ class TPUModelRunner:
             stats["moe_experts_held"] = int(held[1])
             stats["moe_experts_published"] = int(
                 self.model.cfg.router_experts)
+        if self._use_unified():
+            stats["step_decode_kv_tokens_walked"] = \
+                self.decode_kv_walked_hist.to_dict()
         if self._window_group:
             stats["step_kv_window_tokens_read"] = \
                 self.kv_window_tokens_read_hist.to_dict()
