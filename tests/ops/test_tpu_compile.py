@@ -648,22 +648,28 @@ def segment_shapes(T: int, S: int):
 
 @pytest.mark.parametrize("tokens,form", [
     (1, "decode"), (2048, "chunk"), (2048, "auto")])
+@pytest.mark.parametrize("name,geometry", [
+    ("granite-4.0-h-micro", (64, 64, 128, 1, 36)),
+    ("granite-4.0-h-small-ep2", (128, 64, 128, 1, 9))])
 def test_mamba2_kernels_compile_at_published_widths(
-        compile_for_chip, tokens, form):
+        compile_for_chip, name, geometry, tokens, form):
     """``ops/ssd.py`` as `granite-4.0-h-micro` serves it: 64 heads of 64
     channels on 128 state channels, a row of 128 x 4096 float32, 56
     state rows and the dump row, 36 layers in one buffer of 4.9 GB that
     no instruction of the program makes again; a decode batch (one
     token a row: ``ssd_recurrent``), and the 2 048-token bucket in the
     chunked form at Q = 256 (``ssd_chunk``) and as the program picks
-    between the forms at run time."""
+    between the forms at run time. And as `granite-4.0-h-small-ep2`
+    does: 128 heads, a row of 128 x 8192 float32 (4 MiB a layer, twice
+    Micro's), 9 layers: the lane blocks a grid step holds still fit
+    VMEM."""
     from benchmarks.rooflines.ssd_state import mamba_layers
     from vllm_distributed_tpu.ops import ssd
-    cf = bench_config("granite-4.0-h-micro")
+    cf = bench_config(name)
     Hm, Pd, N, G = (cf["mamba_n_heads"], cf["mamba_d_head"],
                     cf["mamba_d_state"], cf["mamba_n_groups"])
     S, layers = cf["engine_args"]["max_num_seqs"], mamba_layers(cf)
-    assert (Hm, Pd, N, G, S, layers) == (64, 64, 128, 1, 56, 36)
+    assert (Hm, Pd, N, G, layers) == geometry
     T = S + 8 if form == "decode" else tokens + 8
     leaves, tree = segment_shapes(T, S)
     f32 = jnp.float32
@@ -686,15 +692,18 @@ def test_mamba2_kernels_compile_at_published_widths(
     assert f"%{ssd.KERNEL_RECURRENT}" in text
     assert (f"%{ssd.KERNEL_CHUNK}" in text) == (form in ("chunk", "auto"))
     # No state per token: the largest temporaries are the chunks' masks
-    # (13 chunks x 64 heads x 256 x 256), far under a state a token
-    # (2 MiB x 2 056).
-    assert compiled.memory_analysis().temp_size_in_bytes < 600 * 2 ** 20
+    # (13 chunks x 64 heads x 256 x 256; twice that at 128 heads), far
+    # under a state a token (2 MiB x 2 056).
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        600 * 2 ** 20 * Hm // 64
 
 
 @pytest.mark.parametrize("tokens", [64, 2048])
 @pytest.mark.parametrize("name,layers,rows,width,bias", [
     ("granite-4.0-h-micro", 36, 56, 4096, True),
     ("granite-4.0-h-micro", 36, 56, 256, True),
+    ("granite-4.0-h-small-ep2", 9, 64, 8192, True),
+    ("granite-4.0-h-small-ep2", 9, 64, 256, True),
     ("ling3-flash-ep4", 6, 96, 12288, False)])
 def test_conv_rows_kernel_compiles_at_published_widths(
         one_chip, no_compile_cache, name, layers, rows, width, bias, tokens):
@@ -817,6 +826,11 @@ ONE_LAYER = {
         "rope_layout": [0, 1]},
     # Every layer is a retention layer; two, so that the scan is there.
     "brumby-14b": {"num_hidden_layers": 2},
+    # A mamba and an attention layer, each with its router, its held
+    # experts and its shared MLP: one period, as the file's ten are, so
+    # a layer's expert buffers are picked outright.
+    "granite-4.0-h-small-ep2": {"num_hidden_layers": 2,
+                                "layer_types": ["mamba", "attention"]},
 }
 
 
@@ -1023,6 +1037,20 @@ def test_one_layer_forward_compiles(abstract_runner, name, t_bucket):
                              r"(copy|dynamic-slice|dynamic-update-slice)\(",
                              text)
         assert text.count(" while(") == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
+    if name == "granite-4.0-h-small-ep2":
+        # Both layers' grouped GEMMs have the share's rows (640 picks
+        # worked on whole at the decode bucket, 15 552 of 20 560 at the
+        # largest), read the 36 held experts' slabs in place, and the
+        # state rows (4 MiB a layer) are updated in place.
+        text = compiled.as_text()
+        assert ragged_dot_rows(compiled) == {dispatch_rows(runner.model, T)}
+        assert text.count("ragged-dot") >= 6
+        for slab in ("bf16[36,4096,768]", "bf16[36,768,4096]"):
+            assert slab in text.split("\n", 1)[0]
+            assert slab_copies(compiled, slab) == []
+        S = bench_config(name)["engine_args"]["max_num_seqs"]
+        assert slab_copies(compiled, f"f32[1,{S + 1},128,8192]") == []
         assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
     if name == "zaya1-8b":
         # The carried rows (u; c0 and vb widened to twelve tiles) come

@@ -70,6 +70,15 @@ def test_page_is_priced_at_one_devices_share(checkpoint, parallel):
     # rows, 2.00 GiB of them the pages of 64 full-length requests.
     pytest.param("rows of 73 MiB", 76_437_504, 131_072, 256, 64,
                  int(2.04 * 2 ** 30), 8, id="granite"),
+    # granite-4.0-h-small-ep2 at 64 rows beside 9.51 GB of weights
+    # (PR 55, call A): rows of 38.2 MB, pages of 4 KiB a token; 2.17 GiB
+    # after the rows, 1.00 GiB of them the pages of 64 full-length
+    # requests; the rest buys 32 slots, in eights.
+    pytest.param("rows of 36 MiB", 38_204_928, 65_536, 256, 64,
+                 int(2.17 * 2 ** 30), 32, id="granite-small-ep2"),
+    # The same rows where the room is the pages' alone: eight are kept.
+    pytest.param("rows of 36 MiB, no room", 38_204_928, 65_536, 256, 64,
+                 int(1.10 * 2 ** 30), 8, id="granite-small-ep2-tight"),
     # zaya1-8b (PR 41, call 1): 2.96 GiB after the rows, under the
     # 4.00 GiB that 64 full-length requests' pages would need, and a
     # default pool of 128 slots that costs 11 MB: kept whole.

@@ -16,6 +16,8 @@ layer walk is a scan over the periods of the layer pattern
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -341,6 +343,11 @@ class BambaForCausalLM(JambaForCausalLM):
         return (attn.reshape(T, QH * D) @ lp["wo"],
                 {**caches, "k": k_all, "v": v_all})
 
+    def _mlp(self, lp: dict, x: jax.Array) -> jax.Array:
+        """What follows a layer's mixer, of the normed stream: a dense
+        SwiGLU here; models/granite_hybrid.py adds routed experts."""
+        return swiglu(x, lp["gate"], lp["up"], lp["down"])
+
     def run_layers(
         self,
         layer_params: dict,
@@ -379,6 +386,28 @@ class BambaForCausalLM(JambaForCausalLM):
         periods = c.num_layers // period
         kinds = [self._is_attn(i) for i in range(period)]
         per = {"a_": sum(kinds), "m_": period - sum(kinds)}
+        # A tuple leaf is one buffer a layer (``serving_layout``,
+        # models/mixtral.py: what ``ragged_dot`` must read in place); it
+        # is not sliced by the walk but picked for the MLP alone.
+        per_layer = {k: v for k, v in layer_params.items()
+                     if isinstance(v, tuple)}
+        layer_params = {k: v for k, v in layer_params.items()
+                        if k not in per_layer}
+
+        def mlp(lp, x, kind, pi, at):
+            """``_mlp`` of the layer at place ``at`` of its kind in
+            period ``pi``, with the layer's own buffers: picked outright
+            where the walk is one period, else by a switch with one
+            branch a period (models/llama.py ``run_layers``)."""
+            own = {k[2:]: v for k, v in per_layer.items()
+                   if k.startswith(kind)}
+            if not own or periods == 1:
+                return self._mlp(
+                    {**lp, **{k: v[at] for k, v in own.items()}}, x)
+            block = jax.jit(self._mlp)
+            return jax.lax.switch(pi, [functools.partial(block, {
+                **lp, **{k: v[p * per[kind] + at] for k, v in own.items()}})
+                for p in range(periods)], x)
 
         def body(carry, pi):
             h, caches = carry
@@ -388,7 +417,6 @@ class BambaForCausalLM(JambaForCausalLM):
                 # The layer's index in its kind's stack; its weights are
                 # read where they lie (a period's would be a copy).
                 layer = pi * per[kind] + at[kind]
-                at[kind] += 1
                 lp = {k[2:]: jax.lax.dynamic_index_in_dim(v, layer, 0, False)
                       for k, v in layer_params.items()
                       if k.startswith(kind)}
@@ -403,7 +431,8 @@ class BambaForCausalLM(JambaForCausalLM):
                         decode_only)
                 h = h + rm * out
                 x2 = rms_norm(h, lp["pre_ln"], c.rms_norm_eps)
-                h = h + rm * swiglu(x2, lp["gate"], lp["up"], lp["down"])
+                h = h + rm * mlp(lp, x2, kind, pi, at[kind])
+                at[kind] += 1
             return (h, caches), None
 
         carry = (hidden, dict(kv_caches))

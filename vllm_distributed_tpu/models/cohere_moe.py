@@ -65,7 +65,8 @@ from jax.sharding import PartitionSpec as P
 
 from vllm_distributed_tpu.models.families_ext import Cohere2ForCausalLM
 from vllm_distributed_tpu.models.llama import MODEL_AXIS
-from vllm_distributed_tpu.models.mixtral import MixtralForCausalLM
+from vllm_distributed_tpu.models.mixtral import (MixtralForCausalLM,
+                                                 read_expert_share)
 
 
 def _published(hf, key: str, want) -> None:
@@ -106,22 +107,7 @@ class Cohere2MoeForCausalLM(MixtralForCausalLM):
             getattr(hf, "num_shared_experts", 0) or 0)
         arch.shared_expert_intermediate_size = (
             arch.num_shared_experts * arch.moe_intermediate_size)
-        arch.router_experts = arch.num_experts
-        arch.experts_held = None
-        share = getattr(hf, "expert_share", None)
-        if share:
-            published, chips, index = (int(share[k]) for k in (
-                "published_experts", "chips", "index"))
-            if (published != arch.num_experts * chips
-                    or not 0 <= index < chips):
-                raise ValueError(
-                    f"expert_share {dict(share)} does not describe "
-                    f"num_experts={arch.num_experts} held experts: "
-                    "published_experts must be num_experts x chips and "
-                    "index one of the chips")
-            arch.router_experts = published
-            arch.experts_held = (index * arch.num_experts,
-                                 arch.num_experts)
+        read_expert_share(arch, hf, "num_experts")
         arch.window_group = cls.window_group_of(arch)
 
     # ------------------------------------------------------------------

@@ -70,7 +70,8 @@ from vllm_distributed_tpu.models.common import (AttentionBatch,
                                                 rms_norm)
 from vllm_distributed_tpu.models.llama import (MODEL_AXIS, TOKEN_AXIS,
                                                LlamaForCausalLM)
-from vllm_distributed_tpu.models.mixtral import MixtralForCausalLM
+from vllm_distributed_tpu.models.mixtral import (MixtralForCausalLM,
+                                                 read_expert_share)
 from vllm_distributed_tpu.ops.mla import (latent_attention_values,
                                           latent_shard_dim,
                                           latent_storage_dim,
@@ -119,22 +120,7 @@ class DeepseekV2ForCausalLM(MixtralForCausalLM):
         arch.norm_topk_prob = bool(getattr(hf, "norm_topk_prob", False))
         # Router width: the published expert count under an expert share
         # (module docstring), else the experts there are.
-        arch.router_experts = arch.num_experts
-        arch.experts_held = None
-        share = getattr(hf, "expert_share", None)
-        if share:
-            published, chips, index = (int(share[k]) for k in (
-                "published_experts", "chips", "index"))
-            if (published != arch.num_experts * chips
-                    or not 0 <= index < chips):
-                raise ValueError(
-                    f"expert_share {dict(share)} does not describe "
-                    f"n_routed_experts={arch.num_experts} held experts: "
-                    "published_experts must be n_routed_experts x chips "
-                    "and index one of the chips")
-            arch.router_experts = published
-            arch.experts_held = (index * arch.num_experts,
-                                 arch.num_experts)
+        read_expert_share(arch, hf, "n_routed_experts")
         if getattr(hf, "moe_layer_freq", 1) not in (None, 1):
             raise ValueError("DeepSeek moe_layer_freq != 1 layouts are "
                              "not supported")

@@ -1,11 +1,14 @@
 """Granite 4.0-H (IBM, ``model_type`` ``granitemoehybrid``): Mamba-2
 layers with a GQA layer where ``layer_types`` says ``attention``, a
-dense SwiGLU (``shared_mlp``) after every mixer, Granite's four
-multipliers, no positional rotation, a tied head.
+dense SwiGLU (``shared_mlp``) after every mixer and, where
+``num_local_experts`` > 0 (the 4.0-H Tiny and Small models), routed
+experts beside it; Granite's four multipliers, no positional rotation,
+a tied head.
 
     h0 = embedding_multiplier * embed[ids]
-    h  = h + residual_multiplier * mix(norm1(h))
-    h  = h + residual_multiplier * mlp(norm2(h))
+    a  = h + residual_multiplier * mix(norm1(h))
+    x  = norm2(a)
+    h' = a + residual_multiplier * (moe(x) + shared_mlp(x))
     logits = (norm_f(h) embed^T) / logits_scaling
 
 ``mix`` on an attention layer is plain causal GQA with the softmax scale
@@ -15,12 +18,32 @@ is Bamba's (models/bamba.py): per-kind stacked parameters, a scan over
 the periods of ``layer_types``, K/V pages beside state rows. What
 differs from Bamba: the multipliers, no rotation, the layer list's key,
 the checkpoint's names (``shared_mlp.input_linear`` holds gate and up
-fused), the head tied to the embedding without a transposed copy, and
-two 64-lane K/V heads sharing one 128-lane page row.
+fused), the head tied to the embedding without a transposed copy,
+two 64-lane K/V heads sharing one 128-lane page row, and the experts.
 
-Not wired (refused at load, by name): routed experts
-(``num_local_experts`` > 0: the 4.0-H Tiny and Small models) and any
-``position_embedding_type`` but ``nope``.
+**Routed experts** (``moe``; absent with ``num_local_experts`` 0, the
+Micro family, whose tree and programs are what they were): the router
+(``block_sparse_moe.router.layer``, no bias) scores every published
+expert, the ``num_experts_per_tok`` largest LOGITS are kept and a softmax
+over the kept ones weighs them (equal to a softmax over all, top-k,
+renormalised: ``MixtralForCausalLM._route`` with ``norm_topk_prob``; a
+test holds the two equal); an expert is a SwiGLU of width
+``intermediate_size`` (the catalog row's note reads that key as the
+expert's width; ``shared_intermediate_size`` is the shared MLP's). The
+dispatch is models/mixtral.py's ``moe_dispatch``: the experts live in
+the per-kind stacks (``a_w_gate`` ``[La, E, H, I]`` ...), which
+``serving_layout`` splits into a buffer a layer for ``ragged_dot`` to
+read in place, and Bamba's walk hands ``_mlp`` its layer's buffers.
+With ``expert_share`` in ``config.json`` the chip holds
+``num_local_experts`` of ``published_experts`` (models/deepseek.py's
+docstring has the deployment this stands for): the router keeps its
+published width, picks on experts held elsewhere add nothing, the
+shared MLP and the mixers are whole, and the partial sum goes on.
+
+Not wired (refused at load, by name): any ``position_embedding_type``
+but ``nope``; for the routed experts expert parallelism, EPLB and
+pipeline stages (refused for every stateful hybrid, models/loader.py)
+and, under ``expert_share``, tensor parallelism.
 """
 
 from __future__ import annotations
@@ -29,21 +52,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.sharding import PartitionSpec as P
+
 from vllm_distributed_tpu.models.bamba import BambaForCausalLM
-from vllm_distributed_tpu.models.llama import LlamaForCausalLM
+from vllm_distributed_tpu.models.llama import MODEL_AXIS, LlamaForCausalLM
+from vllm_distributed_tpu.models.mixtral import (MixtralForCausalLM,
+                                                 read_expert_share)
 
 _LANES = 128
 
 
 class GraniteMoeHybridForCausalLM(BambaForCausalLM):
 
+    # The experts' stacks, one a kind of layer (``serving_layout``
+    # splits them into a buffer a layer, models/mixtral.py).
+    _PER_LAYER = tuple(kind + name for kind in ("a_", "m_")
+                       for name in MixtralForCausalLM._EXPERT_WEIGHTS)
+
     @classmethod
     def configure_arch(cls, arch, hf) -> None:
-        if getattr(hf, "num_local_experts", 0):
-            raise ValueError(
-                f"num_local_experts={hf.num_local_experts}: the routed "
-                f"experts of GraniteMoeHybrid are not wired (only the "
-                f"dense shared_mlp of the 4.0-H Micro family)")
         pos = getattr(hf, "position_embedding_type", "nope")
         if pos != "nope":
             raise ValueError(
@@ -58,6 +85,19 @@ class GraniteMoeHybridForCausalLM(BambaForCausalLM):
                                   if kind == "attention")
         arch.rotary_dim = 0
         arch.intermediate_size = int(hf.shared_intermediate_size)
+        arch.num_experts = int(getattr(hf, "num_local_experts", 0) or 0)
+        if arch.num_experts:
+            arch.num_experts_per_tok = int(hf.num_experts_per_tok)
+            # The width of ONE routed expert (the catalog row's reading
+            # of the key; the shared MLP has a key of its own).
+            arch.moe_intermediate_size = int(hf.intermediate_size)
+        read_expert_share(arch, hf, "num_local_experts")
+        if arch.num_experts and not (0 < arch.num_experts_per_tok
+                                     <= arch.router_experts):
+            raise ValueError(
+                f"num_local_experts={arch.num_experts} needs "
+                f"num_experts_per_tok between 1 and the router's width "
+                f"({arch.router_experts}), not {arch.num_experts_per_tok}")
         arch.embed_scale = float(getattr(hf, "embedding_multiplier", 1.0))
         arch.residual_multiplier = float(
             getattr(hf, "residual_multiplier", 1.0))
@@ -80,6 +120,13 @@ class GraniteMoeHybridForCausalLM(BambaForCausalLM):
         specs = BambaForCausalLM.param_specs(self)
         if self.cfg.tie_word_embeddings:
             del specs["lm_head"]
+        if self.cfg.num_experts:
+            for kind in ("a_", "m_"):
+                specs["layers"].update({
+                    kind + "router": P(None, None, None),
+                    kind + "w_gate": P(None, None, None, MODEL_AXIS),
+                    kind + "w_up": P(None, None, None, MODEL_AXIS),
+                    kind + "w_down": P(None, None, MODEL_AXIS, None)})
         return specs
 
     def init_params(self, rng: jax.Array, scale: float = 0.02) -> dict:
@@ -94,10 +141,25 @@ class GraniteMoeHybridForCausalLM(BambaForCausalLM):
         (at 0.02 and 1/64 they would have 0.1 and every query would
         attend evenly, whatever the scale or a rotation did); and a
         final norm of ``logits_scaling``, so the tied head's logits have
-        a standard deviation near 1 after the division."""
+        a standard deviation near 1 after the division. The routed
+        experts and the router are at ``scale`` like the rest: at 0.02
+        and 4096 channels a router logit has a standard deviation of
+        1.28, so the kept ten of 72 weigh from about 0.26 down to 0.05."""
         c = self.cfg
         params = BambaForCausalLM.init_params(self, rng, scale)
         lay = params["layers"]
+        if c.num_experts:
+            H, E, I = c.hidden_size, c.num_experts, c.moe_intermediate_size
+            ekeys = iter(jax.random.split(jax.random.fold_in(rng, 3), 8))
+            for kind in ("a_", "m_"):
+                depth = lay[kind + "pre_ln"].shape[0]
+                for name, shape in (("router", (H, c.router_experts)),
+                                    ("w_gate", (E, H, I)),
+                                    ("w_up", (E, H, I)),
+                                    ("w_down", (E, I, H))):
+                    lay[kind + name] = (scale * jax.random.normal(
+                        next(ekeys), (depth, *shape),
+                        jnp.float32)).astype(c.dtype)
         keys = iter(jax.random.split(jax.random.fold_in(rng, 2), 8))
         Lm, Hm = lay["m_A_log"].shape
         f32 = jnp.float32
@@ -146,7 +208,57 @@ class GraniteMoeHybridForCausalLM(BambaForCausalLM):
             self, alias, prefix)
         if self.cfg.tie_word_embeddings:
             del params["lm_head"]
+        if self.cfg.num_experts:
+            params["layers"].update(self._experts_from_hf(tensors, prefix))
         return params
+
+    def _experts_from_hf(self, tensors: dict, prefix: str) -> dict:
+        """``block_sparse_moe``: ``input_linear.weight`` ``[E, 2 I, H]``
+        (gate rows over up rows), ``output_linear.weight`` ``[E, H, I]``,
+        ``router.layer.weight`` ``[experts, H]``, into the per-kind
+        stacks; under an expert share the held experts' slabs alone."""
+        c = self.cfg
+        I = c.moe_intermediate_size
+        lo, n = c.experts_held or (0, c.num_experts)
+        moe = prefix + ".layers.{}.block_sparse_moe."
+
+        def stack(ids, name, f):
+            return jnp.asarray(np.stack(
+                [f(np.asarray(tensors[moe.format(i) + name]))
+                 for i in ids])).astype(c.dtype)
+
+        out = {}
+        for kind, ids in (("a_", self._attn_layers),
+                          ("m_", self._mamba_layers)):
+            out[kind + "router"] = stack(
+                ids, "router.layer.weight", lambda a: a.T)
+            out[kind + "w_gate"] = stack(
+                ids, "input_linear.weight",
+                lambda a: a[lo:lo + n, :I].transpose(0, 2, 1))
+            out[kind + "w_up"] = stack(
+                ids, "input_linear.weight",
+                lambda a: a[lo:lo + n, I:].transpose(0, 2, 1))
+            out[kind + "w_down"] = stack(
+                ids, "output_linear.weight",
+                lambda a: a[lo:lo + n].transpose(0, 2, 1))
+        return out
+
+    # ------------------------------------------------------------------
+    def _route(self, lp: dict, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+        """The published order: the largest logits kept, a softmax over
+        the kept ones (float32, as every router here)."""
+        logits = (x.astype(jnp.float32)
+                  @ lp["router"].astype(jnp.float32))
+        top_vals, top_idx = jax.lax.top_k(logits,
+                                          self.cfg.num_experts_per_tok)
+        return top_idx, jax.nn.softmax(top_vals, axis=-1)
+
+    def _mlp(self, lp: dict, x: jax.Array) -> jax.Array:
+        if not self.cfg.num_experts:
+            return BambaForCausalLM._mlp(self, lp, x)
+        routed = MixtralForCausalLM.mlp_block(self, lp, x)
+        with jax.named_scope("moe_shared_mlp"):
+            return routed + BambaForCausalLM._mlp(self, lp, x)
 
     def compute_logits(self, params: dict, hidden: jax.Array) -> jax.Array:
         if "lm_head" in params:

@@ -351,7 +351,11 @@ def get_model(config: EngineConfig, mesh,
                  "TPLA (set VDT_TPLA=0 or tensor_parallel_size=1)"),
                 (pc.pipeline_parallel_size > 1,
                  "pipeline parallelism (the layers left out of the "
-                 "share's file are the further stages)")):
+                 "share's file are the further stages)"),
+                (getattr(arch, "stateful", False)
+                 and pc.tensor_parallel_size > 1,
+                 "tensor parallelism over a state-space stack (the "
+                 "share's mixers are whole on each chip)")):
             if bad:
                 raise ValueError(
                     f"expert_share (config.json) cannot be combined "

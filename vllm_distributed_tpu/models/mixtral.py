@@ -86,6 +86,31 @@ def padded_picks(picks: int, groups: int) -> int:
                key=lambda rows: _ragged_dot_cost(rows, groups))
 
 
+def read_expert_share(arch, hf, key: str) -> None:
+    """``expert_share`` of a configuration file, read for every family
+    that serves one chip's share of an expert layer (models/deepseek.py,
+    models/cohere_moe.py, models/granite_hybrid.py): sets
+    ``arch.router_experts`` (the router's width: the published count
+    under a share, else the experts there are) and ``arch.experts_held``
+    (``(first, count)`` of the experts whose weights are here, ``None``
+    without a share: ``moe_dispatch`` asks it). ``key`` is the family's
+    name for the count of experts held, for the refusal's text."""
+    arch.router_experts = arch.num_experts
+    arch.experts_held = None
+    share = getattr(hf, "expert_share", None)
+    if not share:
+        return
+    published, chips, index = (int(share[k]) for k in (
+        "published_experts", "chips", "index"))
+    if published != arch.num_experts * chips or not 0 <= index < chips:
+        raise ValueError(
+            f"expert_share {dict(share)} does not describe "
+            f"{key}={arch.num_experts} held experts: published_experts "
+            f"must be {key} x chips and index one of the chips")
+    arch.router_experts = published
+    arch.experts_held = (index * arch.num_experts, arch.num_experts)
+
+
 @functools.lru_cache(maxsize=None)
 def _take_layer(sharding):
     return jax.jit(
@@ -370,8 +395,8 @@ class MixtralForCausalLM(LlamaForCausalLM):
         """Routing-agnostic grouped-GEMM dispatch of (token, expert)
         assignments (see mlp_block docstring for the mechanism); under
         an expert share (``cfg.experts_held``, set by the families that
-        read ``expert_share``: models/deepseek.py, models/cohere_moe.py)
-        the share's own dispatch."""
+        read ``expert_share``: models/deepseek.py, models/cohere_moe.py,
+        models/granite_hybrid.py) the share's own dispatch."""
         from vllm_distributed_tpu import envs
         c = self.cfg
         if getattr(c, "experts_held", None) is not None:
